@@ -79,12 +79,12 @@ def _gate(model, path, name, prompt, extra=None,
 
 
 def test_llama_yarn_at_scale(tmp_path):
-    """hidden 1024 x 8 layers, YaRN factor 16 with the prompt reaching
+    """hidden 1024 x 6 layers, YaRN factor 16 with the prompt reaching
     4x past the original window — interpolated bands at real scale."""
     torch.manual_seed(0)
     cfg = transformers.LlamaConfig(
         vocab_size=1024, hidden_size=1024, intermediate_size=2816,
-        num_hidden_layers=8, num_attention_heads=8,
+        num_hidden_layers=6, num_attention_heads=8,
         num_key_value_heads=4, max_position_embeddings=4096,
         rope_theta=500000.0,
         rope_scaling={"rope_type": "yarn", "factor": 16.0,

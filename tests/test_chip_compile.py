@@ -1,0 +1,229 @@
+"""Compile what the chip serves, for the chip, without the chip.
+
+The TPU's compiler is installed here and compiles for a v5e that is
+described and not attached (tools/aot_tpu.py, the topology
+tests/test_copy_census.py builds too). One parametrised test lowers the
+kernels of the serving path at llama3-1b widths through real Mosaic —
+what interpret mode cannot show: a slice not aligned to the tiling, too
+much fast memory, a shape that cannot be partitioned — plus the
+engine's own decode step program at full width, two layers deep, with
+the pool pinned as a single-device engine pins it.
+
+A compile that passes is not a chip run: results and times come from
+``chip_smoke.py``. Skipped where the topology cannot be described.
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+# llama3-1b widths (config.py ModelConfig.llama3_1b) at the worker CLI's
+# page size, and the smoke's pool.
+HQ, HKV, D, PS, L, P = 32, 8, 64, 128, 16, 1024
+B_DEC, MP = 8, 16          # decode batch and page-table width (len 2048)
+B_PF, T = 2, 256           # a prefill window of two pages
+MLA_HKV, MLA_D = 1, 576    # absorbed-MLA latent pool (kv_lora 512 + rope 64)
+
+
+def _pools(sds, hkv=HKV, d=D):
+    pool = sds((L, P, PS, hkv, d), jnp.bfloat16)
+    return pool, pool
+
+
+def _decode_attention(sds, hq=HQ, hkv=HKV, d=D):
+    from xllm_service_tpu.ops.pallas import paged_decode_attention_pallas
+    fn = functools.partial(paged_decode_attention_pallas, interpret=False)
+    return (lambda q, kp, vp, pt, ctx, lyr: fn(q, kp, vp, pt, ctx,
+                                               layer=lyr),
+            (sds((B_DEC, hq, d), jnp.bfloat16), *_pools(sds, hkv, d),
+             sds((B_DEC, MP), jnp.int32), sds((B_DEC,), jnp.int32),
+             sds((), jnp.int32)), {})
+
+
+def _decode_writer(sds, hkv=HKV, d=D):
+    from xllm_service_tpu.ops.pallas.kv_update import paged_kv_update_layer
+    new = sds((B_DEC, hkv, d), jnp.bfloat16)
+    return (functools.partial(paged_kv_update_layer, interpret=False),
+            (*_pools(sds, hkv, d), new, new, sds((B_DEC, MP), jnp.int32),
+             sds((B_DEC,), jnp.int32), sds((B_DEC,), jnp.bool_),
+             sds((), jnp.int32)), {"donate_argnums": (0, 1)})
+
+
+def _prefill_writer(sds, hkv=HKV, d=D):
+    from xllm_service_tpu.ops.pallas.kv_update import (
+        paged_prefill_kv_update_layer)
+    new = sds((B_PF, T, hkv, d), jnp.bfloat16)
+    return (functools.partial(paged_prefill_kv_update_layer,
+                              interpret=False),
+            (*_pools(sds, hkv, d), new, new, sds((B_PF, MP), jnp.int32),
+             sds((B_PF,), jnp.int32), sds((B_PF,), jnp.int32),
+             sds((), jnp.int32)), {"donate_argnums": (0, 1)})
+
+
+def _prefill_attention(sds):
+    """The opt-in prefill kernel (XLLM_PALLAS_PREFILL) in the form
+    write-then-attend calls it: window and prefix both from the pool."""
+    from xllm_service_tpu.ops.pallas import paged_prefill_attention_pallas
+    return (lambda q, kp, vp, pt, st, ln, lyr:
+            paged_prefill_attention_pallas(
+                q, None, None, kp, vp, pt, st, ln, layer=lyr,
+                from_pool=True, interpret=False),
+            (sds((B_PF, T, HQ, D), jnp.bfloat16), *_pools(sds),
+             sds((B_PF, MP), jnp.int32), sds((B_PF,), jnp.int32),
+             sds((B_PF,), jnp.int32), sds((), jnp.int32)), {})
+
+
+def _ragged_attention(sds):
+    """The opt-in ragged kernel (XLLM_RAGGED_ATTN): decode rows and
+    prefill windows in one batch."""
+    from xllm_service_tpu.ops.pallas import ragged_paged_attention_pallas
+    return (lambda q, kp, vp, pt, st, ln, lyr:
+            ragged_paged_attention_pallas(q, kp, vp, pt, st, ln, layer=lyr,
+                                          interpret=False),
+            (sds((B_DEC, T, HQ, D), jnp.bfloat16), *_pools(sds),
+             sds((B_DEC, MP), jnp.int32), sds((B_DEC,), jnp.int32),
+             sds((B_DEC,), jnp.int32), sds((), jnp.int32)), {})
+
+
+KERNELS = {
+    # The default path: what a served worker runs on the chip.
+    "decode-attention": _decode_attention,
+    "decode-kv-writer": _decode_writer,
+    "prefill-kv-writer": _prefill_writer,
+    # Opt-in kernels: compile-checked here, their A/B is later work.
+    "prefill-attention[opt-in]": _prefill_attention,
+    "ragged-attention[opt-in]": _ragged_attention,
+    # The MLA latent pool, one KV "head" of width 576 (not a multiple of
+    # the 128-lane tile): the first benchmark cells will be MLA.
+    "mla-decode-attention": functools.partial(
+        _decode_attention, hq=16, hkv=MLA_HKV, d=MLA_D),
+    "mla-decode-kv-writer": functools.partial(
+        _decode_writer, hkv=MLA_HKV, d=MLA_D),
+    "mla-prefill-kv-writer": functools.partial(
+        _prefill_writer, hkv=MLA_HKV, d=MLA_D),
+}
+
+
+def _engine_decode_step(sds, monkeypatch):
+    """The engine's real jitted decode step (runtime/engine.py
+    ``_decode_step`` as ``_build_step_programs`` jits it: donated,
+    pinned, sampling fused in), llama3-1b at full width and two layers,
+    kernels on and not interpreted, the pool at the smoke's size."""
+    from xllm_service_tpu.config import EngineConfig, ModelConfig
+    from xllm_service_tpu.models import transformer
+    from xllm_service_tpu.runtime import engine as E
+
+    # The gates are read at trace time; the runtime backend is the CPU,
+    # so the test steers them as the chip would resolve them.
+    monkeypatch.setenv("XLLM_PALLAS", "1")
+    monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "0")
+    cfg = dataclasses.replace(ModelConfig.llama3_1b(), num_layers=2)
+    ecfg = EngineConfig(page_size=PS, num_pages=P, max_model_len=2048,
+                        max_batch_size=B_DEC)
+    there = sds((1,), jnp.int32).sharding
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=there), tree)
+    params = described(jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    kv = described(jax.eval_shape(
+        lambda: transformer.init_kv_cache(cfg, P, PS)))
+    # A live engine on the CPU with a token pool, its step programs then
+    # rebuilt for pools of the smoke's size placed on the described chip.
+    eng = E.Engine(cfg, dataclasses.replace(ecfg, num_pages=8),
+                   params=jax.tree_util.tree_map(
+                       lambda a: jnp.zeros(a.shape, a.dtype), params))
+    eng._build_step_programs(kv)
+    small = described((*eng._sampling_tensors([], B_DEC),
+                       *eng._batch_bias([], B_DEC, cfg.vocab_size)))
+    st_f32, st_i32, b_ids, b_vals = small
+    compiled = eng._jit_decode.lower(
+        params, sds((B_DEC, E._PACK_COLS + MP), jnp.int32), kv, st_f32,
+        st_i32, described(jax.eval_shape(lambda: jax.random.PRNGKey(0))),
+        None, b_ids, b_vals).compile()
+    return compiled, sum(
+        2 * int(jnp.prod(jnp.array(x.shape))) for x in kv)
+
+
+def _tp4_forward_decode(monkeypatch):
+    """One decode forward of llama3-1b (two layers) partitioned over the
+    four chips of a described v5e 2x2 with the repo's own sharding rules
+    — what a ``--tp 4`` worker's engine traces: on the reference path
+    (ops/pallas ``reference_path``), because the Mosaic kernels cannot
+    be partitioned automatically and are not wrapped in shard_map yet."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from xllm_service_tpu.config import ModelConfig
+    from xllm_service_tpu.models import transformer
+    from xllm_service_tpu.ops import pallas
+    from xllm_service_tpu.parallel.mesh import MESH_AXES
+    from xllm_service_tpu.parallel.sharding import (
+        kv_cache_sharding, param_shardings)
+
+    monkeypatch.setenv("XLLM_PALLAS", "1")
+    monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "0")
+    cfg = dataclasses.replace(ModelConfig.llama3_1b(), num_layers=2)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices).reshape(1, 1, 1, 4), MESH_AXES)
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        shapes, param_shardings(shapes, mesh, cfg))
+    kv = tuple(
+        jax.ShapeDtypeStruct(a.shape, a.dtype,
+                             sharding=kv_cache_sharding(mesh, cfg))
+        for a in jax.eval_shape(
+            lambda: transformer.init_kv_cache(cfg, P, PS)))
+    everywhere = NamedSharding(mesh, PartitionSpec())
+
+    def whole(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=everywhere)
+    args = (params, whole((B_DEC,), jnp.int32), whole((B_DEC,), jnp.int32),
+            whole((B_DEC,), jnp.bool_), kv, whole((B_DEC, MP), jnp.int32))
+
+    def with_kernels(p, t, pos, act, kv, pt):
+        return transformer.forward_decode(p, cfg, t, pos, act, kv, pt,
+                                          write_then_attend=True)
+
+    def reference(p, t, pos, act, kv, pt):
+        with pallas.reference_path():
+            return transformer.forward_decode(p, cfg, t, pos, act, kv, pt)
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        jax.jit(with_kernels, donate_argnums=(4,)).lower(*args).compile()
+    return jax.jit(reference, donate_argnums=(4,)).lower(*args).compile()
+
+
+@pytest.mark.parametrize("case", [*KERNELS, "engine-decode-step",
+                                  "tp4-forward-decode"])
+def test_compiles_for_v5e(aot, monkeypatch, case):
+    aot_compile, sds = aot
+    if case == "tp4-forward-decode":
+        text = _tp4_forward_decode(monkeypatch).as_text()
+        assert "tpu_custom_call" not in text and "all-reduce" in text
+        return
+    if case != "engine-decode-step":
+        fn, args, jit_kw = KERNELS[case](sds)
+        compiled = aot_compile(fn, args, **jit_kw)
+        assert "tpu_custom_call" in compiled.as_text()
+        return
+    compiled, pool_nominal_bytes = _engine_decode_step(sds, monkeypatch)
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # The pool is aliased through the step (donated in, returned), and
+    # no temporary is anywhere near a pool: unpinned, this program holds
+    # two more whole pools in temporaries. The pinned row-major pool
+    # itself is twice its nominal bytes at head_dim 64 (the minor
+    # dimension pads to the 128-lane tile) — recorded, not asserted away.
+    assert mem.alias_size_in_bytes >= pool_nominal_bytes
+    assert mem.temp_size_in_bytes < pool_nominal_bytes // 8
+    assert mem.alias_size_in_bytes == 2 * pool_nominal_bytes

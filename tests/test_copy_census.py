@@ -56,18 +56,6 @@ ENTRY %main (p0: bf16[2,32,64,8,64]) -> bf16[2,32,64,8,64] {
         assert census_pool_copies(hlo, POOL) == []
 
 
-@pytest.fixture(scope="module")
-def aot():
-    """The offline v5e compile path, or a skip where the image can't
-    build the TPU topology (no libtpu)."""
-    try:
-        from tools.aot_tpu import aot_compile, sds
-        sds((8, 128), jnp.float32)      # forces topology construction
-    except Exception as e:  # noqa: BLE001 — environment-dependent
-        pytest.skip(f"no offline TPU topology: {type(e).__name__}: {e}")
-    return aot_compile, sds
-
-
 @pytest.fixture()
 def census_env(monkeypatch):
     """The kernel mix the census compiles: aliased Pallas writers +
@@ -143,23 +131,26 @@ class TestCensusAot:
 
     def test_restore_scatter_zero_pool_copies(self, aot):
         """The spill-tier restore / cross-worker block-adopt scatter
-        (engine ``_kv_scatter``, shared with PD import): donated,
-        deliberately unpinned (see the donation-coverage allowlist
-        justification) — the aliased in-place write must compile with
-        ZERO pool-sized copies, or every prefix restore pays a
-        pool-sized bill that dwarfs what it saved."""
+        (engine ``_jit_kv_scatter``, shared with PD import): donated and
+        pinned like the step programs — the aliased in-place write must
+        compile with ZERO pool-sized copies, or every prefix restore
+        pays a pool-sized bill that dwarfs what it saved. (Unpinned, the
+        chip hands a [.., 128, 8, 64] pool back in its own default
+        layout: pools the pinned step programs then refuse.)"""
         aot_compile, sds = aot
+        from xllm_service_tpu.runtime.engine import (
+            _kv_scatter as restore, row_major_format)
         L, P, ps, Hkv, D = POOL
         n = 2       # restored blocks per call; structurally identical
         #             at any count (the engine caches per distinct n)
-
-        def restore(kp, vp, idx, kn, vn):
-            return kp.at[:, idx].set(kn), vp.at[:, idx].set(vn)
 
         args = (sds(POOL, jnp.bfloat16), sds(POOL, jnp.bfloat16),
                 sds((n,), jnp.int32),
                 sds((L, n, ps, Hkv, D), jnp.bfloat16),
                 sds((L, n, ps, Hkv, D), jnp.bfloat16))
-        compiled = aot_compile(restore, args, donate_argnums=(0, 1))
+        pin = tuple(row_major_format(5, a.sharding) for a in args[:2])
+        compiled = aot_compile(restore, args, donate_argnums=(0, 1),
+                               in_shardings=(*pin, None, None, None),
+                               out_shardings=pin)
         hits = census_pool_copies(compiled.as_text(), POOL)
         assert hits == [], hits

@@ -467,7 +467,6 @@ class TestEngineSpillRestore:
         pages back to the allocator, and re-park the popped tier blocks
         — then the SAME prefix must still restore cleanly once the
         fault clears (byte-identical)."""
-        import xllm_service_tpu.runtime.engine as engine_mod
         eng = _tiny_engine()
         p1 = [7] * 5 + list(range(40))
         out1 = _run(eng, p1, "a")
@@ -488,13 +487,12 @@ class TestEngineSpillRestore:
         tier_before = [h for h in hashes if h in eng.host_tier]
         assert tier_before, "pressure run never spilled p1's lead"
 
-        real_scatter = engine_mod._kv_scatter
+        real_scatter = eng._jit_kv_scatter
 
         def exploding_scatter(*a, **kw):
             raise RuntimeError("injected scatter failure")
 
-        monkeypatch.setattr(engine_mod, "_kv_scatter",
-                            exploding_scatter)
+        monkeypatch.setattr(eng, "_jit_kv_scatter", exploding_scatter)
         with pytest.raises(RuntimeError, match="injected scatter"):
             eng._restore_spilled(p1, [], 0)
         # no page vanished (the alloc's pressure-reclaim may have
@@ -507,7 +505,7 @@ class TestEngineSpillRestore:
         # every tier block the restore popped is re-parked
         assert all(h in eng.host_tier for h in tier_before)
         # fault cleared: the prefix restores and decodes byte-identical
-        monkeypatch.setattr(engine_mod, "_kv_scatter", real_scatter)
+        monkeypatch.setattr(eng, "_jit_kv_scatter", real_scatter)
         assert _run(eng, p1, "c") == out1
 
     def test_spill_off_by_default(self):

@@ -20,23 +20,10 @@ import sys
 import threading
 import time
 
-import importlib.util
-
 import pytest
-
-# The device wire rides jax.experimental.transfer; the pinned toolchain
-# jax (0.4.x) does not ship the module at all, so the cross-process
-# pull can never run here — skip with the reason instead of burning a
-# two-process timeout on a guaranteed failure. The host-shuttle path
-# stays covered by tests/test_pd_disagg.py.
-pytestmark = pytest.mark.skipif(
-    importlib.util.find_spec("jax.experimental.transfer") is None,
-    reason="jax.experimental.transfer missing in this toolchain")
 
 from xllm_service_tpu.service.coordination_net import StoreServer
 from xllm_service_tpu.service.httpd import http_json
-
-PIN = "import jax; jax.config.update('jax_platforms','cpu'); "
 
 
 def _metrics(addr: str) -> str:
@@ -75,8 +62,7 @@ def test_cross_process_device_wire_migration():
         lines: "queue.Queue" = queue.Queue()
 
         def spawn_worker(itype: str) -> subprocess.Popen:
-            code = (PIN +
-                    "from xllm_service_tpu.runtime.worker import main; "
+            code = ("from xllm_service_tpu.runtime.worker import main; "
                     f"main(['--instance-type','{itype}',"
                     f"'--service-addr','{rpc_addr}',"
                     f"'--store-addr','{store_srv.address}',"

@@ -31,12 +31,6 @@ def test_mesh_axes(cpu_devices):
         make_mesh(MeshSpec(dp=4, tp=4))
 
 
-def _ambient_mesh(mesh):
-    """jax.set_mesh on the current API; on the pinned 0.4.x toolchain a
-    Mesh is itself the ambient-mesh context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
 def test_tp_sharded_forward_matches_single_device(cpu_devices):
     """TP=4 prefill+decode must be numerically identical (up to fp
     reassociation) to the unsharded run — GSPMD inserts the collectives."""
@@ -54,7 +48,7 @@ def test_tp_sharded_forward_matches_single_device(cpu_devices):
     mesh = make_mesh(MeshSpec(tp=4))
     sp_params = shard_params(params, mesh, cfg)
     sp_kv = shard_kv_cache(jax.tree_util.tree_map(jnp.copy, kv), mesh, cfg)
-    with _ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         got_last, _, got_kv = jax.jit(
             forward_prefill, static_argnums=(1,))(
                 sp_params, cfg, toks, zero, lens, sp_kv, pt)
@@ -66,7 +60,7 @@ def test_tp_sharded_forward_matches_single_device(cpu_devices):
     pos = jnp.asarray([4, 3], jnp.int32)
     act = jnp.asarray([True, True])
     ref_logits, _ = forward_decode(params, cfg, nxt, pos, act, ref_kv, pt)
-    with _ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         got_logits, _ = jax.jit(forward_decode, static_argnums=(1,))(
             sp_params, cfg, nxt, pos, act, got_kv, pt)
     np.testing.assert_allclose(np.asarray(got_logits), np.asarray(ref_logits),
@@ -86,7 +80,7 @@ def test_ep_moe_sharded_forward(cpu_devices):
     mesh = make_mesh(MeshSpec(ep=4, tp=2))
     sp_params = shard_params(params, mesh, cfg)
     sp_kv = shard_kv_cache(kv, mesh, cfg)
-    with _ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         got_last, _, _ = jax.jit(forward_prefill, static_argnums=(1,))(
             sp_params, cfg, toks, zero, lens, sp_kv, pt)
     np.testing.assert_allclose(np.asarray(got_last), np.asarray(ref_last),

@@ -190,7 +190,7 @@ class TestSaturateSmoke:
             StoreServer
 
         out = saturate_run(
-            steps=[4, 8], step_seconds=2.0, n_workers=1, gen_tokens=4,
+            steps=[4, 8], step_seconds=1.0, n_workers=1, gen_tokens=4,
             frame_interval_ms=5.0, lock_sample=2, shard_size=16,
             overhead_floor_ms=250.0)
         assert out["metric"] == "service_saturation_knee"
@@ -230,7 +230,7 @@ class TestSaturateSmoke:
                  "XLLM_LOCK_PROFILE_SAMPLE": "2",
                  "XLLM_MAX_CONCURRENCY": "64"})
             try:
-                step = _sat_step([cl.http], cl.proc.pid, 8, 2.0, 4,
+                step = _sat_step([cl.http], cl.proc.pid, 8, 1.0, 4,
                                  5.0, shard_size=16)
                 assert step["completed"] > 0
                 prom = _scrape_prom(cl.http)

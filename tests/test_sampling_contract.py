@@ -2,7 +2,7 @@
 end to end (stop strings, max_completion_tokens, n>1, logprobs,
 penalties, per-request seeds) — the reference carries these in its protos
 (xllm/chat.proto:1-192, completion.proto:1-143); the rebuild must not
-silently drop them (round-1 VERDICT item 4)."""
+silently drop them (round-1 verdict, item 4)."""
 
 import pytest
 

@@ -136,12 +136,23 @@ ROOF = {
 
 
 class TestRoofline:
-    def test_peaks_table_resolves_device_kind(self):
-        fl, bw = steptrace.peaks_for("TPU v6e")
-        assert fl == 918e12 and bw == 1640.0 * 1e9
-        # Unknown kinds land on the documented CPU placeholder row.
-        assert steptrace.peaks_for("") == (1e11, 50.0 * 1e9)
-        assert steptrace.peaks_for("weird-asic") == (1e11, 50.0 * 1e9)
+    def test_peaks_table_resolves_device_kind(self, monkeypatch):
+        # conftest sets the overrides for the CPU session; the table
+        # itself is what this test reads.
+        monkeypatch.setattr(steptrace, "PEAK_FLOPS_OVERRIDE", 0.0)
+        monkeypatch.setattr(steptrace, "PEAK_BW_GBPS_OVERRIDE", 0.0)
+        fl, bw = steptrace.peaks_for("TPU v5 lite")
+        assert fl == 197e12 and bw == 819.0 * 1e9
+        assert steptrace.peaks_for("TPU v6e") == (918e12, 1640.0 * 1e9)
+
+    @pytest.mark.parametrize("kind", ["", "cpu", "weird-asic"])
+    def test_peaks_unknown_kind_raises(self, monkeypatch, kind):
+        """A device that is not in the table is an error, not a
+        default: there is no placeholder row to fall onto."""
+        monkeypatch.setattr(steptrace, "PEAK_FLOPS_OVERRIDE", 0.0)
+        monkeypatch.setattr(steptrace, "PEAK_BW_GBPS_OVERRIDE", 0.0)
+        with pytest.raises(ValueError, match="no peak"):
+            steptrace.peaks_for(kind)
 
     def test_peaks_env_override_wins(self, monkeypatch):
         # The env is read once at import (hot-path flag discipline), so
@@ -203,7 +214,7 @@ class TestRoofline:
     def test_flush_metrics_series_are_cost_analysis_fed(self):
         reg = Registry()
         steptrace.flush_metrics(reg, "tiny", ROOF, 0.25, 1.5,
-                                device_kind="cpu")
+                                peak_flops=1e12)
         text = reg.render()
         assert 'xllm_worker_step_mfu{model="tiny"} 0.25' in text
         assert 'xllm_worker_step_debt_ms{model="tiny"} 1.5' in text

@@ -232,18 +232,13 @@ _WTA = [True]
 
 def _kv_layout_kwargs(args, donate, n_out, kv_out=None):
     """The engine's boundary-layout pin (runtime/engine.py
-    _kv_default_layouts): KV pools at default major-to-minor on BOTH
+    row_major_format): KV pools at default major-to-minor on BOTH
     sides of the jit. Without it XLA assigns the pool parameters an
     attention-biased layout while the aliased writer custom call needs
     the default — 4 full-pool conversion copies per call."""
-    from jax.experimental.layout import DeviceLocalLayout, Layout
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    from tools.aot_tpu import _mesh
-    sh = NamedSharding(_mesh(), PartitionSpec())
+    from xllm_service_tpu.runtime.engine import row_major_format
     kv_idx = donate[0]
-    lay = tuple(Layout(DeviceLocalLayout(tuple(range(x.ndim))), sh)
-                for x in args[kv_idx])
+    lay = tuple(row_major_format(x.ndim, x.sharding) for x in args[kv_idx])
     ins = [None] * len(args)
     ins[kv_idx] = lay
     outs = [None] * n_out

@@ -724,8 +724,8 @@ class EngineConfig:
     enable_prefix_cache: bool = True
     # Decode steps fused into ONE compiled program per host round-trip
     # (lax.scan over the step body). >1 amortizes host↔device dispatch
-    # latency across N tokens — the dominant cost when the chip sits
-    # behind a network tunnel or under Python dispatch overhead. Finish
+    # latency across N tokens — the dominant cost under Python dispatch
+    # overhead. Finish
     # detection runs on host afterwards; tokens sampled past a stop are
     # discarded (bounded waste of N-1 steps worst case).
     decode_steps: int = 1

@@ -107,34 +107,42 @@ try:
 except ValueError:
     PEAK_BW_GBPS_OVERRIDE = 0.0
 
-# Dense bf16 peak FLOP/s and HBM GB/s per chip, by device_kind
-# substring (public specs; same family table as bench.py's headline
-# MFU). The CPU row is a deliberately round placeholder so the tier-1
-# harness exercises the full arithmetic with visibly-modeled numbers.
-_CHIP_PEAKS: Tuple[Tuple[str, float, float], ...] = (
-    ("v6", 918e12, 1640.0),      # Trillium / v6e
-    ("v5p", 459e12, 2765.0),
-    ("v5", 197e12, 819.0),       # v5e
-    ("v4", 275e12, 1228.0),
-    ("v3", 123e12, 900.0),
-    ("v2", 45e12, 700.0),
-    ("cpu", 1e11, 50.0),
-)
+# Dense bf16 peak FLOP/s and HBM GB/s of one chip, keyed by
+# ``jax.Device.device_kind`` (both spellings jax knows for a
+# generation). Source: Google Cloud TPU documentation, the "System
+# architecture" page of each generation ("TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM). The one peaks table of the repo — bench.py reads it
+# too. A device that is not here has no roofline: asking for it is an
+# error, not a default.
+CHIP_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v2": (45e12, 700.0),
+    "TPU v3": (123e12, 900.0),
+    "TPU v4": (275e12, 1228.0),
+    "TPU v5 lite": (197e12, 819.0),      # v5e
+    "TPU v5e": (197e12, 819.0),
+    "TPU v5": (459e12, 2765.0),          # v5p
+    "TPU v5p": (459e12, 2765.0),
+    "TPU v6 lite": (918e12, 1640.0),     # v6e / Trillium
+    "TPU v6e": (918e12, 1640.0),
+}
 
 
 def peaks_for(device_kind: str) -> Tuple[float, float]:
-    """(peak FLOP/s, peak bytes/s) for a device kind — env overrides
-    first, then the public-spec table, then the CPU placeholder row."""
+    """(peak FLOP/s, peak bytes/s) for a device kind: the
+    XLLM_PEAK_FLOPS / XLLM_PEAK_BW_GBPS overrides first, then
+    ``CHIP_PEAKS``. Raises for a kind the table does not hold (a CPU
+    run that wants roofline arithmetic sets both overrides)."""
     flops = PEAK_FLOPS_OVERRIDE
     bw = PEAK_BW_GBPS_OVERRIDE * 1e9
     if flops > 0 and bw > 0:
         return flops, bw
-    kind = (device_kind or "").lower()
-    t_flops, t_bw = _CHIP_PEAKS[-1][1], _CHIP_PEAKS[-1][2]
-    for tag, f, b in _CHIP_PEAKS:
-        if tag in kind:
-            t_flops, t_bw = f, b
-            break
+    if device_kind not in CHIP_PEAKS:
+        raise ValueError(
+            f"no peak FLOP/s and bandwidth on file for device kind "
+            f"{device_kind!r} (obs/steptrace.py CHIP_PEAKS); set "
+            f"XLLM_PEAK_FLOPS and XLLM_PEAK_BW_GBPS to run the roofline "
+            f"arithmetic on it")
+    t_flops, t_bw = CHIP_PEAKS[device_kind]
     return (flops if flops > 0 else t_flops,
             bw if bw > 0 else t_bw * 1e9)
 
@@ -363,7 +371,7 @@ def roofline_table(roofline: Dict[str, Dict[str, Dict[str, float]]],
 
 
 def flush_metrics(registry, model: str, roofline, last_mfu: float,
-                  last_debt_ms: float, device_kind: str = "") -> None:
+                  last_debt_ms: float, peak_flops: float) -> None:
     """Scrape-time mirror of the roofline attribution into a worker
     Registry: per-program/variant FLOPs+bytes gauges (cost_analysis-
     derived numerators — never hardcoded) and the last step's MFU and
@@ -394,7 +402,6 @@ def flush_metrics(registry, model: str, roofline, last_mfu: float,
                      variant=key)
             g_by.set(v.get("bytes", 0.0), model=model, program=prog,
                      variant=key)
-    peak_flops, _ = peaks_for(device_kind)
     registry.gauge(
         "xllm_worker_peak_flops",
         "peak FLOP/s the MFU series is normalized by "

@@ -1,35 +1,23 @@
-"""Pallas TPU API compatibility.
+"""The one spelling site for version-sensitive pallas/jax API names.
 
-The kernels target the current pallas API (``pltpu.CompilerParams``);
-older jax releases (<= 0.4.x, including the pinned toolchain image)
-ship the same class as ``pltpu.TPUCompilerParams``. One alias here so
-every kernel module compiles against either — without it the whole
-Pallas surface (and every interpret-mode test) dies at call time with
-AttributeError on the older API.
+Kernel modules import ``CompilerParams`` / ``HBM`` / ``shard_map_unchecked``
+from here and never from jax directly (xlint rule ``mosaic-compat``), so
+the next rename in jax is a one-file change. Only the installed jax is
+supported: a name that is missing fails at import.
 """
 
+import functools
+
+import jax
 from jax.experimental.pallas import tpu as _pltpu
 
-CompilerParams = getattr(_pltpu, "CompilerParams", None) \
-    or _pltpu.TPUCompilerParams
+CompilerParams = _pltpu.CompilerParams
 
-# ``pltpu.HBM`` (newer name) == ``TPUMemorySpace.ANY`` on the older
-# API: "leave the operand in HBM, the kernel DMAs it itself" (the V3
-# row kernel's manual double-buffered page fetch).
-HBM = getattr(_pltpu, "HBM", None) or _pltpu.TPUMemorySpace.ANY
+# "Leave the operand in HBM, the kernel DMAs it itself."
+HBM = _pltpu.HBM
 
 
 def shard_map_unchecked():
-    """The shard_map entry point with replication checking off, across
-    both API generations: current jax ships ``jax.shard_map`` with
-    ``check_vma=``; the pinned 0.4.x toolchain ships
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep=``.
-    Returns a callable with the usual (f, mesh=..., in_specs=...,
-    out_specs=...) signature."""
-    import functools
-
-    import jax
-    if hasattr(jax, "shard_map"):
-        return functools.partial(jax.shard_map, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm  # noqa: E501 — the one sanctioned spelling site
-    return functools.partial(_sm, check_rep=False)
+    """``jax.shard_map`` with replication checking off; takes the usual
+    (f, mesh=..., in_specs=..., out_specs=...) arguments."""
+    return functools.partial(jax.shard_map, check_vma=False)

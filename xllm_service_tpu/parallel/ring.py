@@ -102,8 +102,7 @@ def ring_attention_sharded(mesh: Mesh, axis_name: str = "sp",
     grouping inside the block must stay aligned), lengths replicated."""
     qkv_spec = P(None, axis_name, head_axis, None)
 
-    # shard_map spelling differs across the jax generations this repo
-    # runs on; the one sanctioned shim lives in ops/pallas/_compat.py
+    # The one sanctioned shard_map spelling site is ops/pallas/_compat.py
     # (enforced by tools/xlint mosaic-compat).
     from xllm_service_tpu.ops.pallas._compat import shard_map_unchecked
     smap = shard_map_unchecked()

@@ -95,16 +95,18 @@ def seq_pspec() -> P:
     return P(AXIS_DP, AXIS_SP)
 
 
-def shard_params(params: Dict[str, Any], mesh: Mesh,
-                 cfg: ModelConfig) -> Dict[str, Any]:
-    """device_put every leaf with its NamedSharding. Specs are derived
-    from the ACTUAL tree structure: rule tables by leaf name (picking the
-    rule whose rank matches — MoE expert stacks vs dense MLPs share
-    names), replicated default for everything unlisted (per-head norms,
-    gemma's extra block norms, the MLA q_a/q_b/kv_a/kv_b_*/shared_*
-    tree). MLA leaves whose name AND rank match a llama rule (q_proj,
-    o_proj — both column/row-parallel on their feature axis) take that
-    rule, which is dimensionally sound for them too."""
+def param_shardings(params: Dict[str, Any], mesh: Mesh,
+                    cfg: ModelConfig) -> Dict[str, Any]:
+    """NamedSharding for every leaf of ``params`` (arrays, or the
+    ShapeDtypeStructs of ``jax.eval_shape`` — only names and ranks are
+    read). Specs are derived from the ACTUAL tree structure: rule tables
+    by leaf name (picking the rule whose rank matches — MoE expert
+    stacks vs dense MLPs share names), replicated default for everything
+    unlisted (per-head norms, gemma's extra block norms, the MLA
+    q_a/q_b/kv_a/kv_b_*/shared_* tree). MLA leaves whose name AND rank
+    match a llama rule (q_proj, o_proj — both column/row-parallel on
+    their feature axis) take that rule, which is dimensionally sound for
+    them too."""
 
     def spec_for(path, leaf) -> P:
         name = next((p.key for p in reversed(path)
@@ -121,11 +123,20 @@ def shard_params(params: Dict[str, Any], mesh: Mesh,
         return P(*([None] * leaf.ndim))
 
     return jax.tree_util.tree_map_with_path(
-        lambda path, x: jax.device_put(
-            x, NamedSharding(mesh, spec_for(path, x))), params)
+        lambda path, x: NamedSharding(mesh, spec_for(path, x)), params)
+
+
+def shard_params(params: Dict[str, Any], mesh: Mesh,
+                 cfg: ModelConfig) -> Dict[str, Any]:
+    """device_put every leaf with its ``param_shardings`` placement."""
+    return jax.tree_util.tree_map(
+        jax.device_put, params, param_shardings(params, mesh, cfg))
+
+
+def kv_cache_sharding(mesh: Mesh, cfg: ModelConfig) -> NamedSharding:
+    return NamedSharding(mesh, kv_cache_pspec(cfg, mesh.shape[AXIS_TP]))
 
 
 def shard_kv_cache(kv, mesh: Mesh, cfg: ModelConfig):
-    tp_size = mesh.shape[AXIS_TP]
-    s = NamedSharding(mesh, kv_cache_pspec(cfg, tp_size))
+    s = kv_cache_sharding(mesh, cfg)
     return tuple(jax.device_put(x, s) for x in kv)

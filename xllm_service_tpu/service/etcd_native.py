@@ -11,56 +11,27 @@ verdict weak #6). ``XLLM_ETCD_ADDR`` still points the same tests at a
 stock etcd when one is available.
 
 Build is on-demand (g++, same pattern as the native httpd/hash modules)
-into ``build/native/xllm_etcd``; the server prints ``LISTENING <port>``
+into ``build/native/xllm_etcd-<source hash>``; the server prints ``LISTENING <port>``
 once bound, so port 0 (ephemeral) works for parallel test runs.
 """
 
 from __future__ import annotations
 
-import os
 import subprocess
 from typing import Optional
 
 from xllm_service_tpu.utils.locks import make_lock
+from xllm_service_tpu.utils.native_build import build_artifact
 
 _build_lock = make_lock("etcd_native.build", 97)
 
 
-def _repo_root() -> str:
-    return os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-
-
 def build_binary() -> Optional[str]:
-    """Compile (if stale) and return the server binary path, or None when
-    the toolchain/source is unavailable."""
-    root = _repo_root()
-    src = os.path.join(root, "csrc", "xllm_etcd.cpp")
-    if not os.path.exists(src):
-        return None
-    out_dir = os.path.join(root, "build", "native")
-    os.makedirs(out_dir, exist_ok=True)
-    binary = os.path.join(out_dir, "xllm_etcd")
+    """Compile (if not yet built for this source) and return the server
+    binary path, or None when the toolchain/source is unavailable."""
     with _build_lock:
-        if os.path.exists(binary) \
-                and os.path.getmtime(binary) >= os.path.getmtime(src):
-            return binary
-        cxx = os.environ.get("CXX", "g++")
-        tmp = f"{binary}.{os.getpid()}.tmp"
-        cmd = [cxx, "-O2", "-std=c++17", "-pthread", src, "-o", tmp]
-        try:
-            subprocess.run(cmd, check=True, capture_output=True,
-                           timeout=180)
-            os.replace(tmp, binary)
-        except Exception:  # noqa: BLE001 — no toolchain / compile
-            # failure: None falls back to the in-process store, which
-            # the caller reports
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
-            return None
-    return binary
+        return build_artifact("xllm_etcd.cpp", "xllm_etcd", "",
+                              ("-O2", "-std=c++17", "-pthread"))
 
 
 class NativeEtcdServer:

@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
@@ -28,6 +27,7 @@ from urllib.parse import parse_qs
 
 from xllm_service_tpu.utils.locks import make_lock
 from xllm_service_tpu.utils import threads
+from xllm_service_tpu.utils.native_build import build_artifact
 from xllm_service_tpu.utils.threads import spawn
 
 # The headers blob is "key\0value\0...": it MUST cross as pointer+length
@@ -47,36 +47,14 @@ _native_lib: Optional[ctypes.CDLL] = None
 _native_tried = False
 
 
-def _repo_root() -> str:
-    return os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
+_FLAGS = ("-O2", "-std=c++17", "-shared", "-fPIC", "-pthread")
 
 
 def _build() -> Optional[str]:
-    root = _repo_root()
-    src = os.path.join(root, "csrc", "xllm_httpd.cpp")
-    if not os.path.exists(src):
-        return None
-    out_dir = os.path.join(root, "build", "native")
-    os.makedirs(out_dir, exist_ok=True)
-    so = os.path.join(out_dir, "libxllm_httpd.so")
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(src):
-        return so
-    cxx = os.environ.get("CXX", "g++")
-    tmp = f"{so}.{os.getpid()}.tmp"
-    cmd = [cxx, "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           src, "-o", tmp]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, so)
-    except Exception:  # noqa: BLE001 — no toolchain / compile failure:
-        # None falls back to the stdlib ThreadingHTTPServer path
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-        return None
-    return so
+    """The front-door library for the current csrc/xllm_httpd.cpp; None
+    (no toolchain) falls back to the stdlib ThreadingHTTPServer path."""
+    return build_artifact("xllm_httpd.cpp", "libxllm_httpd", ".so",
+                          _FLAGS, timeout_s=120)
 
 
 def _load() -> Optional[ctypes.CDLL]:

@@ -19,7 +19,6 @@ from __future__ import annotations
 import ctypes
 import os
 import struct
-import subprocess
 import threading
 
 
@@ -110,36 +109,13 @@ _native_lib: Optional[ctypes.CDLL] = None
 _native_tried = False
 
 
-def _repo_root() -> str:
-    return os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-
 def _build_native() -> Optional[str]:
-    root = _repo_root()
-    src = os.path.join(root, "csrc", "xllm_native.cpp")
-    if not os.path.exists(src):
-        return None
-    out_dir = os.path.join(root, "build", "native")
-    os.makedirs(out_dir, exist_ok=True)
-    so = os.path.join(out_dir, "libxllm_native.so")
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(src):
-        return so
-    cxx = os.environ.get("CXX", "g++")
-    # Compile to a process-unique temp name and rename atomically so a
-    # concurrent process can never dlopen a partially written library.
-    tmp = f"{so}.{os.getpid()}.tmp"
-    cmd = [cxx, "-O2", "-std=c++17", "-shared", "-fPIC", src, "-o", tmp]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, so)
-    except Exception:  # noqa: BLE001 — no toolchain / compile failure:
-        # None falls back to the pure-python murmur path
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-        return None
-    return so
+    """The hash library for the current csrc/xllm_native.cpp; None (no
+    toolchain) falls back to the bit-identical pure-python murmur."""
+    from xllm_service_tpu.utils.native_build import build_artifact
+    return build_artifact("xllm_native.cpp", "libxllm_native", ".so",
+                          ("-O2", "-std=c++17", "-shared", "-fPIC"),
+                          timeout_s=120)
 
 
 def _load_native() -> Optional[ctypes.CDLL]:

