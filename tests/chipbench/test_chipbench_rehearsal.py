@@ -1,0 +1,113 @@
+"""The whole command, walked on the CPU at tiny widths: the result line's
+shape, no device metric under a CPU run, no result without a chip, and a
+timed path broken underneath comes out as not correct."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from chipbench import spec
+
+ROOT = spec.ROOT
+ENV = {**os.environ, "JAX_PLATFORMS": "cpu",
+       "PYTHONPATH": ROOT + os.pathsep + os.environ.get("PYTHONPATH", "")}
+
+
+def run(args, code=None, cwd=ROOT, timeout=600):
+    cmd = [sys.executable, "-c", code, *args] if code else \
+        [sys.executable, "-m", "chipbench.run", *args]
+    return subprocess.run(cmd, cwd=cwd, env=ENV, timeout=timeout,
+                          capture_output=True, text=True)
+
+
+def last_json(proc):
+    lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
+    assert lines, proc.stderr[-2000:]
+    return json.loads(lines[-1])
+
+
+@pytest.mark.parametrize("cell,trace", [("mistral7b-v01-docqa", 0),
+                                        ("mistral7b-v01-docqa", 1)])
+def test_rehearsal_walks_the_whole_command(cell, trace):
+    p = run(["--workload", cell, "--seed", str(2**31 + 21), "--seconds",
+             "5", "--trace", str(trace), "--rehearse-cpu", "--limit",
+             "0.05"])
+    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
+    out = last_json(p)
+    assert set(out) >= {"correct", "attempted", "failed", "metrics",
+                        "device"}
+    assert out["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] > 0
+    assert "generator lateness: median" in p.stdout
+    assert "CHECK served_token_gap_max" in p.stdout
+    assert "CHECK served_token_gap_max" in p.stderr.splitlines()[-3]
+    bench = spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    device_metrics = {m["name"] for m in bench["per_layer"]
+                      if m["source"] != "program_counter"}
+    e2e = {m["name"] for m in bench["end_to_end"]}
+    assert not device_metrics & set(out["metrics"])
+    assert "breakdown" not in out and "busy_s" not in out["device"]
+    if trace:
+        assert not e2e & set(out["metrics"])
+        assert out["metrics"]["compiles_in_window.docqa"]["unit"] == "count"
+        assert "itl_tail_ms.docqa" not in out["metrics"]   # a time
+        assert out["metrics"]["prefix_hit_token_share.docqa"]["value"] > 50
+        assert "hbm_peak_gb" not in out["metrics"]
+    else:
+        # a CPU time is never written under an end-to-end metric's name
+        assert set(out["metrics"]) == {"setup_s"}
+
+
+BREAK = """
+import sys
+from xllm_service_tpu.runtime import engine as E
+real = E.Engine._append_token
+count = [0]
+def altered(self, seq, tok, logprob, top=None):
+    # every third served token is replaced where it is produced
+    count[0] += 1
+    if count[0] % 3 == 0:
+        tok = 3 + (tok + 97) % (self.cfg.vocab_size - 3)
+    return real(self, seq, tok, logprob, top)
+E.Engine._append_token = altered
+from chipbench import run
+raise SystemExit(run.main(sys.argv[1:]))
+"""
+
+
+def test_a_broken_timed_path_comes_out_not_correct():
+    p = run(["--workload", "mistral7b-v01-docqa", "--seed", "77", "--seconds",
+             "5", "--trace", "0", "--rehearse-cpu", "--limit", "0.05"],
+            code=BREAK)
+    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
+    out = last_json(p)
+    assert out["correct"] is False and out["failed"] == 0
+    assert "FAIL" in [ln for ln in p.stderr.splitlines()
+                      if ln.startswith("CHECK served_token_gap_max")][-1]
+
+
+def test_no_chip_no_result():
+    p = run(["--workload", "mistral7b-v01-docqa", "--seed", "1", "--seconds",
+             "3", "--trace", "0"], timeout=120)
+    assert p.returncode != 0
+    assert not [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
+
+
+def test_benchmark_files_alone_give_no_result(tmp_path):
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    for d in spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))["paths"]:
+        shutil.copytree(os.path.join(ROOT, d), tmp_path / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    p = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload",
+         "mistral7b-v01-docqa", "--seed", "1", "--seconds", "3", "--trace", "0",
+         "--rehearse-cpu"], cwd=tmp_path, capture_output=True, text=True,
+        env={k: v for k, v in ENV.items() if k != "PYTHONPATH"},
+        timeout=120)
+    assert p.returncode != 0
+    assert not [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
