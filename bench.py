@@ -113,7 +113,7 @@ def _run_bench(tiny: bool) -> dict:
     platform = dev.platform
     # Raises for a device the peaks table does not hold: an MFU against
     # a made-up peak is not a number.
-    peak, peak_bytes_s = steptrace.peaks_for(dev.device_kind)
+    peak, _ = steptrace.peaks_for(dev.device_kind)
     if tiny:
         cfg = ModelConfig.tiny(vocab_size=1024)
         batch, prompt_len, gen_len, pages = 4, 32, 64, 64
@@ -219,17 +219,10 @@ def _run_bench(tiny: bool) -> dict:
     prefill_tokens = batch * prompt_len
     t0 = time.monotonic()
     tokens = 0
-    # Per-step roofline attribution against the warmup-captured
-    # cost_analysis table (same verdict arithmetic as the worker's
-    # flight recorder) — (wall ms, tokens, ragged?) per iteration.
-    step_samples = []
     while engine.has_work():
         t_step = time.monotonic()
         step_outs = engine.step()
         step_el = time.monotonic() - t_step
-        step_tok = sum(len(out.new_token_ids) for out in step_outs)
-        step_samples.append(
-            (1000.0 * step_el, step_tok, engine.last_step_ragged))
         for out in step_outs:
             tokens += len(out.new_token_ids)
             if out.new_token_ids:
@@ -318,26 +311,6 @@ def _run_bench(tiny: bool) -> dict:
     achieved = flops_per_token * throughput
     mfu = achieved / peak
 
-    # Per-step roofline verdicts over the decode loop: MFU and debt
-    # (wall ms minus the modeled floor) of the MEDIAN iteration, from
-    # the warmup-captured cost_analysis table — the BENCH-side twin of
-    # xllm_worker_step_mfu / xllm_worker_step_debt_ms, so the artifact
-    # and the live exposition share numerators. None when the capture
-    # is off (XLLM_ROOFLINE=0) or the backend would not answer.
-    step_mfu_p50 = decode_debt_ms = None
-    if engine.roofline and step_samples:
-        verdicts = [steptrace.attribute_step(
-            engine.roofline, kind="decode", step_ms=ms,
-            prefill_tokens=0, decode_tokens=tok,
-            batch_size=ecfg.max_batch_size,
-            decode_steps=ecfg.decode_steps, ragged=ragged,
-            peak_flops=peak, peak_bytes_s=peak_bytes_s)
-            for ms, tok, ragged in step_samples]
-        mfus = sorted(v["mfu"] for v in verdicts)
-        debts = sorted(v["debt_ms"] for v in verdicts)
-        step_mfu_p50 = round(mfus[len(mfus) // 2], 4)
-        decode_debt_ms = round(debts[len(debts) // 2], 3)
-
     burst = None
     if tiny or os.environ.get("BENCH_BURST") == "1":
         burst = _burst_goodput_section(
@@ -409,10 +382,6 @@ def _run_bench(tiny: bool) -> dict:
             "slo_targets_ms": {"ttft": slo_thr["ttft"],
                                "e2e": slo_thr["e2e"]},
             "mfu": round(mfu, 4),
-            # Median per-step roofline verdict (computed above); the
-            # aggregate "mfu" smooths over scheduling, these do not.
-            "step_mfu_p50": step_mfu_p50,
-            "decode_debt_ms": decode_debt_ms,
             "prefill_tokens_per_s": round(prefill_tokens / prefill_s, 1),
             # Prefill runs the lm_head only on the LAST position per
             # sequence (forward_prefill return_all_logits=False), so

@@ -465,9 +465,6 @@ def worker_env(rehearse: bool,
     env["XLLM_WARMUP_EXTENDED"] = "0"
     if rehearse:
         env["JAX_PLATFORMS"] = "cpu"
-        # The CPU is in no peaks table; a rehearsal states its own.
-        env.setdefault("XLLM_PEAK_FLOPS", "1e11")
-        env.setdefault("XLLM_PEAK_BW_GBPS", "50")
     env.update(extra or {})
     return env
 

@@ -1,12 +1,20 @@
 """One run of one cell.
 
-    python3 -m chipbench.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 -m chipbench.run --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 A new process each time. It needs a TPU and fails without one (exit 3,
 no result line); ``--rehearse-cpu`` walks the same command at tiny
 widths on the CPU and reports no device metric. The last line of
 standard output is the result object; everything else is on earlier
 lines, and detail goes to ``chiprun_out/chipbench/``.
+
+``--trace 0`` measures and prints the end-to-end metrics. ``--trace 2``
+is that same run, to the closing of its window and the taking of its
+numbers, followed by a short stretch of the same traffic a few seconds
+of which are traced: one line with both kinds of metric. ``--trace 1``
+(a run of its own that traces inside its window and prints the
+per-layer metrics alone) is what the driver used before it. Every trace
+goes through the worker's own control (``Worker.start_device_trace``).
 """
 
 from __future__ import annotations
@@ -16,9 +24,11 @@ import time
 T_START = time.monotonic()
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import statistics  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
@@ -43,7 +53,7 @@ def parse(argv: Optional[List[str]]) -> argparse.Namespace:
     p.add_argument("--workload", required=True)
     p.add_argument("--seed", type=int, required=True)
     p.add_argument("--seconds", type=float, required=True)
-    p.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    p.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     p.add_argument("--rehearse-cpu", action="store_true",
                    help="tiny widths on the CPU; no device metric")
     p.add_argument("--control", default="",
@@ -247,17 +257,25 @@ def main(argv: Optional[List[str]] = None) -> int:
         c_open = cluster.scrape(waddr)
         steps: Dict[int, Dict[str, Any]] = {}
         traced = None
-        if args.trace:
-            traced = traced_window(cell, waddr, open_t, close_t, run_dir,
-                                   steps)
+        if args.trace == 1:
+            traced = traced_window(cell, worker, waddr, open_t, close_t,
+                                   run_dir, steps)
         sleep_until(close_t)
         c_close = cluster.scrape(waddr)
         if args.trace:
+            # --trace 2 pulls the step recorder here for the first time,
+            # with the window closed: its ring holds the window's last
+            # 512 steps (~10 s), over which kv_pages_peak_share reads.
             collect_steps(waddr, steps)
         records = finish_loadgen(p, path, schedule["end_t"]
                                  - schedule["close_t"] + 240)
-        mem = dev.memory_stats() or {}
-        peak_bytes = int(mem.get("peak_bytes_in_use", 0))
+        peak_bytes = run_peak_bytes = peak_in_use(dev)
+        stretch_records: List[Dict] = []
+        if args.trace == 2:
+            traced, stretch_records = traced_stretch(
+                cell, worker, waddr, procs, run_dir, front["http"],
+                schedule, args.seed, vocab, steps)
+            run_peak_bytes = peak_in_use(dev)
 
         # ---- stop the program and free its state, then check ---------
         worker.stop()
@@ -329,8 +347,26 @@ def main(argv: Optional[List[str]] = None) -> int:
     log(f"reference check took {result['check_seconds']:.1f} s")
 
     metrics: Dict[str, Dict[str, Any]] = {}
+    if args.trace != 1:
+        values = {**e2e, "setup_s": setup_s}
+        for m in cell.end_to_end:
+            if args.rehearse_cpu and m["name"] != "setup_s":
+                continue
+            v = values.get(m["name"])
+            if v is None:
+                log(f"metric {m['name']}: the window's sample does not "
+                    f"support it")
+                continue
+            metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     if args.trace:
-        ctx = {"cell": cell, "config": config, "records": records,
+        # Under --trace 2 the window's own numbers (records, counters,
+        # bounds, memory peak) stand beside the stretch's trace; the
+        # stretch's records follow the window's because decode
+        # attention's roofline share counts the tokens that arrived
+        # inside the traced seconds (no reader takes a window number
+        # from them: each filters on open_t..close_t).
+        ctx = {"cell": cell, "config": config,
+               "records": records + stretch_records,
                "schedule": schedule, "open_t": open_t, "close_t": close_t,
                "counters_open": c_open, "counters_close": c_close,
                "steps": [steps[k] for k in sorted(steps)],
@@ -346,32 +382,25 @@ def main(argv: Optional[List[str]] = None) -> int:
             if value is not None:
                 metrics[m["name"]] = {"value": float(value),
                                       "unit": m["unit"]}
-    else:
-        values = {**e2e, "setup_s": setup_s}
-        for m in cell.end_to_end:
-            if args.rehearse_cpu and m["name"] != "setup_s":
-                continue
-            v = values.get(m["name"])
-            if v is None:
-                log(f"metric {m['name']}: the window's sample does not "
-                    f"support it")
-                continue
-            metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices)}
     out: Dict[str, Any] = {"correct": bool(correct), "attempted": attempted,
                            "failed": failed, "metrics": metrics,
                            "device": device}
     if not args.rehearse_cpu:
-        device["memory_peak_bytes"] = peak_bytes
+        device["memory_peak_bytes"] = run_peak_bytes
         if traced is not None:
             device["busy_s"] = traced["busy"]["busy_s"]
             device["window_s"] = traced["busy"]["window_s"]
             out["breakdown"] = traced["breakdown"]
-    elif args.trace == 0:
+    elif args.trace != 1:
         out["rehearsal"] = {k: v for k, v in e2e.items() if v is not None}
     print(json.dumps(out), flush=True)
     return 0
+
+
+def peak_in_use(dev) -> int:
+    return int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
 
 
 def collect_steps(waddr: str, steps: Dict[int, Dict[str, Any]]) -> None:
@@ -381,20 +410,57 @@ def collect_steps(waddr: str, steps: Dict[int, Dict[str, Any]]) -> None:
         steps[int(s["seq"])] = s
 
 
-def traced_window(cell, waddr: str, open_t: float, close_t: float,
-                  run_dir: str, steps: Dict[int, Dict[str, Any]]
-                  ) -> Optional[Dict[str, Any]]:
-    """Wrap a few seconds of the steady window in the profiler; poll the
-    step recorder through the window (its ring holds ~20 s)."""
+def take_trace(cell, worker, run_dir: str, start: float, length: float
+               ) -> Dict[str, Any]:
+    """Hold the worker's device trace for ``length`` seconds from
+    ``start`` (or from now, if that is later) and reduce it. The worker
+    starts the profiler without the Python tracer and switches the
+    program's ``xllm.*`` spans on, so the idle gaps are named by spans
+    that cost nothing when off. The trace's files are deleted once they
+    are read. A CPU trace has no device plane: it comes back with its
+    events alone."""
     import shutil
     import jax
-    from chipbench import cluster, trace
+    from chipbench import spans, trace
+    tdir = os.path.join(run_dir, "trace")
+    shutil.rmtree(tdir, ignore_errors=True)
+    sleep_until(start)
+    worker.start_device_trace(tdir)
+    w0 = time.time()
+    until = time.monotonic() + length
+    with jax.profiler.TraceAnnotation("chipbench.traced_window"):
+        sleep_until(until)
+    w1 = time.time()            # stopping itself takes seconds
+    worker.stop_device_trace()
+    events = trace.load_events(trace.find_xplane(tdir))
+    shutil.rmtree(tdir, ignore_errors=True)
+    out: Dict[str, Any] = {"wall0": w0, "wall1": w1, "events": events}
+    counts = collections.Counter(
+        e["name"] for e in spans.program_spans(events))
+    log("program spans in the trace: " + (", ".join(
+        f"{k} {v}" for k, v in sorted(counts.items())) or "none"))
+    if jax.devices()[0].platform != "tpu":
+        return out
+    odir = os.path.join(ROOT, "chiprun_out", "chipbench")
+    os.makedirs(odir, exist_ok=True)
+    with open(os.path.join(odir, f"{cell.name}.trace_description.json"),
+              "w") as f:
+        json.dump(trace.describe(events), f, indent=1)
+    out["busy"] = trace.busy(events)     # raises where no operation ran
+    out["breakdown"] = {"device_ops": trace.top_ops(events),
+                        "idle_gaps": spans.idle_by_span(events)}
+    return out
+
+
+def traced_window(cell, worker, waddr: str, open_t: float, close_t: float,
+                  run_dir: str, steps: Dict[int, Dict[str, Any]]
+                  ) -> Dict[str, Any]:
+    """``--trace 1``: trace a few seconds of the steady window itself;
+    poll the step recorder through the window (its ring holds ~10 s)."""
     tr = cell.traffic.get("trace") or {}
     start = open_t + float(tr.get("start_after_s", 3.0))
     length = min(float(tr.get("seconds", 3.0)),
                  max(0.5, close_t - start - 0.5))
-    tdir = os.path.join(run_dir, "trace")
-    shutil.rmtree(tdir, ignore_errors=True)
     stop_poll = threading.Event()
 
     def poll():
@@ -406,63 +472,66 @@ def traced_window(cell, waddr: str, open_t: float, close_t: float,
 
     th = threading.Thread(target=poll, daemon=True)
     th.start()
-    out: Optional[Dict[str, Any]] = None
-    on_chip = jax.devices()[0].platform == "tpu"
-    gdir = os.path.join(run_dir, "trace_gaps")
-    shutil.rmtree(gdir, ignore_errors=True)
     try:
-        sleep_until(start)
-        w0 = w1 = time.time()
-        if on_chip:
-            # The numbers come from a trace without the Python tracer
-            # (it slows the very host whose gaps are being measured)...
-            quiet = jax.profiler.ProfileOptions()
-            quiet.python_tracer_level = 0
-            jax.profiler.start_trace(tdir, profiler_options=quiet)
-            w0 = time.time()
-            with jax.profiler.TraceAnnotation("chipbench.traced_window"):
-                sleep_until(start + length)
-            w1 = time.time()        # stop_trace() itself takes seconds
-            jax.profiler.stop_trace()
-        else:
-            sleep_until(start + length)
-            w1 = time.time()
-        out = {"wall0": w0, "wall1": w1}
-        if on_chip and close_t - time.monotonic() > 3.0:
-            # ... and a second, short one WITH it names the idle gaps by
-            # what the host's threads were doing.
-            try:
-                jax.profiler.start_trace(gdir)
-                time.sleep(float(tr.get("gaps_seconds", 1.0)))
-                jax.profiler.stop_trace()
-            except RuntimeError as e:
-                log(f"the gaps trace failed: {e}")
+        return take_trace(cell, worker, run_dir, start, length)
     finally:
         stop_poll.set()
         th.join()
-    if not on_chip:
-        return None
-    events = trace.load_events(trace.find_xplane(tdir))
-    odir = os.path.join(ROOT, "chiprun_out", "chipbench")
-    os.makedirs(odir, exist_ok=True)
-    with open(os.path.join(odir, f"{cell.name}.trace_description.json"),
-              "w") as f:
-        json.dump(trace.describe(events), f, indent=1)
-    out["events"] = events
-    out["busy"] = trace.busy(events)     # raises where no operation ran
-    gaps = []
-    if os.path.isdir(gdir):
-        try:
-            gaps = trace.top_idle_gaps(
-                trace.load_events(trace.find_xplane(gdir)))
-        except (FileNotFoundError, ValueError) as e:
-            log(f"the gaps trace gave nothing ({e}); naming gaps from "
-                f"the first trace's host spans")
-    if not gaps:
-        gaps = trace.top_idle_gaps(events)
-    out["breakdown"] = {"device_ops": trace.top_ops(events),
-                        "idle_gaps": gaps}
-    return out
+
+
+def traced_stretch(cell, worker, waddr: str, procs, run_dir: str,
+                   http_addr: str, schedule: Dict[str, Any], seed: int,
+                   vocab: int, steps: Dict[int, Dict[str, Any]]):
+    """``--trace 2``, after the measured window has closed and its
+    numbers are taken: a second generator on the same mix brings the
+    clients back to steady state (its own ramp), and a few seconds of it
+    are traced. The documents are the window's, which are in the cache;
+    the questions are drawn anew (seed + 1): a question the window has
+    asked before would find its own tokens cached behind the document
+    and leave a shorter window to prefill than any the mix warms up.
+    Nothing polls the worker during the stretch; the step recorder is
+    pulled once after the trace. Returns the trace and the stretch's
+    records."""
+    import shutil
+    tr = cell.traffic.get("trace") or {}
+    after = float(tr.get("start_after_s", 3.0))
+    length = float(tr.get("seconds", 3.0))
+    # The profiler's first start in a process is its dearest: pay for it
+    # now, with nothing running, and throw that trace away.
+    cold = os.path.join(run_dir, "trace_first_start")
+    worker.start_device_trace(cold)
+    worker.stop_device_trace()
+    shutil.rmtree(cold, ignore_errors=True)
+    stretch = traffic.build(cell.traffic, seed + 1, after + length + 1.0,
+                            vocab)
+    stretch["docs"] = schedule["docs"]
+    stretch["sampling"] = schedule["sampling"]
+    p, t0, path = run_loadgen(procs, run_dir, "stretch", stretch,
+                              http_addr, cell.config_name)
+    traced = take_trace(cell, worker, run_dir,
+                        t0 + stretch["open_t"] + after, length)
+    collect_steps(waddr, steps)
+    records = finish_loadgen(p, path, stretch["end_t"] + 240)
+    by_id = {r["id"]: r for r in stretch["requests"]}
+    for r in records:
+        req = by_id.get(r["id"])
+        r["n_prompt"] = len(traffic.prompt_of(stretch, req)) if req else 0
+    bad = [r for r in records if not r.get("ok")]
+    log(f"traced stretch: {len(records)} requests, {len(bad)} failed"
+        + (f" (first: {bad[0].get('error')})" if bad else ""))
+    # What the traffic read WHILE the trace ran, on the generator's
+    # clock: beside the window's own numbers, what tracing costs.
+    off = time.time() - time.monotonic()
+    on = stats.end_to_end(records, traced["wall0"] - off,
+                          traced["wall1"] - off)
+    ttft = [1000.0 * (r["frames"][0][0] - r["due"]) for r in records
+            if r["ok"] and r["frames"] and traced["wall0"] - off
+            <= r["due"] < traced["wall1"] - off]
+    log(f"with the device trace running: {on['out_tok_s']:.1f} tokens/s "
+        f"over {on['_tokens']} tokens; first token after "
+        + (f"{statistics.median(ttft):.1f} ms (median of {len(ttft)})"
+           if ttft else "no request due in it"))
+    return traced, records
 
 
 if __name__ == "__main__":

@@ -27,7 +27,9 @@ def bench():
 
 def test_top_level_keys_and_sizes(bench):
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
+                          "workloads", "end_to_end", "per_layer",
+                          "trace_in_run"}
+    assert bench["trace_in_run"] is True    # the driver passes 0 and 2
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 65536
     assert bench["paths"] == ["chipbench", "tests/chipbench"]
     assert 1 <= bench["run_seconds"] <= 51

@@ -1,0 +1,277 @@
+"""The program's spans on the profiler's clock, the worker's device-trace
+control, the queue-wait histogram and the name of a late compile.
+
+Spans: the catalog is closed and every span site in the tree is in it;
+with the switch off a site builds nothing; they are written only between
+start and stop of the control (checked on a real CPU trace too: the
+profiler runs without a chip, its host plane is all a CPU has)."""
+
+import ast
+import json
+import logging
+import os
+import re
+from http.client import HTTPConnection
+
+import pytest
+
+from xllm_service_tpu.obs import steptrace
+
+PKG = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "xllm_service_tpu")
+
+
+def _post(addr, path, obj):
+    host, port = addr.rsplit(":", 1)
+    conn = HTTPConnection(host, int(port), timeout=120)
+    try:
+        conn.request("POST", path, body=json.dumps(obj),
+                     headers={"Content-Type": "application/json"})
+        r = conn.getresponse()
+        return r.status, json.loads(r.read().decode("utf-8", "replace"))
+    finally:
+        conn.close()
+
+
+def _complete(w, prompt, n=4):
+    return _post(w.name, "/v1/completions", {
+        "model": "tiny", "prompt": prompt, "max_tokens": n,
+        "temperature": 0.0, "ignore_eos": True})
+
+
+@pytest.fixture
+def worker():
+    from xllm_service_tpu.runtime.worker import Worker, WorkerOptions
+    from xllm_service_tpu.service.coordination import InMemoryStore
+    w = Worker(WorkerOptions(model="tiny"), InMemoryStore()).start()
+    try:
+        yield w
+    finally:
+        w.stop()            # stops a trace a failed test left running
+
+
+@pytest.fixture
+def counted(monkeypatch):
+    """``jax.profiler.TraceAnnotation`` replaced by a counter of names."""
+    import jax
+    built = []
+
+    class Counted:
+        def __init__(self, name, **args):
+            built.append((name, args))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counted)
+    yield built
+    steptrace.set_spans(False)
+
+
+# ---------------------------------------------------------------------------
+# The catalog and the helper
+# ---------------------------------------------------------------------------
+def _span_sites():
+    """Every span name the package can emit, read off its source: the
+    literals of ``steptrace.span(...)`` calls, and ``xllm.step.<phase>``
+    for every ``_phase("<phase>")`` / ``_read_host("<phase>")``."""
+    names = set()
+    for root, _, files in os.walk(PKG):
+        for fn in files:
+            if not fn.endswith(".py"):
+                continue
+            tree = ast.parse(open(os.path.join(root, fn)).read())
+            for node in ast.walk(tree):
+                if not isinstance(node, ast.Call) or not isinstance(
+                        node.func, ast.Attribute):
+                    continue
+                lits = [a.value for a in node.args
+                        if isinstance(a, ast.Constant)
+                        and isinstance(a.value, str)]
+                if node.func.attr == "span" and isinstance(
+                        node.func.value, ast.Name) and \
+                        node.func.value.id == "steptrace" and lits:
+                    if len(lits) == len(node.args):
+                        names.add("".join(lits))
+                elif node.func.attr == "_phase" and lits:
+                    names.add("xllm.step." + lits[0])
+                elif node.func.attr == "_read_host" and lits:
+                    names.add(f"xllm.step.{lits[0]}.device_wait")
+                    names.add(f"xllm.step.{lits[0]}.host_copy")
+    return names
+
+
+def test_span_names_is_closed_and_holds_every_site():
+    sites = _span_sites()
+    assert len(sites) > 30
+    assert sites == set(steptrace.SPAN_NAMES)
+    assert len(set(steptrace.SPAN_NAMES)) == len(steptrace.SPAN_NAMES)
+    assert all(re.fullmatch(r"xllm\.[a-z_.]+", n)
+               for n in steptrace.SPAN_NAMES)
+
+
+def test_span_off_is_one_shared_noop_and_builds_nothing(counted):
+    a = steptrace.span("xllm.loop.step", seq=1)
+    b = steptrace.span("xllm.step.", "decode", ".device_wait")
+    assert a is b                       # the one shared no-op
+    with a:
+        pass
+    # off, not even the name is looked at: nothing is joined or checked
+    assert steptrace.span("not", "a", "span") is a
+    assert counted == []
+
+
+def test_span_on_builds_the_named_annotation_and_rejects_others(counted):
+    steptrace.set_spans(True)
+    with steptrace.span("xllm.step.", "decode", ".device_wait"):
+        pass
+    with steptrace.span("xllm.loop.step", seq=7):
+        pass
+    assert counted == [("xllm.step.decode.device_wait", {}),
+                       ("xllm.loop.step", {"seq": 7})]
+    with pytest.raises(ValueError, match="SPAN_NAMES"):
+        steptrace.span("xllm.loop.", "coffee")
+
+
+def test_engine_phase_builds_no_annotation_while_off(counted):
+    from xllm_service_tpu.config import EngineConfig, ModelConfig
+    from xllm_service_tpu.runtime.engine import Engine, EngineRequest
+    from xllm_service_tpu.utils.types import SamplingParams
+    eng = Engine(ModelConfig.tiny(vocab_size=256), EngineConfig(
+        page_size=16, num_pages=32, max_model_len=128, max_batch_size=2,
+        prefill_buckets=(32,)))
+    eng.add_request(EngineRequest(
+        request_id="r0", token_ids=list(range(3, 20)),
+        sampling=SamplingParams(max_tokens=3, temperature=0.0,
+                                ignore_eos=True)))
+    while eng.has_work():
+        eng.step()
+    assert eng.phase_counts["decode.dispatch"] >= 1   # phases did run
+    assert counted == []
+    # the same engine, switch on: its phases are spans now, and the
+    # dispatch carries the shape key the program was launched with
+    steptrace.set_spans(True)
+    eng.add_request(EngineRequest(
+        request_id="r1", token_ids=list(range(3, 20)),
+        sampling=SamplingParams(max_tokens=3, temperature=0.0,
+                                ignore_eos=True)))
+    while eng.has_work():
+        eng.step()
+    names = [n for n, _ in counted]
+    for want in ("xllm.step.sched", "xllm.kv.match_prefix",
+                 "xllm.step.prefill.pack", "xllm.step.prefill.dispatch",
+                 "xllm.step.prefill.device_wait",
+                 "xllm.step.prefill.host_copy", "xllm.step.decode.pack",
+                 "xllm.step.decode.post", "xllm.kv.register_pages"):
+        assert want in names, want
+    args = dict(counted)["xllm.step.decode.dispatch"]
+    assert args == {"program": "decode", "B": 2, "T": 1, "MP": args["MP"]}
+    assert dict(counted)["xllm.kv.match_prefix"] == {"tokens": 17}
+
+
+# ---------------------------------------------------------------------------
+# The control
+# ---------------------------------------------------------------------------
+def test_spans_are_emitted_only_between_start_and_stop(worker, counted,
+                                                       tmp_path):
+    assert _complete(worker, "before the trace")[0] == 200
+    assert counted == []
+    assert worker.start_device_trace(str(tmp_path / "t")) == \
+        str(tmp_path / "t")
+    assert steptrace.spans_on()
+    assert _complete(worker, "inside the trace")[0] == 200
+    assert worker.stop_device_trace() == str(tmp_path / "t")
+    assert not steptrace.spans_on()
+    inside = [n for n, _ in counted]
+    for want in ("xllm.loop.lock_wait", "xllm.loop.step", "xllm.loop.emit",
+                 "xllm.loop.obs_flush", "xllm.admit",
+                 "xllm.admit.lock_wait", "xllm.admit.locked"):
+        assert want in inside, want
+    rid = dict(counted)["xllm.admit"]["rid"]
+    assert rid and dict(counted)["xllm.admit.locked"] == {"rid": rid}
+    n = len(counted)
+    assert _complete(worker, "after the trace")[0] == 200
+    assert len(counted) == n
+
+
+def test_endpoint_start_stop_and_its_refusals(worker, tmp_path):
+    d = str(tmp_path / "trace")
+    assert _post(worker.name, "/admin/devtrace", {"action": "stop"})[0] \
+        == 409                        # an error, not a crash
+    status, body = _post(worker.name, "/admin/devtrace",
+                         {"action": "start", "dir": d})
+    assert (status, body["dir"]) == (200, d)
+    status, body = _post(worker.name, "/admin/devtrace",
+                         {"action": "start", "dir": d + "2"})
+    assert status == 409 and "already running" in body["error"]["message"]
+    assert _complete(worker, "traced for real")[0] == 200
+    assert _post(worker.name, "/admin/devtrace",
+                 {"action": "stop"}) == (200, {"ok": True, "action": "stop",
+                                               "dir": d})
+    assert _post(worker.name, "/admin/devtrace", {"action": "go"})[0] == 400
+    assert _post(worker.name, "/admin/devtrace", {"action": "start"})[0] \
+        == 400
+    assert _complete(worker, "and it still serves")[0] == 200
+    # what the control wrote is a trace the benchmark's loader reads,
+    # with the program's spans on its host plane
+    from chipbench import spans, trace
+    events = trace.load_events(trace.find_xplane(d))
+    names = {e["name"] for e in spans.program_spans(events)}
+    assert {"xllm.loop.step", "xllm.loop.emit", "xllm.loop.obs_flush",
+            "xllm.step.decode.dispatch", "xllm.admit"} <= names
+    steps = spans.program_spans(events, r"^xllm\.loop\.step$")
+    inner = spans.program_spans(events, r"^xllm\.step\.")
+    assert steps and all(any(
+        s["start"] <= e["start"] and e["start"] + e["dur"]
+        <= s["start"] + s["dur"] for s in steps) for e in inner)
+
+
+# ---------------------------------------------------------------------------
+# The two counters
+# ---------------------------------------------------------------------------
+def _metric(w, name):
+    host, port = w.name.rsplit(":", 1)
+    conn = HTTPConnection(host, int(port), timeout=30)
+    try:
+        conn.request("GET", "/metrics")
+        text = conn.getresponse().read().decode()
+    finally:
+        conn.close()
+    return sum(float(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
+               if ln.startswith(name + "{") or ln.startswith(name + " "))
+
+
+def test_queue_wait_counts_one_observation_per_admitted_request(worker):
+    assert _metric(worker, "xllm_worker_queue_wait_ms_count") == 0
+    for i in range(3):
+        assert _complete(worker, f"request number {i}", n=6)[0] == 200
+    assert _metric(worker, "xllm_worker_queue_wait_ms_count") == 3
+    assert 0 < _metric(worker, "xllm_worker_queue_wait_ms_sum") < 60e3
+    eng = worker.primary_runtime().engine
+    assert eng.queue_waits_ms == []            # drained into the histogram
+
+
+def test_a_compile_after_warmup_is_named_in_the_log_and_the_record(
+        worker, caplog):
+    """A CPU worker boots unwarmed, so its first request compiles its
+    programs under serving: each is reported with its shape key."""
+    with caplog.at_level(logging.WARNING,
+                         logger="xllm_service_tpu.runtime.engine"):
+        assert _complete(worker, "compile me")[0] == 200
+    logged = re.findall(r"post-warmup compile of (\S+) \(cache",
+                        caplog.text)
+    assert logged and all(re.fullmatch(
+        r"(prefill:B\d+xT\d+xmp\d+|decode(_multi)?:mp\d+)", c)
+        for c in logged), logged
+    recorded = [c for r in worker.steptrace.tail()
+                for c in r["compiled"]]
+    assert sorted(recorded) == sorted(logged)
+    # a second request of the same shape compiles nothing, and says so
+    n = worker.steptrace.last_seq()
+    assert _complete(worker, "compile me")[0] == 200
+    later = worker.steptrace.tail(since_seq=n)
+    assert later and all(r["compiled"] == () for r in later)
+    assert _metric(worker, "xllm_worker_recompiles_total") == len(logged)

@@ -1,18 +1,15 @@
 """Device-plane step observatory (tier-1).
 
 Units for the step flight recorder (bounded ring, CLOSED field schema,
-seq/window tails, disabled-mode zero-build gate), the roofline
-attribution arithmetic (peaks table + env override, estimate/attribute
-over a hand-built cost_analysis table, /metrics mirror), the master's
+seq/window tails, disabled-mode zero-build gate), the peaks table and
+its env override, the master's
 StepBooks (heartbeat-tail dedupe on seq), the cluster-merged
 chrome-trace builder (byte-stable determinism, counter tracks, complete
 s→t→f flows) and its offline validator (tools/trace_view.py); then one
 e2e on two IN-PROCESS CPU workers: a named request streamed through the
 front door must come back out of ``GET /admin/timeline`` as a validated
 trace with service-plane stage slices, worker step slices with phase
-sub-events, ≥1 counter track, and a complete flow chain for that rid —
-with the MFU/FLOPs series on both planes' ``/metrics`` fed by the
-warmup-captured ``cost_analysis`` numbers, never hand math.
+sub-events, ≥1 counter track, and a complete flow chain for that rid.
 """
 
 import json
@@ -126,16 +123,9 @@ class TestStepBooks:
 
 
 # ---------------------------------------------------------------------------
-# Units: roofline arithmetic
+# Units: the peaks table
 # ---------------------------------------------------------------------------
-ROOF = {
-    "prefill": {"B1xT64xmp2": {"flops": 1e9, "bytes": 2e9,
-                               "tokens": 64.0}},
-    "decode": {"mp2": {"flops": 1e8, "bytes": 4e8, "tokens": 4.0}},
-}
-
-
-class TestRoofline:
+class TestPeaks:
     def test_peaks_table_resolves_device_kind(self, monkeypatch):
         # conftest sets the overrides for the CPU session; the table
         # itself is what this test reads.
@@ -160,71 +150,6 @@ class TestRoofline:
         monkeypatch.setattr(steptrace, "PEAK_FLOPS_OVERRIDE", 2e12)
         monkeypatch.setattr(steptrace, "PEAK_BW_GBPS_OVERRIDE", 100.0)
         assert steptrace.peaks_for("TPU v6e") == (2e12, 100.0 * 1e9)
-
-    def test_estimate_prefill_scales_from_nearest_variant(self):
-        cost = steptrace.estimate_step(
-            ROOF, kind="prefill", prefill_tokens=128, decode_tokens=0,
-            batch_size=4, decode_steps=1, ragged=False)
-        # 128 prompt tokens against the captured 64-token variant:
-        # linear scale 2×.
-        assert cost["flops"] == pytest.approx(2e9)
-        assert cost["bytes"] == pytest.approx(4e9)
-
-    def test_estimate_decode_is_per_burst(self):
-        # A decode dispatch pays the full padded batch: 4 tokens over
-        # batch 4 × 1 step = exactly one burst.
-        cost = steptrace.estimate_step(
-            ROOF, kind="decode", prefill_tokens=0, decode_tokens=4,
-            batch_size=4, decode_steps=1, ragged=False)
-        assert cost["flops"] == pytest.approx(1e8)
-        # 5 tokens need a second (fully paid) burst.
-        cost = steptrace.estimate_step(
-            ROOF, kind="decode", prefill_tokens=0, decode_tokens=5,
-            batch_size=4, decode_steps=1, ragged=False)
-        assert cost["flops"] == pytest.approx(2e8)
-
-    def test_attribute_step_verdict_and_debt(self):
-        v = steptrace.attribute_step(
-            ROOF, kind="decode", step_ms=1.0, prefill_tokens=0,
-            decode_tokens=4, batch_size=4, decode_steps=1,
-            ragged=False, peak_flops=1e12, peak_bytes_s=1e12)
-        # 1e8 FLOPs in 1 ms over a 1e12 FLOP/s peak → MFU 0.1; memory
-        # side dominates (0.4 ms modeled vs 0.1 ms compute) → debt 0.6.
-        assert v["mfu"] == pytest.approx(0.1)
-        assert v["bound"] == "memory"
-        assert v["debt_ms"] == pytest.approx(0.6)
-
-    def test_attribute_step_empty_table_is_unknown(self):
-        v = steptrace.attribute_step(
-            {}, kind="decode", step_ms=5.0, prefill_tokens=0,
-            decode_tokens=4, batch_size=4, decode_steps=1,
-            ragged=False, peak_flops=1e12, peak_bytes_s=1e12)
-        assert v["bound"] == "unknown" and v["flops"] == 0.0
-        assert v["debt_ms"] == pytest.approx(5.0)
-
-    def test_roofline_table_bound_vs_ridge(self):
-        rows = steptrace.roofline_table(ROOF, peak_flops=1e12,
-                                        peak_bytes_s=1e12)
-        by_prog = {r["program"]: r for r in rows}
-        # Ridge = 1 FLOP/byte; both fixtures sit at intensity < 1.
-        assert by_prog["prefill"]["intensity"] == pytest.approx(0.5)
-        assert by_prog["prefill"]["bound"] == "memory"
-        assert by_prog["decode"]["bound"] == "memory"
-
-    def test_flush_metrics_series_are_cost_analysis_fed(self):
-        reg = Registry()
-        steptrace.flush_metrics(reg, "tiny", ROOF, 0.25, 1.5,
-                                peak_flops=1e12)
-        text = reg.render()
-        assert 'xllm_worker_step_mfu{model="tiny"} 0.25' in text
-        assert 'xllm_worker_step_debt_ms{model="tiny"} 1.5' in text
-        # The FLOPs/bytes series carry the table's numbers, per
-        # (program, variant) — the numerators are cost_analysis output.
-        assert 'program="prefill"' in text and \
-            'variant="B1xT64xmp2"' in text
-        assert "xllm_worker_program_flops" in text
-        assert "xllm_worker_program_bytes" in text
-        assert "xllm_worker_peak_flops 100000000000" in text
 
 
 # ---------------------------------------------------------------------------
@@ -262,8 +187,7 @@ def _fixture_inputs():
             {"seq": 1, "t_wall": T0 + 0.06, "kind": "prefill",
              "step_ms": 12.0, "members": ["rid-a"],
              "phases": {"prefill.dispatch": 8.0, "prefill.sample": 2.0},
-             "kv_usage": 0.125, "mfu": 0.2, "bound": "compute",
-             "debt_ms": 1.0},
+             "kv_usage": 0.125, "compiled": ["prefill:B1xT64xmp2"]},
             {"seq": 2, "t_wall": T0 + 0.09, "kind": "decode",
              "step_ms": 5.0, "members": ["rid-a"],
              "phases": {"decode.dispatch": 4.0}, "kv_usage": 0.25},
@@ -430,8 +354,8 @@ def _scrape(http_addr):
 class TestStepObservatoryE2E:
     def test_timeline_spans_steps_flows_and_metrics(self, monkeypatch,
                                                     tmp_path):
-        # CPU workers skip warmup by default (tests boot dozens); the
-        # roofline table is captured AT warmup, so force it — the short
+        # CPU workers skip warmup by default (tests boot dozens); warm
+        # these up, so that a recorded step names no compile — the short
         # sweep, or two engines' pow2 sweeps dominate the test.
         monkeypatch.setenv("XLLM_WARMUP_EXTENDED", "0")
         store = InMemoryStore(sweep_interval_s=0.02)
@@ -461,7 +385,7 @@ class TestStepObservatoryE2E:
             text, done = _stream_named(master.http_address, NAMED_RID)
             assert done and text
 
-            # --- the worker that served it: ring + roofline ----------
+            # --- the worker that served it: the ring -----------------
             served = [w for w in workers
                       if len(w.steptrace) > 0]
             assert served, "no worker recorded a step"
@@ -470,38 +394,22 @@ class TestStepObservatoryE2E:
                                    timeout=10.0)
             assert status == 200
             assert st["enabled"] is True
-            assert st["peak_flops"] > 0 and st["peak_bytes_s"] > 0
+            assert st["devtrace"] is None      # no trace is running
             assert st["steps"], "empty flight recorder after a request"
             rec = st["steps"][-1]
-            # Fixed schema end-to-end: only declared fields, carrying
-            # the roofline verdict.
+            # Fixed schema end-to-end: only declared fields, and each
+            # step says what it compiled (shapes outside the short
+            # warm-up may: each names its program and shape key).
             assert set(rec) <= set(steptrace.STEP_FIELDS)
             assert rec["kind"] in ("prefill", "decode", "mixed")
-            assert rec["bound"] in ("compute", "memory", "unknown")
+            for r in st["steps"]:
+                for c in r["compiled"]:
+                    prog, _, shape = c.partition(":")
+                    assert prog in ("prefill", "decode", "decode_multi")
+                    assert shape.startswith(("B", "mp")), c
             carried = [r for r in st["steps"]
                        if NAMED_RID in (r.get("members") or ())]
             assert carried, "no step recorded the named rid"
-            # The warmup-captured cost table answered: real
-            # cost_analysis rows, nonzero FLOPs, per program variant.
-            assert st["roofline"], "no roofline variants captured"
-            assert any(r["flops"] > 0 for r in st["roofline"])
-            progs = {r["program"] for r in st["roofline"]}
-            assert "prefill" in progs and (
-                "decode" in progs or "decode_multi" in progs)
-
-            # --- worker /metrics: the MFU/FLOPs mirror ---------------
-            wm = _scrape(w.name)
-            assert "xllm_worker_step_mfu{" in wm
-            assert "xllm_worker_step_debt_ms{" in wm
-            assert "xllm_worker_peak_flops" in wm
-            flops_lines = [
-                ln for ln in wm.splitlines()
-                if ln.startswith("xllm_worker_program_flops{")]
-            assert flops_lines
-            assert any(float(ln.rsplit(" ", 1)[1]) > 0
-                       for ln in flops_lines), \
-                "program FLOPs all zero — not cost_analysis-fed"
-
             # --- the merged timeline ---------------------------------
             status, raw = http_json(
                 "GET", master.http_address,

@@ -1,4 +1,5 @@
-"""Device-plane step flight recorder + roofline/MFU attribution.
+"""Device-plane step flight recorder, and the program's spans on the
+profiler's clock.
 
 The service plane got self-profiling in the hotpath-section catalog
 (obs/profiler.py); the device plane still reported only aggregate
@@ -13,14 +14,16 @@ no per-step evidence trail. This module is that trail:
   host_copy splits included), the batch token mix, ragged/split
   dispatch counts, the speculation outcome delta, KV-page/cache
   deltas, and the request-id membership of the step.
-- **Roofline attribution**: at warmup the engine captures
-  ``.lower().compile().cost_analysis()`` FLOPs/bytes per compiled
-  variant of each jitted program (``Engine.roofline``); this module
-  owns the peak table (``XLLM_PEAK_FLOPS`` / ``XLLM_PEAK_BW_GBPS``,
-  with device-kind defaults) and turns (ledger, roofline) into per-step
-  achieved FLOP/s, MFU, a compute-vs-memory-bound verdict, and the
-  decode-debt ms (measured wall − modeled roofline time) the
-  PERF_NOTES decode_budget runbook used to hand-compute.
+- **Spans**: ``span(...)`` is the one helper behind every
+  ``xllm.*`` span of the engine loop, the step's phases, the prefix
+  index and admission (``SPAN_NAMES``, closed). While a device trace
+  runs (``Worker.start_device_trace``) each is a
+  ``jax.profiler.TraceAnnotation`` on the host plane of the same
+  ``.xplane.pb`` as the device's operations; otherwise the helper tests
+  one flag and returns a shared no-op.
+- **Peaks**: the published peak table by device kind (``CHIP_PEAKS``,
+  ``XLLM_PEAK_FLOPS`` / ``XLLM_PEAK_BW_GBPS`` override it), which
+  bench.py divides its tokens/s-based utilization by.
 - **Shipping**: the worker exposes the ring on ``GET /admin/steptrace``
   and ships a bounded tail on every heartbeat (sequence-baseline
   committed only on a delivered beat, so an undelivered tail is
@@ -67,13 +70,94 @@ STEP_FIELDS: Tuple[str, ...] = (
     "kv_usage",         # KV page pool utilization [0,1] after the step
     "pages_delta",      # free-page delta across the step (+freed/-taken)
     "cache_hit_tokens", # prefix-cache hit-token delta this step
-    "flops",            # modeled useful FLOPs of the step (roofline)
-    "bytes",            # modeled bytes moved by the step (roofline)
-    "mfu",              # achieved FLOP/s over the peak, this step
-    "bound",            # roofline verdict: compute | memory | unknown
-    "debt_ms",          # measured step ms − modeled roofline ms
+    "compiled",         # programs compiled AFTER warm-up in this step:
+                        # tuple of "<program>:<shape key>" (0 is the contract)
 )
 
+# ---------------------------------------------------------------------------
+# The closed span catalog. While a device trace runs (Worker.
+# start_device_trace) every name below is written as a
+# jax.profiler.TraceAnnotation into the host plane of the same .xplane.pb
+# as the device's ``XLA Ops`` / ``XLA Modules`` lines, on the same clock,
+# without the Python tracer. ``xllm.step.<phase>`` is every name
+# Engine._phase is called with plus Engine._read_host's two splits.
+# tests/test_devtrace.py holds every span site in the tree to this tuple.
+# ---------------------------------------------------------------------------
+STEP_PHASES: Tuple[str, ...] = (
+    "sched", "kv_restore",
+    "prefill.pack", "prefill.dispatch", "prefill.post",
+    "prefill_ring.pack", "prefill_ring.dispatch",
+    "ragged.pack", "ragged.dispatch", "ragged.post",
+    "decode.pack", "decode.dispatch", "decode.post",
+    "decode_multi.pack", "decode_multi.dispatch",
+    "decode_multi.spec_dispatch", "decode_multi.post",
+)
+READ_HOST_PHASES: Tuple[str, ...] = (
+    "prefill", "prefill_ring", "ragged", "decode", "decode_multi",
+    "kv_spill", "kv_export_blocks")
+
+SPAN_NAMES: Tuple[str, ...] = (
+    "xllm.loop.lock_wait",   # engine loop: wanting _engine_lock -> holding it
+    "xllm.loop.step",        # around Engine.step(); arg seq
+    "xllm.loop.emit",        # around Worker._dispatch_outputs; arg tokens
+    "xllm.loop.obs_flush",   # around Worker._flush_engine_obs
+    "xllm.loop.idle_wait",   # _work_event.wait when no runtime had work
+    "xllm.kv.match_prefix",  # PrefixCacheIndex.match_prefix; arg tokens
+    "xllm.kv.register_pages",  # PrefixCacheIndex.register_full_pages
+    "xllm.admit",            # handler thread: parsed request -> enqueued
+    "xllm.admit.lock_wait",  # ... waiting for _engine_lock
+    "xllm.admit.locked",     # ... holding it (Engine.add_request)
+) + tuple("xllm.step." + p for p in STEP_PHASES) + tuple(
+    f"xllm.step.{p}.{half}" for p in READ_HOST_PHASES
+    for half in ("device_wait", "host_copy"))
+
+_SPAN_SET = frozenset(SPAN_NAMES)
+
+
+class _NullSpan:
+    """The one shared no-op every span site gets while no trace runs."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
+_spans_on = False
+
+
+def set_spans(on: bool) -> None:
+    """The span switch. Process-wide, like the profiler session it
+    follows: Worker.start_device_trace turns it on after the profiler
+    has started and off before it stops."""
+    global _spans_on
+    _spans_on = bool(on)
+
+
+def spans_on() -> bool:
+    return _spans_on
+
+
+def span(*parts: str, **args: Any):
+    """A span on the profiler's clock: ``with steptrace.span(...)``.
+    Off (the normal state) it tests one flag and returns the shared
+    no-op; nothing is built or formatted. On it opens a
+    ``jax.profiler.TraceAnnotation`` named by ``parts`` joined (a member
+    of ``SPAN_NAMES``, or it raises) with ``args`` as the event's
+    stats."""
+    if not _spans_on:
+        return _NULL_SPAN
+    full = "".join(parts)
+    if full not in _SPAN_SET:
+        raise ValueError(
+            f"unknown span {full!r}: add it to steptrace.SPAN_NAMES "
+            f"first (closed catalog)")
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(full, **args)
 _FIELD_SET = frozenset(STEP_FIELDS)
 
 
@@ -92,11 +176,8 @@ def _ring_from_env() -> int:
 ENABLED = _enabled_from_env()
 RING = _ring_from_env()
 
-# Configurable peaks for the roofline model, read ONCE at import (hot-
-# path flag discipline). 0 = auto: resolve from the device kind at
-# engine attach time (the bench's public-spec table), with a deliberate
-# CPU fallback so MFU/debt stay finite (and obviously modeled) on the
-# CPU tier-1 harness.
+# Overrides of the peak table, read ONCE at import. 0 = take the
+# device kind's row.
 try:
     PEAK_FLOPS_OVERRIDE = float(os.environ.get("XLLM_PEAK_FLOPS", "0"))
 except ValueError:
@@ -111,9 +192,8 @@ except ValueError:
 # ``jax.Device.device_kind`` (both spellings jax knows for a
 # generation). Source: Google Cloud TPU documentation, the "System
 # architecture" page of each generation ("TPU v5e": 197 TFLOP/s bf16,
-# 819 GB/s HBM). The one peaks table of the repo — bench.py reads it
-# too. A device that is not here has no roofline: asking for it is an
-# error, not a default.
+# 819 GB/s HBM). bench.py reads it. A device that is not here has no
+# peak: asking for it is an error, not a default.
 CHIP_PEAKS: Dict[str, Tuple[float, float]] = {
     "TPU v2": (45e12, 700.0),
     "TPU v3": (123e12, 900.0),
@@ -131,7 +211,7 @@ def peaks_for(device_kind: str) -> Tuple[float, float]:
     """(peak FLOP/s, peak bytes/s) for a device kind: the
     XLLM_PEAK_FLOPS / XLLM_PEAK_BW_GBPS overrides first, then
     ``CHIP_PEAKS``. Raises for a kind the table does not hold (a CPU
-    run that wants roofline arithmetic sets both overrides)."""
+    run of bench.py sets both overrides)."""
     flops = PEAK_FLOPS_OVERRIDE
     bw = PEAK_BW_GBPS_OVERRIDE * 1e9
     if flops > 0 and bw > 0:
@@ -140,8 +220,7 @@ def peaks_for(device_kind: str) -> Tuple[float, float]:
         raise ValueError(
             f"no peak FLOP/s and bandwidth on file for device kind "
             f"{device_kind!r} (obs/steptrace.py CHIP_PEAKS); set "
-            f"XLLM_PEAK_FLOPS and XLLM_PEAK_BW_GBPS to run the roofline "
-            f"arithmetic on it")
+            f"XLLM_PEAK_FLOPS and XLLM_PEAK_BW_GBPS to state them")
     t_flops, t_bw = CHIP_PEAKS[device_kind]
     return (flops if flops > 0 else t_flops,
             bw if bw > 0 else t_bw * 1e9)
@@ -201,6 +280,12 @@ class StepTrace:
         with self._lock:
             return self._seq
 
+    @property
+    def next_seq(self) -> int:
+        """The ``seq`` the next record gets. Read without the lock: exact
+        on the engine-loop thread, the only one that records."""
+        return self._seq + 1
+
     def __len__(self) -> int:
         with self._lock:
             return len(self._ring)
@@ -241,168 +326,3 @@ class StepBooks:
     def instances(self) -> List[str]:
         with self._lock:
             return sorted(self._books)
-
-
-# ---------------------------------------------------------------------------
-# Roofline arithmetic: (engine roofline table, step ledger) → modeled
-# step cost, MFU, bound verdict, and decode debt.
-# ---------------------------------------------------------------------------
-
-def _median_variant(variants: Dict[str, Dict[str, float]]
-                    ) -> Optional[Dict[str, float]]:
-    rows = [v for v in variants.values()
-            if v.get("flops", 0.0) > 0.0]
-    if not rows:
-        return None
-    rows.sort(key=lambda v: v["flops"])
-    return rows[len(rows) // 2]
-
-
-def _nearest_prefill_variant(variants: Dict[str, Dict[str, float]],
-                             tokens: int) -> Optional[Dict[str, float]]:
-    """The captured prefill/ragged variant whose batch token count
-    (B*T, parsed from the ``B{B}xT{T}x...`` key) is nearest the step's
-    actual prompt-token load — the modeled cost scales from it."""
-    best = None
-    best_d = None
-    for key, v in variants.items():
-        if v.get("flops", 0.0) <= 0.0:
-            continue
-        toks = v.get("tokens", 0.0)
-        if toks <= 0:
-            continue
-        d = abs(toks - tokens)
-        if best_d is None or d < best_d:
-            best, best_d = v, d
-    return best
-
-
-def estimate_step(roofline: Dict[str, Dict[str, Dict[str, float]]],
-                  *, kind: str, prefill_tokens: int, decode_tokens: int,
-                  batch_size: int, decode_steps: int,
-                  ragged: bool) -> Dict[str, float]:
-    """Modeled device cost of one engine iteration from the warmup-
-    captured cost_analysis table: total FLOPs/bytes, and which side of
-    the roofline the dominant program sits on. Scaling is explicit and
-    documented as a MODEL: prefill cost scales linearly in prompt
-    tokens from the nearest captured variant; decode cost is per-burst
-    (a decode dispatch runs the full padded batch, so dead rows are
-    paid — that is the point of the debt number)."""
-    flops = 0.0
-    bytes_ = 0.0
-    if prefill_tokens > 0:
-        prog = "ragged" if ragged else "prefill"
-        variants = roofline.get(prog) or roofline.get("prefill") or {}
-        v = _nearest_prefill_variant(variants, prefill_tokens)
-        if v is not None:
-            scale = prefill_tokens / max(v.get("tokens", 1.0), 1.0)
-            flops += v["flops"] * scale
-            bytes_ += v.get("bytes", 0.0) * scale
-    if decode_tokens > 0 and not (ragged and kind == "mixed"):
-        variants = (roofline.get("decode_multi")
-                    or roofline.get("decode") or {})
-        v = _median_variant(variants)
-        if v is not None:
-            per_burst = max(batch_size, 1) * max(decode_steps, 1)
-            bursts = max(1, -(-decode_tokens // per_burst))
-            flops += v["flops"] * bursts
-            bytes_ += v.get("bytes", 0.0) * bursts
-    return {"flops": flops, "bytes": bytes_}
-
-
-def attribute_step(roofline: Dict[str, Dict[str, Dict[str, float]]],
-                   *, kind: str, step_ms: float, prefill_tokens: int,
-                   decode_tokens: int, batch_size: int,
-                   decode_steps: int, ragged: bool,
-                   peak_flops: float, peak_bytes_s: float
-                   ) -> Dict[str, Any]:
-    """The per-step roofline verdict the flight recorder embeds:
-    modeled flops/bytes, MFU (achieved FLOP/s over peak), compute-vs-
-    memory-bound, and the debt — measured wall ms minus the modeled
-    roofline floor max(flops/peak_flops, bytes/peak_bw)."""
-    cost = estimate_step(
-        roofline, kind=kind, prefill_tokens=prefill_tokens,
-        decode_tokens=decode_tokens, batch_size=batch_size,
-        decode_steps=decode_steps, ragged=ragged)
-    flops, bytes_ = cost["flops"], cost["bytes"]
-    step_s = max(step_ms, 1e-6) / 1000.0
-    mfu = (flops / step_s / peak_flops) if peak_flops > 0 else 0.0
-    t_compute = flops / peak_flops if peak_flops > 0 else 0.0
-    t_memory = bytes_ / peak_bytes_s if peak_bytes_s > 0 else 0.0
-    if flops <= 0.0 and bytes_ <= 0.0:
-        bound = "unknown"
-    elif t_compute >= t_memory:
-        bound = "compute"
-    else:
-        bound = "memory"
-    modeled_ms = 1000.0 * max(t_compute, t_memory)
-    return {
-        "flops": flops,
-        "bytes": bytes_,
-        "mfu": round(mfu, 6),
-        "bound": bound,
-        "debt_ms": round(step_ms - modeled_ms, 3),
-    }
-
-
-def roofline_table(roofline: Dict[str, Dict[str, Dict[str, float]]],
-                   peak_flops: float, peak_bytes_s: float
-                   ) -> List[Dict[str, Any]]:
-    """Flattened per-(program, variant) roofline rows for the debug
-    bundle and /admin/steptrace: arithmetic intensity vs the machine's
-    ridge point decides the bound verdict per compiled program."""
-    ridge = (peak_flops / peak_bytes_s) if peak_bytes_s > 0 else 0.0
-    rows: List[Dict[str, Any]] = []
-    for prog in sorted(roofline):
-        for key in sorted(roofline[prog]):
-            v = roofline[prog][key]
-            fl = v.get("flops", 0.0)
-            by = v.get("bytes", 0.0)
-            intensity = fl / by if by > 0 else 0.0
-            rows.append({
-                "program": prog, "variant": key,
-                "flops": fl, "bytes": by,
-                "intensity": round(intensity, 3),
-                "bound": ("unknown" if fl <= 0 and by <= 0 else
-                          "compute" if intensity >= ridge else
-                          "memory"),
-            })
-    return rows
-
-
-def flush_metrics(registry, model: str, roofline, last_mfu: float,
-                  last_debt_ms: float, peak_flops: float) -> None:
-    """Scrape-time mirror of the roofline attribution into a worker
-    Registry: per-program/variant FLOPs+bytes gauges (cost_analysis-
-    derived numerators — never hardcoded) and the last step's MFU and
-    decode-debt. Same set_total/set pattern as profiler.flush_metrics."""
-    g_mfu = registry.gauge(
-        "xllm_worker_step_mfu",
-        "model FLOP utilization of the last engine step (modeled "
-        "roofline FLOPs over wall time over the configured peak — "
-        "XLLM_PEAK_FLOPS)", labelnames=("model",))
-    g_mfu.set(last_mfu, model=model)
-    registry.gauge(
-        "xllm_worker_step_debt_ms",
-        "last step's wall ms minus its modeled roofline floor "
-        "(the unattributed decode debt, now attributed)",
-        labelnames=("model",)).set(last_debt_ms, model=model)
-    g_fl = registry.gauge(
-        "xllm_worker_program_flops",
-        "cost_analysis FLOPs per compiled program variant "
-        "(captured at warmup)",
-        labelnames=("model", "program", "variant"))
-    g_by = registry.gauge(
-        "xllm_worker_program_bytes",
-        "cost_analysis bytes accessed per compiled program variant",
-        labelnames=("model", "program", "variant"))
-    for prog, variants in (roofline or {}).items():
-        for key, v in variants.items():
-            g_fl.set(v.get("flops", 0.0), model=model, program=prog,
-                     variant=key)
-            g_by.set(v.get("bytes", 0.0), model=model, program=prog,
-                     variant=key)
-    registry.gauge(
-        "xllm_worker_peak_flops",
-        "peak FLOP/s the MFU series is normalized by "
-        "(XLLM_PEAK_FLOPS or the device-kind table)").set(peak_flops)

@@ -198,7 +198,7 @@ def build_timeline(*, service_id: str,
             args = {k: rec.get(k) for k in
                     ("seq", "kind", "model", "prefill_tokens",
                      "decode_tokens", "attn_dispatches", "ragged",
-                     "mfu", "bound", "debt_ms")
+                     "compiled")
                     if k in rec}
             events.append({
                 "ph": "X", "pid": pid, "tid": 1, "ts": ts,

@@ -44,6 +44,7 @@ from jax.experimental.layout import Format, Layout
 
 from xllm_service_tpu.config import EngineConfig, ModelConfig
 from xllm_service_tpu.models import transformer
+from xllm_service_tpu.obs import steptrace
 from xllm_service_tpu.ops.sampling import (
     SamplingTensors, compute_logprobs, compute_top_logprobs, sample_tokens,
     update_counts)
@@ -126,6 +127,10 @@ class Sequence:
     # to the iteration's residual budget, and the executor must run
     # exactly the window the admit decision allocated pages for.
     sched_window: int = 0
+    # Set when the sequence first gets a slot: its queue wait ended there
+    # (a preempted or fault-reset sequence is admitted again, not counted
+    # again).
+    admitted_once: bool = False
 
     @property
     def num_prompt_tokens(self) -> int:
@@ -381,6 +386,13 @@ class Engine:
         # call). The acceptance pin for the ragged path lives on these.
         self.last_step_ragged = False
         self.last_step_attn_dispatches = 0
+        # Programs compiled AFTER warm-up in the last iteration, as
+        # "<program>:<shape key>" (the step record's ``compiled``).
+        self.last_step_compiled: List[str] = []
+        # Queue waits (ms, arrival to first slot) of the sequences
+        # admitted since the worker last drained this list into
+        # xllm_worker_queue_wait_ms.
+        self.queue_waits_ms: List[float] = []
         self.num_preemptions = 0
         # MoE capacity-drop accounting (VERDICT r2 weak #4: drops must be
         # visible). Monotonic per-engine counter of (token, expert)
@@ -417,22 +429,6 @@ class Engine:
         # warmup means a shape escaped warmup's coverage.
         self.phase_times: Dict[str, float] = collections.defaultdict(float)
         self.phase_counts: Dict[str, int] = collections.defaultdict(int)
-
-        # Roofline table (obs/steptrace.py consumes it): program →
-        # variant key → {"flops", "bytes", "tokens"}, captured at
-        # warmup via AOT ``.lower().compile().cost_analysis()``. The
-        # AOT compile does NOT share the jit's executable cache, so
-        # every capture is an extra compile — XLLM_ROOFLINE gates the
-        # whole capture and XLLM_ROOFLINE_VARIANTS caps the per-program
-        # variant count (config-time env reads, flag discipline).
-        self.roofline: Dict[str, Dict[str, Dict[str, float]]] = {}
-        self._roofline_enabled = os.environ.get(
-            "XLLM_ROOFLINE", "1").strip() not in ("0", "false", "no")
-        try:
-            self._roofline_cap = max(1, int(os.environ.get(
-                "XLLM_ROOFLINE_VARIANTS", "8")))
-        except ValueError:
-            self._roofline_cap = 8
 
     def _build_step_programs(self, kv) -> None:
         """Build the jitted step programs for pools placed like ``kv``:
@@ -545,20 +541,32 @@ class Engine:
                                        **scatter_pin)
 
     @contextlib.contextmanager
-    def _phase(self, name: str):
+    def _phase(self, name: str, **args):
+        """Bracket one phase of a step: its wall time goes to the ledger,
+        and while a device trace runs the same bracket is the span
+        ``xllm.step.<name>`` on the profiler's clock (``args``: the
+        launched program's shape key on ``*.dispatch``)."""
         t0 = time.monotonic()
         try:
-            yield
+            with steptrace.span("xllm.step.", name, **args):
+                yield
         finally:
             self.phase_times[name] += time.monotonic() - t0
             self.phase_counts[name] += 1
 
-    def _note_recompile(self, name: str, jitted, before: int) -> None:
+    def _note_recompile(self, name: str, jitted, before: int,
+                        mp: int, B: int = 0, T: int = 0) -> None:
+        """A step program's cache grew under serving: count it, and name
+        the shape that escaped warm-up with the key ``warmup`` uses
+        (``B{B}xT{T}xmp{mp}`` for prefill-shaped programs, ``mp{mp}`` for
+        decode) in the log and in the step's record."""
         after = self._jit_cache_size(jitted)
         if after > before:
             self.phase_counts[name + ".recompile"] += after - before
-            logger.warning("post-warmup compile of %s (cache %d -> %d)",
-                           name, before, after)
+            shape = f"B{B}xT{T}xmp{mp}" if T else f"mp{mp}"
+            self.last_step_compiled.append(f"{name}:{shape}")
+            logger.warning("post-warmup compile of %s:%s (cache %d -> %d)",
+                           name, shape, before, after)
 
     @staticmethod
     def _jit_cache_size(jitted) -> int:
@@ -598,33 +606,6 @@ class Engine:
                 report[name] = self._jit_cache_size(jitted)
         return report
 
-    def _roofline_capture(self, program: str, key: str, tokens: int,
-                          jitted, *args) -> None:
-        """Capture the compiler's own FLOPs/bytes for one warmup shape
-        into ``self.roofline`` via AOT ``cost_analysis()`` — the
-        numerators behind ``xllm_worker_program_flops/_bytes`` and the
-        per-step MFU/debt attribution (obs/steptrace.py) come from the
-        compiled executable, never from hand math. Best-effort by
-        design: cost_analysis is backend-dependent, and a backend that
-        won't answer must not take warmup down with it."""
-        if not self._roofline_enabled or jitted is None:
-            return
-        table = self.roofline.setdefault(program, {})
-        if key in table or len(table) >= self._roofline_cap:
-            return
-        try:
-            cost = jitted.lower(*args).compile().cost_analysis()
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0] if cost else {}
-            table[key] = {
-                "flops": float(cost.get("flops", 0.0) or 0.0),
-                "bytes": float(cost.get("bytes accessed", 0.0) or 0.0),
-                "tokens": float(max(tokens, 1)),
-            }
-        except Exception as exc:  # noqa: BLE001 — diagnostic capture
-            logger.debug("roofline capture failed for %s/%s: %s",
-                         program, key, exc)
-
     def _read_host(self, phase: str, *arrays):
         """Blocking device→host readback with split attribution.
 
@@ -641,11 +622,14 @@ class Engine:
         this as the only blocking-readback site in the step methods."""
         live = [a for a in arrays if a is not None]
         t0 = time.monotonic()
-        _start_host_copy(*live)
-        if live:
-            jax.block_until_ready(live)
+        with steptrace.span("xllm.step.", phase, ".device_wait"):
+            _start_host_copy(*live)
+            if live:
+                jax.block_until_ready(live)
         t1 = time.monotonic()
-        out = tuple(None if a is None else np.asarray(a) for a in arrays)
+        with steptrace.span("xllm.step.", phase, ".host_copy"):
+            out = tuple(None if a is None else np.asarray(a)
+                        for a in arrays)
         t2 = time.monotonic()
         self.phase_times[phase + ".device_wait"] += t1 - t0
         self.phase_counts[phase + ".device_wait"] += 1
@@ -835,6 +819,10 @@ class Engine:
         if seq.req.mm_embeds is None and not seq.req.prompt_logprobs:
             self.prefix_lookups += 1
             self.prefix_hit_tokens += cached_tokens
+        if not seq.admitted_once:
+            seq.admitted_once = True
+            self.queue_waits_ms.append(
+                1000.0 * (time.monotonic() - seq.req.arrival_time))
         seq.slot = slot
         self._slots[slot] = seq
         self._slot_sampling[slot] = seq.req.sampling
@@ -1041,6 +1029,7 @@ class Engine:
         self.last_step_decode_deferred = False
         self.last_step_ragged = False
         self.last_step_attn_dispatches = 0
+        self.last_step_compiled = []
         if self.interleave:
             outs = self._step_interleaved(outs)
         else:
@@ -1276,14 +1265,16 @@ class Engine:
                 mm_e = jnp.asarray(mm_e)
                 mm_p = jnp.asarray(mm_p)
         cache_before = self._jit_cache_size(self._jit_ragged)
-        with self._phase("ragged.dispatch"):
+        with self._phase("ragged.dispatch", program="ragged", B=B, T=T,
+                         MP=MP):
             fused, top_ids, top_lps, self.kv, mdrop = \
                 self._jit_ragged(self.params, jnp.asarray(packed),
                                  self.kv, st_f32, st_i32, key, mm_e,
                                  mm_p, None, bias_ids, bias_vals, None,
                                  T)
         self.last_step_attn_dispatches += 1
-        self._note_recompile("ragged", self._jit_ragged, cache_before)
+        self._note_recompile("ragged", self._jit_ragged, cache_before,
+                             MP, B, T)
         want_top = self._want_top(top_ids, rows)
         fused, top_ids, top_lps, mdrop = self._read_host(
             "ragged", fused,
@@ -1499,7 +1490,9 @@ class Engine:
                 mm_p = jnp.asarray(mm_p)
         jitted = self._jit_prefill_plp if plp_mode else self._jit_prefill
         cache_before = self._jit_cache_size(jitted)
-        with self._phase("prefill.dispatch"):
+        program = "prefill_plp" if plp_mode else "prefill"
+        with self._phase("prefill.dispatch", program=program, B=B, T=T,
+                         MP=MP):
             if plp_mode:
                 fused, top_ids, top_lps, self.kv, plp, mdrop = \
                     jitted(self.params, jnp.asarray(packed), self.kv,
@@ -1513,8 +1506,7 @@ class Engine:
                            st_f32, st_i32, key, mm_e, mm_p, None,
                            bias_ids, bias_vals, rope_pos, T)
         self.last_step_attn_dispatches += 1
-        self._note_recompile("prefill_plp" if plp_mode else "prefill",
-                             jitted, cache_before)
+        self._note_recompile(program, jitted, cache_before, MP, B, T)
         want_top = self._want_top(top_ids, batch)
         fused, plp, top_ids, top_lps, mdrop = self._read_host(
             "prefill", fused, plp,
@@ -1591,14 +1583,15 @@ class Engine:
                 [seq.req.sampling], 1, self.cfg.vocab_size)
             self._rng_key, key = jax.random.split(self._rng_key)
         cache_before = self._jit_cache_size(self._jit_prefill_ring)
-        with self._phase("prefill_ring.dispatch"):
+        with self._phase("prefill_ring.dispatch", program="prefill_ring",
+                         B=1, T=T, MP=MP):
             fused, top_ids, top_lps, self.kv, mdrop = \
                 self._jit_prefill_ring(
                     self.params, jnp.asarray(packed), self.kv,
                     st_f32, st_i32, key, bias_ids, bias_vals, t_len=T)
         self.last_step_attn_dispatches += 1
         self._note_recompile("prefill_ring", self._jit_prefill_ring,
-                             cache_before)
+                             cache_before, MP, 1, T)
         want_top = self._want_top(top_ids, (seq,))
         fused, top_ids, top_lps, mdrop = self._read_host(
             "prefill_ring", fused,
@@ -1657,14 +1650,15 @@ class Engine:
             packed = jnp.asarray(np.ascontiguousarray(
                 self._slot_packed[:, :_PACK_COLS + mp]))
         cache_before = self._jit_cache_size(self._jit_decode)
-        with self._phase("decode.dispatch"):
+        with self._phase("decode.dispatch", program="decode", B=B, T=1,
+                         MP=mp):
             (fused, top_ids, top_lps, self.kv, self._counts,
              mdrop) = self._jit_decode(
                     self.params, packed, self.kv,
                     st_f32, st_i32, key, self._ensure_counts(),
                     *self._ensure_bias())
         self.last_step_attn_dispatches += 1
-        self._note_recompile("decode", self._jit_decode, cache_before)
+        self._note_recompile("decode", self._jit_decode, cache_before, mp)
         want_top = self._want_top(top_ids, self.running)
         fused, top_ids, top_lps, mdrop = self._read_host(
             "decode", fused,
@@ -1824,7 +1818,8 @@ class Engine:
             self._resident = None     # handles are consumed (donated)
         self._note_burst_gap(overlapped=False)
         cache_before = self._jit_cache_size(self._jit_decode_multi)
-        with self._phase("decode_multi.dispatch"):
+        with self._phase("decode_multi.dispatch", program="decode_multi",
+                         B=B, T=N, MP=mp):
             (fused, top_ids, top_lps, self.kv, self._counts,
              mdrop, fin_tok, fin_pos) = self._jit_decode_multi(
                     self.params, dev_tok, dev_pos, self._dev_active_pt,
@@ -1832,7 +1827,7 @@ class Engine:
                     *self._ensure_bias())
         self.last_step_attn_dispatches += 1
         self._note_recompile("decode_multi", self._jit_decode_multi,
-                             cache_before)
+                             cache_before, mp)
         self.phase_counts["decode_multi.resident_hit"] += int(resident_hit)
         want_top = self._want_top(top_ids, self.running)
         _start_host_copy(fused, top_ids if want_top else None,
@@ -1854,7 +1849,11 @@ class Engine:
             return None
         next_key, key = jax.random.split(self._rng_key)
         cache_before = self._jit_cache_size(self._jit_decode_multi)
-        with self._phase("decode_multi.spec_dispatch"):
+        mp = self._dev_active_pt.shape[1] - 2
+        with self._phase("decode_multi.spec_dispatch",
+                         program="decode_multi",
+                         B=self.ecfg.max_batch_size,
+                         T=self.ecfg.decode_steps, MP=mp):
             (fused, top_ids, top_lps, self.kv, self._counts,
              mdrop, fin_tok, fin_pos) = self._jit_decode_multi(
                     self.params, burst["fin_tok"], burst["fin_pos"],
@@ -1862,7 +1861,7 @@ class Engine:
                     self._ensure_counts(), *self._ensure_bias())
         self.last_step_attn_dispatches += 1
         self._note_recompile("decode_multi", self._jit_decode_multi,
-                             cache_before)
+                             cache_before, mp)
         _start_host_copy(fused, top_ids if burst["want_top"] else None,
                          top_lps if burst["want_top"] else None)
         return {"fused": fused, "top_ids": top_ids, "top_lps": top_lps,
@@ -2320,7 +2319,8 @@ class Engine:
         seq = Sequence(req=req, tokens=list(tokens), pages=pages,
                        num_computed=len(tokens) - 1, slot=slot,
                        status=SeqStatus.RUNNING,
-                       first_token_time=time.monotonic())
+                       first_token_time=time.monotonic(),
+                       admitted_once=True)   # it queued on the prefill side
         self._by_id[req.request_id] = seq
         self.running.append(seq)
         self._slots[slot] = seq
@@ -2667,8 +2667,6 @@ class Engine:
                 jnp.zeros((B, _PREFILL_HDR + T + mp), jnp.int32),
                 self.kv, st_f32, st_i32, key, None, None, None,
                 b_ids, b_vals, warm_rp, T)
-            self._roofline_capture("prefill", f"B{B}xT{T}xmp{mp}",
-                                   B * T, self._jit_prefill, *pf_args)
             _, _, _, self.kv, _ = self._jit_prefill(*pf_args)
 
         # Decode (single + fused multi): every pow2 table width. Inactive
@@ -2698,8 +2696,6 @@ class Engine:
             if decode_widths is None or self.ecfg.decode_steps == 1:
                 dec_args = (self.params, packed, self.kv, st_f32,
                             st_i32, key, None, b_ids, b_vals)
-                self._roofline_capture("decode", f"mp{mp}", Bmax,
-                                       self._jit_decode, *dec_args)
                 *_, self.kv, _, _ = self._jit_decode(*dec_args)
             if self.ecfg.decode_steps > 1:
                 tok0 = jnp.zeros((Bmax,), jnp.int32)
@@ -2707,10 +2703,6 @@ class Engine:
                 apt0 = jnp.zeros((Bmax, 2 + mp), jnp.int32)
                 dm_args = (self.params, tok0, pos0, apt0, self.kv,
                            st_f32, st_i32, key, None, b_ids, b_vals)
-                self._roofline_capture(
-                    "decode_multi", f"mp{mp}",
-                    Bmax * self.ecfg.decode_steps,
-                    self._jit_decode_multi, *dm_args)
                 (_, _, _, self.kv, _, _, f_tok,
                  f_pos) = self._jit_decode_multi(*dm_args)
                 # Second call feeding back the returned device-resident
@@ -2748,9 +2740,6 @@ class Engine:
                                       jnp.int32),
                             self.kv, st_f32, st_i32, key, None, None,
                             None, b_ids, b_vals, None, T)
-                        self._roofline_capture(
-                            "ragged", f"B{B}xT{T}xmp{mp}", B * T,
-                            self._jit_ragged, *rg_args)
                         _, _, _, self.kv, _ = self._jit_ragged(*rg_args)
         jax.block_until_ready(jax.tree_util.tree_leaves(self.kv)[0])
         return time.monotonic() - t0

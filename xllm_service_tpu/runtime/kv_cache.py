@@ -24,6 +24,7 @@ import os
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
+from xllm_service_tpu.obs import steptrace
 from xllm_service_tpu.utils.hashing import prefix_block_hashes
 from xllm_service_tpu.utils.locks import make_lock
 
@@ -317,11 +318,12 @@ class PrefixCacheIndex:
         if not self.enable:
             return [], 0
         pages: List[int] = []
-        for h in self.block_hashes(tokens):
-            pid = self._by_hash.get(h)
-            if pid is None:
-                break
-            pages.append(pid)
+        with steptrace.span("xllm.kv.match_prefix", tokens=len(tokens)):
+            for h in self.block_hashes(tokens):
+                pid = self._by_hash.get(h)
+                if pid is None:
+                    break
+                pages.append(pid)
         # Never hand out the *entire* prompt from cache: the last token must
         # be recomputed so prefill has at least one new token to produce
         # logits from.
@@ -345,27 +347,28 @@ class PrefixCacheIndex:
             # chained hash this would compute and discard every decode
             # step of a long SWA sequence.
             return
-        hashes = self.block_hashes(tokens)
-        for i, h in enumerate(hashes):
-            if i >= len(pages):
-                break
-            pid = pages[i]
-            if not pid:
-                # NULL placeholder: a sliding-window-trimmed page
-                # (engine._swa_trim). Its content is gone — and blocks
-                # ABOVE the gap are unreachable too (match_prefix walks
-                # the chained hashes from block 0), so registering them
-                # would advertise digests the cluster's cache-aware
-                # routing could never actually hit.
-                break
-            if self._hash_of.get(pid) == h:
-                continue
-            if h in self._by_hash:
-                continue  # another sequence already owns this content
-            self._evict_mapping(pid)
-            self._by_hash[h] = pid
-            self._hash_of[pid] = h
-            self._pending_event.stored.append(h)
+        with steptrace.span("xllm.kv.register_pages", tokens=len(tokens)):
+            for i, h in enumerate(self.block_hashes(tokens)):
+                if i >= len(pages):
+                    break
+                pid = pages[i]
+                if not pid:
+                    # NULL placeholder: a sliding-window-trimmed page
+                    # (engine._swa_trim). Its content is gone — and
+                    # blocks ABOVE the gap are unreachable too
+                    # (match_prefix walks the chained hashes from block
+                    # 0), so registering them would advertise digests the
+                    # cluster's cache-aware routing could never actually
+                    # hit.
+                    break
+                if self._hash_of.get(pid) == h:
+                    continue
+                if h in self._by_hash:
+                    continue  # another sequence already owns this content
+                self._evict_mapping(pid)
+                self._by_hash[h] = pid
+                self._hash_of[pid] = h
+                self._pending_event.stored.append(h)
 
     # -- refcounting ------------------------------------------------------
     def _acquire(self, pid: int) -> None:

@@ -84,6 +84,11 @@ MODEL_DRAINING = "draining"
 _ABORT = object()
 
 
+class DeviceTraceError(RuntimeError):
+    """start_device_trace / stop_device_trace asked for in the wrong
+    state: a trace is already running, or none is."""
+
+
 class StepFaultInjected(Exception):
     """Raised by the worker.fault_step* failpoints inside the engine's
     step fault boundary — a deterministic device-plane fault for chaos
@@ -556,9 +561,10 @@ class Worker:
         self._st_spec_snap: Dict[str, Dict[str, int]] = {}
         self._st_prefix_snap: Dict[str, int] = {}
         self._st_free_pages: Dict[str, int] = {}
-        # Last roofline verdict per model, mirrored at scrape time as
-        # xllm_worker_step_mfu / xllm_worker_step_debt_ms.
-        self._st_last: Dict[str, Dict[str, float]] = {}
+        # The directory of the device trace that is running
+        # (start_device_trace), None when none is. jax's profiler allows
+        # one session a process and refuses a second itself.
+        self._devtrace_dir: Optional[str] = None
         # Highest step seq already DELIVERED on a heartbeat; committed
         # only on an acked beat (same discipline as _hb_step_cum).
         self._hb_steps_seq = 0                  # guarded-by: worker.hb
@@ -567,12 +573,9 @@ class Worker:
         # here — device enumeration is not hot-path safe — and reported
         # on GET /admin/steptrace. A query that fails raises: a worker
         # that cannot name its device must not come up calling it "cpu".
-        # The roofline peaks follow the device kind; a kind the table
-        # does not hold is an error too (obs/steptrace.py).
         self._devices = (list(mesh.devices.flat) if mesh is not None
                          else jax.devices()[:1])
         self._device_kind = self._devices[0].device_kind
-        self._peaks = steptrace.peaks_for(self._device_kind)
         # Deterministic fault injection (obs/failpoints.py): per-worker
         # so the co-located test harness can kill ONE of two in-process
         # workers; armed via XLLM_FAILPOINTS and POST /admin/failpoint.
@@ -733,6 +736,7 @@ class Worker:
         router.route("GET", "/admin/failpoints",
                      self._serve_failpoints)
         router.route("GET", "/admin/steptrace", self._serve_steptrace)
+        router.route("POST", "/admin/devtrace", self._serve_devtrace)
         self._router = router
         # Jitted embedding fns keyed by model name — a multi-model worker
         # must never run model B's params through model A's closed-over
@@ -1075,6 +1079,8 @@ class Worker:
         self._stop.set()
         self._work_event.set()
         _LOCAL_WORKERS.pop(self.name, None)
+        if self._devtrace_dir is not None:
+            self.stop_device_trace()    # the session is this worker's
         if self._addr_watch is not None:
             try:
                 self.store.cancel_watch(self._addr_watch)
@@ -1201,9 +1207,17 @@ class Worker:
                     continue
                 busy = True
                 t0 = time.monotonic()
+                # Entered here and left once the lock is held: what the
+                # loop waits behind an admission, apart from the step.
+                lock_wait = steptrace.span("xllm.loop.lock_wait")
+                lock_wait.__enter__()
                 try:
                     with self._engine_lock:
-                        outs = eng.step()
+                        lock_wait.__exit__(None, None, None)
+                        with steptrace.span(
+                                "xllm.loop.step",
+                                seq=self.steptrace.next_seq):
+                            outs = eng.step()
                 except Exception as exc:  # noqa: BLE001 — the step
                     # fault boundary (docs/ROBUSTNESS.md): contain,
                     # attribute, resume — or re-raise through the
@@ -1215,11 +1229,15 @@ class Worker:
                         raise
                     continue
                 step_ms = 1000.0 * (time.monotonic() - t0)
-                self._dispatch_outputs(rt, outs, step_ms)
-                self._flush_engine_obs(rt, step_ms)
-                self._engine_alive_gauge().set(1, model=rt.model)
+                with steptrace.span("xllm.loop.emit", tokens=sum(
+                        len(o.new_token_ids) for o in outs)):
+                    self._dispatch_outputs(rt, outs, step_ms)
+                with steptrace.span("xllm.loop.obs_flush"):
+                    self._flush_engine_obs(rt, step_ms)
+                    self._engine_alive_gauge().set(1, model=rt.model)
             if not busy:
-                self._work_event.wait(timeout=0.05)
+                with steptrace.span("xllm.loop.idle_wait"):
+                    self._work_event.wait(timeout=0.05)
                 self._work_event.clear()
 
     def _engine_alive_gauge(self):
@@ -1450,6 +1468,15 @@ class Worker:
             # Prefill-first control path ran a prompt step while decode
             # streams were live — the stall the interleaver removes.
             stall.inc(step_ms, model=m)
+        if eng.queue_waits_ms:
+            h = self.obs.histogram(
+                "xllm_worker_queue_wait_ms",
+                "arrival at the engine to the first slot in a step: "
+                "the worker's queue wait, measured where it ends",
+                labelnames=("model",))
+            for w in eng.queue_waits_ms:
+                h.observe(w, model=m)
+            eng.queue_waits_ms.clear()
         if eng.last_step_prefill_windows:
             h = self.obs.histogram(
                 "xllm_worker_prefill_quantum_tokens",
@@ -1479,8 +1506,7 @@ class Worker:
         ran (engine-loop thread; call-site gated on
         ``steptrace.enabled`` so the OFF path builds nothing). Per-step
         phase/speculation/prefix/page deltas come from snapshot-diffing
-        the engine's cumulative ledgers; the roofline verdict comes from
-        the warmup-captured cost_analysis table."""
+        the engine's cumulative ledgers."""
         eng = rt.engine
         m = rt.model
         # Phase-ms delta against the previous iteration's snapshot —
@@ -1505,17 +1531,6 @@ class Worker:
         free = int(eng.allocator.num_free)
         pages_delta = free - self._st_free_pages.get(m, free)
         self._st_free_pages[m] = free
-        peak_flops, peak_bytes_s = self._peaks
-        verdict = steptrace.attribute_step(
-            eng.roofline, kind=kind, step_ms=step_ms,
-            prefill_tokens=eng.last_step_prefill_tokens,
-            decode_tokens=eng.last_step_decode_tokens,
-            batch_size=eng.ecfg.max_batch_size,
-            decode_steps=eng.ecfg.decode_steps,
-            ragged=eng.last_step_ragged,
-            peak_flops=peak_flops, peak_bytes_s=peak_bytes_s)
-        self._st_last[m] = {"mfu": verdict["mfu"],
-                            "debt_ms": verdict["debt_ms"]}
         self.steptrace.record(
             model=m, kind=kind, step_ms=round(step_ms, 3),
             prefill_tokens=eng.last_step_prefill_tokens,
@@ -1529,9 +1544,7 @@ class Worker:
             kv_usage=round(float(lm.kv_cache_usage), 4),
             pages_delta=pages_delta,
             cache_hit_tokens=hit_delta,
-            flops=verdict["flops"], bytes=verdict["bytes"],
-            mfu=verdict["mfu"], bound=verdict["bound"],
-            debt_ms=verdict["debt_ms"])
+            compiled=tuple(eng.last_step_compiled))
 
     def _flush_overlap(self, rt: ModelRuntime) -> None:
         """Decode-pipeline overlap health: speculative-burst
@@ -1923,9 +1936,8 @@ class Worker:
 
     def _serve_steptrace(self, req: Request) -> Response:
         """The step flight recorder, raw: the ring tail (optionally
-        clipped by ``?seconds=N`` / ``?n=N``), the hot-path section
-        tail, and the warmup-captured roofline table — what the
-        master's /admin/timeline pulls and merges."""
+        clipped by ``?seconds=N`` / ``?n=N``) and the hot-path section
+        tail — what the master's /admin/timeline pulls and merges."""
         try:
             window_s = float(req.param("seconds", "0") or 0)
         except ValueError:
@@ -1935,15 +1947,6 @@ class Worker:
         except ValueError:
             n = 0
         from xllm_service_tpu.obs import profiler
-        peak_flops, peak_bytes_s = self._peaks
-        roofline: List[Dict[str, Any]] = []
-        for _m, rt in self.runtimes.items():
-            if rt.engine is None:
-                continue
-            for row in steptrace.roofline_table(
-                    rt.engine.roofline, peak_flops, peak_bytes_s):
-                row["model"] = rt.model
-                roofline.append(row)
         return Response.json({
             "name": self.name,
             "enabled": self.steptrace.enabled,
@@ -1954,23 +1957,89 @@ class Worker:
             "kv_pinned": {m: rt.engine.kv_pinned
                           for m, rt in self.runtimes.items()
                           if rt.engine is not None},
-            "peak_flops": peak_flops,
-            "peak_bytes_s": peak_bytes_s,
+            "devtrace": self._devtrace_dir,
             "steps": self.steptrace.tail(n=n, window_s=window_s),
             "sections": profiler.recent_events(window_s=window_s),
-            "roofline": roofline,
         })
+
+    def start_device_trace(self, out_dir: str,
+                           python_tracer: bool = False) -> str:
+        """Start ``jax.profiler`` in this process, the one that holds the
+        chip, writing under ``out_dir``; then switch the program's
+        ``xllm.*`` spans on (obs/steptrace.py), so that they land on the
+        host plane of the same ``.xplane.pb`` as the device's
+        operations. The Python tracer stays off unless asked for: it
+        slows the very host whose gaps the trace is read for. One
+        session at a time: a second start raises ``DeviceTraceError``."""
+        if self._devtrace_dir is not None:
+            raise DeviceTraceError(
+                f"a device trace is already running "
+                f"(into {self._devtrace_dir})")
+        options = jax.profiler.ProfileOptions()
+        if not python_tracer:
+            options.python_tracer_level = 0
+        try:
+            jax.profiler.start_trace(out_dir, profiler_options=options)
+        except RuntimeError as e:   # another session of this process
+            raise DeviceTraceError(str(e)) from e
+        self._devtrace_dir = out_dir
+        steptrace.set_spans(True)
+        return out_dir
+
+    def stop_device_trace(self) -> str:
+        """Switch the spans off, stop the profiler (it writes the trace
+        now, which can take seconds) and return the directory."""
+        if self._devtrace_dir is None:
+            raise DeviceTraceError("no device trace is running")
+        steptrace.set_spans(False)
+        out_dir, self._devtrace_dir = self._devtrace_dir, None
+        jax.profiler.stop_trace()
+        return out_dir
+
+    def _serve_devtrace(self, req: Request) -> Response:
+        """``{"action": "start", "dir": ..., "python_tracer": false}`` or
+        ``{"action": "stop"}``: the operator's handle on
+        start_device_trace / stop_device_trace. 409 when the session's
+        state does not allow the action."""
+        try:
+            body = req.json()
+            action = body.get("action")
+        except Exception:  # noqa: BLE001 — the 400 carries the
+            # verdict straight back to the caller
+            return Response.error(400, "invalid JSON body")
+        try:
+            if action == "start":
+                if not body.get("dir"):
+                    return Response.error(400, "start needs a dir")
+                out_dir = self.start_device_trace(
+                    str(body["dir"]), bool(body.get("python_tracer")))
+            elif action == "stop":
+                out_dir = self.stop_device_trace()
+            else:
+                return Response.error(
+                    400, "action must be start or stop")
+        except DeviceTraceError as e:
+            return Response.error(409, str(e))
+        return Response.json({"ok": True, "action": action,
+                              "dir": out_dir})
 
     # ------------------------------------------------------------------
     # Serving
     # ------------------------------------------------------------------
     def _parse_generate(self, body: Dict[str, Any], is_chat: bool,
                         pd_prefill: bool = False) -> "_LiveRequest":
+        srid = body.get("service_request_id") or f"req-{short_uuid()}"
+        with steptrace.span("xllm.admit", rid=srid):
+            return self._admit(body, srid, is_chat, pd_prefill)
+
+    def _admit(self, body: Dict[str, Any], srid: str, is_chat: bool,
+               pd_prefill: bool) -> "_LiveRequest":
+        """From the parsed request to its sequences enqueued in the
+        engine (the span ``xllm.admit`` on the handler's thread)."""
         model = body.get("model", self.opts.model)
         rt = self.runtimes.get(model) or self.primary_runtime()
         if rt.engine is None:
             raise RuntimeError(f"model {model} is asleep on this worker")
-        srid = body.get("service_request_id") or f"req-{short_uuid()}"
         token_ids = body.get("token_ids") or []
         if not token_ids:
             # Direct-to-worker use (no service in front): tokenize here.
@@ -2107,25 +2176,32 @@ class Worker:
                     marked_rids = list(live.engine_rids)
             else:
                 marked_rids = list(live.engine_rids)
+        # As in the engine loop: the wait for the lock apart from the
+        # stretch that holds it (which the loop's lock_wait sees).
+        lock_wait = steptrace.span("xllm.admit.lock_wait", rid=srid)
+        lock_wait.__enter__()
         with self._engine_lock:
-            self._fault_marked.update(marked_rids)
-            for k, erid in enumerate(live.engine_rids):
-                esp = engine_sampling
-                if n > 1:
-                    # Distinct choices: seeded requests offset the seed per
-                    # choice (identical streams otherwise), engine ids get
-                    # a #k suffix.
-                    esp = dataclasses.replace(
-                        engine_sampling,
-                        seed=(engine_sampling.seed + k
-                              if engine_sampling.seed is not None else None))
-                creq = ereq if n == 1 else dataclasses.replace(
-                    ereq, request_id=erid, sampling=esp,
-                    token_ids=list(token_ids),
-                    # Prompt scores are candidate-independent — compute
-                    # them once (candidate 0) and share via the live.
-                    prompt_logprobs=ereq.prompt_logprobs and k == 0)
-                rt.engine.add_request(creq)
+            lock_wait.__exit__(None, None, None)
+            with steptrace.span("xllm.admit.locked", rid=srid):
+                self._fault_marked.update(marked_rids)
+                for k, erid in enumerate(live.engine_rids):
+                    esp = engine_sampling
+                    if n > 1:
+                        # Distinct choices: seeded requests offset the
+                        # seed per choice (identical streams otherwise),
+                        # engine ids get a #k suffix.
+                        seed = engine_sampling.seed
+                        esp = dataclasses.replace(
+                            engine_sampling,
+                            seed=seed + k if seed is not None else None)
+                    creq = ereq if n == 1 else dataclasses.replace(
+                        ereq, request_id=erid, sampling=esp,
+                        token_ids=list(token_ids),
+                        # Prompt scores are candidate-independent:
+                        # compute them once (candidate 0) and share via
+                        # the live.
+                        prompt_logprobs=ereq.prompt_logprobs and k == 0)
+                    rt.engine.add_request(creq)
         self._work_event.set()
         return live
 
@@ -2388,14 +2464,6 @@ class Worker:
             self._flush_phase_ledger(rt)
             self._flush_overlap(rt)
             self._flush_prefix_cache(rt)
-            # Roofline mirrors: per-program cost_analysis FLOPs/bytes
-            # (warmup-captured, never hardcoded) + the last step's MFU
-            # and decode-debt verdict.
-            last = self._st_last.get(rt.model, {})
-            steptrace.flush_metrics(
-                obs, rt.model, rt.engine.roofline,
-                last.get("mfu", 0.0), last.get("debt_ms", 0.0),
-                peak_flops=self._peaks[0])
         # Supervised-thread crash / swallowed-callback books
         # (utils/threads.py — process-global, root-labeled).
         threads.flush_metrics(obs)
