@@ -678,6 +678,7 @@ class TestPrefixReuse:
             # Fetched blocks visible on the worker plane's /metrics.
             wm = _get_text(w2.name, "/metrics")
             assert "xllm_worker_prefix_cache_fetched_blocks_total" in wm
+            assert "xllm_worker_prefix_cache_hashed_tokens_total" in wm
 
             # --- failpoint fallback ----------------------------------
             prompt_b = list(range(200, 264)) + [1, 2, 3]

@@ -399,6 +399,73 @@ def test_engine_prefix_cache_reuse():
     assert eng.prefix_cache.num_cached_pages >= 3
 
 
+def _drive_one(eng, rid, prompt, max_tokens):
+    eng.add_request(EngineRequest(
+        request_id=rid, token_ids=list(prompt),
+        sampling=SamplingParams(max_tokens=max_tokens, temperature=0.0,
+                                ignore_eos=True)))
+    return eng._by_id[rid]
+
+
+@pytest.mark.parametrize("decode_steps", [1, 4])
+def test_prefix_index_hashes_a_page_once(decode_steps):
+    """A P-page prompt decoded across two page boundaries feeds the block
+    hash P pages at admission and one page at each boundary: the cost
+    does not grow with the number of sampled tokens times the length."""
+    ps, P = 8, 2
+    eng = _tiny_engine(page_size=ps, prefill_buckets=(16, 32),
+                       decode_steps=decode_steps)
+    before = eng.prefix_cache_stats()["hashed_tokens_total"]
+    seq = _drive_one(eng, "r", range(1, P * ps + 1), 2 * ps + 3)
+    _collect(eng)
+    assert (len(seq.tokens) - 1) // ps == P + 2     # two pages filled
+    assert len(seq.page_digests) == P + 2
+    assert eng.prefix_cache_stats()["hashed_tokens_total"] - before \
+        == P * ps + 2 * ps
+    assert seq.page_digests == eng.prefix_cache.block_hashes(seq.tokens)
+
+
+@pytest.mark.parametrize("decode_steps", [1, 4])
+def test_no_call_site_rehashes_or_slices_the_token_list(decode_steps,
+                                                        monkeypatch):
+    """Per token, at a preemption, at readmission and at the finish the
+    engine hands the index the sequence's own list and digests: nothing
+    calls the from-block-0 ``block_hashes``, nothing builds
+    ``tokens[:n]``, and a readmitted sequence hashes nothing twice."""
+    ps = 8
+    eng = _tiny_engine(page_size=ps, prefill_buckets=(16, 32),
+                       decode_steps=decode_steps)
+    idx = eng.prefix_cache
+    seq = _drive_one(eng, "r", range(1, 2 * ps + 4), 2 * ps + 3)
+    calls = []
+    real = idx.register_pages
+
+    def spy(digests, tokens, num_computed, pages):
+        calls.append((digests is seq.page_digests, tokens is seq.tokens))
+        return real(digests, tokens, num_computed, pages)
+
+    def banned(*a, **kw):
+        raise AssertionError("the engine rehashed a list from block 0")
+
+    monkeypatch.setattr(idx, "register_pages", spy)
+    monkeypatch.setattr(idx, "block_hashes", banned)
+    monkeypatch.setattr(idx, "register_full_pages", banned)
+    for _ in range(6):
+        eng.step()
+    assert seq.num_generated >= 4
+    eng._preempt_seq(seq)
+    hashed = idx.hashed_tokens
+    assert hashed == len(seq.page_digests) * ps
+    while seq.num_computed < len(seq.tokens) - 1:   # readmitted, re-prefilled
+        eng.step()
+    assert idx.hashed_tokens == hashed
+    _collect(eng)
+    assert len(calls) >= seq.num_generated // decode_steps
+    assert set(calls) == {(True, True)}
+    n_full = (len(seq.tokens) - 1) // ps
+    assert idx.hashed_tokens == n_full * ps == len(seq.page_digests) * ps
+
+
 def test_online_preempts_offline():
     """With pages for only ~1 long sequence, an online arrival must preempt
     the running offline one and still complete; the offline request finishes

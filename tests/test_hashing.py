@@ -86,3 +86,34 @@ def test_out_of_range_token_ids_native_python_parity(monkeypatch):
 def test_empty_and_short():
     assert hashing.prefix_block_hashes([], 128) == []
     assert hashing.prefix_block_hashes([1, 2, 3], 128) == []
+
+
+# One block given its predecessor's digest: what a caller that keeps the
+# digests of a growing token list hashes when a block fills
+# (runtime/kv_cache.py extend_digests).
+_CHAIN_TOKENS = {
+    "in_range": [(i * 2654435761) % 50000 for i in range(5 * 64 + 9)],
+    "out_of_range": [2**31, -5, 2**40 + 3, 1, -2**31 - 1, 7] * 55,
+}
+
+
+@pytest.mark.parametrize("seed", [0, 7, 12345])
+@pytest.mark.parametrize("ids", sorted(_CHAIN_TOKENS))
+def test_chained_block_hash_block_by_block(ids, seed, monkeypatch):
+    """The single-block hash, native and pure Python, walks the same chain
+    as ``prefix_block_hashes`` over the whole list, digest for digest."""
+    if not hashing.native_available():
+        pytest.skip("native lib unavailable")
+    tokens, bs = _CHAIN_TOKENS[ids], 64
+    whole = hashing.prefix_block_hashes(tokens, bs, seed)
+    assert len(whole) == len(tokens) // bs >= 5
+    prev_n = prev_p = None
+    for b, want in enumerate(whole):
+        block = tokens[b * bs:(b + 1) * bs]
+        prev_n = hashing.chained_block_hash(block, prev_n, seed)
+        prev_p = hashing.chained_block_hash_py(block, prev_p, seed)
+        assert prev_n == prev_p == want, b
+    # with no library the bound name falls back to the same digests
+    monkeypatch.setattr(hashing, "_load_native", lambda: None)
+    assert hashing.chained_block_hash(tokens[bs:2 * bs], whole[0], seed) \
+        == whole[1]

@@ -144,6 +144,228 @@ class TestPrefixCacheIndexEdges:
 
 
 # ---------------------------------------------------------------------------
+# A page is hashed once: the per-sequence digests against the full rehash
+# ---------------------------------------------------------------------------
+
+def _register_by_full_rehash(idx: PrefixCacheIndex, tokens: List[int],
+                             pages: List[int]) -> None:
+    """Registration as it was before sequences kept their digests: every
+    call hashes ``tokens`` from block 0. The reference for
+    ``register_pages``."""
+    if pages and not pages[0]:
+        return
+    for i, h in enumerate(idx.block_hashes(tokens)):
+        if i >= len(pages):
+            break
+        pid = pages[i]
+        if not pid:
+            break
+        if idx._hash_of.get(pid) == h:
+            continue
+        if h in idx._by_hash:
+            continue
+        idx._evict_mapping(pid)
+        idx._by_hash[h] = pid
+        idx._hash_of[pid] = h
+        idx._pending_event.stored.append(h)
+
+
+def _toks(n: int, salt: int) -> List[int]:
+    return [(i * 2654435761 + salt * 40503) % 32000 for i in range(n)]
+
+
+class _Seq:
+    """What the engine's Sequence gives the index: tokens that only
+    grow, its pages, how many tokens have KV, and its digests."""
+
+    def __init__(self, prompt: List[int]) -> None:
+        self.prompt = list(prompt)
+        self.tokens = list(prompt)
+        self.pages: List[int] = []
+        self.num_computed = 0
+        self.digests: List[bytes] = []
+
+
+class _Lockstep:
+    """Every call made on two indices: ``new`` through the sequence's
+    digests, ``old`` by the full rehash. After each, both hold the same
+    mappings and have queued the same events."""
+
+    def __init__(self, ps: int, num_pages: int = 24) -> None:
+        self.ps = ps
+        self.new = PrefixCacheIndex(PageAllocator(num_pages), ps, seed=7)
+        self.old = PrefixCacheIndex(PageAllocator(num_pages), ps, seed=7)
+        self.seqs: List[_Seq] = []
+
+    def check(self) -> None:
+        assert self.new._by_hash == self.old._by_hash
+        assert self.new._hash_of == self.old._hash_of
+        assert dict(self.new._ref) == dict(self.old._ref)
+        assert list(self.new._reclaimable) == list(self.old._reclaimable)
+        a, b = self.new.drain_event(), self.old.drain_event()
+        assert (a.stored, a.removed) == (b.stored, b.removed)
+
+    def alloc(self, n: int) -> List[int]:
+        pages = self.new.alloc(n)
+        assert pages is not None and pages == self.old.alloc(n)
+        self.check()
+        return pages
+
+    def release(self, pages: List[int]) -> None:
+        self.new.release_pages(pages)
+        self.old.release_pages(pages)
+
+    def pressure(self) -> None:
+        """Take every free and every reclaimable page, and give them
+        back: all unowned mappings are evicted."""
+        self.release(self.alloc(self.new.allocator.num_free
+                                + self.new.num_reclaimable))
+
+    def admit(self, seq: _Seq, prefill: int = 0) -> int:
+        """Engine._try_admit: look the prompt up, take pages for every
+        token plus the one sampled next; ``prefill`` tokens past the
+        hit are computed (0: all of them)."""
+        if seq not in self.seqs:
+            self.seqs.append(seq)
+        hit, cached = self.new.match_prefix(seq.prompt, seq.digests)
+        assert (hit, cached) == self.old.match_prefix(seq.prompt)
+        need = -(-(len(seq.tokens) + 1) // self.ps) - len(hit)
+        seq.pages = hit + self.alloc(need)
+        seq.num_computed = cached + prefill if prefill \
+            else len(seq.tokens)
+        return cached
+
+    def register(self, seq: _Seq) -> None:
+        self.new.register_pages(seq.digests, seq.tokens, seq.num_computed,
+                                seq.pages)
+        _register_by_full_rehash(
+            self.old, seq.tokens[:seq.num_computed], seq.pages)
+        self.check()
+
+    def decode(self, seq: _Seq, toks: List[int]) -> None:
+        """Engine._append_token, once a token: the sampled one has no KV
+        yet; register, then grow the table for its write."""
+        for tok in toks:
+            seq.tokens.append(tok)
+            seq.num_computed = len(seq.tokens) - 1
+            self.register(seq)
+            need = -(-(len(seq.tokens) + 1) // self.ps) - len(seq.pages)
+            if need > 0:
+                seq.pages += self.alloc(need)
+
+    def trim(self, seq: _Seq, i: int) -> None:
+        """Engine._swa_trim of page ``i``: released, a NULL in its
+        place."""
+        self.release([seq.pages[i]])
+        seq.pages[i] = 0
+
+    def give_up(self, seq: _Seq) -> None:
+        """Engine._preempt_seq and _finish_seq: register, drop the
+        pages; the tokens and their digests stay."""
+        self.register(seq)
+        self.release([p for p in seq.pages if p])
+        seq.pages, seq.num_computed = [], 0
+
+
+def _plain(ls: _Lockstep, ps: int) -> None:
+    a = _Seq(_toks(2 * ps + ps // 2, 1))
+    assert ls.admit(a) == 0
+    ls.decode(a, _toks(2 * ps, 2))              # two pages fill
+    assert len(a.digests) == 4
+    ls.give_up(a)
+    b = _Seq(a.prompt)
+    assert ls.admit(b) == 2 * ps                # a's pages, under a's digests
+    ls.decode(b, _toks(3, 3))
+    ls.give_up(b)
+
+
+def _swa_null_lead(ls: _Lockstep, ps: int) -> None:
+    a = _Seq(_toks(3 * ps + 3, 4))
+    ls.admit(a)
+    ls.decode(a, _toks(ps // 2, 5))
+    ls.trim(a, 0)                               # the window moved on
+    ls.decode(a, _toks(ps, 6))                  # a page fills: not registered
+    assert len(ls.new._by_hash) == 3
+    ls.give_up(a)
+    b = _Seq(_toks(3 * ps + 3, 7))              # a NULL below full pages
+    ls.admit(b)
+    ls.trim(b, 1)
+    ls.decode(b, _toks(2, 8))
+    assert ls.new._hash_of.keys() >= {b.pages[0]}
+    assert b.pages[2] not in ls.new._hash_of    # unreachable above the gap
+    ls.give_up(b)
+
+
+def _shared_content(ls: _Lockstep, ps: int) -> None:
+    a, b = _Seq(_toks(2 * ps + 3, 9)), _Seq(_toks(2 * ps + 3, 9))
+    ls.admit(a)
+    ls.admit(b)                                 # both miss: pages of their own
+    ls.decode(a, _toks(1, 10))                  # a owns the two digests
+    ls.decode(b, _toks(1, 11))                  # b's copies stay unregistered
+    assert not set(b.pages) & set(ls.new._hash_of)
+    for k in range(ps):                         # a third page each, by turns
+        ls.decode(a, _toks(1, 100 + k))
+        ls.decode(b, _toks(1, 200 + k))
+    assert b.pages[2] in ls.new._hash_of
+    ls.give_up(a)
+    ls.give_up(b)
+
+
+def _preempt_reregister(ls: _Lockstep, ps: int) -> None:
+    a = _Seq(_toks(ps + ps // 2, 12))
+    ls.admit(a)
+    ls.decode(a, _toks(ps, 13))                 # 2 full pages, registered
+    before = list(a.pages)
+    ls.give_up(a)                               # preempted
+    held = ls.alloc(3)                          # its old pages are not next
+    ls.pressure()                               # and their content is gone
+    assert ls.new.num_cached_pages == 0
+    hashed = ls.new.hashed_tokens
+    assert ls.admit(a, prefill=ps // 2) == 0    # a first window only
+    assert a.pages[:2] != before[:2]
+    ls.register(a)                              # preempted again mid-prefill
+    assert ls.new.num_cached_pages == 0         # (no page is full yet)
+    a.num_computed = len(a.tokens)              # the remaining windows
+    ls.decode(a, _toks(ps, 14))                 # and another page fills
+    assert ls.new.hashed_tokens == hashed + ps  # only that one was hashed
+    assert [ls.new._by_hash[h] for h in a.digests] == a.pages[:3]
+    ls.release(held)
+    ls.give_up(a)
+
+
+def _evict_other_owner(ls: _Lockstep, ps: int) -> None:
+    a, b = _Seq(_toks(2 * ps + 3, 15)), _Seq(_toks(2 * ps + 3, 15))
+    ls.admit(a)
+    ls.admit(b)
+    ls.decode(b, _toks(1, 16))                  # b owns the shared content
+    ls.decode(a, _toks(2, 17))
+    ls.give_up(b)                               # b's pages: cached, unowned
+    ls.decode(a, _toks(2, 18))
+    assert not set(a.pages) & set(ls.new._hash_of)
+    ls.pressure()                               # b's mappings evicted
+    ls.decode(a, _toks(1, 19))                  # a's next call takes them over
+    assert [ls.new._by_hash[h] for h in a.digests] == a.pages[:2]
+    ls.give_up(a)
+
+
+@pytest.mark.parametrize("ps", [16, 128])
+@pytest.mark.parametrize("scenario", [
+    _plain, _swa_null_lead, _shared_content, _preempt_reregister,
+    _evict_other_owner], ids=lambda f: f.__name__.strip("_"))
+def test_sequence_digests_leave_the_index_as_the_full_rehash_does(
+        scenario, ps):
+    ls = _Lockstep(ps)
+    scenario(ls, ps)
+    # each page of each sequence went through the hash once, however
+    # many tokens were sampled; the rehash fed it many times over
+    assert ls.new.hashed_tokens == ps * sum(
+        len(s.digests) for s in ls.seqs)
+    assert ls.old.hashed_tokens > 2 * ls.new.hashed_tokens
+    for s in ls.seqs:
+        assert s.digests == ls.old.block_hashes(s.tokens)[:len(s.digests)]
+
+
+# ---------------------------------------------------------------------------
 # HostKvTier
 # ---------------------------------------------------------------------------
 
@@ -494,7 +716,7 @@ class TestEngineSpillRestore:
 
         monkeypatch.setattr(eng, "_jit_kv_scatter", exploding_scatter)
         with pytest.raises(RuntimeError, match="injected scatter"):
-            eng._restore_spilled(p1, [], 0)
+            eng._restore_spilled(p1, [], 0, [])
         # no page vanished (the alloc's pressure-reclaim may have
         # legitimately evicted a reclaimable mapping — more free pages
         # are fine, fewer accounted ones are the leak)

@@ -103,7 +103,7 @@ SPAN_NAMES: Tuple[str, ...] = (
     "xllm.loop.obs_flush",   # around Worker._flush_engine_obs
     "xllm.loop.idle_wait",   # _work_event.wait when no runtime had work
     "xllm.kv.match_prefix",  # PrefixCacheIndex.match_prefix; arg tokens
-    "xllm.kv.register_pages",  # PrefixCacheIndex.register_full_pages
+    "xllm.kv.register_pages",  # PrefixCacheIndex.register_pages; arg tokens
     "xllm.admit",            # handler thread: parsed request -> enqueued
     "xllm.admit.lock_wait",  # ... waiting for _engine_lock
     "xllm.admit.locked",     # ... holding it (Engine.add_request)

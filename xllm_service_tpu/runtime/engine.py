@@ -131,6 +131,11 @@ class Sequence:
     # (a preempted or fault-reset sequence is admitted again, not counted
     # again).
     admitted_once: bool = False
+    # Chained digests of the first len(page_digests) full pages of
+    # ``tokens`` (kv_cache.PrefixCacheIndex.extend_digests fills it).
+    # ``tokens`` only grows, so preemption and fault reset keep them:
+    # a page is hashed once in the life of the sequence.
+    page_digests: List[bytes] = dataclasses.field(default_factory=list)
 
     @property
     def num_prompt_tokens(self) -> int:
@@ -772,7 +777,8 @@ class Engine:
             return False
         if seq.req.mm_embeds is None and not seq.req.prompt_logprobs:
             cached_pages, cached_tokens = \
-                self.prefix_cache.match_prefix(seq.req.token_ids)
+                self.prefix_cache.match_prefix(seq.req.token_ids,
+                                               seq.page_digests)
             if self.host_tier is not None \
                     and not self._ring_eligible(seq, 0):
                 # Ring-eligible prompts skip the tier restore outright:
@@ -780,7 +786,8 @@ class Engine:
                 # restore it would immediately release wastes the tier
                 # copies and a pool scatter.
                 cached_pages, cached_tokens = self._restore_spilled(
-                    seq.req.token_ids, cached_pages, cached_tokens)
+                    seq.req.token_ids, cached_pages, cached_tokens,
+                    seq.page_digests)
             if cached_tokens and self._ring_preferred(seq, cached_tokens):
                 # A cached prefix forces the chunked-window path (ring
                 # global positions start at 0). For a ring-eligible long
@@ -915,13 +922,21 @@ class Engine:
         seq.num_trimmed = bound
         self._sync_slot(seq)
 
+    def _register_pages(self, seq: Sequence) -> None:
+        """Content-address ``seq``'s full pages of computed tokens, so
+        other prompts can reuse the prefix. Called for every sampled
+        token: the index hashes a page when it fills and otherwise only
+        walks ``seq.page_digests`` against ``seq.pages``; no token list
+        is sliced or converted here."""
+        if seq.req.mm_embeds is None:
+            self.prefix_cache.register_pages(
+                seq.page_digests, seq.tokens, seq.num_computed, seq.pages)
+
     def _preempt_seq(self, seq: Sequence) -> None:
         """Recompute-style preemption: free pages, requeue (generated
         tokens are kept and re-prefilled on readmission)."""
         self._release_seq_slot(seq)
-        if seq.req.mm_embeds is None:
-            self.prefix_cache.register_full_pages(
-                seq.tokens[:seq.num_computed], seq.pages)
+        self._register_pages(seq)
         self.prefix_cache.release_pages([p for p in seq.pages if p])
         seq.pages = []
         seq.num_trimmed = 0
@@ -988,9 +1003,7 @@ class Engine:
         # Make full pages reusable by future prompts, then drop ownership.
         # Only tokens[:num_computed] have KV resident — the final sampled
         # token was never fed, so its slot must not be content-addressed.
-        if seq.req.mm_embeds is None:
-            self.prefix_cache.register_full_pages(
-                seq.tokens[:seq.num_computed], seq.pages)
+        self._register_pages(seq)
         if seq.req.hold_after_finish and reason != FinishReason.CANCELLED:
             # PD handoff: pages stay refcounted until export_held().
             self._held[seq.req.request_id] = seq
@@ -2094,9 +2107,7 @@ class Engine:
                 if reason != FinishReason.NONE:
                     self._finish_seq(seq, reason)
                 elif seq.status == SeqStatus.RUNNING:
-                    if seq.req.mm_embeds is None:
-                        self.prefix_cache.register_full_pages(
-                            seq.tokens[:seq.num_computed], seq.pages)
+                    self._register_pages(seq)
                     self._swa_trim(seq)
             # Keep the scan's final (tokens, positions) as device-resident
             # state for the next burst. Every still-RUNNING sequence
@@ -2197,9 +2208,7 @@ class Engine:
             # register them so other prompts can reuse the prefix (only
             # computed tokens — the one just sampled has no KV yet), and
             # grow the table for the next token's KV write (may preempt).
-            if seq.req.mm_embeds is None:
-                self.prefix_cache.register_full_pages(
-                    seq.tokens[:seq.num_computed], seq.pages)
+            self._register_pages(seq)
             self._swa_trim(seq)
             self._grow_pages(seq)
         return out
@@ -2330,9 +2339,7 @@ class Engine:
         self._sync_slot(seq)
         # Migrated prefixes are content-addressed here too, so future
         # prompts on this instance reuse them.
-        if req.mm_embeds is None:
-            self.prefix_cache.register_full_pages(
-                seq.tokens[:seq.num_computed], seq.pages)
+        self._register_pages(seq)
         return True
 
     # ------------------------------------------------------------------
@@ -2352,7 +2359,7 @@ class Engine:
         return self.host_tier.put(h, k_host, v_host)
 
     def _restore_spilled(self, tokens: Sequence[int], pages: List[int],
-                         cached_tokens: int
+                         cached_tokens: int, digests: List[bytes]
                          ) -> Tuple[List[int], int]:
         """Extend an HBM prefix hit past the point where match_prefix
         stopped, walking the chain across BOTH lower sources: blocks
@@ -2365,9 +2372,11 @@ class Engine:
         are consumed (popped) before the page allocation so a
         concurrent spill's LRU overflow cannot evict one mid-restore;
         an allocation failure puts them back (the spill/restore
-        counters each tick once for that bounce — cosmetic)."""
+        counters each tick once for that bounce — cosmetic).
+        ``digests`` is the sequence's chain: match_prefix left it
+        covering ``tokens``, so nothing is hashed again here."""
         ps = self.ecfg.page_size
-        hashes = self.prefix_cache.block_hashes(tokens)
+        self.prefix_cache.extend_digests(digests, tokens, len(tokens))
         i = len(pages)
         # ("tier", hash, (k, v)) | ("hbm", hash, pid), in block order.
         # The first entry is always "tier": an HBM-registered block at
@@ -2376,16 +2385,16 @@ class Engine:
         n_tier = 0
         # Same never-the-whole-prompt rule as match_prefix: prefill
         # needs at least one new token to produce logits from.
-        while i < len(hashes) and (i + 1) * ps < len(tokens):
-            blk = self.host_tier.peek(hashes[i])
+        while (i + 1) * ps < len(tokens):
+            blk = self.host_tier.peek(digests[i])
             if blk is not None:
-                plan.append(("tier", hashes[i], blk))
+                plan.append(("tier", digests[i], blk))
                 n_tier += 1
             else:
-                pid = self.prefix_cache.page_of(hashes[i])
+                pid = self.prefix_cache.page_of(digests[i])
                 if pid is None:
                     break
-                plan.append(("hbm", hashes[i], pid))
+                plan.append(("hbm", digests[i], pid))
             i += 1
         if not n_tier:
             return pages, cached_tokens
@@ -2443,7 +2452,7 @@ class Engine:
             else:
                 chain.append(payload)
         all_pages = list(pages) + chain
-        self.prefix_cache.register_full_pages(tokens[:i * ps], all_pages)
+        self.prefix_cache.register_pages(digests, tokens, i * ps, all_pages)
         return all_pages, i * ps
 
     def export_blocks(self, hashes: List[bytes], device: bool = False
@@ -2580,6 +2589,7 @@ class Engine:
             "lookups_total": self.prefix_lookups,
             "hit_tokens_total": self.prefix_hit_tokens,
             "fetched_blocks_total": self.fetched_blocks,
+            "hashed_tokens_total": self.prefix_cache.hashed_tokens,
             "spilled_pages": tier.spilled_blocks if tier else 0,
             "restored_pages": tier.restored_blocks if tier else 0,
         }

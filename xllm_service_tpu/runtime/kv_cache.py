@@ -25,7 +25,8 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 from xllm_service_tpu.obs import steptrace
-from xllm_service_tpu.utils.hashing import prefix_block_hashes
+from xllm_service_tpu.utils.hashing import (
+    chained_block_hash, prefix_block_hashes)
 from xllm_service_tpu.utils.locks import make_lock
 
 logger = logging.getLogger(__name__)
@@ -304,23 +305,57 @@ class PrefixCacheIndex:
         # tier (event: offloaded); False/None-hook = it is gone
         # (event: removed).
         self.spill_hook: Optional[Callable[[bytes, int], bool]] = None
+        # Tokens fed to the block hash over this index's life (lookup,
+        # restore and registration alike): the engine's
+        # ``hashed_tokens_total``.
+        self.hashed_tokens = 0
 
     # -- hashing ----------------------------------------------------------
     def block_hashes(self, tokens: Sequence[int]) -> List[bytes]:
-        return prefix_block_hashes(tokens, self.page_size, self.seed)
+        """Digests of every full page of ``tokens``, hashed from block 0:
+        for a caller that keeps no digests of the list."""
+        hashes = prefix_block_hashes(tokens, self.page_size, self.seed)
+        self.hashed_tokens += len(hashes) * self.page_size
+        return hashes
+
+    def extend_digests(self, digests: List[bytes], tokens: Sequence[int],
+                       num_tokens: int) -> None:
+        """Grow ``digests`` in place to the first ``num_tokens //
+        page_size`` full pages of ``tokens``, hashing only the blocks it
+        lacks. ``digests`` belongs to one token list that only grows
+        (an engine ``Sequence``'s), so ``digest(block_i) =
+        murmur3(digest(block_{i-1}) || le32(block_i))`` never changes once
+        computed: byte-equal to ``block_hashes(tokens[:num_tokens])``."""
+        ps = self.page_size
+        have, want = len(digests), num_tokens // ps
+        if want <= have:
+            return
+        prev = digests[-1] if digests else None
+        for b in range(have, want):
+            prev = chained_block_hash(tokens[b * ps:(b + 1) * ps], prev,
+                                      self.seed)
+            digests.append(prev)
+        self.hashed_tokens += (want - have) * ps
 
     # -- lookup -----------------------------------------------------------
-    def match_prefix(self, tokens: Sequence[int]) -> Tuple[List[int], int]:
+    def match_prefix(self, tokens: Sequence[int],
+                     digests: Optional[List[bytes]] = None
+                     ) -> Tuple[List[int], int]:
         """Longest cached prefix of ``tokens`` in full-page units.
+        ``digests`` is the caller's chain over a list that starts with
+        ``tokens`` (``extend_digests``); it leaves here covering them.
 
         Returns (pages, num_cached_tokens); the pages are ref-counted for
         the caller and must be released via ``release_pages``."""
         if not self.enable:
             return [], 0
+        if digests is None:
+            digests = []
         pages: List[int] = []
         with steptrace.span("xllm.kv.match_prefix", tokens=len(tokens)):
-            for h in self.block_hashes(tokens):
-                pid = self._by_hash.get(h)
+            self.extend_digests(digests, tokens, len(tokens))
+            for i in range(len(tokens) // self.page_size):
+                pid = self._by_hash.get(digests[i])
                 if pid is None:
                     break
                 pages.append(pid)
@@ -336,21 +371,30 @@ class PrefixCacheIndex:
     # -- registration -----------------------------------------------------
     def register_full_pages(self, tokens: Sequence[int],
                             pages: Sequence[int]) -> None:
-        """Register every full page of a sequence under its chained hash.
-        ``pages[i]`` holds tokens [i*ps, (i+1)*ps). Safe to call repeatedly
-        as a sequence grows."""
+        """``register_pages`` for a caller that owns no sequence, and so
+        no digests: every full page of ``tokens`` is hashed here."""
+        self.register_pages([], tokens, len(tokens), pages)
+
+    def register_pages(self, digests: List[bytes], tokens: Sequence[int],
+                       num_computed: int, pages: Sequence[int]) -> None:
+        """Register every full page of ``tokens[:num_computed]`` under its
+        chained hash. ``pages[i]`` holds tokens [i*ps, (i+1)*ps);
+        ``digests`` is the sequence's chain (``extend_digests``), so a
+        page is hashed once, when it fills. Safe to call repeatedly as a
+        sequence grows, and again on new pages after a preemption."""
         if not self.enable:
             return
         if pages and not pages[0]:
             # Leading page already sliding-window-trimmed: nothing below
-            # is registrable (see the break below) — skip the O(len)
-            # chained hash this would compute and discard every decode
-            # step of a long SWA sequence.
+            # is registrable (see the break below), so nothing is hashed
+            # or walked every decode step of a long SWA sequence.
             return
-        with steptrace.span("xllm.kv.register_pages", tokens=len(tokens)):
-            for i, h in enumerate(self.block_hashes(tokens)):
-                if i >= len(pages):
-                    break
+        n_full = num_computed // self.page_size
+        with steptrace.span(
+                "xllm.kv.register_pages",
+                tokens=max(n_full - len(digests), 0) * self.page_size):
+            self.extend_digests(digests, tokens, num_computed)
+            for i in range(min(n_full, len(pages))):
                 pid = pages[i]
                 if not pid:
                     # NULL placeholder: a sliding-window-trimmed page
@@ -361,6 +405,7 @@ class PrefixCacheIndex:
                     # cluster's cache-aware routing could never actually
                     # hit.
                     break
+                h = digests[i]
                 if self._hash_of.get(pid) == h:
                     continue
                 if h in self._by_hash:
@@ -432,7 +477,7 @@ class PrefixCacheIndex:
                         pages: Sequence[int]) -> int:
         """Directly register hash→page mappings, positionally (the
         cross-worker adoption path, where the chain below may resolve
-        through the spill tier rather than HBM — ``register_full_pages``
+        through the spill tier rather than HBM — ``register_pages``
         would need every lead page id). Chain REACHABILITY is the
         caller's contract. Skips hashes already owned (exactly-once:
         the redundant page stays unregistered and frees on release).

@@ -1612,6 +1612,13 @@ class Worker:
             "cached-block fetch)",
             labelnames=("model",)).set_total(
             stats["fetched_blocks_total"], model=m)
+        self.obs.counter(
+            "xllm_worker_prefix_cache_hashed_tokens_total",
+            "tokens fed to the block hash by the prefix index (lookup, "
+            "restore and registration alike); over step_tokens_total it "
+            "stays near 1 while a page is hashed once",
+            labelnames=("model",)).set_total(
+            stats["hashed_tokens_total"], model=m)
 
     def _flush_phase_ledger(self, rt: ModelRuntime) -> None:
         """Mirror the engine's phase wall-time ledger + post-warmup

@@ -137,6 +137,10 @@ def _load_native() -> Optional[ctypes.CDLL]:
                 ctypes.POINTER(ctypes.c_int32), ctypes.c_int32, ctypes.c_int32,
                 ctypes.c_uint32, ctypes.c_void_p]
             lib.xllm_prefix_block_hashes.restype = ctypes.c_int32
+            lib.xllm_chained_block_hash.argtypes = [
+                ctypes.c_char_p, ctypes.c_int32, ctypes.c_char_p,
+                ctypes.c_uint32, ctypes.c_void_p]
+            lib.xllm_chained_block_hash.restype = None
             _native_lib = lib
         except OSError:
             _native_lib = None
@@ -167,6 +171,29 @@ def chained_block_hash_py(tokens: Sequence[int], prev: Optional[bytes],
     buf = (prev or b"") + struct.pack(
         f"<{len(tokens)}i", *[_as_i32(t) for t in tokens])
     return murmur3_x64_128_py(buf, seed)
+
+
+def chained_block_hash(tokens: Sequence[int], prev: Optional[bytes],
+                       seed: int = 0) -> bytes:
+    """Digest of ONE block given its predecessor's digest (None for block
+    0): ``prefix_block_hashes(...)[i]`` without rehashing blocks 0..i-1.
+    For a caller that keeps the digests of a token list that only grows
+    (runtime/kv_cache.py ``PrefixCacheIndex.extend_digests``)."""
+    lib = _load_native()
+    if lib is None:
+        return chained_block_hash_py(tokens, prev, seed)
+    if prev is not None and len(prev) != 16:
+        raise ValueError("prev is a 16-byte digest or None")
+    n = len(tokens)
+    try:
+        data = struct.pack(f"<{n}i", *tokens)
+    except struct.error:        # an id outside int32: wrap as _as_i32 does
+        data = struct.pack(f"<{n}i", *[_as_i32(t) for t in tokens])
+    out = ctypes.create_string_buffer(16)
+    # The library copies the buffer byte for byte, so le32 in is le32
+    # hashed, whatever the host's byte order.
+    lib.xllm_chained_block_hash(data, n, prev, seed & 0xFFFFFFFF, out)
+    return out.raw
 
 
 def prefix_block_hashes(tokens: Sequence[int], block_size: int,
