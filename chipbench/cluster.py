@@ -207,10 +207,18 @@ def build_worker(cell, model_dir: str, front: Dict[str, str], params):
 def enable_cache_again() -> None:
     """A single-device engine switches JAX's persistent cache off for its
     process (a cached executable drops non-default entry layouts, PR 22).
-    At ``head_dim`` 128 the pinned pool layout IS the default one, so a
-    cached program takes the pools as they are; if that ever stops being
-    true the first call fails loudly ("Layout passed to jit does not
-    match"), it cannot serve wrong answers."""
+    Where the row-major layout the engine pins IS the chip's default one
+    for the pool's shape, a cached program takes the pools as they are,
+    and the ban can be lifted: so for pools of 8 heads of 128 (the
+    Mistral cells; PR 26). It is NOT so for a latent pool, one "head" of
+    576: there the chip's default puts the page's 128 slots minor-most,
+    a program that came through the cache hands the pool back in that
+    order, and the next call fails loudly ("Layout passed to jit does
+    not match"; my chip run, PR 29); it cannot serve wrong answers.
+    Whether a configuration's step programs may come from the cache is
+    therefore its own statement (``meta.json``
+    ``step_programs_from_cache``), and ``run.py`` calls this only where
+    it says so."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
     if not jax.config.jax_enable_compilation_cache:

@@ -20,7 +20,9 @@ lower-precision control (``chipbench/check.py``).
 
 Blocks: ``embed`` -> ``layer`` x L -> ``logits``. ``layer`` works on one
 sequence [T, D] and walks its queries in blocks, so that a 16k-token
-sequence fits beside nothing else on a 16 GB chip.
+sequence fits beside nothing else on a 16 GB chip. Every layer is of the
+one kind ``"layer"`` and hands nothing on to the next: ``carry`` comes
+in and goes out as ``None``.
 """
 
 from __future__ import annotations
@@ -90,11 +92,14 @@ def _blocks(fn, x, block):
     return out.reshape(t, *out.shape[2:])
 
 
-def layer(x, lp: Dict[str, Any], cfg: Dict[str, Any],
-          mm: Callable = mm_f32, q_block: int = 512,
-          row_block: int = 2048):
-    """One decoder layer over one whole sequence x [T, D] (float32).
-    Traceable: queries and feed-forward rows go block by block."""
+def layer(x, lp: Dict[str, Any], cfg: Dict[str, Any], mm: Callable,
+          kind: str, carry, q_block: int = 512, row_block: int = 2048):
+    """One decoder layer over one whole sequence x [T, D] (float32);
+    returns ``(x, carry)``. Traceable: queries and feed-forward rows go
+    block by block."""
+    if kind != "layer" or carry is not None:
+        raise ValueError(f"a dense grouped-query decoder has one kind of "
+                         f"layer and carries nothing: got {kind!r}")
     hq = int(cfg["num_attention_heads"])
     hkv = int(cfg.get("num_key_value_heads") or hq)
     dh = int(cfg.get("head_dim") or cfg["hidden_size"] // hq)
@@ -116,7 +121,7 @@ def layer(x, lp: Dict[str, Any], cfg: Dict[str, Any],
         act = jax.nn.silu(mm(h, lp["gate_proj"])) * mm(h, lp["up_proj"])
         return xb + mm(act, lp["down_proj"])
 
-    return _blocks(ffn, x, row_block)
+    return _blocks(ffn, x, row_block), None
 
 
 def logits(x, final_norm, lm_head, cfg: Dict[str, Any],
@@ -130,5 +135,5 @@ def forward(params: Dict[str, Any], tokens, cfg: Dict[str, Any],
     tests use. ``params['layers']`` is a list of per-layer dicts."""
     x = embed(jnp.asarray(tokens), params["embed"])
     for lp in params["layers"][:n_layers]:
-        x = layer(x, lp, cfg, mm)
+        x, _ = layer(x, lp, cfg, mm, "layer", None)
     return logits(x, params["final_norm"], params["lm_head"], cfg, mm)

@@ -42,12 +42,6 @@ if ROOT not in sys.path:
 from chipbench import spec, stats, traffic  # noqa: E402
 from chipbench.cluster import log  # noqa: E402
 
-# Widths of the CPU rehearsal: every key a width, nothing else changes.
-REHEARSAL_WIDTHS = {"hidden_size": 64, "intermediate_size": 128,
-                    "num_attention_heads": 4, "num_key_value_heads": 2,
-                    "num_hidden_layers": 2, "vocab_size": 512}
-
-
 def parse(argv: Optional[List[str]]) -> argparse.Namespace:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--workload", required=True)
@@ -128,8 +122,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.override:
         cell.traffic.update(json.loads(args.override))
     if args.rehearse_cpu:
-        config.update(REHEARSAL_WIDTHS)
-        config.pop("head_dim", None)
+        # The configuration's own tiny widths (``meta.json``): every key
+        # a size of its family, nothing else changes.
+        config.update(cell.meta["rehearsal_widths"])
         cell.traffic.update(cell.traffic.get("rehearsal") or {})
 
     from chipbench import cluster
@@ -169,7 +164,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         f"{args.trace}; device {dev.device_kind} x{len(devices)}; compile "
         f"cache {cache_dir or 'off (CPU)'}")
 
-    from chipbench import check, weights
+    from chipbench import check
+    wts = spec.load_weights(cell)
     out_dir = os.path.join(ROOT, "chiprun_out", "chipbench",
                            f"{cell.name}-seed{args.seed}-t{args.trace}")
     os.makedirs(out_dir, exist_ok=True)
@@ -178,7 +174,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     try:
         # ---- set-up: everything until the first timed request --------
         marks["device"] = time.monotonic() - T_START
-        params = weights.program_tree(config, args.seed)
+        params = wts.program_tree(config, args.seed)
         jax.block_until_ready(params)
         marks["weights"] = time.monotonic() - T_START
         model_dir = cluster.write_model_dir(
@@ -191,7 +187,13 @@ def main(argv: Optional[List[str]] = None) -> int:
         worker = cluster.build_worker(cell, model_dir, front, params)
         del params
         engine = worker.runtimes[cell.config_name].engine
-        cluster.enable_cache_again()
+        # A single-device engine bans JAX's persistent cache for its
+        # process; the configuration says whether its step programs may
+        # come from the cache all the same (``cluster.enable_cache_again``
+        # says when they may).
+        from_cache = bool(cell.meta["step_programs_from_cache"])
+        if from_cache:
+            cluster.enable_cache_again()
         marks["engine"] = time.monotonic() - T_START
         shapes = traffic.warmup_shapes(cell.traffic, engine.ecfg.page_size)
         if args.rehearse_cpu:
@@ -199,6 +201,11 @@ def main(argv: Optional[List[str]] = None) -> int:
             # decode widths only and let the rest compile as it comes.
             shapes = {"prefill": [], "decode_widths":
                       shapes["decode_widths"][-1:]}
+        elif not from_cache:
+            log("the engine's ban on the persistent cache stands for this "
+                "configuration (meta.json step_programs_from_cache): the "
+                "warm-up compiles every step program, in this run and in "
+                "every other")
         else:
             done = cluster.precompile_marker(cache_dir, config,
                                              cell.traffic, shapes)
@@ -326,19 +333,28 @@ def main(argv: Optional[List[str]] = None) -> int:
         s["prompt"] = traffic.prompt_of(schedule, by_id[s["id"]])
     t_ck = time.monotonic()
     ref = spec.load_reference(cell)
-    result = check.compare(ref, config, args.seed, sample,
+    result = check.compare(ref, wts, config, args.seed, sample,
                            control=args.control or None)
     result["check_seconds"] = time.monotonic() - t_ck
     limit = args.limit if args.limit is not None else float(
         cell.meta["check"]["served_token_gap_limit"])
-    correct = check.verdict(result, limit, failed,
-                            int(ck["served_tokens"]))
+    q_limits = cell.meta["check"].get("served_token_gap_quantile_limits")
+    correct, compared = check.verdict(result, limit, failed,
+                                      int(ck["served_tokens"]), q_limits)
     if args.control and "control" in result:
         c = result["control"]
         log(f"CONTROL {c['precision']} first_choice_gap_max "
             f"{c['gap_max']:.6f} over {c['positions']} positions "
             f"({c['not_best']} not the reference's best); the program's "
             f"served_token_gap_max {result['gap_max']:.6f}")
+        ctl_gaps = [g for r in result["per_request"]
+                    for g in r["control_gaps"]]
+        for q in sorted(q_limits or {}):
+            name = check.quantile_name(q)
+            log(f"CONTROL {c['precision']} "
+                f"{name.replace('served_token', 'first_choice')} "
+                f"{check.gap_quantile(ctl_gaps, float(q)):.6f}; the "
+                f"program's {name} {compared[name]['value']:.6f}")
     with open(os.path.join(out_dir, "check.json"), "w") as f:
         json.dump({"seed": args.seed, "limit": limit, "correct": correct,
                    "failed_requests": [r for r in records
@@ -395,6 +411,9 @@ def main(argv: Optional[List[str]] = None) -> int:
             out["breakdown"] = traced["breakdown"]
     elif args.trace != 1:
         out["rehearsal"] = {k: v for k, v in e2e.items() if v is not None}
+    # Last in the line, where the driver's record of a run that is not
+    # correct keeps it: each number compared, beside its limit.
+    out["compared"] = compared
     print(json.dumps(out), flush=True)
     return 0
 

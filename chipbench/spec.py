@@ -87,21 +87,35 @@ def load_kernel_cost(name: str):
     return importlib.import_module(f"chipbench.kernel_costs.{name}")
 
 
-def load_reference(cell_or_dir) -> Any:
-    """The configuration's plain reference: ``reference.py`` beside its
-    ``config.json`` (a body of its own, or a binding to its family's
-    under ``chipbench/reference/``). Imported by path, so that a
-    configuration added later brings its own without touching a package
-    index."""
+def _load_beside_config(cell_or_dir, what: str) -> Any:
+    """``<what>.py`` beside a configuration's ``config.json``, imported by
+    path, so that a configuration added later brings its own without
+    touching a package index."""
     d = cell_or_dir if isinstance(cell_or_dir, str) else cell_or_dir.config_dir
-    path = os.path.join(d, "reference.py")
     import importlib.util
     spec = importlib.util.spec_from_file_location(
-        "chipbench_reference_" + os.path.basename(d).replace("-", "_")
-        .replace(".", "_"), path)
+        f"chipbench_{what}_" + os.path.basename(d).replace("-", "_")
+        .replace(".", "_"), os.path.join(d, what + ".py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reference(cell_or_dir) -> Any:
+    """The configuration's plain reference: ``reference.py`` beside its
+    ``config.json`` (a body of its own, or a binding to its family's
+    under ``chipbench/reference/``): ``embed``, ``layer(x, lp, cfg, mm,
+    kind, carry) -> (x, carry)``, ``logits``, ``forward``, ``mm_f32``."""
+    return _load_beside_config(cell_or_dir, "reference")
+
+
+def load_weights(cell_or_dir) -> Any:
+    """The configuration's weights from the seed: ``weights.py`` beside
+    its ``config.json`` (a body of its own, or a binding to its family's
+    under ``chipbench/weight_families/``): ``layer_kinds``,
+    ``layer_params``, ``head_params``, ``program_tree``
+    (``chipbench/weights.py`` says what each gives)."""
+    return _load_beside_config(cell_or_dir, "weights")
 
 
 def peaks_for(device_kind: str, root: str = ROOT) -> Dict[str, float]:

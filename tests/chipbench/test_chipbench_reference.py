@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from chipbench import check, spec, weights
+from chipbench.weight_families import gqa_decoder as gqa_weights
 
 CONFIG_DIRS = ["mistral-7b-v03", "mistral-7b-v01"]
 TINY = {"hidden_size": 64, "intermediate_size": 128,
@@ -31,9 +32,16 @@ def tiny_config(name, **over):
     return cfg
 
 
+def config_dir(name):
+    return os.path.join(spec.ROOT, "chipbench", "configs", name)
+
+
 def reference(name):
-    return spec.load_reference(os.path.join(spec.ROOT, "chipbench",
-                                            "configs", name))
+    return spec.load_reference(config_dir(name))
+
+
+def generator(name):
+    return spec.load_weights(config_dir(name))
 
 
 def by_layer(tree, n):
@@ -69,7 +77,7 @@ def test_reference_agrees_with_the_programs_forward(name, window):
     over = {} if window is None else {"sliding_window": window,
                                       "max_position_embeddings": 4096}
     cfg = tiny_config(name, **over)
-    tree = weights.program_tree(cfg, 11)
+    tree = generator(name).program_tree(cfg, 11)
     tokens = np.random.default_rng(0).integers(3, 300, size=40)
     got, mc = program_logits(cfg, tree, tokens)
     assert mc.sliding_window == window
@@ -85,35 +93,42 @@ def test_reference_agrees_with_the_programs_forward(name, window):
 
 
 def test_each_configuration_binds_the_one_shared_reference():
-    """One body (``chipbench/reference/gqa_decoder.py``), bound beside
-    each ``config.json``; none of it imports the program."""
+    """One body (``chipbench/reference/gqa_decoder.py``) and one
+    generator (``chipbench/weight_families/gqa_decoder.py``), bound
+    beside each ``config.json``; none of it imports the program."""
     import chipbench.reference.gqa_decoder as body
     for n in CONFIG_DIRS:
-        ref = reference(n)
+        ref, wts = reference(n), generator(n)
         assert all(getattr(ref, f) is getattr(body, f) for f in
                    ("embed", "layer", "logits", "forward", "mm_f32"))
-        src = open(os.path.join(spec.ROOT, "chipbench", "configs", n,
-                                "reference.py")).read()
-        assert "xllm_service_tpu" not in src
-    assert "xllm_service_tpu" not in open(body.__file__).read()
+        assert all(getattr(wts, f) is getattr(gqa_weights, f) for f in
+                   ("layer_kinds", "layer_params", "head_params",
+                    "program_tree"))
+        for file in ("reference.py", "weights.py"):
+            src = open(os.path.join(config_dir(n), file)).read()
+            assert "xllm_service_tpu" not in src
+    for mod in (body, gqa_weights, weights):
+        assert "xllm_service_tpu" not in open(mod.__file__).read()
 
 
 @pytest.mark.parametrize("seed", [5, 2**31 + 9])
 def test_weights_regenerate_layer_by_layer(seed):
     import jax.numpy as jnp
     cfg = tiny_config("mistral-7b-v03")
-    tree = weights.program_tree(cfg, seed)
+    wts = generator("mistral-7b-v03")
+    assert wts.layer_kinds(cfg) == ["layer"] * 3
+    tree = wts.program_tree(cfg, seed)
     key = weights.root_key(seed)
     for i in range(3):
-        for k, v in weights.layer_params(cfg, key, i).items():
+        for k, v in wts.layer_params(cfg, key, i, "layer").items():
             assert v.dtype == jnp.bfloat16
             assert (np.asarray(tree["layers"][k][i], np.float32)
                     == np.asarray(v, np.float32)).all(), (i, k)
-    head = weights.head_params(cfg, key)
+    head = wts.head_params(cfg, key)
     for k in head:
         assert (np.asarray(tree[k], np.float32)
                 == np.asarray(head[k], np.float32)).all()
-    other = weights.program_tree(cfg, seed + 1)
+    other = wts.program_tree(cfg, seed + 1)
     assert not (np.asarray(other["embed"], np.float32)
                 == np.asarray(tree["embed"], np.float32)).all()
     assert abs(float(jnp.mean(tree["final_norm"].astype(jnp.float32)))
@@ -130,12 +145,12 @@ def test_control_precision_comes_out_worse_than_the_served_one(name):
     smallest widest-gap is over three times the served type's largest."""
     import jax
     cfg = tiny_config(name, num_hidden_layers=4)
-    ref = reference(name)
+    ref, wts = reference(name), generator(name)
     P, N, R = 60, 64, 6
     served, control = [], []
     for seed in (1, 2, 3):
         rng = np.random.default_rng(seed)
-        tree = by_layer(weights.program_tree(cfg, seed), 4)
+        tree = by_layer(wts.program_tree(cfg, seed), 4)
         # Greedy decode in the served type. A sequence is padded to its
         # final length (causal: what follows a position cannot change
         # it), so that one compiled forward serves every step.
@@ -149,7 +164,7 @@ def test_control_precision_comes_out_worse_than_the_served_one(name):
             sample.append({"id": f"r{r}", "prompt": toks[:P].tolist(),
                            "token_ids": toks[P:].tolist()})
         sample[-1]["compare"] = N - 8         # the last one is cut
-        out = check.compare(ref, cfg, seed, sample, control="int8")
+        out = check.compare(ref, wts, cfg, seed, sample, control="int8")
         served.append(out["gap_max"])
         control.append(out["control"]["gap_max"])
         assert out["served_tokens"] == R * N - 8

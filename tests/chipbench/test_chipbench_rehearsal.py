@@ -77,6 +77,11 @@ def test_rehearsal_walks_the_whole_command(cell, trace):
     assert "generator lateness: median" in p.stdout
     assert "CHECK served_token_gap_max" in p.stdout
     assert "CHECK served_token_gap_max" in p.stderr.splitlines()[-3]
+    # each number compared beside its limit, as the line's last key too
+    assert list(out)[-1] == "compared"
+    assert list(out["compared"]) == [
+        "served_token_gap_max", "served_tokens_compared", "requests_failed"]
+    assert out["compared"]["served_token_gap_max"]["limit"] == 0.05
     bench = spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     device_metrics = {m["name"] for m in bench["per_layer"]
                       if m["source"] != "program_counter"}

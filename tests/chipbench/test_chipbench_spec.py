@@ -86,6 +86,13 @@ def test_cells_configs_and_what_each_reports(bench):
             ROOT, os.path.dirname(c["file"]), "meta.json"))
         assert meta["source"] == c["source"]
         assert meta["reduced"] == c["reduced"]
+        # what run.py takes from the configuration and holds no table of
+        config = spec.load_json(os.path.join(ROOT, c["file"]))
+        assert meta["rehearsal_widths"] \
+            and set(meta["rehearsal_widths"]) <= set(config)
+        assert meta["step_programs_from_cache"] in (True, False)
+        assert os.path.exists(os.path.join(
+            ROOT, os.path.dirname(c["file"]), "weights.py"))
     for name in cells:
         cell = spec.load_cell(name)
         mine = {m["name"] for m in cell.end_to_end}
