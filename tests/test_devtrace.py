@@ -113,6 +113,62 @@ def test_span_names_is_closed_and_holds_every_site():
                for n in steptrace.SPAN_NAMES)
 
 
+def test_decode_upload_is_a_span_with_one_site():
+    """The block's upload is bracketed once, inside ``decode.pack``, and
+    nowhere else."""
+    assert "xllm.step.decode.upload" in steptrace.SPAN_NAMES
+    src = open(os.path.join(PKG, "runtime", "engine.py")).read()
+    assert src.count('_phase("decode.upload")') == 1
+    run_decode = next(n for n in ast.walk(ast.parse(src))
+                      if isinstance(n, ast.FunctionDef)
+                      and n.name == "_run_decode")
+    withs = [n for n in ast.walk(run_decode) if isinstance(n, ast.With)]
+
+    def phase(w):
+        c = w.items[0].context_expr
+        return c.args[0].value if isinstance(c, ast.Call) and getattr(
+            c.func, "attr", "") == "_phase" else None
+    pack = next(w for w in withs if phase(w) == "decode.pack")
+    inner = [phase(w) for w in ast.walk(pack) if isinstance(w, ast.With)]
+    assert "decode.upload" in inner
+    # and what it brackets is the upload alone: one statement
+    up = next(w for w in withs if phase(w) == "decode.upload")
+    assert len(up.body) == 1 and "device_put" in ast.unparse(up.body[0])
+
+
+def test_idle_under_decode_upload_goes_to_it_and_not_to_the_pack():
+    """``chipbench/spans.py`` gives an idle stretch to the innermost
+    span over it: with the new phase inside ``decode.pack`` the pack's
+    idle time splits into upload and bookkeeping by itself."""
+    from chipbench import spans
+    dev, host = "/device:TPU:0", "/host:CPU"
+
+    def ev(plane, line, name, start, dur):
+        return {"plane": plane, "line": line, "name": name,
+                "start": start, "dur": dur}
+    prog = "%while.1 = (s32[], bf16[8,1,64]"
+    events = [
+        ev(dev, "XLA Modules", "jit__unknown(1)", 0, 100),
+        ev(dev, "XLA Ops", prog, 0, 100),
+        ev(dev, "XLA Modules", "jit__unknown(1)", 200, 100),
+        ev(dev, "XLA Ops", prog, 200, 100),
+        # a miss step: the device is idle 100..200 under the pack
+        # (110..180), of which the upload is 140..170
+        ev(host, "python", "xllm.loop.step", 105, 200),
+        ev(host, "python", "xllm.step.decode.pack", 110, 70),
+        ev(host, "python", "xllm.step.decode.upload", 140, 30),
+        ev(host, "python", "xllm.step.decode.dispatch", 180, 20),
+    ]
+    got = {k: round(v * 1e9) for k, v in spans.idle_by_span(events)}
+    assert got["xllm.step.decode.upload"] == 30
+    assert got["xllm.step.decode.pack"] == 40
+    assert got["xllm.step.decode.dispatch"] == 20
+    assert sum(got.values()) == 100
+    # the metric file's reduction: a mean over steps, most have none
+    assert spans.per_step_ms(events, r"^xllm\.step\.decode\.upload$",
+                             "mean_per_step") == pytest.approx(30 / 1e6)
+
+
 def test_span_off_is_one_shared_noop_and_builds_nothing(counted):
     a = steptrace.span("xllm.loop.step", seq=1)
     b = steptrace.span("xllm.step.", "decode", ".device_wait")
@@ -275,3 +331,48 @@ def test_a_compile_after_warmup_is_named_in_the_log_and_the_record(
     later = worker.steptrace.tail(since_seq=n)
     assert later and all(r["compiled"] == () for r in later)
     assert _metric(worker, "xllm_worker_recompiles_total") == len(logged)
+
+
+# ---------------------------------------------------------------------------
+# The seam the benchmark's precompile holds the decode program by
+# ---------------------------------------------------------------------------
+def test_benchmark_precompile_lowers_the_step_programs_as_they_are_served():
+    """``chipbench.cluster.precompile`` builds the step programs'
+    arguments itself (nine for ``_jit_decode``, ``E._PACK_COLS + mp``
+    columns, a ``PRNGKey(0)``) and raises if they move: a checkout's
+    first benchmark run would fail. What it lowers from uploaded
+    arguments is also the module the engine serves with carried ones
+    (their placement is stated in ``_pin``): warm-up and serving add no
+    cache entry for the width beyond the one."""
+    import jax
+    from chipbench import cluster
+    from xllm_service_tpu.config import EngineConfig, ModelConfig
+    from xllm_service_tpu.runtime import engine as E
+    from xllm_service_tpu.utils.types import SamplingParams
+    eng = E.Engine(ModelConfig.tiny(vocab_size=256), EngineConfig(
+        page_size=16, num_pages=32, max_model_len=128, max_batch_size=2,
+        prefill_buckets=(32,)))
+    mp = 2
+    shapes = {"prefill": [(1, 32, mp)], "decode_widths": [mp]}
+    assert cluster.precompile(eng, shapes, threads=1) > 0
+    B = eng.ecfg.max_batch_size
+    args = [eng.params,
+            jax.numpy.zeros((B, E._PACK_COLS + mp), jax.numpy.int32),
+            eng.kv, *eng._sampling_tensors([], B), jax.random.PRNGKey(0),
+            None, *eng._batch_bias([], B, eng.cfg.vocab_size)]
+    uploaded = eng._jit_decode.lower(*args).as_text()
+    for i in (1, 5):                    # the block and the key, carried
+        args[i] = jax.device_put(args[i], eng._carry_place)
+    assert eng._jit_decode.lower(*args).as_text() == uploaded
+    eng.warmup(prefill_shapes=shapes["prefill"], decode_widths=[mp])
+    assert eng.compile_report()["decode"] == 1
+    eng.add_request(E.EngineRequest(
+        request_id="r0", token_ids=list(range(3, 20)),
+        sampling=SamplingParams(max_tokens=8, temperature=0.0,
+                                ignore_eos=True)))
+    while eng.has_work():
+        eng.step()
+    assert eng._decode_carry[1].shape[1] == E._PACK_COLS + mp
+    assert eng.phase_counts["decode.resident_hit"] >= 5
+    assert eng.compile_report()["decode"] == 1
+    assert eng.compile_report()["prefill"] == 1

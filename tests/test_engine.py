@@ -1,6 +1,7 @@
 """Engine-level tests: allocator, prefix cache, continuous batching,
 online-over-offline preemption — all on CPU with a tiny model."""
 
+import collections
 import dataclasses
 
 import numpy as np
@@ -1099,6 +1100,293 @@ class TestDecodePipeline:
         assert on == run(False, True)
         assert len(on) == 8
         assert run(True, False) == []     # transfer gated off: no tops
+
+
+# ---------------------------------------------------------------------------
+# The single-step decode keeps its inputs on the device
+# ---------------------------------------------------------------------------
+
+# Sampled token ids of ``_carry_scenario``, recorded on the parent commit
+# (host-side ``jax.random.split`` before every decode step, the block
+# uploaded whole every step): the key the step program splits itself
+# gives the same stream, byte for byte.
+_PARENT_STREAMS = {
+    ("unseeded", None): {
+        "a": [49, 15, 14, 5, 18, 17, 25, 43, 30, 24, 33, 33, 3, 22, 36, 1, 61, 34, 19, 4, 1, 28],
+        "off": [20, 5, 54, 13, 19, 33, 8, 15, 55, 5, 58, 17, 31, 61, 49, 24, 36, 57, 22, 50],
+        "c": [32, 52, 46, 61, 57, 54, 32, 6, 18, 12, 22, 19, 32, 36],
+        "short": [63, 34, 40],
+        "x": [44, 23, 15, 7],
+    },
+    ("unseeded", 8): {
+        "a": [49, 15, 14, 5, 6, 14, 39, 43, 45, 30, 12, 49, 17, 22, 5, 15, 61, 24, 10, 19, 4, 1],
+        "off": [20, 5, 54, 13, 19, 33, 3, 61, 8, 1, 21, 43, 16, 57, 1, 32, 10, 47, 31, 43],
+        "c": [32, 52, 46, 61, 39, 50, 45, 43, 6, 55, 23, 31, 13, 19],
+        "short": [63, 34, 40],
+        "x": [7, 26, 15, 33, 11, 5],
+    },
+    ("seeded", None): {
+        "a": [58, 50, 20, 26, 4, 12, 1, 58, 21, 62, 0, 52, 55, 45, 25, 34, 4, 15, 56, 51, 51, 50],
+        "off": [9, 16, 9, 25, 30, 31, 60, 7, 49, 11, 18, 40, 39, 30, 49, 58, 44, 33, 50, 58],
+        "c": [12, 18, 5, 58, 2, 9, 49, 13, 41, 42, 53, 49, 33, 13],
+        "short": [4, 56, 2],
+        "x": [7, 13, 11, 26],
+    },
+    ("seeded", 8): {
+        "a": [58, 50, 20, 26, 58, 12, 1, 56, 7, 51, 60, 52, 55, 46, 26, 41, 39, 23, 23, 51, 23, 38],
+        "off": [9, 16, 9, 25, 58, 31, 25, 7, 44, 11, 18, 40, 58, 46, 2, 22, 37, 33, 50, 23],
+        "c": [12, 18, 5, 5, 12, 24, 49, 13, 41, 42, 5, 49, 33, 44],
+        "short": [4, 56, 2],
+        "x": [7, 13, 11, 26, 32, 3],
+    },
+}
+
+
+def _carry_scenario(sampling, window, drop_carry):
+    """Page growth across page boundaries (4-token pages), a mid-decode
+    admit, a finish, a cancel, page-pressure preemptions of the offline
+    request and, with a window, trims. ``drop_carry``: the engine is
+    made to forget what the device holds before every step, so every
+    decode step uploads its block as the parent did.
+    Returns ({rid: (tokens, logprobs, reason)}, engine, trimmed)."""
+    cfg = dataclasses.replace(ModelConfig.tiny(vocab_size=64),
+                              dtype="float32", sliding_window=window)
+    eng = Engine(cfg, EngineConfig(
+        page_size=4, num_pages=16 if window is None else 10,
+        max_model_len=64, max_batch_size=4, max_prefill_tokens=64,
+        prefill_buckets=(8, 16, 32)), seed=0)
+
+    def req(rid, prompt, n, offline=False):
+        sp = dict(max_tokens=n, ignore_eos=True)
+        if sampling == "greedy":
+            sp["temperature"] = 0.0
+        elif sampling == "unseeded":
+            sp["temperature"] = 1.0
+        else:
+            sp.update(temperature=1.0, seed=1000 + len(rid) * 7 + prompt[0])
+        return EngineRequest(request_id=rid, token_ids=list(prompt),
+                             sampling=SamplingParams(**sp), offline=offline)
+
+    feed = {1: [req("a", range(1, 7), 22),
+                req("off", range(9, 14), 20, offline=True)],
+            4: [req("c", range(3, 11), 14)],
+            6: [req("short", range(20, 23), 3),
+                req("x", range(30, 35), 30)]}
+    toks, lps, reasons = {}, {}, {}
+    step = trimmed = 0
+    while eng.has_work() or step < max(feed):
+        step += 1
+        for r in feed.get(step, ()):
+            eng.add_request(r)
+        if step == 12:
+            eng.cancel("x")
+        trimmed = max([trimmed] + [s.num_trimmed for s in eng.running])
+        if drop_carry:
+            eng._decode_carry = None
+        for out in eng.step():
+            toks.setdefault(out.request_id, []).extend(out.new_token_ids)
+            lps.setdefault(out.request_id, []).extend(out.logprobs)
+            if out.finished:
+                reasons[out.request_id] = out.finish_reason
+        assert step < 300, "engine did not drain"
+    return ({r: (toks[r], lps[r], reasons.get(r)) for r in toks}, eng,
+            trimmed)
+
+
+@pytest.mark.parametrize("window", [None, 8])
+@pytest.mark.parametrize("sampling", ["greedy", "unseeded", "seeded"])
+def test_decode_carry_streams_are_the_uploaded_ones(sampling, window):
+    """Token ids, logprobs and finish reasons do not depend on whether a
+    decode step was handed the resident block or an upload, and the
+    sampled streams are the parent commit's."""
+    kept, eng, trimmed = _carry_scenario(sampling, window, False)
+    dropped, eng_d, _ = _carry_scenario(sampling, window, True)
+    assert kept == dropped
+    # the scenario did what it says
+    assert {r: v[2] for r, v in kept.items()} == {
+        "a": FinishReason.LENGTH, "off": FinishReason.LENGTH,
+        "c": FinishReason.LENGTH, "short": FinishReason.LENGTH,
+        "x": FinishReason.CANCELLED}
+    assert eng.num_preemptions >= 1 and len(kept["x"][0]) >= 2
+    assert (trimmed > 0) == (window is not None)
+    hits = eng.phase_counts["decode.resident_hit"]
+    ups = eng.phase_counts["decode.upload"]
+    assert hits > 0 and ups > 0
+    assert hits + ups == eng.phase_counts["decode.dispatch"]
+    assert eng_d.phase_counts["decode.resident_hit"] == 0
+    assert eng_d.phase_counts["decode.upload"] == hits + ups
+    if sampling != "greedy":
+        assert {r: v[0] for r, v in kept.items()} == \
+            _PARENT_STREAMS[sampling, window]
+
+
+def _count_calls(monkeypatch, *targets):
+    """Counting wrappers over ``module.function`` pairs."""
+    calls = collections.Counter()
+
+    def wrap(mod, name):
+        real = getattr(mod, name)
+
+        def counted(*a, **kw):
+            calls[name] += 1
+            return real(*a, **kw)
+        monkeypatch.setattr(mod, name, counted)
+    for mod, name in targets:
+        wrap(mod, name)
+    return calls
+
+
+def _steady_engine(**kw):
+    d = dict(page_size=16, num_pages=32, max_model_len=128,
+             max_batch_size=4, max_prefill_tokens=64,
+             prefill_buckets=(8, 16))
+    d.update(kw)
+    return Engine(dataclasses.replace(ModelConfig.tiny(vocab_size=64),
+                                      dtype="float32"),
+                  EngineConfig(**d), seed=0)
+
+
+def _req(rid, prompt, n, temperature=1.0):
+    return EngineRequest(request_id=rid, token_ids=list(prompt),
+                         sampling=SamplingParams(
+                             max_tokens=n, temperature=temperature,
+                             ignore_eos=True))
+
+
+def _uploads_per_step(eng, steps):
+    out = []
+    for _ in range(steps):
+        before = eng.phase_counts["decode.upload"]
+        eng.step()
+        out.append(eng.phase_counts["decode.upload"] - before)
+    return out
+
+
+def test_steady_decode_steps_make_no_device_call_in_the_pack(monkeypatch):
+    """N steps between two events: no block upload, no host-side key
+    split, no other host-to-device call: sampled traffic included."""
+    eng = _steady_engine()
+    eng.add_request(_req("a", range(1, 7), 40))
+    eng.add_request(_req("b", range(2, 9), 40, temperature=0.0))
+    assert _uploads_per_step(eng, 2) == [0, 1]   # prefill; first decode
+    calls = _count_calls(monkeypatch, (jax.random, "split"),
+                         (jax, "device_put"), (jnp, "asarray"))
+    hits = eng.phase_counts["decode.resident_hit"]
+    assert _uploads_per_step(eng, 6) == [0] * 6  # 8..13 of a 16-token page
+    assert eng.phase_counts["decode.resident_hit"] == hits + 6
+    assert not calls, calls
+    assert eng._decode_carry is not None
+    dev, mirror = eng._decode_carry
+    assert np.array_equal(np.asarray(dev), mirror)
+
+
+@pytest.mark.parametrize("event", ["page_growth", "admit", "finish",
+                                   "cancel", "width"])
+def test_an_event_costs_exactly_one_upload(event):
+    eng = _steady_engine()
+    eng.add_request(_req("a", range(1, 7), 60))
+    eng.add_request(_req("b", range(2, 9), 5 if event == "finish" else 60))
+    assert _uploads_per_step(eng, 3) == [0, 1, 0]
+    if event == "page_growth":
+        # a: 6 prompt tokens + 1 from prefill + 2 decoded = 9; the step
+        # that samples token 16 grows the page for position 16
+        got = _uploads_per_step(eng, 10)
+        assert got == [0] * 6 + [0, 1, 1, 0], got   # b (7 tokens) first
+    elif event == "admit":
+        eng.add_request(_req("late", range(5, 12), 60))
+        # decode (held block), then the prefill that admits; the next
+        # decode sees the new row
+        assert _uploads_per_step(eng, 3) == [0, 1, 0]
+    elif event == "finish":
+        # b: 1 token from prefill, 2 decoded; its 5th ends it two steps on
+        assert _uploads_per_step(eng, 4) == [0, 0, 1, 0]
+    elif event == "cancel":
+        eng.cancel("b")
+        assert _uploads_per_step(eng, 2) == [1, 0]
+    else:
+        # another table width is another shape: a miss, not an error
+        wide = eng._table_width() * 2
+        eng._table_width = lambda: wide
+        assert _uploads_per_step(eng, 2) == [1, 0]
+        assert eng._decode_carry[1].shape[1] == 4 + wide
+
+
+def test_a_fault_reset_drops_the_carry():
+    eng = _steady_engine()
+    eng.add_request(_req("a", range(1, 7), 30))
+    _uploads_per_step(eng, 3)
+    assert eng._decode_carry is not None
+    eng.fault_reset(())
+    assert eng._decode_carry is None and eng._resident is None
+    toks, done = _collect(eng)
+    assert done["a"] == FinishReason.LENGTH
+
+
+def test_warmup_leaves_the_key_and_one_cache_entry_per_width():
+    """Warm-up runs the decode program with a throwaway key, and a hit
+    step, a miss step and warm-up share ONE call signature per width:
+    serving adds no cache entry (``compile_report``) and counts no
+    recompile."""
+    eng = _steady_engine(page_size=4)
+    key0 = np.asarray(eng._rng_key)
+    eng.warmup(prefill_shapes=[(2, 8, 2), (1, 8, 2)],
+               decode_widths=[2, 4, 8])
+    assert np.array_equal(np.asarray(eng._rng_key), key0)
+    assert eng._decode_carry is None
+    warm = eng.compile_report()
+    assert warm["decode"] == 3
+    eng.add_request(_req("a", range(1, 7), 20))
+    eng.add_request(_req("b", range(2, 9), 9, temperature=0.0))
+    for step in range(1, 40):
+        if step == 6:
+            eng.add_request(_req("late", range(11, 17), 5))
+        eng.step()
+    assert not eng.has_work()
+    assert eng.phase_counts["decode.resident_hit"] >= 5
+    assert eng.phase_counts["decode.upload"] >= 5
+    assert eng.compile_report() == warm
+    assert not [k for k, v in eng.phase_report().items()
+                if k.endswith(".recompile") and v]
+
+
+def test_worker_counts_block_uploads_beside_its_steps():
+    from http.client import HTTPConnection
+    import json
+
+    from xllm_service_tpu.runtime.worker import Worker, WorkerOptions
+    from xllm_service_tpu.service.coordination import InMemoryStore
+    w = Worker(WorkerOptions(model="tiny"), InMemoryStore()).start()
+
+    def http(method, path, body=None):
+        host, port = w.name.rsplit(":", 1)
+        conn = HTTPConnection(host, int(port), timeout=120)
+        try:
+            conn.request(method, path, body=body and json.dumps(body),
+                         headers={"Content-Type": "application/json"})
+            r = conn.getresponse()
+            return r.status, r.read().decode()
+        finally:
+            conn.close()
+    try:
+        assert http("POST", "/v1/completions", {
+            "model": "tiny", "prompt": "count my uploads",
+            "max_tokens": 24, "temperature": 0.0,
+            "ignore_eos": True})[0] == 200
+        text = http("GET", "/metrics")[1]
+    finally:
+        w.stop()
+
+    def total(name, where=""):
+        return sum(float(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
+                   if ln.startswith(name + "{") and where in ln)
+    eng = w.primary_runtime().engine
+    ups = eng.phase_counts["decode.upload"]
+    hits = eng.phase_counts["decode.resident_hit"]
+    assert ups >= 1 and hits >= 10
+    assert total("xllm_worker_decode_block_uploads_total") == ups
+    assert ups + hits == total("xllm_worker_steps_total",
+                               'phase="decode"') \
+        + total("xllm_worker_steps_total", 'phase="mixed"')
 
 
 # ---------------------------------------------------------------------------

@@ -184,7 +184,6 @@ class Engine:
         self.cfg = model_cfg
         self.ecfg = engine_cfg
         self.mesh = mesh
-        self._rng_key = jax.random.PRNGKey(seed)
         dtype = jnp.dtype(model_cfg.dtype)
 
         # Weights and pools are BORN where they live: made under a jit
@@ -227,6 +226,7 @@ class Engine:
             here = jax.sharding.SingleDeviceSharding(device)
             kv_place = tuple(row_major_format(x.ndim, here)
                              for x in kv_shapes)
+            self._carry_place = here
         else:
             from xllm_service_tpu.parallel.sharding import (
                 kv_cache_sharding, param_shardings, shard_params)
@@ -237,6 +237,15 @@ class Engine:
                 params = shard_params(params, mesh, model_cfg)
             kv_place = tuple(kv_cache_sharding(mesh, model_cfg)
                              for _ in kv_shapes)
+            self._carry_place = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec())
+        # The rng key and the decode block are carried from one step
+        # program into the next as device arrays, which are committed.
+        # What the host uploads in their place (here, on a block miss,
+        # in warm-up) is committed to the same placement, so that a step
+        # program sees ONE call signature whichever it is handed.
+        self._rng_key = jax.device_put(jax.random.PRNGKey(seed),
+                                       self._carry_place)
         self.kv = jax.jit(make_kv, out_shardings=kv_place)()
         if self.kv_pinned:
             for x, want in zip(self.kv, kv_place):
@@ -343,6 +352,11 @@ class Engine:
         self._last_burst_step = -1
         self._dev_active_pt: Optional[jnp.ndarray] = None
         self._active_pt_mirror: Optional[np.ndarray] = None
+        # Single-step decode carry: the block the last step program
+        # handed back (its ``next_packed``) and the host's copy of what
+        # that block holds. The next step passes the handle instead of
+        # uploading when host truth compares equal; None = upload.
+        self._decode_carry: Optional[Tuple[jnp.ndarray, np.ndarray]] = None
         # Output-token histogram [B, V] for presence/frequency penalties;
         # lives on device only while some running slot uses penalties.
         self._counts: Optional[jnp.ndarray] = None
@@ -455,13 +469,25 @@ class Engine:
         kvl = tuple(row_major_format(x.ndim, x.sharding)
                     for x in kv) if self.kv_pinned else None
 
-        def _pin(n_in: int, kv_in: int, n_out: int, kv_out: int = 3):
+        def _pin(n_in: int, kv_in: int, n_out: int, kv_out: int = 3,
+                 here_in: Tuple[int, ...] = (),
+                 here_out: Tuple[int, ...] = ()):
+            """``here_in`` / ``here_out``: the small arguments the engine
+            carries as device arrays (the rng key, the decode block),
+            stated to live on the pools' device. Left unstated, a
+            committed array lowers to another module than an uploaded
+            one, and a program compiled ahead from uploaded arguments
+            (the benchmark's precompile) would not be the one served."""
             if kvl is None:
                 return {}
             ins: List[Any] = [None] * n_in
             ins[kv_in] = kvl
             outs: List[Any] = [None] * n_out
             outs[kv_out] = kvl
+            for i in here_in:
+                ins[i] = kvl[0].sharding
+            for i in here_out:
+                outs[i] = kvl[0].sharding
             return {"in_shardings": tuple(ins),
                     "out_shardings": tuple(outs)}
 
@@ -473,7 +499,7 @@ class Engine:
                               page_aligned=aligned, kernels=self.kernels,
                               write_then_attend=self.write_then_attend),
             donate_argnums=(2,), static_argnums=(12,),
-            **_pin(12, 2, 5))
+            **_pin(12, 2, 5, here_in=(5,)))
         # echo+logprobs variant: also scores every window token. Compiled
         # on first use (rare path; the recompile counter will note it) —
         # warmup stays lean.
@@ -483,7 +509,7 @@ class Engine:
                               kernels=self.kernels,
                               write_then_attend=self.write_then_attend),
             donate_argnums=(2,), static_argnums=(12,),
-            **_pin(12, 2, 6))
+            **_pin(12, 2, 6, here_in=(5,)))
         # Ragged mixed steps: a mixed iteration packs decode rows
         # (length-1 continuation windows) and prefill windows into ONE
         # ragged batch served by ONE compiled program. It reuses the
@@ -499,7 +525,7 @@ class Engine:
                                   kernels=self.kernels,
                                   write_then_attend=True, ragged=True),
                 donate_argnums=(2,), static_argnums=(12,),
-                **_pin(12, 2, 5))
+                **_pin(12, 2, 5, here_in=(5,)))
         # Sequence-parallel ring prefill: available when the mesh has an
         # sp axis — prompts longer than the largest single-chip bucket
         # prefill in ONE sp-sharded step instead of many chunked windows.
@@ -513,11 +539,12 @@ class Engine:
             functools.partial(_decode_step, cfg=model_cfg, num_top=K,
                               kernels=self.kernels,
                               write_then_attend=self.write_then_attend),
-            donate_argnums=(2, 6), **_pin(9, 2, 6))
+            donate_argnums=(2, 6),
+            **_pin(9, 2, 8, here_in=(1, 5), here_out=(6, 7)))
         # tokens/positions (1, 2) are donated too: each burst feeds back
         # the previous burst's returned final-state handles, and a donated
         # input lets XLA alias the new final state into the same buffers.
-        multi_pin = _pin(11, 4, 8)
+        multi_pin = _pin(11, 4, 8, here_in=(7,))
         if multi_pin:
             # The burst's device-resident token/position handles flow
             # OUT (fin_tok/fin_pos) and back IN next burst; under
@@ -1658,17 +1685,33 @@ class Engine:
                 self._slot_st = self._sampling_tensors(
                     self._slot_sampling, B)
             st_f32, st_i32 = self._slot_st
-            self._rng_key, key = jax.random.split(self._rng_key)
             mp = self._table_width()
-            packed = jnp.asarray(np.ascontiguousarray(
-                self._slot_packed[:, :_PACK_COLS + mp]))
+            # Upload by value: the slot arrays above are host truth; the
+            # device already holds them when they equal what the last
+            # step program handed back (a steady step moves only token
+            # and position, by values the device computed itself). Any
+            # other change (admit, finish, page growth, trim, import,
+            # another width) compares unequal and uploads the block whole.
+            block = self._slot_packed[:, :_PACK_COLS + mp]
+            carry, self._decode_carry = self._decode_carry, None
+            if carry is not None and _same_block(carry[1], block):
+                packed, mirror = carry
+                self.phase_counts["decode.resident_hit"] += 1
+            else:
+                with self._phase("decode.upload"):
+                    packed = jax.device_put(np.ascontiguousarray(block),
+                                            self._carry_place)
+                mirror = block.copy()
         cache_before = self._jit_cache_size(self._jit_decode)
         with self._phase("decode.dispatch", program="decode", B=B, T=1,
                          MP=mp):
+            # The program splits the key itself and hands the first half
+            # back: the values of a host-side split, with no program of
+            # its own between two steps.
             (fused, top_ids, top_lps, self.kv, self._counts,
-             mdrop) = self._jit_decode(
+             mdrop, next_packed, self._rng_key) = self._jit_decode(
                     self.params, packed, self.kv,
-                    st_f32, st_i32, key, self._ensure_counts(),
+                    st_f32, st_i32, self._rng_key, self._ensure_counts(),
                     *self._ensure_bias())
         self.last_step_attn_dispatches += 1
         self._note_recompile("decode", self._jit_decode, cache_before, mp)
@@ -1679,6 +1722,12 @@ class Engine:
             top_lps if want_top else None, mdrop)
         next_tok, logprob = _split_tok_lp(fused)
         self._note_moe_dropped(mdrop)
+        # ``next_packed`` as the host can compute it: active rows took
+        # the sampled token and the next position.
+        act = mirror[:, 2] != 0
+        mirror[act, 0] = next_tok[act]
+        mirror[act, 1] += 1
+        self._decode_carry = (next_packed, mirror)
         outs: List[StepOutput] = []
         # Snapshot (seq, slot) first: _append_token may preempt a *later*
         # sequence in this list (page-growth pressure), clearing its slot
@@ -1808,9 +1857,7 @@ class Engine:
             # the device copy — page tables change every page_size tokens,
             # not every burst.
             apt_now = self._slot_packed[:, 2:_PACK_COLS + mp]
-            if (self._active_pt_mirror is None
-                    or self._active_pt_mirror.shape != apt_now.shape
-                    or not np.array_equal(self._active_pt_mirror, apt_now)):
+            if not _same_block(self._active_pt_mirror, apt_now):
                 self._active_pt_mirror = apt_now.copy()
                 self._dev_active_pt = jnp.asarray(
                     np.ascontiguousarray(apt_now))
@@ -1930,8 +1977,7 @@ class Engine:
             return False
         mp = self._active_pt_mirror.shape[1] - 2
         apt_now = self._slot_packed[:, 2:_PACK_COLS + mp]
-        return (self._active_pt_mirror.shape == apt_now.shape
-                and np.array_equal(self._active_pt_mirror, apt_now))
+        return _same_block(self._active_pt_mirror, apt_now)
 
     def _discard_spec(self, p: Dict[str, Any]) -> None:
         """Roll a speculative burst back (host bookkeeping only — the
@@ -1943,6 +1989,7 @@ class Engine:
         self.phase_counts["decode_multi.spec_rollback"] += 1
         self._counts = None
         self._resident = None
+        self._decode_carry = None
         # A rolled-back boundary is neither idle nor covered: the device
         # spent it computing the discarded burst (wasted work, counted
         # above) — exclude it from the idle ledger rather than book a
@@ -2048,6 +2095,8 @@ class Engine:
         self._counts = None
         self._slot_st = None
         self._bias = None
+        self._resident = None
+        self._decode_carry = None
         return evicted
 
     def _note_burst_gap(self, overlapped: bool) -> None:
@@ -2626,7 +2675,7 @@ class Engine:
         buckets = tuple(buckets or self.ecfg.prefill_buckets)
         Bmax = self.ecfg.max_batch_size
         budget = self.ecfg.max_prefill_tokens
-        key = jax.random.PRNGKey(0)
+        key = jax.device_put(jax.random.PRNGKey(0), self._carry_place)
         # jax.random.split AND the tuple-unpack of its result (an Array
         # __getitem__ program) are tiny jitted computations. Warmup never
         # used to run them, so the FIRST serving prefill paid their
@@ -2698,7 +2747,9 @@ class Engine:
         else:
             widths = list(decode_widths)
         for mp in widths:
-            packed = jnp.zeros((Bmax, _PACK_COLS + mp), jnp.int32)
+            packed = jax.device_put(
+                np.zeros((Bmax, _PACK_COLS + mp), np.int32),
+                self._carry_place)
             # Scoped callers ask for exactly what their schedule hits: with
             # fused bursts on, steady state is _run_decode_multi (single
             # steps only near max_model_len, which a scoped bench never
@@ -2706,7 +2757,7 @@ class Engine:
             if decode_widths is None or self.ecfg.decode_steps == 1:
                 dec_args = (self.params, packed, self.kv, st_f32,
                             st_i32, key, None, b_ids, b_vals)
-                *_, self.kv, _, _ = self._jit_decode(*dec_args)
+                *_, self.kv, _, _, _, _ = self._jit_decode(*dec_args)
             if self.ecfg.decode_steps > 1:
                 tok0 = jnp.zeros((Bmax,), jnp.int32)
                 pos0 = jnp.zeros((Bmax,), jnp.int32)
@@ -2841,6 +2892,19 @@ def _top_row(top_ids, top_lps, row: int) -> List[Dict[str, Any]]:
             for i, l in zip(ids, lps)]
 
 
+def _same_block(mirror: Optional[np.ndarray], now: np.ndarray) -> bool:
+    """Does the device still hold ``now``? ``mirror`` is the host's copy
+    of the block it holds (None: nothing); another shape is a miss.
+    Compared as bytes, which keeps the GIL: numpy's elementwise compare
+    gives it away above 500 elements (8 rows of 4 + 64 are 544), and in
+    a serving worker the first switch after a step's emit hands the
+    engine thread's next launch behind the handlers' work: 0.9 ms a step
+    on the chip (PERF.md, PR 32). Past the launch the same wait overlaps
+    the device's work."""
+    return (mirror is not None and mirror.shape == now.shape
+            and mirror.tobytes() == now.tobytes())
+
+
 def _fuse_tok_lp(tok: jnp.ndarray, lp: jnp.ndarray) -> jnp.ndarray:
     """Stack sampled token ids and their logprobs into ONE int32 block
     ([2, ...]; logprobs bitcast) so they cross device->host in a single
@@ -2924,12 +2988,19 @@ def _decode_step(params, packed, kv, st_f32, st_i32, key, counts=None,
                  bias_ids=None, bias_vals=None, *, cfg: ModelConfig,
                  num_top: int = 0, write_then_attend: bool = False,
                  kernels: bool = True):
+    """One decode iteration. Besides its results it hands back what the
+    next step needs, so that the host uploads neither: ``next_packed``
+    (``packed`` with, on active rows, the sampled token in column 0 and
+    position + 1 in column 1; everything else as given) and ``next_key``
+    (``key`` is the engine's key: split here, the second half samples,
+    the first goes back: the values of a host-side split)."""
     tokens = packed[:, 0]
     positions = packed[:, 1]
     active = packed[:, 2].astype(bool)
     rope_delta = packed[:, 3] if cfg.is_mrope else None
     page_table = packed[:, _PACK_COLS:]
     st = SamplingTensors.unpack(st_f32, st_i32)
+    next_key, key = jax.random.split(key)
     with _kernel_path(kernels):
         logits, kv, stats = transformer.forward_decode(
             params, cfg, tokens, positions, active, kv, page_table,
@@ -2943,8 +3014,10 @@ def _decode_step(params, packed, kv, st_f32, st_i32, key, counts=None,
         top_ids, top_lps = compute_top_logprobs(logits, num_top)
     if counts is not None:
         counts = update_counts(counts, tok, active)
+    next_packed = packed.at[:, 0].set(jnp.where(active, tok, tokens)) \
+        .at[:, 1].set(positions + active.astype(jnp.int32))
     return (_fuse_tok_lp(tok, lp), top_ids, top_lps, kv, counts,
-            stats["moe_dropped"])
+            stats["moe_dropped"], next_packed, next_key)
 
 
 def _decode_multi_step(params, tokens, positions, active_pt, kv, st_f32,

@@ -1429,6 +1429,15 @@ class Worker:
             "engine iterations by phase "
             "(mixed = interleaved decode+prefill)",
             labelnames=("model", "phase")).inc(1, model=m, phase=kind)
+        # Beside the step counts: 1 - uploads / (decode + mixed steps) is
+        # the share of single-step decodes that were handed the block the
+        # last step program left on the device.
+        self.obs.counter(
+            "xllm_worker_decode_block_uploads_total",
+            "single-step decode iterations that uploaded their slot "
+            "block (the others passed the one the last step handed back)",
+            labelnames=("model",)).set_total(
+            eng.phase_counts.get("decode.upload", 0), model=m)
         tok = self.obs.counter(
             "xllm_worker_step_tokens_total",
             "batch token occupancy: prompt tokens computed (prefill) / "
