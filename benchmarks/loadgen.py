@@ -102,9 +102,9 @@ def summarize_results(results: List[Optional[RequestResult]],
                       target_tpot_ms: float,
                       num_requests: Optional[int] = None) -> dict:
     """One summary dict from a batch of per-request results — the single
-    summarization path shared by open-loop ``run_load``, the closed-loop
-    ramp, and bench.py's engine-level burst section, so goodput and the
-    percentile arithmetic cannot drift between harnesses.
+    summarization path shared by open-loop ``run_load`` and the
+    closed-loop ramp, so goodput and the percentile arithmetic cannot
+    drift between harnesses.
 
     ``goodput_under_slo`` is completed req/s meeting BOTH the TTFT and
     TPOT targets (online tier only — offline is best-effort by design);

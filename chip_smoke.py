@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import http.client
 import json
 import math
@@ -941,14 +942,12 @@ def _instance_loads(bundle: Dict[str, Any]) -> Dict[str, float]:
 # ---------------------------------------------------------------------------
 
 def child_parity(rehearse: bool) -> None:
-    if rehearse:
-        os.environ["XLLM_PALLAS"] = "1"       # kernels on, interpreted
     import jax
     import numpy as np
 
     from xllm_service_tpu.config import EngineConfig, ModelConfig
     from xllm_service_tpu.models import transformer
-    from xllm_service_tpu.ops import pallas
+    from xllm_service_tpu.ops.plan import KernelPlan
     from xllm_service_tpu.utils.jaxcache import enable_compile_cache
 
     enable_compile_cache()
@@ -957,11 +956,6 @@ def child_parity(rehearse: bool) -> None:
           f"{len(jax.devices())}", flush=True)
     if dev.platform != "tpu" and not rehearse:
         raise SystemExit(f"parity needs a TPU, JAX gave {dev.platform!r}")
-    if not pallas.enabled() or pallas.default_interpret() != rehearse:
-        raise SystemExit(
-            f"kernels enabled={pallas.enabled()} "
-            f"interpret={pallas.default_interpret()}: not the path the "
-            f"chip serves by default")
 
     cfg = ModelConfig.tiny() if rehearse else ModelConfig.llama3_1b()
     ps, n_pages, steps = (16, 32, 5) if rehearse else (PAGE_SIZE, 16, 6)
@@ -973,6 +967,18 @@ def child_parity(rehearse: bool) -> None:
     # a new page. Pages are handed out in a shuffled order, so an offset
     # computed wrongly lands in another sequence's page.
     T = 2 * ps
+    # The two paths, as plans: what an engine of this geometry resolves
+    # on this device (the served path; the rehearsal states the chip's
+    # plan, interpreted), and the XLA reference.
+    on_chip = KernelPlan(decode_attn=True, kv_writers=True,
+                         write_then_attend=True)
+    served_plan = dataclasses.replace(on_chip, interpret=True) \
+        if rehearse else KernelPlan.from_env(cfg, EngineConfig(
+            page_size=ps, num_pages=n_pages, max_model_len=4 * ps,
+            prefill_buckets=(T,)))
+    print(f"served plan: {served_plan}", flush=True)
+    if dataclasses.replace(served_plan, interpret=False) != on_chip:
+        raise SystemExit("not the path the chip serves by default")
     lens = np.array([T - ps // 2 - 3, T], np.int32)
     rng = np.random.default_rng(22)
     tokens = rng.integers(3, cfg.vocab_size, (2, T)).astype(np.int32)
@@ -980,39 +986,34 @@ def child_parity(rehearse: bool) -> None:
     table = np.stack([order[:4], order[4:]])
     start, active = np.zeros(2, np.int32), np.ones(2, bool)
 
-    def path(served: bool):
-        """(prefill, decode) jitted for one path. A fresh closure per
-        path: the kernel gates are read from the environment at trace
-        time and are not part of jit's cache key."""
-        def prefill(p, t, s, n, kv, pt):
-            os.environ["XLLM_PALLAS"] = "1" if served else "0"
-            return transformer.forward_prefill(
-                p, cfg, t, s, n, kv, pt, page_aligned_prefill=True,
-                write_then_attend=served)
-
-        def decode(p, t, pos, act, kv, pt):
-            os.environ["XLLM_PALLAS"] = "1" if served else "0"
-            return transformer.forward_decode(
-                p, cfg, t, pos, act, kv, pt, write_then_attend=served)
-        return jax.jit(prefill), jax.jit(decode)
+    # One jitted forward each: the plan is a static, so part of the
+    # cache key, and each path is its own program.
+    prefill = jax.jit(transformer.forward_prefill,
+                      static_argnames=("cfg", "plan"))
+    decode = jax.jit(transformer.forward_decode,
+                     static_argnames=("cfg", "plan"))
 
     def run(served: bool, feed, table_after_prefill=None):
         """Logits of the prefill and of each decode step. ``feed`` None:
         continue greedily and return the tokens fed, too."""
-        prefill, decode = path(served)
+        plan = served_plan if served else KernelPlan()
         kv = transformer.init_kv_cache(cfg, n_pages, ps)
-        calls = prefill.lower(params, tokens, start, lens, kv,
-                              table).as_text().count("tpu_custom_call")
-        last, _, kv = prefill(params, tokens, start, lens, kv, table)
+        calls = prefill.lower(
+            params, cfg, tokens, start, lens, kv, table,
+            plan=plan).as_text().count("tpu_custom_call")
+        last, _, kv = prefill(params, cfg, tokens, start, lens, kv, table,
+                              plan=plan)
         out, fed, pos = [np.asarray(last, np.float32)], [], lens.copy()
         pt = table if table_after_prefill is None else table_after_prefill
         for i in range(steps if feed is None else len(feed)):
             tok = out[-1].argmax(-1).astype(np.int32) if feed is None \
                 else feed[i]
             if i == 0:
-                calls += decode.lower(params, tok, pos, active, kv,
-                                      pt).as_text().count("tpu_custom_call")
-            logits, kv = decode(params, tok, pos, active, kv, pt)
+                calls += decode.lower(
+                    params, cfg, tok, pos, active, kv, pt,
+                    plan=plan).as_text().count("tpu_custom_call")
+            logits, kv = decode(params, cfg, tok, pos, active, kv, pt,
+                                plan=plan)
             out.append(np.asarray(logits, np.float32))
             fed.append(tok)
             pos = pos + 1
@@ -1059,9 +1060,7 @@ def child_parity(rehearse: bool) -> None:
         return
 
     # The engine's own step programs at the serve configuration, pinned:
-    # what the compiler says they need on this device. (The gate goes
-    # back to unset first: the engine resolves it as the worker will.)
-    os.environ.pop("XLLM_PALLAS", None)
+    # what the compiler says they need on this device.
     from xllm_service_tpu.runtime.engine import Engine
     _step_memory(Engine(cfg, EngineConfig(
         page_size=PAGE_SIZE, num_pages=NUM_PAGES, max_model_len=MAX_LEN,

@@ -10,12 +10,6 @@ import os
 import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-# The CPU is in no peaks table (obs/steptrace.py: a device that is not
-# there is an error), so bench.py's utilization arithmetic, which the
-# suite runs at the tiny size, divides by these two stated overrides.
-# Round numbers: they are inputs of a CPU test, never a device metric.
-os.environ.setdefault("XLLM_PEAK_FLOPS", "1e11")
-os.environ.setdefault("XLLM_PEAK_BW_GBPS", "50")
 # One persistent compile cache for the session. The suite builds
 # hundreds of engines and CLI workers whose step programs are the same
 # few dozen; with the cache each distinct program is compiled once and

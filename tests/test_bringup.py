@@ -60,14 +60,17 @@ def test_on_tpu_lets_a_backend_failure_through(monkeypatch):
     that answer would switch the kernels off and the interpreter on."""
     import jax
 
-    from xllm_service_tpu.ops import pallas
+    from xllm_service_tpu.ops import plan
 
     def broken():
         raise RuntimeError("Unable to initialize backend 'tpu'")
     monkeypatch.setattr(jax, "devices", broken)
     monkeypatch.delenv("XLLM_PALLAS", raising=False)
     monkeypatch.delenv("XLLM_PALLAS_INTERPRET", raising=False)
-    for ask in (pallas._on_tpu, pallas.enabled, pallas.default_interpret):
+    from xllm_service_tpu.config import EngineConfig, ModelConfig
+    ecfg = EngineConfig(page_size=8, num_pages=16, max_model_len=64)
+    for ask in (plan._on_tpu, plan.default_interpret,
+                lambda: plan.KernelPlan.from_env(ModelConfig.tiny(), ecfg)):
         with pytest.raises(RuntimeError, match="Unable to initialize"):
             ask()
 
@@ -110,7 +113,7 @@ def test_chip_smoke_fails_off_the_chip():
 def test_sharded_engine_traces_the_reference_path(monkeypatch, cpu_devices):
     """A Mosaic kernel cannot be partitioned over a mesh automatically
     (tests/test_chip_compile.py shows the compiler's refusal), so an
-    engine on a mesh traces its step programs on the XLA reference path
+    engine on a mesh resolves its plan to the XLA reference everywhere,
     whatever the kernel gates say — and a single-device engine, under
     the same gates, keeps its kernels."""
     import jax
@@ -133,9 +136,13 @@ def test_sharded_engine_traces_the_reference_path(monkeypatch, cpu_devices):
 
     sharded = E.Engine(ModelConfig.tiny(), ecfg,
                        mesh=make_mesh(MeshSpec(tp=2)))
-    assert not (sharded.kernels or sharded.write_then_attend
-                or sharded.kv_pinned)
+    from xllm_service_tpu.ops.plan import KernelPlan
+    assert sharded.plan == KernelPlan(interpret=True)
+    assert not sharded.kv_pinned
     assert "pallas_call" not in decode_jaxpr(sharded)
     single = E.Engine(ModelConfig.tiny(), ecfg)
-    assert single.kernels and single.write_then_attend and single.kv_pinned
+    assert single.plan == KernelPlan(
+        decode_attn=True, kv_writers=True, write_then_attend=True,
+        interpret=True)
+    assert single.kv_pinned
     assert "pallas_call" in decode_jaxpr(single)

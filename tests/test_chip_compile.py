@@ -116,8 +116,8 @@ def _engine_decode_step(sds, monkeypatch):
     from xllm_service_tpu.models import transformer
     from xllm_service_tpu.runtime import engine as E
 
-    # The gates are read at trace time; the runtime backend is the CPU,
-    # so the test steers them as the chip would resolve them.
+    # The engine resolves its plan when it is built; the runtime backend
+    # is the CPU, so the test steers it as the chip would resolve it.
     monkeypatch.setenv("XLLM_PALLAS", "1")
     monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "0")
     cfg = dataclasses.replace(ModelConfig.llama3_1b(), num_layers=2)
@@ -153,16 +153,17 @@ def _engine_decode_step(sds, monkeypatch):
 def _tp4_forward_decode(monkeypatch):
     """One decode forward of llama3-1b (two layers) partitioned over the
     four chips of a described v5e 2x2 with the repo's own sharding rules
-    — what a ``--tp 4`` worker's engine traces: on the reference path
-    (ops/pallas ``reference_path``), because the Mosaic kernels cannot
-    be partitioned automatically and are not wrapped in shard_map yet."""
+    — what a ``--tp 4`` worker's engine traces: the reference plan
+    (ops/plan.py ``KernelPlan.from_env`` on a mesh), because the Mosaic
+    kernels cannot be partitioned automatically and are not wrapped in
+    shard_map yet."""
     import numpy as np
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-    from xllm_service_tpu.config import ModelConfig
+    from xllm_service_tpu.config import EngineConfig, ModelConfig
     from xllm_service_tpu.models import transformer
-    from xllm_service_tpu.ops import pallas
+    from xllm_service_tpu.ops.plan import KernelPlan
     from xllm_service_tpu.parallel.mesh import MESH_AXES
     from xllm_service_tpu.parallel.sharding import (
         kv_cache_sharding, param_shardings)
@@ -190,13 +191,21 @@ def _tp4_forward_decode(monkeypatch):
     args = (params, whole((B_DEC,), jnp.int32), whole((B_DEC,), jnp.int32),
             whole((B_DEC,), jnp.bool_), kv, whole((B_DEC, MP), jnp.int32))
 
+    ecfg = EngineConfig(page_size=PS, num_pages=P, max_model_len=2048,
+                        max_batch_size=B_DEC)
+    one_chip_plan = KernelPlan.from_env(cfg, ecfg)
+    mesh_plan = KernelPlan.from_env(cfg, ecfg, mesh)
+    assert one_chip_plan.decode_attn and one_chip_plan.write_then_attend \
+        and not one_chip_plan.interpret
+    assert mesh_plan == KernelPlan(page_aligned=False)
+
     def with_kernels(p, t, pos, act, kv, pt):
         return transformer.forward_decode(p, cfg, t, pos, act, kv, pt,
-                                          write_then_attend=True)
+                                          plan=one_chip_plan)
 
     def reference(p, t, pos, act, kv, pt):
-        with pallas.reference_path():
-            return transformer.forward_decode(p, cfg, t, pos, act, kv, pt)
+        return transformer.forward_decode(p, cfg, t, pos, act, kv, pt,
+                                          plan=mesh_plan)
     with pytest.raises(NotImplementedError,
                        match="cannot be automatically partitioned"):
         jax.jit(with_kernels, donate_argnums=(4,)).lower(*args).compile()

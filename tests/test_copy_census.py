@@ -14,7 +14,6 @@ Two tiers inside one file:
 import os
 
 import jax.numpy as jnp
-import pytest
 
 from tools.aot_copy_census import census_pool_copies
 
@@ -56,19 +55,26 @@ ENTRY %main (p0: bf16[2,32,64,8,64]) -> bf16[2,32,64,8,64] {
         assert census_pool_copies(hlo, POOL) == []
 
 
-@pytest.fixture()
-def census_env(monkeypatch):
-    """The kernel mix the census compiles: aliased Pallas writers +
-    XLA attention, REAL Mosaic lowering (no interpreter)."""
+def test_census_plan_is_what_an_engine_resolves(monkeypatch):
+    """The kernel mix the census compiles (``cc.census_plan``: aliased
+    Pallas writers + XLA attention, REAL Mosaic lowering) is a plan an
+    engine can be given: what XLLM_PALLAS=0 XLLM_PALLAS_KV=1
+    XLLM_PALLAS_INTERPRET=0 resolve to."""
+    import tools.aot_copy_census as cc
+    from xllm_service_tpu.config import EngineConfig, ModelConfig
+    from xllm_service_tpu.ops.plan import KernelPlan
     monkeypatch.setenv("XLLM_PALLAS_INTERPRET", "0")
     monkeypatch.setenv("XLLM_PALLAS", "0")
-    monkeypatch.setenv("XLLM_PALLAS_PREFILL", "0")
     monkeypatch.setenv("XLLM_PALLAS_KV", "1")
+    for wta in (True, False):
+        assert cc.census_plan(wta) == KernelPlan.from_env(
+            ModelConfig.tiny(),
+            EngineConfig(page_size=64, num_pages=32, max_model_len=128,
+                         prefill_buckets=(64,), write_then_attend=wta))
 
 
 class TestCensusAot:
-    def test_positive_control_undonated_writer_copies(self, aot,
-                                                      census_env):
+    def test_positive_control_undonated_writer_copies(self, aot):
         """An UN-donated aliased write forces XLA to copy both pools —
         the census must see them, or a zero result proves nothing."""
         aot_compile, sds = aot
@@ -90,13 +96,12 @@ class TestCensusAot:
         donated = aot_compile(write, args, donate_argnums=(0, 1))
         assert census_pool_copies(donated.as_text(), POOL) == []
 
-    def test_decode_step_zero_pool_copies_wta(self, aot, census_env):
+    def test_decode_step_zero_pool_copies_wta(self, aot):
         """The real (tiny-shaped, structurally identical) decode step
         with write_then_attend on: ZERO pool-sized copies anywhere in
         the optimized HLO — loop bodies and the call boundary."""
         aot_compile, _ = aot
         import tools.aot_copy_census as cc
-        cc._WTA[0] = True
         progs = cc.build_programs(tiny=True)
         fn, args, donate, pool_shape = progs["decode_single"]
         kw = cc._kv_layout_kwargs(args, donate, cc._N_OUT["decode_single"])
@@ -104,10 +109,9 @@ class TestCensusAot:
         hits = census_pool_copies(compiled.as_text(), pool_shape)
         assert hits == [], hits
 
-    def test_prefill_zero_pool_copies_wta(self, aot, census_env):
+    def test_prefill_zero_pool_copies_wta(self, aot):
         aot_compile, _ = aot
         import tools.aot_copy_census as cc
-        cc._WTA[0] = True
         progs = cc.build_programs(tiny=True)
         fn, args, donate, pool_shape = progs["prefill"]
         kw = cc._kv_layout_kwargs(args, donate, cc._N_OUT["prefill"])
@@ -115,7 +119,7 @@ class TestCensusAot:
         hits = census_pool_copies(compiled.as_text(), pool_shape)
         assert hits == [], hits
 
-    def test_ragged_zero_pool_copies(self, aot, census_env):
+    def test_ragged_zero_pool_copies(self, aot):
         """The ragged mixed-batch program (XLLM_RAGGED_ATTN): ONE
         dispatch serving decode rows + prefill windows must keep the
         prefill program's guarantees — pools donated straight through,

@@ -1390,31 +1390,34 @@ def test_worker_counts_block_uploads_beside_its_steps():
 
 
 # ---------------------------------------------------------------------------
-# Scoped bench warmup (bench.py) predicts the real schedule's programs
+# Scoped warmup (Engine.warmup prefill_shapes / decode_widths) covers a
+# schedule whose shapes the caller states
 # ---------------------------------------------------------------------------
 
 def test_scoped_warmup_covers_bench_schedule():
-    """bench.py warms only the programs its workload compiles (a step
-    program compiles in tens of seconds). This pins the shape
-    prediction to the real engine: after scoped warmup, a bench-shaped
-    run must trigger ZERO post-warmup recompiles."""
-    import bench as bench_mod
-
+    """A budgeted caller warms only the programs its workload compiles
+    (a step program compiles in tens of seconds), as the benchmark does
+    from its mix's ``warmup`` data. After a scoped warmup with the
+    schedule's shapes, the run must trigger ZERO post-warmup
+    recompiles."""
     cfg = ModelConfig.tiny(vocab_size=256)
     ecfg = EngineConfig(page_size=16, num_pages=256, max_model_len=256,
                         max_batch_size=16, max_prefill_tokens=128,
                         prefill_buckets=(32,), decode_steps=8)
     engine = Engine(cfg, ecfg, seed=0)
     batch, prompt_len, gen_len = 16, 32, 64
-    pf_shapes, widths = bench_mod.scoped_warmup_shapes(
-        ecfg, batch, prompt_len, gen_len)
-    engine.warmup(prefill_shapes=pf_shapes, decode_widths=widths)
+    # 128 prefill tokens admit 4 prompts at once; under the token budget
+    # later admissions shrink down the pow2 ladder (B 4, 2, 1) at the one
+    # bucket, table width pow2(pages(33)) = 4; contexts 33..96 walk the
+    # decode widths 4 and 8.
+    engine.warmup(prefill_shapes=[(1, 32, 4), (2, 32, 4), (4, 32, 4)],
+                  decode_widths=[4, 8])
 
     sp = SamplingParams(max_tokens=gen_len, temperature=0.0,
                        ignore_eos=True)
     for i in range(batch):
-        # Distinct prompts, as in bench.py — identical ones prefix-cache
-        # hit after the first batch and change later batch shapes.
+        # Distinct prompts: identical ones prefix-cache hit after the
+        # first batch and change later batch shapes.
         engine.add_request(EngineRequest(
             request_id=f"bench-{i}",
             token_ids=[(i + j) % (cfg.vocab_size - 1) + 1
@@ -1436,8 +1439,6 @@ def test_scoped_warmup_covers_ragged_bucket_ladder():
     combined batch × prefill bucket × table width), so the bench-shaped
     run still triggers ZERO post-warmup recompiles — and actually
     exercises the ragged program while doing so."""
-    import bench as bench_mod
-
     cfg = ModelConfig.tiny(vocab_size=256)
     ecfg = EngineConfig(page_size=16, num_pages=128, max_model_len=128,
                         max_batch_size=8, max_prefill_tokens=64,
@@ -1445,9 +1446,10 @@ def test_scoped_warmup_covers_ragged_bucket_ladder():
                         ragged_attn=True)
     engine = Engine(cfg, ecfg, seed=0)
     batch, prompt_len, gen_len = 8, 32, 24
-    pf_shapes, widths = bench_mod.scoped_warmup_shapes(
-        ecfg, batch, prompt_len, gen_len)
-    engine.warmup(prefill_shapes=pf_shapes, decode_widths=widths)
+    # 64 prefill tokens admit 2 prompts at once (then 1 under decode
+    # load); contexts 33..56 stay at table width 4.
+    engine.warmup(prefill_shapes=[(1, 32, 4), (2, 32, 4)],
+                  decode_widths=[4])
 
     sp = SamplingParams(max_tokens=gen_len, temperature=0.0,
                         ignore_eos=True)
@@ -1466,31 +1468,3 @@ def test_scoped_warmup_covers_ragged_bucket_ladder():
     recompiles = {k: v for k, v in engine.phase_report().items()
                   if k.endswith(".recompile") and v}
     assert not recompiles, f"ragged warmup missed programs: {recompiles}"
-
-
-@pytest.mark.slow
-def test_bench_reports_boot_and_recompile_provenance(monkeypatch):
-    """The bench result JSON must prove "no routed request ever pays a
-    compile" per round: boot_cold_s (init + first warmup),
-    boot_warm_s (the same sweep with every program cached —
-    dispatch-only, so cold minus warm is the compile bill warmup
-    absorbed), and recompiles_post_warmup from the engine's standing
-    counters. Marked slow (two full tiny warmups): tier-1 covers the
-    recompile invariant via test_scoped_warmup_covers_bench_schedule,
-    and bench.py itself emits these fields every round."""
-    import bench as bench_mod
-
-    monkeypatch.setenv("BENCH_TINY_GEN", "8")   # trim the decode loop
-    out = bench_mod._run_bench(tiny=True)
-    detail = out["detail"]
-    for key in ("boot_cold_s", "boot_warm_s",
-                "recompiles_post_warmup"):
-        assert key in detail, sorted(detail)
-    assert detail["boot_cold_s"] >= detail["warmup_s"] > 0
-    # Every program compiled during the cold boot: the warm re-sweep
-    # pays dispatch only.
-    assert detail["boot_warm_s"] < detail["boot_cold_s"]
-    # The tiny schedule is fully covered by full warmup — any recompile
-    # is a coverage regression (same invariant the scoped test pins).
-    assert detail["recompiles_post_warmup"] == 0
-    assert out["value"] > 0

@@ -325,7 +325,7 @@ class TestRaggedMixedStep:
         phase counters the obs flush exports, and a "ragged" entry in
         compile_report."""
         eng = Engine(MCFG, self._ecfg(ragged=True), seed=0)
-        assert eng.ragged and eng._jit_ragged is not None
+        assert eng.plan.mixed_step and eng._jit_ragged is not None
         assert "ragged" in eng.compile_report()
         eng.add_request(_req("a", range(1, 9), 16))
         hit = False
@@ -381,11 +381,18 @@ class TestRaggedMixedStep:
         assert len(on["a"]) == 8 and len(on["b"]) == 8
 
     def test_env_resolution_and_default_off(self, monkeypatch):
+        """Off by default; the variable decides for an engine built
+        under it, over the field (tests/test_kernel_plan.py holds the
+        resolver's whole table)."""
         assert self._ecfg().ragged_attn is None
         eng = Engine(MCFG, self._ecfg(), seed=0)
-        assert not eng.ragged and eng._jit_ragged is None
+        assert not eng.plan.mixed_step and eng._jit_ragged is None
         assert "ragged" not in eng.compile_report()
         monkeypatch.setenv("XLLM_RAGGED_ATTN", "1")
-        assert self._ecfg().ragged_attn is True
+        on = Engine(MCFG, self._ecfg(), seed=0, params=eng.params)
+        assert on.plan.mixed_step and on._jit_ragged is not None
         monkeypatch.setenv("XLLM_RAGGED_ATTN", "0")
-        assert self._ecfg(ragged=True).ragged_attn is False
+        off = Engine(MCFG, self._ecfg(ragged=True), seed=0,
+                     params=eng.params)
+        assert not off.plan.mixed_step and off._jit_ragged is None
+        assert eng._jit_ragged is None and on._jit_ragged is not None

@@ -443,11 +443,12 @@ class TestPagedKvUpdateKernel:
     def test_matches_xla_scatter_including_drops(self, monkeypatch):
         import numpy as np
         from xllm_service_tpu.ops import attention as att
+        from xllm_service_tpu.ops.plan import KernelPlan
         from xllm_service_tpu.ops.pallas.kv_update import paged_kv_update
-        # Pin the REFERENCE to the XLA scatter: with XLLM_PALLAS=1 in
-        # the env the helper would dispatch to the kernel under test
-        # and the comparison would be kernel-vs-itself.
-        monkeypatch.setenv("XLLM_PALLAS_KV", "0")
+        # The REFERENCE is the dispatcher under the reference plan (the
+        # XLA scatter), whatever the environment says: the plan decides,
+        # and the comparison can never be kernel-vs-itself.
+        monkeypatch.setenv("XLLM_PALLAS_KV", "1")
         rng = np.random.default_rng(0)
         L, P, ps, Hkv, D, B, MP = 8, 32, 8, 2, 64, 5, 4
         kp = jnp.asarray(rng.normal(size=(L, P, ps, Hkv, D)), jnp.float32)
@@ -464,11 +465,16 @@ class TestPagedKvUpdateKernel:
         pos = jnp.asarray([0, 5, 7, 13, 100], jnp.int32)  # 100: off-table
         act = jnp.asarray([1, 1, 0, 1, 1], bool)          # row 2 inactive
         ref_k, ref_v = att.write_decode_kv_all_layers(
-            kp, vp, kn, vn, pt, pos, act)
+            kp, vp, kn, vn, pt, pos, act, KernelPlan())
         new_k, new_v = paged_kv_update(kp, vp, kn, vn, pt, pos, act,
                                        interpret=True)
         assert jnp.array_equal(ref_k, new_k)
         assert jnp.array_equal(ref_v, new_v)
+        via_k, via_v = att.write_decode_kv_all_layers(
+            kp, vp, kn, vn, pt, pos, act,
+            KernelPlan(kv_writers=True, interpret=True))
+        assert jnp.array_equal(ref_k, via_k)
+        assert jnp.array_equal(ref_v, via_v)
 
     def test_layered_decode_kernel_matches_sliced(self):
         """layer= + full 5D pools (no per-layer slice for XLA to
@@ -505,7 +511,8 @@ class TestPagedPrefillKvUpdateKernel:
         from xllm_service_tpu.ops import attention as att
         from xllm_service_tpu.ops.pallas.kv_update import (
             paged_prefill_kv_update)
-        monkeypatch.setenv("XLLM_PALLAS_KV", "0")   # pin the reference
+        from xllm_service_tpu.ops.plan import KernelPlan
+        monkeypatch.setenv("XLLM_PALLAS_KV", "1")   # the plan decides
         rng = np.random.default_rng(5)
         L, P, ps, Hkv, D, B, T, MP = 3, 32, 8, 2, 16, 4, 16, 6
         kp = jnp.asarray(rng.normal(size=(L, P, ps, Hkv, D)), jnp.float32)
@@ -521,11 +528,16 @@ class TestPagedPrefillKvUpdateKernel:
         start = jnp.asarray([0, 8, 0, 16], jnp.int32)  # page-aligned
         lens = jnp.asarray([16, 11, 16, 5], jnp.int32)  # ragged tails
         ref_k, ref_v = att.write_prefill_kv_all_layers(
-            kp, vp, kn, vn, pt, start, lens)
+            kp, vp, kn, vn, pt, start, lens, KernelPlan())
         new_k, new_v = paged_prefill_kv_update(
             kp, vp, kn, vn, pt, start, lens, interpret=True)
         assert jnp.array_equal(ref_k, new_k)
         assert jnp.array_equal(ref_v, new_v)
+        via_k, via_v = att.write_prefill_kv_all_layers(
+            kp, vp, kn, vn, pt, start, lens,
+            KernelPlan(kv_writers=True, interpret=True))
+        assert jnp.array_equal(ref_k, via_k)
+        assert jnp.array_equal(ref_v, via_v)
 
 
 def test_kv_update_kernels_match_scatter_at_mla_latent_shape():

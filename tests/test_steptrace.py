@@ -123,36 +123,6 @@ class TestStepBooks:
 
 
 # ---------------------------------------------------------------------------
-# Units: the peaks table
-# ---------------------------------------------------------------------------
-class TestPeaks:
-    def test_peaks_table_resolves_device_kind(self, monkeypatch):
-        # conftest sets the overrides for the CPU session; the table
-        # itself is what this test reads.
-        monkeypatch.setattr(steptrace, "PEAK_FLOPS_OVERRIDE", 0.0)
-        monkeypatch.setattr(steptrace, "PEAK_BW_GBPS_OVERRIDE", 0.0)
-        fl, bw = steptrace.peaks_for("TPU v5 lite")
-        assert fl == 197e12 and bw == 819.0 * 1e9
-        assert steptrace.peaks_for("TPU v6e") == (918e12, 1640.0 * 1e9)
-
-    @pytest.mark.parametrize("kind", ["", "cpu", "weird-asic"])
-    def test_peaks_unknown_kind_raises(self, monkeypatch, kind):
-        """A device that is not in the table is an error, not a
-        default: there is no placeholder row to fall onto."""
-        monkeypatch.setattr(steptrace, "PEAK_FLOPS_OVERRIDE", 0.0)
-        monkeypatch.setattr(steptrace, "PEAK_BW_GBPS_OVERRIDE", 0.0)
-        with pytest.raises(ValueError, match="no peak"):
-            steptrace.peaks_for(kind)
-
-    def test_peaks_env_override_wins(self, monkeypatch):
-        # The env is read once at import (hot-path flag discipline), so
-        # the override test pins the module constants it lands in.
-        monkeypatch.setattr(steptrace, "PEAK_FLOPS_OVERRIDE", 2e12)
-        monkeypatch.setattr(steptrace, "PEAK_BW_GBPS_OVERRIDE", 100.0)
-        assert steptrace.peaks_for("TPU v6e") == (2e12, 100.0 * 1e9)
-
-
-# ---------------------------------------------------------------------------
 # Units: the merged chrome-trace builder + offline validator
 # ---------------------------------------------------------------------------
 T0 = 1_700_000_000.0

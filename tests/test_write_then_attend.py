@@ -85,10 +85,14 @@ class TestLayerWriters:
     def test_prefill_layer_writer_unaligned_start_falls_back(self,
                                                              monkeypatch):
         """A mid-page window start must NOT reach the page-granular
-        kernel (it would misplace whole pages); the dispatcher's
-        page_aligned_starts=False pins the XLA scatter, which handles
-        any alignment."""
+        kernel (it would misplace whole pages); a plan whose
+        page_aligned is False pins the XLA scatter, which handles any
+        alignment — with the writers on, and whatever the environment
+        says."""
+        from xllm_service_tpu.ops.plan import KernelPlan
         monkeypatch.setenv("XLLM_PALLAS_KV", "1")
+        plan = KernelPlan(kv_writers=True, page_aligned=False,
+                          interpret=True)
         rng = np.random.default_rng(13)
         L, P, ps, Hkv, D, B, T, MP = 2, 32, 8, 1, 16, 2, 16, 6
         kp = jnp.asarray(rng.normal(size=(L, P, ps, Hkv, D)), jnp.float32)
@@ -104,7 +108,7 @@ class TestLayerWriters:
         for li in range(L):
             kp, vp = att.write_prefill_kv_layer(
                 kp, vp, kn[li], vn[li], pt, start, lens, jnp.int32(li),
-                page_aligned_starts=False)
+                plan)
         assert jnp.array_equal(ref[0], kp)
         assert jnp.array_equal(ref[1], vp)
 
@@ -264,17 +268,21 @@ class TestEngineWriteThenAttend:
         for rid in off:
             assert off[rid] == on[rid], rid
 
-    def test_env_flag_reaches_config(self, monkeypatch):
-        from xllm_service_tpu.config import EngineConfig
+    def test_env_flag_reaches_the_engines_plan(self, monkeypatch):
+        """The variable lands in the plan of an engine built under it
+        (tests/test_kernel_plan.py holds the resolver's whole table),
+        not in the configuration."""
+        from xllm_service_tpu.config import EngineConfig, ModelConfig
+        from xllm_service_tpu.runtime.engine import Engine
+        ecfg = EngineConfig(page_size=16, num_pages=32, max_model_len=64)
+        monkeypatch.setenv("XLLM_PALLAS", "0")
         monkeypatch.setenv("XLLM_WRITE_THEN_ATTEND", "1")
-        assert EngineConfig(page_size=16, num_pages=32,
-                            max_model_len=64).write_then_attend is True
+        on = Engine(ModelConfig.tiny(), ecfg)
+        assert on.plan.write_then_attend and ecfg.write_then_attend is None
         monkeypatch.setenv("XLLM_WRITE_THEN_ATTEND", "0")
-        assert EngineConfig(page_size=16, num_pages=32,
-                            max_model_len=64).write_then_attend is False
-        monkeypatch.delenv("XLLM_WRITE_THEN_ATTEND")
-        assert EngineConfig(page_size=16, num_pages=32,
-                            max_model_len=64).write_then_attend is None
+        assert not Engine(ModelConfig.tiny(), ecfg,
+                          params=on.params).plan.write_then_attend
+        assert on.plan.write_then_attend
 
 
 class TestMlaWriteThenAttend:
@@ -292,10 +300,12 @@ class TestMlaWriteThenAttend:
             num_kv_heads=4, kv_lora_rank=16, qk_rope_head_dim=8,
             qk_nope_head_dim=16, v_head_dim=16, dtype="float32")
 
-    def _forward(self, monkeypatch, wta, start, T, aligned,
-                 pallas="1"):
+    def _forward(self, wta, start, T, aligned, kernels=True):
         from xllm_service_tpu.models import transformer
-        monkeypatch.setenv("XLLM_PALLAS", pallas)
+        from xllm_service_tpu.ops.plan import KernelPlan
+        plan = KernelPlan(decode_attn=kernels, kv_writers=kernels,
+                          write_then_attend=wta, page_aligned=aligned,
+                          interpret=True)
         cfg = self._mla_cfg()
         params = transformer.init_params(cfg, jax.random.PRNGKey(1))
         kv = transformer.init_kv_cache(cfg, 16, 8, jnp.float32)
@@ -306,30 +316,29 @@ class TestMlaWriteThenAttend:
         lens = jnp.asarray([T, T - 3], jnp.int32)
         pt = jnp.asarray(np.arange(1, B * 6 + 1).reshape(B, 6), jnp.int32)
         last, _, kv2 = transformer.forward_prefill(
-            params, cfg, toks, starts, lens, kv, pt,
-            page_aligned_prefill=aligned, write_then_attend=wta)
+            params, cfg, toks, starts, lens, kv, pt, plan=plan)
         return (np.asarray(last), np.asarray(kv2[0]), np.asarray(kv2[1]))
 
-    def test_mla_wta_matches_baseline(self, monkeypatch):
-        base = self._forward(monkeypatch, wta=False, start=8, T=16,
-                             aligned=True, pallas="0")
-        got = self._forward(monkeypatch, wta=True, start=8, T=16,
+    def test_mla_wta_matches_baseline(self):
+        base = self._forward(wta=False, start=8, T=16,
+                             aligned=True, kernels=False)
+        got = self._forward(wta=True, start=8, T=16,
                             aligned=True)
         for a, b in zip(base, got):
             assert np.max(np.abs(a - b)) < 2e-4
 
-    def test_mla_misaligned_bucket_uses_scatter(self, monkeypatch):
+    def test_mla_misaligned_bucket_uses_scatter(self):
         """start_pos=20 on 8-token pages (a 20-token bucket's second
         window): before page_aligned_prefill was threaded through
         _mla_forward_prefill, the kernel path engaged with the
         unaligned start and silently corrupted the pool."""
-        base = self._forward(monkeypatch, wta=False, start=20, T=16,
-                             aligned=False, pallas="0")
-        got = self._forward(monkeypatch, wta=False, start=20, T=16,
+        base = self._forward(wta=False, start=20, T=16,
+                             aligned=False, kernels=False)
+        got = self._forward(wta=False, start=20, T=16,
                             aligned=False)
         for a, b in zip(base, got):
             assert np.max(np.abs(a - b)) < 2e-4
-        got_wta = self._forward(monkeypatch, wta=True, start=20, T=16,
+        got_wta = self._forward(wta=True, start=20, T=16,
                                 aligned=False)
         for a, b in zip(base, got_wta):
             assert np.max(np.abs(a - b)) < 2e-4

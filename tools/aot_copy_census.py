@@ -7,7 +7,8 @@ a handful of times per CALL around the two custom calls, because the
 opaque attention call read a buffer the post-scan writer aliased —
 amortized to noise inside the fused 64-step decode burst, but
 ~10-15 GB per PREFILL call. Write-then-attend
-(EngineConfig.write_then_attend / XLLM_WRITE_THEN_ATTEND) removes the
+(``KernelPlan.write_then_attend``; EngineConfig.write_then_attend /
+XLLM_WRITE_THEN_ATTEND where an engine resolves it) removes the
 hazard at the root: the aliased writer is the pool's first consumer in
 every layer body, so nothing ever reads the pre-write buffer.
 
@@ -140,13 +141,15 @@ def _tiny_sds():
     return cfg, params
 
 
-def build_programs(tiny: bool = False):
+def build_programs(tiny: bool = False, plan=None):
     """(name → (fn, args, donate_argnums, pool_shape)) for the census:
     the prefill step, the single decode step, and the fused decode
-    burst, at the bench geometry (or a scaled-down structurally
-    identical one for the tier-1 check)."""
+    burst, under ``plan`` (default: ``census_plan(True)``), at the bench
+    geometry (or a scaled-down structurally identical one for the
+    tier-1 check)."""
     from xllm_service_tpu.models import transformer
 
+    plan = plan or census_plan(True)
     if tiny:
         cfg, params = _tiny_sds()
         P, ps, burst = 32, 64, 4
@@ -172,16 +175,14 @@ def build_programs(tiny: bool = False):
 
     def decode_single(params, tok, pos, act, kv, pt):
         logits, kv = transformer.forward_decode(
-            params, cfg, tok, pos, act, kv, pt,
-            write_then_attend=_WTA[0])
+            params, cfg, tok, pos, act, kv, pt, plan=plan)
         return jnp.argmax(logits, -1).astype(jnp.int32), kv
 
     def decode_burst(params, tok, pos, act, kv, pt):
         def body(carry, _):
             t, p, kv = carry
             logits, kv = transformer.forward_decode(
-                params, cfg, t, p, act, kv, pt,
-                write_then_attend=_WTA[0])
+                params, cfg, t, p, act, kv, pt, plan=plan)
             t2 = jnp.argmax(logits, -1).astype(jnp.int32)
             return (t2, p + 1, kv), t2
         (t, p, kv2), toks = jax.lax.scan(
@@ -196,20 +197,19 @@ def build_programs(tiny: bool = False):
 
     def prefill_step(params, tokens, start, lens, kv, ptp):
         last, _, kv = transformer.forward_prefill(
-            params, cfg, tokens, start, lens, kv, ptp,
-            write_then_attend=_WTA[0])
+            params, cfg, tokens, start, lens, kv, ptp, plan=plan)
         return jnp.argmax(last, -1).astype(jnp.int32), kv
 
     # The ragged mixed-batch program (XLLM_RAGGED_ATTN): same packed
     # [B, T]+(start, lens) surface as prefill but decode rows ride as
     # length-1 windows; always write-then-attend and never page-aligned
-    # (engine.py _jit_ragged). The pools must stay donated and unmoved
-    # exactly like the prefill program they replace on mixed iterations.
+    # (the plan's mixed_program(), as engine.py builds _jit_ragged). The
+    # pools must stay donated and unmoved exactly like the prefill
+    # program they replace on mixed iterations.
     def ragged_step(params, tokens, start, lens, kv, ptp):
         last, _, kv = transformer.forward_prefill(
             params, cfg, tokens, start, lens, kv, ptp,
-            page_aligned_prefill=False, write_then_attend=True,
-            ragged=True)
+            plan=plan.mixed_program())
         return jnp.argmax(last, -1).astype(jnp.int32), kv
 
     return {
@@ -224,10 +224,21 @@ def build_programs(tiny: bool = False):
     }
 
 
-# write_then_attend is threaded through a mutable cell so build_programs
-# traces fresh closures per mode (jit caches by function identity — the
-# census compiles a new function object per (program, mode) anyway).
-_WTA = [True]
+def census_plan(write_then_attend: bool):
+    """Real Mosaic lowering, with the kernel mix THIS toolchain lowers:
+    the aliased KV writers (the aliasing story the census is about) +
+    XLA attention. The baked jax's Mosaic is older than round 5's and
+    rejects the attention kernels' in-kernel [ps, Hkv, D] relayouts
+    ("transpose[permutation=(1,0,2)]" / 3D dots — see
+    tools/aot_kernel_probes.py output on this image), so programs with
+    the attention kernels on cannot compile offline here; XLA attention
+    reads the same pool buffers, so the copy hazard under test —
+    attention reading what the writer aliases — is identical. (What an
+    engine resolves under XLLM_PALLAS=0 XLLM_PALLAS_KV=1
+    XLLM_PALLAS_INTERPRET=0 with page-multiple buckets.)"""
+    from xllm_service_tpu.ops.plan import KernelPlan
+    return KernelPlan(kv_writers=True, write_then_attend=write_then_attend,
+                      interpret=False)
 
 
 def _kv_layout_kwargs(args, donate, n_out, kv_out=None):
@@ -255,9 +266,8 @@ def run_census(tiny: bool = False, modes=(True, False)) -> dict:
     {f"{name}[wta={mode}]": {"ok":, "pool_copies":, "hits": [...]}}."""
     results = {}
     for mode in modes:
-        _WTA[0] = mode
         for name, (fn, args, donate, pool_shape) in \
-                build_programs(tiny).items():
+                build_programs(tiny, census_plan(mode)).items():
             tag = f"{name}[wta={'on' if mode else 'off'}]"
             try:
                 kw = _kv_layout_kwargs(args, donate, _N_OUT[name])
@@ -276,21 +286,6 @@ def run_census(tiny: bool = False, modes=(True, False)) -> dict:
 
 def main() -> int:
     tiny = "--tiny" in sys.argv
-    # Real Mosaic lowering, with the kernel mix THIS toolchain lowers:
-    # the aliased KV writers (XLLM_PALLAS_KV=1 — the aliasing story the
-    # census is about) + XLA attention. The baked jax's Mosaic is older
-    # than round 5's and rejects the attention kernels' in-kernel
-    # [ps, Hkv, D] relayouts ("transpose[permutation=(1,0,2)]" /
-    # 3D dots — see tools/aot_kernel_probes.py output on this image),
-    # so XLLM_PALLAS=1 programs cannot compile offline here; XLA
-    # attention reads the same pool buffers, so the copy hazard under
-    # test — attention reading what the writer aliases — is identical.
-    # The wta flag itself is passed explicitly per mode (not via env)
-    # so one process covers the A/B.
-    os.environ["XLLM_PALLAS_INTERPRET"] = "0"
-    os.environ["XLLM_PALLAS"] = "0"
-    os.environ["XLLM_PALLAS_PREFILL"] = "0"
-    os.environ["XLLM_PALLAS_KV"] = "1"
     results = run_census(tiny=tiny)
     on_clean = all(r["ok"] and r["pool_copies"] == 0
                    for t, r in results.items() if "[wta=on]" in t)

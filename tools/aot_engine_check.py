@@ -18,7 +18,6 @@ Prints one verdict line per program + a JSON summary.
 
 from __future__ import annotations
 
-import functools
 import json
 import os
 import sys
@@ -64,12 +63,7 @@ def main() -> int:
 
     def check(name, fn, args, donate=()):
         try:
-            # Fresh wrapper per variant: jit caches by function identity
-            # and abstract args — env-gated dispatch (XLLM_PALLAS*) is
-            # NOT part of the cache key, so reusing the same function
-            # object would silently hand variant 2 variant 1's trace.
-            fresh = functools.wraps(fn)(lambda *a: fn(*a))
-            compiled = aot_compile(fresh, args, donate_argnums=donate)
+            compiled = aot_compile(fn, args, donate_argnums=donate)
             ca = compiled.cost_analysis()
             if isinstance(ca, list):
                 ca = ca[0]
@@ -101,33 +95,38 @@ def main() -> int:
     act = sds((B,), jnp.bool_)
     pt = sds((B, MP), jnp.int32)
 
-    def decode_step(params, tok, pos, act, kv, pt):
-        logits, kv = transformer.forward_decode(
-            params, cfg, tok, pos, act, kv, pt)
-        return jnp.argmax(logits, -1).astype(jnp.int32), kv
-
-    # Real Mosaic lowering for the kernels even though the RUNTIME
-    # platform is the pinned CPU (tools/aot_tpu.py): without this the
-    # kernels silently lower as interpreter ops and the analysis
+    # The two paths as plans (attend-then-scatter, as these checks have
+    # always compiled them). interpret=False: real Mosaic lowering even
+    # though the RUNTIME platform is the pinned CPU (tools/aot_tpu.py) —
+    # interpreted, the kernels lower as interpreter ops and the analysis
     # describes a program the TPU never runs.
-    os.environ["XLLM_PALLAS_INTERPRET"] = "0"
-    for label, env in (("gather", "0"), ("pallas_kernel", "1")):
-        os.environ["XLLM_PALLAS"] = env
-        check(f"decode_single B=64 ctx=384 [{label}]", decode_step,
+    from xllm_service_tpu.ops.plan import KernelPlan
+    kernel_plan = KernelPlan(decode_attn=True, prefill_attn=True,
+                             kv_writers=True, interpret=False)
+    paths = (("gather", KernelPlan()), ("pallas_kernel", kernel_plan))
+
+    def decode_step(plan):
+        def fn(params, tok, pos, act, kv, pt):
+            logits, kv = transformer.forward_decode(
+                params, cfg, tok, pos, act, kv, pt, plan=plan)
+            return jnp.argmax(logits, -1).astype(jnp.int32), kv
+        return fn
+
+    for label, plan in paths:
+        check(f"decode_single B=64 ctx=384 [{label}]", decode_step(plan),
               (params, tok, pos, act, kv, pt), donate=(4,))
 
     def decode_burst(params, tok, pos, act, kv, pt):
         def body(carry, _):
             t, p, kv = carry
             logits, kv = transformer.forward_decode(
-                params, cfg, t, p, act, kv, pt)
+                params, cfg, t, p, act, kv, pt, plan=kernel_plan)
             t2 = jnp.argmax(logits, -1).astype(jnp.int32)
             return (t2, p + 1, kv), t2
         (t, p, kv), toks = jax.lax.scan(
             body, (tok, pos, kv), None, length=64)
         return toks, t, p, kv
 
-    os.environ["XLLM_PALLAS"] = "1"
     check("decode_burst64 B=64 ctx=384 [pallas_kernel]", decode_burst,
           (params, tok, pos, act, kv, pt), donate=(4,))
 
@@ -140,19 +139,16 @@ def main() -> int:
     lens = sds((Bp,), jnp.int32)
     ptp = sds((Bp, MPp), jnp.int32)
 
-    def prefill_step(params, tokens, start, lens, kv, ptp):
-        last, lps, kv = transformer.forward_prefill(
-            params, cfg, tokens, start, lens, kv, ptp)
-        return last, kv
+    def prefill_step(plan):
+        def fn(params, tokens, start, lens, kv, ptp):
+            last, lps, kv = transformer.forward_prefill(
+                params, cfg, tokens, start, lens, kv, ptp, plan=plan)
+            return last, kv
+        return fn
 
-    for label, env in (("gather", "0"), ("pallas_kernel", "1")):
-        os.environ["XLLM_PALLAS_PREFILL"] = env
-        os.environ["XLLM_PALLAS"] = env   # kernel path needs base gate
-        check(f"prefill B=32 T=128 [{label}]", prefill_step,
+    for label, plan in paths:
+        check(f"prefill B=32 T=128 [{label}]", prefill_step(plan),
               (params, tokens, start, lens, kv, ptp), donate=(4,))
-    for k in ("XLLM_PALLAS", "XLLM_PALLAS_PREFILL",
-              "XLLM_PALLAS_INTERPRET"):
-        os.environ.pop(k, None)
 
     print(json.dumps({"aot_target": "v5e:1x1 (local libtpu)",
                       "results": results}))

@@ -3,8 +3,7 @@
 # + ONE real worker + benchmarks.loadgen, percentiles through the full
 # /v1/chat/completions path. Defaults drive the llama3-1b flagship on
 # the device JAX gives (pin CPU with JAX_PLATFORMS=cpu for a harness
-# smoke; a CPU worker needs XLLM_PEAK_FLOPS / XLLM_PEAK_BW_GBPS). Every
-# process started here is stopped on the way out.
+# smoke). Every process started here is stopped on the way out.
 #
 # Usage: tools/loadgen_stack.sh [model] [num_requests] [max_tokens] \
 #            [request_rate] [mean_prompt_len]

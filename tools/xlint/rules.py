@@ -947,7 +947,7 @@ _ENGINE_FILE = "xllm_service_tpu/runtime/engine.py"
 # async device→host copy, waits with split device_wait/host_copy
 # attribution, then materializes. Every other np.asarray/device_get on a
 # device array inside an Engine method either hides a host sync in the
-# serving loop (the BENCH_TPU_LAST.json 5.9 s "readback" that was really
+# serving loop (round 6's 5.9 s "readback" that was really
 # unattributed device wait) or belongs on a justified allowlist entry
 # for a genuinely cold path (PD KV export).
 _READBACK_HELPER = "_read_host"

@@ -254,7 +254,7 @@ class ModelConfig:
 
     @classmethod
     def llama3_1b(cls) -> "ModelConfig":
-        # Llama-3.2-1B shape: the single-chip flagship for bench.py.
+        # Llama-3.2-1B shape: what chip_smoke.py serves on one chip.
         return cls(name="llama3-1b", vocab_size=128256, hidden_size=2048,
                    intermediate_size=8192, num_layers=16, num_heads=32,
                    num_kv_heads=8, head_dim=64, rope_theta=500000.0,
@@ -746,8 +746,9 @@ class EngineConfig:
     # token — from the pool. Kills the jit-call-boundary pool copies XLA
     # inserts around the post-scan writer (~10-15 GB per prefill call at
     # the bench shape). None = auto: on wherever the Pallas kernels are
-    # on (pallas.enabled()), off on the pure-XLA path, resolved at
-    # Engine init. Env XLLM_WRITE_THEN_ATTEND=0/1 overrides.
+    # on, off on the pure-XLA path. Resolved once, when an engine is
+    # built (ops/plan.py KernelPlan.from_env), where
+    # XLLM_WRITE_THEN_ATTEND=0/1 overrides this field.
     write_then_attend: Optional[bool] = None
     # Pipelined decode: after dispatching fused burst k, start its
     # device→host copy asynchronously and — while the batch snapshot
@@ -768,8 +769,9 @@ class EngineConfig:
     # (ops/pallas/ragged_attention.py) instead of a decode burst plus a
     # prefill call. Pure-decode and pure-prefill iterations keep their
     # dedicated programs (the fused burst + speculation pipeline stays).
-    # None = auto: off (opt-in while the kernel soaks). Env
-    # XLLM_RAGGED_ATTN=0/1 overrides; read once at Engine init.
+    # None = auto: off (opt-in while the kernel soaks). Resolved once,
+    # when an engine is built (ops/plan.py KernelPlan.from_env), where
+    # XLLM_RAGGED_ATTN=0/1 overrides this field.
     ragged_attn: Optional[bool] = None
     # Token-budget prefill/decode interleaving (staggered admission,
     # arxiv 2512.16134): every engine iteration decodes the running set
@@ -815,28 +817,11 @@ class EngineConfig:
                 f"max_model_len={self.max_model_len} must be a multiple of "
                 f"page_size={self.page_size}")
         self.max_pages_per_seq = self.max_model_len // self.page_size
-        # Every chunked-prefill window start is a sum of earlier bucket
-        # sizes, so starts stay page-aligned iff EVERY bucket is a page
-        # multiple. The in-place prefill KV-write kernel requires that
-        # alignment; mixed buckets (e.g. a 200-token bucket on 64-token
-        # pages) keep the XLA scatter path instead of corrupting pools.
-        self.prefill_page_aligned = all(
-            b % self.page_size == 0 for b in self.prefill_buckets)
-        env = os.environ.get("XLLM_WRITE_THEN_ATTEND", "").strip()
-        if env in ("0", "false", "no"):
-            self.write_then_attend = False
-        elif env in ("1", "true", "yes"):
-            self.write_then_attend = True
         env = os.environ.get("XLLM_DECODE_PIPELINE", "").strip()
         if env in ("0", "false", "no"):
             self.decode_pipeline = False
         elif env in ("1", "true", "yes"):
             self.decode_pipeline = True
-        env = os.environ.get("XLLM_RAGGED_ATTN", "").strip()
-        if env in ("0", "false", "no"):
-            self.ragged_attn = False
-        elif env in ("1", "true", "yes"):
-            self.ragged_attn = True
         env = os.environ.get("XLLM_INTERLEAVE", "").strip()
         if env in ("0", "false", "no"):
             self.interleave = False

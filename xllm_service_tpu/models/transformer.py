@@ -52,7 +52,9 @@ from xllm_service_tpu.ops.attention import (
     write_prefill_kv_layer,
     write_decode_kv_all_layers,
     write_decode_kv_layer,
+    write_prefill_kv_all_layers_xla,
 )
+from xllm_service_tpu.ops.plan import KernelPlan
 
 Params = Dict[str, Any]
 KVCache = Tuple[jnp.ndarray, jnp.ndarray]  # k_pages, v_pages: [L, P, ps, Hkv, Dh]
@@ -142,40 +144,6 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
 # Layer body (shared by prefill and decode via an `is_prefill` closure switch
 # — two separate compiled programs, one source of truth)
 # ---------------------------------------------------------------------------
-
-def _layer_unroll() -> int:
-    """Unroll factor for the layer scans (XLLM_UNROLL_LAYERS, default
-    1 = rolled). Round-5 pool-copy experiment knob: XLA cannot prove
-    the post-scan KV write in-place while the pool is read inside a
-    NESTED while loop, so it copies both pools every burst iteration;
-    unrolling exposes straight-line reads the alias analysis can see
-    through."""
-    import os
-    try:
-        return max(1, int(os.environ.get("XLLM_UNROLL_LAYERS", "1")))
-    except ValueError:
-        return 1
-
-
-def _use_prefill_kernel(window: int, page_size: int) -> bool:
-    """Trace-time gate for the Pallas flash-prefill kernel: env-enabled
-    AND the window tiles exactly into pool pages (engine buckets are pow2
-    multiples of the page size at serving shapes; odd test shapes fall
-    back to the XLA path)."""
-    from xllm_service_tpu.ops.pallas import prefill_kernel_enabled
-    return prefill_kernel_enabled() and window % page_size == 0
-
-
-def _use_ragged_kernel() -> bool:
-    """Trace-time gate for the ragged mixed-batch kernel: just the base
-    Pallas gate — the ragged layout reads everything through the page
-    table (write-then-attend), so there is no window/page alignment
-    requirement and no separate env knob at this level (the engine's
-    XLLM_RAGGED_ATTN gate decides whether ragged batches are built at
-    all; off TPU the XLA gather reference serves them)."""
-    from xllm_service_tpu.ops import pallas
-    return pallas.enabled()
-
 
 # Sentinel window for full-attention layers when windows ride the layer
 # scan as traced per-layer values (Gemma-2 alternation): larger than any
@@ -361,9 +329,7 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                     prompt_lp_targets: Optional[jnp.ndarray] = None,
                     return_stats: bool = False,
                     rope_pos: Optional[jnp.ndarray] = None,
-                    page_aligned_prefill: bool = True,
-                    write_then_attend: bool = False,
-                    ragged: bool = False,
+                    plan: KernelPlan = KernelPlan(),
                     ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], KVCache]:
     """Prefill ``tokens`` [B, T] (padded; true new-token counts in
     ``lengths``; nonzero ``start_pos`` = prefix-cache hit, those tokens are
@@ -388,24 +354,27 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     pool (``_mla_forward_prefill``); multimodal splice is not defined
     for them.
 
-    ``write_then_attend`` (static): the round-5 "known residue" fix —
-    the pool rides the layer scan as a CARRY and each layer writes its
+    ``plan`` (static; ops/plan.py ``KernelPlan``) is every choice the
+    layer body makes, resolved once per engine; the default is the XLA
+    reference, attend-then-scatter.
+
+    ``plan.write_then_attend``: the round-5 "known residue" fix — the
+    pool rides the layer scan as a CARRY and each layer writes its
     fresh window into the pool FIRST (aliased Pallas writer = the
     pool's first consumer), then attention reads everything — cached
     prefix AND the current window — from the pool. Kills the jit-call-
     boundary pool copies XLA inserts when an opaque attention call
     reads a buffer the post-scan writer aliases (~10-15 GB per prefill
-    call at the bench shape). Default off here; the engine turns it on
-    per EngineConfig.write_then_attend.
+    call at the bench shape).
 
-    ``ragged`` (static): the batch is a RAGGED MIX — rows may be prefill
-    windows (lengths > 1) or single decode continuations (lengths = 1,
-    start_pos = context − 1), assembled by the engine's one-dispatch
-    interleaved step (XLLM_RAGGED_ATTN). Requires ``write_then_attend``
-    (every row's new K/V must land in the pool before attention) and
-    ``page_aligned_prefill=False`` (decode rows start mid-page).
-    Attention dispatches to the ragged Pallas kernel
-    (ops/pallas/ragged_attention.py) when the base Pallas gate is on;
+    ``plan.ragged_rows``: the batch is a RAGGED MIX — rows may be
+    prefill windows (lengths > 1) or single decode continuations
+    (lengths = 1, start_pos = context − 1), assembled by the engine's
+    one-dispatch interleaved step (``plan.mixed_program()``, which also
+    sets write-then-attend — every row's new K/V must land in the pool
+    before attention — and clears ``page_aligned``: decode rows start
+    mid-page). Attention is the ragged Pallas kernel
+    (ops/pallas/ragged_attention.py) where ``plan.decode_attn``;
     otherwise the pool-gather XLA reference below already handles
     arbitrary (start, length) rows.
 
@@ -417,15 +386,15 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     """
     if cfg.mla:
         assert mm_embeds is None, "MLA models have no multimodal splice"
-        assert not ragged, "MLA models have no ragged mixed-batch path"
+        assert not plan.ragged_rows, \
+            "MLA models have no ragged mixed-batch path"
         return _mla_forward_prefill(
             params, cfg, tokens, start_pos, lengths, kv, page_table,
             return_all_logits=return_all_logits,
             prompt_lp_targets=prompt_lp_targets,
-            return_stats=return_stats,
-            page_aligned_prefill=page_aligned_prefill,
-            write_then_attend=write_then_attend)
+            return_stats=return_stats, plan=plan)
     k_pages, v_pages = kv
+    write_then_attend = plan.write_then_attend
     x = _scale_embed(cfg, params["embed"][tokens]
                      .astype(jnp.dtype(cfg.dtype)))              # [B, T, D]
     if mm_embeds is not None:
@@ -470,6 +439,10 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             k = rope_for(cfg.rope_scaling, k, positions, cfg.rope_theta,
                          positions3=rope_pos)
         B, T = tokens.shape
+        # The flash-prefill kernel needs the window to tile exactly into
+        # pool pages (engine buckets are pow2 multiples of the page size
+        # at serving shapes; odd test shapes take the XLA path).
+        prefill_kernel = plan.prefill_attn and T % kp_c.shape[2] == 0
         if write_then_attend:
             # Write-then-attend: the window's fresh K/V lands in the
             # pool FIRST (the aliased writer is the pool's first
@@ -479,23 +452,27 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             # overlay.
             kp_c, vp_c = write_prefill_kv_layer(
                 kp_c, vp_c, k, v, page_table, start_pos, lengths, li,
-                page_aligned_starts=page_aligned_prefill)
-            if ragged and _use_ragged_kernel():
+                plan)
+            if plan.ragged_rows and plan.decode_attn:
+                # No window/page alignment requirement: the ragged
+                # layout reads everything through the page table.
                 from xllm_service_tpu.ops.pallas import (
                     ragged_paged_attention_pallas)
                 attn = ragged_paged_attention_pallas(
                     q, kp_c, vp_c, page_table, start_pos, lengths,
                     sliding_window=w_l, sinks=lp.get("sinks"),
                     logits_soft_cap=cfg.attn_logit_softcapping,
-                    scale=extras.get("scale"), layer=li)
-            elif _use_prefill_kernel(T, kp_c.shape[2]):
+                    scale=extras.get("scale"), layer=li,
+                    interpret=plan.interpret)
+            elif prefill_kernel:
                 from xllm_service_tpu.ops.pallas import (
                     paged_prefill_attention_pallas)
                 attn = paged_prefill_attention_pallas(
                     q, None, None, kp_c, vp_c, page_table, start_pos,
                     lengths, sliding_window=w_l, sinks=lp.get("sinks"),
                     logits_soft_cap=cfg.attn_logit_softcapping,
-                    scale=extras.get("scale"), layer=li, from_pool=True)
+                    scale=extras.get("scale"), layer=li, from_pool=True,
+                    interpret=plan.interpret)
             else:
                 kp = jax.lax.dynamic_index_in_dim(kp_c, li, axis=0,
                                                   keepdims=False)
@@ -506,7 +483,7 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                     q, gather_pages(kp, page_table),
                     gather_pages(vp, page_table), kv_lengths, start_pos,
                     sliding_window=w_l, sinks=lp.get("sinks"), **extras)
-        elif _use_prefill_kernel(T, kp_c.shape[2]):
+        elif prefill_kernel:
             # Attend against cache (prefix-cache hits) + this step's
             # fresh K/V; the pool itself is NOT written here: emitting
             # updated pools as scan ys would rewrite the whole pool per
@@ -526,7 +503,8 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 q, k, v, kp_c, vp_c, page_table, start_pos,
                 lengths, sliding_window=w_l, sinks=lp.get("sinks"),
                 logits_soft_cap=cfg.attn_logit_softcapping,
-                scale=extras.get("scale"), layer=li)
+                scale=extras.get("scale"), layer=li,
+                interpret=plan.interpret)
         else:
             # The XLA reference slices locally (its gather fuses) then
             # overlays the not-yet-written fresh window.
@@ -571,13 +549,12 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         xs = (params["layers"], li_arr)
     if write_then_attend:
         (x, k_pages, v_pages), dropped_l = jax.lax.scan(
-            layer, (x, k_pages, v_pages), xs, unroll=_layer_unroll())
+            layer, (x, k_pages, v_pages), xs)
     else:
-        x, (k_new, v_new, dropped_l) = jax.lax.scan(
-            layer, x, xs, unroll=_layer_unroll())
+        x, (k_new, v_new, dropped_l) = jax.lax.scan(layer, x, xs)
         k_pages, v_pages = write_prefill_kv_all_layers(
             k_pages, v_pages, k_new, v_new, page_table, start_pos,
-            lengths, page_aligned_starts=page_aligned_prefill)
+            lengths, plan)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:
@@ -686,7 +663,9 @@ def forward_prefill_ring(params: Params, cfg: ModelConfig,
         return x, (k, v, dropped)
 
     x, (k_new, v_new, dropped_l) = jax.lax.scan(layer, x, params["layers"])
-    k_pages, v_pages = write_prefill_kv_all_layers(
+    # A ring program exists only on a mesh, where every plan is the XLA
+    # reference: the scatter, never the in-place writer.
+    k_pages, v_pages = write_prefill_kv_all_layers_xla(
         k_pages, v_pages, k_new, v_new, page_table,
         jnp.zeros((B,), jnp.int32), lengths)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
@@ -773,7 +752,7 @@ def forward_embedding(params: Params, cfg: ModelConfig,
         xs = (params["layers"], rope_arr)
     else:
         xs = params["layers"]
-    x, _ = jax.lax.scan(layer, x, xs, unroll=_layer_unroll())
+    x, _ = jax.lax.scan(layer, x, xs)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps).astype(
         jnp.float32)
     mask = (jnp.arange(T, dtype=jnp.int32)[None] <
@@ -793,7 +772,7 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                    kv: KVCache, page_table: jnp.ndarray,
                    return_stats: bool = False,
                    rope_delta: Optional[jnp.ndarray] = None,
-                   write_then_attend: bool = False,
+                   plan: KernelPlan = KernelPlan(),
                    ) -> Tuple[jnp.ndarray, KVCache]:
     """One decode step for ``tokens`` [B] at ``positions`` [B]
     (``active`` [B] bool masks empty batch slots). Returns
@@ -805,7 +784,8 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     (images compress T·H·W patch tokens into a max(t,h,w)-sized rope
     span, so post-image rope positions trail storage positions).
 
-    ``write_then_attend`` (static): the pool rides the layer scan as a
+    ``plan`` (static; ops/plan.py ``KernelPlan``). With
+    ``plan.write_then_attend`` the pool rides the layer scan as a
     carry; each layer writes the current token's K/V in place (aliased
     Pallas writer) BEFORE attending, and attention reads the pool alone
     — the ``k_cur``/``v_cur`` plumbing disappears, and so do the
@@ -813,9 +793,9 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if cfg.mla:
         return _mla_forward_decode(params, cfg, tokens, positions,
                                    active, kv, page_table,
-                                   return_stats=return_stats,
-                                   write_then_attend=write_then_attend)
+                                   return_stats=return_stats, plan=plan)
     k_pages, v_pages = kv
+    write_then_attend = plan.write_then_attend
     x = _scale_embed(cfg, params["embed"][tokens[:, None]]
                      .astype(jnp.dtype(cfg.dtype)))              # [B,1,D]
     cache_lens = jnp.where(active, positions, 0)   # tokens already written
@@ -824,7 +804,7 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     rope_arr = _layer_rope(cfg)
 
     # The attention dispatch gets the FULL 5D pools + a traced layer
-    # scalar: on the Pallas path the kernel's page DMAs index
+    # scalar: where plan.decode_attn the kernel's page DMAs index
     # [L, P, ps, Hkv, D] directly (round-5: a per-layer pool slice
     # feeding a custom call is MATERIALIZED — 134 MB x 2 pools x layers
     # per step); the XLA gather fallback slices per layer, which fuses.
@@ -869,10 +849,10 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             # k_cur/v_cur plumbing.
             kp_c, vp_c = write_decode_kv_layer(
                 kp_c, vp_c, k[:, 0], v[:, 0], page_table, positions,
-                active, li)
+                active, li, plan)
             attn = paged_decode_attention_auto(
                 q[:, 0], kp_c, vp_c, page_table,
-                jnp.where(active, positions + 1, 0),
+                jnp.where(active, positions + 1, 0), plan,
                 sliding_window=w_l, sinks=lp.get("sinks"),
                 layer=li, **extras)                              # [B,Hq,Dh]
         else:
@@ -882,7 +862,7 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             # per step).
             attn = paged_decode_attention_current_auto(
                 q[:, 0], kp_c, vp_c, page_table, cache_lens,
-                k[:, 0], v[:, 0],
+                k[:, 0], v[:, 0], plan,
                 sliding_window=w_l, sinks=lp.get("sinks"),
                 layer=li, **extras)                              # [B,Hq,Dh]
         B = tokens.shape[0]
@@ -914,12 +894,12 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         xs = (params["layers"], li_arr)
     if write_then_attend:
         (x, k_pages, v_pages), dropped_l = jax.lax.scan(
-            layer, (x, k_pages, v_pages), xs, unroll=_layer_unroll())
+            layer, (x, k_pages, v_pages), xs)
     else:
-        x, (k_new, v_new, dropped_l) = jax.lax.scan(
-            layer, x, xs, unroll=_layer_unroll())
+        x, (k_new, v_new, dropped_l) = jax.lax.scan(layer, x, xs)
         k_pages, v_pages = write_decode_kv_all_layers(
-            k_pages, v_pages, k_new, v_new, page_table, positions, active)
+            k_pages, v_pages, k_new, v_new, page_table, positions, active,
+            plan)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:
@@ -1150,9 +1130,9 @@ def _mla_forward_prefill(params: Params, cfg: ModelConfig,
                          return_all_logits: bool = False,
                          prompt_lp_targets: Optional[jnp.ndarray] = None,
                          return_stats: bool = False,
-                         page_aligned_prefill: bool = True,
-                         write_then_attend: bool = False):
+                         plan: KernelPlan = KernelPlan()):
     k_pages, v_pages = kv
+    write_then_attend = plan.write_then_attend
     L_dense = params["layers"]["input_norm"].shape[0]
     x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
     positions = start_pos[:, None] + jnp.arange(tokens.shape[1],
@@ -1174,8 +1154,7 @@ def _mla_forward_prefill(params: Params, cfg: ModelConfig,
                 # plumbing), then attend from the pool: no overlay.
                 kp_full, vp_full = write_prefill_kv_layer(
                     kp_full, vp_full, latent, latent,
-                    page_table, start_pos, lengths, li,
-                    page_aligned_starts=page_aligned_prefill)
+                    page_table, start_pos, lengths, li, plan)
                 kp = jax.lax.dynamic_index_in_dim(kp_full, li, axis=0,
                                                   keepdims=False)
                 lat_all = gather_pages(kp, page_table)
@@ -1228,7 +1207,7 @@ def _mla_forward_prefill(params: Params, cfg: ModelConfig,
             k_new, v_new = k_d, v_d
         k_pages, v_pages = write_prefill_kv_all_layers(
             k_pages, v_pages, k_new, v_new, page_table, start_pos,
-            lengths, page_aligned_starts=page_aligned_prefill)
+            lengths, plan)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:
@@ -1250,16 +1229,20 @@ def _mla_forward_decode(params: Params, cfg: ModelConfig,
                         active: jnp.ndarray, kv: KVCache,
                         page_table: jnp.ndarray,
                         return_stats: bool = False,
-                        write_then_attend: bool = False):
+                        plan: KernelPlan = KernelPlan()):
     k_pages, v_pages = kv
+    write_then_attend = plan.write_then_attend
     L_dense = params["layers"]["input_norm"].shape[0]
     x = params["embed"][tokens[:, None]].astype(jnp.dtype(cfg.dtype))
     cache_lens = jnp.where(active, positions, 0)
     B = tokens.shape[0]
 
+    if plan.latent_decode:
+        from xllm_service_tpu.ops.pallas import (
+            paged_decode_attention_pallas)
+
     def body(moe: bool):
         def layer(carry, xs):
-            from xllm_service_tpu.ops import pallas as _pallas
             if write_then_attend:
                 x, kp_full, vp_full = carry
                 lp, li = xs
@@ -1268,18 +1251,18 @@ def _mla_forward_decode(params: Params, cfg: ModelConfig,
                 # Latent row into the pool first (aliased write), then
                 # attend from the pool with context INCLUDING the
                 # current token — no k_cur/v_cur plumbing. MLA keeps
-                # its own kernel opt-in (XLLM_PALLAS_MLA): the absorbed
-                # block shape (Hkv=1, D=576) routes to the XLA gather
-                # reference otherwise.
+                # its own kernel opt-in (plan.latent_decode,
+                # XLLM_PALLAS_MLA): the absorbed block shape (Hkv=1,
+                # D=576) routes to the XLA gather reference otherwise.
                 kp_full, vp_full = write_decode_kv_layer(
                     kp_full, vp_full, latent[:, 0], latent[:, 0],
-                    page_table, positions, active, li)
+                    page_table, positions, active, li, plan)
                 ctx = jnp.where(active, positions + 1, 0)
-                if _pallas.mla_kernel_enabled():
-                    attn = _pallas.paged_decode_attention_pallas(
+                if plan.latent_decode:
+                    attn = paged_decode_attention_pallas(
                         q_t[:, 0], kp_full, kp_full, page_table, ctx,
                         k_cur=None, v_cur=None, scale=_mla_scale(cfg),
-                        layer=li)
+                        layer=li, interpret=plan.interpret)
                 else:
                     kp = jax.lax.dynamic_index_in_dim(
                         kp_full, li, axis=0, keepdims=False)
@@ -1297,14 +1280,16 @@ def _mla_forward_decode(params: Params, cfg: ModelConfig,
                 # MLA, a known 2x-storage cost of keeping the engine's
                 # uniform (k, v) pool plumbing (single-pool layout is a
                 # follow-up). The XLA reference path is the DEFAULT here
-                # even with XLLM_PALLAS on: the absorbed-MLA block shape
+                # even with the kernels on: the absorbed-MLA block shape
                 # (Hkv=1, D=r+rope=576 — not 128-lane-aligned) compiles
                 # for v5e (tests/test_chip_compile.py) but has no result
-                # checked on a chip; XLLM_PALLAS_MLA=1 opts into it.
-                if _pallas.mla_kernel_enabled():
-                    attn = paged_decode_attention_current_auto(
+                # checked on a chip; plan.latent_decode
+                # (XLLM_PALLAS_MLA=1) opts into it.
+                if plan.latent_decode:
+                    attn = paged_decode_attention_pallas(
                         q_t[:, 0], kp, kp, page_table, cache_lens,
-                        latent[:, 0], latent[:, 0], scale=_mla_scale(cfg))
+                        k_cur=latent[:, 0], v_cur=latent[:, 0],
+                        scale=_mla_scale(cfg), interpret=plan.interpret)
                 else:
                     attn = paged_decode_attention_current(
                         q_t[:, 0], kp, kp, page_table, cache_lens,
@@ -1345,7 +1330,8 @@ def _mla_forward_decode(params: Params, cfg: ModelConfig,
         else:
             k_new, v_new = k_d, v_d
         k_pages, v_pages = write_decode_kv_all_layers(
-            k_pages, v_pages, k_new, v_new, page_table, positions, active)
+            k_pages, v_pages, k_new, v_new, page_table, positions, active,
+            plan)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:

@@ -7,8 +7,8 @@ Dependency-free (stdlib only), thread-safe, shared by both planes:
   ``/metrics`` line in this repo renders through a ``Registry``
   (enforced by the ``metrics-registry`` xlint rule).
 - ``expfmt``: the read side — exposition parsing, structural histogram
-  validation (tier-1 tests), and ``histogram_quantile`` (bench.py's
-  latency percentiles).
+  validation (tier-1 tests), and ``histogram_quantile`` (latency
+  percentiles out of rendered histogram text).
 - ``spans``: per-request stage timelines in a bounded ring, merged
   across the service/worker boundary by correlation id and served at
   ``GET /admin/trace/<request_id>``.

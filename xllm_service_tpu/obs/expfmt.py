@@ -2,10 +2,10 @@
 
 The read side of the registry: tests point ``validate_exposition`` at
 both planes' ``/metrics`` bodies (every line must parse; histograms must
-be internally consistent), and bench.py scrapes its latency percentiles
+be internally consistent), and a harness scrapes latency percentiles
 out of rendered histogram text with ``histogram_quantile`` — the same
 arithmetic a Prometheus server would run, so the numbers a dashboard
-shows and the numbers BENCH_*.json records cannot drift apart.
+shows and the numbers a harness records cannot drift apart.
 """
 
 from __future__ import annotations
@@ -199,8 +199,8 @@ def fraction_le_from_buckets(bs: List[Tuple[float, float]],
     ascending, ending with ``+Inf``. Mass in the ``+Inf`` bucket counts
     as ABOVE any finite threshold (the conservative reading). None on an
     empty series. This is the one copy of the SLO-attainment arithmetic:
-    the live engine (obs/slo.py) and bench.py's scraped
-    ``slo_*_attainment`` fields both run it."""
+    the live engine (obs/slo.py) and any harness scraping
+    ``slo_*_attainment`` run it."""
     if not bs or bs[-1][1] <= 0:
         return None
     total = bs[-1][1]

@@ -26,8 +26,7 @@ Deployment note: one serving process hosts one plane, so a plane's
 registry is process-global there. The test harness co-locates several
 masters/workers in one process; each plane instance therefore OWNS its
 registry (``Worker.obs`` / ``HttpService.obs``) to keep attribution
-per-instance, and ``default_registry()`` serves single-plane callers
-(bench.py).
+per-instance, and ``default_registry()`` serves single-plane callers.
 """
 
 from __future__ import annotations
@@ -363,8 +362,8 @@ _DEFAULT: Optional[Registry] = None
 
 
 def default_registry() -> Registry:
-    """The process-default registry for single-plane processes (bench.py
-    and ad-hoc tools). Plane objects own their registries — see module
+    """The process-default registry for single-plane processes (ad-hoc
+    tools). Plane objects own their registries — see module
     docstring."""
     global _DEFAULT
     if _DEFAULT is None:

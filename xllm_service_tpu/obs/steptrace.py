@@ -21,9 +21,6 @@ no per-step evidence trail. This module is that trail:
   ``jax.profiler.TraceAnnotation`` on the host plane of the same
   ``.xplane.pb`` as the device's operations; otherwise the helper tests
   one flag and returns a shared no-op.
-- **Peaks**: the published peak table by device kind (``CHIP_PEAKS``,
-  ``XLLM_PEAK_FLOPS`` / ``XLLM_PEAK_BW_GBPS`` override it), which
-  bench.py divides its tokens/s-based utilization by.
 - **Shipping**: the worker exposes the ring on ``GET /admin/steptrace``
   and ships a bounded tail on every heartbeat (sequence-baseline
   committed only on a delivered beat, so an undelivered tail is
@@ -175,56 +172,6 @@ def _ring_from_env() -> int:
 
 ENABLED = _enabled_from_env()
 RING = _ring_from_env()
-
-# Overrides of the peak table, read ONCE at import. 0 = take the
-# device kind's row.
-try:
-    PEAK_FLOPS_OVERRIDE = float(os.environ.get("XLLM_PEAK_FLOPS", "0"))
-except ValueError:
-    PEAK_FLOPS_OVERRIDE = 0.0
-try:
-    PEAK_BW_GBPS_OVERRIDE = float(
-        os.environ.get("XLLM_PEAK_BW_GBPS", "0"))
-except ValueError:
-    PEAK_BW_GBPS_OVERRIDE = 0.0
-
-# Dense bf16 peak FLOP/s and HBM GB/s of one chip, keyed by
-# ``jax.Device.device_kind`` (both spellings jax knows for a
-# generation). Source: Google Cloud TPU documentation, the "System
-# architecture" page of each generation ("TPU v5e": 197 TFLOP/s bf16,
-# 819 GB/s HBM). bench.py reads it. A device that is not here has no
-# peak: asking for it is an error, not a default.
-CHIP_PEAKS: Dict[str, Tuple[float, float]] = {
-    "TPU v2": (45e12, 700.0),
-    "TPU v3": (123e12, 900.0),
-    "TPU v4": (275e12, 1228.0),
-    "TPU v5 lite": (197e12, 819.0),      # v5e
-    "TPU v5e": (197e12, 819.0),
-    "TPU v5": (459e12, 2765.0),          # v5p
-    "TPU v5p": (459e12, 2765.0),
-    "TPU v6 lite": (918e12, 1640.0),     # v6e / Trillium
-    "TPU v6e": (918e12, 1640.0),
-}
-
-
-def peaks_for(device_kind: str) -> Tuple[float, float]:
-    """(peak FLOP/s, peak bytes/s) for a device kind: the
-    XLLM_PEAK_FLOPS / XLLM_PEAK_BW_GBPS overrides first, then
-    ``CHIP_PEAKS``. Raises for a kind the table does not hold (a CPU
-    run of bench.py sets both overrides)."""
-    flops = PEAK_FLOPS_OVERRIDE
-    bw = PEAK_BW_GBPS_OVERRIDE * 1e9
-    if flops > 0 and bw > 0:
-        return flops, bw
-    if device_kind not in CHIP_PEAKS:
-        raise ValueError(
-            f"no peak FLOP/s and bandwidth on file for device kind "
-            f"{device_kind!r} (obs/steptrace.py CHIP_PEAKS); set "
-            f"XLLM_PEAK_FLOPS and XLLM_PEAK_BW_GBPS to state them")
-    t_flops, t_bw = CHIP_PEAKS[device_kind]
-    return (flops if flops > 0 else t_flops,
-            bw if bw > 0 else t_bw * 1e9)
-
 
 class StepTrace:
     """Bounded per-worker ring of step records.

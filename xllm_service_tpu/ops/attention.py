@@ -16,6 +16,9 @@ without materializing repeated KV. Softmax runs in float32 on the VPU.
 
 These are the XLA reference implementations; ``ops/pallas/`` holds the fused
 TPU kernels that replace the gather-then-attend pattern on the hot path.
+Which of the two serves is the caller's ``plan`` (``ops/plan.py``
+``KernelPlan``, resolved once per engine): the dispatchers below read its
+fields and nothing else.
 """
 
 from __future__ import annotations
@@ -122,38 +125,17 @@ def write_decode_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     return k_flat.reshape(k_pages.shape), v_flat.reshape(v_pages.shape)
 
 
-def _kv_update_kernel_enabled() -> bool:
-    """Gate for the Pallas in-place KV writers
-    (ops/pallas/kv_update.py): unset follows the base XLLM_PALLAS
-    semantics (on wherever the Pallas kernels are on);
-    XLLM_PALLAS_KV=0 switches the writers off on their own;
-    XLLM_PALLAS_KV=1 FORCES them on even with XLLM_PALLAS=0 — the
-    aliased writers lower on Mosaic toolchains whose attention-kernel
-    relayouts do not, and XLA-attention + Pallas-writers is a
-    legitimate serving mix (it is what the write-then-attend copy
-    census compiles, tools/aot_copy_census.py). The XLA scatter the
-    writers replace copies BOTH pools around every decode step inside
-    the fused burst (~8.6 GB/step at the bench shape) — the round-5
-    offline-AOT conviction."""
-    import os
-    from xllm_service_tpu.ops import pallas
-    if pallas.reference_only():
-        return False
-    env = os.environ.get("XLLM_PALLAS_KV", "").strip()
-    if env in ("0", "false", "no"):
-        return False
-    if env in ("1", "true", "yes"):
-        return True
-    return pallas.enabled()
-
-
 def write_decode_kv_all_layers(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                                k_new: jnp.ndarray, v_new: jnp.ndarray,
                                page_table: jnp.ndarray,
                                positions: jnp.ndarray,
-                               active: jnp.ndarray
+                               active: jnp.ndarray, plan
                                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Write ONE decode token's K/V for ALL layers in a single scatter.
+    """Write ONE decode token's K/V for ALL layers in a single scatter —
+    or, where ``plan.kv_writers``, the in-place Pallas writer (the XLA
+    scatter copies BOTH pools around every decode step inside the fused
+    burst, ~8.6 GB/step at the bench shape: the round-5 offline-AOT
+    conviction).
 
     k_pages: [L, P, ps, Hkv, D]; k_new: [L, B, Hkv, D] (per-layer scan ys).
     This exists so the layer scan never carries the pool as stacked ys —
@@ -176,11 +158,11 @@ def write_decode_kv_all_layers(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     # (docs/AOT_VERDICTS_r5.txt: 'KV UPDATE @ MLA latent' and
     # 'PREFILL KV UPDATE @ MLA latent'), with interpret parity pinned
     # at an unaligned-minor latent geometry in the ops suite.
-    if _kv_update_kernel_enabled() and ps_ % 8 == 0 \
-            and footprint < 6 * 2 ** 20:
+    if plan.kv_writers and ps_ % 8 == 0 and footprint < 6 * 2 ** 20:
         from xllm_service_tpu.ops.pallas.kv_update import paged_kv_update
         return paged_kv_update(k_pages, v_pages, k_new, v_new,
-                               page_table, positions, active)
+                               page_table, positions, active,
+                               interpret=plan.interpret)
     return write_decode_kv_all_layers_xla(
         k_pages, v_pages, k_new, v_new, page_table, positions, active)
 
@@ -207,17 +189,18 @@ def write_prefill_kv_all_layers(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                                 k_new: jnp.ndarray, v_new: jnp.ndarray,
                                 page_table: jnp.ndarray,
                                 start_pos: jnp.ndarray,
-                                lengths: jnp.ndarray,
-                                page_aligned_starts: bool = True
+                                lengths: jnp.ndarray, plan
                                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Prefill counterpart: k_new [L, B, T, Hkv, D] → one scatter — or,
-    on the Pallas path, the in-place page-granular write kernel (the
-    XLA scatter copies a full pool around the write per prefill call;
-    the decode conviction's sibling). Kernel eligibility is static:
-    T % ps == 0 (bucketed windows) and page-aligned window starts,
-    which the engine guarantees whenever its prefill buckets are
-    page-multiples (chunked-prefill starts advance by bucket sizes;
-    prefix-cache grants are whole pages)."""
+    where ``plan.kv_writers``, the in-place page-granular write kernel
+    (the XLA scatter copies a full pool around the write per prefill
+    call; the decode conviction's sibling). Kernel eligibility is
+    static: T % ps == 0 (bucketed windows) and page-aligned window
+    starts (``plan.page_aligned``), which hold whenever the engine's
+    prefill buckets are page-multiples (chunked-prefill starts advance
+    by bucket sizes; prefix-cache grants are whole pages); mixed buckets
+    (a 200-token bucket on 64-token pages) keep the scatter instead of
+    corrupting pools."""
     T_, ps2 = k_new.shape[2], k_pages.shape[2]
     _, _, _, Hkv2, D2 = k_pages.shape
     # Per-cell VMEM: 6 page blocks (4 pool + 2 new), double-buffered —
@@ -225,13 +208,14 @@ def write_prefill_kv_all_layers(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     # the scatter instead of failing Mosaic allocation. MLA latent
     # pools included (see the decode gate's note).
     cell_bytes = 2 * 6 * ps2 * Hkv2 * D2 * k_pages.dtype.itemsize
-    if _kv_update_kernel_enabled() and page_aligned_starts \
+    if plan.kv_writers and plan.page_aligned \
             and T_ % ps2 == 0 and ps2 % 8 == 0 \
             and cell_bytes < 6 * 2 ** 20:
         from xllm_service_tpu.ops.pallas.kv_update import (
             paged_prefill_kv_update)
         return paged_prefill_kv_update(k_pages, v_pages, k_new, v_new,
-                                       page_table, start_pos, lengths)
+                                       page_table, start_pos, lengths,
+                                       interpret=plan.interpret)
     return write_prefill_kv_all_layers_xla(
         k_pages, v_pages, k_new, v_new, page_table, start_pos, lengths)
 
@@ -261,7 +245,7 @@ def write_decode_kv_layer(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                           k_new: jnp.ndarray, v_new: jnp.ndarray,
                           page_table: jnp.ndarray,
                           positions: jnp.ndarray, active: jnp.ndarray,
-                          layer) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                          layer, plan) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Write ONE decode token's K/V for ONE (traced) layer into the FULL
     [L, P, ps, Hkv, D] pools — the write-then-attend layer-body writer.
 
@@ -271,11 +255,12 @@ def write_decode_kv_layer(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     everything — including the current token — from the pool.
     k_new/v_new: [B, Hkv, D]; ``layer``: traced int32 scalar."""
     _, _, ps_, Hkv_, D_ = k_pages.shape
-    if _kv_update_kernel_enabled() and ps_ % 8 == 0:
+    if plan.kv_writers and ps_ % 8 == 0:
         from xllm_service_tpu.ops.pallas.kv_update import (
             paged_kv_update_layer)
         return paged_kv_update_layer(k_pages, v_pages, k_new, v_new,
-                                     page_table, positions, active, layer)
+                                     page_table, positions, active, layer,
+                                     interpret=plan.interpret)
     return write_decode_kv_layer_xla(k_pages, v_pages, k_new, v_new,
                                      page_table, positions, active, layer)
 
@@ -303,7 +288,7 @@ def write_prefill_kv_layer(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                            k_new: jnp.ndarray, v_new: jnp.ndarray,
                            page_table: jnp.ndarray,
                            start_pos: jnp.ndarray, lengths: jnp.ndarray,
-                           layer, page_aligned_starts: bool = True
+                           layer, plan
                            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Prefill counterpart of ``write_decode_kv_layer``: one layer's
     fresh window [B, T, Hkv, D] lands in the full pools BEFORE that
@@ -313,13 +298,13 @@ def write_prefill_kv_layer(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     ``write_prefill_kv_all_layers`` (page-aligned starts, T % ps == 0);
     otherwise the XLA scatter at a traced layer index."""
     T_, ps2 = k_new.shape[1], k_pages.shape[2]
-    if _kv_update_kernel_enabled() and page_aligned_starts \
+    if plan.kv_writers and plan.page_aligned \
             and T_ % ps2 == 0 and ps2 % 8 == 0:
         from xllm_service_tpu.ops.pallas.kv_update import (
             paged_prefill_kv_update_layer)
         return paged_prefill_kv_update_layer(
             k_pages, v_pages, k_new, v_new, page_table, start_pos,
-            lengths, layer)
+            lengths, layer, interpret=plan.interpret)
     return write_prefill_kv_layer_xla(k_pages, v_pages, k_new, v_new,
                                       page_table, start_pos, lengths,
                                       layer)
@@ -631,26 +616,27 @@ def paged_decode_attention_current(q: jnp.ndarray, k_pages: jnp.ndarray,
 
 
 def paged_decode_attention_current_auto(q, k_pages, v_pages, page_table,
-                                        cache_lens, k_cur, v_cur,
+                                        cache_lens, k_cur, v_cur, plan,
                                         logits_soft_cap: float = 0.0,
                                         sliding_window=0, scale=None,
                                         sinks=None, layer=None):
-    """Trace-time dispatch for the current-token variant. The base (V1)
-    Pallas kernel implements the full model-delta surface — windowed
-    masks (static or traced per-layer), Gemma soft-cap and scale
-    overrides, GPT-OSS sinks — so SWA families ride the kernel path too
-    (round-4 verdict item 3).
+    """Dispatch on ``plan.decode_attn`` for the current-token variant.
+    The base (V1) Pallas kernel implements the full model-delta surface
+    — windowed masks (static or traced per-layer), Gemma soft-cap and
+    scale overrides, GPT-OSS sinks — so SWA families ride the kernel
+    path too (round-4 verdict item 3).
 
     ``layer`` (traced int32 scalar) + FULL 5D pools routes the kernel's
     page DMAs straight into [L, P, ps, Hkv, D] — no per-layer pool
     slice for XLA to materialize (134 MB x 2 pools x layers per decode
     step, the round-5 offline-AOT conviction). The XLA fallback slices
     locally (its gather fuses; nothing materializes)."""
-    from xllm_service_tpu.ops import pallas
-    if pallas.enabled():
+    if plan.decode_attn:
+        from xllm_service_tpu.ops import pallas
         return pallas.paged_decode_attention_pallas(
             q, k_pages, v_pages, page_table, cache_lens,
-            k_cur=k_cur, v_cur=v_cur, sliding_window=sliding_window,
+            k_cur=k_cur, v_cur=v_cur, interpret=plan.interpret,
+            sliding_window=sliding_window,
             logits_soft_cap=logits_soft_cap, scale=scale, sinks=sinks,
             layer=layer)
     if layer is not None:
@@ -707,7 +693,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 
 
 def paged_decode_attention_auto(q, k_pages, v_pages, page_table,
-                                context_lens, logits_soft_cap: float = 0.0,
+                                context_lens, plan,
+                                logits_soft_cap: float = 0.0,
                                 sliding_window=0, scale=None, sinks=None,
                                 layer=None):
     """Write-then-attend decode dispatch: the current token's K/V is
@@ -715,11 +702,12 @@ def paged_decode_attention_auto(q, k_pages, v_pages, page_table,
     ``context_lens`` INCLUDES it and there is no ``k_cur``/``v_cur``
     plumbing. The Pallas kernel path reads the full 5D pools at a traced
     ``layer``; the XLA fallback slices locally (its gather fuses)."""
-    from xllm_service_tpu.ops import pallas
-    if pallas.enabled():
+    if plan.decode_attn:
+        from xllm_service_tpu.ops import pallas
         return pallas.paged_decode_attention_pallas(
             q, k_pages, v_pages, page_table, context_lens,
-            k_cur=None, v_cur=None, sliding_window=sliding_window,
+            k_cur=None, v_cur=None, interpret=plan.interpret,
+            sliding_window=sliding_window,
             logits_soft_cap=logits_soft_cap, scale=scale, sinks=sinks,
             layer=layer)
     if layer is not None:

@@ -92,16 +92,6 @@ except ValueError:
 _FULL = FULL_WINDOW
 
 
-def prefill_kernel_enabled() -> bool:
-    """Call-time gate (sibling of XLLM_PALLAS / XLLM_RAGGED_ATTN):
-    off by default until validated on hardware. Requires the base Pallas
-    gate too — there is no interpret fallback on the serving path."""
-    if os.environ.get("XLLM_PALLAS_PREFILL", "0") != "1":
-        return False
-    from xllm_service_tpu.ops import pallas
-    return pallas.enabled()
-
-
 def _kernel_layered(qstart_ref, lens_ref, pt_ref, win_ref, lyr_ref,
                     *rest, **kw):
     """Layered-pool entry: the 5th scalar-prefetch ref (layer) is

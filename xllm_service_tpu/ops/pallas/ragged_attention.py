@@ -66,17 +66,6 @@ except ValueError:
 _FULL = FULL_WINDOW
 
 
-def ragged_attn_enabled() -> bool:
-    """Serving gate for the one-dispatch ragged step (default OFF until
-    the chip session validates it). Requires the base Pallas gate — off
-    TPU the engine's ragged path still runs, but through the XLA gather
-    reference (the kernel itself is exercised under the interpreter only
-    in tests). The engine reads this ONCE per Engine.__init__ and caches
-    it, so flipping the env mid-run cannot recompile the serving jits
-    (xlint rule 17)."""
-    return os.environ.get("XLLM_RAGGED_ATTN", "0") == "1"
-
-
 def _kernel(qstart_ref, lens_ref, pt_ref, win_ref, q_ref, kp_ref, vp_ref,
             sk_ref, o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
             q_block: int, num_kv_steps: int, logits_soft_cap: float,

@@ -1,0 +1,146 @@
+"""Which attention path a step program takes: ONE decision per engine.
+
+``ops/attention.py`` holds the XLA reference implementations and
+``ops/pallas/`` the fused TPU kernels that replace them where it pays.
+``KernelPlan.from_env`` chooses between them when an engine is built,
+from the platform, the mesh, the two configurations and the
+``XLLM_PALLAS*`` / ``XLLM_RAGGED_ATTN`` / ``XLLM_WRITE_THEN_ATTEND``
+variables (read here and nowhere else). The result, a ``KernelPlan``,
+rides every step program as a jit static; the layer bodies and the
+dispatchers of ``ops/attention.py`` branch on its fields.
+
+This module imports no kernel: ``ops/pallas`` (a second and a half of
+``jax.experimental.pallas``) is loaded by the engine that needs it.
+"""
+
+import dataclasses
+import os
+
+import jax
+
+_OFF = ("0", "false", "no")
+_ON = ("1", "true", "yes")
+
+
+def _on_off(value: str):
+    """An on/off variable's value: True, False, or None when unset (or
+    unreadable, which every gate has always treated as unset)."""
+    value = value.strip()
+    return False if value in _OFF else True if value in _ON else None
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelPlan:
+    """Everything a step program's layer body branches on. Frozen and
+    hashable: a jit static, so two plans are two compiled programs and a
+    traced program can never serve under another plan than its own.
+    The default is the XLA reference throughout, attend-then-scatter."""
+    # Pallas (True) or the XLA reference of ops/attention.py (False):
+    # paged decode attention — and the mixed program's ragged attention,
+    # whose rows are decode rows and windows read from the pool alike
+    decode_attn: bool = False
+    prefill_attn: bool = False     # flash prefill (windows that tile pages)
+    latent_decode: bool = False    # absorbed-MLA decode (Hkv=1, D=r+rope)
+    kv_writers: bool = False       # in-place KV writers, else XLA scatter
+    # The engine serves a mixed iteration as ONE ragged program ...
+    mixed_step: bool = False
+    # ... and THIS program is it: rows are prefill windows or single
+    # decode continuations (``mixed_program`` sets it, nothing else).
+    ragged_rows: bool = False
+    # The pool rides the layer scan as a carry and each layer writes
+    # before it attends; else attend first, one scatter after the scan.
+    write_then_attend: bool = False
+    # Every prefill window starts on a page boundary (all buckets are
+    # page multiples): the in-place prefill writer's condition.
+    page_aligned: bool = True
+    # Kernels run under the Pallas interpreter (anywhere but a TPU).
+    interpret: bool = False
+
+    @property
+    def uses_kernels(self) -> bool:
+        return (self.decode_attn or self.prefill_attn
+                or self.latent_decode or self.kv_writers)
+
+    def mixed_program(self) -> "KernelPlan":
+        """The plan of the ragged mixed program: decode rows start
+        mid-page, and every row's K/V must be in the pool before
+        attention reads it."""
+        return dataclasses.replace(self, ragged_rows=True,
+                                   write_then_attend=True,
+                                   page_aligned=False)
+
+    @classmethod
+    def from_env(cls, model_cfg, engine_cfg, mesh=None) -> "KernelPlan":
+        """The plan of an engine for ``model_cfg`` / ``engine_cfg`` on
+        ``mesh``, from what can be observed now: the platform and the
+        environment. The environment wins over the configuration's
+        fields, as it always has.
+
+        A program partitioned over a mesh takes the XLA reference
+        everywhere: a Mosaic kernel cannot be partitioned automatically
+        ("Please wrap the call in a shard_map" — the v5e compiler,
+        PR 22), and none of these kernels is wrapped yet."""
+        environ = os.environ
+        base = _on_off(environ.get("XLLM_PALLAS", ""))
+        if base is None:
+            base = _on_tpu()
+        base = base and mesh is None
+        # The writers follow the base gate; XLLM_PALLAS_KV=0 switches
+        # them off on their own and =1 FORCES them on with the attention
+        # kernels off: the aliased writers lower on Mosaic toolchains
+        # whose attention-kernel relayouts do not, and XLA attention +
+        # Pallas writers is what the copy census compiles
+        # (tools/aot_copy_census.py).
+        writers = _on_off(environ.get("XLLM_PALLAS_KV", ""))
+        if writers is None:
+            writers = base
+        # Write-then-attend: auto is on wherever the kernels are on (the
+        # aliased writers are what make the in-scan pool write free).
+        wta = _on_off(environ.get("XLLM_WRITE_THEN_ATTEND", ""))
+        if wta is None:
+            wta = engine_cfg.write_then_attend
+        if wta is None:
+            wta = base
+        mixed = _on_off(environ.get("XLLM_RAGGED_ATTN", ""))
+        if mixed is None:
+            mixed = engine_cfg.ragged_attn
+        return cls(
+            decode_attn=base,
+            # Opt-in until a chip run has checked them; both need the
+            # base gate (no interpreter fallback on the serving path).
+            prefill_attn=base
+            and environ.get("XLLM_PALLAS_PREFILL", "0") == "1",
+            latent_decode=base
+            and environ.get("XLLM_PALLAS_MLA", "0") == "1",
+            kv_writers=writers and mesh is None,
+            # No ragged kernel for absorbed-MLA pools: they keep the
+            # split path.
+            mixed_step=bool(mixed) and not model_cfg.mla,
+            write_then_attend=bool(wta),
+            # A window start is a sum of earlier bucket sizes.
+            page_aligned=all(b % engine_cfg.page_size == 0
+                             for b in engine_cfg.prefill_buckets),
+            interpret=default_interpret())
+
+
+def _on_tpu() -> bool:
+    # A backend that fails to initialise raises here: "no TPU" must not
+    # be how a broken TPU run looks (it would switch the kernels off and
+    # the interpreter on, and serve from the reference path unnoticed).
+    return jax.devices()[0].platform == "tpu"
+
+
+def default_interpret() -> bool:
+    """Kernel ``interpret=None`` resolution for callers that are not an
+    engine (kernel tests, tools/aot_*; an engine passes its plan's):
+    run under the Pallas interpreter anywhere but a real TPU (so
+    XLLM_PALLAS=1 on CPU exercises kernel paths in tests instead of
+    crashing in Mosaic). ``XLLM_PALLAS_INTERPRET=0`` forces REAL Mosaic
+    lowering regardless of the runtime platform — required by the
+    offline v5e AOT checks (tools/aot_engine_check.py), whose runtime
+    backend is the pinned CPU while the compile target is the libtpu
+    topology (without the override every kernel silently lowers as
+    interpreter ops and the 'TPU' program under analysis contains no
+    Mosaic at all)."""
+    env = _on_off(os.environ.get("XLLM_PALLAS_INTERPRET", ""))
+    return (not _on_tpu()) if env is None else env

@@ -45,6 +45,7 @@ from jax.experimental.layout import Format, Layout
 from xllm_service_tpu.config import EngineConfig, ModelConfig
 from xllm_service_tpu.models import transformer
 from xllm_service_tpu.obs import steptrace
+from xllm_service_tpu.ops.plan import KernelPlan
 from xllm_service_tpu.ops.sampling import (
     SamplingTensors, compute_logprobs, compute_top_logprobs, sample_tokens,
     update_counts)
@@ -302,29 +303,18 @@ class Engine:
         self._slot_sampling: List[SamplingParams] = [SamplingParams()] * B
         self._slot_st: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
 
-        # Write-then-attend resolution: the config's None means auto —
-        # on wherever the Pallas kernels are on (the aliased writers are
-        # what make the in-scan pool write free), off on the pure-XLA
-        # path, which keeps its attend-then-scatter ordering.
-        # A sharded engine traces its programs on the XLA reference path:
-        # the kernels cannot be partitioned over a mesh until they are
-        # wrapped in shard_map (ops/pallas reference_path).
-        self.kernels = mesh is None
-        wta = getattr(engine_cfg, "write_then_attend", None)
-        if wta is None:
-            from xllm_service_tpu.ops import pallas
-            wta = self.kernels and pallas.enabled()
-        self.write_then_attend = bool(wta)
-        # One-dispatch ragged mixed steps (opt-in, XLLM_RAGGED_ATTN or
-        # EngineConfig.ragged_attn). The gate is read ONCE here and
-        # cached — the engine never re-reads the env on the hot path
-        # (xlint recompile-hazard rule). MLA models keep the legacy
-        # split path (no ragged kernel for absorbed-MLA pools).
-        rag = getattr(engine_cfg, "ragged_attn", None)
-        if rag is None:
-            from xllm_service_tpu.ops.pallas import ragged_attn_enabled
-            rag = ragged_attn_enabled()
-        self.ragged = bool(rag) and not model_cfg.mla
+        # Which attention path, which KV writer and which ordering the
+        # step programs take: decided once, here, and carried by every
+        # program as a jit static. The engine never reads a kernel gate
+        # again (a program traced later, for a new bucket or table
+        # width, is traced under this same plan).
+        self.plan = KernelPlan.from_env(model_cfg, engine_cfg, mesh)
+        logger.info("engine plan: %s", self.plan)
+        if self.plan.uses_kernels:
+            # The kernels are loaded here (1.2-1.6 s of
+            # jax.experimental.pallas), where an engine is built, and
+            # not inside the first program traced under the plan.
+            import xllm_service_tpu.ops.pallas  # noqa: F401
         self._sp = int(mesh.shape.get("sp", 1)) if mesh is not None else 1
         self._build_step_programs(self.kv)
         # Device-resident decode state between bursts: the previous
@@ -465,7 +455,7 @@ class Engine:
         tools/aot_copy_census.py)."""
         model_cfg, engine_cfg, mesh = self.cfg, self.ecfg, self.mesh
         K = engine_cfg.num_top_logprobs
-        aligned = getattr(engine_cfg, "prefill_page_aligned", True)
+        plan = self.plan
         kvl = tuple(row_major_format(x.ndim, x.sharding)
                     for x in kv) if self.kv_pinned else None
 
@@ -496,8 +486,7 @@ class Engine:
         # pin forces the positional convention at every call site.
         self._jit_prefill = jax.jit(
             functools.partial(_prefill_step, cfg=model_cfg, num_top=K,
-                              page_aligned=aligned, kernels=self.kernels,
-                              write_then_attend=self.write_then_attend),
+                              plan=plan),
             donate_argnums=(2,), static_argnums=(12,),
             **_pin(12, 2, 5, here_in=(5,)))
         # echo+logprobs variant: also scores every window token. Compiled
@@ -505,25 +494,21 @@ class Engine:
         # warmup stays lean.
         self._jit_prefill_plp = jax.jit(
             functools.partial(_prefill_step, cfg=model_cfg, num_top=K,
-                              with_prompt_lps=True, page_aligned=aligned,
-                              kernels=self.kernels,
-                              write_then_attend=self.write_then_attend),
+                              with_prompt_lps=True, plan=plan),
             donate_argnums=(2,), static_argnums=(12,),
             **_pin(12, 2, 6, here_in=(5,)))
         # Ragged mixed steps: a mixed iteration packs decode rows
         # (length-1 continuation windows) and prefill windows into ONE
         # ragged batch served by ONE compiled program. It reuses the
-        # prefill step verbatim with ragged=True: decode rows are
-        # continuation windows (start=len(tokens)-1, length=1), so
-        # write-then-attend + per-row causal masking already give the
+        # prefill step verbatim under the plan's mixed_program(): decode
+        # rows are continuation windows (start=len(tokens)-1, length=1),
+        # so write-then-attend + per-row causal masking already give the
         # exact decode semantics.
         self._jit_ragged = None
-        if self.ragged:
+        if plan.mixed_step:
             self._jit_ragged = jax.jit(
                 functools.partial(_prefill_step, cfg=model_cfg,
-                                  num_top=K, page_aligned=False,
-                                  kernels=self.kernels,
-                                  write_then_attend=True, ragged=True),
+                                  num_top=K, plan=plan.mixed_program()),
                 donate_argnums=(2,), static_argnums=(12,),
                 **_pin(12, 2, 5, here_in=(5,)))
         # Sequence-parallel ring prefill: available when the mesh has an
@@ -537,8 +522,7 @@ class Engine:
                 donate_argnums=(2,), static_argnames=("t_len",))
         self._jit_decode = jax.jit(
             functools.partial(_decode_step, cfg=model_cfg, num_top=K,
-                              kernels=self.kernels,
-                              write_then_attend=self.write_then_attend),
+                              plan=plan),
             donate_argnums=(2, 6),
             **_pin(9, 2, 8, here_in=(1, 5), here_out=(6, 7)))
         # tokens/positions (1, 2) are donated too: each burst feeds back
@@ -561,8 +545,7 @@ class Engine:
         self._jit_decode_multi = jax.jit(
             functools.partial(_decode_multi_step, cfg=model_cfg,
                               n_steps=engine_cfg.decode_steps, num_top=K,
-                              kernels=self.kernels,
-                              write_then_attend=self.write_then_attend),
+                              plan=plan),
             donate_argnums=(1, 2, 4, 8), **multi_pin)
         # PD import, spill-tier restore and cross-worker block adoption
         # write pages into the pools through this one program.
@@ -643,7 +626,7 @@ class Engine:
 
         The conflated ``*.readback`` phase absorbed device compute AND
         the host copy in one number, which made TPOT attribution
-        misleading in every TPU bench so far (BENCH_TPU_LAST.json:
+        misleading in every TPU bench before the split (round 6:
         5,946 ms of ``decode_multi.readback`` that was mostly the device
         running the scan). Here an async copy is started for every live
         array first (idempotent — the pipelined decode path already
@@ -2661,9 +2644,9 @@ class Engine:
 
         ``prefill_shapes`` ((B, T, MP) triples) / ``decode_widths``
         restrict warmup to exactly those programs — the scoped mode a
-        budgeted caller (bench.py) uses: one step program compiles in
-        tens of seconds, so the full pow2 sweep (~24 programs for the
-        bench config) must not stand between a time budget and a
+        budgeted caller (the benchmark, from its mix's ``warmup`` data)
+        uses: one step program compiles in tens of seconds, so the full
+        pow2 sweep must not stand between a time budget and a
         measurement. A shape the scope missed still
         compiles lazily mid-run (and shows in the recompile counters).
 
@@ -2917,36 +2900,22 @@ def _split_tok_lp(fused: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     return fused[0], fused[1].view(np.float32)
 
 
-def _kernel_path(kernels: bool):
-    """Trace-time choice of attention path for one step program: the
-    gates' own answer, or (a sharded engine) the reference path."""
-    if kernels:
-        return contextlib.nullcontext()
-    from xllm_service_tpu.ops import pallas
-    return pallas.reference_path()
-
-
 def _prefill_step(params, packed, kv, st_f32, st_i32, key, mm_embeds=None,
                   mm_positions=None, plp_targets=None, bias_ids=None,
                   bias_vals=None, rope_pos=None, t_len: int = 0, *,
                   cfg: ModelConfig, num_top: int = 0,
                   with_prompt_lps: bool = False,
-                  page_aligned: bool = True,
-                  write_then_attend: bool = False,
-                  ragged: bool = False, kernels: bool = True):
+                  plan: KernelPlan = KernelPlan()):
     start_pos = packed[:, 0]
     lengths = packed[:, 1]
     tokens = packed[:, _PREFILL_HDR:_PREFILL_HDR + t_len]
     page_table = packed[:, _PREFILL_HDR + t_len:]
     st = SamplingTensors.unpack(st_f32, st_i32)
-    with _kernel_path(kernels):
-        res = transformer.forward_prefill(
-            params, cfg, tokens, start_pos, lengths, kv, page_table,
-            mm_embeds=mm_embeds, mm_positions=mm_positions,
-            prompt_lp_targets=plp_targets if with_prompt_lps else None,
-            return_stats=True, rope_pos=rope_pos,
-            page_aligned_prefill=page_aligned,
-            write_then_attend=write_then_attend, ragged=ragged)
+    res = transformer.forward_prefill(
+        params, cfg, tokens, start_pos, lengths, kv, page_table,
+        mm_embeds=mm_embeds, mm_positions=mm_positions,
+        prompt_lp_targets=plp_targets if with_prompt_lps else None,
+        return_stats=True, rope_pos=rope_pos, plan=plan)
     if with_prompt_lps:
         last_logits, _, kv, plp, stats = res
     else:
@@ -2986,8 +2955,7 @@ def _prefill_ring_step(params, packed, kv, st_f32, st_i32, key,
 
 def _decode_step(params, packed, kv, st_f32, st_i32, key, counts=None,
                  bias_ids=None, bias_vals=None, *, cfg: ModelConfig,
-                 num_top: int = 0, write_then_attend: bool = False,
-                 kernels: bool = True):
+                 num_top: int = 0, plan: KernelPlan = KernelPlan()):
     """One decode iteration. Besides its results it hands back what the
     next step needs, so that the host uploads neither: ``next_packed``
     (``packed`` with, on active rows, the sampled token in column 0 and
@@ -3001,11 +2969,9 @@ def _decode_step(params, packed, kv, st_f32, st_i32, key, counts=None,
     page_table = packed[:, _PACK_COLS:]
     st = SamplingTensors.unpack(st_f32, st_i32)
     next_key, key = jax.random.split(key)
-    with _kernel_path(kernels):
-        logits, kv, stats = transformer.forward_decode(
-            params, cfg, tokens, positions, active, kv, page_table,
-            return_stats=True, rope_delta=rope_delta,
-            write_then_attend=write_then_attend)
+    logits, kv, stats = transformer.forward_decode(
+        params, cfg, tokens, positions, active, kv, page_table,
+        return_stats=True, rope_delta=rope_delta, plan=plan)
     tok = sample_tokens(logits, st, key, positions=positions, counts=counts,
                         bias_ids=bias_ids, bias_vals=bias_vals)
     lp = compute_logprobs(logits, tok)
@@ -3023,8 +2989,8 @@ def _decode_step(params, packed, kv, st_f32, st_i32, key, counts=None,
 def _decode_multi_step(params, tokens, positions, active_pt, kv, st_f32,
                        st_i32, key, counts=None, bias_ids=None,
                        bias_vals=None, *, cfg: ModelConfig, n_steps: int,
-                       num_top: int = 0, write_then_attend: bool = False,
-                       kernels: bool = True):
+                       num_top: int = 0,
+                       plan: KernelPlan = KernelPlan()):
     """``n_steps`` fused greedy/sampled decode iterations: the scan body is
     traced once, tokens feed forward on-device, and only the [N, B] token/
     logprob blocks cross back to the host — one dispatch per N tokens.
@@ -3044,11 +3010,9 @@ def _decode_multi_step(params, tokens, positions, active_pt, kv, st_f32,
 
     def body(carry, key_i):
         tok, pos, kv, cnt, drop = carry
-        with _kernel_path(kernels):
-            logits, kv, stats = transformer.forward_decode(
-                params, cfg, tok, pos, active, kv, page_table,
-                return_stats=True, rope_delta=rope_delta,
-                write_then_attend=write_then_attend)
+        logits, kv, stats = transformer.forward_decode(
+            params, cfg, tok, pos, active, kv, page_table,
+            return_stats=True, rope_delta=rope_delta, plan=plan)
         new_tok = sample_tokens(logits, st, key_i, positions=pos,
                                 counts=cnt, bias_ids=bias_ids,
                                 bias_vals=bias_vals)
@@ -3071,9 +3035,3 @@ def _decode_multi_step(params, tokens, positions, active_pt, kv, st_f32,
     # next burst feeds them in again without a host→device upload.
     return (_fuse_tok_lp(toks, lps), top_ids, top_lps, kv, counts,
             moe_dropped, fin_tok, fin_pos)
-
-
-def row_major_format(ndim: int, sharding) -> Format:
-    """Default major-to-minor layout on ``sharding`` — the layout the
-    aliased Pallas KV writers require of the pool."""
-    return Format(Layout(major_to_minor=tuple(range(ndim))), sharding)

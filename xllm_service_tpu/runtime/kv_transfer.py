@@ -16,7 +16,7 @@ Three transfer paths exist for PD disaggregation (SURVEY.md §7.3 item 1):
   directions.
 
 ``probe_kv_migration`` measures all three on the live hardware with
-pool-layout-identical engines, so deployments (and bench.py) can record
+pool-layout-identical engines, so deployments can record
 ``kv_migration_gbps`` instead of guessing. The HTTP hop itself is not
 simulated — the host path here measures the serialize/deserialize +
 device roundtrip floor, an upper bound on what any loopback wire gives.

@@ -2475,7 +2475,7 @@ class Worker:
             # Queue depths / KV utilization / preemptions + the
             # per-phase step-time attribution (pack / dispatch /
             # readback per program) and post-warmup recompile counters
-            # — the same ledger bench.py surfaces, live per worker.
+            # — the engine's phase ledger, live per worker.
             self._engine_load(rt)
             self._flush_phase_ledger(rt)
             self._flush_overlap(rt)

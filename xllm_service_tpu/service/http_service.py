@@ -305,8 +305,7 @@ class HttpService:
         SAME histogram/counter families /metrics exports — the SLO
         engine judges exactly what the dashboards see. Latency "good"
         counts interpolate the threshold inside its bucket (one copy of
-        the arithmetic: expfmt.fraction_le_from_buckets, shared with
-        bench.py's slo_*_attainment fields)."""
+        the arithmetic: expfmt.fraction_le_from_buckets)."""
         thresholds = {o.name: o.threshold_ms
                       for o in self.slo_cfg.objectives}
         out: Dict[str, Any] = {}

@@ -7,7 +7,7 @@ set, the cache is ``<checkout>/.jax_cache``: a fixed path, because the
 path is part of the cache key and a directory that moves never hits.
 Nothing here is derived from a pid, a temporary name or the time.
 
-Every process that compiles (worker, bench.py, chip_smoke.py's children,
+Every process that compiles (worker, chip_smoke.py's children,
 ``__graft_entry__``) calls ``enable_compile_cache()`` before its first
 compilation, so a program compiled once is loaded, not recompiled, by
 every later process that shares the directory.
