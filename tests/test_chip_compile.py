@@ -33,13 +33,14 @@ def _pools(sds, hkv=HKV, d=D):
     return pool, pool
 
 
-def _decode_attention(sds, hq=HQ, hkv=HKV, d=D):
+def _decode_attention(sds, hq=HQ, hkv=HKV, d=D, mp=MP, window=0):
     from xllm_service_tpu.ops.pallas import paged_decode_attention_pallas
-    fn = functools.partial(paged_decode_attention_pallas, interpret=False)
+    fn = functools.partial(paged_decode_attention_pallas, interpret=False,
+                           sliding_window=window)
     return (lambda q, kp, vp, pt, ctx, lyr: fn(q, kp, vp, pt, ctx,
                                                layer=lyr),
             (sds((B_DEC, hq, d), jnp.bfloat16), *_pools(sds, hkv, d),
-             sds((B_DEC, MP), jnp.int32), sds((B_DEC,), jnp.int32),
+             sds((B_DEC, mp), jnp.int32), sds((B_DEC,), jnp.int32),
              sds((), jnp.int32)), {})
 
 
@@ -91,6 +92,12 @@ def _ragged_attention(sds):
 KERNELS = {
     # The default path: what a served worker runs on the chip.
     "decode-attention": _decode_attention,
+    # The benchmark's cell (Mistral-7B-v0.1: heads of 128, a 64-column
+    # table, a STATIC window of 4096): the grid walks the window's 33
+    # columns from each row's first live page, by arithmetic on
+    # prefetched scalars inside the block index maps.
+    "decode-attention[window]": functools.partial(
+        _decode_attention, d=128, mp=64, window=4096),
     "decode-kv-writer": _decode_writer,
     "prefill-kv-writer": _prefill_writer,
     # Opt-in kernels: compile-checked here, their A/B is later work.

@@ -224,7 +224,9 @@ def test_engine_phase_builds_no_annotation_while_off(counted):
                  "xllm.step.decode.post", "xllm.kv.register_pages"):
         assert want in names, want
     args = dict(counted)["xllm.step.decode.dispatch"]
-    assert args == {"program": "decode", "B": 2, "T": 1, "MP": args["MP"]}
+    # (no window in this model: the attention walks the whole table)
+    assert args == {"program": "decode", "B": 2, "T": 1, "MP": args["MP"],
+                    "walk": args["MP"]}
     assert dict(counted)["xllm.kv.match_prefix"] == {"tokens": 17}
 
 

@@ -433,6 +433,134 @@ class TestPallasPagedAttention:
         assert jnp.allclose(ref, out, atol=1e-5), \
             float(jnp.max(jnp.abs(ref - out)))
 
+    # -- the window's page walk (PR 34) ---------------------------------
+    # Under a STATIC window the grid has ceil(W / ps) + 1 columns a row
+    # and starts at the row's first live page. One call holds the whole
+    # sweep as its rows.
+    WALK_PS, WALK_MP = 8, 12
+
+    @staticmethod
+    def _walk_rows(W, ps, MP, current):
+        """Contexts through every alignment over three page boundaries
+        past the window (``first`` moves), rows shorter than the window,
+        an inactive row and a full table."""
+        ctxs = list(range(W, W + 3 * ps + 2))
+        ctxs += [0, 1, max(W // 2, 1), W - 1, MP * ps - 1, MP * ps]
+        return [c for c in ctxs if current or c <= MP * ps]
+
+    @staticmethod
+    def _walk_tables(ctxs, W, ps, MP, current, n_garbage):
+        """Real pages only where the window needs them; leading columns
+        NULL (as ``_swa_trim`` leaves them), every other column a page of
+        garbage, so a column wrongly folded cannot pass."""
+        import numpy as np
+        pt = np.zeros((len(ctxs), MP), np.int32)
+        nxt = n_garbage
+        for b, c in enumerate(ctxs):
+            q_pos = c if current else c - 1
+            lo = max(q_pos - W + 1, 0) // ps
+            hi = (c - 1) // ps if c > 0 else -1
+            for j in range(MP):
+                if lo <= j <= hi:
+                    pt[b, j] = nxt
+                    nxt += 1
+                elif j > hi:
+                    pt[b, j] = 1 + (b + j) % (n_garbage - 1)
+        return pt, nxt
+
+    @pytest.mark.parametrize("layered", [False, True],
+                             ids=["pool4d", "layered"])
+    @pytest.mark.parametrize("current", [True, False],
+                             ids=["in_register", "written"])
+    @pytest.mark.parametrize("W", [8, 16, 32, 13],
+                             ids=["W=ps", "W=2ps", "W=4ps", "W=13"])
+    def test_static_window_walks_the_windows_pages(self, W, current,
+                                                   layered):
+        import numpy as np
+
+        from xllm_service_tpu.ops.attention import (
+            paged_decode_attention, paged_decode_attention_current)
+        from xllm_service_tpu.ops.pallas.paged_attention import (
+            paged_decode_attention_pallas)
+        from xllm_service_tpu.ops.plan import decode_walk_columns
+
+        ps, MP, G = self.WALK_PS, self.WALK_MP, 4
+        assert decode_walk_columns(MP, ps, W) < MP
+        rng = np.random.default_rng(34 + W)
+        ctxs = self._walk_rows(W, ps, MP, current)
+        pt, P = self._walk_tables(ctxs, W, ps, MP, current, G)
+        B, Hq, Hkv, D, L = len(ctxs), 4, 2, 16, 2
+        pools = rng.normal(size=(2, L, P, ps, Hkv, D))
+        pools[:, :, :G] *= 50       # NULL page 0 and the garbage pages
+        k5, v5 = (jnp.asarray(x, jnp.float32) for x in pools)
+        q = jnp.asarray(rng.normal(size=(B, Hq, D)), jnp.float32)
+        pt, ctx = jnp.asarray(pt), jnp.asarray(ctxs, jnp.int32)
+        cur = ([jnp.asarray(rng.normal(size=(B, Hkv, D)), jnp.float32)
+                for _ in range(2)] if current else [None, None])
+        if current:
+            ref = paged_decode_attention_current(
+                q, k5[1], v5[1], pt, ctx, *cur, sliding_window=W)
+        else:
+            ref = paged_decode_attention(q, k5[1], v5[1], pt, ctx,
+                                         sliding_window=W)
+        pool = ((k5, v5) if layered else (k5[1], v5[1]))
+        kw = dict(interpret=True, layer=jnp.int32(1) if layered else None)
+        out = paged_decode_attention_pallas(
+            q, *pool, pt, ctx, *cur, sliding_window=W, **kw)
+        # (a written-token row of context 0 attends to nothing)
+        live = np.asarray(ctxs) >= (0 if current else 1)
+        err = np.abs(np.asarray(ref) - np.asarray(out)).max(axis=(1, 2))
+        assert (err[live] < 1e-5).all(), [
+            (c, float(e)) for c, e in zip(ctxs, err) if e >= 1e-5]
+        # The full walk (the window as a traced scalar) folds the same
+        # pages in the same order: the same bits, inactive rows too.
+        full = paged_decode_attention_pallas(
+            q, *pool, pt, ctx, *cur, sliding_window=jnp.int32(W), **kw)
+        assert jnp.array_equal(full, out)
+
+    @pytest.mark.parametrize("window,MP,ps,want", [
+        (0, 12, 8, 12),                 # full attention
+        ("traced", 12, 8, 12),          # per-layer window vectors
+        (96, 12, 8, 12),                # W = MP * ps
+        (200, 12, 8, 12),               # W > MP * ps
+        (88, 12, 8, 12),                # span 12: not shorter
+        (8, 12, 8, 2), (16, 12, 8, 3), (32, 12, 8, 5), (13, 12, 8, 3),
+        (80, 12, 8, 11),
+        (4096, 64, 128, 33),            # the docqa cell
+        (4096, 32, 128, 32),
+    ], ids=str)
+    def test_grid_columns(self, window, MP, ps, want):
+        """The grid the kernel is lowered with, read off the jaxpr."""
+        import jax
+
+        from xllm_service_tpu.ops.pallas.paged_attention import (
+            paged_decode_attention_pallas)
+        from xllm_service_tpu.ops.plan import decode_walk_columns
+
+        B, Hq, Hkv, D, P = 2, 4, 2, 16, 4
+        args = (jnp.zeros((B, Hq, D)), jnp.zeros((P, ps, Hkv, D)),
+                jnp.zeros((P, ps, Hkv, D)), jnp.zeros((B, MP), jnp.int32),
+                jnp.zeros((B,), jnp.int32))
+        if window == "traced":
+            jaxpr = jax.make_jaxpr(
+                lambda w, *a: paged_decode_attention_pallas(
+                    *a, sliding_window=w, interpret=True))(
+                        jnp.int32(8), *args)
+        else:
+            jaxpr = jax.make_jaxpr(
+                lambda *a: paged_decode_attention_pallas(
+                    *a, sliding_window=window, interpret=True))(*args)
+            assert decode_walk_columns(MP, ps, window) == want
+
+        def grids(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    yield tuple(eqn.params["grid_mapping"].grid)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from grids(sub)
+        assert list(grids(jaxpr.jaxpr)) == [(B, want)]
+
+
 class TestPagedKvUpdateKernel:
     """The Pallas in-place decode KV write (ops/pallas/kv_update.py) —
     the round-5 fix for XLA copying BOTH pools around the scatter every

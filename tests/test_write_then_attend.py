@@ -268,6 +268,74 @@ class TestEngineWriteThenAttend:
         for rid in off:
             assert off[rid] == on[rid], rid
 
+    def test_swa_window_walk_matches_reference_plan(self, monkeypatch,
+                                                    caplog):
+        """A uniform-window engine past W + two pages, pages trimmed, its
+        table wider than the window's span: the kernel plan (interpreted)
+        walks the span's columns and not the table's, and generates the
+        reference plan's tokens."""
+        import contextlib
+        import dataclasses
+        import logging
+
+        from xllm_service_tpu.config import EngineConfig, ModelConfig
+        from xllm_service_tpu.obs import steptrace
+        from xllm_service_tpu.runtime.engine import Engine, EngineRequest
+        from xllm_service_tpu.utils.types import SamplingParams
+
+        W, ps = 16, 8
+        # float32: in bfloat16 the two plans' roundings part on a near-tie
+        cfg = dataclasses.replace(ModelConfig.tiny(vocab_size=256),
+                                  name="tiny-swa-walk", sliding_window=W,
+                                  dtype="float32")
+        ecfg = EngineConfig(page_size=ps, num_pages=64, max_model_len=128,
+                            max_batch_size=2, max_prefill_tokens=64,
+                            prefill_buckets=(16, 32))
+        dispatched = []
+
+        def span(*parts, **args):
+            if "".join(parts) == "xllm.step.decode.dispatch":
+                dispatched.append(args)
+            return contextlib.nullcontext()
+        monkeypatch.setattr(steptrace, "span", span)
+
+        def generate(pallas):
+            monkeypatch.setenv("XLLM_PALLAS", pallas)
+            eng = Engine(cfg, ecfg, seed=0)
+            assert eng.plan.decode_attn == (pallas == "1")
+            sp = SamplingParams(max_tokens=W + 3 * ps, temperature=0.0,
+                                ignore_eos=True)
+            for i, p in enumerate([list(range(1, 21)), [7, 9, 11] * 4]):
+                eng.add_request(EngineRequest(
+                    request_id=f"r{i}", token_ids=p, sampling=sp))
+            outs, trimmed = {}, 0
+            del dispatched[:]
+            while eng.has_work():
+                for o in eng.step():
+                    outs.setdefault(o.request_id, []).extend(
+                        o.new_token_ids)
+                trimmed = max([trimmed] + [s.num_trimmed
+                                           for s in eng.running])
+            assert trimmed >= 3                  # _swa_trim was active
+            return outs, list(dispatched)
+
+        with caplog.at_level(logging.INFO,
+                             logger="xllm_service_tpu.runtime.engine"):
+            ref, ref_args = generate("0")
+            got, got_args = generate("1")
+        assert ref == got
+        span_cols = W // ps + 1
+        # the plan's log line states the walk once per engine
+        assert [m.rsplit("; ", 1)[1] for m in caplog.messages
+                if m.startswith("engine plan:")] == [
+            "decode walk 16 of 16 columns",
+            f"decode walk {span_cols} of 16 columns"]
+        wide = [a for a in got_args if a["MP"] > span_cols]
+        assert wide and all(a["walk"] == span_cols for a in wide)
+        assert all(a["walk"] == min(a["MP"], span_cols) for a in got_args)
+        # the XLA reference gathers the whole table
+        assert all(a["walk"] == a["MP"] for a in ref_args)
+
     def test_env_flag_reaches_the_engines_plan(self, monkeypatch):
         """The variable lands in the plan of an engine built under it
         (tests/test_kernel_plan.py holds the resolver's whole table),

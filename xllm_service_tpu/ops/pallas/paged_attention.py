@@ -9,13 +9,24 @@ reads ``page_table[b, p]`` before the kernel body runs, so the pipeline
 DMAs exactly the right page), folding each page into a flash-style
 online-softmax accumulator in VMEM scratch.
 
-Grid: (B, max_pages), pages fastest → the scratch accumulator carries
+Grid: (B, walk), pages fastest → the scratch accumulator carries
 across the page walk of one batch row (standard TPU flash pattern). Each
 block is a whole page with all KV heads ([ps, Hkv, D] — Pallas TPU wants
 the trailing two block dims full or (8,128)-aligned, so heads stay in the
 block and the GQA grouping happens in-kernel). NULL pages (id 0) and
-positions ≥ context_len are masked; fully out-of-range pages skip compute
-via ``pl.when`` (their DMA lands on page 0 and is discarded).
+positions ≥ context_len are masked; a page wholly out of range skips its
+compute via ``pl.when`` but still pays its grid step (0.12 us on a v5e
+against 0.96 us for a page folded: PERF.md section 6, PR 34).
+
+The walk. ``walk`` is the table's width MP, except under a STATIC
+sliding window W (``ops/plan.py`` ``decode_walk_columns``): a window
+spans at most ceil(W / ps) + 1 pages, so the grid has that many columns
+and column p of row b is table column ``first[b] + p``, where ``first``
+is the page of the oldest position the window keeps, computed in the
+index maps and the body from the prefetched scalars. The pages folded,
+and their order, are those of the full walk: the result is the same
+arithmetic. A traced window (per-layer window vectors) cannot shape a
+grid and walks all MP columns, as does full attention.
 
 The V2–V5 experiment variants (transpose-free fold, whole-row manual-DMA
 walk, multi-row cells, wide block-diagonal) were deleted when the ragged
@@ -36,6 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from xllm_service_tpu.ops.pallas._compat import (
     CompilerParams as _CompilerParams)
+from xllm_service_tpu.ops.plan import decode_walk_columns
 
 _NEG_INF = -1e30
 
@@ -46,11 +58,26 @@ _NEG_INF = -1e30
 from xllm_service_tpu.ops.attention import FULL_WINDOW as _FULL
 
 
+def _query_pos(ctx, has_current: bool):
+    """The query's logical position: with the current token held
+    in-registers the cache holds [0, ctx) and the query sits at ctx;
+    without it, ctx INcludes the query token (position ctx − 1)."""
+    return ctx if has_current else ctx - 1
+
+
+def _first_column(q_pos, w, page_size: int):
+    """Table column of the oldest position a window of ``w`` keeps for a
+    query at ``q_pos``: max(0, (q_pos − w + 1) // ps). Scalar int32
+    arithmetic on prefetched values: the block index maps and the body
+    share it."""
+    return jax.lax.div(jnp.maximum(q_pos - w + 1, 0), page_size)
+
+
 def _kernel(ctx_ref, pt_ref, win_ref, q_ref, k_ref, v_ref, kc_ref, vc_ref,
             sk_ref, o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
-            pages_per_seq: int, num_kv_heads: int, has_current: bool,
-            logits_soft_cap: float, scale: float, has_sinks: bool,
-            layered: bool = False):
+            pages_per_seq: int, walk: int, num_kv_heads: int,
+            has_current: bool, logits_soft_cap: float, scale: float,
+            has_sinks: bool, layered: bool = False):
     b = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -64,12 +91,14 @@ def _kernel(ctx_ref, pt_ref, win_ref, q_ref, k_ref, v_ref, kc_ref, vc_ref,
     page_start = p * page_size
     w = win_ref[0]
     w_eff = jnp.where(w > 0, w, _FULL)
-    # The query's logical position: with the current token held
-    # in-registers the cache holds [0, ctx) and the query sits at ctx;
-    # without it, ctx INcludes the query token (position ctx − 1). The
-    # window keeps cache slot j > q_pos − W (slot j holds position j).
-    q_pos = ctx if has_current else ctx - 1
+    # The window keeps cache slot j > q_pos − W (slot j holds position j).
+    q_pos = _query_pos(ctx, has_current)
     win_floor = q_pos - w_eff
+    if walk < pages_per_seq:
+        # Grid column p is table column first + p, UNclamped here: one
+        # past the table lies past the context (ctx <= MP * ps), so the
+        # fold below skips it like any other.
+        page_start += _first_column(q_pos, w, page_size) * page_size
 
     @pl.when((page_start < ctx) & (page_start + page_size - 1 > win_floor))
     def _fold():
@@ -111,7 +140,7 @@ def _kernel(ctx_ref, pt_ref, win_ref, q_ref, k_ref, v_ref, kc_ref, vc_ref,
         acc_ref[:] = acc_ref[:] * corr + pv.reshape(hq, d)
         m_ref[:] = m_new
 
-    @pl.when(p == pages_per_seq - 1)
+    @pl.when(p == walk - 1)
     def _finalize():
         m_fin = m_ref[:]
         l_fin = l_ref[:]
@@ -171,8 +200,10 @@ def paged_decode_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
 
     ``sliding_window`` is a static int OR a traced int32 scalar (per-layer
     window vectors riding the layer scan — Gemma-2/3, GPT-OSS); 0
-    disables. ``logits_soft_cap``/``scale`` static floats (Gemma);
-    ``sinks`` an optional [Hq] array (GPT-OSS).
+    disables. A static one narrower than the table also shortens each
+    row's page walk to the window's span (module docstring).
+    ``logits_soft_cap``/``scale`` static floats (Gemma); ``sinks`` an
+    optional [Hq] array (GPT-OSS).
 
     ``interpret=None`` → Pallas interpreter off TPU (XLLM_PALLAS=1 on CPU
     exercises the kernel path in tests instead of crashing in Mosaic)."""
@@ -186,7 +217,9 @@ def paged_decode_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
         q, k_pages, v_pages, page_table, context_lens, k_cur, v_cur, win,
         sinks, interpret=interpret,
         logits_soft_cap=float(logits_soft_cap), scale=float(scale),
-        layer=layer)
+        layer=layer,
+        walk=decode_walk_columns(page_table.shape[1], k_pages.shape[-3],
+                                 sliding_window))
 
 
 def _kernel_layered(ctx_ref, pt_ref, win_ref, lyr_ref, *rest, **kw):
@@ -197,7 +230,7 @@ def _kernel_layered(ctx_ref, pt_ref, win_ref, lyr_ref, *rest, **kw):
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "logits_soft_cap",
-                                    "scale"))
+                                    "scale", "walk"))
 def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
                                  v_pages: jnp.ndarray,
                                  page_table: jnp.ndarray,
@@ -209,7 +242,11 @@ def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
                                  interpret: bool = False,
                                  logits_soft_cap: float = 0.0,
                                  scale: float = None,
-                                 layer: jnp.ndarray = None) -> jnp.ndarray:
+                                 layer: jnp.ndarray = None,
+                                 walk: int = None) -> jnp.ndarray:
+    """``walk``: grid columns a row (``decode_walk_columns`` of the
+    caller's STATIC window; None or MP walks the whole table). Under a
+    shorter walk ``win`` must hold that window."""
     B, Hq, D = q.shape
     layered = layer is not None
     if layered:
@@ -228,6 +265,17 @@ def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
     has_sinks = sinks is not None
     sk2 = (sinks.astype(jnp.float32).reshape(Hq, 1) if has_sinks
            else jnp.zeros((Hq, 1), jnp.float32))
+    if walk is None:
+        walk = MP
+
+    def column(b, p, ctx, w):
+        """Table column of grid column p in row b. Clamped to the table:
+        the body skips what the clamp repeats."""
+        if walk < MP:
+            first = _first_column(_query_pos(ctx[b], has_current), w[0],
+                                  page_size)
+            return jnp.minimum(first + p, MP - 1)
+        return p
 
     if layered:
         # Pool blocks index (layer, page) straight out of the FULL
@@ -236,21 +284,23 @@ def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
         lyr = layer.reshape(1).astype(jnp.int32)
         pool_spec = pl.BlockSpec(
             (1, 1, page_size, Hkv, D),
-            lambda b, p, ctx, pt, w, l: (l[0], pt[b, p], 0, 0, 0))
+            lambda b, p, ctx, pt, w, l: (
+                l[0], pt[b, column(b, p, ctx, w)], 0, 0, 0))
         n_prefetch = 4
         def small(ix):
             return lambda b, p, ctx, pt, w, l: ix(b)
     else:
         pool_spec = pl.BlockSpec(
             (1, page_size, Hkv, D),
-            lambda b, p, ctx, pt, w: (pt[b, p], 0, 0, 0))
+            lambda b, p, ctx, pt, w: (
+                pt[b, column(b, p, ctx, w)], 0, 0, 0))
         n_prefetch = 3
         def small(ix):
             return lambda b, p, ctx, pt, w: ix(b)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,  # ctx, page_table, win[, layer]
-        grid=(B, MP),
+        grid=(B, walk),
         in_specs=[
             pl.BlockSpec((1, Hq, D), small(lambda b: (b, 0, 0))),
             pool_spec,
@@ -271,7 +321,8 @@ def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
     out = pl.pallas_call(
         functools.partial(_kernel_layered if layered else _kernel,
                           page_size=page_size, pages_per_seq=MP,
-                          num_kv_heads=Hkv, has_current=has_current,
+                          walk=walk, num_kv_heads=Hkv,
+                          has_current=has_current,
                           logits_soft_cap=logits_soft_cap, scale=scale,
                           has_sinks=has_sinks, layered=layered),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),

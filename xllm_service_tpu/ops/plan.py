@@ -123,6 +123,21 @@ class KernelPlan:
             interpret=default_interpret())
 
 
+def decode_walk_columns(table_width: int, page_size: int,
+                        sliding_window) -> int:
+    """Columns of a ``table_width``-wide page table that the decode
+    attention kernel's grid walks for each row. A window of W positions
+    spans at most ceil(W / page_size) + 1 pages wherever it starts, so
+    under a STATIC positive window the walk is that many columns, from
+    the row's own first live page (ops/pallas/paged_attention.py).
+    Everything else walks the whole table: full attention (0), a traced
+    window (the per-layer window vectors: a scan body has one grid), and
+    a window whose span is no shorter than the table."""
+    if isinstance(sliding_window, int) and sliding_window > 0:
+        return min(table_width, -(-sliding_window // page_size) + 1)
+    return table_width
+
+
 def _on_tpu() -> bool:
     # A backend that fails to initialise raises here: "no TPU" must not
     # be how a broken TPU run looks (it would switch the kernels off and

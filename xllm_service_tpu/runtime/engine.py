@@ -45,7 +45,7 @@ from jax.experimental.layout import Format, Layout
 from xllm_service_tpu.config import EngineConfig, ModelConfig
 from xllm_service_tpu.models import transformer
 from xllm_service_tpu.obs import steptrace
-from xllm_service_tpu.ops.plan import KernelPlan
+from xllm_service_tpu.ops.plan import KernelPlan, decode_walk_columns
 from xllm_service_tpu.ops.sampling import (
     SamplingTensors, compute_logprobs, compute_top_logprobs, sample_tokens,
     update_counts)
@@ -309,7 +309,16 @@ class Engine:
         # again (a program traced later, for a new bucket or table
         # width, is traced under this same plan).
         self.plan = KernelPlan.from_env(model_cfg, engine_cfg, mesh)
-        logger.info("engine plan: %s", self.plan)
+        # The window the decode-attention kernel is handed as a STATIC:
+        # a uniform-window model's, where the kernel serves (a per-layer
+        # window vector rides the layer scan traced, latent attention
+        # passes none, the XLA reference gathers the whole table).
+        self._static_window = (
+            (model_cfg.sliding_window or 0)
+            if self.plan.decode_attn and model_cfg.layer_sliding is None
+            and not model_cfg.mla else 0)
+        logger.info("engine plan: %s; decode walk %d of %d columns",
+                    self.plan, self._decode_walk(MP), MP)
         if self.plan.uses_kernels:
             # The kernels are loaded here (1.2-1.6 s of
             # jax.experimental.pallas), where an engine is built, and
@@ -1642,6 +1651,13 @@ class Engine:
         mp = 1 << max(mp - 1, 0).bit_length()
         return min(mp, self.ecfg.max_pages_per_seq)
 
+    def _decode_walk(self, mp: int) -> int:
+        """Columns of an ``mp``-wide table that the decode program's
+        attention walks for each row (``mp`` unless a static window is
+        narrower: ops/plan.py ``decode_walk_columns``)."""
+        return decode_walk_columns(mp, self.ecfg.page_size,
+                                   self._static_window)
+
     def _run_decode(self) -> List[StepOutput]:
         B = self.ecfg.max_batch_size
         # Restore the pages-cover-len invariant at dispatch regardless of
@@ -1687,7 +1703,7 @@ class Engine:
                 mirror = block.copy()
         cache_before = self._jit_cache_size(self._jit_decode)
         with self._phase("decode.dispatch", program="decode", B=B, T=1,
-                         MP=mp):
+                         MP=mp, walk=self._decode_walk(mp)):
             # The program splits the key itself and hands the first half
             # back: the values of a host-side split, with no program of
             # its own between two steps.
