@@ -53,6 +53,29 @@ def _decode_writer(sds, hkv=HKV, d=D):
              sds((), jnp.int32)), {"donate_argnums": (0, 1)})
 
 
+def _latent_pool(sds):
+    """The latent pool as its kernels take it: [L, P, ps, D]."""
+    return sds((L, P, PS, MLA_D), jnp.bfloat16)
+
+
+def _latent_attention(sds):
+    from xllm_service_tpu.ops.pallas.latent import latent_decode_attention
+    return (functools.partial(latent_decode_attention, scale=0.07,
+                              interpret=False),
+            (sds((B_DEC, 16, MLA_D), jnp.bfloat16), _latent_pool(sds),
+             sds((B_DEC, MP), jnp.int32), sds((B_DEC,), jnp.int32),
+             sds((), jnp.int32)), {})
+
+
+def _latent_writer(sds):
+    from xllm_service_tpu.ops.pallas.latent import latent_kv_update_layer
+    return (functools.partial(latent_kv_update_layer, interpret=False),
+            (_latent_pool(sds), sds((B_DEC, MLA_D), jnp.bfloat16),
+             sds((B_DEC, MP), jnp.int32), sds((B_DEC,), jnp.int32),
+             sds((B_DEC,), jnp.bool_), sds((), jnp.int32)),
+            {"donate_argnums": (0,)})
+
+
 def _prefill_writer(sds, hkv=HKV, d=D):
     from xllm_service_tpu.ops.pallas.kv_update import (
         paged_prefill_kv_update_layer)
@@ -111,6 +134,10 @@ KERNELS = {
         _decode_writer, hkv=MLA_HKV, d=MLA_D),
     "mla-prefill-kv-writer": functools.partial(
         _prefill_writer, hkv=MLA_HKV, d=MLA_D),
+    # What a write-then-attend decode step of a latent model runs since
+    # PR 36 (ops/pallas/latent.py): the pool as [L, P, ps, 576].
+    "latent-decode-attention": _latent_attention,
+    "latent-decode-kv-writer": _latent_writer,
 }
 
 

@@ -148,13 +148,12 @@ class TestCensusAot:
         n = 2       # restored blocks per call; structurally identical
         #             at any count (the engine caches per distinct n)
 
-        args = (sds(POOL, jnp.bfloat16), sds(POOL, jnp.bfloat16),
-                sds((n,), jnp.int32),
-                sds((L, n, ps, Hkv, D), jnp.bfloat16),
-                sds((L, n, ps, Hkv, D), jnp.bfloat16))
-        pin = tuple(row_major_format(5, a.sharding) for a in args[:2])
-        compiled = aot_compile(restore, args, donate_argnums=(0, 1),
-                               in_shardings=(*pin, None, None, None),
+        blk = sds((L, n, ps, Hkv, D), jnp.bfloat16)
+        args = ((sds(POOL, jnp.bfloat16), sds(POOL, jnp.bfloat16)),
+                sds((n,), jnp.int32), (blk, blk))
+        pin = tuple(row_major_format(5, a.sharding) for a in args[0])
+        compiled = aot_compile(restore, args, donate_argnums=(0,),
+                               in_shardings=(pin, None, None),
                                out_shardings=pin)
         hits = census_pool_copies(compiled.as_text(), POOL)
         assert hits == [], hits

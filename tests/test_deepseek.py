@@ -212,9 +212,9 @@ def test_mla_engine_greedy_matches_hf(tmp_path):
 
 
 def test_mla_decode_kernel_gate_matches_reference(tmp_path, monkeypatch):
-    """XLLM_PALLAS_MLA=1 routes absorbed-MLA decode through the paged
+    """With the kernels on, absorbed-MLA decode goes through the paged
     decode kernel (Pallas interpreter on CPU) — greedy tokens must equal
-    the default XLA-reference serving path."""
+    the XLA-reference serving path's."""
     model = _make_hf("lite")
     model.save_pretrained(str(tmp_path), safe_serialization=True)
     cfg, params = _load_ours(str(tmp_path))
@@ -224,11 +224,11 @@ def test_mla_decode_kernel_gate_matches_reference(tmp_path, monkeypatch):
 
     def run(kernel: bool):
         monkeypatch.setenv("XLLM_PALLAS", "1" if kernel else "0")
-        monkeypatch.setenv("XLLM_PALLAS_MLA", "1" if kernel else "0")
         eng = Engine(cfg, EngineConfig(
             page_size=4, num_pages=64, max_model_len=128,
             max_batch_size=2, max_prefill_tokens=64,
             prefill_buckets=(8, 16, 32, 64)), params=params)
+        assert eng.plan.latent_decode is kernel
         eng.add_request(EngineRequest(
             request_id="mla", token_ids=list(prompt),
             sampling=SamplingParams(max_tokens=steps, temperature=0.0,

@@ -1322,6 +1322,52 @@ def test_a_fault_reset_drops_the_carry():
     assert done["a"] == FinishReason.LENGTH
 
 
+@pytest.mark.parametrize("decode_steps", [1, 4])
+def test_warmup_compiles_side_by_side_and_its_calls_compile_nothing(
+        decode_steps):
+    """Warm-up lowers every program, compiles them in threads, and only
+    then calls them: each call finds the executable its own lowering
+    holds, so the step programs are compiled ONCE each, all of them
+    before the first is run (a latent model's ten programs were 217 s one
+    after another on a v5e, where its pools' pin bans the persistent
+    cache; PERF.md, PR 36)."""
+    from jax import monitoring
+    eng = _steady_engine(page_size=4, decode_steps=decode_steps)
+    compiled = []
+    monitoring.register_event_duration_secs_listener(
+        lambda event, _dur, **kw: compiled.append(event) if event ==
+        "/jax/core/compile/backend_compile_duration" else None)
+    walks = []
+    real = Engine._warm_programs
+
+    def walk(self, launch, *a):
+        walks.append(len(compiled))
+        real(self, launch, *a)
+        walks.append(len(compiled))
+
+    Engine._warm_programs = walk
+    try:
+        eng.warmup(prefill_shapes=[(2, 8, 2), (1, 8, 2)],
+                   decode_widths=[2, 4])
+    finally:
+        Engine._warm_programs = real
+    calling = walks[3] - walks[2]
+    programs = 2 + 2        # two prefill shapes, one decode program a width
+    # The threads have compiled every step program (and the lowering walk
+    # the tiny programs that make its inert arguments) before the first
+    # one is called.
+    assert walks[2] - walks[0] >= programs
+    # The calling walk compiles no step program either: at most the tiny
+    # programs around them (a key split, a slice of its result).
+    report = eng.compile_report()
+    assert report["prefill"] == 2
+    if decode_steps == 1:
+        assert report["decode"] == 2
+    else:       # a burst's two call signatures a width share one executable
+        assert report["decode_multi"] == 4
+    assert calling <= 2, (walks, compiled)
+
+
 def test_warmup_leaves_the_key_and_one_cache_entry_per_width():
     """Warm-up runs the decode program with a throwaway key, and a hit
     step, a miss step and warm-up share ONE call signature per width:

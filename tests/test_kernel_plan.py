@@ -27,8 +27,8 @@ from xllm_service_tpu.ops import plan as plan_mod
 from xllm_service_tpu.ops.plan import KernelPlan
 from xllm_service_tpu.runtime import engine as E
 
-GATES = ("XLLM_PALLAS", "XLLM_PALLAS_PREFILL", "XLLM_PALLAS_MLA",
-         "XLLM_PALLAS_KV", "XLLM_PALLAS_INTERPRET", "XLLM_RAGGED_ATTN",
+GATES = ("XLLM_PALLAS", "XLLM_PALLAS_PREFILL", "XLLM_PALLAS_KV",
+         "XLLM_PALLAS_INTERPRET", "XLLM_RAGGED_ATTN",
          "XLLM_WRITE_THEN_ATTEND")
 
 # What "the kernels are on" resolves to, before the opt-ins.
@@ -55,11 +55,17 @@ TABLE = [
      False, {}, {}),
     ("prefill-off-on-tpu-by-default", True, False,
      {"XLLM_PALLAS_PREFILL": "true"}, False, {}, ON),
-    ("mla-opt-in", True, False, {"XLLM_PALLAS_MLA": "1"}, True, {},
+    # A latent pool's decode attention takes the kernel wherever the
+    # kernels are on (decided on the chip, PR 36), and only there.
+    ("mla-kernel-on-tpu", True, False, {}, True, {},
      dict(ON, latent_decode=True)),
-    ("mla-needs-base", True, False,
-     {"XLLM_PALLAS": "0", "XLLM_PALLAS_MLA": "1"}, True, {}, {}),
-    ("mla-off-by-default", True, False, {}, True, {}, ON),
+    ("mla-needs-base", True, False, {"XLLM_PALLAS": "0"}, True, {}, {}),
+    # ... and only as a write-then-attend pair (ops/pallas/latent.py).
+    ("mla-kernels-need-write-then-attend", True, False,
+     {"XLLM_WRITE_THEN_ATTEND": "0"}, True, {},
+     dict(ON, write_then_attend=False)),
+    ("mla-kernel-interpreted-on-cpu", False, False, {"XLLM_PALLAS": "1"},
+     True, {}, dict(ON, latent_decode=True)),
     # The writers follow the base gate, can be switched off alone, and
     # can be FORCED on with the attention kernels off.
     ("kv-forced-on-kernels-off", False, False,
@@ -72,7 +78,7 @@ TABLE = [
     # A mesh: the XLA reference everywhere, whatever the gates say.
     ("mesh-every-gate-set", True, True,
      {"XLLM_PALLAS": "1", "XLLM_PALLAS_PREFILL": "1",
-      "XLLM_PALLAS_MLA": "1", "XLLM_PALLAS_KV": "1"}, True, {}, {}),
+      "XLLM_PALLAS_KV": "1"}, True, {}, {}),
     ("mesh-tpu-default", True, True, {}, False, {}, {}),
     ("mesh-wta-asked-for", False, True, {"XLLM_WRITE_THEN_ATTEND": "1"},
      False, {}, dict(write_then_attend=True)),
@@ -104,7 +110,7 @@ TABLE = [
     ("ragged-env-beats-field", False, False, {"XLLM_RAGGED_ATTN": "0"},
      False, dict(ragged_attn=True), {}),
     ("ragged-never-for-mla", True, False, {"XLLM_RAGGED_ATTN": "1"}, True,
-     dict(ragged_attn=True), ON),
+     dict(ragged_attn=True), dict(ON, latent_decode=True)),
     # Prefill windows start on pages iff every bucket is a page multiple.
     ("unaligned-buckets", True, False, {}, False,
      dict(prefill_buckets=(12, 32)), dict(ON, page_aligned=False)),
@@ -129,6 +135,27 @@ def _mla_cfg():
     return dataclasses.replace(
         ModelConfig.tiny(), kv_lora_rank=32, qk_nope_head_dim=16,
         qk_rope_head_dim=8, v_head_dim=16)
+
+
+@pytest.mark.parametrize("on_tpu,mesh,env,want", [
+    (True, False, {}, True), (False, False, {}, False),
+    (False, False, {"XLLM_PALLAS": "1"}, True),
+    (True, False, {"XLLM_PALLAS": "0"}, False), (True, True, {}, False)])
+def test_the_expert_layers_grouped_matmul_follows_the_base_gate(
+        monkeypatch, no_gates, on_tpu, mesh, env, want):
+    """``expert_gmm``: the Pallas grouped matmul wherever the kernels are
+    on, for the one family whose sparse layers call it (latent attention
+    with experts); XLA's ``ragged_dot`` elsewhere, and on a mesh."""
+    monkeypatch.setattr(plan_mod, "_on_tpu", lambda: on_tpu)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    sparse = dataclasses.replace(_mla_cfg(), num_experts=8,
+                                 num_experts_per_tok=2)
+    got = KernelPlan.from_env(sparse, _ecfg(), object() if mesh else None)
+    assert got.expert_gmm is want
+    assert got.uses_kernels or not want
+    for other in (_mla_cfg(), ModelConfig.tiny(num_experts=4)):
+        assert not KernelPlan.from_env(other, _ecfg(), None).expert_gmm
 
 
 @pytest.fixture

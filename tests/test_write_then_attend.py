@@ -336,6 +336,35 @@ class TestEngineWriteThenAttend:
         # the XLA reference gathers the whole table
         assert all(a["walk"] == a["MP"] for a in ref_args)
 
+    @pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernels"])
+    def test_a_prefill_table_is_clamped_to_the_sequences_pages(
+            self, monkeypatch, pallas):
+        """Under write-then-attend a prefill's table is no wider than
+        ``max_pages_per_seq`` (6 pages here, where the power of two over
+        them is 8). A cached late start whose bucket overshoots the
+        table (start 80, bucket 32: positions to 112 of 96) writes and
+        attends what the overlay's full-width table does."""
+        from xllm_service_tpu.runtime.engine import Engine
+        widths = []
+        real = Engine._prefill_table_width
+
+        def spy(self, pages):
+            widths.append(real(self, pages))
+            return widths[-1]
+        monkeypatch.setattr(Engine, "_prefill_table_width", spy)
+        kw = dict(max_model_len=96, max_prefill_tokens=96,
+                  prefill_buckets=(32, 64, 96))
+        doc = [(7 * i + 3) % 251 for i in range(80)]
+        prompts = [doc + [5, 6, 7, 8, 9, 10, 11, 12, 13, 14], doc + [99]]
+        env = {"XLLM_PALLAS": pallas, "XLLM_PALLAS_INTERPRET": "1"}
+        on = _run_engine(monkeypatch, dict(env, XLLM_WRITE_THEN_ATTEND="1"),
+                         prompts=prompts, max_tokens=4, ecfg_kw=kw)
+        clamped, widths[:] = sorted(set(widths)), []
+        off = _run_engine(monkeypatch, dict(env, XLLM_WRITE_THEN_ATTEND="0"),
+                          prompts=prompts, max_tokens=4, ecfg_kw=kw)
+        assert on == off
+        assert max(clamped) == 6 and max(widths) == 8
+
     def test_env_flag_reaches_the_engines_plan(self, monkeypatch):
         """The variable lands in the plan of an engine built under it
         (tests/test_kernel_plan.py holds the resolver's whole table),
@@ -385,7 +414,8 @@ class TestMlaWriteThenAttend:
         pt = jnp.asarray(np.arange(1, B * 6 + 1).reshape(B, 6), jnp.int32)
         last, _, kv2 = transformer.forward_prefill(
             params, cfg, toks, starts, lens, kv, pt, plan=plan)
-        return (np.asarray(last), np.asarray(kv2[0]), np.asarray(kv2[1]))
+        assert len(kv2) == 1        # the one latent pool
+        return (np.asarray(last), np.asarray(kv2[0]))
 
     def test_mla_wta_matches_baseline(self):
         base = self._forward(wta=False, start=8, T=16,

@@ -203,6 +203,8 @@ class ModelConfig:
     # Sparse dispatch capacity factor (parallel/expert.py): each expert
     # takes ≤ ceil(k·G·cf/E) tokens per group. ≥ E/k guarantees no drops;
     # 0 selects the dense-compute oracle (every expert on every token).
+    # Decides nothing on the latent (MLA) path, whose sparse layers are
+    # dropless (expert.dropless_moe).
     moe_capacity_factor: float = 2.0
     # Dispatch group size G: tokens route in groups so the dispatch /
     # combine masks are [G, E, C_g] per group — linear, not quadratic, in
@@ -451,8 +453,11 @@ class ModelConfig:
                      "mixtral", "gemma2", "gemma3", "gemma3_text",
                      "qwen2_vl", "qwen2_5_vl",
                      "qwen3_moe", "deepseek_v2", "deepseek_v3",
-                     "gpt_oss")
-        _dsk = mt in ("deepseek_v2", "deepseek_v3")
+                     "joyai_llm_flash", "gpt_oss")
+        # The latent family: DeepSeek-V2, and the V3 layer (sigmoid
+        # scores, selection bias) that JD's JoyAI-LLM-Flash shares.
+        _v3 = mt in ("deepseek_v3", "joyai_llm_flash")
+        _dsk = mt == "deepseek_v2" or _v3
         if _dsk:
             tkm = d.get("topk_method")
             ok = ((None, "greedy", "group_limited_greedy")
@@ -465,7 +470,7 @@ class ModelConfig:
                 raise ValueError(
                     f"deepseek topk_method {tkm!r} is not implemented")
             sf = d.get("scoring_func")
-            want_sf = "sigmoid" if mt == "deepseek_v3" else "softmax"
+            want_sf = "sigmoid" if _v3 else "softmax"
             if sf is not None and sf != want_sf:
                 raise ValueError(
                     f"{mt} with scoring_func {sf!r} is not implemented "
@@ -584,7 +589,10 @@ class ModelConfig:
             num_layers=d["num_hidden_layers"],
             num_heads=d["num_attention_heads"],
             num_kv_heads=d.get("num_key_value_heads", d["num_attention_heads"]),
-            head_dim=d.get("head_dim"),
+            # A latent model's head widths are qk_nope/qk_rope/v_head_dim;
+            # a head_dim its config.json also carries (JoyAI-LLM-Flash:
+            # 64, the rope part) is no width of any of its tensors.
+            head_dim=None if _dsk else d.get("head_dim"),
             rope_theta=d.get("rope_theta", 10000.0),
             rms_norm_eps=d.get("rms_norm_eps", 1e-5),
             max_position_embeddings=d.get("max_position_embeddings", 4096),
@@ -628,14 +636,16 @@ class ModelConfig:
             n_shared_experts=(d.get("n_shared_experts") or 0) if _dsk
             else 0,
             routed_scaling_factor=d.get("routed_scaling_factor", 1.0),
-            # V3's "noaux_tc" IS grouped selection under sigmoid scoring.
-            topk_method=("group_limited_greedy" if mt == "deepseek_v3"
-                         else d.get("topk_method", "greedy")),
+            # V3's "noaux_tc" IS grouped selection under sigmoid scoring;
+            # with one group (JoyAI-LLM-Flash) nothing is limited.
+            topk_method=(("group_limited_greedy"
+                          if (d.get("n_group") or 1) > 1 else "greedy")
+                         if _v3 else d.get("topk_method", "greedy")),
             n_group=d.get("n_group"),
             topk_group=d.get("topk_group"),
             first_k_dense_replace=(d.get("first_k_dense_replace", 0)
                                    if _dsk else 0),
-            moe_scoring="sigmoid" if mt == "deepseek_v3" else "softmax",
+            moe_scoring="sigmoid" if _v3 else "softmax",
             gptoss=mt == "gpt_oss",
             rope_interleave=bool(d.get("rope_interleave", True)),
             # The mscale² softmax-scale fold follows the CHECKPOINT, not

@@ -53,11 +53,16 @@ from xllm_service_tpu.ops.attention import (
     write_decode_kv_all_layers,
     write_decode_kv_layer,
     write_prefill_kv_all_layers_xla,
+    write_prefill_kv_layer_xla,
+    write_decode_kv_all_layers_xla,
+    write_decode_kv_layer_xla,
 )
 from xllm_service_tpu.ops.plan import KernelPlan
 
 Params = Dict[str, Any]
-KVCache = Tuple[jnp.ndarray, jnp.ndarray]  # k_pages, v_pages: [L, P, ps, Hkv, Dh]
+# k_pages, v_pages: [L, P, ps, Hkv, Dh]; under latent attention the one
+# latent pool alone (init_kv_cache).
+KVCache = Tuple[jnp.ndarray, ...]
 
 
 # ---------------------------------------------------------------------------
@@ -133,10 +138,13 @@ def num_params(params: Params) -> int:
 def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                   dtype: Optional[jnp.dtype] = None) -> KVCache:
     dtype = dtype or jnp.dtype(cfg.dtype)
-    # MLA: one latent "head" of width kv_lora_rank + qk_rope_head_dim per
-    # token instead of per-head K/V (cfg.kv_cache_{heads,dim}).
     shape = (cfg.num_layers, num_pages, page_size, cfg.kv_cache_heads,
              cfg.kv_cache_dim)
+    if cfg.mla:
+        # ONE pool: a token's cached row under latent attention is one
+        # latent "head" of width kv_lora_rank + qk_rope_head_dim
+        # (cfg.kv_cache_{heads,dim}) that serves as key and value both.
+        return (jnp.zeros(shape, dtype),)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
@@ -993,9 +1001,11 @@ def _init_mla_params(cfg: ModelConfig, key: jax.Array,
 
 def _deepseek_gate(cfg: ModelConfig, x: jnp.ndarray,
                    router_w: jnp.ndarray,
-                   bias: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Routing scores AFTER DeepSeek's selection rules, as a dense [.., E]
-    weight map.
+                   bias: Optional[jnp.ndarray] = None
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The experts each token takes and their weights AFTER DeepSeek's
+    selection rules: ``(topi [.., k] int32, topw [.., k] float32)``. The
+    one place the choice is made.
 
     V2 (softmax scoring): softmax over fp32 logits; group-limited
     routing zeroes every expert outside the top ``topk_group`` of
@@ -1027,43 +1037,76 @@ def _deepseek_gate(cfg: ModelConfig, x: jnp.ndarray,
         choice = jnp.where(jnp.repeat(gmask, E // G, axis=-1) > 0,
                            choice, 0.0)
     _, topi = jax.lax.top_k(choice, cfg.num_experts_per_tok)
-    sel = _scatter_topk(
-        jnp.ones(topi.shape, scores.dtype), topi, E)
     # V3 combines with the RAW sigmoid scores (bias shapes choice only);
     # V2 combines with the masked selection values themselves.
-    weights = (scores if sigmoid else choice) * sel
+    topw = jnp.take_along_axis(scores if sigmoid else choice, topi,
+                               axis=-1)
     if sigmoid and cfg.norm_topk_prob:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                             + 1e-20)
-    return weights * cfg.routed_scaling_factor
+        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-20)
+    return topi.astype(jnp.int32), topw * cfg.routed_scaling_factor
+
+
+def moe_stats_shape(cfg: ModelConfig) -> Tuple[int, ...]:
+    """Shape of what a step's sparse layers count: the latent family's
+    count what they routed (``expert.MOE_STATS``, summed over layers;
+    element 0 is the dropped count every family reports), every other
+    model has the one scalar."""
+    from xllm_service_tpu.parallel.expert import MOE_STATS
+    return (len(MOE_STATS),) if cfg.mla and cfg.is_moe else ()
+
+
+def _moe_stats_dict(moe_stats: jnp.ndarray) -> Dict[str, jnp.ndarray]:
+    """The ``return_stats`` dict of the latent forwards: ``moe_dropped``
+    as every family gives it, and the whole vector under ``moe``."""
+    if moe_stats.ndim == 0:
+        return {"moe_dropped": moe_stats}
+    return {"moe_dropped": moe_stats[0], "moe": moe_stats}
+
+
+def step_moe_stats(stats: Dict[str, jnp.ndarray]) -> jnp.ndarray:
+    """What a step program hands the engine of ``return_stats``: the
+    ``expert.MOE_STATS`` vector where the model counts it, else the
+    dropped scalar (``moe_stats_shape``)."""
+    return stats.get("moe", stats["moe_dropped"])
+
+
+# The routed experts' weights: the layer scan hands them on WHOLE (a
+# closure, with the layer's index) and never as its per-layer slice.
+_EXPERT_LEAVES = ("gate_proj", "up_proj", "down_proj")
+
+
+def _split_experts(stack: Dict[str, jnp.ndarray]):
+    """A sparse stack as (what the scan slices, the experts' stacks)."""
+    return ({k: v for k, v in stack.items() if k not in _EXPERT_LEAVES},
+            {k: stack[k] for k in _EXPERT_LEAVES})
 
 
 def _mla_moe_mlp(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
-                 x: jnp.ndarray,
-                 valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Routed experts + the always-on shared experts. The DeepSeek gate
-    (group limits, scaling, no normalization) produces a dense weight
-    map; with a capacity factor the map feeds the group-chunked sparse
-    dispatch (top-k FLOPs, ep-shardable) — only cf == 0 runs the dense
-    every-expert oracle (the test reference)."""
-    weights = _deepseek_gate(cfg, x, lp["router"],
-                             lp.get("router_bias"))          # [B, T, E]
-    if cfg.moe_capacity_factor > 0:
-        from xllm_service_tpu.parallel.expert import moe_mlp
-        routed, _ = moe_mlp(
-            x, lp["router"], lp["gate_proj"], lp["up_proj"],
-            lp["down_proj"], cfg.num_experts_per_tok,
-            cfg.moe_capacity_factor, valid=valid,
-            group_size=cfg.moe_group_size, norm_topk=False,
-            gates=weights)
-    else:
-        h = jax.nn.silu(jnp.einsum("btd,edf->btef", x, lp["gate_proj"])) \
-            * jnp.einsum("btd,edf->btef", x, lp["up_proj"])
-        out = jnp.einsum("btef,efd->bted", h, lp["down_proj"])
-        routed = jnp.einsum("bted,bte->btd", out, weights.astype(x.dtype))
+                 experts: Dict[str, jnp.ndarray], layer: jnp.ndarray,
+                 x: jnp.ndarray, valid: Optional[jnp.ndarray] = None,
+                 plan: KernelPlan = KernelPlan()
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Routed experts + the always-on shared experts of sparse layer
+    ``layer`` (its index in the ``experts`` stacks); returns
+    ``(out [B, T, D], stats)``. The DeepSeek gate chooses; the dropless
+    layer (parallel/expert.py) computes exactly what it chose, whatever
+    ``moe_capacity_factor`` says: no buckets on this path, nothing
+    dropped, and ``stats`` counts it."""
+    from xllm_service_tpu.parallel.expert import dropless_moe
+    B, T, D = x.shape
+    topi, topw = _deepseek_gate(cfg, x, lp["router"],
+                                lp.get("router_bias"))       # [B, T, k]
+    k = topi.shape[-1]
+    vf = (jnp.ones((B * T,), bool) if valid is None
+          else jnp.broadcast_to(valid, (B, T)).reshape(B * T))
+    routed, stats = dropless_moe(
+        x.reshape(B * T, D), topi.reshape(B * T, k),
+        topw.reshape(B * T, k), vf, experts["gate_proj"],
+        experts["up_proj"], experts["down_proj"], layer=layer,
+        kernel=plan.expert_gmm, interpret=plan.interpret)
     shared = (jax.nn.silu(x @ lp["shared_gate"]) * (x @ lp["shared_up"])) \
         @ lp["shared_down"] if "shared_gate" in lp else 0.0
-    return routed + shared
+    return routed.reshape(B, T, D) + shared, stats
 
 
 def _mla_qkv(cfg: ModelConfig, lp, h, positions):
@@ -1131,7 +1174,7 @@ def _mla_forward_prefill(params: Params, cfg: ModelConfig,
                          prompt_lp_targets: Optional[jnp.ndarray] = None,
                          return_stats: bool = False,
                          plan: KernelPlan = KernelPlan()):
-    k_pages, v_pages = kv
+    k_pages, = kv
     write_then_attend = plan.write_then_attend
     L_dense = params["layers"]["input_norm"].shape[0]
     x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
@@ -1145,69 +1188,70 @@ def _mla_forward_prefill(params: Params, cfg: ModelConfig,
     def body(moe: bool):
         def layer(carry, xs):
             if write_then_attend:
-                x, kp_full, vp_full = carry
+                x, kp_full = carry
                 lp, li = xs
                 h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
                 q_t, latent = _mla_qkv(cfg, lp, h, positions)
-                # Write the latent window first (both pools carry the
-                # same latent row — the engine's uniform (k, v)
-                # plumbing), then attend from the pool: no overlay.
-                kp_full, vp_full = write_prefill_kv_layer(
-                    kp_full, vp_full, latent, latent,
-                    page_table, start_pos, lengths, li, plan)
-                kp = jax.lax.dynamic_index_in_dim(kp_full, li, axis=0,
-                                                  keepdims=False)
+                # Write the latent window first (the one pool: its row
+                # is key and value both), then attend from the pool: no
+                # overlay.
+                kp_full, = write_prefill_kv_layer_xla(
+                    kp_full, None, latent, None,
+                    page_table, start_pos, lengths, li)
+                kp = jax.lax.dynamic_index_in_dim(
+                    kp_full, li, axis=0, keepdims=False)
                 lat_all = gather_pages(kp, page_table)
                 attn = mha_prefill_auto(q_t, lat_all, lat_all,
                                         kv_lengths, start_pos,
                                         scale=_mla_scale(cfg))
             else:
                 x, = carry
-                lp, kp, vp = xs
+                lp, li, kp = xs
                 h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
                 q_t, latent = _mla_qkv(cfg, lp, h, positions)
-                lat_all = overlay_fresh_kv(gather_pages(kp, page_table),
-                                           latent, start_pos)
-                attn = mha_prefill_auto(q_t, lat_all, lat_all, kv_lengths,
-                                        start_pos, scale=_mla_scale(cfg))
+                lat_all = overlay_fresh_kv(
+                    gather_pages(kp, page_table), latent, start_pos)
+                attn = mha_prefill_auto(q_t, lat_all, lat_all,
+                                        kv_lengths, start_pos,
+                                        scale=_mla_scale(cfg))
             x = x + _mla_out(cfg, lp, attn)
             h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
+            stats = None
             if moe:
-                x = x + _mla_moe_mlp(cfg, lp, h, valid=tok_valid)
+                y, stats = _mla_moe_mlp(cfg, lp, experts, li - L_dense, h,
+                                        valid=tok_valid, plan=plan)
+                x = x + y
             else:
                 x = x + (jax.nn.silu(h @ lp["gate_proj"])
                          * (h @ lp["up_proj"])) @ lp["down_proj"]
             if write_then_attend:
-                return (x, kp_full, vp_full), None
-            return (x,), (latent, latent)
+                return (x, kp_full), stats
+            return (x,), (latent, stats)
         return layer
 
+    moe_stats = jnp.zeros(moe_stats_shape(cfg), jnp.int32)
+    li_d = jnp.arange(L_dense, dtype=jnp.int32)
+    if "layers_moe" in params:
+        sparse, experts = _split_experts(params["layers_moe"])
+        li_m = L_dense + jnp.arange(sparse["input_norm"].shape[0],
+                                    dtype=jnp.int32)
     if write_then_attend:
-        li_d = jnp.arange(L_dense, dtype=jnp.int32)
-        (x, k_pages, v_pages), _ = jax.lax.scan(
-            body(False), (x, k_pages, v_pages), (params["layers"], li_d))
+        (x, k_pages), _ = jax.lax.scan(
+            body(False), (x, k_pages), (params["layers"], li_d))
         if "layers_moe" in params:
-            n_moe = params["layers_moe"]["input_norm"].shape[0]
-            li_m = L_dense + jnp.arange(n_moe, dtype=jnp.int32)
-            (x, k_pages, v_pages), _ = jax.lax.scan(
-                body(True), (x, k_pages, v_pages),
-                (params["layers_moe"], li_m))
+            (x, k_pages), moe_l = jax.lax.scan(
+                body(True), (x, k_pages), (sparse, li_m))
+            moe_stats = jnp.sum(moe_l, axis=0)
     else:
-        (x,), (k_d, v_d) = jax.lax.scan(
-            body(False), (x,),
-            (params["layers"], k_pages[:L_dense], v_pages[:L_dense]))
+        (x,), (k_new, _) = jax.lax.scan(
+            body(False), (x,), (params["layers"], li_d, k_pages[:L_dense]))
         if "layers_moe" in params:
-            (x,), (k_m, v_m) = jax.lax.scan(
-                body(True), (x,),
-                (params["layers_moe"], k_pages[L_dense:],
-                 v_pages[L_dense:]))
-            k_new = jnp.concatenate([k_d, k_m], axis=0)
-            v_new = jnp.concatenate([v_d, v_m], axis=0)
-        else:
-            k_new, v_new = k_d, v_d
-        k_pages, v_pages = write_prefill_kv_all_layers(
-            k_pages, v_pages, k_new, v_new, page_table, start_pos,
-            lengths, plan)
+            (x,), (k_m, moe_l) = jax.lax.scan(
+                body(True), (x,), (sparse, li_m, k_pages[L_dense:]))
+            moe_stats = jnp.sum(moe_l, axis=0)
+            k_new = jnp.concatenate([k_new, k_m], axis=0)
+        k_pages, = write_prefill_kv_all_layers_xla(
+            k_pages, None, k_new, None, page_table, start_pos, lengths)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:
@@ -1216,11 +1260,11 @@ def _mla_forward_prefill(params: Params, cfg: ModelConfig,
     last_x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
     outs = [_head_logits(cfg, last_x, head),
             _head_logits(cfg, x, head) if return_all_logits else None,
-            (k_pages, v_pages)]
+            (k_pages,)]
     if prompt_lp_targets is not None:
         outs.append(_prompt_logprobs(x, head, prompt_lp_targets))
     if return_stats:
-        outs.append({"moe_dropped": jnp.zeros((), jnp.int32)})
+        outs.append(_moe_stats_dict(moe_stats))
     return tuple(outs)
 
 
@@ -1230,40 +1274,47 @@ def _mla_forward_decode(params: Params, cfg: ModelConfig,
                         page_table: jnp.ndarray,
                         return_stats: bool = False,
                         plan: KernelPlan = KernelPlan()):
-    k_pages, v_pages = kv
+    k_pages, = kv
     write_then_attend = plan.write_then_attend
     L_dense = params["layers"]["input_norm"].shape[0]
     x = params["embed"][tokens[:, None]].astype(jnp.dtype(cfg.dtype))
     cache_lens = jnp.where(active, positions, 0)
     B = tokens.shape[0]
 
-    if plan.latent_decode:
-        from xllm_service_tpu.ops.pallas import (
-            paged_decode_attention_pallas)
+    # Under plan.latent_decode (write-then-attend with the kernels on)
+    # a step carries the pool as [L, P, ps, D], the shape the latent
+    # kernels take (ops/pallas/latent.py); under the engine's pin the
+    # reshape moves nothing. Every other write of a latent pool is the
+    # XLA scatter: the paged writers' blocks [.., ps, 1, D] would have
+    # the pool copied into a row-major layout of 2.2 times its bytes.
+    flat = write_then_attend and plan.latent_decode
+    if flat:
+        from xllm_service_tpu.ops.pallas.latent import (
+            latent_decode_attention, latent_kv_update_layer)
 
     def body(moe: bool):
         def layer(carry, xs):
             if write_then_attend:
-                x, kp_full, vp_full = carry
+                x, kp_full = carry
                 lp, li = xs
                 h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
                 q_t, latent = _mla_qkv(cfg, lp, h, positions[:, None])
                 # Latent row into the pool first (aliased write), then
                 # attend from the pool with context INCLUDING the
-                # current token — no k_cur/v_cur plumbing. MLA keeps
-                # its own kernel opt-in (plan.latent_decode,
-                # XLLM_PALLAS_MLA): the absorbed block shape (Hkv=1,
-                # D=576) routes to the XLA gather reference otherwise.
-                kp_full, vp_full = write_decode_kv_layer(
-                    kp_full, vp_full, latent[:, 0], latent[:, 0],
-                    page_table, positions, active, li, plan)
+                # current token — no k_cur/v_cur plumbing: the latent
+                # kernels, else the XLA scatter and gather reference.
                 ctx = jnp.where(active, positions + 1, 0)
-                if plan.latent_decode:
-                    attn = paged_decode_attention_pallas(
-                        q_t[:, 0], kp_full, kp_full, page_table, ctx,
-                        k_cur=None, v_cur=None, scale=_mla_scale(cfg),
-                        layer=li, interpret=plan.interpret)
+                if flat:
+                    kp_full = latent_kv_update_layer(
+                        kp_full, latent[:, 0, 0], page_table, positions,
+                        active, li, interpret=plan.interpret)
+                    attn = latent_decode_attention(
+                        q_t[:, 0], kp_full, page_table, ctx, li,
+                        scale=_mla_scale(cfg), interpret=plan.interpret)
                 else:
+                    kp_full, = write_decode_kv_layer_xla(
+                        kp_full, None, latent[:, 0], None,
+                        page_table, positions, active, li)
                     kp = jax.lax.dynamic_index_in_dim(
                         kp_full, li, axis=0, keepdims=False)
                     attn = paged_decode_attention(
@@ -1271,73 +1322,62 @@ def _mla_forward_decode(params: Params, cfg: ModelConfig,
                         scale=_mla_scale(cfg))
             else:
                 x, = carry
-                lp, kp, vp = xs
+                lp, li, kp = xs
                 h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
                 q_t, latent = _mla_qkv(cfg, lp, h, positions[:, None])
-                # Both "k" and "v" reads come from the SAME latent pool
+                # Both "k" and "v" reads come from the ONE latent pool
                 # (kp twice — XLA CSEs the duplicate gather into one HBM
-                # read); the duplicate v_pages pool is write-only under
-                # MLA, a known 2x-storage cost of keeping the engine's
-                # uniform (k, v) pool plumbing (single-pool layout is a
-                # follow-up). The XLA reference path is the DEFAULT here
-                # even with the kernels on: the absorbed-MLA block shape
-                # (Hkv=1, D=r+rope=576 — not 128-lane-aligned) compiles
-                # for v5e (tests/test_chip_compile.py) but has no result
-                # checked on a chip; plan.latent_decode
-                # (XLLM_PALLAS_MLA=1) opts into it.
-                if plan.latent_decode:
-                    attn = paged_decode_attention_pallas(
-                        q_t[:, 0], kp, kp, page_table, cache_lens,
-                        k_cur=latent[:, 0], v_cur=latent[:, 0],
-                        scale=_mla_scale(cfg), interpret=plan.interpret)
-                else:
-                    attn = paged_decode_attention_current(
-                        q_t[:, 0], kp, kp, page_table, cache_lens,
-                        latent[:, 0], latent[:, 0], scale=_mla_scale(cfg))
+                # read), the current row folded in.
+                attn = paged_decode_attention_current(
+                    q_t[:, 0], kp, kp, page_table, cache_lens,
+                    latent[:, 0], latent[:, 0], scale=_mla_scale(cfg))
             x = x + _mla_out(cfg, lp, attn)[:, None, :]
             h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
+            stats = None
             if moe:
-                x = x + _mla_moe_mlp(cfg, lp, h, valid=active[:, None])
+                y, stats = _mla_moe_mlp(cfg, lp, experts, li - L_dense, h,
+                                        valid=active[:, None], plan=plan)
+                x = x + y
             else:
                 x = x + (jax.nn.silu(h @ lp["gate_proj"])
                          * (h @ lp["up_proj"])) @ lp["down_proj"]
             if write_then_attend:
-                return (x, kp_full, vp_full), None
-            return (x,), (latent[:, 0], latent[:, 0])
+                return (x, kp_full), stats
+            return (x,), (latent[:, 0], stats)
         return layer
 
+    moe_stats = jnp.zeros(moe_stats_shape(cfg), jnp.int32)
+    li_d = jnp.arange(L_dense, dtype=jnp.int32)
+    if "layers_moe" in params:
+        sparse, experts = _split_experts(params["layers_moe"])
+        li_m = L_dense + jnp.arange(sparse["input_norm"].shape[0],
+                                    dtype=jnp.int32)
     if write_then_attend:
-        li_d = jnp.arange(L_dense, dtype=jnp.int32)
-        (x, k_pages, v_pages), _ = jax.lax.scan(
-            body(False), (x, k_pages, v_pages), (params["layers"], li_d))
+        pool_shape = k_pages.shape
+        if flat:
+            k_pages = k_pages.reshape(pool_shape[:3] + pool_shape[4:])
+        (x, k_pages), _ = jax.lax.scan(
+            body(False), (x, k_pages), (params["layers"], li_d))
         if "layers_moe" in params:
-            n_moe = params["layers_moe"]["input_norm"].shape[0]
-            li_m = L_dense + jnp.arange(n_moe, dtype=jnp.int32)
-            (x, k_pages, v_pages), _ = jax.lax.scan(
-                body(True), (x, k_pages, v_pages),
-                (params["layers_moe"], li_m))
+            (x, k_pages), moe_l = jax.lax.scan(
+                body(True), (x, k_pages), (sparse, li_m))
+            moe_stats = jnp.sum(moe_l, axis=0)
+        k_pages = k_pages.reshape(pool_shape)
     else:
-        (x,), (k_d, v_d) = jax.lax.scan(
-            body(False), (x,),
-            (params["layers"], k_pages[:L_dense], v_pages[:L_dense]))
+        (x,), (k_new, _) = jax.lax.scan(
+            body(False), (x,), (params["layers"], li_d, k_pages[:L_dense]))
         if "layers_moe" in params:
-            (x,), (k_m, v_m) = jax.lax.scan(
-                body(True), (x,),
-                (params["layers_moe"], k_pages[L_dense:],
-                 v_pages[L_dense:]))
-            k_new = jnp.concatenate([k_d, k_m], axis=0)
-            v_new = jnp.concatenate([v_d, v_m], axis=0)
-        else:
-            k_new, v_new = k_d, v_d
-        k_pages, v_pages = write_decode_kv_all_layers(
-            k_pages, v_pages, k_new, v_new, page_table, positions, active,
-            plan)
+            (x,), (k_m, moe_l) = jax.lax.scan(
+                body(True), (x,), (sparse, li_m, k_pages[L_dense:]))
+            moe_stats = jnp.sum(moe_l, axis=0)
+            k_new = jnp.concatenate([k_new, k_m], axis=0)
+        k_pages, = write_decode_kv_all_layers_xla(
+            k_pages, None, k_new, None, page_table, positions, active)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:
         head = params["embed"].T
     logits = _head_logits(cfg, x[:, 0], head)
     if return_stats:
-        return logits, (k_pages, v_pages), \
-            {"moe_dropped": jnp.zeros((), jnp.int32)}
-    return logits, (k_pages, v_pages)
+        return logits, (k_pages,), _moe_stats_dict(moe_stats)
+    return logits, (k_pages,)

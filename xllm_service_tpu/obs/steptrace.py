@@ -69,6 +69,11 @@ STEP_FIELDS: Tuple[str, ...] = (
     "cache_hit_tokens", # prefix-cache hit-token delta this step
     "compiled",         # programs compiled AFTER warm-up in this step:
                         # tuple of "<program>:<shape key>" (0 is the contract)
+    "moe",              # what the step's sparse layers counted on the
+                        # device: {assignments, experts_touched, dropped,
+                        # load_max_over_mean (busiest expert's rows over
+                        # the mean of the touched ones)}; None where no
+                        # layer counts its routing
 )
 
 # ---------------------------------------------------------------------------

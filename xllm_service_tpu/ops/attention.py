@@ -151,7 +151,7 @@ def write_decode_kv_all_layers(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     row_bytes = L * Hkv_ * D_ * k_new.dtype.itemsize
     footprint = 2 * (4 * tile_bytes + 2 * row_bytes)
     # The MLA latent shape (Hkv=1, D=576) is INCLUDED: unlike the
-    # math-heavy MLA attention kernel (still behind XLLM_PALLAS_MLA),
+    # math-heavy MLA attention kernel (plan.latent_decode),
     # both writers are pure block-pipelined memory ops with
     # full-trailing-dims blocks, and BOTH Mosaic-compile at the latent
     # geometry in the offline v5e probe matrix
@@ -171,7 +171,13 @@ def write_decode_kv_all_layers_xla(k_pages, v_pages, k_new, v_new,
                                    page_table, positions, active
                                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The raw XLA scatter (kernel-free reference) — the gate's
-    fallback, and the A/B baseline the budget table pins by name."""
+    fallback, and the A/B baseline the budget table pins by name.
+
+    ``v_pages`` / ``v_new`` None (here and in the three ``_xla`` writers
+    below): a model under latent attention keeps ONE pool, whose row is
+    key and value both; the scatter runs over it alone and returns a
+    1-tuple (models/transformer.py ``_mla_forward_*`` call these
+    directly; the paged kernel writers never see a latent pool)."""
     L = k_pages.shape[0]
     page_size = k_pages.shape[2]
     num_slots = k_pages.shape[1] * page_size
@@ -180,6 +186,8 @@ def write_decode_kv_all_layers_xla(k_pages, v_pages, k_new, v_new,
     pool_shape = (L, -1) + k_pages.shape[3:]
     k_flat = k_pages.reshape(pool_shape).at[:, flat].set(
         k_new, mode="drop")
+    if v_pages is None:
+        return (k_flat.reshape(k_pages.shape),)
     v_flat = v_pages.reshape(pool_shape).at[:, flat].set(
         v_new, mode="drop")
     return (k_flat.reshape(k_pages.shape), v_flat.reshape(v_pages.shape))
@@ -236,6 +244,8 @@ def write_prefill_kv_all_layers_xla(k_pages, v_pages, k_new, v_new,
     new_shape = (L, B * T) + k_new.shape[3:]
     k_flat = k_pages.reshape(pool_shape).at[:, flat].set(
         k_new.reshape(new_shape), mode="drop")
+    if v_pages is None:
+        return (k_flat.reshape(k_pages.shape),)
     v_flat = v_pages.reshape(pool_shape).at[:, flat].set(
         v_new.reshape(new_shape), mode="drop")
     return (k_flat.reshape(k_pages.shape), v_flat.reshape(v_pages.shape))
@@ -279,6 +289,8 @@ def write_decode_kv_layer_xla(k_pages, v_pages, k_new, v_new, page_table,
     lyr = jnp.asarray(layer, jnp.int32)
     k_flat = k_pages.reshape(pool_shape).at[lyr, flat].set(
         k_new, mode="drop")
+    if v_pages is None:
+        return (k_flat.reshape(k_pages.shape),)
     v_flat = v_pages.reshape(pool_shape).at[lyr, flat].set(
         v_new, mode="drop")
     return (k_flat.reshape(k_pages.shape), v_flat.reshape(v_pages.shape))
@@ -328,6 +340,8 @@ def write_prefill_kv_layer_xla(k_pages, v_pages, k_new, v_new,
     lyr = jnp.asarray(layer, jnp.int32)
     k_flat = k_pages.reshape(pool_shape).at[lyr, flat].set(
         k_new.reshape(new_shape), mode="drop")
+    if v_pages is None:
+        return (k_flat.reshape(k_pages.shape),)
     v_flat = v_pages.reshape(pool_shape).at[lyr, flat].set(
         v_new.reshape(new_shape), mode="drop")
     return (k_flat.reshape(k_pages.shape), v_flat.reshape(v_pages.shape))

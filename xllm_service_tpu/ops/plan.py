@@ -40,7 +40,11 @@ class KernelPlan:
     # whose rows are decode rows and windows read from the pool alike
     decode_attn: bool = False
     prefill_attn: bool = False     # flash prefill (windows that tile pages)
-    latent_decode: bool = False    # absorbed-MLA decode (Hkv=1, D=r+rope)
+    # a latent model's decode write and attention (ops/pallas/latent.py)
+    latent_decode: bool = False
+    # the dropless expert layer's grouped matmuls: the Pallas one
+    # (ops/pallas/grouped_matmul.py), else XLA's jax.lax.ragged_dot
+    expert_gmm: bool = False
     kv_writers: bool = False       # in-place KV writers, else XLA scatter
     # The engine serves a mixed iteration as ONE ragged program ...
     mixed_step: bool = False
@@ -59,7 +63,8 @@ class KernelPlan:
     @property
     def uses_kernels(self) -> bool:
         return (self.decode_attn or self.prefill_attn
-                or self.latent_decode or self.kv_writers)
+                or self.latent_decode or self.expert_gmm
+                or self.kv_writers)
 
     def mixed_program(self) -> "KernelPlan":
         """The plan of the ragged mixed program: decode rows start
@@ -106,12 +111,21 @@ class KernelPlan:
             mixed = engine_cfg.ragged_attn
         return cls(
             decode_attn=base,
-            # Opt-in until a chip run has checked them; both need the
-            # base gate (no interpreter fallback on the serving path).
+            # Opt-in until a chip run has checked it; needs the base
+            # gate (no interpreter fallback on the serving path).
             prefill_attn=base
             and environ.get("XLLM_PALLAS_PREFILL", "0") == "1",
-            latent_decode=base
-            and environ.get("XLLM_PALLAS_MLA", "0") == "1",
+            # Decided on the chip (PERF.md, PR 36: batch 32, table width
+            # 96, contexts of 8k-10.5k over a 576-wide row): the kernel
+            # reads a row's pages once where the XLA reference gathers,
+            # copies and reads the whole table's slab. The latent
+            # kernels (ops/pallas/latent.py) are a write-then-attend
+            # pair: without it a latent model decodes on the reference.
+            latent_decode=base and model_cfg.mla and bool(wta),
+            # Chosen on the chip over ragged_dot (PERF.md, PR 36). Only
+            # the latent family's sparse layers call it, so only their
+            # plan says so.
+            expert_gmm=base and model_cfg.mla and model_cfg.is_moe,
             kv_writers=writers and mesh is None,
             # No ragged kernel for absorbed-MLA pools: they keep the
             # split path.

@@ -174,3 +174,100 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, gate_w: jnp.ndarray,
     requested = k * jnp.sum(vf.astype(jnp.int32))
     kept = jnp.sum(dispatch).astype(jnp.int32)
     return out, requested - kept
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer (the latent family's; PERF.md, PR 36). No buckets:
+# every (row, expert) assignment the gate made is computed, and nothing
+# else. The assignments are sorted by expert, each projection is ONE
+# grouped matmul over the sorted rows (an expert's rows are contiguous,
+# an expert without rows is never visited), and the rows go back to their
+# places weighted by the gate. Work and weight traffic follow the routing:
+# rows x k expert MLPs, and only the experts that received a row.
+# ---------------------------------------------------------------------------
+
+# What ``dropless_moe`` counts, in the order of its int32 stats vector.
+MOE_STATS: Tuple[str, ...] = (
+    "dropped",          # assignments the gate made and no expert computed
+    "assignments",      # (valid row, expert) pairs computed
+    "experts_touched",  # experts that received at least one row
+    "load_max",         # rows of the most loaded expert
+    "layers",           # 1 where the layer had a valid row (sums to calls)
+)
+
+
+def dropless_moe(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
+                 valid: jnp.ndarray, gate_w: jnp.ndarray, up_w: jnp.ndarray,
+                 down_w: jnp.ndarray, layer: jnp.ndarray,
+                 kernel: bool = False, interpret: bool = False
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Routed SwiGLU experts without capacity.
+
+    x [N, D]; ``topi`` / ``topw`` [N, k]: the experts the gate chose for
+    each row and their weights (the choice is the gate's; nothing here
+    re-selects); ``valid`` [N] bool: padding and inactive lanes, which are
+    given to NO group; gate/up [L, E, D, F], down [L, E, F, D]: the
+    layers' WHOLE stacks, and ``layer`` the (traced) index of this one.
+    Returns ``(out [N, D], stats int32[len(MOE_STATS)])``. ``kernel``
+    takes the Pallas grouped matmul (ops/pallas/grouped_matmul.py;
+    ``interpret`` anywhere but a TPU), else XLA's ``jax.lax.ragged_dot``:
+    the plan's choice (ops/plan.py ``expert_gmm``).
+
+    The stacks and not a layer's slice: the grouped matmul is a kernel
+    call, whose operand a layer scan's slice would first be copied into
+    (2.4 GB a layer at 256 experts of 2048 x 768; PERF.md, PR 36). The
+    stack is read as L x E groups of which only this layer's E have rows.
+
+    ``dropped`` is counted from what the matmul was GIVEN: a sorted row
+    is computed where the group its position falls into, by the group
+    sizes handed to the matmul, is the expert the gate chose for it. An
+    expert id outside [0, E), a row past the last group, or sizes that
+    disagree with the sort would show there.
+    """
+    N, D = x.shape
+    k = topi.shape[-1]
+    L, E = gate_w.shape[:2]
+    # An invalid row's assignments carry expert id E: they sort
+    # behind every real group and belong to none.
+    flat = jnp.where(valid[:, None], topi, E).reshape(N * k)
+    order = jnp.argsort(flat, stable=True)
+    group_sizes = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
+    groups = jax.lax.dynamic_update_slice(
+        jnp.zeros((L * E,), jnp.int32), group_sizes, (layer * E,))
+    gate_w, up_w, down_w = (w.reshape((L * E,) + w.shape[2:])
+                            for w in (gate_w, up_w, down_w))
+    if kernel:
+        from xllm_service_tpu.ops.pallas.grouped_matmul import (
+            grouped_matmul)
+
+        def gmm(a, w):
+            return grouped_matmul(a, w, groups, interpret=interpret)
+    else:
+        def gmm(a, w):
+            return jax.lax.ragged_dot(a, w, groups)
+    xs = x[order // k]                                   # [N*k, D]
+    h = jax.nn.silu(gmm(xs, gate_w)) * gmm(xs, up_w)
+    ys = gmm(h, down_w)                                  # [N*k, D]
+    # Back to (row, choice) order; rows past the last group are no
+    # expert's output, whatever the matmul left there.
+    inv = jnp.zeros((N * k,), order.dtype).at[order].set(
+        jnp.arange(N * k, dtype=order.dtype))
+    y = ys[inv].reshape(N, k, D)
+    w = jnp.where(valid[:, None], topw, 0.0).astype(x.dtype)
+    y = jnp.where(valid[:, None, None], y, jnp.zeros((), y.dtype))
+    out = jnp.einsum("nkd,nk->nd", y, w,
+                     preferred_element_type=jnp.float32).astype(x.dtype)
+    # The group each sorted position falls into (L * E past the last).
+    given = jnp.searchsorted(jnp.cumsum(groups),
+                             jnp.arange(N * k, dtype=jnp.int32),
+                             side="right", method="compare_all")
+    chosen = flat[order]
+    computed = jnp.sum(((chosen >= 0) & (chosen < E)
+                        & (given == layer * E + chosen))
+                       .astype(jnp.int32))
+    requested = k * jnp.sum(valid.astype(jnp.int32))
+    stats = jnp.stack([requested - computed, computed,
+                       jnp.sum((group_sizes > 0).astype(jnp.int32)),
+                       jnp.max(group_sizes),
+                       (computed > 0).astype(jnp.int32)])
+    return out, stats

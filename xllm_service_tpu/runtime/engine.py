@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import concurrent.futures
 import os
 import contextlib
 import dataclasses
@@ -46,6 +47,7 @@ from xllm_service_tpu.config import EngineConfig, ModelConfig
 from xllm_service_tpu.models import transformer
 from xllm_service_tpu.obs import steptrace
 from xllm_service_tpu.ops.plan import KernelPlan, decode_walk_columns
+from xllm_service_tpu.parallel.expert import MOE_STATS
 from xllm_service_tpu.ops.sampling import (
     SamplingTensors, compute_logprobs, compute_top_logprobs, sample_tokens,
     update_counts)
@@ -225,8 +227,7 @@ class Engine:
                     "the engine pins its KV pools' layout, and a cached "
                     "executable does not keep the pin")
             here = jax.sharding.SingleDeviceSharding(device)
-            kv_place = tuple(row_major_format(x.ndim, here)
-                             for x in kv_shapes)
+            kv_place = tuple(self._pool_format(x, here) for x in kv_shapes)
             self._carry_place = here
         else:
             from xllm_service_tpu.parallel.sharding import (
@@ -416,6 +417,9 @@ class Engine:
         # visible). Monotonic per-engine counter of (token, expert)
         # assignments lost to expert capacity; 0 forever on dense models.
         self.moe_dropped_tokens = 0
+        # expert.MOE_STATS, summed over layers and steps (latent path).
+        self.moe_stats: Dict[str, int] = dict.fromkeys(MOE_STATS, 0)
+        self.last_step_moe: Dict[str, int] = dict.fromkeys(MOE_STATS, 0)
         # Prefix-reuse ledger (xllm_worker_prefix_cache_* on /metrics):
         # how many admits consulted the cache, how many prompt tokens it
         # covered (local hits, restores and cross-worker fetches alike),
@@ -465,7 +469,7 @@ class Engine:
         model_cfg, engine_cfg, mesh = self.cfg, self.ecfg, self.mesh
         K = engine_cfg.num_top_logprobs
         plan = self.plan
-        kvl = tuple(row_major_format(x.ndim, x.sharding)
+        kvl = tuple(self._pool_format(x, x.sharding)
                     for x in kv) if self.kv_pinned else None
 
         def _pin(n_in: int, kv_in: int, n_out: int, kv_out: int = 3,
@@ -559,10 +563,16 @@ class Engine:
         # PD import, spill-tier restore and cross-worker block adoption
         # write pages into the pools through this one program.
         scatter_pin = {} if kvl is None else {
-            "in_shardings": (*kvl, None, None, None),
-            "out_shardings": kvl}
-        self._jit_kv_scatter = jax.jit(_kv_scatter, donate_argnums=(0, 1),
+            "in_shardings": (kvl, None, None), "out_shardings": kvl}
+        self._jit_kv_scatter = jax.jit(_kv_scatter, donate_argnums=(0,),
                                        **scatter_pin)
+
+    def _pool_format(self, pool, sharding) -> Format:
+        """The layout a pool is pinned to on ``sharding``: row-major, but
+        a latent pool's on a TPU (``latent_pool_format``)."""
+        if self.cfg.mla and jax.devices()[0].platform == "tpu":
+            return latent_pool_format(sharding)
+        return row_major_format(pool.ndim, sharding)
 
     @contextlib.contextmanager
     def _phase(self, name: str, **args):
@@ -1062,6 +1072,8 @@ class Engine:
         self.last_step_ragged = False
         self.last_step_attn_dispatches = 0
         self.last_step_compiled = []
+        if self.cfg.is_moe:
+            self.last_step_moe = dict.fromkeys(MOE_STATS, 0)
         if self.interleave:
             outs = self._step_interleaved(outs)
         else:
@@ -1459,12 +1471,7 @@ class Engine:
             mp = max(max(len(s.pages) for s in batch),
                      max(self._pages_needed(s.num_computed + T)
                          for s in batch))
-            # Deliberately NOT clamped to max_pages_per_seq: a bucketed T
-            # can overshoot a late-start sequence's true window, and the
-            # overlay view must still cover [start, start+T) — extra
-            # columns are NULL pages, masked in attention and dropped by
-            # the pool scatter.
-            MP = 1 << max(mp - 1, 0).bit_length()
+            MP = self._prefill_table_width(mp)
             # One packed transfer: [start, len, tokens…, page table…].
             packed = np.zeros((B, _PREFILL_HDR + T + MP), np.int32)
             for i, seq in enumerate(batch):
@@ -1641,6 +1648,23 @@ class Engine:
             top=self._top_entry(seq, top_ids, top_lps, 0))
         self._sync_slot(seq)
         return [out]
+
+    def _prefill_table_width(self, pages: int) -> int:
+        """Columns of a prefill program's table over ``pages`` pages: the
+        power of two over them. Under the overlay deliberately NOT
+        clamped to max_pages_per_seq: a bucketed T can overshoot a
+        late-start sequence's true window, and the overlay view must
+        still cover [start, start+T) — extra columns are NULL pages,
+        masked in attention and dropped by the pool scatter. A
+        write-then-attend program has no overlay (its writer drops what
+        lies past the table, which is padding: no row owns more than
+        max_pages_per_seq pages), so its table is clamped like
+        ``_table_width``: at 96 pages a sequence, a 10k-token document
+        is gathered over 96 columns and not 128."""
+        mp = 1 << max(pages - 1, 0).bit_length()
+        if self.plan.write_then_attend:
+            mp = min(mp, self.ecfg.max_pages_per_seq)
+        return mp
 
     def _table_width(self) -> int:
         """Page-table columns actually needed by the running batch, bucketed
@@ -2324,9 +2348,7 @@ class Engine:
         if seq is None:
             return None
         self.drain_pipeline()
-        k_pages, v_pages = self.kv
-        idx = jnp.asarray(seq.pages, jnp.int32)
-        k, v = k_pages[:, idx], v_pages[:, idx]
+        k, v = self._pages_out(jnp.asarray(seq.pages, jnp.int32))
         if not device:
             k = np.asarray(jax.device_get(k))
             v = np.asarray(jax.device_get(v))
@@ -2350,7 +2372,7 @@ class Engine:
         engine's KV layout."""
         self.drain_pipeline()
         n_pages_needed = self._pages_needed(len(tokens))
-        k_pages, v_pages = self.kv
+        k_pages = self.kv[0]
         expect = (k_pages.shape[0], n_pages_needed, k_pages.shape[2],
                   k_pages.shape[3], k_pages.shape[4])
         if (tuple(k.shape) != expect or tuple(v.shape) != expect
@@ -2369,10 +2391,7 @@ class Engine:
             pages = self.prefix_cache.alloc(n_pages_needed)
         if pages is None:
             return False
-        idx = jnp.asarray(pages, jnp.int32)
-        self.kv = self._jit_kv_scatter(k_pages, v_pages, idx,
-                              jnp.asarray(k).astype(k_pages.dtype),
-                              jnp.asarray(v).astype(v_pages.dtype))
+        self._pages_in(jnp.asarray(pages, jnp.int32), k, v)
         seq = Sequence(req=req, tokens=list(tokens), pages=pages,
                        num_computed=len(tokens) - 1, slot=slot,
                        status=SeqStatus.RUNNING,
@@ -2394,6 +2413,21 @@ class Engine:
     # Tiered prefix cache + cross-worker cached-block fetch
     # (docs/KV_CACHE.md; the cluster-scale prefix-reuse loop)
     # ------------------------------------------------------------------
+    def _pages_out(self, idx) -> Tuple[Any, Any]:
+        """The (k, v) blocks [L, n, ps, Hkv, Dh] of pages ``idx``, as
+        device arrays. The wire, the host tier and a peer's import all
+        speak (k, v); a model under latent attention keeps ONE pool
+        (``transformer.init_kv_cache``), whose block stands for both."""
+        k = self.kv[0][:, idx]
+        return k, (self.kv[1][:, idx] if len(self.kv) > 1 else k)
+
+    def _pages_in(self, idx, k, v) -> None:
+        """Write (k, v) blocks into pages ``idx`` of the pools, in place
+        (``_kv_scatter``); a single latent pool takes ``k`` alone."""
+        self.kv = self._jit_kv_scatter(
+            self.kv, idx, tuple(jnp.asarray(x).astype(p.dtype)
+                                for p, x in zip(self.kv, (k, v))))
+
     def _spill_page(self, h: bytes, pid: int) -> bool:
         """PrefixCacheIndex spill hook: park an HBM page about to be
         reclaimed in the host-DRAM tier. The gather is enqueued before
@@ -2401,9 +2435,7 @@ class Engine:
         program order), so it reads the pre-overwrite content."""
         if self.host_tier is None:
             return False
-        k_pages, v_pages = self.kv
-        k_host, v_host = self._read_host(
-            "kv_spill", k_pages[:, pid], v_pages[:, pid])
+        k_host, v_host = self._read_host("kv_spill", *self._pages_out(pid))
         return self.host_tier.put(h, k_host, v_host)
 
     def _restore_spilled(self, tokens: Sequence[int], pages: List[int],
@@ -2466,16 +2498,12 @@ class Engine:
             new_pages = self.prefix_cache.alloc(n_tier)
             if new_pages is not None:
                 with self._phase("kv_restore"):
-                    k_pages, v_pages = self.kv
-                    idx = jnp.asarray(new_pages, jnp.int32)
                     k_new = np.stack([b[0] for kind, _, b in plan
                                       if kind == "tier"], axis=1)
                     v_new = np.stack([b[1] for kind, _, b in plan
                                       if kind == "tier"], axis=1)
-                    self.kv = self._jit_kv_scatter(
-                        k_pages, v_pages, idx,
-                        jnp.asarray(k_new).astype(k_pages.dtype),
-                        jnp.asarray(v_new).astype(v_pages.dtype))
+                    self._pages_in(jnp.asarray(new_pages, jnp.int32),
+                                   k_new, v_new)
                 restored = True
         finally:
             if not restored:
@@ -2529,9 +2557,8 @@ class Engine:
         # gather raise; xlint rule resource-leak pins this shape).
         try:
             if n_hbm:
-                k_pages, v_pages = self.kv
-                idx = jnp.asarray(pages, jnp.int32)
-                k_dev, v_dev = k_pages[:, idx], v_pages[:, idx]
+                k_dev, v_dev = self._pages_out(
+                    jnp.asarray(pages, jnp.int32))
         finally:
             self.prefix_cache.release_pages(pages)
         if n_hbm:
@@ -2571,7 +2598,7 @@ class Engine:
         Returns the number of blocks adopted (0 = clean refusal — the
         caller prefills from token zero, correctness unaffected)."""
         self.drain_pipeline()
-        k_pages, v_pages = self.kv
+        k_pages = self.kv[0]
         n = int(k.shape[1]) if hasattr(k, "shape") else 0
         expect = (k_pages.shape[0], n, k_pages.shape[2],
                   k_pages.shape[3], k_pages.shape[4])
@@ -2606,11 +2633,7 @@ class Engine:
             pages = self.prefix_cache.alloc(n)
             if pages is None:
                 return 0
-            k_pages, v_pages = self.kv
-            idx = jnp.asarray(pages, jnp.int32)
-            self.kv = self._jit_kv_scatter(k_pages, v_pages, idx,
-                                  jnp.asarray(k).astype(k_pages.dtype),
-                                  jnp.asarray(v).astype(v_pages.dtype))
+            self._pages_in(jnp.asarray(pages, jnp.int32), k, v)
             # Positional hash→page registration (lead pages may resolve
             # through the tier, so a full positional lead list does not
             # exist — register_blocks aligns by the fetched run alone).
@@ -2626,8 +2649,8 @@ class Engine:
         """Bytes of one content-addressed KV block (k+v, all layers) —
         advertised in worker registration for the service's
         fetch-vs-recompute cost model."""
-        k_pages = jax.tree_util.tree_leaves(self.kv)[0]
-        return 2 * int(k_pages.nbytes) // int(k_pages.shape[1])
+        return sum(int(x.nbytes) for x in self.kv) \
+            // int(self.kv[0].shape[1])
 
     def prefix_cache_stats(self) -> Dict[str, int]:
         """The xllm_worker_prefix_cache_* series source (worker obs
@@ -2668,7 +2691,17 @@ class Engine:
 
         Shapes are driven directly through the jitted steps with inert
         inputs (all-NULL page tables, inactive slots) — no allocator or
-        slot state is touched. Returns seconds spent."""
+        slot state is touched. Returns seconds spent.
+
+        The programs are walked twice: first lowered and compiled SIDE BY
+        SIDE in threads (one step program compiles in ~21 s on a v5e's
+        host and an engine whose pools' pin bans the persistent cache
+        compiles all of them at every boot: a latent model's ten programs
+        were 217 s of its start one after another and are 79-88 s side
+        by side, PERF.md PR 36), then called one after another. A call
+        finds the executable its own lowering holds (same arguments, so
+        the same cached lowering), so the second walk compiles nothing
+        and leaves the one cache entry per shape that serving will hit."""
         self.drain_pipeline()
         t0 = time.monotonic()
         buckets = tuple(buckets or self.ecfg.prefill_buckets)
@@ -2706,31 +2739,14 @@ class Engine:
                     # pages_needed(T) exactly when T is page-aligned.
                     # Compile both or the wider one compiles mid-serving
                     # (measured: a ~15 s TTFT spike in the round-2 bench).
-                    mps = {1 << max(self._pages_needed(T) - 1,
-                                    0).bit_length(),
-                           1 << max(self._pages_needed(T + 1) - 1,
-                                    0).bit_length()}
+                    mps = {self._prefill_table_width(self._pages_needed(T)),
+                           self._prefill_table_width(
+                               self._pages_needed(T + 1))}
                     prefill_shapes.extend((B, T, mp) for mp in sorted(mps))
                     if not extended:
                         break
                 if not extended:
                     break
-        for B, T, mp in prefill_shapes:
-            st_f32, st_i32 = self._sampling_tensors([], B)
-            b_ids, b_vals = self._batch_bias([], B, self.cfg.vocab_size)
-            warm_rp = (jnp.zeros((B, 3, T), jnp.int32)
-                       if self._mrope else None)
-            pf_args = (
-                self.params,
-                jnp.zeros((B, _PREFILL_HDR + T + mp), jnp.int32),
-                self.kv, st_f32, st_i32, key, None, None, None,
-                b_ids, b_vals, warm_rp, T)
-            _, _, _, self.kv, _ = self._jit_prefill(*pf_args)
-
-        # Decode (single + fused multi): every pow2 table width. Inactive
-        # slots + NULL pages make the KV writes no-ops.
-        st_f32, st_i32 = self._sampling_tensors([], Bmax)
-        b_ids, b_vals = self._batch_bias([], Bmax, self.cfg.vocab_size)
         if decode_widths is None:
             widths = []
             w = 1
@@ -2745,37 +2761,82 @@ class Engine:
                 widths = widths[:1]
         else:
             widths = list(decode_widths)
+        # Scoped callers ask for exactly what their schedule hits: with
+        # fused bursts on, steady state is _run_decode_multi (single
+        # steps only near max_model_len, which a scoped bench never
+        # approaches) — don't pay a compile for the other one.
+        single = decode_widths is None or self.ecfg.decode_steps == 1
+        with concurrent.futures.ThreadPoolExecutor(
+                os.cpu_count() or 1) as pool:
+            # Each program goes to the compiler as soon as it is lowered,
+            # while this thread lowers the next.
+            compiling: List[Any] = []
+            self._warm_programs(
+                lambda jitted, *args: compiling.append(
+                    pool.submit(jitted.lower(*args).compile)),
+                key, prefill_shapes, widths, batch_pows, single, extended)
+            for job in compiling:
+                job.result()
+        self._warm_programs(
+            lambda jitted, *args: jitted(*args),
+            key, prefill_shapes, widths, batch_pows, single, extended)
+        jax.block_until_ready(jax.tree_util.tree_leaves(self.kv)[0])
+        return time.monotonic() - t0
+
+    def _warm_programs(self, launch, key, prefill_shapes, widths,
+                       batch_pows, single: bool, ragged: bool) -> None:
+        """One walk over warm-up's programs, each with its inert
+        arguments: ``launch(jitted, *args)`` lowers it (and returns
+        nothing) or calls it (and returns its outputs, the pools among
+        them, which the next call takes)."""
+        Bmax = self.ecfg.max_batch_size
+        for B, T, mp in prefill_shapes:
+            st_f32, st_i32 = self._sampling_tensors([], B)
+            b_ids, b_vals = self._batch_bias([], B, self.cfg.vocab_size)
+            warm_rp = (jnp.zeros((B, 3, T), jnp.int32)
+                       if self._mrope else None)
+            out = launch(
+                self._jit_prefill, self.params,
+                jnp.zeros((B, _PREFILL_HDR + T + mp), jnp.int32),
+                self.kv, st_f32, st_i32, key, None, None, None,
+                b_ids, b_vals, warm_rp, T)
+            if out is not None:
+                self.kv = out[3]
+
+        # Decode (single + fused multi): every width asked for. Inactive
+        # slots + NULL pages make the KV writes no-ops.
+        st_f32, st_i32 = self._sampling_tensors([], Bmax)
+        b_ids, b_vals = self._batch_bias([], Bmax, self.cfg.vocab_size)
         for mp in widths:
-            packed = jax.device_put(
-                np.zeros((Bmax, _PACK_COLS + mp), np.int32),
-                self._carry_place)
-            # Scoped callers ask for exactly what their schedule hits: with
-            # fused bursts on, steady state is _run_decode_multi (single
-            # steps only near max_model_len, which a scoped bench never
-            # approaches) — don't pay a compile for the other one.
-            if decode_widths is None or self.ecfg.decode_steps == 1:
-                dec_args = (self.params, packed, self.kv, st_f32,
-                            st_i32, key, None, b_ids, b_vals)
-                *_, self.kv, _, _, _, _ = self._jit_decode(*dec_args)
+            if single:
+                packed = jax.device_put(
+                    np.zeros((Bmax, _PACK_COLS + mp), np.int32),
+                    self._carry_place)
+                out = launch(self._jit_decode, self.params, packed,
+                             self.kv, st_f32, st_i32, key, None, b_ids,
+                             b_vals)
+                if out is not None:
+                    self.kv = out[3]
             if self.ecfg.decode_steps > 1:
                 tok0 = jnp.zeros((Bmax,), jnp.int32)
                 pos0 = jnp.zeros((Bmax,), jnp.int32)
                 apt0 = jnp.zeros((Bmax, 2 + mp), jnp.int32)
-                dm_args = (self.params, tok0, pos0, apt0, self.kv,
-                           st_f32, st_i32, key, None, b_ids, b_vals)
-                (_, _, _, self.kv, _, _, f_tok,
-                 f_pos) = self._jit_decode_multi(*dm_args)
-                # Second call feeding back the returned device-resident
-                # carries and a split (device-committed) key: the
-                # serving path's resident-reuse signature. Under the
-                # pinned-layout jits, committed-vs-uncommitted inputs
-                # are distinct pjit cache signatures (same executable,
-                # no compile) — prime both here or the first serving
-                # burst shows up in the recompile counters.
-                key2 = jax.random.split(key)[0]
-                (_, _, _, self.kv, _, _, _, _) = self._jit_decode_multi(
-                    self.params, f_tok, f_pos, apt0, self.kv, st_f32,
-                    st_i32, key2, None, b_ids, b_vals)
+                out = launch(self._jit_decode_multi, self.params, tok0,
+                             pos0, apt0, self.kv, st_f32, st_i32, key,
+                             None, b_ids, b_vals)
+                if out is not None:
+                    # Second call feeding back the returned device-resident
+                    # carries and a split (device-committed) key: the
+                    # serving path's resident-reuse signature. Under the
+                    # pinned-layout jits, committed-vs-uncommitted inputs
+                    # are distinct pjit cache signatures (same executable,
+                    # no compile) — prime both here or the first serving
+                    # burst shows up in the recompile counters.
+                    _, _, _, self.kv, _, _, f_tok, f_pos = out
+                    key2 = jax.random.split(key)[0]
+                    self.kv = self._jit_decode_multi(
+                        self.params, f_tok, f_pos, apt0, self.kv, st_f32,
+                        st_i32, key2, None, b_ids, b_vals)[3]
         # Ragged mixed programs (opt-in): batch bucket = pow2(decoders +
         # admits) — any rung of the pow2 ladder — at each prefill bucket,
         # with the table as wide as the wider of the decode widths and
@@ -2784,7 +2845,7 @@ class Engine:
         # IS the ragged bucket ladder: every shape a mixed iteration of
         # the covered schedule can form compiles here, keeping the
         # post-warmup recompile counters at zero with the ragged path on.
-        if self._jit_ragged is not None and extended:
+        if self._jit_ragged is not None and ragged:
             t_set = sorted({T for _, T, _ in prefill_shapes})
             mp_set = sorted({mp for *_, mp in prefill_shapes}
                             | set(widths))
@@ -2794,22 +2855,31 @@ class Engine:
                                                  self.cfg.vocab_size)
                 for T in t_set:
                     for mp in mp_set:
-                        rg_args = (
-                            self.params,
+                        out = launch(
+                            self._jit_ragged, self.params,
                             jnp.zeros((B, _PREFILL_HDR + T + mp),
                                       jnp.int32),
                             self.kv, st_f32, st_i32, key, None, None,
                             None, b_ids, b_vals, None, T)
-                        _, _, _, self.kv, _ = self._jit_ragged(*rg_args)
-        jax.block_until_ready(jax.tree_util.tree_leaves(self.kv)[0])
-        return time.monotonic() - t0
+                        if out is not None:
+                            self.kv = out[3]
 
     def _note_moe_dropped(self, mdrop) -> None:
-        """Accumulate the step's capacity-dropped (token, expert)
-        assignments (device scalar riding the step outputs; free for
-        dense models where it is a constant 0)."""
-        if self.cfg.is_moe:
+        """Accumulate what the step's sparse layers counted (a device
+        value riding the step outputs; free for dense models where it is
+        a constant 0): the capacity-dropped (token, expert) assignments,
+        and on the latent path, whose layer drops nothing, the whole
+        ``expert.MOE_STATS`` vector (``moe_stats``; ``last_step_moe``
+        holds this step's share for the step record)."""
+        if not self.cfg.is_moe:
+            return
+        if np.ndim(mdrop) == 0:
             self.moe_dropped_tokens += int(mdrop)
+            return
+        for name, v in zip(self.moe_stats, mdrop.tolist()):
+            self.moe_stats[name] += v
+            self.last_step_moe[name] += v
+        self.moe_dropped_tokens = self.moe_stats["dropped"]
 
     def load_metrics(self) -> Dict[str, Any]:
         """The LoadMetrics the reference ships in heartbeats
@@ -2857,7 +2927,20 @@ def row_major_format(ndim: int, sharding) -> Format:
     return Format(Layout(major_to_minor=tuple(range(ndim))), sharding)
 
 
-def _kv_scatter(k_pages, v_pages, idx, k_new, v_new):
+def latent_pool_format(sharding) -> Format:
+    """A latent pool ``[L, P, ps, 1, D]`` with its size-1 head axis
+    outermost: the bytes of a row-major ``[L, P, ps, D]`` array, tiled
+    over (ps, D). Row-major would put the tiles over (1, D): the head
+    axis padded to 2 and 576 to 640, 2.2 times the rows' bytes on a v5e
+    (3.09 GB for 1,888 pages of 5 layers, where this is 1.55), and every
+    prefill program, whose XLA attention wants this layout, copied the
+    whole pool in and out (7.5 + 7.2 ms a step; PERF.md, PR 36). The
+    latent kernels (ops/pallas/latent.py) take the pool reshaped to
+    ``[L, P, ps, D]``, which under this layout moves nothing."""
+    return Format(Layout(major_to_minor=(3, 0, 1, 2, 4)), sharding)
+
+
+def _kv_scatter(pools, idx, news):
     """In-place (donated) write of migrated KV pages — no pool-sized copy.
     Jitted per engine (``_jit_kv_scatter``) so that the pools keep the
     engine's pinned layout through it: an unpinned program hands back
@@ -2865,7 +2948,7 @@ def _kv_scatter(k_pages, v_pages, idx, k_new, v_new):
     then refuse (seen on the chip, PR 22: the first decode step after a
     PD import faulted). Recompiles per distinct imported-page count;
     serving shapes hit a handful of counts, all cached after first use."""
-    return k_pages.at[:, idx].set(k_new), v_pages.at[:, idx].set(v_new)
+    return tuple(p.at[:, idx].set(n) for p, n in zip(pools, news))
 
 
 def _start_host_copy(*arrays) -> None:
@@ -2945,8 +3028,9 @@ def _prefill_step(params, packed, kv, st_f32, st_i32, key, mm_embeds=None,
         top_ids, top_lps = compute_top_logprobs(last_logits, num_top)
     if with_prompt_lps:
         return (_fuse_tok_lp(tok, lp), top_ids, top_lps, kv, plp,
-                stats["moe_dropped"])
-    return _fuse_tok_lp(tok, lp), top_ids, top_lps, kv, stats["moe_dropped"]
+                transformer.step_moe_stats(stats))
+    return (_fuse_tok_lp(tok, lp), top_ids, top_lps, kv,
+            transformer.step_moe_stats(stats))
 
 
 def _prefill_ring_step(params, packed, kv, st_f32, st_i32, key,
@@ -2999,7 +3083,7 @@ def _decode_step(params, packed, kv, st_f32, st_i32, key, counts=None,
     next_packed = packed.at[:, 0].set(jnp.where(active, tok, tokens)) \
         .at[:, 1].set(positions + active.astype(jnp.int32))
     return (_fuse_tok_lp(tok, lp), top_ids, top_lps, kv, counts,
-            stats["moe_dropped"], next_packed, next_key)
+            transformer.step_moe_stats(stats), next_packed, next_key)
 
 
 def _decode_multi_step(params, tokens, positions, active_pt, kv, st_f32,
@@ -3040,13 +3124,15 @@ def _decode_multi_step(params, tokens, positions, active_pt, kv, st_f32,
         if cnt is not None:
             cnt = update_counts(cnt, new_tok, active)
         return (new_tok, pos + 1, kv, cnt,
-                drop + stats["moe_dropped"]), (new_tok, lp, top_ids, top_lps)
+                drop + transformer.step_moe_stats(stats)), \
+            (new_tok, lp, top_ids, top_lps)
 
     keys = jax.random.split(key, n_steps)
     (fin_tok, fin_pos, kv, counts, moe_dropped), \
         (toks, lps, top_ids, top_lps) = \
         jax.lax.scan(body, (tokens, positions, kv, counts,
-                            jnp.zeros((), jnp.int32)), keys)
+                            jnp.zeros(transformer.moe_stats_shape(cfg),
+                                      jnp.int32)), keys)
     # Final carry token/position go back to the host AS HANDLES ONLY —
     # next burst feeds them in again without a host→device upload.
     return (_fuse_tok_lp(toks, lps), top_ids, top_lps, kv, counts,

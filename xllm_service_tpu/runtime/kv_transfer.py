@@ -40,7 +40,7 @@ def probe_kv_migration(src: Engine, dst: Engine, n_pages: int = 16,
     ``iters`` timed reps each (one warmup). Engines must share pool
     layout. Returns {"bytes", "pages", "direct_gbps", "host_gbps",
     "host_pipelined_gbps"}."""
-    ks, vs = src.kv
+    ks = src.kv[0]
     if ks.shape[0:1] + ks.shape[2:] != \
             dst.kv[0].shape[0:1] + dst.kv[0].shape[2:]:
         raise ValueError("engines have different KV pool layouts")
@@ -49,6 +49,8 @@ def probe_kv_migration(src: Engine, dst: Engine, n_pages: int = 16,
         raise ValueError("pool too small to probe (needs >= 2 pages)")
     src_idx = jnp.arange(1, n_pages + 1, dtype=jnp.int32)
     dst_idx = jnp.arange(1, n_pages + 1, dtype=jnp.int32)
+    # (k, v) blocks, as every transfer path speaks them (a single
+    # latent pool's block stands for both: Engine._pages_out).
     nbytes = 2 * int(np.prod(ks[:, :n_pages].shape)) * ks.dtype.itemsize
 
     def _sync() -> None:
@@ -62,27 +64,20 @@ def probe_kv_migration(src: Engine, dst: Engine, n_pages: int = 16,
         np.asarray(jax.device_get(dst.kv[0][0, n_pages]))
 
     def direct_once() -> None:
-        kd, vd = dst.kv
-        k = ks[:, src_idx]
-        v = vs[:, src_idx]
-        dst.kv = dst._jit_kv_scatter(kd, vd, dst_idx, k.astype(kd.dtype),
-                             v.astype(vd.dtype))
+        dst._pages_in(dst_idx, *src._pages_out(src_idx))
         _sync()
 
     def host_once() -> None:
-        kd, vd = dst.kv
         # The wire path: gather → host → bytes → host → device → scatter.
-        k_host = np.asarray(jax.device_get(ks[:, src_idx]))
-        v_host = np.asarray(jax.device_get(vs[:, src_idx]))
+        k_host, v_host = (np.asarray(jax.device_get(x))
+                          for x in src._pages_out(src_idx))
         blob = k_host.tobytes() + v_host.tobytes()
         half = len(blob) // 2
         k2 = np.frombuffer(blob[:half], dtype=k_host.dtype).reshape(
             k_host.shape)
         v2 = np.frombuffer(blob[half:], dtype=v_host.dtype).reshape(
             v_host.shape)
-        dst.kv = dst._jit_kv_scatter(kd, vd, dst_idx,
-                             jnp.asarray(k2).astype(kd.dtype),
-                             jnp.asarray(v2).astype(vd.dtype))
+        dst._pages_in(dst_idx, k2, v2)
         _sync()
 
     def host_pipelined_once() -> None:
@@ -91,8 +86,8 @@ def probe_kv_migration(src: Engine, dst: Engine, n_pages: int = 16,
         # front, then stream chunks host→device as their bytes land — the
         # D2H of chunk i+1 overlaps the H2D of chunk i instead of the
         # two directions strictly alternating on one monolith.
-        kd, vd = dst.kv
-        kb, vb = ks[:, src_idx], vs[:, src_idx]
+        kd = dst.kv[0]
+        kb, vb = src._pages_out(src_idx)
         L = int(kb.shape[0])
         C = max(2, min(L, 8))
         bounds = [(i * L // C, (i + 1) * L // C) for i in range(C)]
@@ -105,10 +100,10 @@ def probe_kv_migration(src: Engine, dst: Engine, n_pages: int = 16,
             k_host = np.asarray(pk)            # completes the async D2H
             v_host = np.asarray(pv)
             up.append((jnp.asarray(k_host).astype(kd.dtype),
-                       jnp.asarray(v_host).astype(vd.dtype)))
+                       jnp.asarray(v_host).astype(kd.dtype)))
         k2 = jnp.concatenate([u[0] for u in up], axis=0)
         v2 = jnp.concatenate([u[1] for u in up], axis=0)
-        dst.kv = dst._jit_kv_scatter(kd, vd, dst_idx, k2, v2)
+        dst._pages_in(dst_idx, k2, v2)
         _sync()
 
     # Report the EFFECTIVE page count: callers print this next to the

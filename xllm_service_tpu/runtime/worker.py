@@ -374,6 +374,22 @@ class _StopWatcher:
         return out
 
 
+def _moe_record(st: Dict[str, int]) -> Optional[Dict[str, Any]]:
+    """The step record's ``moe``: what the step's sparse layers counted
+    (``Engine.last_step_moe``), and ``load_max_over_mean``: rows of a
+    layer's busiest expert (mean over the step's sparse layers) over the
+    mean rows of the experts touched. None where the step routed
+    nothing, or the family's layer does not count."""
+    if not (st["assignments"] and st["layers"] and st["experts_touched"]):
+        return None
+    return {"assignments": st["assignments"],
+            "experts_touched": st["experts_touched"],
+            "dropped": st["dropped"],
+            "load_max_over_mean": round(
+                (st["load_max"] / st["layers"])
+                / (st["assignments"] / st["experts_touched"]), 3)}
+
+
 def _merge_step_outputs(outs: List[StepOutput]) -> StepOutput:
     """Concatenate held-back deltas of one choice (in arrival order) into
     a single StepOutput; the final element supplies finish state."""
@@ -1438,6 +1454,8 @@ class Worker:
             "block (the others passed the one the last step handed back)",
             labelnames=("model",)).set_total(
             eng.phase_counts.get("decode.upload", 0), model=m)
+        if eng.cfg.is_moe:
+            self._flush_moe(rt)
         tok = self.obs.counter(
             "xllm_worker_step_tokens_total",
             "batch token occupancy: prompt tokens computed (prefill) / "
@@ -1553,7 +1571,27 @@ class Worker:
             kv_usage=round(float(lm.kv_cache_usage), 4),
             pages_delta=pages_delta,
             cache_hit_tokens=hit_delta,
-            compiled=tuple(eng.last_step_compiled))
+            compiled=tuple(eng.last_step_compiled),
+            moe=_moe_record(eng.last_step_moe))
+
+    def _flush_moe(self, rt: ModelRuntime) -> None:
+        """What the sparse layers counted on the device (``Engine.
+        moe_stats``: the latent family's dropless layer; all zeros where
+        a family's layer still buckets and counts its drops alone)."""
+        st, m = rt.engine.moe_stats, rt.model
+        for name, key, text in (
+                ("xllm_worker_moe_assignments_total", "assignments",
+                 "(valid row, expert) assignments the sparse layers "
+                 "computed, summed over layers and steps"),
+                ("xllm_worker_moe_experts_touched_total", "experts_touched",
+                 "experts that received at least one row, summed over "
+                 "sparse layers and steps: over layers x experts it is "
+                 "the share of expert weights a step reads"),
+                ("xllm_worker_moe_dropped_assignments_total", "dropped",
+                 "assignments the gate made and no expert computed "
+                 "(requested - computed; 0 under the dropless layer)")):
+            self.obs.counter(name, text, labelnames=("model",)).set_total(
+                st[key], model=m)
 
     def _flush_overlap(self, rt: ModelRuntime) -> None:
         """Decode-pipeline overlap health: speculative-burst
