@@ -1,0 +1,434 @@
+"""The single-step decode launches step N+1 before it reads step N
+(PR 37, ``Engine._launch_ahead``): the streams are a sequential engine's,
+whatever falls on a step that was launched ahead; the counters say how
+often it engaged; nothing is launched while a request waits; and the
+launch is the decode program's one call signature."""
+
+import dataclasses
+import functools
+import json
+
+import pytest
+
+from xllm_service_tpu.config import EngineConfig, ModelConfig
+from xllm_service_tpu.runtime.engine import Engine, EngineRequest
+from xllm_service_tpu.utils.types import FinishReason, SamplingParams
+
+# The latent cell's configuration at its rehearsal widths: ONE latent
+# pool and the dropless expert layer.
+from tests.test_latent_pool import tiny_model
+
+
+def _tiny(window=None, **kw):
+    return dataclasses.replace(ModelConfig.tiny(vocab_size=64, **kw),
+                               dtype="float32", sliding_window=window)
+
+
+def _engine(model=None, sequential=False, **kw):
+    d = dict(page_size=16, num_pages=32, max_model_len=128,
+             max_batch_size=4, max_prefill_tokens=64,
+             prefill_buckets=(8, 16, 32))
+    d.update(kw)
+    eng = Engine(model or _tiny(), EngineConfig(**d), seed=0)
+    if sequential:
+        # The control: the same engine with the predicate forced false.
+        eng._ahead_eligible = lambda *a: False
+    return eng
+
+
+def _req(rid, prompt, n, sampling="greedy", eos=None, offline=False,
+         **sp):
+    sp.update(max_tokens=n, ignore_eos=eos is None)
+    if sampling == "greedy":
+        sp["temperature"] = 0.0
+    else:
+        sp.update(temperature=1.0, seed=1000 + len(rid) * 7 + prompt[0])
+    return EngineRequest(
+        request_id=rid, token_ids=list(prompt), offline=offline,
+        sampling=SamplingParams(**sp),
+        eos_token_ids=() if eos is None else (eos,))
+
+
+_COUNTS = {"launch": "decode.ahead_dispatch", "hit": "decode.ahead_hit",
+           "discard": "decode.ahead_discard",
+           "dropped": "decode.ahead_dropped_rows"}
+
+
+def _drive(eng, feed, cancel=()):
+    """Feed ``{step: [requests]}`` and cancel ``{step: rid}``; returns
+    ``({rid: (tokens, logprobs, reason)}, [a record per step])``. A
+    record holds the step's counter deltas, its kind, who finished, whose
+    table grew, who was trimmed, how many were preempted, and whether a
+    request waited when it began."""
+    cancel = dict(cancel)
+    toks, lps, reasons, recs = {}, {}, {}, []
+    pc, step = eng.phase_counts, 0
+    while eng.has_work() or step < max(feed):
+        step += 1
+        for r in feed.get(step, ()):
+            eng.add_request(r)
+        if step in cancel:
+            eng.cancel(cancel[step])
+        was = {k: pc[v] for k, v in _COUNTS.items()}
+        pre = eng.num_preemptions
+        pages = {s.req.request_id: len(s.pages) for s in eng.running}
+        trim = {s.req.request_id: s.num_trimmed for s in eng.running}
+        rec = {"step": step, "waiting": bool(eng.waiting),
+               "pending": eng._pending is not None, "fin": {}}
+        for out in eng.step():
+            toks.setdefault(out.request_id, []).extend(out.new_token_ids)
+            lps.setdefault(out.request_id, []).extend(out.logprobs)
+            if out.finished:
+                reasons[out.request_id] = rec["fin"][out.request_id] = \
+                    out.finish_reason
+        rec.update({k: pc[v] - was[k] for k, v in _COUNTS.items()})
+        rec.update(
+            kind=eng.last_step_kind, preempted=eng.num_preemptions - pre,
+            grew=[s.req.request_id for s in eng.running
+                  if len(s.pages) > pages.get(s.req.request_id, 1 << 30)],
+            trimmed=[s.req.request_id for s in eng.running
+                     if s.num_trimmed > trim.get(s.req.request_id, 1 << 30)])
+        recs.append(rec)
+        assert step < 400, "engine did not drain"
+    return {r: (toks[r], lps[r], reasons.get(r)) for r in toks}, recs
+
+
+# ---------------------------------------------------------------------------
+# The same streams, whatever falls on a step launched ahead
+# ---------------------------------------------------------------------------
+def _event_schedule(event, sampling, eos=None):
+    """(engine options, feed, cancels) of the schedule that makes
+    ``event`` fall on a step that was launched ahead."""
+    a = _req("a", range(1, 7), 40, sampling)
+    b = _req("b", range(2, 9), 40, sampling)
+    opts, cancel = {}, {}
+    feed = {1: [a, b]}
+    if event == "admit":
+        feed[6] = [_req("late", range(5, 12), 12, sampling)]
+    elif event == "max_tokens":
+        feed[1] = [a, _req("b", range(2, 9), 5, sampling)]
+    elif event == "eos":
+        feed[1] = [a, _req("b", range(2, 9), 40, sampling, eos=eos)]
+    elif event == "cancel":
+        cancel[6] = "b"
+    elif event == "preempt":
+        # 10 usable pages of 4 tokens for three rows that want 21
+        opts = dict(page_size=4, num_pages=11, max_model_len=64)
+        feed = {1: [_req("a", range(1, 7), 22, sampling),
+                    _req("off", range(9, 14), 20, sampling, offline=True)],
+                3: [_req("c", range(3, 11), 14, sampling)]}
+    elif event == "page_growth":
+        opts = dict(page_size=8)
+    elif event == "swa_trim":
+        opts = dict(page_size=4, num_pages=24, model=_tiny(window=8))
+    return opts, feed, cancel
+
+
+@functools.lru_cache(maxsize=None)
+def _event_runs(event, sampling):
+    eos = None
+    if event == "eos":
+        # The token the control's stream reaches fifth or later, first.
+        opts, feed, cancel = _event_schedule("steady", sampling)
+        st = _drive(_engine(sequential=True, **opts), feed, cancel)[0]["b"][0]
+        eos = next(t for i, t in enumerate(st) if i >= 4 and t not in st[:i])
+    runs = []
+    for sequential in (False, True):
+        opts, feed, cancel = _event_schedule(event, sampling, eos)
+        runs.append(_drive(_engine(sequential=sequential, **opts), feed,
+                           cancel))
+    return runs
+
+
+@pytest.mark.parametrize("sampling", ["greedy", "seeded"])
+@pytest.mark.parametrize("event", [
+    "admit", "max_tokens", "eos", "cancel", "preempt", "page_growth",
+    "swa_trim"])
+def test_streams_are_the_sequential_engines(event, sampling):
+    """Token ids, logprobs and finish reasons are those of the same
+    engine with the predicate forced false, with ``event`` falling on a
+    step that was launched ahead (greedy and seeded sampling: the key
+    chain is the program's own, and a discard puts the key back)."""
+    (got, recs), (want, control) = _event_runs(event, sampling)
+    assert got == want
+    assert not any(r["launch"] or r["hit"] for r in control)
+    hits = [r for r in recs if r["hit"]]
+    assert len(hits) >= 4
+    by_step = {r["step"]: r for r in recs}
+    if event == "admit":
+        r = by_step[6]      # the step in flight is taken, then the prefill
+        assert r["hit"] and r["kind"] == "mixed" and not r["launch"]
+        assert len(got["late"][0]) == 12
+    elif event == "max_tokens":
+        # known a step ahead: nothing is launched behind the step that
+        # ends b, and the next one is packed without it
+        r = next(r for r in hits if r["fin"].get("b") == FinishReason.LENGTH)
+        nxt = by_step[r["step"] + 1]
+        assert not r["launch"] and not nxt["hit"] and nxt["launch"]
+        assert not any(r["dropped"] or r["discard"] for r in recs)
+    elif event == "eos":
+        r = next(r for r in hits if r["fin"].get("b") == FinishReason.STOP)
+        # ... and the step launched behind it ran b's row for nothing
+        nxt = by_step[r["step"] + 1]
+        assert r["launch"] and nxt["hit"] and nxt["dropped"] == 1
+        assert not nxt["launch"] and not any(r["discard"] for r in recs)
+    elif event == "cancel":
+        r = by_step[6]
+        assert r["pending"] and r["hit"] and r["dropped"] == 1
+        assert got["b"][2] == FinishReason.CANCELLED
+    elif event == "preempt":
+        assert any(r["preempted"] for r in hits)
+        assert all(v[2] == FinishReason.LENGTH for v in got.values())
+    elif event == "page_growth":
+        grown = [r for r in hits if r["grew"]]
+        # the step after a grown table is packed and uploaded again
+        assert grown and not any(by_step[r["step"] + 1]["hit"]
+                                 for r in grown)
+    else:
+        assert any(r["trimmed"] for r in hits)
+
+
+# ---------------------------------------------------------------------------
+# The counters, by hand
+# ---------------------------------------------------------------------------
+def test_the_counters_equal_a_hand_count():
+    """Two rows on pages of 16: a (6 prompt tokens, 9 to make) and b
+    (7, 4 to make); b is cancelled before step 7. Step 1 prefills both
+    (each has 1 token). Step 2 packs the first decode and launches step
+    3 behind it; 3 takes it and launches 4; 4 takes it and launches
+    nothing (it makes b's fourth and last token: known a step ahead) and
+    ends b; 5 packs again (a alone) and launches 6; "late" (7, 30 to
+    make) arrives before 6, which takes its step, launches nothing and
+    prefills; 7 packs both and launches 8; late is cancelled before 8,
+    which takes a's row, drops late's, and launches nothing (late is
+    still active on the device); 9 packs a alone and takes a's ninth
+    token: nothing is launched, no row would be left."""
+    eng = _engine()
+    _, recs = _drive(eng, {1: [_req("a", range(1, 7), 9),
+                               _req("b", range(2, 9), 4)],
+                           6: [_req("late", range(3, 10), 30)]},
+                     cancel={8: "late"})
+    assert [r["kind"] for r in recs] == \
+        ["prefill"] + ["decode"] * 4 + ["mixed"] + ["decode"] * 3
+    assert [r["launch"] for r in recs] == [0, 1, 1, 0, 1, 0, 1, 0, 0]
+    assert [r["hit"] for r in recs] == [0, 0, 1, 1, 0, 1, 0, 1, 0]
+    assert [r["dropped"] for r in recs] == [0] * 7 + [1, 0]
+    assert not any(r["discard"] for r in recs)
+    pc = eng.phase_counts
+    assert pc["decode.ahead_dispatch"] == pc["decode.ahead_hit"] == 4
+    assert pc["decode.dispatch"] == pc["decode.pack"] == 4
+    assert pc["decode.upload"] == 4 and pc["decode.resident_hit"] == 4
+    assert eng.overlap_metrics()["spec_hits"] == 4
+    assert eng._pending is None and not eng.has_work()
+
+
+def test_a_launch_whose_rows_have_all_gone_is_dropped():
+    """A cancel takes the only row while a step launched ahead is in
+    flight: nothing would take it, so it is discarded at once, with the
+    key it was given put back."""
+    eng = _engine()
+    eng.add_request(_req("a", range(1, 7), 30, "seeded"))
+    for _ in range(4):
+        eng.step()
+    assert eng._pending is not None
+    key = eng._pending["key_before"]
+    eng.cancel("a")
+    outs = eng.step()
+    assert [o.finish_reason for o in outs] == [FinishReason.CANCELLED]
+    assert eng._pending is None and not eng.has_work()
+    assert eng._rng_key is key
+    assert eng.phase_counts["decode.ahead_discard"] == 1
+
+
+def test_drain_pipeline_takes_the_single_steps_launch_too():
+    """Whatever changes membership outside the loop drains first (import,
+    export, sleep, fault_reset, isolate, warm-up): the discarded step is
+    run again from the same key, so the streams do not move."""
+    def run(drain):
+        eng = _engine()
+        eng.add_request(_req("a", range(1, 7), 20, "seeded"))
+        eng.add_request(_req("b", range(2, 9), 20, "seeded"))
+        toks, step = {}, 0
+        while eng.has_work():
+            step += 1
+            if drain and step % 3 == 0:
+                eng.drain_pipeline()
+            for o in eng.step():
+                toks.setdefault(o.request_id, []).extend(o.new_token_ids)
+        return toks, eng.phase_counts["decode.ahead_discard"]
+    (plain, none), (drained, some) = run(False), run(True)
+    assert plain == drained and none == 0 and some >= 4
+
+
+# ---------------------------------------------------------------------------
+# Nothing is launched while a request waits
+# ---------------------------------------------------------------------------
+def test_no_launch_while_a_request_waits():
+    """An arriving request's prefill is dispatched directly after the
+    decode it would have followed anyway: the step in flight when it
+    arrived is taken, nothing is launched behind it, and the prefill is
+    that very iteration's. A chunked prompt keeps waiting between its
+    windows: no launch until its last."""
+    eng = _engine(max_prefill_tokens=16, prefill_buckets=(8, 16))
+    feed = {1: [_req("a", range(1, 7), 30)],
+            5: [_req("long", range(1, 41), 6)]}     # 40 tokens: windows
+    _, recs = _drive(eng, feed)
+    assert not any(r["launch"] for r in recs if r["waiting"])
+    arrival = recs[4]
+    assert arrival["pending"] and arrival["hit"] and \
+        arrival["kind"] == "mixed"
+    windows = [r for r in recs if r["kind"] == "mixed"]
+    assert len(windows) >= 3 and not any(r["launch"] for r in windows)
+    assert sum(r["launch"] for r in recs) >= 10
+    assert not any(r["discard"] for r in recs)
+
+
+def test_a_mixed_program_does_not_throw_the_step_in_flight_away():
+    """Under the ragged mixed program an arrival finds the decode of its
+    iteration already on the device: the iteration takes it and prefills
+    behind it (the split sections) where the mixed program would first
+    have to discard it."""
+    eng = _engine(ragged_attn=True)
+    assert eng._jit_ragged is not None
+    _, recs = _drive(eng, {1: [_req("a", range(1, 7), 20)],
+                           5: [_req("late", range(3, 9), 8)]})
+    arrival = recs[4]
+    assert arrival["pending"] and arrival["hit"] and \
+        arrival["kind"] == "mixed"
+    assert not eng.phase_counts["ragged.dispatch"]
+    assert not any(r["discard"] for r in recs)
+
+
+# ---------------------------------------------------------------------------
+# One program, one call signature
+# ---------------------------------------------------------------------------
+def test_one_cache_entry_a_width_after_a_mixed_schedule():
+    """A launch ahead gives the decode program exactly what a resident
+    hit gives it: after warm-up, a schedule of packs, uploads, resident
+    hits and launches ahead over three table widths adds no entry to the
+    program's cache and counts no recompile."""
+    eng = _engine(page_size=4, max_model_len=64)
+    eng.warmup(prefill_shapes=[(2, 8, 2), (1, 8, 2)],
+               decode_widths=[2, 4, 8])
+    warm = eng.compile_report()
+    assert warm["decode"] == 3
+    _, recs = _drive(eng, {1: [_req("a", range(1, 7), 20, "seeded"),
+                               _req("b", range(2, 9), 9)],
+                           6: [_req("late", range(11, 17), 5, "seeded")]})
+    pc = eng.phase_counts
+    assert pc["decode.ahead_hit"] >= 5 and pc["decode.upload"] >= 5
+    assert pc["decode.resident_hit"] >= pc["decode.ahead_hit"]
+    assert eng.compile_report() == warm
+    assert not [k for k, v in eng.phase_report().items()
+                if k.endswith(".recompile") and v]
+
+
+# ---------------------------------------------------------------------------
+# Every family: one rule, by what the engine can observe
+# ---------------------------------------------------------------------------
+def _family_streams(model, sequential, eos=None, **opts):
+    eng = _engine(model=model, sequential=sequential, **opts)
+    got, recs = _drive(eng, {
+        1: [_req("a", range(1, 9), 24), _req("b", range(2, 12), 24,
+                                             eos=eos)],
+        7: [_req("late", range(5, 14), 16)]}, cancel={12: "late"})
+    return got, recs, eng
+
+
+def test_a_latent_pool_under_the_dropless_experts_launches_ahead():
+    """The latent cell's family at its rehearsal widths: ONE pool, the
+    dropless expert layer. Its rows do not see each other, so a row that
+    leaves is dropped and the others stand."""
+    opts = dict(max_model_len=256, max_batch_size=4, max_prefill_tokens=256,
+                prefill_buckets=(32, 64, 128))
+    got, recs, eng = _family_streams(tiny_model(), False, **opts)
+    want, _, _ = _family_streams(tiny_model(), True, **opts)
+    assert eng.cfg.mla and eng.cfg.is_moe and len(eng.kv) == 1
+    assert not eng._rows_interfere
+    assert got == want
+    assert sum(r["hit"] for r in recs) >= 15
+    assert sum(r["dropped"] for r in recs) >= 1
+    assert not any(r["discard"] for r in recs)
+
+
+def test_rows_that_share_an_experts_capacity_are_taken_whole():
+    """``_mlp``'s bucketed sparse layer: a row that has left still
+    competes for an expert's capacity in a step launched ahead. There a
+    known finish forbids the launch, and an EOS discards the step whole
+    (the burst path's rule): the streams are the sequential engine's."""
+    model = _tiny(num_experts=4)
+    st = _family_streams(model, True)[0]["b"][0]
+    eos = next(t for i, t in enumerate(st) if i >= 4 and t not in st[:i])
+    got, recs, eng = _family_streams(model, False, eos=eos)
+    want, _, _ = _family_streams(model, True, eos=eos)
+    assert eng._rows_interfere
+    assert got == want and got["b"][2] == FinishReason.STOP
+    assert sum(r["hit"] for r in recs) >= 10
+    assert not any(r["dropped"] for r in recs)
+    stop = next(r for r in recs if r["fin"].get("b") == FinishReason.STOP)
+    assert stop["launch"] and stop["discard"] == 1
+    # the cancel discards one more; a finish by length is known a step
+    # ahead: not launched, so not discarded
+    assert sum(r["discard"] for r in recs) == 2
+
+
+def test_a_burst_engines_single_step_launches_nothing():
+    """Where bursts are fused the single step is the fallback for a row
+    near ``max_model_len``; its next decode may be a burst again, and
+    ``XLLM_DECODE_PIPELINE`` keeps its meaning for bursts alone."""
+    eng = _engine(decode_steps=4, max_model_len=32)
+    _, recs = _drive(eng, {1: [_req("a", range(1, 10), 30)]})
+    pc = eng.phase_counts
+    assert pc["decode.dispatch"] >= 2 and pc["decode_multi.dispatch"] >= 1
+    assert not pc["decode.ahead_dispatch"]
+    assert pc["decode_multi.spec_dispatch"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# What a scrape shows
+# ---------------------------------------------------------------------------
+def test_worker_exports_the_launches_beside_its_steps():
+    from http.client import HTTPConnection
+
+    from xllm_service_tpu.obs import steptrace, validate_exposition
+    from xllm_service_tpu.runtime.worker import Worker, WorkerOptions
+    from xllm_service_tpu.service.coordination import InMemoryStore
+    assert "decode.ahead_dispatch" in steptrace.STEP_PHASES
+    assert "xllm.step.decode.ahead_dispatch" in steptrace.SPAN_NAMES
+    w = Worker(WorkerOptions(model="tiny"), InMemoryStore()).start()
+
+    def http(method, path, body=None):
+        host, port = w.name.rsplit(":", 1)
+        conn = HTTPConnection(host, int(port), timeout=120)
+        try:
+            conn.request(method, path, body=body and json.dumps(body),
+                         headers={"Content-Type": "application/json"})
+            r = conn.getresponse()
+            return r.status, r.read().decode()
+        finally:
+            conn.close()
+    try:
+        assert http("POST", "/v1/completions", {
+            "model": "tiny", "prompt": "count my launches",
+            "max_tokens": 24, "temperature": 0.0,
+            "ignore_eos": True})[0] == 200
+        text = http("GET", "/metrics")[1]
+    finally:
+        w.stop()
+    validate_exposition(text)
+
+    def total(name, where=""):
+        return sum(float(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
+                   if ln.startswith(name + "{") and where in ln)
+    pc = w.primary_runtime().engine.phase_counts
+    assert pc["decode.ahead_hit"] >= 10
+    for result, phase in (("launched", "decode.ahead_dispatch"),
+                          ("hit", "decode.ahead_hit"),
+                          ("discarded", "decode.ahead_discard")):
+        assert total("xllm_worker_decode_ahead_total",
+                     f'result="{result}"') == pc[phase]
+    assert total("xllm_worker_decode_ahead_dropped_rows_total") == \
+        pc["decode.ahead_dropped_rows"]
+    assert total("xllm_worker_phase_calls_total",
+                 'phase="decode.ahead_dispatch"') == \
+        pc["decode.ahead_dispatch"]

@@ -121,7 +121,7 @@ def test_decode_upload_is_a_span_with_one_site():
     assert src.count('_phase("decode.upload")') == 1
     run_decode = next(n for n in ast.walk(ast.parse(src))
                       if isinstance(n, ast.FunctionDef)
-                      and n.name == "_run_decode")
+                      and n.name == "_dispatch_decode")
     withs = [n for n in ast.walk(run_decode) if isinstance(n, ast.With)]
 
     def phase(w):

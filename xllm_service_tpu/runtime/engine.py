@@ -333,18 +333,28 @@ class Engine:
         # copy of the active+page-table block with its host mirror for
         # change detection. (docs/PERF_NOTES.md "ranked next steps" #1.)
         self._resident: Optional[Dict[str, Any]] = None
-        # Pipelined decode (docs/PERF_NOTES.md round 7): after burst k is
-        # dispatched, burst k+1 can be dispatched SPECULATIVELY from the
-        # device-resident carries before burst k's outputs are read back
-        # — burst k's host post then overlaps burst k+1's device
-        # compute. None = auto: on whenever bursts are fused.
+        # Pipelined decode bursts (docs/PERF_NOTES.md round 7): after
+        # burst k is dispatched, burst k+1 can be dispatched from the
+        # device-resident carries before burst k's outputs are read back.
+        # None = auto: on whenever bursts are fused. The SINGLE step has
+        # no such option: it launches ahead wherever ``_ahead_eligible``
+        # holds (``_launch_ahead``).
         dp = getattr(engine_cfg, "decode_pipeline", None)
         if dp is None:
             dp = engine_cfg.decode_steps > 1
         self.decode_pipeline = bool(dp) and engine_cfg.decode_steps > 1
-        # The in-flight speculative burst's device handles + the batch
-        # snapshot it assumed (consumed or rolled back by the next step).
+        # The ONE step or burst launched ahead of the host's read of the
+        # one before it: its device handles and what it assumed of the
+        # batch (taken or discarded by the next decode: ``_take_ahead``).
         self._pending: Optional[Dict[str, Any]] = None
+        # Do the rows of one step see each other? Only through a sparse
+        # layer that buckets by capacity (``transformer._mlp``'s
+        # ``moe_mlp``; the latent family's dropless layer computes what
+        # each row chose): there a row that has left still competes for
+        # an expert's capacity in a step launched ahead, so such a step
+        # is taken whole or not at all.
+        self._rows_interfere = (model_cfg.is_moe and not model_cfg.mla
+                                and model_cfg.moe_capacity_factor > 0)
         # Device-idle attribution: when the previous decode burst's
         # outputs became ready, and whether a speculative burst was
         # already covering the gap to the next dispatch.
@@ -685,15 +695,18 @@ class Engine:
         speculation dispatch/hit/rollback counts, the hit ratio, and
         host-side device-idle ms per burst boundary (0 for boundaries a
         speculative burst covered)."""
-        disp = self.phase_counts.get("decode_multi.spec_dispatch", 0)
-        hits = self.phase_counts.get("decode_multi.spec_hit", 0)
-        idle_n = self.phase_counts.get("decode_multi.device_idle", 0)
+        pc = self.phase_counts
+        disp = pc.get("decode_multi.spec_dispatch", 0) \
+            + pc.get("decode.ahead_dispatch", 0)
+        hits = pc.get("decode_multi.spec_hit", 0) \
+            + pc.get("decode.ahead_hit", 0)
+        idle_n = pc.get("decode_multi.device_idle", 0)
         idle_s = self.phase_times.get("decode_multi.device_idle", 0.0)
         return {
             "spec_dispatches": disp,
             "spec_hits": hits,
-            "spec_rollbacks": self.phase_counts.get(
-                "decode_multi.spec_rollback", 0),
+            "spec_rollbacks": pc.get("decode_multi.spec_rollback", 0)
+            + pc.get("decode.ahead_discard", 0),
             "hit_ratio": hits / disp if disp else 0.0,
             "device_idle_ms_per_burst":
                 1e3 * idle_s / idle_n if idle_n else 0.0,
@@ -1058,6 +1071,9 @@ class Engine:
         stall under prompt bursts."""
         self.step_count += 1
         outs = self._drain_cancelled()
+        if not self.running:
+            # Every row of a launch ahead has gone: nothing would take it.
+            self.drain_pipeline()
         # The same list every section extends in place: on a step fault
         # the worker salvages the completed sections' outputs from here
         # (a committed decode's tokens are already on the sequences —
@@ -1087,11 +1103,15 @@ class Engine:
         return outs
 
     def _step_interleaved(self, outs: List[StepOutput]) -> List[StepOutput]:
-        if self._jit_ragged is not None and self.running and self.waiting:
+        if self._jit_ragged is not None and self.running and self.waiting \
+                and (self._pending is None or self._pending["steps"] > 1):
             # One-dispatch ragged mixed step: decode rows and prefill
             # windows in one batch, one compiled program. Falls back to
             # the legacy decode-then-prefill sections when the iteration
             # isn't ragged-eligible (returns False without scheduling).
+            # A single step launched ahead IS this iteration's decode,
+            # already on the device: the sections below take it and
+            # prefill behind it.
             if self._step_ragged_mixed(outs):
                 return outs
         pre = len(outs)
@@ -1156,8 +1176,7 @@ class Engine:
                 len(s.tokens) + N - 1 <= self.ecfg.max_model_len
                 for s in self.running):
             return self._run_decode_multi()
-        # Single-step fallback: burst carries are unusable.
-        self.drain_pipeline()
+        # Single-step fallback (it discards a burst launched ahead).
         return self._run_decode()
 
     def _starvation_quantum(self) -> int:
@@ -1683,7 +1702,68 @@ class Engine:
                                    self._static_window)
 
     def _run_decode(self) -> List[StepOutput]:
-        B = self.ecfg.max_batch_size
+        """One decode step for the running rows. Step N+1 is launched
+        BEFORE step N is read (``_launch_ahead``): N's program hands back
+        the whole input of N+1 (``next_packed``, the key, the pools, the
+        histogram), so wherever the host's post of N cannot change what
+        N+1 needs, the device has N+1 queued while the host blocks on N,
+        posts it, emits, flushes and comes back. The next call takes the
+        step in flight in place of a pack and a dispatch."""
+        step = self._take_ahead(1)
+        if step is None:
+            step = self._dispatch_decode()
+            if step is None:
+                return []
+        self.last_step_attn_dispatches += 1
+        # In a burst engine the single step is the fallback for a row
+        # near ``max_model_len``: its next decode may be a burst again.
+        ahead = self._launch_ahead(step) \
+            if self.ecfg.decode_steps == 1 else None
+        fused, top_ids, top_lps, mdrop = self._read_host(
+            "decode", step["fused"],
+            step["top_ids"] if step["want_top"] else None,
+            step["top_lps"] if step["want_top"] else None, step["mdrop"])
+        next_tok, logprob = _split_tok_lp(fused)
+        self._note_moe_dropped(mdrop)
+        # ``next_packed`` as the host can compute it: active rows took
+        # the sampled token and the next position.
+        mirror = step["mirror"]
+        act = mirror[:, 2] != 0
+        mirror[act, 0] = next_tok[act]
+        mirror[act, 1] += 1
+        self._decode_carry = (step["next_packed"], mirror)
+        outs: List[StepOutput] = []
+        # Snapshot (seq, slot) first: _append_token may preempt a *later*
+        # sequence in this list (page-growth pressure), clearing its slot
+        # before we read its sampled token. Every running row was active
+        # in the step (``_ahead_stands``); a row that left since it was
+        # launched has its result dropped here, by not being read.
+        with self._phase("decode.post"):
+            for seq, i in [(s, s.slot) for s in self.running]:
+                if seq.status == SeqStatus.RUNNING:
+                    seq.num_computed = len(seq.tokens)
+                # A sequence preempted earlier in this loop still gets its
+                # token (sampled while its KV was resident); it re-prefills
+                # later.
+                outs.append(self._append_token(
+                    seq, int(next_tok[i]), float(logprob[i]),
+                    top=self._top_entry(seq, top_ids, top_lps, i)))
+        self._settle_ahead(ahead)
+        return outs
+
+    def _fill_slots(self) -> None:
+        """Host truth of the slot block's first three columns: the
+        running rows' last token, its position, and who is active."""
+        self._slot_active[:] = 0
+        for seq in self.running:
+            i = seq.slot
+            self._slot_active[i] = 1
+            self._slot_last_token[i] = seq.tokens[-1]
+            self._slot_pos[i] = len(seq.tokens) - 1
+
+    def _dispatch_decode(self) -> Optional[Dict[str, Any]]:
+        """Pack one decode step from host truth and launch it (None when
+        growing the pages preempted every row away)."""
         # Restore the pages-cover-len invariant at dispatch regardless of
         # which decode path ran last: the fused multi-step accepts up to N
         # tokens but pre-grows only its own lookahead window, so a sequence
@@ -1697,17 +1777,11 @@ class Engine:
                 if seq.status == SeqStatus.RUNNING:
                     self._grow_pages(seq)
             if not self.running:
-                return []
-            self._slot_active[:] = 0
-            for seq in self.running:
-                i = seq.slot
-                self._slot_active[i] = 1
-                self._slot_last_token[i] = seq.tokens[-1]
-                self._slot_pos[i] = len(seq.tokens) - 1
+                return None
+            self._fill_slots()
             if self._slot_st is None:
                 self._slot_st = self._sampling_tensors(
-                    self._slot_sampling, B)
-            st_f32, st_i32 = self._slot_st
+                    self._slot_sampling, self.ecfg.max_batch_size)
             mp = self._table_width()
             # Upload by value: the slot arrays above are host truth; the
             # device already holds them when they equal what the last
@@ -1725,47 +1799,66 @@ class Engine:
                     packed = jax.device_put(np.ascontiguousarray(block),
                                             self._carry_place)
                 mirror = block.copy()
+        return self._launch_decode(
+            self._phase("decode.dispatch", **self._decode_shape(mp)),
+            packed, mirror)
+
+    def _decode_shape(self, mp: int) -> Dict[str, Any]:
+        """The shape key a decode launch's span carries."""
+        return dict(program="decode", B=self.ecfg.max_batch_size, T=1,
+                    MP=mp, walk=self._decode_walk(mp))
+
+    def _launch_decode(self, bracket, packed: jnp.ndarray,
+                       mirror: np.ndarray) -> Dict[str, Any]:
+        """Enqueue the decode program on ``packed`` under the phase
+        ``bracket`` and start its outputs' host copy. ``mirror`` is the
+        host's copy of ``packed`` (of a step launched ahead: once the
+        step before it is read). The program splits the key itself and
+        hands the first half back: the values of a host-side split, with
+        no program of its own between two steps; the key it was given
+        rides along for a discard."""
+        mp = mirror.shape[1] - _PACK_COLS
+        key_before = self._rng_key
         cache_before = self._jit_cache_size(self._jit_decode)
-        with self._phase("decode.dispatch", program="decode", B=B, T=1,
-                         MP=mp, walk=self._decode_walk(mp)):
-            # The program splits the key itself and hands the first half
-            # back: the values of a host-side split, with no program of
-            # its own between two steps.
+        with bracket:
             (fused, top_ids, top_lps, self.kv, self._counts,
              mdrop, next_packed, self._rng_key) = self._jit_decode(
-                    self.params, packed, self.kv,
-                    st_f32, st_i32, self._rng_key, self._ensure_counts(),
+                    self.params, packed, self.kv, *self._slot_st,
+                    key_before, self._ensure_counts(),
                     *self._ensure_bias())
-        self.last_step_attn_dispatches += 1
         self._note_recompile("decode", self._jit_decode, cache_before, mp)
         want_top = self._want_top(top_ids, self.running)
-        fused, top_ids, top_lps, mdrop = self._read_host(
-            "decode", fused,
-            top_ids if want_top else None,
-            top_lps if want_top else None, mdrop)
-        next_tok, logprob = _split_tok_lp(fused)
-        self._note_moe_dropped(mdrop)
-        # ``next_packed`` as the host can compute it: active rows took
-        # the sampled token and the next position.
-        act = mirror[:, 2] != 0
-        mirror[act, 0] = next_tok[act]
-        mirror[act, 1] += 1
-        self._decode_carry = (next_packed, mirror)
-        outs: List[StepOutput] = []
-        # Snapshot (seq, slot) first: _append_token may preempt a *later*
-        # sequence in this list (page-growth pressure), clearing its slot
-        # before we read its sampled token.
-        with self._phase("decode.post"):
-            for seq, i in [(s, s.slot) for s in self.running]:
-                if seq.status == SeqStatus.RUNNING:
-                    seq.num_computed = len(seq.tokens)
-                # A sequence preempted earlier in this loop still gets its
-                # token (sampled while its KV was resident); it re-prefills
-                # later.
-                outs.append(self._append_token(
-                    seq, int(next_tok[i]), float(logprob[i]),
-                    top=self._top_entry(seq, top_ids, top_lps, i)))
-        return outs
+        _start_host_copy(fused, top_ids if want_top else None,
+                         top_lps if want_top else None)
+        return {"steps": 1, "whole": self._rows_interfere,
+                "counts": ("decode.ahead_hit", "decode.ahead_discard"),
+                "fused": fused, "top_ids": top_ids, "top_lps": top_lps,
+                "mdrop": mdrop, "want_top": want_top,
+                "next_packed": next_packed, "mirror": mirror,
+                "key_before": key_before,
+                "members": tuple((s.req.request_id, s.slot)
+                                 for s in self.running)}
+
+    def _launch_ahead(self, step: Dict[str, Any]
+                      ) -> Optional[Dict[str, Any]]:
+        """Launch step N+1 from what ``step`` (N: enqueued, not yet
+        read) leaves on the device, exactly as a resident hit would be
+        launched after N's post, if that post cannot change what N+1
+        needs (``_ahead_eligible``) and the block N hands on is host
+        truth in all but the two columns N itself computes: same width,
+        same rows active, same tables. Nothing is allocated and nothing
+        on the host moves, so a discard has nothing to undo."""
+        mirror = step["mirror"]
+        mp = mirror.shape[1] - _PACK_COLS
+        if not self._ahead_eligible(1) or mp != self._table_width():
+            return None
+        self._fill_slots()
+        if not _same_block(mirror[:, 2:],
+                           self._slot_packed[:, 2:_PACK_COLS + mp]):
+            return None
+        return self._launch_decode(
+            self._phase("decode.ahead_dispatch", **self._decode_shape(mp)),
+            step["next_packed"], mirror)
 
     def _run_decode_multi(self) -> List[StepOutput]:
         """N fused decode steps per host round-trip (one lax.scan program).
@@ -1783,31 +1876,24 @@ class Engine:
         burst k+1 is dispatched SPECULATIVELY before blocking on burst
         k's copy; the host post of burst k then runs concurrently with
         burst k+1's device compute. A speculation invalidated by the post
-        (EOS/length finish, preempt, admit, trim) is discarded: its rng
-        split is never committed (the replacement burst re-splits the
-        same key — token streams stay byte-identical to pipeline-off,
-        pinned in tests/test_engine.py), the penalty histogram rebuilds
-        from host truth, and its in-place KV writes are harmless — they
-        land only at positions >= every sequence's computed length
+        (EOS/length finish, preempt, admit, trim) is discarded: the
+        engine's key goes back to the one it split (the replacement burst
+        re-splits the same key — token streams stay byte-identical to
+        pipeline-off, pinned in tests/test_engine.py), the penalty
+        histogram rebuilds from host truth, and its in-place KV writes
+        are harmless — they land only at positions >= every sequence's
+        computed length
         (re-written by the replacement burst before they are attended or
         content-addressed), and pages released meanwhile are only reused
         by computations the runtime enqueues after it (program order on
         the one device stream)."""
-        burst = None
-        pending, self._pending = self._pending, None
-        if pending is not None:
-            if self._pending_matches(pending):
-                # Speculation hit: burst k+1 was dispatched before burst
-                # k's readback and the batch still matches its carries —
-                # consume it with zero pack/upload work; the device
-                # never idled across the boundary.
-                self.phase_counts["decode_multi.spec_hit"] += 1
-                self._rng_key = pending["next_key"]
-                self._note_burst_gap(overlapped=True)
-                burst = pending
-            else:
-                self._discard_spec(pending)
-        if burst is None:
+        # A hit: burst k+1 was dispatched before burst k's readback and
+        # the batch still matches its carries: it is consumed with zero
+        # pack/upload work; the device never idled across the boundary.
+        burst = self._take_ahead(self.ecfg.decode_steps)
+        if burst is not None:
+            self._note_burst_gap(overlapped=True)
+        else:
             burst = self._dispatch_burst()
             if burst is None:
                 return []
@@ -1828,14 +1914,7 @@ class Engine:
 
         outs = self._post_decode_multi(burst, toks, logps, top_ids,
                                        top_lps, carry_free=spec is None)
-        if spec is not None:
-            if self._pending_matches(spec):
-                self._pending = spec
-            else:
-                # The post discovered the speculation was wrong (a finish
-                # mid-burst, a trim, ...) — discard before anything else
-                # observes the stale carries.
-                self._discard_spec(spec)
+        self._settle_ahead(spec)
         return outs
 
     def _dispatch_burst(self) -> Optional[Dict[str, Any]]:
@@ -1862,12 +1941,7 @@ class Engine:
                                      lookahead=max(remaining - 1, 0))
             if not self.running:
                 return None
-            self._slot_active[:] = 0
-            for seq in self.running:
-                i = seq.slot
-                self._slot_active[i] = 1
-                self._slot_last_token[i] = seq.tokens[-1]
-                self._slot_pos[i] = len(seq.tokens) - 1
+            self._fill_slots()
             if self._slot_st is None:
                 self._slot_st = self._sampling_tensors(
                     self._slot_sampling, B)
@@ -1922,21 +1996,22 @@ class Engine:
     def _dispatch_spec(self, burst: Dict[str, Any]
                        ) -> Optional[Dict[str, Any]]:
         """Speculatively dispatch the NEXT burst from ``burst``'s
-        device-resident carries, before ``burst``'s readback. The rng
-        split is held uncommitted in the returned dict (committed only
-        on acceptance) so a rollback replays the exact pipeline-off key
-        stream. Starts the async host copy of the speculative outputs
-        immediately: by the time the next step accepts them the copy has
-        been overlapping host post + device compute for a whole burst."""
-        if not self._spec_eligible():
+        device-resident carries, before ``burst``'s readback. The key it
+        split rides along (``_discard_ahead`` puts it back, so a rollback
+        replays the exact pipeline-off key stream). Starts the async host
+        copy of the speculative outputs immediately: by the time the next
+        step accepts them the copy has been overlapping host post +
+        device compute for a whole burst."""
+        N = self.ecfg.decode_steps
+        if self._dev_active_pt is None or not self._ahead_eligible(N):
             return None
-        next_key, key = jax.random.split(self._rng_key)
+        key_before = self._rng_key
+        self._rng_key, key = jax.random.split(key_before)
         cache_before = self._jit_cache_size(self._jit_decode_multi)
         mp = self._dev_active_pt.shape[1] - 2
         with self._phase("decode_multi.spec_dispatch",
                          program="decode_multi",
-                         B=self.ecfg.max_batch_size,
-                         T=self.ecfg.decode_steps, MP=mp):
+                         B=self.ecfg.max_batch_size, T=N, MP=mp):
             (fused, top_ids, top_lps, self.kv, self._counts,
              mdrop, fin_tok, fin_pos) = self._jit_decode_multi(
                     self.params, burst["fin_tok"], burst["fin_pos"],
@@ -1947,26 +2022,29 @@ class Engine:
                              cache_before, mp)
         _start_host_copy(fused, top_ids if burst["want_top"] else None,
                          top_lps if burst["want_top"] else None)
-        return {"fused": fused, "top_ids": top_ids, "top_lps": top_lps,
+        return {"steps": N, "whole": True,
+                "counts": ("decode_multi.spec_hit",
+                           "decode_multi.spec_rollback"),
+                "fused": fused, "top_ids": top_ids, "top_lps": top_lps,
                 "mdrop": mdrop, "fin_tok": fin_tok, "fin_pos": fin_pos,
-                "want_top": burst["want_top"], "next_key": next_key,
+                "want_top": burst["want_top"], "key_before": key_before,
                 "members": tuple((s.req.request_id, s.slot)
                                  for s in self.running)}
 
-    def _spec_eligible(self) -> bool:
-        """May the next burst be dispatched from the current burst's
-        device carries before its outputs are read back? Conservative —
-        only when the host post cannot need anything the speculation
-        lacks: no queued or cancelled work (the next step would schedule
-        a prefill), nobody can expire by length inside the current burst
-        (an EOS still rolls back — it is unpredictable), the speculative
-        writes stay inside ``max_model_len``, the existing page tables
-        already cover them (speculation never allocates, so a rollback
-        has nothing to undo), and any penalty histogram is already
+    def _ahead_eligible(self, N: int) -> bool:
+        """May the next ``N`` decode steps be launched from the device
+        carries of the step or burst in flight, before its outputs are
+        read back? Conservative: only when the host post cannot need
+        anything the launch lacks: no queued or cancelled work (the next
+        iteration would schedule a prefill behind it, or drain), the
+        sampling tensors resident, nobody expiring by length in the step
+        or burst in flight (known a step ahead; an EOS is not: its row's
+        result is dropped, or the launch discarded where it is taken
+        whole), the writes inside ``max_model_len``, the existing page
+        tables already covering them (a launch ahead never allocates, so
+        a discard has nothing to undo), and any penalty histogram already
         device-resident (a host rebuild would read a stale ledger)."""
-        N = self.ecfg.decode_steps
-        if self.waiting or self._cancelled or self._slot_st is None \
-                or self._dev_active_pt is None:
+        if self.waiting or self._cancelled or self._slot_st is None:
             return False
         ps = self.ecfg.page_size
         for s in self.running:
@@ -1985,34 +2063,83 @@ class Engine:
             return False
         return True
 
-    def _pending_matches(self, p: Dict[str, Any]) -> bool:
-        """A speculative burst stays valid only while the batch is
-        exactly what its carries assumed: same membership in the same
-        slots (an EOS/length finish, preempt, cancel or import changes
-        it — and membership equality implies every sequence accepted the
-        full burst, so the host token tail EQUALS the device carries)
-        and an unchanged active+page-table block (sliding-window trims
-        and page growth re-upload it)."""
-        if self._active_pt_mirror is None or self._slot_st is None:
+    def _ahead_stands(self, p: Dict[str, Any]) -> bool:
+        """Do the results of the launch ahead ``p`` stand? A row's does
+        when it is the same request in the same slot, still running:
+        every input of that row was the output of the step before, by
+        construction; a row that finished, was cancelled or preempted
+        since has its result dropped (its write landed at or past its
+        computed length in a page it held when the program was enqueued,
+        see ``_run_decode_multi``). So a step whose rows do not see each
+        other stands while every running row was active in it, whatever
+        the post did to a table meanwhile (it read and wrote only pages
+        the row held at launch; a trimmed page lies outside the window
+        it attended). Taken ``whole`` (a burst, or rows that share an
+        expert's capacity) it stands only while the batch is exactly
+        what its carries assumed: same membership in the same slots (an
+        EOS/length finish, preempt, cancel or import changes it, and
+        membership equality implies every sequence accepted the full
+        burst, so the host token tail EQUALS the device carries) and,
+        for a burst, an unchanged active+page-table block (its next
+        launch passes the device copy on)."""
+        live = tuple((s.req.request_id, s.slot) for s in self.running)
+        if not live:
             return False
-        members = tuple((s.req.request_id, s.slot) for s in self.running)
-        if not members or members != p["members"]:
+        if not p["whole"]:
+            return set(live) <= set(p["members"])
+        if live != p["members"] or self._slot_st is None:
+            return False
+        if p["steps"] == 1:
+            return True
+        if self._active_pt_mirror is None:
             return False
         mp = self._active_pt_mirror.shape[1] - 2
         apt_now = self._slot_packed[:, 2:_PACK_COLS + mp]
         return _same_block(self._active_pt_mirror, apt_now)
 
-    def _discard_spec(self, p: Dict[str, Any]) -> None:
-        """Roll a speculative burst back (host bookkeeping only — the
-        device computation finishes on its own and its outputs are
-        dropped). The rng key was never committed, so the replacement
-        burst re-splits the same key; the penalty histogram rebuilds
-        from host truth at the next dispatch; the resident carries are
-        dropped so the replacement uploads fresh token/position state."""
-        self.phase_counts["decode_multi.spec_rollback"] += 1
+    def _take_ahead(self, n_steps: int) -> Optional[Dict[str, Any]]:
+        """The launch ahead, handed to the decode of ``n_steps`` that
+        would otherwise pack and dispatch; discarded (None) if it is of
+        the other kind or no longer stands."""
+        p, self._pending = self._pending, None
+        if p is None:
+            return None
+        if p["steps"] != n_steps or not self._ahead_stands(p):
+            self._discard_ahead(p)
+            return None
+        self.phase_counts[p["counts"][0]] += 1
+        if n_steps == 1:
+            # It was handed the block the step before left on the device.
+            self.phase_counts["decode.resident_hit"] += 1
+            self.phase_counts["decode.ahead_dropped_rows"] += \
+                len(p["members"]) - len(self.running)
+        return p
+
+    def _settle_ahead(self, p: Optional[Dict[str, Any]]) -> None:
+        """After the post of the step before it: keep the launch ahead
+        for the next decode, or discard it now if the post voided it (a
+        finish mid-burst, a trim, every row gone: nothing would ever
+        take it), before anything else observes the stale carries."""
+        if p is None:
+            return
+        if self._ahead_stands(p):
+            self._pending = p
+        else:
+            self._discard_ahead(p)
+
+    def _discard_ahead(self, p: Dict[str, Any]) -> None:
+        """Roll a launch ahead back (host bookkeeping only: the device
+        computation finishes on its own and its outputs are dropped).
+        The engine's key goes back to the one the launch was given, so
+        the replacement draws what a sequential engine draws; the penalty
+        histogram rebuilds from host truth at the next dispatch; the
+        burst's resident carries are dropped so the replacement uploads
+        fresh token/position state (the single step's carry is compared
+        by value anyway)."""
+        self.phase_counts[p["counts"][1]] += 1
+        self._rng_key = p["key_before"]
         self._counts = None
         self._resident = None
-        self._decode_carry = None
         # A rolled-back boundary is neither idle nor covered: the device
         # spent it computing the discarded burst (wasted work, counted
         # above) — exclude it from the idle ledger rather than book a
@@ -2025,7 +2152,7 @@ class Engine:
         import/export, warmup — and by the worker's sleep path."""
         pending, self._pending = self._pending, None
         if pending is not None:
-            self._discard_spec(pending)
+            self._discard_ahead(pending)
 
     # ------------------------------------------------------------------
     # Device-plane fault containment (docs/ROBUSTNESS.md): the worker's
@@ -2129,7 +2256,7 @@ class Engine:
         speculative burst covered the gap. Only consecutive decode
         bursts count: a prefill or idle stretch in between is
         scheduling, and a rolled-back boundary is excluded entirely
-        (_discard_spec clears the timestamp — the device was busy on
+        (_discard_ahead clears the timestamp — the device was busy on
         the discarded burst, not idle)."""
         t = self._last_burst_ready_t
         if t is None or self.step_count != self._last_burst_step + 1:

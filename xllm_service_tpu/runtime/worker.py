@@ -1454,6 +1454,27 @@ class Worker:
             "block (the others passed the one the last step handed back)",
             labelnames=("model",)).set_total(
             eng.phase_counts.get("decode.upload", 0), model=m)
+        # hit / (decode + mixed steps) is the share of single-step decodes
+        # that were already on the device when their iteration began.
+        ahead = self.obs.counter(
+            "xllm_worker_decode_ahead_total",
+            "single decode steps launched before the step before them "
+            "was read (launched), taken by the next iteration in place "
+            "of a pack and a dispatch (hit), or thrown away whole "
+            "(discarded)",
+            labelnames=("model", "result"))
+        for result, phase in (("launched", "decode.ahead_dispatch"),
+                              ("hit", "decode.ahead_hit"),
+                              ("discarded", "decode.ahead_discard")):
+            ahead.set_total(eng.phase_counts.get(phase, 0), model=m,
+                            result=result)
+        self.obs.counter(
+            "xllm_worker_decode_ahead_dropped_rows_total",
+            "rows of taken launched-ahead steps whose result was dropped "
+            "(the request finished, was cancelled or preempted at the "
+            "step before)",
+            labelnames=("model",)).set_total(
+            eng.phase_counts.get("decode.ahead_dropped_rows", 0), model=m)
         if eng.cfg.is_moe:
             self._flush_moe(rt)
         tok = self.obs.counter(
