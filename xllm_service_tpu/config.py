@@ -210,6 +210,21 @@ class ModelConfig:
     # combine masks are [G, E, C_g] per group — linear, not quadratic, in
     # window length (GShard's group axis; parallel/expert.py).
     moe_group_size: int = 512
+    # Layers that differ in KIND (LFM2: gated short convolutions between
+    # attention layers, a dense FFN in the leading layers and routed
+    # experts after): one "<operator>+<ffn>" a layer, operator "conv" |
+    # "attn", ffn "dense" | "moe". Data, not a family: the layer loop
+    # (models/transformer.py, "Layers that differ in kind") walks
+    # whatever pattern stands here. None = every layer alike (the
+    # scanned bodies of the other families).
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    # Taps of a "conv" layer's causal depthwise filter (HF conv_L_cache):
+    # the layer's state is the last conv_kernel - 1 gated inputs.
+    conv_kernel: int = 0
+    # What the sigmoid gate adds to the chosen scores' sum before it
+    # divides by it (norm_topk_prob): 1e-20 in DeepSeek-V3's gate, 1e-6
+    # in LFM2's.
+    moe_gate_eps: float = 1e-20
     dtype: str = "bfloat16"
 
     def __post_init__(self) -> None:
@@ -230,6 +245,26 @@ class ModelConfig:
     @property
     def mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def num_attn_layers(self) -> int:
+        """Layers that keep keys and values: the pools' leading axis."""
+        if self.layer_kinds is None:
+            return self.num_layers
+        return sum(k.startswith("attn+") for k in self.layer_kinds)
+
+    @property
+    def num_conv_layers(self) -> int:
+        """Layers whose state is a convolution tail (the third pool)."""
+        return sum(k.startswith("conv+") for k in self.layer_kinds or ())
+
+    @property
+    def dropless_experts(self) -> bool:
+        """Do the sparse layers run ``expert.dropless_moe`` (what the
+        gate chose is computed, nothing dropped, the routing counted)?
+        The latent family's and a ``layer_kinds`` model's do; ``_mlp``'s
+        families bucket by capacity."""
+        return self.is_moe and (self.mla or self.layer_kinds is not None)
 
     @property
     def qk_head_dim(self) -> int:
@@ -453,7 +488,7 @@ class ModelConfig:
                      "mixtral", "gemma2", "gemma3", "gemma3_text",
                      "qwen2_vl", "qwen2_5_vl",
                      "qwen3_moe", "deepseek_v2", "deepseek_v3",
-                     "joyai_llm_flash", "gpt_oss")
+                     "joyai_llm_flash", "gpt_oss", "lfm2_moe")
         # The latent family: DeepSeek-V2, and the V3 layer (sigmoid
         # scores, selection bias) that JD's JoyAI-LLM-Flash shares.
         _v3 = mt in ("deepseek_v3", "joyai_llm_flash")
@@ -516,6 +551,36 @@ class ModelConfig:
                 raise ValueError(
                     f"gemma3 rope_scaling {rs_kind!r} is not implemented "
                     f"(global layers support linear scaling only)")
+        layer_kinds = None
+        _lfm = mt == "lfm2_moe"
+        if _lfm:
+            # LiquidAI LFM2-MoE: layer_types says which operator a layer
+            # has, num_dense_layers how many leading layers keep a dense
+            # FFN; the rest route. Anything this loop has no body for is
+            # refused, as everywhere here.
+            if d.get("conv_bias"):
+                raise ValueError("lfm2_moe with conv_bias is not implemented")
+            ops = {"conv": "conv", "full_attention": "attn"}
+            lt = d["layer_types"]
+            if len(lt) != d["num_hidden_layers"] \
+                    or any(t not in ops for t in lt):
+                raise ValueError(
+                    f"lfm2_moe layer_types {sorted(set(lt))} over "
+                    f"{len(lt)} of {d['num_hidden_layers']} layers is not "
+                    f"implemented (conv, full_attention; one a layer)")
+            n_dense = int(d.get("num_dense_layers", 0))
+            layer_kinds = tuple(
+                ops[t] + ("+dense" if i < n_dense else "+moe")
+                for i, t in enumerate(lt))
+            # rope_parameters is transformers' newer spelling of
+            # rope_theta + rope_scaling.
+            rp = d.get("rope_parameters") or {}
+            d = {"rope_theta": rp.get("rope_theta", 1000000.0),
+                 "rope_scaling": rp if rp.get("rope_type", "default")
+                 != "default" else None,
+                 "rms_norm_eps": d.get("norm_eps", 1e-5),
+                 # Lfm2MoeConfig ties the head to the embedding.
+                 "tie_word_embeddings": True, **d}
         layer_sliding = None
         if mt in ("gemma2", "gemma3_text", "gpt_oss"):
             # Alternating local/global layers: HF's layer_types (or the
@@ -603,7 +668,7 @@ class ModelConfig:
                                  in ("qwen2", "qwen2_vl", "qwen2_5_vl",
                                      "gpt_oss")),
             qk_norm=d.get("model_type") in ("qwen3", "qwen3_moe",
-                                            "gemma3_text"),
+                                            "gemma3_text", "lfm2_moe"),
             fused_proj=d.get("model_type") == "phi3",
             sliding_window=sw,
             layer_sliding=layer_sliding,
@@ -623,7 +688,8 @@ class ModelConfig:
             gemma=mt in ("gemma2", "gemma3_text"),
             rope_local_base_freq=(d.get("rope_local_base_freq", 10000.0)
                                   if mt == "gemma3_text" else None),
-            num_experts=(d.get("num_experts", 0) if mt == "qwen3_moe"
+            num_experts=(d.get("num_experts", 0)
+                         if mt in ("qwen3_moe", "lfm2_moe")
                          else d.get("n_routed_experts", 0) if _dsk
                          else d.get("num_local_experts", 0)),
             num_experts_per_tok=d.get("num_experts_per_tok", 2),
@@ -645,7 +711,13 @@ class ModelConfig:
             topk_group=d.get("topk_group"),
             first_k_dense_replace=(d.get("first_k_dense_replace", 0)
                                    if _dsk else 0),
-            moe_scoring="sigmoid" if _v3 else "softmax",
+            # LFM2's gate is V3's with one group: sigmoid scores, a bias
+            # that shapes the choice only (use_expert_bias; zeros without
+            # it), the chosen scores over their sum + 1e-6.
+            moe_scoring="sigmoid" if _v3 or _lfm else "softmax",
+            moe_gate_eps=1e-6 if _lfm else 1e-20,
+            layer_kinds=layer_kinds,
+            conv_kernel=int(d.get("conv_L_cache", 0)) if _lfm else 0,
             gptoss=mt == "gpt_oss",
             rope_interleave=bool(d.get("rope_interleave", True)),
             # The mscale² softmax-scale fold follows the CHECKPOINT, not

@@ -74,6 +74,8 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     """Random-init a parameter pytree with the stacked-layer layout."""
     if cfg.mla:
         return _init_mla_params(cfg, key, dtype)
+    if cfg.layer_kinds is not None:
+        return _init_kinds_params(cfg, key, dtype)
     dtype = dtype or jnp.dtype(cfg.dtype)
     L, D, F = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
     Hq, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -138,6 +140,19 @@ def num_params(params: Params) -> int:
 def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                   dtype: Optional[jnp.dtype] = None) -> KVCache:
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.layer_kinds is not None:
+        # Keys and values of the ATTENTION layers alone, and a third
+        # pool of convolution tails: one row a page a convolution layer,
+        # the layer's state as of the last token written into that page
+        # ("Layers that differ in kind", below). Flat rows, so that no
+        # short axis is padded to a tile.
+        pack = _kv_pack(cfg)
+        shape = (max(cfg.num_attn_layers, 1), num_pages, page_size,
+                 cfg.num_kv_heads // pack, pack * cfg.head_dim)
+        tails = (max(cfg.num_conv_layers, 1), num_pages,
+                 max(cfg.conv_kernel - 1, 1) * cfg.hidden_size)
+        return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
+                jnp.zeros(tails, dtype))
     shape = (cfg.num_layers, num_pages, page_size, cfg.kv_cache_heads,
              cfg.kv_cache_dim)
     if cfg.mla:
@@ -401,6 +416,15 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             return_all_logits=return_all_logits,
             prompt_lp_targets=prompt_lp_targets,
             return_stats=return_stats, plan=plan)
+    if cfg.layer_kinds is not None:
+        assert mm_embeds is None and not plan.ragged_rows, \
+            "a layer_kinds model has neither a multimodal splice nor a " \
+            "ragged mixed-batch path"
+        return _kinds_forward_prefill(
+            params, cfg, tokens, start_pos, lengths, kv, page_table,
+            return_all_logits=return_all_logits,
+            prompt_lp_targets=prompt_lp_targets,
+            return_stats=return_stats, plan=plan)
     k_pages, v_pages = kv
     write_then_attend = plan.write_then_attend
     x = _scale_embed(cfg, params["embed"][tokens]
@@ -631,7 +655,8 @@ def forward_prefill_ring(params: Params, cfg: ModelConfig,
     from xllm_service_tpu.parallel.mesh import AXIS_TP
     from xllm_service_tpu.parallel.ring import ring_attention_sharded
 
-    if cfg.sliding_window or cfg.gemma or cfg.mla or cfg.gptoss:
+    if cfg.sliding_window or cfg.gemma or cfg.mla or cfg.gptoss \
+            or cfg.layer_kinds is not None:
         # Ring rotation assumes full causal reach and the plain llama
         # layer body; SWA/Gemma/MLA/GPT-OSS long prompts take the
         # chunked-window path (whose flash fold skips out-of-window
@@ -700,9 +725,10 @@ def forward_embedding(params: Params, cfg: ModelConfig,
     """Sequence embeddings: causal forward (no KV cache), masked mean-pool
     of the final hidden states, L2-normalized. tokens [B, T] padded,
     lengths [B] → [B, hidden] float32."""
-    if cfg.mla:
+    if cfg.mla or cfg.layer_kinds is not None:
         raise NotImplementedError(
-            "/v1/embeddings is not implemented for MLA models")
+            "/v1/embeddings is not implemented for MLA models nor for "
+            "models whose layers differ in kind")
     B, T = tokens.shape
     x = _scale_embed(cfg, params["embed"][tokens]
                      .astype(jnp.dtype(cfg.dtype)))
@@ -802,6 +828,10 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         return _mla_forward_decode(params, cfg, tokens, positions,
                                    active, kv, page_table,
                                    return_stats=return_stats, plan=plan)
+    if cfg.layer_kinds is not None:
+        return _kinds_forward_decode(params, cfg, tokens, positions,
+                                     active, kv, page_table,
+                                     return_stats=return_stats, plan=plan)
     k_pages, v_pages = kv
     write_then_attend = plan.write_then_attend
     x = _scale_embed(cfg, params["embed"][tokens[:, None]]
@@ -1042,17 +1072,18 @@ def _deepseek_gate(cfg: ModelConfig, x: jnp.ndarray,
     topw = jnp.take_along_axis(scores if sigmoid else choice, topi,
                                axis=-1)
     if sigmoid and cfg.norm_topk_prob:
-        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-20)
+        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True)
+                       + cfg.moe_gate_eps)
     return topi.astype(jnp.int32), topw * cfg.routed_scaling_factor
 
 
 def moe_stats_shape(cfg: ModelConfig) -> Tuple[int, ...]:
-    """Shape of what a step's sparse layers count: the latent family's
+    """Shape of what a step's sparse layers count: the dropless layers
     count what they routed (``expert.MOE_STATS``, summed over layers;
     element 0 is the dropped count every family reports), every other
     model has the one scalar."""
     from xllm_service_tpu.parallel.expert import MOE_STATS
-    return (len(MOE_STATS),) if cfg.mla and cfg.is_moe else ()
+    return (len(MOE_STATS),) if cfg.dropless_experts else ()
 
 
 def _moe_stats_dict(moe_stats: jnp.ndarray) -> Dict[str, jnp.ndarray]:
@@ -1081,11 +1112,11 @@ def _split_experts(stack: Dict[str, jnp.ndarray]):
             {k: stack[k] for k in _EXPERT_LEAVES})
 
 
-def _mla_moe_mlp(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
-                 experts: Dict[str, jnp.ndarray], layer: jnp.ndarray,
-                 x: jnp.ndarray, valid: Optional[jnp.ndarray] = None,
-                 plan: KernelPlan = KernelPlan()
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _dropless_moe_mlp(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+                      experts: Dict[str, jnp.ndarray], layer: jnp.ndarray,
+                      x: jnp.ndarray, valid: Optional[jnp.ndarray] = None,
+                      plan: KernelPlan = KernelPlan()
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Routed experts + the always-on shared experts of sparse layer
     ``layer`` (its index in the ``experts`` stacks); returns
     ``(out [B, T, D], stats)``. The DeepSeek gate chooses; the dropless
@@ -1218,8 +1249,9 @@ def _mla_forward_prefill(params: Params, cfg: ModelConfig,
             h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
             stats = None
             if moe:
-                y, stats = _mla_moe_mlp(cfg, lp, experts, li - L_dense, h,
-                                        valid=tok_valid, plan=plan)
+                y, stats = _dropless_moe_mlp(
+                    cfg, lp, experts, li - L_dense, h, valid=tok_valid,
+                    plan=plan)
                 x = x + y
             else:
                 x = x + (jax.nn.silu(h @ lp["gate_proj"])
@@ -1335,8 +1367,9 @@ def _mla_forward_decode(params: Params, cfg: ModelConfig,
             h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
             stats = None
             if moe:
-                y, stats = _mla_moe_mlp(cfg, lp, experts, li - L_dense, h,
-                                        valid=active[:, None], plan=plan)
+                y, stats = _dropless_moe_mlp(
+                    cfg, lp, experts, li - L_dense, h,
+                    valid=active[:, None], plan=plan)
                 x = x + y
             else:
                 x = x + (jax.nn.silu(h @ lp["gate_proj"])
@@ -1381,3 +1414,404 @@ def _mla_forward_decode(params: Params, cfg: ModelConfig,
     if return_stats:
         return logits, (k_pages,), _moe_stats_dict(moe_stats)
     return logits, (k_pages,)
+
+
+# ---------------------------------------------------------------------------
+# Layers that differ in kind (ModelConfig.layer_kinds; LFM2-MoE)
+#
+# A layer is "<operator>+<ffn>": the operator a gated short convolution
+# ("conv") or grouped-query attention ("attn"), the FFN a dense SwiGLU
+# ("dense") or routed experts ("moe": the DeepSeek gate and the dropless
+# layer, as the latent family's). Weights are stacked PER KIND, in layer
+# order (params["stacks"][kind]); the loop walks the pattern as leading
+# layers, a ``lax.scan`` over the repeating period and a remainder, and
+# inside each of those a run of layers alike is a scan of its own, so a
+# program traces a kind's body once for each of the three places it
+# appears in, whatever the depth. Prefill and decode share the loop and
+# each kind's body; they differ in the two operators they hand it.
+#
+# The cache is three pools. Keys and values belong to the ATTENTION
+# layers alone ([L_attn, P, ps, Hkv, Dh]) and always ride the loop as a
+# carry, written before they are read (write-then-attend, whatever the
+# plan says of the other families' ordering). A convolution layer's
+# state is the last ``conv_kernel - 1`` gated inputs z: ONE ROW A PAGE,
+# tails[c, p] = the layer's state as of the last token written into page
+# p. A token at position t reads the row of the page that holds t - 1
+# and writes the row of the page that holds t; a prefill window writes
+# the row of every page it touches. A full page's row is therefore the
+# state a prefix hit continues from, a partial page belongs to one
+# sequence, freeing a page frees its row, and the host keeps no state
+# of its own: the prefix index still hashes pages and nothing else.
+# ---------------------------------------------------------------------------
+
+def _init_kinds_params(cfg: ModelConfig, key: jax.Array,
+                       dtype: Optional[jnp.dtype]) -> Params:
+    dtype = dtype or jnp.dtype(cfg.dtype)
+    D, F = cfg.hidden_size, cfg.intermediate_size
+    Hq, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    E, Fe = cfg.num_experts, cfg.moe_intermediate_size or F
+    K = cfg.conv_kernel
+    keys = iter(jax.random.split(key, 64))
+
+    def w(shape, fan_in):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * (1.0 / math.sqrt(fan_in))).astype(dtype)
+
+    stacks: Dict[str, Dict[str, jnp.ndarray]] = {}
+    for kind in sorted(set(cfg.layer_kinds)):
+        n = cfg.layer_kinds.count(kind)
+        op, ffn = kind.split("+")
+        st = {"input_norm": jnp.ones((n, D), dtype),
+              "post_norm": jnp.ones((n, D), dtype)}
+        if op == "conv":
+            st.update(conv_in=w((n, D, 3 * D), D), conv_w=w((n, K, D), K),
+                      conv_out=w((n, D, D), D))
+        else:
+            st.update(q_proj=w((n, D, Hq * Dh), D),
+                      k_proj=w((n, D, Hkv * Dh), D),
+                      v_proj=w((n, D, Hkv * Dh), D),
+                      o_proj=w((n, Hq * Dh, D), Hq * Dh))
+            if cfg.qk_norm:
+                st.update(q_norm=jnp.ones((n, Dh), dtype),
+                          k_norm=jnp.ones((n, Dh), dtype))
+        if ffn == "moe":
+            st.update(router=w((n, D, E), D),
+                      router_bias=jnp.zeros((n, E), jnp.float32),
+                      gate_proj=w((n, E, D, Fe), D),
+                      up_proj=w((n, E, D, Fe), D),
+                      down_proj=w((n, E, Fe, D), Fe))
+        else:
+            st.update(gate_proj=w((n, D, F), D), up_proj=w((n, D, F), D),
+                      down_proj=w((n, F, D), F))
+        stacks[kind] = st
+    params: Params = {"embed": w((cfg.vocab_size, D), D), "stacks": stacks,
+                      "final_norm": jnp.ones((D,), dtype)}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w((D, cfg.vocab_size), D)
+    return params
+
+
+def _runs(kinds) -> Tuple[Tuple[str, int], ...]:
+    """``kinds`` as runs of layers alike: ((kind, count), ...)."""
+    out: list = []
+    for k in kinds:
+        if out and out[-1][0] == k:
+            out[-1][1] += 1
+        else:
+            out.append([k, 1])
+    return tuple((k, n) for k, n in out)
+
+
+def kinds_pattern(kinds: Tuple[str, ...]) -> Tuple[int, int, int]:
+    """``(lead, period, repeats)``: ``kinds[:lead]`` lead, then ``repeats``
+    times the ``period`` kinds that follow them, then a remainder. Of
+    all such readings the one that leaves the fewest layers outside the
+    scan (the published 40 layers: 2 leading, 9 periods of 4, 2 left
+    over); ``(len, 0, 0)`` where nothing repeats."""
+    L = len(kinds)
+    best = (L, L, 0, 0)
+    for lead in range(L):
+        for p in range(1, (L - lead) // 2 + 1):
+            n = 1
+            while lead + (n + 1) * p <= L and \
+                    kinds[lead + n * p:lead + (n + 1) * p] \
+                    == kinds[lead:lead + p]:
+                n += 1
+            if n >= 2:
+                best = min(best, (L - n * p + p, lead, p, n))
+    return best[1:]
+
+
+def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+                  pools, conv_op, attn_op, valid: jnp.ndarray,
+                  plan: KernelPlan):
+    """The layer loop over ``cfg.layer_kinds``. ``pools`` = (k, v,
+    tails), carried and updated in place; ``conv_op(lp, h, tails, c) ->
+    (y, tails)`` and ``attn_op(lp, h, k, v, a) -> (y, k, v)`` are the
+    caller's (prefill's or decode's), ``c`` / ``a`` the layer's index
+    among the convolution / attention layers. Returns ``(x, pools,
+    moe_stats)``."""
+    kinds = cfg.layer_kinds
+    lead, period, repeats = kinds_pattern(kinds)
+
+    def tally(span) -> Dict[str, int]:
+        # how many layers of each kind, attention layers ("attn") and
+        # convolution layers ("conv") ``span`` holds
+        r = {k: span.count(k) for k in set(kinds)}
+        r["attn"] = sum(k.startswith("attn+") for k in span)
+        r["conv"] = sum(k.startswith("conv+") for k in span)
+        return r
+
+    def body(kind: str):
+        op, ffn = kind.split("+")
+        stack = params["stacks"][kind]
+        small, experts = _split_experts(stack) if ffn == "moe" \
+            else (stack, None)
+
+        def layer(carry, s, a, c):
+            """Layer ``s`` of this kind's stack, the ``a``-th attention
+            or ``c``-th convolution layer of the model."""
+            x, kp, vp, tails, stats = carry
+            lp = jax.tree_util.tree_map(
+                lambda w: jax.lax.dynamic_index_in_dim(
+                    w, s, axis=0, keepdims=False), small)
+            h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+            if op == "conv":
+                y, tails = conv_op(lp, h, tails, c)
+            else:
+                y, kp, vp = attn_op(lp, h, kp, vp, a)
+            x = x + y
+            h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
+            if ffn == "moe":
+                m, st = _dropless_moe_mlp(cfg, lp, experts, s, h,
+                                          valid=valid, plan=plan)
+                stats = stats + st
+            else:
+                m = (jax.nn.silu(h @ lp["gate_proj"])
+                     * (h @ lp["up_proj"])) @ lp["down_proj"]
+            return (x + m, kp, vp, tails, stats)
+        return layer
+
+    def walk(carry, span, at: Dict[str, int], step, r):
+        """The layers ``span`` (kinds), the first of them at ranks
+        ``at``; in the scanned period every rank moves on by ``step`` a
+        repeat and ``r`` is the (traced) repeat."""
+        at = dict(at)
+        for kind, count in _runs(span):
+            op = kind.split("+")[0]
+            s0, a0, c0 = (at[k] + step.get(k, 0) * r
+                          for k in (kind, "attn", "conv"))
+            layer = body(kind)
+            if count == 1:
+                carry = layer(carry, s0, a0, c0)
+            else:
+                carry, _ = jax.lax.scan(
+                    lambda cr, j, layer=layer, s0=s0, a0=a0, c0=c0:
+                    (layer(cr, s0 + j, a0 + j, c0 + j), None),
+                    carry, jnp.arange(count, dtype=jnp.int32))
+            at[kind] += count
+            at[op] += count
+        return carry
+
+    carry = (x,) + tuple(pools) + (
+        jnp.zeros(moe_stats_shape(cfg), jnp.int32),)
+    zero = jnp.zeros((), jnp.int32)
+    carry = walk(carry, kinds[:lead], tally(()), {}, zero)
+    if repeats:
+        span = kinds[lead:lead + period]
+        carry, _ = jax.lax.scan(
+            lambda cr, r: (walk(cr, span, tally(kinds[:lead]), tally(span),
+                                r), None),
+            carry, jnp.arange(repeats, dtype=jnp.int32))
+    done = lead + period * repeats
+    carry = walk(carry, kinds[done:], tally(kinds[:done]), {}, zero)
+    return carry[0], carry[1:4], carry[4]
+
+
+def _conv_mix(cfg: ModelConfig, lp, h: jnp.ndarray, tail: jnp.ndarray):
+    """The gated short convolution over h [B, T, D] with the K - 1 gated
+    inputs before it, ``tail`` [B, K-1, D] (zeros at a sequence's
+    start): ``[b, c, u] = h W_in``; ``z = b * u``; ``y_t = (c_t * sum_j
+    w_j z_{t-K+1+j}) W_out``. Returns ``(y, zz)``, ``zz`` [B, K-1+T, D]
+    the tail and the window's own z one after the other: the state
+    after position i of the window is ``zz[:, i+1:i+K]``."""
+    K, T = cfg.conv_kernel, h.shape[1]
+    b, c, u = jnp.split(h @ lp["conv_in"], 3, axis=-1)
+    zz = jnp.concatenate([tail.astype(h.dtype), b * u], axis=1)
+    w = lp["conv_w"].astype(jnp.float32)                       # [K, D]
+    acc = sum(w[j] * zz[:, j:j + T].astype(jnp.float32) for j in range(K))
+    y = (c.astype(jnp.float32) * acc).astype(h.dtype) @ lp["conv_out"]
+    return y, zz
+
+
+def _tails_read(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
+                positions: jnp.ndarray, ps: int) -> jnp.ndarray:
+    """[B, K-1, D]: convolution layer ``c``'s state before ``positions``
+    [B]: the row of the page that holds the position before, zeros at
+    position 0."""
+    before = jnp.maximum(positions - 1, 0)
+    pid = jnp.take_along_axis(page_table, (before // ps)[:, None],
+                              axis=1)[:, 0]
+    rows = tails[c, pid].reshape(-1, cfg.conv_kernel - 1, cfg.hidden_size)
+    return jnp.where((positions > 0)[:, None, None], rows,
+                     jnp.zeros((), rows.dtype))
+
+
+def _tails_write(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
+                 start: jnp.ndarray, lengths: jnp.ndarray,
+                 zz: jnp.ndarray, ps: int) -> jnp.ndarray:
+    """Write convolution layer ``c``'s rows of every page that the
+    windows [start, start + lengths) touch: page p's row is the state
+    after the LAST of the window's positions that lies in p. ``zz``
+    [B, K-1+T, D] is ``_conv_mix``'s (the state after window position i
+    is ``zz[:, i+1:i+K]``); a row of length 0 (padding, an inactive
+    lane) writes nothing."""
+    K = cfg.conv_kernel
+    B, T = zz.shape[0], zz.shape[1] - (K - 1)
+    P, MP = tails.shape[1], page_table.shape[1]
+    # a window of T positions touches at most this many pages
+    j = jnp.arange(-(-T // ps) + 1 if T > 1 else 1, dtype=jnp.int32)
+    last = start + lengths - 1                                   # [B]
+    page = start[:, None] // ps + j[None, :]                     # [B, J]
+    touched = (lengths[:, None] > 0) & (page * ps <= last[:, None])
+    # the window position (0-based) of the page's last written token
+    end = jnp.minimum(page * ps + ps - 1, last[:, None]) - start[:, None]
+    at = jnp.clip(end, 0, T - 1)[:, :, None] + 1 \
+        + jnp.arange(K - 1, dtype=jnp.int32)[None, None, :]      # [B,J,K-1]
+    rows = jax.vmap(lambda z, i: z[i])(zz, at)                   # [B,J,K-1,D]
+    pid = jnp.take_along_axis(page_table, jnp.minimum(page, MP - 1),
+                              axis=1)
+    pid = jnp.where(touched, pid, P)            # past the pool: dropped
+    return tails.at[c, pid.reshape(-1)].set(
+        rows.reshape(pid.size, -1), mode="drop")
+
+
+def _kv_pack(cfg: ModelConfig) -> int:
+    """Key-value heads that share one row of the pools. A TPU tiles an
+    array's last axis in 128 lanes: a pool of 8 heads of 64 is stored
+    as 8 of 128, half of them padding (3.96 GB for the cell's 1.98, and
+    every page read twice over: compiled for a described v5e, PERF.md,
+    PR 38). So heads narrower than 128 are packed side by side, as many
+    as fit and divide the heads: [.., 8, 64] is kept as [.., 4, 128],
+    the same bytes in the same order, and every writer and attention
+    kernel sees 4 heads of 128 (``_packed_qkv``)."""
+    return math.gcd(cfg.num_kv_heads, max(128 // cfg.head_dim, 1))
+
+
+def _packed_qkv(cfg: ModelConfig, q, k, v):
+    """q [.., Hq, Dh], k / v [.., Hkv, Dh] as attention over the packed
+    pools takes them: k and v reshaped to [.., Hkv / p, p * Dh], and
+    each query widened to p * Dh with its own values in the slot of ITS
+    key-value head and zeros in the others, so that its product with a
+    packed key row is exactly its product with its own head's key.
+    Returns ``(q, k, v, unpack)``; ``unpack`` takes the attention's
+    output [.., Hq, p * Dh] back to [.., Hq, Dh] (the slot of the
+    head's own values; the other slots hold its probabilities over a
+    neighbour's values and are dropped)."""
+    p = _kv_pack(cfg)
+    if p == 1:
+        return q, k, v, lambda o: o
+    Hq, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    slot = (jnp.arange(Hq) // (Hq // Hkv)) % p                  # [Hq]
+    mask = jax.nn.one_hot(slot, p, dtype=q.dtype)               # [Hq, p]
+    q = (q[..., None, :] * mask[:, :, None]).reshape(
+        q.shape[:-1] + (p * Dh,))
+    k = k.reshape(k.shape[:-2] + (Hkv // p, p * Dh))
+    v = v.reshape(v.shape[:-2] + (Hkv // p, p * Dh))
+
+    def unpack(o):
+        o = o.reshape(o.shape[:-1] + (p, Dh))
+        return jnp.take_along_axis(
+            o, slot.reshape((1,) * (o.ndim - 3) + (Hq, 1, 1)),
+            axis=-2)[..., 0, :]
+    return q, k, v, unpack
+
+
+def _kinds_head(params: Params, cfg: ModelConfig, x: jnp.ndarray):
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    head = params.get("lm_head")
+    return x, (params["embed"].T if head is None else head)
+
+
+def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
+                           tokens: jnp.ndarray, start_pos: jnp.ndarray,
+                           lengths: jnp.ndarray, kv: KVCache,
+                           page_table: jnp.ndarray,
+                           return_all_logits: bool = False,
+                           prompt_lp_targets: Optional[jnp.ndarray] = None,
+                           return_stats: bool = False,
+                           plan: KernelPlan = KernelPlan()):
+    B, T = tokens.shape
+    ps = kv[0].shape[2]
+    x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
+    positions = start_pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    kv_lengths = start_pos + lengths
+    tok_valid = (jnp.arange(T, dtype=jnp.int32)[None, :]
+                 < lengths[:, None])                             # [B, T]
+    scale = cfg.head_dim ** -0.5        # of the head, not the packed row
+
+    def conv_op(lp, h, tails, c):
+        y, zz = _conv_mix(cfg, lp, h, _tails_read(
+            cfg, tails, c, page_table, start_pos, ps))
+        return y, _tails_write(cfg, tails, c, page_table, start_pos,
+                               lengths, zz, ps)
+
+    def attn_op(lp, h, kp, vp, a):
+        q, k, v = _qkv(lp, cfg, h)
+        q = rope_for(cfg.rope_scaling, q, positions, cfg.rope_theta)
+        k = rope_for(cfg.rope_scaling, k, positions, cfg.rope_theta)
+        q, k, v, unpack = _packed_qkv(cfg, q, k, v)
+        # The window's keys and values into the pool first, then
+        # attention reads cached prefix and window alike from the pool.
+        kp, vp = write_prefill_kv_layer(kp, vp, k, v, page_table,
+                                        start_pos, lengths, a, plan)
+        if plan.prefill_attn and T % ps == 0:
+            from xllm_service_tpu.ops.pallas import (
+                paged_prefill_attention_pallas)
+            attn = paged_prefill_attention_pallas(
+                q, None, None, kp, vp, page_table, start_pos, lengths,
+                scale=scale, layer=a, from_pool=True,
+                interpret=plan.interpret)
+        else:
+            attn = mha_prefill_auto(
+                q, gather_pages(jax.lax.dynamic_index_in_dim(
+                    kp, a, axis=0, keepdims=False), page_table),
+                gather_pages(jax.lax.dynamic_index_in_dim(
+                    vp, a, axis=0, keepdims=False), page_table),
+                kv_lengths, start_pos, scale=scale)
+        return unpack(attn).reshape(B, T, -1) @ lp["o_proj"], kp, vp
+
+    x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
+                                     tok_valid, plan)
+    x, head = _kinds_head(params, cfg, x)
+    last_idx = jnp.maximum(lengths - 1, 0)
+    last_x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
+    outs = [_head_logits(cfg, last_x, head),
+            _head_logits(cfg, x, head) if return_all_logits else None, kv]
+    if prompt_lp_targets is not None:
+        outs.append(_prompt_logprobs(x, head, prompt_lp_targets))
+    if return_stats:
+        outs.append(_moe_stats_dict(moe_stats))
+    return tuple(outs)
+
+
+def _kinds_forward_decode(params: Params, cfg: ModelConfig,
+                          tokens: jnp.ndarray, positions: jnp.ndarray,
+                          active: jnp.ndarray, kv: KVCache,
+                          page_table: jnp.ndarray,
+                          return_stats: bool = False,
+                          plan: KernelPlan = KernelPlan()):
+    B = tokens.shape[0]
+    ps = kv[0].shape[2]
+    x = params["embed"][tokens[:, None]].astype(jnp.dtype(cfg.dtype))
+    pos2 = positions[:, None]
+    one = active.astype(jnp.int32)          # an inactive lane: length 0
+    scale = cfg.head_dim ** -0.5        # of the head, not the packed row
+
+    def conv_op(lp, h, tails, c):
+        y, zz = _conv_mix(cfg, lp, h, _tails_read(
+            cfg, tails, c, page_table, positions, ps))
+        return y, _tails_write(cfg, tails, c, page_table, positions, one,
+                               zz, ps)
+
+    def attn_op(lp, h, kp, vp, a):
+        q, k, v = _qkv(lp, cfg, h)
+        q = rope_for(cfg.rope_scaling, q, pos2, cfg.rope_theta)
+        k = rope_for(cfg.rope_scaling, k, pos2, cfg.rope_theta)
+        q, k, v, unpack = _packed_qkv(cfg, q, k, v)
+        kp, vp = write_decode_kv_layer(kp, vp, k[:, 0], v[:, 0],
+                                       page_table, positions, active, a,
+                                       plan)
+        attn = paged_decode_attention_auto(
+            q[:, 0], kp, vp, page_table,
+            jnp.where(active, positions + 1, 0), plan, scale=scale,
+            layer=a)
+        return unpack(attn).reshape(B, 1, -1) @ lp["o_proj"], kp, vp
+
+    x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
+                                     active[:, None], plan)
+    x, head = _kinds_head(params, cfg, x)
+    logits = _head_logits(cfg, x[:, 0], head)
+    if return_stats:
+        return logits, kv, _moe_stats_dict(moe_stats)
+    return logits, kv

@@ -74,6 +74,10 @@ STEP_FIELDS: Tuple[str, ...] = (
                         # load_max_over_mean (busiest expert's rows over
                         # the mean of the touched ones)}; None where no
                         # layer counts its routing
+    "state_restored",   # per row ADMITTED in this step, 1 where its first
+                        # computed position read a cached page's
+                        # convolution tails (a prefix hit), else 0; None
+                        # for a model whose cached state is pages alone
 )
 
 # ---------------------------------------------------------------------------

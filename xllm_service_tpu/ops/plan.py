@@ -109,10 +109,17 @@ class KernelPlan:
         mixed = _on_off(environ.get("XLLM_RAGGED_ATTN", ""))
         if mixed is None:
             mixed = engine_cfg.ragged_attn
+        # A model whose layers differ in kind carries its pools through
+        # the layer loop and writes before it attends, always
+        # (models/transformer.py, "Layers that differ in kind").
+        kinds = model_cfg.layer_kinds is not None
         return cls(
             decode_attn=base,
-            # Opt-in until a chip run has checked it; needs the base
-            # gate (no interpreter fallback on the serving path).
+            # Opt-in; needs the base gate (no interpreter fallback on
+            # the serving path). Checked on the chip for a model whose
+            # layers differ in kind (PERF.md, PR 38: correct, a
+            # follow-up window 17.6 ms against 28.9) and left opt-in
+            # there too: the cell it speeds up spreads twice as widely.
             prefill_attn=base
             and environ.get("XLLM_PALLAS_PREFILL", "0") == "1",
             # Decided on the chip (PERF.md, PR 36: batch 32, table width
@@ -122,15 +129,15 @@ class KernelPlan:
             # kernels (ops/pallas/latent.py) are a write-then-attend
             # pair: without it a latent model decodes on the reference.
             latent_decode=base and model_cfg.mla and bool(wta),
-            # Chosen on the chip over ragged_dot (PERF.md, PR 36). Only
-            # the latent family's sparse layers call it, so only their
-            # plan says so.
-            expert_gmm=base and model_cfg.mla and model_cfg.is_moe,
+            # Chosen on the chip over ragged_dot (PERF.md, PR 36); the
+            # plan of every model whose sparse layers are the dropless
+            # one says so, and no other's (ModelConfig.dropless_experts).
+            expert_gmm=base and model_cfg.dropless_experts,
             kv_writers=writers and mesh is None,
-            # No ragged kernel for absorbed-MLA pools: they keep the
-            # split path.
-            mixed_step=bool(mixed) and not model_cfg.mla,
-            write_then_attend=bool(wta),
+            # No ragged kernel for absorbed-MLA pools, no ragged rows in
+            # the loop over layer kinds: they keep the split path.
+            mixed_step=bool(mixed) and not model_cfg.mla and not kinds,
+            write_then_attend=bool(wta) or kinds,
             # A window start is a sum of earlier bucket sizes.
             page_aligned=all(b % engine_cfg.page_size == 0
                              for b in engine_cfg.prefill_buckets),

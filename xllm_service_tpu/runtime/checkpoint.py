@@ -152,6 +152,8 @@ def load_checkpoint(model_dir: str, cfg: ModelConfig,
         r = _PrefixRemap(r, "model.", "model.language_model.")
     if cfg.mla:
         return _load_mla_checkpoint(r, cfg, dtype, mesh)
+    if cfg.layer_kinds is not None:
+        return _load_kinds_checkpoint(r, cfg, dtype)
 
     def stack(fmt: str, transpose: bool = False) -> np.ndarray:
         rows: List[np.ndarray] = []
@@ -457,6 +459,71 @@ def _load_mla_checkpoint(r, cfg: ModelConfig, dtype, mesh):
     return jax.tree_util.tree_map(jax.device_put, params)
 
 
+def _load_kinds_checkpoint(r, cfg: ModelConfig, dtype):
+    """LFM2-MoE tree: one stack per KIND of layer, in layer order
+    (models/transformer.py ``_init_kinds_params``), from the published
+    names: ``operator_norm`` / ``ffn_norm``; ``conv.{in_proj, conv,
+    out_proj}`` (the depthwise filter [D, 1, K] becomes [K, D]: tap j
+    weighs the gated input K - 1 - j positions back);
+    ``self_attn.{q, k, v, out}_proj`` with ``q_layernorm`` /
+    ``k_layernorm``; ``feed_forward.{w1, w3, w2}`` (gate, up, down), or
+    ``feed_forward.gate`` + ``expert_bias`` (zeros without
+    ``use_expert_bias``) + ``experts.E.{w1, w3, w2}``; ``embedding_norm``
+    after the last layer."""
+    def t(name):
+        return np.ascontiguousarray(r.get(name).T)
+
+    stacks: Dict[str, Any] = {}
+    for kind in sorted(set(cfg.layer_kinds)):
+        op, ffn = kind.split("+")
+        idxs = [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
+
+        def stack(fmt, f=r.get, to=dtype):
+            return np.stack([f(fmt.format(i=i)) for i in idxs]).astype(to)
+
+        L = "model.layers.{i}."
+        st = {"input_norm": stack(L + "operator_norm.weight"),
+              "post_norm": stack(L + "ffn_norm.weight")}
+        if op == "conv":
+            st["conv_in"] = stack(L + "conv.in_proj.weight", t)
+            st["conv_w"] = stack(L + "conv.conv.weight",
+                                 lambda n: r.get(n)[:, 0, :].T)
+            st["conv_out"] = stack(L + "conv.out_proj.weight", t)
+        else:
+            A = L + "self_attn."
+            for ours, hf in (("q_proj", "q_proj"), ("k_proj", "k_proj"),
+                             ("v_proj", "v_proj"), ("o_proj", "out_proj")):
+                st[ours] = stack(A + hf + ".weight", t)
+            st["q_norm"] = stack(A + "q_layernorm.weight")
+            st["k_norm"] = stack(A + "k_layernorm.weight")
+        F = L + "feed_forward."
+        names = (("gate_proj", "w1"), ("up_proj", "w3"), ("down_proj", "w2"))
+        if ffn == "moe":
+            st["router"] = stack(F + "gate.weight", t)
+            st["router_bias"] = stack(
+                F + "expert_bias",
+                lambda n: r.get(n) if n in r
+                else np.zeros((cfg.num_experts,), np.float32), np.float32)
+            for ours, hf in names:
+                st[ours] = np.stack([np.stack([
+                    t(F.format(i=i) + f"experts.{e}.{hf}.weight")
+                    for e in range(cfg.num_experts)])
+                    for i in idxs]).astype(dtype)
+        else:
+            for ours, hf in names:
+                st[ours] = stack(F + hf + ".weight", t)
+        stacks[kind] = st
+    params: Dict[str, Any] = {
+        "embed": r.get("model.embed_tokens.weight").astype(dtype),
+        "stacks": stacks,
+        "final_norm": r.get("model.embedding_norm.weight").astype(dtype)}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = (t("lm_head.weight") if "lm_head.weight" in r
+                             else params["embed"].T).astype(dtype)
+    r.close()
+    return jax.tree_util.tree_map(jax.device_put, params)
+
+
 def _visual_reader(model_dir: str, depth: int, dtype):
     """Shared scaffolding for both vision-tower loaders: open the shard
     reader, resolve the visual key prefix (published "visual." vs module
@@ -602,11 +669,11 @@ def save_checkpoint(params: Dict[str, Any], cfg: ModelConfig,
     weights)."""
     from safetensors.numpy import save_file
 
-    if cfg.mla or cfg.gptoss:
+    if cfg.mla or cfg.gptoss or cfg.layer_kinds is not None:
         raise NotImplementedError(
             "save_checkpoint for MLA/GPT-OSS trees is not implemented — "
             "the absorbed kv_b / interleaved gate_up splits are one-way "
-            "for now")
+            "for now; nor for the per-kind stacks of a layer_kinds model")
 
     os.makedirs(model_dir, exist_ok=True)
     get = lambda x: np.asarray(jax.device_get(x))  # noqa: E731

@@ -188,6 +188,25 @@ class Engine:
         self.ecfg = engine_cfg
         self.mesh = mesh
         dtype = jnp.dtype(model_cfg.dtype)
+        # Is a sequence's cached state its (k, v) pages and nothing else?
+        # Not where convolution layers keep a tail beside each page
+        # (transformer.init_kv_cache's third pool): the wire, the host
+        # tier and a peer's import carry (k, v) blocks, and a page that
+        # arrived without its tail must never be resumed from. So what
+        # moves pages OUT of or INTO the pools is refused for such a
+        # model (ROADMAP.md Reach A1), here, once, and at each door.
+        self.pages_only = model_cfg.num_conv_layers == 0
+        if not self.pages_only:
+            if mesh is not None:
+                raise ValueError(
+                    "a model with convolution layers runs on one device: "
+                    "its per-kind weight stacks and its pool of tails have "
+                    "no sharding rules (parallel/sharding.py)")
+            logger.info(
+                "%s keeps a convolution tail beside each page: PD "
+                "migration, host spill and cross-worker block fetch are "
+                "refused for it (pages move with (k, v) alone)",
+                model_cfg.name)
 
         # Weights and pools are BORN where they live: made under a jit
         # whose out_shardings is their final placement, each device makes
@@ -269,7 +288,8 @@ class Engine:
         # scatter. Off (None) unless kv_spill_mb > 0.
         self.host_tier: Optional[HostKvTier] = None
         spill_bytes = int(engine_cfg.kv_spill_mb * 1e6)
-        if spill_bytes > 0 and engine_cfg.enable_prefix_cache:
+        if spill_bytes > 0 and engine_cfg.enable_prefix_cache \
+                and self.pages_only:
             self.host_tier = HostKvTier(
                 spill_bytes, disk_dir=engine_cfg.kv_spill_dir,
                 disk_capacity_bytes=int(engine_cfg.kv_spill_disk_mb * 1e6))
@@ -318,8 +338,12 @@ class Engine:
             (model_cfg.sliding_window or 0)
             if self.plan.decode_attn and model_cfg.layer_sliding is None
             and not model_cfg.mla else 0)
-        logger.info("engine plan: %s; decode walk %d of %d columns",
-                    self.plan, self._decode_walk(MP), MP)
+        kinds = model_cfg.layer_kinds or ()
+        logger.info("engine plan: %s; decode walk %d of %d columns%s",
+                    self.plan, self._decode_walk(MP), MP,
+                    "; layer kinds " + ", ".join(
+                        f"{k} {kinds.count(k)}"
+                        for k in dict.fromkeys(kinds)) if kinds else "")
         if self.plan.uses_kernels:
             # The kernels are loaded here (1.2-1.6 s of
             # jax.experimental.pallas), where an engine is built, and
@@ -349,11 +373,12 @@ class Engine:
         self._pending: Optional[Dict[str, Any]] = None
         # Do the rows of one step see each other? Only through a sparse
         # layer that buckets by capacity (``transformer._mlp``'s
-        # ``moe_mlp``; the latent family's dropless layer computes what
-        # each row chose): there a row that has left still competes for
+        # ``moe_mlp``; the dropless layer computes what each row
+        # chose): there a row that has left still competes for
         # an expert's capacity in a step launched ahead, so such a step
         # is taken whole or not at all.
-        self._rows_interfere = (model_cfg.is_moe and not model_cfg.mla
+        self._rows_interfere = (model_cfg.is_moe
+                                and not model_cfg.dropless_experts
                                 and model_cfg.moe_capacity_factor > 0)
         # Device-idle attribution: when the previous decode burst's
         # outputs became ready, and whether a speculative burst was
@@ -437,6 +462,16 @@ class Engine:
         self.prefix_lookups = 0
         self.prefix_hit_tokens = 0
         self.fetched_blocks = 0
+        # The convolution tails' ledger (xllm_worker_state_rows_total;
+        # nothing moves for a model without them), counted on the host
+        # from page spans, no device read: admissions whose first
+        # computed position read a CACHED page's tails, and pages whose
+        # row a prefill window wrote (one row a convolution layer each).
+        # ``last_step_state_restored``: 0/1 per row admitted in the
+        # last iteration (the step record's ``state_restored``).
+        self.state_rows_restored = 0
+        self.state_rows_written = 0
+        self.last_step_state_restored: List[int] = []
 
         # Device-plane fault containment (docs/ROBUSTNESS.md): the
         # worker's step fault boundary reads ``step_members`` (the
@@ -868,6 +903,11 @@ class Engine:
         if seq.req.mm_embeds is None and not seq.req.prompt_logprobs:
             self.prefix_lookups += 1
             self.prefix_hit_tokens += cached_tokens
+        if not self.pages_only:
+            # Its first window reads the tails that whoever wrote the
+            # last cached page left in that page's row.
+            self.state_rows_restored += cached_tokens > 0
+            self.last_step_state_restored.append(int(cached_tokens > 0))
         if not seq.admitted_once:
             seq.admitted_once = True
             self.queue_waits_ms.append(
@@ -1088,6 +1128,7 @@ class Engine:
         self.last_step_ragged = False
         self.last_step_attn_dispatches = 0
         self.last_step_compiled = []
+        self.last_step_state_restored = []
         if self.cfg.is_moe:
             self.last_step_moe = dict.fromkeys(MOE_STATS, 0)
         if self.interleave:
@@ -1499,6 +1540,12 @@ class Engine:
                 packed[i, 0] = seq.num_computed
                 packed[i, 1] = len(new)
                 packed[i, _PREFILL_HDR:_PREFILL_HDR + len(new)] = new
+                if not self.pages_only and new:
+                    # pages whose row of tails this window writes
+                    ps = self.ecfg.page_size
+                    self.state_rows_written += (
+                        (seq.num_computed + len(new) - 1) // ps
+                        - seq.num_computed // ps + 1)
                 packed[i, _PREFILL_HDR + T:
                        _PREFILL_HDR + T + len(seq.pages)] = seq.pages
             st_f32, st_i32 = self._sampling_tensors(
@@ -2474,6 +2521,11 @@ class Engine:
         seq = self._held.pop(request_id, None)
         if seq is None:
             return None
+        if not self.pages_only:
+            # Refused (``pages_only``): the pages go back, nothing leaves.
+            self.prefix_cache.release_pages(seq.pages)
+            seq.pages = []
+            return None
         self.drain_pipeline()
         k, v = self._pages_out(jnp.asarray(seq.pages, jnp.int32))
         if not device:
@@ -2496,7 +2548,10 @@ class Engine:
         ``tokens`` = prompt + first generated token; ``k``/``v`` hold KV for
         ``tokens[:-1]``. Returns False (clean refusal → caller falls back)
         when no slot/pages are free or the payload doesn't match this
-        engine's KV layout."""
+        engine's KV layout, or this model's pages do not move
+        (``pages_only``)."""
+        if not self.pages_only:
+            return False
         self.drain_pipeline()
         n_pages_needed = self._pages_needed(len(tokens))
         k_pages = self.kv[0]
@@ -2671,6 +2726,8 @@ class Engine:
         arrays; re-uploading them to stage a pull would be wasted
         motion). The gathered block is a fresh buffer, so the acquired
         pages are released immediately (export_held's argument)."""
+        if not self.pages_only:
+            return None
         pages = self.prefix_cache.pages_for_hashes(hashes)
         n_hbm = len(pages)
         k_hbm = v_hbm = None
@@ -2724,6 +2781,8 @@ class Engine:
         requesting prompt's admit hits them like any local prefix.
         Returns the number of blocks adopted (0 = clean refusal — the
         caller prefills from token zero, correctness unaffected)."""
+        if not self.pages_only:
+            return 0
         self.drain_pipeline()
         k_pages = self.kv[0]
         n = int(k.shape[1]) if hasattr(k, "shape") else 0
@@ -2791,6 +2850,15 @@ class Engine:
             "spilled_pages": tier.spilled_blocks if tier else 0,
             "restored_pages": tier.restored_blocks if tier else 0,
         }
+
+    def state_stats(self) -> Optional[Dict[str, int]]:
+        """The xllm_worker_state_* series source; None for a model whose
+        cached state is its pages alone."""
+        if self.pages_only:
+            return None
+        return {"restored": self.state_rows_restored,
+                "written": self.state_rows_written,
+                "pool_bytes": int(self.kv[2].nbytes)}
 
     # ------------------------------------------------------------------
     # Warmup / metrics

@@ -525,6 +525,15 @@ class Worker:
             self.opts.instance_type = InstanceType.ENCODE
         self.runtimes: Dict[str, ModelRuntime] = {}
         primary_cfg = resolve_model_config(opts.model, opts.model_dir)
+        if primary_cfg.num_conv_layers and self.instance_type in (
+                InstanceType.PREFILL, InstanceType.DECODE):
+            # Its pages do not move between workers (Engine.pages_only):
+            # a disaggregated role could only ever fall back.
+            raise ValueError(
+                f"{opts.model} has convolution layers, whose tails ride "
+                f"the page table: instance type "
+                f"{self.instance_type.value} (PD migration) is refused; "
+                f"serve it as DEFAULT or MIX")
         # Encode-only mode: the LM runtime starts asleep — engine=None,
         # no params, no KV pool. Every heartbeat/metrics/registration
         # path already handles an asleep runtime; the vision tower
@@ -1593,12 +1602,16 @@ class Worker:
             pages_delta=pages_delta,
             cache_hit_tokens=hit_delta,
             compiled=tuple(eng.last_step_compiled),
-            moe=_moe_record(eng.last_step_moe))
+            moe=_moe_record(eng.last_step_moe),
+            state_restored=(None if eng.pages_only
+                            else tuple(eng.last_step_state_restored)))
 
     def _flush_moe(self, rt: ModelRuntime) -> None:
         """What the sparse layers counted on the device (``Engine.
-        moe_stats``: the latent family's dropless layer; all zeros where
-        a family's layer still buckets and counts its drops alone)."""
+        moe_stats``: the dropless layer's, whichever family runs it; all
+        zeros where a family's layer still buckets and counts its drops
+        alone). And the convolution tails' ledger, where the model has
+        them (``Engine.state_stats``)."""
         st, m = rt.engine.moe_stats, rt.model
         for name, key, text in (
                 ("xllm_worker_moe_assignments_total", "assignments",
@@ -1613,6 +1626,22 @@ class Worker:
                  "(requested - computed; 0 under the dropless layer)")):
             self.obs.counter(name, text, labelnames=("model",)).set_total(
                 st[key], model=m)
+        state = rt.engine.state_stats()
+        if state is not None:
+            c = self.obs.counter(
+                "xllm_worker_state_rows_total",
+                "convolution tails by event: restored = admissions whose "
+                "first computed position read a cached page's tails (a "
+                "prefix hit); written = pages whose row a prefill window "
+                "wrote (one row a convolution layer each)",
+                labelnames=("model", "event"))
+            c.set_total(state["restored"], model=m, event="restored")
+            c.set_total(state["written"], model=m, event="written")
+            self.obs.gauge(
+                "xllm_worker_state_pool_bytes",
+                "bytes of the pool of convolution tails (one row a page "
+                "a convolution layer)",
+                labelnames=("model",)).set(state["pool_bytes"], model=m)
 
     def _flush_overlap(self, rt: ModelRuntime) -> None:
         """Decode-pipeline overlap health: speculative-burst
