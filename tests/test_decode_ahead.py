@@ -1,9 +1,13 @@
 """The single-step decode launches step N+1 before it reads step N
-(PR 37, ``Engine._launch_ahead``): the streams are a sequential engine's,
-whatever falls on a step that was launched ahead; the counters say how
-often it engaged; nothing is launched while a request waits; and the
-launch is the decode program's one call signature."""
+(PR 37, ``Engine._launch_ahead``), and an iteration that could not
+dispatches its successor from host truth at its tail (PR 39,
+``Engine._tail_eligible``): the streams are a sequential engine's,
+whatever falls on a step that was on the device before its iteration
+began; the counters say how often each engaged; nothing is launched
+while a request waits; and either launch is the decode program's one
+call signature."""
 
+import collections
 import dataclasses
 import functools
 import json
@@ -24,15 +28,18 @@ def _tiny(window=None, **kw):
                                dtype="float32", sliding_window=window)
 
 
-def _engine(model=None, sequential=False, **kw):
+def _engine(model=None, sequential=False, ahead=True, **kw):
     d = dict(page_size=16, num_pages=32, max_model_len=128,
              max_batch_size=4, max_prefill_tokens=64,
              prefill_buckets=(8, 16, 32))
     d.update(kw)
     eng = Engine(model or _tiny(), EngineConfig(**d), seed=0)
-    if sequential:
-        # The control: the same engine with the predicate forced false.
+    if sequential or not ahead:
+        # The same engine with the predicate forced false ...
         eng._ahead_eligible = lambda *a: False
+    if sequential:
+        # ... and the control: with both forced false.
+        eng._tail_eligible = lambda *a: False
     return eng
 
 
@@ -51,15 +58,19 @@ def _req(rid, prompt, n, sampling="greedy", eos=None, offline=False,
 
 _COUNTS = {"launch": "decode.ahead_dispatch", "hit": "decode.ahead_hit",
            "discard": "decode.ahead_discard",
-           "dropped": "decode.ahead_dropped_rows"}
+           "dropped": "decode.ahead_dropped_rows",
+           "tail": "decode.tail_dispatch", "tail_hit": "decode.tail_hit",
+           "tail_discard": "decode.tail_discard",
+           "upload": "decode.upload", "resident": "decode.resident_hit"}
 
 
 def _drive(eng, feed, cancel=()):
     """Feed ``{step: [requests]}`` and cancel ``{step: rid}``; returns
     ``({rid: (tokens, logprobs, reason)}, [a record per step])``. A
     record holds the step's counter deltas, its kind, who finished, whose
-    table grew, who was trimmed, how many were preempted, and whether a
-    request waited when it began."""
+    table grew, who was trimmed, how many were preempted, whether a
+    request waited when it began and when it ended, and the members of
+    the step it left pending (None: nothing)."""
     cancel = dict(cancel)
     toks, lps, reasons, recs = {}, {}, {}, []
     pc, step = eng.phase_counts, 0
@@ -87,7 +98,9 @@ def _drive(eng, feed, cancel=()):
             grew=[s.req.request_id for s in eng.running
                   if len(s.pages) > pages.get(s.req.request_id, 1 << 30)],
             trimmed=[s.req.request_id for s in eng.running
-                     if s.num_trimmed > trim.get(s.req.request_id, 1 << 30)])
+                     if s.num_trimmed > trim.get(s.req.request_id, 1 << 30)],
+            left=eng._pending and [m[0] for m in eng._pending["members"]],
+            waits=bool(eng.waiting))
         recs.append(rec)
         assert step < 400, "engine did not drain"
     return {r: (toks[r], lps[r], reasons.get(r)) for r in toks}, recs
@@ -125,19 +138,37 @@ def _event_schedule(event, sampling, eos=None):
 
 
 @functools.lru_cache(maxsize=None)
-def _event_runs(event, sampling):
-    eos = None
-    if event == "eos":
-        # The token the control's stream reaches fifth or later, first.
-        opts, feed, cancel = _event_schedule("steady", sampling)
-        st = _drive(_engine(sequential=True, **opts), feed, cancel)[0]["b"][0]
-        eos = next(t for i, t in enumerate(st) if i >= 4 and t not in st[:i])
-    runs = []
-    for sequential in (False, True):
-        opts, feed, cancel = _event_schedule(event, sampling, eos)
-        runs.append(_drive(_engine(sequential=sequential, **opts), feed,
-                           cancel))
-    return runs
+def _eos_of(sampling):
+    """The token the control's stream of b reaches fifth or later, first."""
+    opts, feed, cancel = _event_schedule("steady", sampling)
+    st = _drive(_engine(sequential=True, **opts), feed, cancel)[0]["b"][0]
+    return next(t for i, t in enumerate(st) if i >= 4 and t not in st[:i])
+
+
+@functools.lru_cache(maxsize=None)
+def _event_run(event, sampling, mode):
+    """``(streams, records, counts)`` of one engine: ``both`` (as built),
+    ``tail`` (nothing is launched ahead, so every iteration that may ends
+    with its successor dispatched and ``event`` falls on such a step) or
+    ``sequential`` (the control: neither). ``counts``: the engine's
+    ``phase_counts`` and, as ``tail_preempted``, the preemptions made
+    inside a tail dispatch (the engine itself is not kept: dozens of live
+    ones, each with its executables, crash the CPU backend's loader)."""
+    eos = _eos_of(sampling) if event == "eos" else None
+    opts, feed, cancel = _event_schedule(event, sampling, eos)
+    eng = _engine(sequential=mode == "sequential", ahead=mode == "both",
+                  **opts)
+    real = eng._dispatch_decode
+
+    def dispatch(at_tail=False):
+        pre = eng.num_preemptions
+        step = real(at_tail=at_tail)
+        eng.phase_counts["tail_preempted"] += \
+            at_tail * (eng.num_preemptions - pre)
+        return step
+    eng._dispatch_decode = dispatch
+    return _drive(eng, feed, cancel) + (collections.Counter(
+        eng.phase_counts),)
 
 
 @pytest.mark.parametrize("sampling", ["greedy", "seeded"])
@@ -149,9 +180,10 @@ def test_streams_are_the_sequential_engines(event, sampling):
     engine with the predicate forced false, with ``event`` falling on a
     step that was launched ahead (greedy and seeded sampling: the key
     chain is the program's own, and a discard puts the key back)."""
-    (got, recs), (want, control) = _event_runs(event, sampling)
-    assert got == want
-    assert not any(r["launch"] or r["hit"] for r in control)
+    got, recs, pc = _event_run(event, sampling, "both")
+    want, control, _ = _event_run(event, sampling, "sequential")
+    assert got == want and not pc["tail_preempted"]
+    assert not any(r["launch"] or r["hit"] or r["tail"] for r in control)
     hits = [r for r in recs if r["hit"]]
     assert len(hits) >= 4
     by_step = {r["step"]: r for r in recs}
@@ -159,12 +191,15 @@ def test_streams_are_the_sequential_engines(event, sampling):
         r = by_step[6]      # the step in flight is taken, then the prefill
         assert r["hit"] and r["kind"] == "mixed" and not r["launch"]
         assert len(got["late"][0]) == 12
+        # ... behind which the next step goes out with the new row
+        assert r["tail"] and "late" in r["left"] and by_step[7]["tail_hit"]
     elif event == "max_tokens":
         # known a step ahead: nothing is launched behind the step that
         # ends b, and the next one is packed without it
         r = next(r for r in hits if r["fin"].get("b") == FinishReason.LENGTH)
         nxt = by_step[r["step"] + 1]
         assert not r["launch"] and not nxt["hit"] and nxt["launch"]
+        assert not r["tail"] and r["left"] is None and not nxt["tail_hit"]
         assert not any(r["dropped"] or r["discard"] for r in recs)
     elif event == "eos":
         r = next(r for r in hits if r["fin"].get("b") == FinishReason.STOP)
@@ -181,11 +216,138 @@ def test_streams_are_the_sequential_engines(event, sampling):
         assert all(v[2] == FinishReason.LENGTH for v in got.values())
     elif event == "page_growth":
         grown = [r for r in hits if r["grew"]]
-        # the step after a grown table is packed and uploaded again
+        # the step after a grown table is packed and uploaded again: by
+        # the iteration that found no page to launch ahead onto, at its
+        # tail, where the page is grown
         assert grown and not any(by_step[r["step"] + 1]["hit"]
                                  for r in grown)
+        assert all(r["tail"] and r["upload"] and not r["launch"]
+                   and by_step[r["step"] + 1]["tail_hit"] for r in grown)
     else:
         assert any(r["trimmed"] for r in hits)
+
+
+# ---------------------------------------------------------------------------
+# The tail dispatch alone: the same streams with it forced off
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("sampling", ["greedy", "seeded"])
+@pytest.mark.parametrize("event", [
+    "admit", "max_tokens", "eos", "cancel", "preempt", "page_growth",
+    "swa_trim"])
+def test_streams_are_those_of_the_tail_dispatch_forced_off(event, sampling):
+    """Token ids, logprobs and finish reasons are those of the same
+    engine with ``_tail_eligible`` forced false: the step dispatched at
+    the tail is the step the next iteration would have packed, from the
+    same host truth, with the same key."""
+    got, recs, pc = _event_run(event, sampling, "tail")
+    want, control, _ = _event_run(event, sampling, "sequential")
+    assert got == want
+    assert not any(r["tail"] or r["tail_hit"] for r in control)
+    assert not any(r["launch"] or r["hit"] for r in recs)
+    hits = [r for r in recs if r["tail_hit"]]
+    assert len(hits) >= 8
+    by_step = {r["step"]: r for r in recs}
+    # taken by the very next iteration, or (a cancel took the last row,
+    # or rows that share an expert) thrown away by it
+    for r in recs:
+        if r["tail"]:
+            nxt = by_step[r["step"] + 1]
+            assert r["left"] and nxt["pending"]
+            assert nxt["tail_hit"] + nxt["tail_discard"] == 1
+    # never after a finish, never while anything waits
+    assert not any(r["tail"] for r in recs if r["fin"] or r["waits"])
+    assert not any(r["tail_discard"] for r in recs)
+    assert pc["decode.upload"] + pc["decode.resident_hit"] == \
+        pc["decode.tail_hit"] + pc["decode.dispatch"]
+    if event == "admit":
+        r = by_step[6]      # the step in flight is taken, then the prefill
+        assert r["tail_hit"] and r["kind"] == "mixed"
+        # ... and the tail dispatch packs the new row
+        assert r["tail"] and r["upload"] and "late" in r["left"]
+    elif event == "max_tokens":
+        r = next(r for r in recs if r["fin"].get("b") == FinishReason.LENGTH)
+        assert r["left"] is None and not by_step[r["step"] + 1]["pending"]
+    elif event == "eos":
+        r = next(r for r in recs if r["fin"].get("b") == FinishReason.STOP)
+        assert r["left"] is None and not by_step[r["step"] + 1]["pending"]
+    elif event == "cancel":
+        # it lands between the tail dispatch and the iteration that
+        # takes it: b's row ran for nothing, a's stands
+        r = by_step[6]
+        assert by_step[5]["tail"] and "b" in by_step[5]["left"]
+        assert r["pending"] and r["tail_hit"] and r["dropped"] == 1
+        assert got["b"][2] == FinishReason.CANCELLED
+    elif event == "preempt":
+        # where growing a table needs a victim the dispatch is left to
+        # the head of the next iteration
+        assert sum(r["preempted"] for r in recs) >= 1
+        assert pc["tail_preempted"] == 0
+        assert all(v[2] == FinishReason.LENGTH for v in got.values())
+    elif event == "page_growth":
+        grown = [r for r in recs if r["grew"] and r["kind"] == "decode"]
+        assert grown and all(r["tail"] and r["upload"] for r in grown)
+        steady = [r for r in recs if r["tail"] and not r["grew"]
+                  and r["kind"] == "decode"]
+        assert steady and not any(r["upload"] for r in steady)
+    else:
+        assert any(r["trimmed"] for r in hits)
+
+
+def test_what_step_leaves_pending():
+    """``step()`` leaves the next decode on the device after a page
+    grown and after a prefill section; nothing after a finish, while
+    anything waits, in a burst engine and under ``interleave=False``."""
+    def left(eng, feed, cancel=()):
+        return [r["left"] for r in _drive(eng, feed, cancel)[1]]
+    a, b = _req("a", range(1, 7), 12), _req("b", range(2, 9), 3)
+    # a prefill section (1), a page grown for position 8 (2), b's last
+    # token (3), a finish behind it; 4 packs at its head
+    assert left(_engine(ahead=False, page_size=8), {1: [a, b]})[:4] == \
+        [["a", "b"], ["a", "b"], None, ["a"]]
+    # a chunked prompt keeps waiting between its windows
+    eng = _engine(ahead=False, max_prefill_tokens=16,
+                  prefill_buckets=(8, 16))
+    recs = _drive(eng, {1: [_req("a", range(1, 7), 30)],
+                        4: [_req("long", range(1, 41), 6)]})[1]
+    windows = [r for r in recs if r["kind"] == "mixed"]
+    assert len(windows) >= 3
+    assert [r["left"] for r in windows[:-1]] == [None] * (len(windows) - 1)
+    assert windows[-1]["left"] == ["a", "long"]
+    for opts in (dict(decode_steps=4), dict(interleave=False)):
+        eng = _engine(ahead=False, **opts)
+        _drive(eng, {1: [_req("a", range(1, 7), 12)],
+                     5: [_req("b", range(2, 9), 3)]})
+        assert not eng.phase_counts["decode.tail_dispatch"]
+
+
+def test_a_discarded_tail_dispatch_is_run_again_from_the_same_key():
+    """A drain between the tail dispatch and the iteration that would
+    take it (import, export, sleep, fault_reset, warm-up) discards it:
+    the key goes back, the page it grew stays with its row, and the
+    block it was given is uploaded again."""
+    def run(drain):
+        eng = _engine(ahead=False, page_size=8)
+        eng.add_request(_req("a", range(1, 7), 20, "seeded"))
+        eng.add_request(_req("b", range(2, 9), 20, "seeded"))
+        toks, step = {}, 0
+        while eng.has_work():
+            step += 1
+            if drain and step % 3 == 0:
+                key, pages = eng._pending["key_before"], [
+                    list(s.pages) for s in eng.running]
+                eng.drain_pipeline()
+                assert eng._rng_key is key and eng._decode_carry is None
+                assert [list(s.pages) for s in eng.running] == pages
+            for o in eng.step():
+                toks.setdefault(o.request_id, []).extend(o.new_token_ids)
+        return toks, eng.phase_counts
+    (plain, pc0), (drained, pc) = run(False), run(True)
+    assert plain == drained
+    assert pc0["decode.tail_discard"] == 0 and pc["decode.tail_discard"] >= 4
+    # a discarded dispatch had counted its block once already
+    assert pc["decode.upload"] + pc["decode.resident_hit"] == \
+        pc["decode.tail_hit"] + pc["decode.dispatch"] \
+        + pc["decode.tail_discard"]
 
 
 # ---------------------------------------------------------------------------
@@ -194,15 +356,18 @@ def test_streams_are_the_sequential_engines(event, sampling):
 def test_the_counters_equal_a_hand_count():
     """Two rows on pages of 16: a (6 prompt tokens, 9 to make) and b
     (7, 4 to make); b is cancelled before step 7. Step 1 prefills both
-    (each has 1 token). Step 2 packs the first decode and launches step
-    3 behind it; 3 takes it and launches 4; 4 takes it and launches
-    nothing (it makes b's fourth and last token: known a step ahead) and
-    ends b; 5 packs again (a alone) and launches 6; "late" (7, 30 to
-    make) arrives before 6, which takes its step, launches nothing and
-    prefills; 7 packs both and launches 8; late is cancelled before 8,
-    which takes a's row, drops late's, and launches nothing (late is
-    still active on the device); 9 packs a alone and takes a's ninth
-    token: nothing is launched, no row would be left."""
+    (each has 1 token) and dispatches the first decode at its tail.
+    Step 2 takes it and launches step 3 behind it; 3 takes that and
+    launches 4; 4 takes it and launches nothing (it makes b's fourth and
+    last token: known a step ahead), ends b and, after a finish,
+    dispatches nothing at its tail; 5 packs again (a alone) and launches
+    6; "late" (7, 30 to make) arrives before 6, which takes its step,
+    launches nothing, prefills, and packs both rows at its tail; 7 takes
+    that and launches 8; late is cancelled before 8, which takes a's
+    row, drops late's, launches nothing (late is still active on the
+    device) and, having finished a row, nothing at its tail; 9 packs a
+    alone and takes a's ninth token: nothing is launched, no row would
+    be left."""
     eng = _engine()
     _, recs = _drive(eng, {1: [_req("a", range(1, 7), 9),
                                _req("b", range(2, 9), 4)],
@@ -212,13 +377,23 @@ def test_the_counters_equal_a_hand_count():
         ["prefill"] + ["decode"] * 4 + ["mixed"] + ["decode"] * 3
     assert [r["launch"] for r in recs] == [0, 1, 1, 0, 1, 0, 1, 0, 0]
     assert [r["hit"] for r in recs] == [0, 0, 1, 1, 0, 1, 0, 1, 0]
+    assert [r["tail"] for r in recs] == [1, 0, 0, 0, 0, 1, 0, 0, 0]
+    assert [r["tail_hit"] for r in recs] == [0, 1, 0, 0, 0, 0, 1, 0, 0]
+    assert [r["left"] for r in recs] == [
+        ["a", "b"], ["a", "b"], ["a", "b"], None, ["a"], ["a", "late"],
+        ["a", "late"], None, None]
     assert [r["dropped"] for r in recs] == [0] * 7 + [1, 0]
-    assert not any(r["discard"] for r in recs)
+    assert not any(r["discard"] or r["tail_discard"] for r in recs)
     pc = eng.phase_counts
     assert pc["decode.ahead_dispatch"] == pc["decode.ahead_hit"] == 4
-    assert pc["decode.dispatch"] == pc["decode.pack"] == 4
+    assert pc["decode.tail_dispatch"] == pc["decode.tail_hit"] == 2
+    assert pc["decode.dispatch"] == 2 and pc["decode.pack"] == 4
+    # every pack uploaded (the batch had changed), every launch ahead
+    # was handed its block: uploads + resident hits = decode steps
+    assert [r["upload"] for r in recs] == [1, 0, 0, 0, 1, 1, 0, 0, 1]
     assert pc["decode.upload"] == 4 and pc["decode.resident_hit"] == 4
-    assert eng.overlap_metrics()["spec_hits"] == 4
+    assert eng.overlap_metrics()["spec_hits"] == 6
+    assert eng.overlap_metrics()["spec_dispatches"] == 6
     assert eng._pending is None and not eng.has_work()
 
 
@@ -304,8 +479,9 @@ def test_a_mixed_program_does_not_throw_the_step_in_flight_away():
 # ---------------------------------------------------------------------------
 def test_one_cache_entry_a_width_after_a_mixed_schedule():
     """A launch ahead gives the decode program exactly what a resident
-    hit gives it: after warm-up, a schedule of packs, uploads, resident
-    hits and launches ahead over three table widths adds no entry to the
+    hit gives it, a tail dispatch what a miss step gives it: after
+    warm-up, a schedule of packs, uploads, resident hits, launches ahead
+    and tail dispatches over three table widths adds no entry to the
     program's cache and counts no recompile."""
     eng = _engine(page_size=4, max_model_len=64)
     eng.warmup(prefill_shapes=[(2, 8, 2), (1, 8, 2)],
@@ -317,6 +493,7 @@ def test_one_cache_entry_a_width_after_a_mixed_schedule():
                            6: [_req("late", range(11, 17), 5, "seeded")]})
     pc = eng.phase_counts
     assert pc["decode.ahead_hit"] >= 5 and pc["decode.upload"] >= 5
+    assert pc["decode.tail_hit"] >= 4
     assert pc["decode.resident_hit"] >= pc["decode.ahead_hit"]
     assert eng.compile_report() == warm
     assert not [k for k, v in eng.phase_report().items()
@@ -326,8 +503,8 @@ def test_one_cache_entry_a_width_after_a_mixed_schedule():
 # ---------------------------------------------------------------------------
 # Every family: one rule, by what the engine can observe
 # ---------------------------------------------------------------------------
-def _family_streams(model, sequential, eos=None, **opts):
-    eng = _engine(model=model, sequential=sequential, **opts)
+def _family_streams(model, sequential, eos=None, ahead=True, **opts):
+    eng = _engine(model=model, sequential=sequential, ahead=ahead, **opts)
     got, recs = _drive(eng, {
         1: [_req("a", range(1, 9), 24), _req("b", range(2, 12), 24,
                                              eos=eos)],
@@ -372,6 +549,28 @@ def test_rows_that_share_an_experts_capacity_are_taken_whole():
     assert sum(r["discard"] for r in recs) == 2
 
 
+def test_every_family_dispatches_at_the_tail():
+    """The same two families with nothing launched ahead: the latent
+    pool's rows stand one by one behind a cancel that lands after the
+    tail dispatch; rows that share an expert's capacity are discarded
+    whole there and packed again."""
+    opts = dict(max_model_len=256, max_batch_size=4, max_prefill_tokens=256,
+                prefill_buckets=(32, 64, 128))
+    for model, opts, discards in ((tiny_model(), opts, 0),
+                                  (_tiny(num_experts=4), {}, 1)):
+        got, recs, eng = _family_streams(model, False, ahead=False, **opts)
+        want, _, _ = _family_streams(model, True, **opts)
+        assert eng._rows_interfere == bool(discards)
+        assert got == want
+        assert sum(r["tail_hit"] for r in recs) >= 15
+        cancel = recs[11]
+        assert cancel["pending"] and "late" in recs[10]["left"]
+        assert (cancel["tail_hit"], cancel["dropped"],
+                cancel["tail_discard"]) == (1 - discards, 1 - discards,
+                                            discards)
+        assert sum(r["tail_discard"] for r in recs) == discards
+
+
 def test_a_burst_engines_single_step_launches_nothing():
     """Where bursts are fused the single step is the fallback for a row
     near ``max_model_len``; its next decode may be a burst again, and
@@ -381,6 +580,7 @@ def test_a_burst_engines_single_step_launches_nothing():
     pc = eng.phase_counts
     assert pc["decode.dispatch"] >= 2 and pc["decode_multi.dispatch"] >= 1
     assert not pc["decode.ahead_dispatch"]
+    assert not pc["decode.tail_dispatch"]
     assert pc["decode_multi.spec_dispatch"] >= 1
 
 
@@ -395,6 +595,8 @@ def test_worker_exports_the_launches_beside_its_steps():
     from xllm_service_tpu.service.coordination import InMemoryStore
     assert "decode.ahead_dispatch" in steptrace.STEP_PHASES
     assert "xllm.step.decode.ahead_dispatch" in steptrace.SPAN_NAMES
+    assert "decode.tail_dispatch" in steptrace.STEP_PHASES
+    assert "xllm.step.decode.tail_dispatch" in steptrace.SPAN_NAMES
     w = Worker(WorkerOptions(model="tiny"), InMemoryStore()).start()
 
     def http(method, path, body=None):
@@ -421,14 +623,17 @@ def test_worker_exports_the_launches_beside_its_steps():
         return sum(float(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
                    if ln.startswith(name + "{") and where in ln)
     pc = w.primary_runtime().engine.phase_counts
-    assert pc["decode.ahead_hit"] >= 10
+    assert pc["decode.ahead_hit"] >= 10 and pc["decode.tail_hit"] >= 1
     for result, phase in (("launched", "decode.ahead_dispatch"),
                           ("hit", "decode.ahead_hit"),
-                          ("discarded", "decode.ahead_discard")):
+                          ("discarded", "decode.ahead_discard"),
+                          ("tail_launched", "decode.tail_dispatch"),
+                          ("tail_hit", "decode.tail_hit"),
+                          ("tail_discarded", "decode.tail_discard")):
         assert total("xllm_worker_decode_ahead_total",
                      f'result="{result}"') == pc[phase]
     assert total("xllm_worker_decode_ahead_dropped_rows_total") == \
         pc["decode.ahead_dropped_rows"]
-    assert total("xllm_worker_phase_calls_total",
-                 'phase="decode.ahead_dispatch"') == \
-        pc["decode.ahead_dispatch"]
+    for phase in ("decode.ahead_dispatch", "decode.tail_dispatch"):
+        assert total("xllm_worker_phase_calls_total",
+                     f'phase="{phase}"') == pc[phase]

@@ -205,7 +205,7 @@ def test_engine_phase_builds_no_annotation_while_off(counted):
                                 ignore_eos=True)))
     while eng.has_work():
         eng.step()
-    assert eng.phase_counts["decode.dispatch"] >= 1   # phases did run
+    assert eng.phase_counts["decode.tail_dispatch"] >= 1  # phases did run
     assert counted == []
     # the same engine, switch on: its phases are spans now, and the
     # dispatch carries the shape key the program was launched with
@@ -223,7 +223,8 @@ def test_engine_phase_builds_no_annotation_while_off(counted):
                  "xllm.step.prefill.host_copy", "xllm.step.decode.pack",
                  "xllm.step.decode.post", "xllm.kv.register_pages"):
         assert want in names, want
-    args = dict(counted)["xllm.step.decode.dispatch"]
+    # the step the prefill iteration dispatched at its tail (PR 39)
+    args = dict(counted)["xllm.step.decode.tail_dispatch"]
     # (no window in this model: the attention walks the whole table)
     assert args == {"program": "decode", "B": 2, "T": 1, "MP": args["MP"],
                     "walk": args["MP"]}
@@ -279,7 +280,7 @@ def test_endpoint_start_stop_and_its_refusals(worker, tmp_path):
     events = trace.load_events(trace.find_xplane(d))
     names = {e["name"] for e in spans.program_spans(events)}
     assert {"xllm.loop.step", "xllm.loop.emit", "xllm.loop.obs_flush",
-            "xllm.step.decode.dispatch", "xllm.admit"} <= names
+            "xllm.step.decode.tail_dispatch", "xllm.admit"} <= names
     steps = spans.program_spans(events, r"^xllm\.loop\.step$")
     inner = spans.program_spans(events, r"^xllm\.step\.")
     assert steps and all(any(

@@ -1183,7 +1183,7 @@ def _carry_scenario(sampling, window, drop_carry):
         trimmed = max([trimmed] + [s.num_trimmed for s in eng.running])
         if drop_carry:
             eng._decode_carry = None
-            eng._ahead_eligible = lambda *a: False
+            eng._ahead_eligible = eng._tail_eligible = lambda *a: False
         for out in eng.step():
             toks.setdefault(out.request_id, []).extend(out.new_token_ids)
             lps.setdefault(out.request_id, []).extend(out.logprobs)
@@ -1213,9 +1213,13 @@ def test_decode_carry_streams_are_the_uploaded_ones(sampling, window):
     hits = eng.phase_counts["decode.resident_hit"]
     ups = eng.phase_counts["decode.upload"]
     assert hits > 0 and ups > 0
-    # a step is launched from its own pack or ahead, by the one before it
+    # a step is launched from its own pack, at the head of its iteration
+    # or at the tail of the one before it, or ahead, by the one before
+    # it; each counts its block where it chooses it
     assert hits + ups == eng.phase_counts["decode.dispatch"] \
-        + eng.phase_counts["decode.ahead_hit"]
+        + eng.phase_counts["decode.ahead_dispatch"] \
+        + eng.phase_counts["decode.tail_dispatch"]
+    assert eng.phase_counts["decode.tail_hit"] >= 3
     assert eng_d.phase_counts["decode.resident_hit"] == 0
     assert eng_d.phase_counts["decode.upload"] == hits + ups
     if sampling != "greedy":
@@ -1271,7 +1275,8 @@ def test_steady_decode_steps_make_no_device_call_in_the_pack(monkeypatch):
     eng = _steady_engine()
     eng.add_request(_req("a", range(1, 7), 40))
     eng.add_request(_req("b", range(2, 9), 40, temperature=0.0))
-    assert _uploads_per_step(eng, 2) == [0, 1]   # prefill; first decode
+    # the prefill iteration packs the first decode at its tail
+    assert _uploads_per_step(eng, 2) == [1, 0]
     calls = _count_calls(monkeypatch, (jax.random, "split"),
                          (jax, "device_put"), (jnp, "asarray"))
     hits = eng.phase_counts["decode.resident_hit"]
@@ -1289,17 +1294,19 @@ def test_an_event_costs_exactly_one_upload(event):
     eng = _steady_engine()
     eng.add_request(_req("a", range(1, 7), 60))
     eng.add_request(_req("b", range(2, 9), 5 if event == "finish" else 60))
-    assert _uploads_per_step(eng, 3) == [0, 1, 0]
+    # the prefill iteration ends with the first decode packed and uploaded
+    assert _uploads_per_step(eng, 3) == [1, 0, 0]
     if event == "page_growth":
         # a: 6 prompt tokens + 1 from prefill + 2 decoded = 9; the step
-        # that samples token 16 grows the page for position 16
+        # that samples token 16 grows the page for position 16, at its
+        # tail (nothing was launched ahead: no page to write onto)
         got = _uploads_per_step(eng, 10)
-        assert got == [0] * 6 + [0, 1, 1, 0], got   # b (7 tokens) first
+        assert got == [0] * 6 + [1, 1, 0, 0], got   # b (7 tokens) first
     elif event == "admit":
         eng.add_request(_req("late", range(5, 12), 60))
-        # decode (held block), then the prefill that admits; the next
-        # decode sees the new row
-        assert _uploads_per_step(eng, 3) == [0, 1, 0]
+        # decode (held block), then the prefill that admits, then, at
+        # the same iteration's tail, the decode that sees the new row
+        assert _uploads_per_step(eng, 3) == [1, 0, 0]
     elif event == "finish":
         # b: 1 token from prefill, 2 decoded; its 5th ends it two steps on
         assert _uploads_per_step(eng, 4) == [0, 0, 1, 0]
@@ -1309,10 +1316,11 @@ def test_an_event_costs_exactly_one_upload(event):
         assert _uploads_per_step(eng, 3) == [0, 1, 0]
     else:
         # another table width is another shape: a miss, not an error
-        # (once the step in flight at the old width is taken)
+        # (once the step in flight at the old width is taken: the
+        # iteration that takes it packs the wider one at its tail)
         wide = eng._table_width() * 2
         eng._table_width = lambda: wide
-        assert _uploads_per_step(eng, 3) == [0, 1, 0]
+        assert _uploads_per_step(eng, 3) == [1, 0, 0]
         assert eng._decode_carry[1].shape[1] == 4 + wide
 
 

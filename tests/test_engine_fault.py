@@ -202,6 +202,80 @@ class TestPoisonLedger:
 
 
 # ---------------------------------------------------------------------------
+# A fault inside the tail dispatch (PR 39): the iteration's sections are
+# read and posted when the next decode step is packed and launched
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("where", ["hook", "launch"])
+def test_fault_in_the_tail_dispatch_loses_no_committed_output(where):
+    """``Engine.step()`` dispatches the next decode step after its
+    sections' post. A fault raised there (by the injection hook, or by
+    the launch itself) escapes with every output of the iteration in
+    ``last_step_partial_outs``, the running rows as its members, nothing
+    pending; ``fault_reset`` drops the carry, and the streams go on to
+    the unfaulted engine's."""
+    import dataclasses
+
+    from xllm_service_tpu.config import ModelConfig
+    from xllm_service_tpu.runtime.engine import Engine, EngineRequest
+    from xllm_service_tpu.utils.types import SamplingParams
+
+    def engine():
+        eng = Engine(dataclasses.replace(ModelConfig.tiny(vocab_size=64),
+                                         dtype="float32"),
+                     EngineConfig(page_size=8, num_pages=32,
+                                  max_model_len=128, max_batch_size=4,
+                                  max_prefill_tokens=64,
+                                  prefill_buckets=(8, 16, 32)), seed=0)
+        # every iteration that may ends with a tail dispatch
+        eng._ahead_eligible = lambda *a: False
+        for rid, prompt in (("a", range(1, 7)), ("b", range(2, 9))):
+            eng.add_request(EngineRequest(
+                request_id=rid, token_ids=list(prompt),
+                sampling=SamplingParams(max_tokens=12, temperature=0.0,
+                                        ignore_eos=True)))
+        return eng
+
+    def drain(eng, toks):
+        while eng.has_work():
+            for o in eng.step():
+                toks.setdefault(o.request_id, []).extend(o.new_token_ids)
+        return toks
+
+    want = drain(engine(), {})
+    eng, toks = engine(), {}
+    for _ in range(3):
+        for o in eng.step():
+            toks.setdefault(o.request_id, []).extend(o.new_token_ids)
+    assert eng._pending is not None
+
+    def boom(*a, **kw):
+        # the tail's call: the iteration's decode section is posted
+        if eng.last_step_decode_tokens:
+            raise StepFaultInjected("tail")
+        return real(*a, **kw) if real is not None else None
+    if where == "hook":
+        real, eng.fault_hook = None, boom
+    else:
+        real, eng._jit_decode = eng._jit_decode, boom
+    with pytest.raises(StepFaultInjected):
+        eng.step()
+    if where == "launch":
+        eng._jit_decode = real
+    eng.fault_hook = None
+    outs = list(eng.last_step_partial_outs)
+    assert sorted(o.request_id for o in outs) == ["a", "b"]
+    assert sorted(eng.step_members) == ["a", "b"]
+    for o in outs:     # committed: the tokens are on the sequences
+        assert eng._by_id[o.request_id].tokens[-1] == o.new_token_ids[-1]
+        toks[o.request_id].extend(o.new_token_ids)
+    assert eng._pending is None
+    eng.fault_reset(())
+    assert eng._pending is None and eng._decode_carry is None
+    assert drain(eng, toks) == want
+    assert eng.phase_counts["decode.tail_discard"] == 0
+
+
+# ---------------------------------------------------------------------------
 # e2e chaos: contained fault, then the poison pill (tier-1)
 # ---------------------------------------------------------------------------
 def small_engine_cfg() -> EngineConfig:

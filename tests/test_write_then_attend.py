@@ -294,7 +294,8 @@ class TestEngineWriteThenAttend:
         dispatched = []
 
         def span(*parts, **args):
-            if "".join(parts) == "xllm.step.decode.dispatch":
+            if "".join(parts) in ("xllm.step.decode.dispatch",
+                                  "xllm.step.decode.tail_dispatch"):
                 dispatched.append(args)
             return contextlib.nullcontext()
         monkeypatch.setattr(steptrace, "span", span)

@@ -95,7 +95,7 @@ STEP_PHASES: Tuple[str, ...] = (
     "prefill_ring.pack", "prefill_ring.dispatch",
     "ragged.pack", "ragged.dispatch", "ragged.post",
     "decode.pack", "decode.upload", "decode.dispatch",
-    "decode.ahead_dispatch", "decode.post",
+    "decode.ahead_dispatch", "decode.tail_dispatch", "decode.post",
     "decode_multi.pack", "decode_multi.dispatch",
     "decode_multi.spec_dispatch", "decode_multi.post",
 )

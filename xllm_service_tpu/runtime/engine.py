@@ -362,14 +362,17 @@ class Engine:
         # device-resident carries before burst k's outputs are read back.
         # None = auto: on whenever bursts are fused. The SINGLE step has
         # no such option: it launches ahead wherever ``_ahead_eligible``
-        # holds (``_launch_ahead``).
+        # holds (``_launch_ahead``), and an iteration that could not
+        # dispatches its successor at its tail (``_tail_eligible``).
         dp = getattr(engine_cfg, "decode_pipeline", None)
         if dp is None:
             dp = engine_cfg.decode_steps > 1
         self.decode_pipeline = bool(dp) and engine_cfg.decode_steps > 1
-        # The ONE step or burst launched ahead of the host's read of the
-        # one before it: its device handles and what it assumed of the
-        # batch (taken or discarded by the next decode: ``_take_ahead``).
+        # The ONE step or burst on the device ahead of the iteration that
+        # will read it (launched before the host read the one before it,
+        # or dispatched from host truth at an iteration's tail): its
+        # device handles and what it assumed of the batch (taken or
+        # discarded by the next decode: ``_take_ahead``).
         self._pending: Optional[Dict[str, Any]] = None
         # Do the rows of one step see each other? Only through a sparse
         # layer that buckets by capacity (``transformer._mlp``'s
@@ -732,16 +735,19 @@ class Engine:
         speculative burst covered)."""
         pc = self.phase_counts
         disp = pc.get("decode_multi.spec_dispatch", 0) \
-            + pc.get("decode.ahead_dispatch", 0)
+            + pc.get("decode.ahead_dispatch", 0) \
+            + pc.get("decode.tail_dispatch", 0)
         hits = pc.get("decode_multi.spec_hit", 0) \
-            + pc.get("decode.ahead_hit", 0)
+            + pc.get("decode.ahead_hit", 0) \
+            + pc.get("decode.tail_hit", 0)
         idle_n = pc.get("decode_multi.device_idle", 0)
         idle_s = self.phase_times.get("decode_multi.device_idle", 0.0)
         return {
             "spec_dispatches": disp,
             "spec_hits": hits,
             "spec_rollbacks": pc.get("decode_multi.spec_rollback", 0)
-            + pc.get("decode.ahead_discard", 0),
+            + pc.get("decode.ahead_discard", 0)
+            + pc.get("decode.tail_discard", 0),
             "hit_ratio": hits / disp if disp else 0.0,
             "device_idle_ms_per_burst":
                 1e3 * idle_s / idle_n if idle_n else 0.0,
@@ -1141,7 +1147,39 @@ class Engine:
         self.last_step_kind = ("mixed" if pf and dc else
                                "prefill" if pf else
                                "decode" if dc else "idle")
+        if self._tail_eligible(outs):
+            # The iteration ends with the next decode step on the device:
+            # the worker's emit, flush and lock hand-over, and the
+            # handlers' threads they wake, run under it and not before it.
+            self._note_members(self.running)
+            self._pending = self._dispatch_decode(at_tail=True)
         return outs
+
+    def _tail_eligible(self, outs: List[StepOutput]) -> bool:
+        """May this iteration, whose sections are read and posted, pack
+        and dispatch the NEXT single decode step from host truth before
+        it hands ``outs`` out? Where the next iteration would begin with
+        exactly that pack and nothing else: rows are running, nothing
+        waits or is cancelled (the next iteration would first drain a
+        cancel, or schedule a prefill behind the step), and no step was
+        launched ahead already (one step runs, at most one is queued
+        behind it). Not after a finish: a closed loop's follow-up arrives
+        a fixed time behind the ``emit`` that ended its predecessor, and
+        an iteration made shorter there moves the step boundary from just
+        behind that arrival to just in front of it (PERF.md section 6,
+        PRs 37 and 39); the launch ahead keeps the same rule for a finish
+        it can foresee (``_ahead_eligible``). And never where growing a
+        row's table could need a victim: a preemption is left to the
+        head of the next iteration."""
+        if (self.ecfg.decode_steps != 1 or not self.interleave
+                or not self.running or self.waiting or self._cancelled
+                or self._pending is not None
+                or any(o.finished for o in outs)):
+            return False
+        grow = sum(max(self._pages_needed(len(s.tokens)) - len(s.pages), 0)
+                   for s in self.running)
+        return grow <= (self.allocator.num_free
+                        + self.prefix_cache.num_reclaimable)
 
     def _step_interleaved(self, outs: List[StepOutput]) -> List[StepOutput]:
         if self._jit_ragged is not None and self.running and self.waiting \
@@ -1755,7 +1793,9 @@ class Engine:
         histogram), so wherever the host's post of N cannot change what
         N+1 needs, the device has N+1 queued while the host blocks on N,
         posts it, emits, flushes and comes back. The next call takes the
-        step in flight in place of a pack and a dispatch."""
+        step in flight in place of a pack and a dispatch. Where N+1 could
+        not be launched ahead, ``step()`` dispatches it after N's post,
+        from host truth, and it is taken here all the same."""
         step = self._take_ahead(1)
         if step is None:
             step = self._dispatch_decode()
@@ -1808,9 +1848,14 @@ class Engine:
             self._slot_last_token[i] = seq.tokens[-1]
             self._slot_pos[i] = len(seq.tokens) - 1
 
-    def _dispatch_decode(self) -> Optional[Dict[str, Any]]:
+    def _dispatch_decode(self, at_tail: bool = False
+                         ) -> Optional[Dict[str, Any]]:
         """Pack one decode step from host truth and launch it (None when
-        growing the pages preempted every row away)."""
+        growing the pages preempted every row away). ``at_tail``: at the
+        end of the iteration before the one that will read it
+        (``_tail_eligible``), under a phase and a pair of counts of its
+        own; a page it allocates stays with its row whatever becomes of
+        the launch, so a discard has nothing to undo."""
         # Restore the pages-cover-len invariant at dispatch regardless of
         # which decode path ran last: the fused multi-step accepts up to N
         # tokens but pre-grows only its own lookahead window, so a sequence
@@ -1846,6 +1891,11 @@ class Engine:
                     packed = jax.device_put(np.ascontiguousarray(block),
                                             self._carry_place)
                 mirror = block.copy()
+        if at_tail:
+            return self._launch_decode(
+                self._phase("decode.tail_dispatch",
+                            **self._decode_shape(mp)),
+                packed, mirror, kind="decode.tail")
         return self._launch_decode(
             self._phase("decode.dispatch", **self._decode_shape(mp)),
             packed, mirror)
@@ -1856,9 +1906,12 @@ class Engine:
                     MP=mp, walk=self._decode_walk(mp))
 
     def _launch_decode(self, bracket, packed: jnp.ndarray,
-                       mirror: np.ndarray) -> Dict[str, Any]:
+                       mirror: np.ndarray, kind: str = "decode"
+                       ) -> Dict[str, Any]:
         """Enqueue the decode program on ``packed`` under the phase
-        ``bracket`` and start its outputs' host copy. ``mirror`` is the
+        ``bracket`` and start its outputs' host copy; a step kept
+        pending (``kind``: ``decode.ahead`` or ``decode.tail``) counts
+        ``<kind>_hit`` or ``<kind>_discard``. ``mirror`` is the
         host's copy of ``packed`` (of a step launched ahead: once the
         step before it is read). The program splits the key itself and
         hands the first half back: the values of a host-side split, with
@@ -1878,7 +1931,7 @@ class Engine:
         _start_host_copy(fused, top_ids if want_top else None,
                          top_lps if want_top else None)
         return {"steps": 1, "whole": self._rows_interfere,
-                "counts": ("decode.ahead_hit", "decode.ahead_discard"),
+                "counts": (kind + "_hit", kind + "_discard"),
                 "fused": fused, "top_ids": top_ids, "top_lps": top_lps,
                 "mdrop": mdrop, "want_top": want_top,
                 "next_packed": next_packed, "mirror": mirror,
@@ -1903,9 +1956,13 @@ class Engine:
         if not _same_block(mirror[:, 2:],
                            self._slot_packed[:, 2:_PACK_COLS + mp]):
             return None
+        # It is handed the block the step before it leaves on the device
+        # (counted where the block is chosen, as ``_dispatch_decode``
+        # counts its own: uploads + resident hits = steps + discards).
+        self.phase_counts["decode.resident_hit"] += 1
         return self._launch_decode(
             self._phase("decode.ahead_dispatch", **self._decode_shape(mp)),
-            step["next_packed"], mirror)
+            step["next_packed"], mirror, kind="decode.ahead")
 
     def _run_decode_multi(self) -> List[StepOutput]:
         """N fused decode steps per host round-trip (one lax.scan program).
@@ -2156,8 +2213,6 @@ class Engine:
             return None
         self.phase_counts[p["counts"][0]] += 1
         if n_steps == 1:
-            # It was handed the block the step before left on the device.
-            self.phase_counts["decode.resident_hit"] += 1
             self.phase_counts["decode.ahead_dropped_rows"] += \
                 len(p["members"]) - len(self.running)
         return p

@@ -1463,18 +1463,23 @@ class Worker:
             "block (the others passed the one the last step handed back)",
             labelnames=("model",)).set_total(
             eng.phase_counts.get("decode.upload", 0), model=m)
-        # hit / (decode + mixed steps) is the share of single-step decodes
-        # that were already on the device when their iteration began.
+        # (hit + tail_hit) / (decode + mixed steps) is the share of
+        # single-step decodes that were already on the device when their
+        # iteration began.
         ahead = self.obs.counter(
             "xllm_worker_decode_ahead_total",
             "single decode steps launched before the step before them "
             "was read (launched), taken by the next iteration in place "
             "of a pack and a dispatch (hit), or thrown away whole "
-            "(discarded)",
+            "(discarded); tail_*: the same of steps dispatched from "
+            "host truth at the tail of the iteration before theirs",
             labelnames=("model", "result"))
         for result, phase in (("launched", "decode.ahead_dispatch"),
                               ("hit", "decode.ahead_hit"),
-                              ("discarded", "decode.ahead_discard")):
+                              ("discarded", "decode.ahead_discard"),
+                              ("tail_launched", "decode.tail_dispatch"),
+                              ("tail_hit", "decode.tail_hit"),
+                              ("tail_discarded", "decode.tail_discard")):
             ahead.set_total(eng.phase_counts.get(phase, 0), model=m,
                             result=result)
         self.obs.counter(
