@@ -45,4 +45,6 @@ from xllm_service_tpu.obs import profiler           # noqa: F401
 from xllm_service_tpu.obs.slo import (              # noqa: F401
     AnomalyDetector, InstanceSignal, SloConfig, SloEngine, SloObjective)
 from xllm_service_tpu.obs.spans import (            # noqa: F401
-    REQUEST_ID_HEADER, SERVICE_STAGES, WORKER_STAGES, SpanStore)
+    FIRST_TOKEN_STAGES, FIRST_TOKEN_STAMPS, FRONT_MS_HEADER,
+    REQUEST_ID_HEADER, SCHEDULE_MS_HEADER, SERVICE_STAGES, WORKER_STAGES,
+    SpanStore, first_token_stages)

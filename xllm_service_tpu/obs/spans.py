@@ -4,9 +4,10 @@ One span per ``service_request_id``: an ordered list of stage events,
 each stamped with the recording process's monotonic clock (interval
 arithmetic within a plane) and wall clock (cross-plane ordering — the
 service and worker monotonic clocks share no epoch). The service plane
-records received → admitted → scheduled → dispatched → first_token →
-finished; the worker records its own received → scheduled →
-first_token → finished under the SAME correlation id (propagated as the
+records accepted → received → admitted → scheduled → dispatched →
+first_token → finished; the worker records its own received →
+scheduled → first_token → finished, and between them the stamps of
+``FIRST_TOKEN_STAMPS``, under the SAME correlation id (propagated as the
 ``x-xllm-request-id`` header on the forwarded request) and ships
 finished spans back on the heartbeat path, where the service merges
 them in with ``plane="worker"``. The merged timeline is queryable at
@@ -44,16 +45,56 @@ def _deep_copy(v: Any) -> Any:
 # Canonical service-plane stage order (docs/OBSERVABILITY.md); extra
 # stages (e.g. "redispatch"/"redispatched") may interleave — the first
 # occurrence per (stage, plane) wins (see record()).
-SERVICE_STAGES = ("received", "admitted", "scheduled", "dispatched",
-                  "first_token", "finished")
+SERVICE_STAGES = ("accepted", "received", "admitted", "scheduled",
+                  "dispatched", "first_token", "finished")
 # "encoded" appears only on multimodal requests: the prefill worker
 # records it once the EPD encode stage resolved (attrs say whether a
 # remote ENCODE instance, a cache hit, or local fallback produced the
 # embeddings — docs/EPD.md). "faulted" appears only on requests the
 # engine-step fault boundary blamed and evicted (docs/ROBUSTNESS.md
 # device-plane fault contract).
-WORKER_STAGES = ("received", "encoded", "scheduled", "first_token",
-                 "faulted", "finished")
+WORKER_STAGES = ("received", "encoded", "parsed", "locked", "scheduled",
+                 "slotted", "launched", "ready", "first_token",
+                 "first_frame", "faulted", "finished")
+
+# Where a request's time to its first token goes, on the worker: each
+# stamp (a worker-plane stage above, taken where docs/OBSERVABILITY.md
+# says) and the interval that ENDS at it, the ``stage`` label of
+# ``xllm_worker_first_token_stage_ms``. The stamps are contiguous, so a
+# request's stage observations sum to its ``total`` (``received`` to
+# ``first_frame``). Closed: every stamp site in the tree names an entry
+# (tests/test_first_token_stages.py).
+FIRST_TOKEN_STAMPS = (
+    ("received", None),
+    ("parsed", "parse"),
+    ("locked", "lock_wait"),
+    ("slotted", "queue"),
+    ("launched", "prefill_host"),
+    ("ready", "prefill_device"),
+    ("first_token", "post_emit"),
+    ("first_frame", "stream_out"),
+)
+# Beside them: the master's share, which rides the forward as a duration
+# (FRONT_MS_HEADER), and the whole of the worker's.
+FIRST_TOKEN_STAGES = ("master_in",) + tuple(
+    stage for _, stage in FIRST_TOKEN_STAMPS if stage) + ("total",)
+
+
+def first_token_stages(stamps: Dict[str, float]) -> Dict[str, float]:
+    """Milliseconds of each stage whose stamp and the stamp before it in
+    the table are both there; ``total`` where the chain has both ends. A
+    path that lacks a stamp gives the stages it has."""
+    out: Dict[str, float] = {}
+    prev = None
+    for name, stage in FIRST_TOKEN_STAMPS:
+        t = stamps.get(name)
+        if stage and t is not None and prev is not None:
+            out[stage] = 1000.0 * (t - prev)
+        prev = t
+    first, last = FIRST_TOKEN_STAMPS[0][0], FIRST_TOKEN_STAMPS[-1][0]
+    if first in stamps and last in stamps:
+        out["total"] = 1000.0 * (stamps[last] - stamps[first])
+    return out
 
 DEFAULT_CAPACITY = 2048
 
@@ -62,6 +103,13 @@ DEFAULT_CAPACITY = 2048
 # http_service, so the worker doesn't import the whole service plane
 # for one constant).
 REQUEST_ID_HEADER = "x-xllm-request-id"
+# The master's share of a request's time to its first token, on the same
+# forward: milliseconds on the master's monotonic clock from the entry of
+# its completions handler to the building of the forward's headers, and
+# the part of that inside ``scheduler.schedule()``. Durations, since the
+# two planes' monotonic clocks share no epoch.
+FRONT_MS_HEADER = "x-xllm-front-ms"
+SCHEDULE_MS_HEADER = "x-xllm-schedule-ms"
 
 
 class SpanStore:
@@ -79,6 +127,13 @@ class SpanStore:
         # service, which drains nothing; only workers export — stays
         # bounded by ``capacity`` instead of leaking one id per request.
         self._finished: set = set()
+        # The ids drained lately (an LRU set): an event recorded for one
+        # AFTER its span left (a first-token stamp folded by the handler's
+        # thread once the engine's had already finished the request)
+        # queues what it starts for the next drain by itself, and the
+        # importer merges the two parts.
+        self._drained: "collections.OrderedDict[str, None]" = \
+            collections.OrderedDict()
         # Eviction visibility: a counter (exported as
         # ``xllm_span_evictions_total`` on both planes) plus a small
         # tombstone ring of evicted rids, so ``GET /admin/trace/<id>``
@@ -134,9 +189,15 @@ class SpanStore:
         """Record one stage event. Idempotent per (stage, plane): retry
         paths (redispatch, on_close backstops) may reach the same stage
         twice, and the FIRST occurrence is the truthful timestamp."""
-        event = {"stage": stage, "plane": plane,
-                 "t_mono": time.monotonic() if t_mono is None else t_mono,
-                 "t_wall": time.time() if t_wall is None else t_wall}
+        now = time.monotonic()
+        if t_mono is None:
+            t_mono = now
+        if t_wall is None:
+            # A stamp taken earlier than it is recorded keeps its place
+            # in the wall-clock order too.
+            t_wall = time.time() - (now - t_mono)
+        event = {"stage": stage, "plane": plane, "t_mono": t_mono,
+                 "t_wall": t_wall}
         event.update(attrs)
         with profiler.section("span.write"):
             with self._lock:
@@ -145,7 +206,7 @@ class SpanStore:
                        for e in span["events"]):
                     return
                 span["events"].append(event)
-                if stage == "finished":
+                if stage == "finished" or rid in self._drained:
                     self._finished.add(rid)
 
     def merge_remote(self, rid: str, plane: str,
@@ -254,10 +315,14 @@ class SpanStore:
             for rid in rids:
                 span = self._spans.pop(rid, None)
                 if span is not None:
+                    self._drained[rid] = None
+                    self._drained.move_to_end(rid)
                     out.append({"request_id": rid,
                                 "attrs": _deep_copy(span["attrs"]),
                                 "events": [_deep_copy(e)
                                            for e in span["events"]]})
+            while len(self._drained) > 256:
+                self._drained.popitem(last=False)
         return out
 
     def requeue(self, drained: List[Dict[str, Any]]) -> None:

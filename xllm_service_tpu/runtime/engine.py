@@ -134,6 +134,10 @@ class Sequence:
     # (a preempted or fault-reset sequence is admitted again, not counted
     # again).
     admitted_once: bool = False
+    # When that was: the ``slotted`` stamp of the request's first-token
+    # chain (obs/spans.py FIRST_TOKEN_STAMPS); it rides out on the
+    # sequence's first StepOutput with the two stamps of its last window.
+    slotted_time: float = 0.0
     # Chained digests of the first len(page_digests) full pages of
     # ``tokens`` (kv_cache.PrefixCacheIndex.extend_digests fills it).
     # ``tokens`` only grows, so preemption and fault reset keep them:
@@ -170,6 +174,9 @@ class StepOutput:
     # restore or cross-worker fetch) — rides the first prefill output so
     # the worker can annotate the request span (cache_hit_tokens).
     num_cached_tokens: int = 0
+    # The engine's stamps of the request's first-token chain (obs/spans.py
+    # FIRST_TOKEN_STAMPS): on a sequence's FIRST output only, None after.
+    first_token_stamps: Optional[Dict[str, float]] = None
 
     @property
     def finished(self) -> bool:
@@ -499,6 +506,12 @@ class Engine:
         # warmup means a shape escaped warmup's coverage.
         self.phase_times: Dict[str, float] = collections.defaultdict(float)
         self.phase_counts: Dict[str, int] = collections.defaultdict(int)
+        # The engine thread's OWN time in each phase (``thread_time``):
+        # what is left of the wall time went to other threads or to a wait.
+        self.phase_cpu: Dict[str, float] = collections.defaultdict(float)
+        # The clock read that closed the last phase (``_phase`` or
+        # ``_read_host``): ``launched`` and ``ready`` are two of them.
+        self._phase_end = 0.0
 
     def _build_step_programs(self, kv) -> None:
         """Build the jitted step programs for pools placed like ``kv``:
@@ -628,12 +641,14 @@ class Engine:
         and while a device trace runs the same bracket is the span
         ``xllm.step.<name>`` on the profiler's clock (``args``: the
         launched program's shape key on ``*.dispatch``)."""
-        t0 = time.monotonic()
+        t0, c0 = time.monotonic(), time.thread_time()
         try:
             with steptrace.span("xllm.step.", name, **args):
                 yield
         finally:
-            self.phase_times[name] += time.monotonic() - t0
+            self._phase_end = t1 = time.monotonic()
+            self.phase_times[name] += t1 - t0
+            self.phase_cpu[name] += time.thread_time() - c0
             self.phase_counts[name] += 1
 
     def _note_recompile(self, name: str, jitted, before: int,
@@ -717,6 +732,7 @@ class Engine:
         self.phase_counts[phase + ".device_wait"] += 1
         self.phase_times[phase + ".host_copy"] += t2 - t1
         self.phase_counts[phase + ".host_copy"] += 1
+        self._phase_end = t2
         return out
 
     @staticmethod
@@ -916,8 +932,9 @@ class Engine:
             self.last_step_state_restored.append(int(cached_tokens > 0))
         if not seq.admitted_once:
             seq.admitted_once = True
+            seq.slotted_time = time.monotonic()
             self.queue_waits_ms.append(
-                1000.0 * (time.monotonic() - seq.req.arrival_time))
+                1000.0 * (seq.slotted_time - seq.req.arrival_time))
         seq.slot = slot
         self._slots[slot] = seq
         self._slot_sampling[slot] = seq.req.sampling
@@ -1414,6 +1431,7 @@ class Engine:
                                  self.kv, st_f32, st_i32, key, mm_e,
                                  mm_p, None, bias_ids, bias_vals, None,
                                  T)
+        launched = self._phase_end
         self.last_step_attn_dispatches += 1
         self._note_recompile("ragged", self._jit_ragged, cache_before,
                              MP, B, T)
@@ -1422,6 +1440,7 @@ class Engine:
             "ragged", fused,
             top_ids if want_top else None,
             top_lps if want_top else None, mdrop)
+        ready = self._phase_end
         next_tok, logprob = _split_tok_lp(fused)
         self._note_moe_dropped(mdrop)
         # Batch membership changed (admits): penalty histograms rebuild
@@ -1454,7 +1473,7 @@ class Engine:
                 out = self._append_token(
                     seq, int(next_tok[i]), float(logprob[i]),
                     top=self._top_entry(seq, top_ids, top_lps, i))
-                out.num_cached_tokens = seq.num_cached_tokens
+                self._first_output(out, seq, launched, ready)
                 outs.append(out)
                 self._sync_slot(seq)
         self.last_step_prefill_s = time.monotonic() - t0
@@ -1648,6 +1667,7 @@ class Engine:
                     jitted(self.params, jnp.asarray(packed), self.kv,
                            st_f32, st_i32, key, mm_e, mm_p, None,
                            bias_ids, bias_vals, rope_pos, T)
+        launched = self._phase_end
         self.last_step_attn_dispatches += 1
         self._note_recompile(program, jitted, cache_before, MP, B, T)
         want_top = self._want_top(top_ids, batch)
@@ -1655,6 +1675,7 @@ class Engine:
             "prefill", fused, plp,
             top_ids if want_top else None,
             top_lps if want_top else None, mdrop)
+        ready = self._phase_end
         next_tok, logprob = _split_tok_lp(fused)
         self._note_moe_dropped(mdrop)
         if plp is not None:
@@ -1695,7 +1716,7 @@ class Engine:
                 out = self._append_token(
                     seq, tok, float(logprob[i]),
                     top=self._top_entry(seq, top_ids, top_lps, i))
-                out.num_cached_tokens = seq.num_cached_tokens
+                self._first_output(out, seq, launched, ready)
                 if seq.prompt_lps is not None:
                     out.prompt_logprobs = seq.prompt_lps
                     seq.prompt_lps = None
@@ -1732,6 +1753,7 @@ class Engine:
                 self._jit_prefill_ring(
                     self.params, jnp.asarray(packed), self.kv,
                     st_f32, st_i32, key, bias_ids, bias_vals, t_len=T)
+        launched = self._phase_end
         self.last_step_attn_dispatches += 1
         self._note_recompile("prefill_ring", self._jit_prefill_ring,
                              cache_before, MP, 1, T)
@@ -1750,6 +1772,7 @@ class Engine:
         out = self._append_token(
             seq, int(next_tok[0]), float(logprob[0]),
             top=self._top_entry(seq, top_ids, top_lps, 0))
+        self._first_output(out, seq, launched, self._phase_end)
         self._sync_slot(seq)
         return [out]
 
@@ -2513,6 +2536,19 @@ class Engine:
             self._swa_trim(seq)
             self._grow_pages(seq)
         return out
+
+    @staticmethod
+    def _first_output(out: StepOutput, seq: Sequence, launched: float,
+                      ready: float) -> None:
+        """What only the output of a prompt's LAST window carries: the
+        prefix cache's share, and (before the sequence's first token
+        alone: a preempted one is prefilled again) the engine's stamps of
+        the first-token chain. ``launched`` and ``ready`` are the clock
+        reads that closed that window's dispatch and its read."""
+        out.num_cached_tokens = seq.num_cached_tokens
+        if seq.num_generated == 1:
+            out.first_token_stamps = {"slotted": seq.slotted_time,
+                                      "launched": launched, "ready": ready}
 
     def _finish_reason(self, seq: Sequence, tok: int) -> FinishReason:
         sp = seq.req.sampling

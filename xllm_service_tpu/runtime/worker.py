@@ -45,7 +45,8 @@ from xllm_service_tpu.config import (
 from xllm_service_tpu.nlp.tokenizer import (
     IncrementalDecoder, Tokenizer, TokenizerFactory)
 from xllm_service_tpu.obs import (
-    Failpoints, REQUEST_ID_HEADER, Registry, SpanStore)
+    FRONT_MS_HEADER, SCHEDULE_MS_HEADER, Failpoints, REQUEST_ID_HEADER,
+    Registry, SpanStore, first_token_stages)
 from xllm_service_tpu.obs import steptrace
 from xllm_service_tpu.obs.events import EventLog
 from xllm_service_tpu.obs.expfmt import quantile_from_buckets
@@ -390,6 +391,15 @@ def _moe_record(st: Dict[str, int]) -> Optional[Dict[str, Any]]:
                 / (st["assignments"] / st["experts_touched"]), 3)}
 
 
+def _header_ms(headers: Dict[str, str], name: str) -> Optional[float]:
+    """A duration header's milliseconds; None where it is absent or not
+    a number (a direct caller, an older master)."""
+    try:
+        return float(headers[name])
+    except (KeyError, ValueError):
+        return None
+
+
 def _merge_step_outputs(outs: List[StepOutput]) -> StepOutput:
     """Concatenate held-back deltas of one choice (in arrival order) into
     a single StepOutput; the final element supplies finish state."""
@@ -435,7 +445,7 @@ class _LiveRequest:
                  "stream_to_service", "service_request_id", "model",
                  "is_chat", "stream", "include_usage", "first_out_time",
                  "sampling", "prompt_tokens", "target_n", "prompt_lps",
-                 "_echo_cache", "emit_token_ids")
+                 "_echo_cache", "emit_token_ids", "stamps", "front_ms")
 
     def __init__(self, req: EngineRequest, tokenizer: Tokenizer,
                  service_request_id: str, model: str, is_chat: bool,
@@ -452,6 +462,14 @@ class _LiveRequest:
         self.include_usage = include_usage
         self.stream_to_service = stream_to_service
         self.first_out_time = 0.0
+        # The request's first-token chain (obs/spans.py
+        # FIRST_TOKEN_STAMPS): stamp -> this process's monotonic clock,
+        # written where each stage ends; None once the handler's thread
+        # has folded it (``Worker._fold_first_token``), so a request past
+        # its first token carries nothing. ``front_ms``: the master's
+        # share, as the forward's header gave it.
+        self.stamps: Optional[Dict[str, float]] = {}
+        self.front_ms: Optional[float] = None
         n = max(1, n)
         self.engine_rids = ([service_request_id] if n == 1 else
                             [f"{service_request_id}#{k}" for k in range(n)])
@@ -485,6 +503,12 @@ class _LiveRequest:
                         logprob=plp, top_logprobs=[]))
             self._echo_cache = (text, lps)
         return self._echo_cache
+
+    def stamp(self, name: str, t: Optional[float] = None) -> None:
+        """Take one stamp of the first-token chain (now, or ``t``)."""
+        stamps = self.stamps
+        if stamps is not None:
+            stamps[name] = time.monotonic() if t is None else t
 
     def choice_index(self, engine_rid: str) -> int:
         if len(self.choices) == 1:
@@ -1722,6 +1746,20 @@ class Worker:
             labelnames=("model",)).set_total(
             stats["hashed_tokens_total"], model=m)
 
+    def _flush_phase_cpu(self, rt: ModelRuntime) -> None:
+        """The phase ledger's CPU column, mirrored at scrape time alone:
+        nothing reads it between two scrapes, and a dozen series more in
+        every iteration's flush is engine-thread time."""
+        c_cpu = self.obs.counter(
+            "xllm_worker_phase_cpu_seconds_total",
+            "the engine thread's own CPU time per engine phase "
+            "(thread_time): over phase_seconds_total, the share of a "
+            "phase's wall time that was its own work and not another "
+            "thread's hold of the interpreter or a wait",
+            labelnames=("model", "phase"))
+        for name, cpu in list(rt.engine.phase_cpu.items()):
+            c_cpu.set_total(cpu, model=rt.model, phase=name)
+
     def _flush_phase_ledger(self, rt: ModelRuntime) -> None:
         """Mirror the engine's phase wall-time ledger + post-warmup
         recompile counters into the registry (same series /metrics
@@ -1778,11 +1816,17 @@ class Worker:
             if live is None:
                 continue
             if live.first_out_time == 0.0:
-                live.first_out_time = now
+                # Its own clock read: ``emit`` up to THIS request is the
+                # end of its ``post_emit`` stage.
+                t_first = live.first_out_time = time.monotonic()
+                stamps = live.stamps
+                if stamps is not None:
+                    stamps.update(out.first_token_stamps or ())
+                    live.stamp("first_token", t_first)
                 self._latency.recent_max_ttft_ms = max(
                     self._latency.recent_max_ttft_ms, step_ms)
                 self.spans.record(live.service_request_id, "first_token",
-                                  plane="worker", t_mono=now)
+                                  plane="worker", t_mono=t_first)
                 # Per-request prefix-reuse evidence on the span (rides
                 # the heartbeat to /admin/trace/<id>): prompt tokens
                 # whose KV was already resident when prefill started.
@@ -2287,10 +2331,12 @@ class Worker:
                 marked_rids = list(live.engine_rids)
         # As in the engine loop: the wait for the lock apart from the
         # stretch that holds it (which the loop's lock_wait sees).
+        live.stamp("parsed")
         lock_wait = steptrace.span("xllm.admit.lock_wait", rid=srid)
         lock_wait.__enter__()
         with self._engine_lock:
             lock_wait.__exit__(None, None, None)
+            live.stamp("locked")
             with steptrace.span("xllm.admit.locked", rid=srid):
                 self._fault_marked.update(marked_rids)
                 for k, erid in enumerate(live.engine_rids):
@@ -2370,7 +2416,47 @@ class Worker:
         corr = headers.get(REQUEST_ID_HEADER, "")
         if corr:
             self.spans.annotate(srid, correlation_header=corr)
+        # The master's share of the time to the first token, where the
+        # forward carries it (durations on the master's clock).
+        front_ms = _header_ms(headers, FRONT_MS_HEADER)
+        if front_ms is not None:
+            self.spans.annotate(
+                srid, front_ms=front_ms,
+                schedule_ms=_header_ms(headers, SCHEDULE_MS_HEADER))
         self.spans.record(srid, "received", plane="worker", t_mono=t_recv)
+
+    def _fold_first_token(self, live: _LiveRequest) -> None:
+        """Close a request's first-token chain: one observation a stage
+        into ``xllm_worker_first_token_stage_ms`` and one span event a new
+        stamp, so that ``/admin/trace/<id>`` shows for one request what
+        the histogram shows for all. On the HANDLER's thread, once the
+        first frame is written (or where the path's last stamp is taken:
+        docs/OBSERVABILITY.md has the table); the engine thread only
+        stamps. A path that lacks a stamp observes the stages it has."""
+        stamps, live.stamps = live.stamps, None
+        if not stamps:
+            return
+        h = self.obs.histogram(
+            "xllm_worker_first_token_stage_ms",
+            "a request's time to its first token by stage (obs/spans.py "
+            "FIRST_TOKEN_STAMPS): master_in is the master's share as the "
+            "forward carried it, total is received to first_frame, and "
+            "the stages between sum to it",
+            labelnames=("model", "stage"))
+        m, srid = live.model, live.service_request_id
+        if live.front_ms is not None:
+            h.observe(live.front_ms, model=m, stage="master_in")
+        for stage, ms in first_token_stages(stamps).items():
+            h.observe(ms, model=m, stage=stage)
+        # One offset for the chain: its order on the wall clock is its
+        # order on this one.
+        off = time.time() - time.monotonic()
+        for name, t in list(stamps.items()):
+            # (idempotent: ``received`` and ``first_token`` are events
+            # already, recorded where they were taken; a copy, since at
+            # the fan-in's ack the engine thread may still stamp)
+            self.spans.record(srid, name, plane="worker", t_mono=t,
+                              t_wall=t + off)
 
     def _serve_generate_inner(self, req: Request,
                               is_chat: bool) -> Response:
@@ -2400,6 +2486,7 @@ class Worker:
         srid_hint = body.get("service_request_id") or ""
         if srid_hint:
             self._ingress_span(srid_hint, t_recv, req.headers)
+        front_ms = _header_ms(req.headers, FRONT_MS_HEADER)
         routing = body.get("routing") or {}
         sp_body = body.get("sampling") or {}
         try:
@@ -2432,11 +2519,14 @@ class Worker:
                 and max_toks > 1 and n_choices == 1 and best_of <= 1
                 and not echo):
             return self._serve_pd_prefill(body, is_chat,
-                                          routing["decode_name"])
+                                          routing["decode_name"], t_recv,
+                                          front_ms)
         try:
             live = self._parse_generate(body, is_chat)
         except (TypeError, ValueError, RuntimeError) as e:
             return Response.error(400, str(e))
+        live.stamp("received", t_recv)
+        live.front_ms = front_ms
         if not srid_hint:   # direct-to-worker: srid minted in the parse
             self._ingress_span(live.service_request_id, t_recv,
                                req.headers)
@@ -2445,6 +2535,9 @@ class Worker:
         if live.stream_to_service:
             # Topology 2: tokens flow worker → service RPC fan-in; the
             # relay response is a plain ack (rpc_service/service.h:67-79).
+            # No handler's thread sees the first token: the chain is
+            # folded here, as far as the admission took it.
+            self._fold_first_token(live)
             return Response.json({"status": "accepted",
                                   "service_request_id":
                                       live.service_request_id})
@@ -2496,11 +2589,17 @@ class Worker:
                 if out is None:
                     yield SSE_DONE
                     return
-                done = False
+                done = wrote = False
                 for ro in self._process_step_output(live, out):
                     for frame in asm.on_output(ro):
                         yield frame
+                        wrote = True
                     done = done or ro.finished
+                if wrote and live.stamps is not None and out.new_token_ids:
+                    # Control is back from the yield of the first frame
+                    # that carries a token: the frame is written.
+                    live.stamp("first_frame")
+                    self._fold_first_token(live)
                 if done:
                     return
         finally:
@@ -2535,6 +2634,10 @@ class Worker:
                         "engine_fault")
                 if out is None:
                     break
+                if live.stamps is not None:
+                    # No frame is written before the last token: the
+                    # chain ends at ``first_token``.
+                    self._fold_first_token(live)
                 done = False
                 for ro in self._process_step_output(live, out):
                     coll.add(ro)
@@ -2571,6 +2674,7 @@ class Worker:
             # — the engine's phase ledger, live per worker.
             self._engine_load(rt)
             self._flush_phase_ledger(rt)
+            self._flush_phase_cpu(rt)
             self._flush_overlap(rt)
             self._flush_prefix_cache(rt)
         # Supervised-thread crash / swallowed-callback books
@@ -3254,11 +3358,14 @@ class Worker:
     # one meta-JSON line + raw K bytes + raw V bytes.
     # ------------------------------------------------------------------
     def _serve_pd_prefill(self, body: Dict[str, Any], is_chat: bool,
-                          decode_name: str) -> Response:
+                          decode_name: str, t_recv: float,
+                          front_ms: Optional[float]) -> Response:
         try:
             live = self._parse_generate(body, is_chat, pd_prefill=True)
         except (ValueError, RuntimeError) as e:
             return Response.error(400, str(e))
+        live.stamp("received", t_recv)
+        live.front_ms = front_ms
         rt = self.runtimes.get(live.model) or self.primary_runtime()
         srid = live.service_request_id
         self.spans.record(srid, "scheduled", plane="worker")
@@ -3280,6 +3387,9 @@ class Worker:
             self._finalize_live(live)
             return Response.error(503, "worker died (failpoint)",
                                   "unavailable")
+        # The first token is made here and its frame written by whoever
+        # decodes: the chain ends at ``first_token``.
+        self._fold_first_token(live)
         self._drop_live(srid)
         if first is None or first.finish_reason == FinishReason.STOP \
                 or first.finish_reason == FinishReason.CANCELLED:

@@ -34,8 +34,9 @@ from typing import Any, Dict, Iterator, List, Optional
 
 from xllm_service_tpu.config import ServiceOptions
 from xllm_service_tpu.obs import (
-    REQUEST_ID_HEADER, AnomalyDetector, EventLog, Failpoints,
-    InstanceSignal, Registry, SloConfig, SloEngine, SpanStore)
+    FRONT_MS_HEADER, REQUEST_ID_HEADER, SCHEDULE_MS_HEADER, AnomalyDetector,
+    EventLog, Failpoints, InstanceSignal, Registry, SloConfig, SloEngine,
+    SpanStore)
 from xllm_service_tpu.obs import profiler
 from xllm_service_tpu.obs import steptrace, timeline
 from xllm_service_tpu.obs.expfmt import fraction_le_from_buckets
@@ -123,20 +124,35 @@ class _RequestObs:
     frame-interval approximation (the relay never parses tokens out of
     the proxied bytes — see docs/OBSERVABILITY.md)."""
 
-    __slots__ = ("svc", "srid", "t0", "t_first", "tokens", "_done",
-                 "_dispatched")
+    __slots__ = ("svc", "srid", "t_accept", "t0", "t_first", "tokens",
+                 "schedule_ms", "_done", "_dispatched")
 
     def __init__(self, svc: "HttpService", srid: str, kind: str,
-                 model: str) -> None:
+                 model: str, t_accept: float) -> None:
         self.svc = svc
         self.srid = srid
+        # The handler's entry, before the body's parse; ``t0`` (and with
+        # it ``received`` and every histogram here) stays where it was:
+        # after parse, shed check, request build and validation.
+        self.t_accept = t_accept
         self.t0 = time.monotonic()
         self.t_first = 0.0
         self.tokens = 0
+        self.schedule_ms = 0.0
         self._done = False
         self._dispatched = False
         svc.spans.annotate(srid, kind=kind, model=model)
+        svc.spans.record(srid, "accepted", t_mono=t_accept)
         svc.spans.record(srid, "received", t_mono=self.t0)
+
+    def forward_headers(self) -> Dict[str, str]:
+        """The master's share of the time to the first token, for the
+        worker to book beside its own stages: milliseconds from the
+        handler's entry to now (the building of a forward's headers), and
+        the part of them inside ``scheduler.schedule()``."""
+        return {FRONT_MS_HEADER:
+                "%.3f" % (1000.0 * (time.monotonic() - self.t_accept)),
+                SCHEDULE_MS_HEADER: "%.3f" % self.schedule_ms}
 
     def stage(self, stage: str, **attrs: Any) -> None:
         self.svc.spans.record(self.srid, stage, **attrs)
@@ -454,6 +470,7 @@ class HttpService:
         return resp
 
     def _completions(self, http_req: Request, is_chat: bool) -> Response:
+        t_accept = time.monotonic()
         self._m_requests.inc()
         try:
             body = http_req.json()
@@ -477,12 +494,14 @@ class HttpService:
         except (TypeError, ValueError) as e:
             return Response.error(400, f"invalid request: {e}")
         robs = _RequestObs(self, req.service_request_id, kind,
-                           body.get("model", ""))
+                           body.get("model", ""), t_accept)
         robs.stage("admitted", stream=req.stream)
         self.tracer.trace(req.service_request_id,
                           {"stage": "ingress", "kind": kind, "body": body,
                            "x_request_time": req.arrival_time or None})
+        t_sched = time.monotonic()
         status, routing = self.scheduler.schedule(req)
+        robs.schedule_ms = 1000.0 * (time.monotonic() - t_sched)
         if not status.ok:
             self._m_errors.inc()
             if status.code == StatusCode.INTERNAL and \
@@ -526,11 +545,18 @@ class HttpService:
                                            is_chat, robs)
         return self._relay_mode_response(req, fwd, target, path, robs)
 
-    def _fwd_headers(self, req: SchedRequest) -> Dict[str, str]:
+    def _fwd_headers(self, req: SchedRequest,
+                     robs: Optional[_RequestObs] = None) -> Dict[str, str]:
         """Correlation header for every forward of this request — the
         worker stamps its span stages with the same id, so the merged
-        timeline at /admin/trace/<id> crosses the plane boundary."""
-        return {REQUEST_ID_HEADER: req.service_request_id}
+        timeline at /admin/trace/<id> crosses the plane boundary. With
+        ``robs`` (a dispatch of a request no worker has served yet: not
+        the resume of a broken stream) the master's share of the time to
+        the first token rides along (``_RequestObs.forward_headers``)."""
+        headers = {REQUEST_ID_HEADER: req.service_request_id}
+        if robs is not None:
+            headers.update(robs.forward_headers())
+        return headers
 
     # -- re-dispatch ------------------------------------------------------
     def _redispatch(self, req: SchedRequest, fwd: Dict[str, Any],
@@ -566,7 +592,7 @@ class HttpService:
 
     def _send_with_redispatch(self, req: SchedRequest,
                               fwd: Dict[str, Any], target: str,
-                              path: str):
+                              path: str, robs: _RequestObs):
         """One JSON forward with redispatch on refusal-class outcomes
         ONLY (503 status / refused connection) — shared by the
         non-stream relay and the RPC ack so their retry policies cannot
@@ -582,7 +608,7 @@ class HttpService:
                 status, resp = http_json(
                     "POST", target, path, fwd,
                     timeout=self.opts.request_timeout_s,
-                    headers=self._fwd_headers(req))
+                    headers=self._fwd_headers(req, robs))
             except ConnectionRefusedError as e:
                 last_exc = e
                 failed.add(self._routed_name(fwd))
@@ -650,7 +676,7 @@ class HttpService:
                     status, body = http_stream_status(
                         "POST", target, path, fwd,
                         timeout=self.opts.request_timeout_s,
-                        headers=self._fwd_headers(req))
+                        headers=self._fwd_headers(req, robs))
                 except Exception as e:  # noqa: BLE001
                     # Refusal-class failures (see _redispatch) — plus,
                     # for recoverable streams, any pre-header transport
@@ -771,7 +797,7 @@ class HttpService:
         robs.dispatched(target)
         try:
             status, resp = self._send_with_redispatch(req, fwd, target,
-                                                      path)
+                                                      path, robs)
         except Exception as e:  # noqa: BLE001 — worker unreachable
             self.scheduler.finish_request(req.service_request_id,
                                           cancelled=True)
@@ -1043,7 +1069,7 @@ class HttpService:
         robs.dispatched(target)
         try:
             status, ack = self._send_with_redispatch(req, fwd, target,
-                                                     path)
+                                                     path, robs)
             if status != 200:
                 raise RuntimeError(f"worker returned {status}: {ack}")
         except Exception as e:  # noqa: BLE001
