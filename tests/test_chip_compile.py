@@ -58,12 +58,13 @@ def _latent_pool(sds):
     return sds((L, P, PS, MLA_D), jnp.bfloat16)
 
 
-def _latent_attention(sds):
+def _latent_attention(sds, b=B_DEC, hq=16, mp=MP, pool=None):
     from xllm_service_tpu.ops.pallas.latent import latent_decode_attention
     return (functools.partial(latent_decode_attention, scale=0.07,
                               interpret=False),
-            (sds((B_DEC, 16, MLA_D), jnp.bfloat16), _latent_pool(sds),
-             sds((B_DEC, MP), jnp.int32), sds((B_DEC,), jnp.int32),
+            (sds((b, hq, MLA_D), jnp.bfloat16),
+             _latent_pool(sds) if pool is None else sds(pool, jnp.bfloat16),
+             sds((b, mp), jnp.int32), sds((b,), jnp.int32),
              sds((), jnp.int32)), {})
 
 
@@ -137,6 +138,13 @@ KERNELS = {
     # What a write-then-attend decode step of a latent model runs since
     # PR 36 (ops/pallas/latent.py): the pool as [L, P, ps, 576].
     "latent-decode-attention": _latent_attention,
+    # ... and at the benchmark cell's shapes (joyai-flash-docqa32: 32
+    # rows, 32 heads, a table of 96, the pool of 1,888 pages of 5
+    # layers), where a grid step folds a block of 8 pages (ops/plan.py
+    # ``latent_fold_pages``): a block that does not fit VMEM fails here.
+    "latent-decode-attention[cell]": functools.partial(
+        _latent_attention, b=32, hq=32, mp=96,
+        pool=(5, 1888, PS, MLA_D)),
     "latent-decode-kv-writer": _latent_writer,
 }
 

@@ -172,6 +172,100 @@ def test_the_latent_decode_kernel_attends_what_the_gather_attends():
                                    atol=2e-5)
 
 
+# (page size, row width, table columns) -> pages a grid step in float32,
+# in bfloat16 (ops/plan.py ``latent_fold_pages``): one column is one page
+# a step; a table of four folds whole; a table of six folds four and then
+# two, the block's last two columns past the table; a float32 page of
+# 1.3 MB folds alone under a table of three, and in bfloat16 two by two.
+FOLD_SHAPES = {
+    "K1-one-column": (16, 40, 1, 1, 1),
+    "K4-divides-4": (16, 40, 4, 4, 4),
+    "K4-of-6-columns": (16, 40, 6, 4, 4),
+    "K1-or-2-large-page": (512, 576, 3, 1, 2),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", sorted(FOLD_SHAPES))
+def test_the_latent_decode_kernel_folds_a_block_of_pages_a_grid_step(
+        shape, dtype):
+    """``latent_decode_attention`` against the hand reference (softmax(q
+    . rows) . rows of the rows each table names, in float64) where a grid
+    step folds K pages: contexts of nothing, one row, one short of a
+    page, exactly a block, one past a block and the whole table; dead
+    columns name page 0, whose rows are large and must weigh nothing; the
+    layer index traced."""
+    from xllm_service_tpu.ops.pallas.latent import latent_decode_attention
+    from xllm_service_tpu.ops.plan import latent_fold_pages
+    ps, D, MP, *pages = FOLD_SHAPES[shape]
+    K = pages[dtype == "bfloat16"]
+    dt = jnp.dtype(dtype)
+    assert latent_fold_pages(ps, D, dt.itemsize, MP) == K
+    whole = MP * ps
+    ctx = np.asarray([0, 1, ps - 1, min(K * ps, whole),
+                      min(K * ps + 1, whole), whole], np.int32)
+    B, Hq, L = len(ctx), 4, 2
+    rng = np.random.default_rng(MP)
+    P = 1 + B * MP
+    pool = rng.normal(size=(L, P, ps, D)) * 0.5
+    pool[:, 0] = 1e3
+    table = np.zeros((B, MP), np.int32)
+    for b, n in enumerate(ctx):
+        live = -(-int(n) // ps)
+        table[b, :live] = 1 + b * MP + rng.permutation(MP)[:live]
+    pool = jnp.asarray(pool, dt)
+    q = jnp.asarray(rng.normal(size=(B, Hq, D)) * 0.3, dt)
+    out = jax.jit(lambda li: latent_decode_attention(
+        q, pool, jnp.asarray(table), jnp.asarray(ctx), li, scale=0.3,
+        interpret=True))(jnp.asarray(1, jnp.int32))
+    assert out.shape == (B, Hq, D) and out.dtype == dt
+    out = np.asarray(out, np.float64)
+    stored, qs = np.asarray(pool, np.float64), np.asarray(q, np.float64)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    for b, n in enumerate(ctx):
+        if n == 0:
+            assert not out[b].any()
+            continue
+        rows = stored[1][table[b]].reshape(-1, D)[:n]
+        lg = qs[b] @ rows.T * 0.3
+        w = np.exp(lg - lg.max(-1, keepdims=True))
+        want = (w / w.sum(-1, keepdims=True)) @ rows
+        np.testing.assert_allclose(out[b], want, rtol=tol, atol=tol)
+
+
+def test_the_fold_is_what_the_shapes_say_and_the_plan_line_names_it(
+        monkeypatch, caplog):
+    """K comes from shapes in ONE function (ops/plan.py
+    ``latent_fold_pages``): the kernel reads it there and so does the
+    engine's plan line. At the benchmark cell's shapes (pages of 128 rows
+    of 576 bfloat16 values in 640 lanes, a table of 96) the
+    double-buffered block of 8 pages is 2.6 MB; no block is wider than
+    its table; a model without latent attention says nothing of it."""
+    import logging
+    from xllm_service_tpu.ops.plan import latent_fold_pages
+    assert latent_fold_pages(128, 576, 2, 96) == 8
+    assert [latent_fold_pages(128, 576, 2, mp)
+            for mp in (1, 2, 3, 4, 8, 64)] == [1, 2, 2, 4, 8, 8]
+    assert latent_fold_pages(16, 40, 4, 4) == 4
+    assert latent_fold_pages(128, 576, 4, 96) == 4     # float32 rows
+    monkeypatch.setenv("XLLM_PALLAS", "1")
+    with caplog.at_level(logging.INFO,
+                         logger="xllm_service_tpu.runtime.engine"):
+        eng = tiny_engine()
+        dense = Engine(ModelConfig.tiny(), EngineConfig(
+            page_size=16, num_pages=32, max_model_len=256,
+            max_batch_size=2), seed=0)
+    assert eng.plan.latent_decode and not dense.plan.latent_decode
+    mp = eng.ecfg.max_pages_per_seq
+    pages = latent_fold_pages(16, eng.kv[0].shape[-1], 4, mp)
+    assert mp == 16 and pages == 16
+    lines = [m for m in caplog.messages if m.startswith("engine plan:")]
+    assert [m.rsplit("; ", 1)[1] for m in lines] == [
+        "latent fold 16 pages a grid step, 1 steps of 16 columns",
+        "decode walk 16 of 16 columns"]
+    assert "decode walk 16 of 16 columns; latent fold" in lines[0]
+
+
 def test_the_latent_writer_writes_a_steps_rows_and_nothing_else():
     from xllm_service_tpu.ops.pallas.latent import latent_kv_update_layer
     pool, _, table = _latent_case()

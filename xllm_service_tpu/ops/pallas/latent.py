@@ -12,10 +12,20 @@ the pool so that the reshape moves nothing
 ``latent_kv_update_layer`` writes a decode step's new rows in place
 (the pool aliased to the output), ``latent_decode_attention`` then
 attends from the pool, the new row included: the write-then-attend
-layer body of ``models/transformer.py`` ``_mla_forward_decode``. One DMA
-a page, no transpose: ``q [Hq, D] x page [ps, D]`` gives the logits and
-``prob [Hq, ps] x page`` the weighted rows (the caller keeps their first
-``kv_lora_rank`` columns).
+layer body of ``models/transformer.py`` ``_mla_forward_decode``.
+
+A grid step of ``latent_decode_attention`` folds a BLOCK of K pages of
+one row (PR 41): the pool is passed K times with K one-page blocks, which
+Pallas double-buffers, operand j of step p reading table column
+``p * K + j``. No transpose and no f32 copy: ``q [Hq, D] x page [ps, D]``
+gives a page's logits and ``prob [Hq, ps] x page`` its weighted rows (the
+caller keeps their first ``kv_lora_rank`` columns), the operands in the
+pool's own type, summed in f32; ONE online-softmax update a block, so the
+K pages' matmuls overlap and hide under the block's DMA. K comes from
+shapes (``ops/plan.py`` ``latent_fold_pages``: 8 at pages of 128 rows of
+576 bfloat16 values). On the chip at the benchmark cell's shapes a grid
+step costs 0.36 us that no copy hides, so one page a step ran at 0.59 us
+a page against a DMA of 0.20; 8 pages a step run at 0.26 (PERF.md, PR 41).
 """
 
 import functools
@@ -27,13 +37,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 from xllm_service_tpu.ops.pallas._compat import (
     CompilerParams as _CompilerParams)
+from xllm_service_tpu.ops.plan import latent_fold_pages
 
 _NEG_INF = -1e30
 _DROP = -1
 
 
-def _attend_kernel(ctx_ref, pt_ref, lyr_ref, q_ref, page_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, page_size: int, scale: float):
+def _attend_kernel(ctx_ref, pt_ref, lyr_ref, q_ref, *refs, pages: int,
+                   page_size: int, scale: float):
+    """One grid step folds a BLOCK of ``pages`` pages of row ``b``: one
+    online-softmax update over the block's [Hq, pages * ps] logits."""
+    page_refs = refs[:pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[pages:]
     b = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -44,34 +59,63 @@ def _attend_kernel(ctx_ref, pt_ref, lyr_ref, q_ref, page_ref, o_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     ctx = ctx_ref[b]
-    page_start = p * page_size
+    block_start = p * pages * page_size
 
-    @pl.when(page_start < ctx)
+    @pl.when(block_start < ctx)
     def _fold():
-        q = q_ref[0].astype(jnp.float32)                     # [Hq, D]
-        rows = page_ref[0, 0].astype(jnp.float32)            # [ps, D]
-        logits = jax.lax.dot_general(
-            q, rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [Hq, ps]
-        pos = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        mask = pos < ctx
-        logits = jnp.where(mask, logits, _NEG_INF)
+        # Operands in the pool's own type, products summed in f32: the
+        # MXU rounds an f32 operand to bf16 anyway.
+        rows = [r[0, 0] for r in page_refs]                  # [ps, D] each
+        q = q_ref[0].astype(rows[0].dtype)                   # [Hq, D]
+        # A column past the context or past the table holds a page read
+        # before (``_fold_table``): masked, weight 0.
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+        logits = [jnp.where(
+            block_start + j * page_size + lane < ctx,
+            jax.lax.dot_general(q, r, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale,
+            _NEG_INF) for j, r in enumerate(rows)]           # [Hq, ps] each
+        # ONE update a block: the pages' logits meet lane by lane, then
+        # one reduce across lanes for the max and one for the sum.
         m_prev = m_ref[:]                                    # [Hq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1,
-                                            keepdims=True))
-        prob = jnp.where(mask, jnp.exp(logits - m_new), 0.0)
+        m_new = jnp.maximum(m_prev, jnp.max(
+            functools.reduce(jnp.maximum, logits), axis=-1, keepdims=True))
+        # the block holds a live position, so a masked one gives exp = 0
+        probs = [jnp.exp(lg - m_new) for lg in logits]
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(prob, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            prob, rows, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [Hq, D]
+        l_ref[:] = l_ref[:] * corr + jnp.sum(
+            functools.reduce(jnp.add, probs), axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + functools.reduce(jnp.add, [
+            jax.lax.dot_general(pr.astype(r.dtype), r,
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for pr, r in zip(probs, rows)])                  # [Hq, D]
         m_ref[:] = m_new
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _finalize():
         o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
                     ).astype(o_ref.dtype)
+
+
+def _fold_table(page_table: jnp.ndarray, context_lens: jnp.ndarray,
+                page_size: int, pages: int) -> jnp.ndarray:
+    """The table as the grid reads it: operand j of grid step p takes
+    column ``p * pages + j``, so the width is padded to whole blocks, and
+    a column past a row's context (``context_lens``: no more than the
+    table holds) names the page its operand read LAST: the same block
+    index as the step before, for which Pallas issues no copy. A dead
+    column costs no DMA and the index maps no arithmetic (on the chip,
+    PERF.md PR 41: 3.07 ms a step of the cell against 3.12 with dead
+    columns fetched and 3.32 with this arithmetic inside the index
+    maps)."""
+    MP = page_table.shape[1]
+    cols = jnp.arange(pl.cdiv(MP, pages) * pages, dtype=jnp.int32)[None]
+    live = pl.cdiv(context_lens, page_size)[:, None]
+    j, p = cols % pages, cols // pages
+    last = jnp.maximum((live - 1 - j) // pages, 0)
+    return jnp.take_along_axis(page_table,
+                               jnp.minimum(p, last) * pages + j, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -88,25 +132,30 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
     B, Hq, D = q.shape
     page_size = pool.shape[2]
     MP = page_table.shape[1]
+    K = latent_fold_pages(page_size, D, pool.dtype.itemsize, MP)
+    # no position lies past the table, whatever a row's length says
+    ctx = jnp.minimum(context_lens.astype(jnp.int32), MP * page_size)
 
     def row(ix):
         return lambda b, p, ctx, pt, lyr: ix(b)
 
+    def page(j):
+        # straight out of the FULL pool: no per-layer slice exists for
+        # XLA to materialize
+        return pl.BlockSpec(
+            (1, 1, page_size, D),
+            lambda b, p, ctx, pt, lyr: (lyr[0], pt[b, p * K + j], 0, 0))
+
     return pl.pallas_call(
-        functools.partial(_attend_kernel, page_size=page_size,
+        functools.partial(_attend_kernel, pages=K, page_size=page_size,
                           scale=scale),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,              # ctx, page table, layer
-            grid=(B, MP),
-            in_specs=[
-                pl.BlockSpec((1, Hq, D), row(lambda b: (b, 0, 0))),
-                # straight out of the FULL pool: no per-layer slice
-                # exists for XLA to materialize
-                pl.BlockSpec((1, 1, page_size, D),
-                             lambda b, p, ctx, pt, lyr: (
-                                 lyr[0], pt[b, p], 0, 0)),
-            ],
+            grid=(B, pl.cdiv(MP, K)),
+            in_specs=[pl.BlockSpec((1, Hq, D), row(lambda b: (b, 0, 0))),
+                      # the pool K times: Pallas double-buffers each page
+                      *(page(j) for j in range(K))],
             out_specs=pl.BlockSpec((1, Hq, D), row(lambda b: (b, 0, 0))),
             scratch_shapes=[
                 pltpu.VMEM((Hq, 1), jnp.float32),    # running max
@@ -116,8 +165,8 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
         compiler_params=_CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(context_lens.astype(jnp.int32), page_table,
-      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
+    )(ctx, _fold_table(page_table, ctx, page_size, K),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, *([pool] * K))
 
 
 def _update_kernel(slot_ref, lyr_ref, new_ref, in_ref, out_ref, *,

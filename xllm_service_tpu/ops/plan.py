@@ -159,6 +159,28 @@ def decode_walk_columns(table_width: int, page_size: int,
     return table_width
 
 
+# What the double-buffered block of pages that one grid step of the
+# latent decode kernel folds may take of VMEM (of 16 MiB a kernel may
+# use by default; decided on the chip, PERF.md, PR 41).
+_LATENT_FOLD_VMEM_BYTES = 4 << 20
+
+
+def latent_fold_pages(page_size: int, width: int, itemsize: int,
+                      table_width: int) -> int:
+    """Pages of a row that ONE grid step of the latent decode kernel
+    folds (ops/pallas/latent.py): the largest power of two whose
+    double-buffered block, a page's ``width`` values riding whole
+    128-lane tiles, stays under ``_LATENT_FOLD_VMEM_BYTES``, and no more
+    than the table has columns. From shapes alone: the kernel and the
+    engine's plan line both read it here."""
+    page = page_size * -(-width // 128) * 128 * itemsize
+    pages = 1
+    while (2 * pages <= table_width
+           and 2 * (2 * pages) * page <= _LATENT_FOLD_VMEM_BYTES):
+        pages *= 2
+    return pages
+
+
 def _on_tpu() -> bool:
     # A backend that fails to initialise raises here: "no TPU" must not
     # be how a broken TPU run looks (it would switch the kernels off and

@@ -46,7 +46,8 @@ from jax.experimental.layout import Format, Layout
 from xllm_service_tpu.config import EngineConfig, ModelConfig
 from xllm_service_tpu.models import transformer
 from xllm_service_tpu.obs import steptrace
-from xllm_service_tpu.ops.plan import KernelPlan, decode_walk_columns
+from xllm_service_tpu.ops.plan import (
+    KernelPlan, decode_walk_columns, latent_fold_pages)
 from xllm_service_tpu.parallel.expert import MOE_STATS
 from xllm_service_tpu.ops.sampling import (
     SamplingTensors, compute_logprobs, compute_top_logprobs, sample_tokens,
@@ -346,11 +347,21 @@ class Engine:
             if self.plan.decode_attn and model_cfg.layer_sliding is None
             and not model_cfg.mla else 0)
         kinds = model_cfg.layer_kinds or ()
-        logger.info("engine plan: %s; decode walk %d of %d columns%s",
+        fold = ""
+        if self.plan.latent_decode:
+            # pages a grid step of the latent decode kernel, from the
+            # function the kernel reads it from (ops/pallas/latent.py)
+            pages = latent_fold_pages(
+                engine_cfg.page_size, self.kv[0].shape[-1],
+                self.kv[0].dtype.itemsize, MP)
+            fold = (f"; latent fold {pages} pages a grid step, "
+                    f"{-(-MP // pages)} steps of {MP} columns")
+        logger.info("engine plan: %s; decode walk %d of %d columns%s%s",
                     self.plan, self._decode_walk(MP), MP,
                     "; layer kinds " + ", ".join(
                         f"{k} {kinds.count(k)}"
-                        for k in dict.fromkeys(kinds)) if kinds else "")
+                        for k in dict.fromkeys(kinds)) if kinds else "",
+                    fold)
         if self.plan.uses_kernels:
             # The kernels are loaded here (1.2-1.6 s of
             # jax.experimental.pallas), where an engine is built, and
