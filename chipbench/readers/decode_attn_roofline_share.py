@@ -22,7 +22,7 @@ def read(ctx, info):
     kernel_s = sum(e["dur"] for e in ops) / 1e9
     if kernel_s <= 0:
         return None
-    cost = spec.load_kernel_cost(info["kernel_cost"])
+    cost = spec.load_kernel_cost(info["kernel_cost"], ctx["root"])
     peaks = spec.peaks_for(ctx["device_kind"], ctx["root"])
     off = ctx["wall_minus_mono"]
     flops = bytes_ = 0.0

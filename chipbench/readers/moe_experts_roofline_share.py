@@ -41,7 +41,8 @@ def read(ctx, info):
         if not trace.CONTAINERS.match(e["name"])) / 1e9
     if counts is None or kernel_s <= 0:
         return None
-    flops, bytes_ = spec.load_kernel_cost(info["kernel_cost"]).cost(
+    flops, bytes_ = spec.load_kernel_cost(info["kernel_cost"],
+                                          ctx["root"]).cost(
         counts[0], counts[1], ctx["config"])
     peaks = spec.peaks_for(ctx["device_kind"], ctx["root"])
     least_s = max(flops / peaks["bf16_flops"], bytes_ / peaks["hbm_bytes_s"])

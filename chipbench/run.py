@@ -115,8 +115,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = parse(argv)
     if args.rehearse_cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
-        os.environ.setdefault("XLLM_PEAK_FLOPS", "1e11")
-        os.environ.setdefault("XLLM_PEAK_BW_GBPS", "50")
     cell = spec.load_cell(args.workload, ROOT)
     config = dict(cell.config)
     if args.override:
@@ -394,7 +392,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             info = spec.layer_metric_file(m["name"], ROOT)
             if args.rehearse_cpu and info["source"] != "program_counter":
                 continue        # a CPU run reports counts, never a time
-            value = spec.load_reader(info["reader"]).read(ctx, info)
+            value = spec.load_reader(info["reader"], ROOT).read(ctx, info)
             if value is not None:
                 metrics[m["name"]] = {"value": float(value),
                                       "unit": m["unit"]}

@@ -9,7 +9,7 @@ adds files and entries and edits nothing here.
 from __future__ import annotations
 
 import dataclasses
-import importlib
+import importlib.util
 import json
 import os
 from typing import Any, Dict, List, Optional
@@ -77,14 +77,32 @@ def layer_metric_file(name: str, root: str = ROOT) -> Dict[str, Any]:
                                   name + ".json"))
 
 
-def load_reader(name: str):
-    """The reader module of a per-layer metric: ``read(ctx) -> float |
-    None`` in ``chipbench/readers/<reader>.py``."""
-    return importlib.import_module(f"chipbench.readers.{name}")
+def _load_by_path(module_name: str, path: str) -> Any:
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
-def load_kernel_cost(name: str):
-    return importlib.import_module(f"chipbench.kernel_costs.{name}")
+def _load_named(kind: str, name: str, root: str) -> Any:
+    """``chipbench/<kind>/<name>.py`` under ``root``, by path as a
+    configuration's files are: what a later PR adds is found in the root
+    it was added to, with no package index to touch."""
+    return _load_by_path(f"chipbench_{kind}_{name}", os.path.join(
+        root, "chipbench", kind, name + ".py"))
+
+
+def load_reader(name: str, root: str = ROOT):
+    """The reader module of a per-layer metric: ``read(ctx, info) -> float
+    | None`` in ``chipbench/readers/<reader>.py``."""
+    return _load_named("readers", name, root)
+
+
+def load_kernel_cost(name: str, root: str = ROOT):
+    """``cost(...) -> (flops, bytes)`` in
+    ``chipbench/kernel_costs/<name>.py``; a reader passes its
+    ``ctx["root"]``."""
+    return _load_named("kernel_costs", name, root)
 
 
 def _load_beside_config(cell_or_dir, what: str) -> Any:
@@ -92,13 +110,9 @@ def _load_beside_config(cell_or_dir, what: str) -> Any:
     path, so that a configuration added later brings its own without
     touching a package index."""
     d = cell_or_dir if isinstance(cell_or_dir, str) else cell_or_dir.config_dir
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
+    return _load_by_path(
         f"chipbench_{what}_" + os.path.basename(d).replace("-", "_")
         .replace(".", "_"), os.path.join(d, what + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def load_reference(cell_or_dir) -> Any:

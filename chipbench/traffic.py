@@ -130,23 +130,42 @@ def _open_loop(mix, rng, seconds, vocab, n_docs) -> List[Dict[str, Any]]:
 
 def _closed_loop(mix, rng, seconds, vocab, n_docs) -> List[Dict[str, Any]]:
     """Each client's list of requests, sent one after another. Round k of
-    all clients together holds every stratum exactly once (client c takes
-    stratum (c + k) mod K after a seeded relabelling), so the work of any
-    stretch of the run is the same for every seed."""
+    all clients together holds every stratum exactly once, so the work of
+    any stretch of the run is the same for every seed.
+
+    How a round's strata go to the clients follows from the number of
+    clients K and the window, with no key in the mix. Where the window
+    holds two whole cycles of K rounds or more (by the mix's own ceiling,
+    ``seconds * max_rounds_per_s``), client c takes stratum (c + k) mod K
+    of the questions and (c + 3k) mod K of the answers after ONE seeded
+    relabelling: every client walks the same cycle of lengths, after K
+    rounds all have done the same work, and every seed reads alike (8
+    clients in 51 s: the cycle closes every 18 s). Where it holds fewer
+    (32 or 64 clients: half a cycle or less), which clients finish an
+    iteration apart, and for how many rounds, would be the relabelling's,
+    so each round is dealt by a permutation of its own (PR 42; PERF.md
+    section 6 has what each dealing read in each cell)."""
     K = int(mix["clients"])
     total = float(mix["ramp_s"]) + seconds + float(mix.get("tail_s", 0.0))
     rounds = int(math.ceil(total * float(mix["max_rounds_per_s"]))) + 1
     q_len, a_len = strata(mix["prompt_tokens"], K), \
         strata(mix["output_tokens"], K)
+    cyclic = seconds * float(mix["max_rounds_per_s"]) >= 2 * K
+    # drawn under either dealing: the sets of PR 42 were measured so
     q_perm, a_perm = rng.permutation(K), rng.permutation(K)
     reqs: List[Dict[str, Any]] = []
     for k in range(rounds):
+        if cyclic:
+            q_deal = [q_perm[(c + k) % K] for c in range(K)]
+            a_deal = [a_perm[(c + 3 * k) % K] for c in range(K)]
+        else:
+            q_deal, a_deal = rng.permutation(K), rng.permutation(K)
         for c in range(K):
             reqs.append({
                 "id": f"c{c}k{k}", "client": c, "order": k, "due": None,
                 "doc": ((c + k) % n_docs) if n_docs else None,
-                "tokens": _tokens(rng, q_len[q_perm[(c + k) % K]], vocab),
-                "max_tokens": int(a_len[a_perm[(c + 3 * k) % K]])})
+                "tokens": _tokens(rng, q_len[q_deal[c]], vocab),
+                "max_tokens": int(a_len[a_deal[c]])})
     return reqs
 
 

@@ -28,13 +28,23 @@ REPO = os.path.dirname(os.path.dirname(HERE))
 FIXTURES = os.path.join(HERE, "fixtures")
 
 
-def copy_benchmark(root: str) -> None:
-    """``BENCHMARK.json`` and ``chipbench/`` as the repo has them."""
+def copy_benchmark(root: str, src: str = REPO) -> None:
+    """``BENCHMARK.json`` and ``chipbench/`` as the repo has them (or as
+    another root ``src`` has them: a copy that a cell was added to)."""
     os.makedirs(root, exist_ok=True)
-    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(REPO, "chipbench"),
+    shutil.copy(os.path.join(src, "BENCHMARK.json"), root)
+    shutil.copytree(os.path.join(src, "chipbench"),
                     os.path.join(root, "chipbench"),
                     ignore=shutil.ignore_patterns("__pycache__"))
+
+
+def mtimes(root: str) -> dict:
+    """Every file under ``root`` but ``BENCHMARK.json`` (the one file an
+    addition lengthens) with its modification time: equal before and
+    after means no copied file was edited."""
+    return {os.path.join(dp, f): os.path.getmtime(os.path.join(dp, f))
+            for dp, _, fs in os.walk(root) for f in fs
+            if f != "BENCHMARK.json"}
 
 
 def add_fixture(root: str) -> str:

@@ -33,12 +33,6 @@ def tiny_config():
     return cfg
 
 
-def mtimes(root):
-    return {os.path.join(dp, f): os.path.getmtime(os.path.join(dp, f))
-            for dp, _, fs in os.walk(root) for f in fs
-            if f != "BENCHMARK.json"}
-
-
 def test_the_fixture_is_in_no_benchmark_of_the_repo():
     bench = open(os.path.join(spec.ROOT, "BENCHMARK.json")).read()
     entries = spec.load_json(os.path.join(HERE, "fixtures", "entries.json"))
@@ -49,18 +43,19 @@ def test_the_fixture_is_in_no_benchmark_of_the_repo():
         spec.ROOT, "chipbench", "configs", entries["config"]["name"]))
 
 
-def test_another_family_arrives_as_new_files_only(tmp_path):
+def test_another_family_arrives_as_new_files_only(tmp_path, root):
     """The sibling of ``test_a_cell_arrives_as_new_files_only``, with a
-    family the harness has never held: copy the benchmark, ADD the
+    family the harness has never held: copy the benchmark (as committed,
+    and as grown by a cell already: ``conftest.py``'s ``root``), ADD the
     fixture's files and entries, and see the harness find its weights,
     its reference, its kinds, its tiny widths, its mix and its metrics,
     with no copied file's mtime changed."""
-    root = str(tmp_path / "copy")
-    fixture_root.copy_benchmark(root)
-    before = mtimes(root)
+    src, root = root, str(tmp_path / "copy")
+    fixture_root.copy_benchmark(root, src)
+    before = fixture_root.mtimes(root)
     old_bench = spec.load_json(os.path.join(root, "BENCHMARK.json"))
     name = fixture_root.add_fixture(root)
-    after = mtimes(root)
+    after = fixture_root.mtimes(root)
     assert all(after[p] == t for p, t in before.items())
     added = sorted(os.path.relpath(p, root) for p in set(after) - set(before))
     assert added == sorted(

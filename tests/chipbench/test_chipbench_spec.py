@@ -3,14 +3,14 @@ a cell, a configuration, a mix, a per-layer metric and a kernel cost
 function can each be added as new files, with no edit to one that is
 there."""
 
-import json
 import os
 import re
-import shutil
 import sys
 
 import pytest
 
+import fixture_root            # beside this file (pytest prepends its directory)
+import grown_root
 from chipbench import spec
 
 ROOT = spec.ROOT
@@ -20,17 +20,20 @@ WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj).*size|_dim$|"
                    r"_rank$|head_dim|expan|experts_per_tok")
 
 
-@pytest.fixture(scope="module")
-def bench():
-    return spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+@pytest.fixture
+def bench(root):
+    """``BENCHMARK.json`` as committed and grown by a cell, in turn
+    (``conftest.py``): no test here may count the cells, look at the last
+    entry or name what every cell reports."""
+    return spec.load_json(os.path.join(root, "BENCHMARK.json"))
 
 
-def test_top_level_keys_and_sizes(bench):
+def test_top_level_keys_and_sizes(bench, root):
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer",
                           "trace_in_run"}
     assert bench["trace_in_run"] is True    # the driver passes 0 and 2
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 65536
+    assert os.path.getsize(os.path.join(root, "BENCHMARK.json")) < 65536
     assert bench["paths"] == ["chipbench", "tests/chipbench"]
     assert 1 <= bench["run_seconds"] <= 51
     assert (2 + 14 * 24) * (bench["run_seconds"] + 60) + 24 * 180 + 1200 \
@@ -66,7 +69,7 @@ def test_names_units_and_lines(bench):
                           "moves", "workloads"}
 
 
-def test_cells_configs_and_what_each_reports(bench):
+def test_cells_configs_and_what_each_reports(bench, root):
     cells = {w["name"]: w for w in bench["workloads"]}
     configs = {c["name"]: c for c in bench["configs"]}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
@@ -75,38 +78,43 @@ def test_cells_configs_and_what_each_reports(bench):
     assert {w["config"] for w in cells.values()} == set(configs)
     assert sum(w["chips"] == 4 for w in cells.values()) \
         <= max(1, len(cells) // 4)
+    assert len({c["file"] for c in configs.values()}) == len(configs)
     for c in configs.values():
         assert c["file"].startswith("chipbench/")
-        assert os.path.exists(os.path.join(ROOT, c["file"]))
+        assert os.path.exists(os.path.join(root, c["file"]))
         assert os.path.exists(os.path.join(
-            ROOT, os.path.dirname(c["file"]), "reference.py"))
+            root, os.path.dirname(c["file"]), "reference.py"))
         assert len(c["reduced"]) <= 16
         assert not any(WIDTH.search(k) for k in c["reduced"])
         meta = spec.load_json(os.path.join(
-            ROOT, os.path.dirname(c["file"]), "meta.json"))
+            root, os.path.dirname(c["file"]), "meta.json"))
         assert meta["source"] == c["source"]
         assert meta["reduced"] == c["reduced"]
         # what run.py takes from the configuration and holds no table of
-        config = spec.load_json(os.path.join(ROOT, c["file"]))
+        config = spec.load_json(os.path.join(root, c["file"]))
         assert meta["rehearsal_widths"] \
             and set(meta["rehearsal_widths"]) <= set(config)
         assert meta["step_programs_from_cache"] in (True, False)
         assert os.path.exists(os.path.join(
-            ROOT, os.path.dirname(c["file"]), "weights.py"))
+            root, os.path.dirname(c["file"]), "weights.py"))
     for name in cells:
-        cell = spec.load_cell(name)
+        cell = spec.load_cell(name, root)
         mine = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in mine and len(mine) >= 2
         assert cell.per_layer
         for m in cell.per_layer:
             assert m["moves"] in mine, (name, m["name"])
-            info = spec.layer_metric_file(m["name"])
+            info = spec.layer_metric_file(m["name"], root)
             for key in ("layer", "unit", "source", "moves", "better"):
                 assert info[key] == m[key], (m["name"], key)
-            assert hasattr(spec.load_reader(info["reader"]), "read")
+            assert hasattr(spec.load_reader(info["reader"], root), "read")
+            if "kernel_cost" in info:
+                assert hasattr(spec.load_kernel_cost(info["kernel_cost"],
+                                                     root), "cost")
     for m in bench["per_layer"]:
         assert m["moves"] in e2e
         for w in m.get("workloads", cells):
+            assert w in cells, (m["name"], w)
             scope = e2e[m["moves"]].get("workloads")
             assert scope is None or w in scope
 
@@ -126,70 +134,69 @@ def test_published_widths_are_untouched():
 
 
 def test_a_cell_arrives_as_new_files_only(tmp_path):
-    """Copy the benchmark, add one configuration, one mix, one per-layer
-    metric with its reader, one kernel cost function and one cell, all
-    as new files plus new entries, and see the harness find each."""
-    root = tmp_path / "copy"
-    shutil.copytree(os.path.join(ROOT, "chipbench"), root / "chipbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = {p: os.path.getmtime(os.path.join(dp, p))
-              for dp, _, fs in os.walk(root) for p in fs}
-    bench = spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    src = root / "chipbench" / "configs" / "mistral-7b-v03"
-    new = root / "chipbench" / "configs" / "new-model"
-    shutil.copytree(src, new)
-    mix = spec.load_json(root / "chipbench" / "traffic" / "chat.json")
-    mix["rate_rps"] = 1.0
-    (root / "chipbench" / "traffic" / "trickle.json").write_text(
-        json.dumps(mix))
-    (root / "chipbench" / "layer_metrics" / "late_ms.json").write_text(
-        json.dumps({"name": "late_ms", "layer": "device", "unit": "ms",
-                    "better": "lower", "source": "host_clock",
-                    "moves": "ttft_p50_ms", "reader": "late_ms"}))
-    (root / "chipbench" / "readers" / "late_ms.py").write_text(
-        "def read(ctx, info):\n    return 1.5\n")
-    (root / "chipbench" / "kernel_costs" / "matmul.py").write_text(
-        "def cost(m, n, k):\n    return 2.0 * m * n * k, 2.0 * (m*k + k*n + m*n)\n")
-    bench["configs"].append({
-        "name": "new-model", "source": "https://example.org/new",
-        "file": "chipbench/configs/new-model/config.json",
-        "reduced": ["num_hidden_layers"], "why": "a test's"})
-    bench["workloads"].append({
-        "name": "new-model.trickle", "config": "new-model",
-        "traffic": "trickle", "chips": 1, "why": "a test's"})
-    bench["per_layer"].append({
-        "name": "late_ms", "unit": "ms", "better": "lower",
-        "source": "host_clock", "layer": "device", "moves": "ttft_p50_ms",
-        "workloads": ["new-model.trickle"]})
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    cell = spec.load_cell("new-model.trickle", str(root))
+    """Copy the benchmark, add one configuration, one mix, per-layer
+    metrics with their reader, one kernel cost function and one cell, all
+    as new files plus new entries (``grown_root.grow``: what the shape
+    tests' second root is made by), and see the harness find each."""
+    root = str(tmp_path / "copy")
+    fixture_root.copy_benchmark(root)
+    before = fixture_root.mtimes(root)
+    old_bench = spec.load_json(os.path.join(root, "BENCHMARK.json"))
+    grown = grown_root.grow(root)
+    after = fixture_root.mtimes(root)
+    assert all(after[p] == t for p, t in before.items())   # no file edited
+    assert sorted(os.path.relpath(p, root) for p in set(after) - set(before)) \
+        == sorted(grown.files_added)
+    # entries are added, each group's old ones first and as they were;
+    # the one thing lengthened is a list of cells, by the new cell's name
+    bench = spec.load_json(os.path.join(root, "BENCHMARK.json"))
+    for group in ("configs", "workloads"):
+        assert bench[group][:-1] == old_bench[group]
+    for new, old in zip(bench["end_to_end"] + bench["per_layer"],
+                        old_bench["end_to_end"] + old_bench["per_layer"]):
+        assert {**new, "workloads": None} == {**old, "workloads": None}
+        if set(old.get("workloads", ())) == set(grown.cells_before):
+            assert new["workloads"] == old["workloads"] + [grown.cell]
+        else:
+            assert new.get("workloads") == old.get("workloads")
+    assert grown.cell in next(m for m in bench["end_to_end"]
+                              if m["name"] == "out_tok_s")["workloads"]
+    trailing = bench["per_layer"][len(old_bench["per_layer"]):]
+    assert [m["name"] for m in trailing] == list(grown_root.METRICS)
+    assert {m["source"] for m in trailing} == {
+        "device_trace", "program_span", "program_counter", "host_clock"}
+    assert all(m["workloads"] == [grown.cell] for m in trailing)
+    cell = spec.load_cell(grown.cell, root)
     assert cell.traffic["rate_rps"] == 1.0
-    assert cell.config_dir == str(new)
-    assert "late_ms" in [m["name"] for m in cell.per_layer]
-    info = spec.layer_metric_file("late_ms", str(root))
-    sys.path.insert(0, str(root))
+    assert cell.config_dir == os.path.join(root, "chipbench", "configs",
+                                           grown_root.CONFIG)
+    assert set(grown_root.METRICS) <= {m["name"] for m in cell.per_layer}
+    info = spec.layer_metric_file("grown_clock_ms.trickle", root)
+    assert spec.load_reader(info["reader"], root).read({}, info) == 1.5
+    assert spec.load_kernel_cost(grown_root.KERNEL_COST, root).cost(
+        2, 3, 4)[0] == 48.0
+    # and the copy's own harness, run from the copy, finds them by name
+    sys.path.insert(0, root)
     saved = {k: sys.modules.pop(k) for k in list(sys.modules)
              if k == "chipbench" or k.startswith("chipbench.")}
     try:
         import chipbench.spec as copy_spec
-        assert copy_spec.ROOT == str(root)
+        assert copy_spec.ROOT == root
         assert copy_spec.load_reader(info["reader"]).read({}, info) == 1.5
-        assert copy_spec.load_kernel_cost("matmul").cost(2, 3, 4)[0] == 48.0
+        assert copy_spec.load_kernel_cost(
+            grown_root.KERNEL_COST).cost(2, 3, 4)[0] == 48.0
         assert hasattr(copy_spec.load_reference(cell), "forward")
     finally:
         for k in [k for k in sys.modules if k == "chipbench"
                   or k.startswith("chipbench.")]:
             del sys.modules[k]
         sys.modules.update(saved)
-        sys.path.remove(str(root))
-    # the old cell is untouched and still loads from the copy
-    old = spec.load_cell("mistral7b-v01-docqa", str(root))
-    assert "late_ms" not in [m["name"] for m in old.per_layer]
-    after = {p: os.path.getmtime(os.path.join(dp, p))
-             for dp, _, fs in os.walk(root) for p in fs}
-    assert all(after[p] == t for p, t in before.items())   # no file edited
+        sys.path.remove(root)
+    # a cell that was there is untouched and still loads from the copy
+    old = spec.load_cell(grown.cells_before[0], root)
+    assert not set(grown_root.METRICS) & {m["name"] for m in old.per_layer}
 
 
-def test_an_unknown_workload_is_refused():
+def test_an_unknown_workload_is_refused(root):
     with pytest.raises(SystemExit, match="no workload"):
-        spec.load_cell("no-such-cell")
+        spec.load_cell("no-such-cell", root)

@@ -91,6 +91,39 @@ def test_closed_loop_round_holds_every_stratum_once():
             assert sorted(r["client"] for r in reqs) == list(range(K))
 
 
+def walks_shifted(m, seconds=51.0, seed=2**31 + 11):
+    """Pairs of clients of which one walks the other's sequence of answer
+    lengths, up to three rounds behind it."""
+    walks = {}
+    for r in traffic.build(m, seed, seconds, 32000)["requests"]:
+        walks.setdefault(r["client"], []).append(r["max_tokens"])
+    n = min(len(w) for w in walks.values())
+    assert n >= 8
+    return [(c, d, shift) for c in walks for d in walks if c != d
+            for shift in range(4) if walks[c][shift:n] == walks[d][:n - shift]]
+
+
+def test_how_a_rounds_lengths_are_dealt_follows_from_clients_and_window():
+    """No mix states how a round's strata are dealt: where the window
+    holds two whole cycles of K rounds or more by the mix's own ceiling
+    (the Mistral cell's 8 clients in 51 s), one relabelling a run, and
+    client c takes at round k + 1 what client c + 3 took at round k;
+    where it holds fewer (32 and 64 clients; 8 clients in a window of a
+    few seconds) each round is dealt by a permutation of its own and no
+    client walks another's sequence (PERF.md section 6, PR 42)."""
+    for name in ("docqa", "docqa32", "docqa64"):
+        assert "deal" not in mix(name)
+    for name in ("docqa32", "docqa64"):
+        assert walks_shifted(mix(name)) == []
+        assert walks_shifted(dict(mix(name), clients=8))
+    K = mix("docqa")["clients"]
+    assert {(c, d) for c, d, s in walks_shifted(mix("docqa")) if s == 1} \
+        >= {(c, (c + 3) % K) for c in range(K)}
+    ceiling = mix("docqa")["max_rounds_per_s"]
+    assert walks_shifted(mix("docqa"), 2 * K / ceiling)
+    assert walks_shifted(mix("docqa"), 2 * K / ceiling - 1.0) == []
+
+
 def test_docqa_followups_compute_one_bucket():
     """Every follow-up computes (document tail past its last full page)
     + question tokens: more than 128 and at most 256, the one prefill
