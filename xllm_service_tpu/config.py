@@ -137,11 +137,27 @@ class ModelConfig:
     # head_dim**-0.5 (Gemma-2 fixes it at 256 regardless of head_dim).
     query_pre_attn_scalar: Optional[int] = None
     # Gemma family conventions: sqrt(hidden) embedding scale, the
-    # four-norm block (post-attn/post-ffw norms on the SUBLAYER OUTPUT
-    # before the residual add), and tanh-GELU gating in the MLP. The
-    # (1 + weight) RMSNorm convention is normalized away at checkpoint
-    # load (runtime/checkpoint.py adds 1; save subtracts it back).
+    # four-norm block (``four_norm_block``, which this implies), and
+    # tanh-GELU gating in the MLP. The (1 + weight) RMSNorm convention
+    # is normalized away at checkpoint load (runtime/checkpoint.py adds
+    # 1; save subtracts it back).
     gemma: bool = False
+    # The four-norm block alone, for a family that has it without
+    # Gemma's other conventions (Ouro: SiLU, no embedding scale, no
+    # soft-cap): a second norm on each SUBLAYER'S OUTPUT before the
+    # residual add. Read through ``four_norm_block``.
+    four_norm: bool = False
+    # Layer passes (Ouro's ``total_ut_steps``): the whole layer stack
+    # runs this many times over THE SAME weights, the final norm after
+    # every pass, each pass with its own keys and values (cache slot
+    # pass * num_layers + layer: ``kv_cache_layers``). Above 1 the model
+    # holds an exit gate (hidden -> 1, with a bias) that reads each
+    # pass's normed state. 1 = every other model: no loop, no gate.
+    total_ut_steps: int = 1
+    # A row leaves the loop at the first pass whose cumulative exit
+    # probability reaches this. Only 1.0 (every row runs every pass) is
+    # implemented; ``from_hf_config`` refuses anything lower.
+    early_exit_threshold: float = 1.0
     # Gemma-3: sliding (local) layers rotate with their own rope base
     # and WITHOUT the long-context scaling; full (global) layers use
     # rope_theta + rope_scaling. None = single rope base everywhere.
@@ -231,6 +247,25 @@ class ModelConfig:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim",
                                self.hidden_size // self.num_heads)
+        if self.total_ut_steps < 1:
+            raise ValueError(
+                f"total_ut_steps={self.total_ut_steps}: a model runs its "
+                f"layers at least once")
+        if self.total_ut_steps > 1:
+            if self.mla or self.layer_kinds is not None or self.is_moe:
+                raise ValueError(
+                    "a layer loop (total_ut_steps > 1) wraps the dense "
+                    "families' scan only: latent attention, layers that "
+                    "differ in kind and sparse experts have none")
+            if self.early_exit_threshold < 1.0:
+                raise ValueError(
+                    f"early_exit_threshold={self.early_exit_threshold} "
+                    f"is not implemented: rows of one batch that leave "
+                    f"the layer loop at different passes (the skipped "
+                    f"passes' keys and values must still be filled) "
+                    f"have no step program; only a threshold of 1 "
+                    f"(every row runs all {self.total_ut_steps} passes) "
+                    f"is served")
 
     @property
     def is_moe(self) -> bool:
@@ -245,6 +280,24 @@ class ModelConfig:
     @property
     def mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def four_norm_block(self) -> bool:
+        """Does a layer norm each sublayer's OUTPUT before the residual
+        add, besides its input (``post_norm`` on the attention's,
+        ``pre_ff_norm`` / ``post_ff_norm`` around the MLP)?"""
+        return self.gemma or self.four_norm
+
+    @property
+    def looped(self) -> bool:
+        return self.total_ut_steps > 1
+
+    @property
+    def kv_cache_layers(self) -> int:
+        """Leading axis of the (k, v) pools, and what a worker
+        advertises as its cache ids: one slot a layer that keeps keys
+        and values, a PASS."""
+        return self.num_attn_layers * self.total_ut_steps
 
     @property
     def num_attn_layers(self) -> int:
@@ -488,7 +541,7 @@ class ModelConfig:
                      "mixtral", "gemma2", "gemma3", "gemma3_text",
                      "qwen2_vl", "qwen2_5_vl",
                      "qwen3_moe", "deepseek_v2", "deepseek_v3",
-                     "joyai_llm_flash", "gpt_oss", "lfm2_moe")
+                     "joyai_llm_flash", "gpt_oss", "lfm2_moe", "ouro")
         # The latent family: DeepSeek-V2, and the V3 layer (sigmoid
         # scores, selection bias) that JD's JoyAI-LLM-Flash shares.
         _v3 = mt in ("deepseek_v3", "joyai_llm_flash")
@@ -581,6 +634,11 @@ class ModelConfig:
                  "rms_norm_eps": d.get("norm_eps", 1e-5),
                  # Lfm2MoeConfig ties the head to the embedding.
                  "tie_word_embeddings": True, **d}
+        if mt == "ouro" and set(d.get("layer_types") or ()) \
+                - {"full_attention"}:
+            raise ValueError(
+                f"ouro layer_types {sorted(set(d['layer_types']))} is not "
+                f"implemented (full_attention in every layer)")
         layer_sliding = None
         if mt in ("gemma2", "gemma3_text", "gpt_oss"):
             # Alternating local/global layers: HF's layer_types (or the
@@ -686,6 +744,15 @@ class ModelConfig:
                 d.get("query_pre_attn_scalar", 256)
                 if mt in ("gemma2", "gemma3_text") else None),
             gemma=mt in ("gemma2", "gemma3_text"),
+            # ByteDance Ouro: a llama layer with a second norm on each
+            # sublayer's output, the stack run total_ut_steps times over
+            # the same weights (the exit threshold is checked by
+            # ``__post_init__``, which refuses one under 1).
+            four_norm=mt == "ouro",
+            total_ut_steps=(int(d.get("total_ut_steps", 1))
+                            if mt == "ouro" else 1),
+            early_exit_threshold=(float(d.get("early_exit_threshold", 1.0))
+                                  if mt == "ouro" else 1.0),
             rope_local_base_freq=(d.get("rope_local_base_freq", 10000.0)
                                   if mt == "gemma3_text" else None),
             num_experts=(d.get("num_experts", 0)

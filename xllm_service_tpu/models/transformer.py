@@ -4,7 +4,8 @@ One scanned layer body serves every family in ``docs/MODELS.md`` —
 Llama-2/3.x, Qwen2/2.5/3 (+Qwen3-MoE), Phi-3, Mistral (v0.1 sliding
 window and v0.2+), Gemma-2/3 (four-norm blocks, soft-caps, per-layer
 windows and rope bases as traced scan xs), Mixtral, GPT-OSS (attention
-sinks, clamped-GLU experts), the Qwen2/2.5-VL mrope text stacks — plus
+sinks, clamped-GLU experts), the Qwen2/2.5-VL mrope text stacks, Ouro
+(that scan inside a loop over passes: "Layer passes") — plus
 a dedicated multi-head-latent-attention path (DeepSeek-V2/V3/R1) that
 serves a latent pool through the same paged machinery. Design choices
 are TPU-first (SURVEY.md §7.1):
@@ -60,8 +61,9 @@ from xllm_service_tpu.ops.attention import (
 from xllm_service_tpu.ops.plan import KernelPlan
 
 Params = Dict[str, Any]
-# k_pages, v_pages: [L, P, ps, Hkv, Dh]; under latent attention the one
-# latent pool alone (init_kv_cache).
+# k_pages, v_pages: [L, P, ps, Hkv, Dh], L a slot a layer a PASS
+# (ModelConfig.kv_cache_layers); under latent attention the one latent
+# pool alone (init_kv_cache).
 KVCache = Tuple[jnp.ndarray, ...]
 
 
@@ -97,7 +99,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         layers["q_bias"] = jnp.zeros((L, Hq * Dh), dtype)
         layers["k_bias"] = jnp.zeros((L, Hkv * Dh), dtype)
         layers["v_bias"] = jnp.zeros((L, Hkv * Dh), dtype)
-    if cfg.gemma:
+    if cfg.four_norm_block:
         layers["pre_ff_norm"] = jnp.ones((L, D), dtype)
         layers["post_ff_norm"] = jnp.ones((L, D), dtype)
     if cfg.gptoss:
@@ -130,6 +132,10 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w((D, cfg.vocab_size), D)
+    if cfg.looped:
+        # The exit gate: hidden -> 1 with a bias, read off each pass's
+        # normed state (``_exit_gate``).
+        params["exit_gate"] = {"w": w((D,), D), "b": jnp.zeros((), dtype)}
     return params
 
 
@@ -153,7 +159,9 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                  max(cfg.conv_kernel - 1, 1) * cfg.hidden_size)
         return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
                 jnp.zeros(tails, dtype))
-    shape = (cfg.num_layers, num_pages, page_size, cfg.kv_cache_heads,
+    # One slot a layer a PASS (``ModelConfig.kv_cache_layers``): pass p
+    # of a looped model keeps layer l's keys and values at p * L + l.
+    shape = (cfg.kv_cache_layers, num_pages, page_size, cfg.kv_cache_heads,
              cfg.kv_cache_dim)
     if cfg.mla:
         # ONE pool: a token's cached row under latent attention is one
@@ -337,6 +345,88 @@ def _mlp(lp: Dict[str, jnp.ndarray], cfg: ModelConfig,
     out = jnp.einsum("btef,efd->bted", h, lp["down_proj"])
     return jnp.einsum("bted,bte->btd", out,
                       weights.astype(x.dtype)), zero
+
+
+# ---------------------------------------------------------------------------
+# Layer passes (a looped model: ``ModelConfig.total_ut_steps`` > 1)
+#
+# The dense families' layer scan runs inside a loop over passes: the same
+# stacked weights every pass, the final norm after every pass (the normed
+# state is what the next pass starts from and what the exit gate reads),
+# pool slot ``pass * num_layers + layer``. The loop is a scan, traced
+# once; at a pass count of 1 there is no loop and no gate, and the
+# program is what it was before there were passes.
+# ---------------------------------------------------------------------------
+
+def _exit_gate(params: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """lambda = sigmoid(w_g . x + b_g) of normed states x [..., D];
+    float32 [...]."""
+    g = params["exit_gate"]
+    return jax.nn.sigmoid(
+        jnp.dot(x, g["w"], preferred_element_type=jnp.float32)
+        + g["b"].astype(jnp.float32))
+
+
+def exit_pdf(lam: jnp.ndarray) -> jnp.ndarray:
+    """Exit probabilities q [P, ...] from the gate's lambda [P, ...]:
+    q_p = lambda_p * prod_{j<p}(1 - lambda_j), the last pass takes what
+    is left (so they sum to 1)."""
+    survive = jnp.cumprod(1.0 - lam, axis=0)
+    before = jnp.concatenate([jnp.ones_like(lam[:1]), survive[:-1]], axis=0)
+    return jnp.concatenate([(lam * before)[:-1], before[-1:]], axis=0)
+
+
+def _run_passes(cfg: ModelConfig, params: Params, scan_layers, end_pass,
+                carry, gate_rows):
+    """``scan_layers(carry, li_arr) -> (carry, ys)`` (the layer scan over
+    pool slots ``li_arr``) once, or in a scan over
+    ``cfg.total_ut_steps`` passes, each ended by ``end_pass(carry)`` (the
+    final norm). Returns ``(carry, ys, loop)``: ``ys`` with the passes
+    folded into the layers' axis; ``loop`` is ``(passes the loop counted,
+    lambda [P, B])``, with ``gate_rows(x)`` [B, D] the rows the gate reads
+    of a pass's normed state. Without a loop, ``loop`` is None and the
+    one pass comes back NOT ended: its caller ends it where it did before
+    there were passes (after the pools' scatter), so that such a model's
+    program is the text it was (tests/test_step_program_pins.py)."""
+    li = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    if not cfg.looped:
+        carry, ys = scan_layers(carry, li)
+        return carry, ys, None
+
+    def body(c, p):
+        carry, n = c
+        carry, ys = scan_layers(carry, p * cfg.num_layers + li)
+        carry = end_pass(carry)
+        x = carry[0] if isinstance(carry, tuple) else carry
+        return (carry, n + 1), (ys, _exit_gate(params, gate_rows(x)))
+
+    (carry, n), (ys, lam) = jax.lax.scan(
+        body, (carry, jnp.zeros((), jnp.int32)),
+        jnp.arange(cfg.total_ut_steps, dtype=jnp.int32))
+    ys = jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+    return carry, ys, (n, lam)
+
+
+def _dense_stats(dropped: jnp.ndarray, loop, decode_rows: jnp.ndarray
+                 ) -> Dict[str, jnp.ndarray]:
+    """The ``return_stats`` dict of the dense forwards. A looped model
+    adds ``exit_pdf`` [B, P] (each row's exit probabilities) and
+    ``loop``, the float32 vector that rides back in the place of the
+    dropped scalar (``step_moe_stats``): [dropped, passes run, decode
+    rows, then for each pass but the last the sum over the decode rows
+    of the cumulative exit probability after it]. ``decode_rows`` [B]
+    bool: the rows that count (none in a prefill)."""
+    if loop is None:
+        return {"moe_dropped": dropped}
+    n, lam = loop
+    q = exit_pdf(lam)                                            # [P, B]
+    rows = decode_rows.astype(jnp.float32)
+    cdf = jnp.cumsum(q, axis=0)[:-1] * rows[None, :]
+    vec = jnp.concatenate([
+        jnp.stack([dropped.astype(jnp.float32), n.astype(jnp.float32),
+                   jnp.sum(rows)]), jnp.sum(cdf, axis=1)])
+    return {"moe_dropped": dropped, "loop": vec, "exit_pdf": q.T}
 
 
 # ---------------------------------------------------------------------------
@@ -554,8 +644,8 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         a = attn.reshape(B, T, -1) @ lp["o_proj"]
         if "o_bias" in lp:
             a = a + lp["o_bias"]
-        if cfg.gemma:
-            # Gemma four-norm block: post-norms apply to the SUBLAYER
+        if cfg.four_norm_block:
+            # The four-norm block: post-norms apply to the SUBLAYER
             # OUTPUT before the residual add.
             x = x + rms_norm(a, lp["post_norm"], cfg.rms_norm_eps)
             h = rms_norm(x, lp["pre_ff_norm"], cfg.rms_norm_eps)
@@ -570,30 +660,41 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             return (x, kp_c, vp_c), dropped
         return x, (k, v, dropped)
 
-    li_arr = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    if win_arr is not None and rope_arr is not None:
-        xs = (params["layers"], li_arr, win_arr, rope_arr)
-    elif win_arr is not None:
-        xs = (params["layers"], li_arr, win_arr)
-    elif rope_arr is not None:
-        xs = (params["layers"], li_arr, rope_arr)
-    else:
-        xs = (params["layers"], li_arr)
+    def last_rows(x):
+        last_idx = jnp.maximum(lengths - 1, 0)
+        return jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
+
+    def scan_layers(carry, li_arr):
+        if win_arr is not None and rope_arr is not None:
+            xs = (params["layers"], li_arr, win_arr, rope_arr)
+        elif win_arr is not None:
+            xs = (params["layers"], li_arr, win_arr)
+        elif rope_arr is not None:
+            xs = (params["layers"], li_arr, rope_arr)
+        else:
+            xs = (params["layers"], li_arr)
+        return jax.lax.scan(layer, carry, xs)
+
+    def final_norm(x):
+        return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+
     if write_then_attend:
-        (x, k_pages, v_pages), dropped_l = jax.lax.scan(
-            layer, (x, k_pages, v_pages), xs)
+        (x, k_pages, v_pages), dropped_l, loop = _run_passes(
+            cfg, params, scan_layers,
+            lambda c: (final_norm(c[0]),) + c[1:],
+            (x, k_pages, v_pages), last_rows)
     else:
-        x, (k_new, v_new, dropped_l) = jax.lax.scan(layer, x, xs)
+        x, (k_new, v_new, dropped_l), loop = _run_passes(
+            cfg, params, scan_layers, final_norm, x, last_rows)
         k_pages, v_pages = write_prefill_kv_all_layers(
             k_pages, v_pages, k_new, v_new, page_table, start_pos,
             lengths, plan)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    if loop is None:
+        x = final_norm(x)                   # the one pass ends here
     head = params.get("lm_head")
     if head is None:
         head = params["embed"].T
-    last_idx = jnp.maximum(lengths - 1, 0)
-    last_x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-    last_logits = _head_logits(cfg, last_x, head)                # [B, V]
+    last_logits = _head_logits(cfg, last_rows(x), head)          # [B, V]
     all_logits = _head_logits(cfg, x, head) if return_all_logits else None
     outs = [last_logits, all_logits, (k_pages, v_pages)]
     if prompt_lp_targets is not None:
@@ -602,7 +703,8 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         outs.append(_prompt_logprobs(x, head, prompt_lp_targets,
                                      cap=cfg.final_logit_softcapping))
     if return_stats:
-        outs.append({"moe_dropped": jnp.sum(dropped_l)})
+        outs.append(_dense_stats(jnp.sum(dropped_l), loop,
+                                 jnp.zeros(lengths.shape, bool)))
     return tuple(outs)
 
 
@@ -655,15 +757,16 @@ def forward_prefill_ring(params: Params, cfg: ModelConfig,
     from xllm_service_tpu.parallel.mesh import AXIS_TP
     from xllm_service_tpu.parallel.ring import ring_attention_sharded
 
-    if cfg.sliding_window or cfg.gemma or cfg.mla or cfg.gptoss \
-            or cfg.layer_kinds is not None:
+    if cfg.sliding_window or cfg.four_norm_block or cfg.mla or cfg.gptoss \
+            or cfg.layer_kinds is not None or cfg.looped:
         # Ring rotation assumes full causal reach and the plain llama
-        # layer body; SWA/Gemma/MLA/GPT-OSS long prompts take the
-        # chunked-window path (whose flash fold skips out-of-window
-        # chunks, so the work is O(T·W) there anyway).
+        # layer body run once; SWA/four-norm/MLA/GPT-OSS/looped long
+        # prompts take the chunked-window path (whose flash fold skips
+        # out-of-window chunks, so the work is O(T·W) there anyway).
         raise NotImplementedError(
             "ring prefill implements neither sliding-window masks, the "
-            "gemma layer body, latent attention, nor attention sinks")
+            "four-norm layer body, latent attention, attention sinks "
+            "nor layer passes")
 
     k_pages, v_pages = kv
     B, T = tokens.shape
@@ -725,10 +828,10 @@ def forward_embedding(params: Params, cfg: ModelConfig,
     """Sequence embeddings: causal forward (no KV cache), masked mean-pool
     of the final hidden states, L2-normalized. tokens [B, T] padded,
     lengths [B] → [B, hidden] float32."""
-    if cfg.mla or cfg.layer_kinds is not None:
+    if cfg.mla or cfg.layer_kinds is not None or cfg.looped:
         raise NotImplementedError(
-            "/v1/embeddings is not implemented for MLA models nor for "
-            "models whose layers differ in kind")
+            "/v1/embeddings is not implemented for MLA models, for "
+            "models whose layers differ in kind, nor for a layer loop")
     B, T = tokens.shape
     x = _scale_embed(cfg, params["embed"][tokens]
                      .astype(jnp.dtype(cfg.dtype)))
@@ -767,7 +870,7 @@ def forward_embedding(params: Params, cfg: ModelConfig,
         a = attn.reshape(B, T, -1) @ lp["o_proj"]
         if "o_bias" in lp:
             a = a + lp["o_bias"]
-        if cfg.gemma:
+        if cfg.four_norm_block:
             x = x + rms_norm(a, lp["post_norm"], cfg.rms_norm_eps)
             h = rms_norm(x, lp["pre_ff_norm"], cfg.rms_norm_eps)
             x = x + rms_norm(_mlp(lp, cfg, h, valid=tok_valid)[0],
@@ -907,7 +1010,7 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         a = attn.reshape(B, 1, -1) @ lp["o_proj"]
         if "o_bias" in lp:
             a = a + lp["o_bias"]
-        if cfg.gemma:
+        if cfg.four_norm_block:
             x = x + rms_norm(a, lp["post_norm"], cfg.rms_norm_eps)
             h = rms_norm(x, lp["pre_ff_norm"], cfg.rms_norm_eps)
             m, dropped = _mlp(lp, cfg, h, valid=active[:, None])
@@ -921,31 +1024,40 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             return (x, kp_c, vp_c), dropped
         return x, (k[:, 0], v[:, 0], dropped)
 
-    li_arr = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    if win_arr is not None and rope_arr is not None:
-        xs = (params["layers"], li_arr, win_arr, rope_arr)
-    elif win_arr is not None:
-        xs = (params["layers"], li_arr, win_arr)
-    elif rope_arr is not None:
-        xs = (params["layers"], li_arr, rope_arr)
-    else:
-        xs = (params["layers"], li_arr)
+    def scan_layers(carry, li_arr):
+        if win_arr is not None and rope_arr is not None:
+            xs = (params["layers"], li_arr, win_arr, rope_arr)
+        elif win_arr is not None:
+            xs = (params["layers"], li_arr, win_arr)
+        elif rope_arr is not None:
+            xs = (params["layers"], li_arr, rope_arr)
+        else:
+            xs = (params["layers"], li_arr)
+        return jax.lax.scan(layer, carry, xs)
+
+    def final_norm(x):
+        return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+
     if write_then_attend:
-        (x, k_pages, v_pages), dropped_l = jax.lax.scan(
-            layer, (x, k_pages, v_pages), xs)
+        (x, k_pages, v_pages), dropped_l, loop = _run_passes(
+            cfg, params, scan_layers,
+            lambda c: (final_norm(c[0]),) + c[1:],
+            (x, k_pages, v_pages), lambda x: x[:, 0])
     else:
-        x, (k_new, v_new, dropped_l) = jax.lax.scan(layer, x, xs)
+        x, (k_new, v_new, dropped_l), loop = _run_passes(
+            cfg, params, scan_layers, final_norm, x, lambda x: x[:, 0])
         k_pages, v_pages = write_decode_kv_all_layers(
             k_pages, v_pages, k_new, v_new, page_table, positions, active,
             plan)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    if loop is None:
+        x = final_norm(x)                   # the one pass ends here
     head = params.get("lm_head")
     if head is None:
         head = params["embed"].T
     logits = _head_logits(cfg, x[:, 0], head)                    # [B, V]
     if return_stats:
         return logits, (k_pages, v_pages), \
-            {"moe_dropped": jnp.sum(dropped_l)}
+            _dense_stats(jnp.sum(dropped_l), loop, active)
     return logits, (k_pages, v_pages)
 
 
@@ -1083,7 +1195,16 @@ def moe_stats_shape(cfg: ModelConfig) -> Tuple[int, ...]:
     element 0 is the dropped count every family reports), every other
     model has the one scalar."""
     from xllm_service_tpu.parallel.expert import MOE_STATS
+    if cfg.looped:
+        return (cfg.total_ut_steps + 2,)        # ``_dense_stats``' vector
     return (len(MOE_STATS),) if cfg.dropless_experts else ()
+
+
+def step_stats_zeros(cfg: ModelConfig) -> jnp.ndarray:
+    """What a burst of steps starts its sum of ``step_moe_stats`` from:
+    int32 counts, but a looped model's float32 vector."""
+    return jnp.zeros(moe_stats_shape(cfg),
+                     jnp.float32 if cfg.looped else jnp.int32)
 
 
 def _moe_stats_dict(moe_stats: jnp.ndarray) -> Dict[str, jnp.ndarray]:
@@ -1096,9 +1217,10 @@ def _moe_stats_dict(moe_stats: jnp.ndarray) -> Dict[str, jnp.ndarray]:
 
 def step_moe_stats(stats: Dict[str, jnp.ndarray]) -> jnp.ndarray:
     """What a step program hands the engine of ``return_stats``: the
-    ``expert.MOE_STATS`` vector where the model counts it, else the
-    dropped scalar (``moe_stats_shape``)."""
-    return stats.get("moe", stats["moe_dropped"])
+    ``expert.MOE_STATS`` vector where the model counts it, a looped
+    model's vector of passes and exit probabilities (``_dense_stats``),
+    else the dropped scalar (``moe_stats_shape``)."""
+    return stats.get("loop", stats.get("moe", stats["moe_dropped"]))
 
 
 # The routed experts' weights: the layer scan hands them on WHOLE (a

@@ -74,6 +74,13 @@ STEP_FIELDS: Tuple[str, ...] = (
                         # load_max_over_mean (busiest expert's rows over
                         # the mean of the touched ones)}; None where no
                         # layer counts its routing
+    "passes",           # passes of the whole layer stack the step's
+                        # programs ran, counted by the program's own
+                        # loop; None for a model without a layer loop
+    "exit_cdf",         # over the step's decode rows, the mean
+                        # cumulative exit probability after each pass
+                        # but the last; None where no row decoded or
+                        # the model has no loop
     "state_restored",   # per row ADMITTED in this step, 1 where its first
                         # computed position read a cached page's
                         # convolution tails (a prefix hit), else 0; None

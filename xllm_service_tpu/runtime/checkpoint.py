@@ -137,6 +137,23 @@ class _PrefixRemap:
         self._inner.close()
 
 
+_EXIT_GATE = "model.early_exit_gate."
+
+
+def _norm_names(cfg: ModelConfig) -> Dict[str, str]:
+    """The tree's block norms besides ``input_norm`` -> the checkpoint's
+    names: llama's one, Gemma's three, Ouro's three."""
+    if cfg.gemma:
+        return {"post_norm": "post_attention_layernorm",
+                "pre_ff_norm": "pre_feedforward_layernorm",
+                "post_ff_norm": "post_feedforward_layernorm"}
+    if cfg.four_norm:
+        return {"post_norm": "input_layernorm_2",
+                "pre_ff_norm": "post_attention_layernorm",
+                "post_ff_norm": "post_attention_layernorm_2"}
+    return {"post_norm": "post_attention_layernorm"}
+
+
 def load_checkpoint(model_dir: str, cfg: ModelConfig,
                     mesh=None) -> Dict[str, Any]:
     """Load a HF checkpoint directory into the transformer's pytree,
@@ -177,15 +194,12 @@ def load_checkpoint(model_dir: str, cfg: ModelConfig,
     M = "model.layers.{i}.mlp."
     layers: Dict[str, np.ndarray] = {
         "input_norm": stack_norm("model.layers.{i}.input_layernorm.weight"),
-        "post_norm": stack_norm(
-            "model.layers.{i}.post_attention_layernorm.weight"),
         "o_proj": stack(A + "o_proj.weight", transpose=True),
     }
-    if cfg.gemma:
-        layers["pre_ff_norm"] = stack_norm(
-            "model.layers.{i}.pre_feedforward_layernorm.weight")
-        layers["post_ff_norm"] = stack_norm(
-            "model.layers.{i}.post_feedforward_layernorm.weight")
+    # post_norm is the norm after attention: in a four-norm block on the
+    # attention's OUTPUT, else on the residual stream ahead of the MLP.
+    for ours, theirs in _norm_names(cfg).items():
+        layers[ours] = stack_norm("model.layers.{i}." + theirs + ".weight")
     if cfg.fused_proj:
         # Phi-3 layout: qkv_proj rows = [q | k | v], gate_up rows =
         # [gate | up]. Split into the separate projections the compute
@@ -332,6 +346,11 @@ def load_checkpoint(model_dir: str, cfg: ModelConfig,
             # Checkpoints that tie without saying so in config.json.
             params["lm_head"] = np.ascontiguousarray(
                 params["embed"].T)
+    if cfg.looped:
+        # Ouro's exit gate, nn.Linear(hidden, 1): weight [1, D], bias [1].
+        params["exit_gate"] = {
+            "w": r.get(_EXIT_GATE + "weight")[0].astype(dtype),
+            "b": r.get(_EXIT_GATE + "bias").reshape(()).astype(dtype)}
     r.close()
 
     if mesh is not None:
@@ -693,18 +712,16 @@ def save_checkpoint(params: Dict[str, Any], cfg: ModelConfig,
     if "lm_head" in params:
         out["lm_head.weight"] = np.ascontiguousarray(
             get(params["lm_head"]).T)
+    if cfg.looped:
+        out[_EXIT_GATE + "weight"] = get(params["exit_gate"]["w"])[None]
+        out[_EXIT_GATE + "bias"] = get(params["exit_gate"]["b"])[None]
     lp = params["layers"]
     for i in range(L):
         A = f"model.layers.{i}.self_attn."
         out[f"model.layers.{i}.input_layernorm.weight"] = \
             get_norm(lp["input_norm"][i])
-        out[f"model.layers.{i}.post_attention_layernorm.weight"] = \
-            get_norm(lp["post_norm"][i])
-        if cfg.gemma:
-            out[f"model.layers.{i}.pre_feedforward_layernorm.weight"] = \
-                get_norm(lp["pre_ff_norm"][i])
-            out[f"model.layers.{i}.post_feedforward_layernorm.weight"] = \
-                get_norm(lp["post_ff_norm"][i])
+        for ours, theirs in _norm_names(cfg).items():
+            out[f"model.layers.{i}.{theirs}.weight"] = get_norm(lp[ours][i])
         if cfg.fused_proj:
             out[A + "qkv_proj.weight"] = np.ascontiguousarray(
                 np.concatenate([get(lp[nm][i]).T for nm in
@@ -766,7 +783,8 @@ def save_checkpoint(params: Dict[str, Any], cfg: ModelConfig,
         # base: labeling it gemma2 would reload without qk-norm and
         # without rope_local_base_freq — silently wrong logits
         # (round-4 advisor finding).
-        "model_type": ("qwen2_vl" if cfg.is_mrope
+        "model_type": ("ouro" if cfg.four_norm
+                       else "qwen2_vl" if cfg.is_mrope
                        else "gemma3_text"
                        if cfg.gemma and cfg.rope_local_base_freq
                        is not None
@@ -775,6 +793,9 @@ def save_checkpoint(params: Dict[str, Any], cfg: ModelConfig,
                        else "phi3" if cfg.fused_proj
                        else "qwen2" if cfg.attention_bias else "llama"),
     }
+    if cfg.four_norm:
+        hf_cfg["total_ut_steps"] = cfg.total_ut_steps
+        hf_cfg["early_exit_threshold"] = cfg.early_exit_threshold
     if cfg.rope_local_base_freq is not None:
         hf_cfg["rope_local_base_freq"] = cfg.rope_local_base_freq
     if cfg.sliding_window:

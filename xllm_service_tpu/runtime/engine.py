@@ -476,6 +476,11 @@ class Engine:
         # expert.MOE_STATS, summed over layers and steps (latent path).
         self.moe_stats: Dict[str, int] = dict.fromkeys(MOE_STATS, 0)
         self.last_step_moe: Dict[str, int] = dict.fromkeys(MOE_STATS, 0)
+        # A looped model's ledger (``_note_step_stats``): layer passes
+        # by phase, decode rows, and for each pass but the last the sum
+        # over those rows of the cumulative exit probability after it.
+        self.loop_stats = self._loop_book()
+        self.last_step_loop = self._loop_book()
         # Prefix-reuse ledger (xllm_worker_prefix_cache_* on /metrics):
         # how many admits consulted the cache, how many prompt tokens it
         # covered (local hits, restores and cross-worker fetches alike),
@@ -995,7 +1000,8 @@ class Engine:
         never computes prompt logprobs — echo+logprobs prompts must take
         the chunked-window path that does)."""
         return (self._jit_prefill_ring is not None and start == 0
-                and not self.cfg.sliding_window and not self.cfg.gemma
+                and not self.cfg.sliding_window
+                and not self.cfg.four_norm_block and not self.cfg.looped
                 and not self.cfg.mla and not self.cfg.gptoss
                 and seq.req.mm_embeds is None
                 and not seq.req.prompt_logprobs
@@ -1165,6 +1171,8 @@ class Engine:
         self.last_step_state_restored = []
         if self.cfg.is_moe:
             self.last_step_moe = dict.fromkeys(MOE_STATS, 0)
+        if self.cfg.looped:
+            self.last_step_loop = self._loop_book()
         if self.interleave:
             outs = self._step_interleaved(outs)
         else:
@@ -1453,7 +1461,7 @@ class Engine:
             top_lps if want_top else None, mdrop)
         ready = self._phase_end
         next_tok, logprob = _split_tok_lp(fused)
-        self._note_moe_dropped(mdrop)
+        self._note_step_stats(mdrop, "prefill")
         # Batch membership changed (admits): penalty histograms rebuild
         # from host truth before the next penalized decode.
         self._counts = None
@@ -1688,7 +1696,7 @@ class Engine:
             top_lps if want_top else None, mdrop)
         ready = self._phase_end
         next_tok, logprob = _split_tok_lp(fused)
-        self._note_moe_dropped(mdrop)
+        self._note_step_stats(mdrop, "prefill")
         if plp is not None:
             # Stitch this window's scores into the per-sequence ledger:
             # window position t scored the token at global t+1.
@@ -1774,7 +1782,7 @@ class Engine:
             top_ids if want_top else None,
             top_lps if want_top else None, mdrop)
         next_tok, logprob = _split_tok_lp(fused)
-        self._note_moe_dropped(mdrop)
+        self._note_step_stats(mdrop, "prefill")
         self._counts = None
         seq.status = SeqStatus.RUNNING
         seq.num_computed = len(seq.tokens)
@@ -1845,7 +1853,7 @@ class Engine:
             step["top_ids"] if step["want_top"] else None,
             step["top_lps"] if step["want_top"] else None, step["mdrop"])
         next_tok, logprob = _split_tok_lp(fused)
-        self._note_moe_dropped(mdrop)
+        self._note_step_stats(mdrop, "decode")
         # ``next_packed`` as the host can compute it: active rows took
         # the sampled token and the next position.
         mirror = step["mirror"]
@@ -2046,7 +2054,7 @@ class Engine:
             burst["top_lps"] if burst["want_top"] else None,
             burst["mdrop"])
         toks, logps = _split_tok_lp(fused)               # [N, B] each
-        self._note_moe_dropped(mdrop)
+        self._note_step_stats(mdrop, "decode")
         self._last_burst_ready_t = time.monotonic()
         self._last_burst_step = self.step_count
 
@@ -3161,13 +3169,25 @@ class Engine:
                         if out is not None:
                             self.kv = out[3]
 
-    def _note_moe_dropped(self, mdrop) -> None:
-        """Accumulate what the step's sparse layers counted (a device
-        value riding the step outputs; free for dense models where it is
-        a constant 0): the capacity-dropped (token, expert) assignments,
+    def _note_step_stats(self, mdrop, phase: str) -> None:
+        """Accumulate what the step's program counted (a device value
+        riding the step outputs; free for dense models where it is a
+        constant 0): the capacity-dropped (token, expert) assignments,
         and on the latent path, whose layer drops nothing, the whole
         ``expert.MOE_STATS`` vector (``moe_stats``; ``last_step_moe``
-        holds this step's share for the step record)."""
+        holds this step's share for the step record). A looped model's
+        vector (``transformer._dense_stats``) holds the layer passes the
+        program's own loop ran, under ``phase`` (prefill | decode), and
+        its decode rows' cumulative exit probabilities (``loop_stats``;
+        ``last_step_loop`` for the step record)."""
+        if self.cfg.looped:
+            v = mdrop.tolist()
+            for book in (self.loop_stats, self.last_step_loop):
+                book["passes"][phase] += int(v[1])
+                book["rows"] += int(v[2])
+                for i, x in enumerate(v[3:]):
+                    book["cdf_sum"][i] += x
+            return
         if not self.cfg.is_moe:
             return
         if np.ndim(mdrop) == 0:
@@ -3177,6 +3197,10 @@ class Engine:
             self.moe_stats[name] += v
             self.last_step_moe[name] += v
         self.moe_dropped_tokens = self.moe_stats["dropped"]
+
+    def _loop_book(self) -> Dict[str, Any]:
+        return {"passes": {"prefill": 0, "decode": 0}, "rows": 0,
+                "cdf_sum": [0.0] * (self.cfg.total_ut_steps - 1)}
 
     def load_metrics(self) -> Dict[str, Any]:
         """The LoadMetrics the reference ships in heartbeats
@@ -3428,8 +3452,7 @@ def _decode_multi_step(params, tokens, positions, active_pt, kv, st_f32,
     (fin_tok, fin_pos, kv, counts, moe_dropped), \
         (toks, lps, top_ids, top_lps) = \
         jax.lax.scan(body, (tokens, positions, kv, counts,
-                            jnp.zeros(transformer.moe_stats_shape(cfg),
-                                      jnp.int32)), keys)
+                            transformer.step_stats_zeros(cfg)), keys)
     # Final carry token/position go back to the host AS HANDLES ONLY —
     # next burst feeds them in again without a host→device upload.
     return (_fuse_tok_lp(toks, lps), top_ids, top_lps, kv, counts,

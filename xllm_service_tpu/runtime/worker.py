@@ -391,6 +391,16 @@ def _moe_record(st: Dict[str, int]) -> Optional[Dict[str, Any]]:
                 / (st["assignments"] / st["experts_touched"]), 3)}
 
 
+def _loop_record(st: Dict[str, Any]):
+    """The step record's ``passes`` and ``exit_cdf`` of a looped model's
+    step (``Engine.last_step_loop``): the layer passes its programs ran,
+    and over its decode rows the mean cumulative exit probability after
+    each pass but the last (None where it decoded no row)."""
+    rows = st["rows"]
+    return (sum(st["passes"].values()),
+            [round(x / rows, 6) for x in st["cdf_sum"]] if rows else None)
+
+
 def _header_ms(headers: Dict[str, str], name: str) -> Optional[float]:
     """A duration header's milliseconds; None where it is absent or not
     a number (a direct caller, an older master)."""
@@ -1188,10 +1198,12 @@ class Worker:
             ttft_profiling_data=ttft_prof,
             tpot_profiling_data=tpot_prof,
             memory_budget_gb=self.opts.memory_budget_gb,
+            # one id a slot of the pools' leading axis: a layer that
+            # keeps keys and values, a pass (ModelConfig.kv_cache_layers)
             k_cache_ids=list(range(
-                self.primary_runtime().model_cfg.num_layers)),
+                self.primary_runtime().model_cfg.kv_cache_layers)),
             v_cache_ids=list(range(
-                self.primary_runtime().model_cfg.num_layers)),
+                self.primary_runtime().model_cfg.kv_cache_layers)),
             addrs=[self.name],
             # Block-hash contract + block weight (docs/KV_CACHE.md):
             # the service fails loud when page_size/seed diverge from
@@ -1515,6 +1527,8 @@ class Worker:
             eng.phase_counts.get("decode.ahead_dropped_rows", 0), model=m)
         if eng.cfg.is_moe:
             self._flush_moe(rt)
+        if eng.cfg.looped:
+            self._flush_loop(rt)
         tok = self.obs.counter(
             "xllm_worker_step_tokens_total",
             "batch token occupancy: prompt tokens computed (prefill) / "
@@ -1617,6 +1631,8 @@ class Worker:
         free = int(eng.allocator.num_free)
         pages_delta = free - self._st_free_pages.get(m, free)
         self._st_free_pages[m] = free
+        passes, exit_cdf = (_loop_record(eng.last_step_loop)
+                            if eng.cfg.looped else (None, None))
         self.steptrace.record(
             model=m, kind=kind, step_ms=round(step_ms, 3),
             prefill_tokens=eng.last_step_prefill_tokens,
@@ -1632,6 +1648,7 @@ class Worker:
             cache_hit_tokens=hit_delta,
             compiled=tuple(eng.last_step_compiled),
             moe=_moe_record(eng.last_step_moe),
+            passes=passes, exit_cdf=exit_cdf,
             state_restored=(None if eng.pages_only
                             else tuple(eng.last_step_state_restored)))
 
@@ -1671,6 +1688,33 @@ class Worker:
                 "bytes of the pool of convolution tails (one row a page "
                 "a convolution layer)",
                 labelnames=("model",)).set(state["pool_bytes"], model=m)
+
+    def _flush_loop(self, rt: ModelRuntime) -> None:
+        """What a looped model's step programs counted on the device
+        (``Engine.loop_stats``): the layer passes their own loop ran, and
+        the decode rows' cumulative exit probabilities."""
+        st, m = rt.engine.loop_stats, rt.model
+        c = self.obs.counter(
+            "xllm_worker_layer_passes_total",
+            "passes of the whole layer stack the step programs ran, "
+            "counted by the program's own loop: over the steps of a "
+            "phase it is the model's total_ut_steps unless a change "
+            "leaves work out",
+            labelnames=("model", "phase"))
+        for phase, n in st["passes"].items():
+            c.set_total(n, model=m, phase=phase)
+        c = self.obs.counter(
+            "xllm_worker_exit_cdf_sum",
+            "sum over decode rows of the cumulative exit probability "
+            "after layer pass `pass` (every pass but the last, whose is "
+            "1); over xllm_worker_exit_cdf_count it is the mean",
+            labelnames=("model", "pass"))
+        for i, x in enumerate(st["cdf_sum"]):
+            c.set_total(x, model=m, **{"pass": str(i + 1)})
+        self.obs.counter(
+            "xllm_worker_exit_cdf_count",
+            "decode rows the exit gate was read for",
+            labelnames=("model",)).set_total(st["rows"], model=m)
 
     def _flush_overlap(self, rt: ModelRuntime) -> None:
         """Decode-pipeline overlap health: speculative-burst
