@@ -15,7 +15,10 @@ import os
 
 import jax.numpy as jnp
 
-from tools.aot_copy_census import census_pool_copies
+import pytest
+
+from tools.aot_copy_census import (census_pool_copies,
+                                   census_weight_relayouts)
 
 POOL = (2, 32, 64, 8, 64)
 
@@ -55,6 +58,86 @@ ENTRY %main (p0: bf16[2,32,64,8,64]) -> bf16[2,32,64,8,64] {
         assert census_pool_copies(hlo, POOL) == []
 
 
+# A stack of 4 projection weights of 256 x 512 and one layer's matrix.
+WEIGHTS = [(4, 256, 512), (256, 512)]
+
+
+class TestWeightCensusParser:
+    def test_a_slice_into_a_temporary_and_its_transposed_copy_count(self):
+        """The parent's form (PERF.md, PR 44): a layer's matrix sliced out
+        of the stack into S(1), then copied into another order. The
+        alternate memory space excuses neither."""
+        hlo = """
+%fused_computation.82 (p0: bf16[4,256,512], p1: s32[]) -> bf16[1,256,512] {
+  %p0 = bf16[4,256,512]{2,1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %ds = bf16[1,256,512]{2,1,0:T(8,128)(2,1)S(1)} dynamic-slice(%p0, %p1, %c, %c), dynamic_slice_sizes={1,256,512}
+}
+
+%body (arg: (s32[], bf16[4,256,512])) -> (s32[], bf16[4,256,512]) {
+  %constant_dynamic-slice_fusion.6 = bf16[1,256,512]{2,1,0:T(8,128)(2,1)S(1)} fusion(%w, %i), kind=kLoop, calls=%fused_computation.82
+  %copy.44 = bf16[1,256,512]{1,2,0:T(8,128)(2,1)S(1)} copy(%constant_dynamic-slice_fusion.6)
+}
+"""
+        assert census_weight_relayouts(hlo, WEIGHTS) == [
+            "fusion 1,256,512 {2,1,0}", "copy 1,256,512 {1,2,0}"]
+
+    def test_a_whole_stack_copied_outside_the_loop_counts(self):
+        hlo = ("ENTRY %main (w: bf16[4,256,512]) -> bf16[8,512] {\n"
+               "  %copy.17 = bf16[4,256,512]{1,2,0:T(8,128)(2,1)} "
+               "copy(%w)\n"
+               "  %transpose.3 = bf16[4,512,256]{2,1,0} transpose(%w), "
+               "dimensions={0,2,1}\n}\n")
+        assert len(census_weight_relayouts(hlo, WEIGHTS)) == 2
+
+    def test_a_slice_fused_into_its_product_is_clean(self):
+        """The feed-forward's form, and the cured projections': the
+        dynamic-slice (and a fusion nested around it) lives in the
+        product's own fusion, whose result is an activation. Nothing in
+        a fusion's body is materialized, so nothing there counts."""
+        hlo = """
+%fused_computation.5 (p0: bf16[4,256,512], p1: s32[]) -> bf16[256,512] {
+  %ds = bf16[1,256,512]{2,1,0} dynamic-slice(%p0, %p1, %c, %c), dynamic_slice_sizes={1,256,512}
+  ROOT %b = bf16[256,512]{1,0} bitcast(%ds)
+}
+
+%fused_computation.12 (p0: bf16[8,256], p1: bf16[4,256,512], p2: s32[]) -> bf16[8,512] {
+  %fusion.109 = bf16[256,512]{1,0:T(8,128)(2,1)} fusion(%p1, %p2), kind=kLoop, calls=%fused_computation.5
+  %copy.9 = bf16[256,512]{0,1} copy(%fusion.109)
+  ROOT %convolution.31 = bf16[8,512]{1,0} convolution(%p0, %copy.9), dim_labels=bf_io->bf
+}
+
+%body (arg: (s32[], bf16[4,256,512])) -> (s32[], bf16[4,256,512]) {
+  %fusion.134 = bf16[8,512]{1,0:T(8,128)(2,1)S(1)} fusion(%x, %w, %i), kind=kOutput, calls=%fused_computation.12
+}
+"""
+        assert census_weight_relayouts(hlo, WEIGHTS) == []
+
+    def test_other_sizes_and_other_fusions_do_not_count(self):
+        hlo = ("%body (a: s32[]) -> s32[] {\n"
+               # weight-sized, but no dynamic-slice in what it calls
+               "  %fusion.7 = bf16[256,512]{1,0} fusion(%a), kind=kLoop, "
+               "calls=%fused_computation.1\n"
+               # a copy of an activation
+               "  %copy.2 = bf16[8,512]{0,1} copy(%x)\n}\n")
+        assert census_weight_relayouts(hlo, WEIGHTS) == []
+
+    def test_the_prefetch_of_an_unstacked_weight_is_excused(self):
+        """A copy-start into an alternate space that keeps the order of
+        the dimensions is that weight's one read; one that changes the
+        order, or lands in default memory, is a relayout."""
+        tail = ("bf16[1,256,512]{2,1,0:T(8,128)(2,1)}, u32[]{:S(2)}) "
+                "copy-start(%p)\n}\n")
+        head = "ENTRY %main (p: bf16[1,256,512]) -> bf16[8,512] {\n  %cs = ("
+        prefetch = (head + "bf16[1,256,512]{2,1,0:T(8,128)(2,1)S(1)}, "
+                    + tail)
+        reordered = (head + "bf16[1,256,512]{1,2,0:T(8,128)(2,1)S(1)}, "
+                     + tail)
+        in_hbm = head + "bf16[1,256,512]{2,1,0:T(8,128)(2,1)}, " + tail
+        assert census_weight_relayouts(prefetch, WEIGHTS) == []
+        assert len(census_weight_relayouts(reordered, WEIGHTS)) == 1
+        assert len(census_weight_relayouts(in_hbm, WEIGHTS)) == 1
+
+
 def test_census_plan_is_what_an_engine_resolves(monkeypatch):
     """The kernel mix the census compiles (``cc.census_plan``: aliased
     Pallas writers + XLA attention, REAL Mosaic lowering) is a plan an
@@ -71,6 +154,15 @@ def test_census_plan_is_what_an_engine_resolves(monkeypatch):
             ModelConfig.tiny(),
             EngineConfig(page_size=64, num_pages=32, max_model_len=128,
                          prefill_buckets=(64,), write_then_attend=wta))
+    # ... and the weight census's (``cc.chip_plan``) is what the
+    # benchmark's dense cells resolve on the chip: every kernel on, page
+    # 128 under the default bucket ladder.
+    monkeypatch.setenv("XLLM_PALLAS", "1")
+    monkeypatch.delenv("XLLM_PALLAS_KV")
+    for cfg in (ModelConfig.tiny(), _cell_config("lfm2-24b-a2b")):
+        assert cc.chip_plan(cfg) == KernelPlan.from_env(
+            cfg, EngineConfig(page_size=128, num_pages=64,
+                              max_model_len=8192, max_batch_size=8))
 
 
 class TestCensusAot:
@@ -157,3 +249,108 @@ class TestCensusAot:
                                out_shardings=pin)
         hits = census_pool_copies(compiled.as_text(), POOL)
         assert hits == [], hits
+
+
+# The benchmark's cells whose layers run ``transformer._qkv``, at their
+# published widths and few layers: layers (0: the benchmark's own cut),
+# pool pages, page-table width, decode rows.
+CELLS = {
+    "mistral-7b-v01": (4, 64, 64, 8),
+    # 4 passes over the same 4 layers: the pass scan around the layer
+    # scan is what lets the compiler hoist a copy of the WHOLE stack.
+    "ouro-2.6b": (4, 40, 8, 8),
+    # Two attention layers among convolutions, heads of 64 packed two to
+    # a row of pools tiled (4, 128); sparse experts.
+    "lfm2-24b-a2b": (0, 256, 96, 64),
+}
+DENSE_CELLS = ["mistral-7b-v01", "ouro-2.6b"]
+
+
+def _cell_config(name):
+    """The benchmark's own config.json, cut in depth alone."""
+    import json
+
+    from xllm_service_tpu.config import ModelConfig
+    layers = CELLS[name][0]
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "configs", name,
+        "config.json")
+    with open(path) as f:
+        hf = json.load(f)
+    if layers:
+        hf["num_hidden_layers"] = layers
+        if "layer_types" in hf:
+            hf["layer_types"] = hf["layer_types"][:layers]
+    return ModelConfig.from_hf_config(hf, name)
+
+
+def _census(aot, name, program):
+    """(weight relayouts, pool copies) of one program of a cell
+    (tools/aot_copy_census.py ``build_cell_programs``)."""
+    import tools.aot_copy_census as cc
+    aot_compile, _ = aot
+    _, pages, table_width, batch = CELLS[name]
+    programs, weights, pools = cc.build_cell_programs(
+        _cell_config(name), pages, table_width, batch)
+    fn, args, jit_kw = programs[program]
+    text = aot_compile(fn, args, **jit_kw).as_text()
+    return (census_weight_relayouts(text, weights),
+            [hit for pool in set(pools)
+             for hit in census_pool_copies(text, pool)])
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("cell", DENSE_CELLS)
+class TestWeightCensusAot:
+    """No compiled dense step program slices a q/k/v weight into a
+    temporary or copies one into another layout (PR 44; the cure is
+    ``transformer._pin``, flat): Mistral's widths, where the compiler
+    did it a layer at a time, and the looped model's, where it copied
+    each stack whole once a step."""
+
+    def test_projections_read_their_weights_where_they_lie(
+            self, aot, cell, program):
+        weights, pools = _census(aot, cell, program)
+        assert weights == [] and pools == [], (weights, pools)
+
+    def test_positive_control_products_then_reshape(
+            self, aot, cell, program, monkeypatch):
+        """The parent's form, three products and then the reshape to
+        heads, patched in: the census must find q's, k's and v's
+        relayouts, or the zero above proves nothing."""
+        from xllm_service_tpu.models import transformer
+        monkeypatch.setattr(transformer, "_pin", lambda values: values)
+        hits, _ = _census(aot, cell, program)
+        assert len(hits) >= 3, hits
+        assert any(h.startswith("copy") for h in hits), hits
+
+
+class TestHybridCellAot:
+    """The same cure in the hybrid cell's two attention layers moves
+    neither a weight nor a POOL (PR 44; ``transformer._pin``, in
+    heads)."""
+
+    @pytest.mark.parametrize("program", ["decode", "prefill"])
+    def test_no_weight_and_no_pool_moves(self, aot, program):
+        weights, pools = _census(aot, "lfm2-24b-a2b", program)
+        # 256 x 4 x 8 x 128 values of the prefill's attention have a
+        # weight's element count (2048 x 512) and hold a dynamic-slice.
+        weights = [h for h in weights if not h.startswith("fusion 1,256,4,")]
+        assert weights == [] and pools == [], (weights, pools)
+
+    def test_positive_control_pinned_flat_alone(self, aot, monkeypatch):
+        """Pinned flat and not in heads (this PR's first form, which cost
+        the cell a fifth of its ``out_tok_s`` on the chip): the prefill
+        program carries a pool through its layer loop in another layout
+        and copies it whole, in, out and around each attention layer."""
+        import jax
+
+        from xllm_service_tpu.models import transformer
+
+        def flat_alone(values):
+            leaves = jax.tree_util.tree_leaves(values)
+            return (jax.lax.optimization_barrier(values)
+                    if leaves[0].ndim == 3 else values)
+        monkeypatch.setattr(transformer, "_pin", flat_alone)
+        weights, pools = _census(aot, "lfm2-24b-a2b", "prefill")
+        assert len(pools) >= 2, pools

@@ -2,9 +2,13 @@
 latent one arrived lower to the text they lowered to on the parent
 (PR 33's method: sha256 of ``.lower().as_text()``; ``tests/pins/
 step_programs_pr34.json`` was taken on commit 17f4a2a with this file's
-``digests``). A change to the latent family's path, the expert layer or
-the step statistics must not reach a dense model's program: the Mistral
-cell is then measured on what it was measured on."""
+``digests``, and taken again by PR 44, which changed the dense path's
+text on purpose: two ``optimization_barrier``s a layer body, before and
+after the q/k/v products' reshape to heads, nothing else, by the count
+of every operation in the old and the new text). A change to the latent
+family's path, the expert layer or the step statistics must not reach a
+dense model's program: the Mistral cell is then measured on what it was
+measured on."""
 
 import hashlib
 import json

@@ -27,11 +27,21 @@ Prints one verdict line per (program, mode) plus a JSON summary. The
 tier-1 suite runs the same census at the tiny shape
 (tests/test_copy_census.py), so a PR reintroducing pool copies fails
 CI instead of shipping a silent 10 GB/call regression.
+
+A second census beside it (PR 44), ``census_weight_relayouts``: the
+slices into a temporary and the copies into another layout of a
+projection WEIGHT, which the compiler pays every step where a product's
+result goes straight into a reshape to heads. The tier-1 suite compiles
+the dense decode and prefill programs at the benchmark's two dense
+cells' widths (``build_cell_programs`` under ``chip_plan``) and holds
+them at zero, and with them the pool copies of the hybrid cell's
+programs, which one form of the weights' cure brought in.
 """
 
 from __future__ import annotations
 
 import json
+import math
 import os
 import re
 import sys
@@ -88,6 +98,90 @@ def census_pool_copies(hlo_text: str, pool_shape) -> list:
                 n *= int(d)
         if n == want:
             hits.append(f"{op.group(1)} {dims}")
+    return hits
+
+
+# "%f.6 = bf16[1,4096,4096]{2,1,0:T(8,128)(2,1)S(1)} fusion(...), kind=kLoop,
+# calls=%fused_computation.82"; a copy-start's result is a tuple of the
+# destination, the source and a context word.
+_RELAYOUT_OP_RE = re.compile(r"\s(copy|copy-start|transpose|fusion)\(")
+_CALLS_RE = re.compile(r"calls=%([\w.\-]+)")
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s.*\{\s*$")
+_LAYOUT_RE = re.compile(r"\]\{([0-9,]*)([^}]*)\}")
+
+
+def census_weight_relayouts(hlo_text: str, weight_shapes) -> list:
+    """Instructions of a compiled program that put a projection weight
+    somewhere else before a product reads it: every ``copy`` /
+    ``copy-start`` / ``transpose``, and every fusion that holds a
+    ``dynamic-slice``, whose RESULT has the element count of one of
+    ``weight_shapes`` (give each weight's stacked ``[L, D, N]`` and a
+    layer's ``[D, N]``). Returns
+    ``"<opcode> <dims> {<major-to-minor>}"`` strings; an empty list means
+    every product reads its weight where it lies, as the feed-forward's
+    do (their slice of the stack is fused INTO the product, whose result
+    is an activation).
+
+    A fusion's own body is not searched: its instructions are the
+    fusion's arithmetic, and nothing in it is materialized. Unlike
+    ``census_pool_copies`` a result in an alternate memory space
+    (``S(1)``) COUNTS: the slice-then-relayout this census hunts lands
+    there (PERF.md, PR 44: ``constant_dynamic-slice_fusion.6-8`` into
+    ``S(1)``, then ``copy`` to ``{1,2,0}``), and it is paid every step
+    whatever memory holds the temporary. The one thing excused is a
+    ``copy-start`` into an alternate space that keeps the order of the
+    dimensions: the prefetch of a whole unstacked weight, which is that
+    weight's one read and no relayout (a leading layer outside the scan
+    of a model whose layers differ in kind)."""
+    want = {math.prod(shape) for shape in weight_shapes}
+    lines = hlo_text.splitlines()
+    # A first pass sorts the text's computations: which are fusion
+    # bodies, and which hold a dynamic-slice, in themselves or in a
+    # fusion nested in them.
+    bodies, slicing, calls, name = set(), set(), {}, None
+    for line in lines:
+        head = _COMPUTATION_RE.match(line)
+        if head:
+            name = head.group(1)
+            continue
+        if " dynamic-slice(" in line:
+            slicing.add(name)
+        if " fusion(" in line:
+            callee = _CALLS_RE.search(line)
+            if callee:
+                bodies.add(callee.group(1))
+                calls.setdefault(name, set()).add(callee.group(1))
+    grew = True
+    while grew:
+        grew = False
+        for caller, callees in calls.items():
+            if caller not in slicing and callees & slicing:
+                slicing.add(caller)
+                grew = True
+    hits, name = [], None
+    for line in lines:
+        head = _COMPUTATION_RE.match(line)
+        if head:
+            name = head.group(1)
+            continue
+        op = _RELAYOUT_OP_RE.search(line)
+        m = _SHAPE_RE.search(line)
+        if (name in bodies or not op or not m or math.prod(
+                int(d) for d in m.group(1).split(",") if d) not in want):
+            continue
+        # (major-to-minor, tiling and memory space) of each shape of the
+        # result: a copy-start's are the destination's, then the source's.
+        layouts = _LAYOUT_RE.findall(line[:op.start()])
+        if op.group(1) == "fusion":
+            callee = _CALLS_RE.search(line)
+            if not callee or callee.group(1) not in slicing:
+                continue
+        elif (op.group(1) == "copy-start" and len(layouts) >= 2
+              and re.search(r"S\([1-9]", layouts[0][1])
+              and layouts[0][0] == layouts[1][0]):
+            continue
+        hits.append(f"{op.group(1)} {m.group(1)} "
+                    f"{{{layouts[0][0] if layouts else ''}}}")
     return hits
 
 
@@ -222,6 +316,77 @@ def build_programs(tiny: bool = False, plan=None):
         "decode_burst": (decode_burst, (params, tok, pos, act, kv, pt),
                          (4,), pool_shape),
     }
+
+
+def build_cell_programs(cfg, pages: int, table_width: int, batch: int = 8,
+                        window: int = 256, page_size: int = 128):
+    """(name → (fn, args, jit keywords)), the shapes of the attention
+    projections' weights, stacked and a layer's
+    (``census_weight_relayouts``), and the pools' (``census_pool_copies``):
+    the decode step and a one-row prefill window of ``cfg``, a dense
+    model or one whose layers differ in kind, under the plan an engine
+    resolves on a chip (``chip_plan``), pools donated and pinned
+    row-major on both sides as an engine pins them. Shapes alone: nothing
+    is allocated, so a cell's real widths cost a few seconds a program at
+    a few layers."""
+    from xllm_service_tpu.models import transformer
+    from xllm_service_tpu.runtime.engine import row_major_format
+
+    plan = chip_plan(cfg)
+    there = sds((1,), jnp.int32).sharding
+
+    def described(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=there),
+            jax.eval_shape(make))
+    params = described(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    kv = described(
+        lambda: transformer.init_kv_cache(cfg, pages, page_size))
+    pin = tuple(row_major_format(x.ndim, x.sharding) for x in kv)
+    jit_kw = {"donate_argnums": (4,),
+              "in_shardings": (None, None, None, None, pin, None),
+              "out_shardings": (None, pin)}
+
+    def decode(params, tok, pos, act, kv, pt):
+        return transformer.forward_decode(
+            params, cfg, tok, pos, act, kv, pt, plan=plan)[:2]
+
+    def prefill(params, tokens, start, lens, kv, pt):
+        last, _, kv = transformer.forward_prefill(
+            params, cfg, tokens, start, lens, kv, pt, plan=plan)[:3]
+        return last, kv
+
+    def ints(*shape):
+        return sds(shape, jnp.int32)
+    programs = {
+        "decode": (decode, (params, ints(batch), ints(batch),
+                            sds((batch,), jnp.bool_), kv,
+                            ints(batch, table_width)), jit_kw),
+        "prefill": (prefill, (params, ints(1, window), ints(1), ints(1),
+                              kv, ints(1, table_width)), jit_kw),
+    }
+    weights = []
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if getattr(path[-1], "key", None) in ("q_proj", "k_proj", "v_proj",
+                                              "o_proj"):
+            weights += [tuple(leaf.shape), tuple(leaf.shape[1:])]
+    return programs, weights, [tuple(pool.shape) for pool in kv]
+
+
+def chip_plan(cfg):
+    """What ``KernelPlan.from_env`` resolves for ``cfg`` (no latent
+    attention) on one chip at the benchmark's page size and the default
+    bucket ladder: the paged decode kernel, the in-place decode writer,
+    write-then-attend, the grouped matmul where the experts are the
+    dropless ones; prefill attention in XLA and its write a scatter (a
+    64-token bucket under a page of 128 is not page-aligned); real
+    Mosaic lowering."""
+    from xllm_service_tpu.ops.plan import KernelPlan
+    return KernelPlan(decode_attn=True, kv_writers=True,
+                      write_then_attend=True, page_aligned=False,
+                      expert_gmm=cfg.dropless_experts, interpret=False)
 
 
 def census_plan(write_then_attend: bool):

@@ -253,6 +253,40 @@ def _head_logits(cfg: ModelConfig, x: jnp.ndarray,
     return logits
 
 
+def _pin(values):
+    """The identity on values, and NOT a no-op: ``values`` exist, as
+    they are shaped here, before anything consumes them. A projection
+    that feeds a reshape to heads is pinned twice, flat and in heads.
+
+    FLAT (``[B, T, N]``, as the feed-forward's results are). Left to
+    itself the TPU compiler folds the reshape to heads into the product
+    and gives the folded product's WEIGHT operand the layout ``{1,2,0}``.
+    A parameter's layout is fixed, so the program pays for the other one
+    in every step: a layer's matrix sliced out of the stack into a
+    temporary and copied transposed (13% of the Mistral cell's decode
+    step), or the whole stack copied once a step, 1.2 GB of temporaries,
+    and sliced 192 times (a fifth of the looped cell's; PERF.md, PR 44).
+    How the weights are stored does not cure it (stored transposed the
+    slice stays; one head-grouped weight brings both back): what does is
+    a product whose result is consumed flat first. Then it reads its
+    weight where it lies.
+
+    IN HEADS (``[B, T, H, Dh]``). Pinned flat alone, the layout a
+    consumer wants reaches back through the reshape and is settled
+    somewhere worse: the hybrid family's prefill, whose values go
+    straight into a pool tiled (4, 128), relaid the whole carried pool
+    out and back around every attention layer (9 ms of a 29 ms program;
+    PERF.md, PR 44). Pinned in heads too, the reshape is a small copy of
+    an activation, as it was before, and no pool moves.
+
+    To see either: ``tools/aot_copy_census.py`` counts the weights'
+    slices and copies (``census_weight_relayouts``) and the pools'
+    (``census_pool_copies``) in a compiled program's text;
+    ``tests/test_copy_census.py`` holds the step programs at zero at the
+    benchmark's widths and finds both again with this patched out."""
+    return jax.lax.optimization_barrier(values)
+
+
 def _qkv(lp: Dict[str, jnp.ndarray], cfg: ModelConfig, x: jnp.ndarray):
     """x: [B, T, D] → q [B, T, Hq, Dh], k/v [B, T, Hkv, Dh]."""
     B, T, _ = x.shape
@@ -263,9 +297,15 @@ def _qkv(lp: Dict[str, jnp.ndarray], cfg: ModelConfig, x: jnp.ndarray):
         q = q + lp["q_bias"]
         k = k + lp["k_bias"]
         v = v + lp["v_bias"]
+    # Pinned flat AFTER the bias, not before: the bias is flat too, and
+    # on this side of the pin its add rides the product's own fusion
+    # (before it, three more fusions a layer; the weights lie still
+    # either way).
+    q, k, v = _pin((q, k, v))
     q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
     k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    q, k, v = _pin((q, k, v))
     if "q_norm" in lp:
         # Qwen3: per-head RMSNorm on q/k before rope.
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
@@ -1281,7 +1321,9 @@ def _mla_qkv(cfg: ModelConfig, lp, h, positions):
             @ lp["q_b"]
     else:
         q = h @ lp["q_proj"]
-    q = q.reshape(B, T, Hq, cfg.qk_head_dim)
+    # q_b's slice and transposed copy a layer: the census found the fold
+    # here too (PERF.md, PR 44).
+    q = _pin(_pin(q).reshape(B, T, Hq, cfg.qk_head_dim))
     q_nope, q_pe = q[..., :nope], q[..., nope:]
     q_pe = rope_fn(q_pe, positions, cfg.rope_theta, cfg.rope_scaling)
     # Absorb the key up-projection into the query side.
