@@ -354,3 +354,57 @@ class TestHybridCellAot:
         monkeypatch.setattr(transformer, "_pin", flat_alone)
         weights, pools = _census(aot, "lfm2-24b-a2b", "prefill")
         assert len(pools) >= 2, pools
+
+
+class TestStatePoolAot:
+    """The family with a mixer beside attention (PR 45): no step program
+    holds a copy of the pool of matrix states by slot ([6, 97, 32, 128,
+    256] float32, 2.44 GB), nor of the pages' pools, and no projection
+    of either branch is relaid. The state update is the Pallas kernel of
+    ops/pallas/ssm_update.py, which maps a row's block by its slot and
+    aliases the pool; XLA's own gather-and-scatter form compiles beside
+    it as the reading it was chosen over."""
+
+    PAGES, WIDTH, BATCH, SLOTS = 256, 16, 32, 97
+
+    def _programs(self, plan=None):
+        import json
+
+        import tools.aot_copy_census as cc
+        from xllm_service_tpu.config import ModelConfig
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench", "configs",
+            "falcon-h1-34b", "config.json")
+        with open(path) as f:
+            cfg = ModelConfig.from_hf_config(json.load(f), "falcon-h1-34b")
+        return cfg, cc.build_cell_programs(
+            cfg, self.PAGES, self.WIDTH, self.BATCH,
+            state_slots=self.SLOTS, plan=plan)
+
+    @pytest.mark.parametrize("program", ["decode", "prefill"])
+    def test_no_pool_moves_and_no_weight_is_relaid(self, aot, program):
+        aot_compile, _ = aot
+        _, (programs, _, pools) = self._programs()
+        assert pools[3] == (6, 97, 32, 256, 128)
+        fn, args, jit_kw = programs[program]
+        compiled = aot_compile(fn, args, **jit_kw)
+        text = compiled.as_text()
+        assert [hit for pool in set(pools)
+                for hit in census_pool_copies(text, pool)] == []
+        if program == "decode":
+            assert "ssm_decode_update" in text
+            assert "paged_decode_attention" in text
+        # the projections' own shapes, by dimension (an activation of
+        # 32 rows has the element count of the 32-wide dt projection,
+        # which the census by count would take for the weight)
+        import re
+        relaid = [ln for ln in text.splitlines()
+                  if re.search(r"\s(copy|copy-start|transpose)\(", ln)
+                  and re.search(r"\[(6,)?(5120,(2560|512|4096|5120|32)"
+                                r"|2560,5120|4096,5120)\]", ln)]
+        assert relaid == []
+        # weights 10.5 GB + pools 2.9 GB, and the program's own
+        # temporaries, fit the chip's 16.9 GB... (a v5e reports 15.75 GiB)
+        ma = compiled.memory_analysis()
+        assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.2e9
+        assert ma.alias_size_in_bytes >= 2.9e9      # every pool in place

@@ -319,7 +319,8 @@ def build_programs(tiny: bool = False, plan=None):
 
 
 def build_cell_programs(cfg, pages: int, table_width: int, batch: int = 8,
-                        window: int = 256, page_size: int = 128):
+                        window: int = 256, page_size: int = 128,
+                        state_slots: int = 0, plan=None):
     """(name → (fn, args, jit keywords)), the shapes of the attention
     projections' weights, stacked and a layer's
     (``census_weight_relayouts``), and the pools' (``census_pool_copies``):
@@ -328,11 +329,14 @@ def build_cell_programs(cfg, pages: int, table_width: int, batch: int = 8,
     resolves on a chip (``chip_plan``), pools donated and pinned
     row-major on both sides as an engine pins them. Shapes alone: nothing
     is allocated, so a cell's real widths cost a few seconds a program at
-    a few layers."""
+    a few layers. A model with a mixer beside attention takes
+    ``state_slots`` slots of its fourth pool, and its programs the slot
+    columns an engine hands them (a state row a decode row, four slot
+    columns a prefill row)."""
     from xllm_service_tpu.models import transformer
     from xllm_service_tpu.runtime.engine import row_major_format
 
-    plan = chip_plan(cfg)
+    plan = plan or chip_plan(cfg)
     there = sds((1,), jnp.int32).sharding
 
     def described(make):
@@ -343,19 +347,24 @@ def build_cell_programs(cfg, pages: int, table_width: int, batch: int = 8,
     params = described(
         lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
     kv = described(
-        lambda: transformer.init_kv_cache(cfg, pages, page_size))
+        lambda: transformer.init_kv_cache(cfg, pages, page_size,
+                                          state_slots=state_slots))
     pin = tuple(row_major_format(x.ndim, x.sharding) for x in kv)
+    by_slot = cfg.num_ssm_layers > 0
     jit_kw = {"donate_argnums": (4,),
-              "in_shardings": (None, None, None, None, pin, None),
+              "in_shardings": (None, None, None, None, pin, None)
+              + ((None,) if by_slot else ()),
               "out_shardings": (None, pin)}
 
-    def decode(params, tok, pos, act, kv, pt):
+    def decode(params, tok, pos, act, kv, pt, rows=None):
         return transformer.forward_decode(
-            params, cfg, tok, pos, act, kv, pt, plan=plan)[:2]
+            params, cfg, tok, pos, act, kv, pt, plan=plan,
+            state_rows=rows)[:2]
 
-    def prefill(params, tokens, start, lens, kv, pt):
+    def prefill(params, tokens, start, lens, kv, pt, cols=None):
         last, _, kv = transformer.forward_prefill(
-            params, cfg, tokens, start, lens, kv, pt, plan=plan)[:3]
+            params, cfg, tokens, start, lens, kv, pt, plan=plan,
+            state_cols=cols)[:3]
         return last, kv
 
     def ints(*shape):
@@ -363,9 +372,11 @@ def build_cell_programs(cfg, pages: int, table_width: int, batch: int = 8,
     programs = {
         "decode": (decode, (params, ints(batch), ints(batch),
                             sds((batch,), jnp.bool_), kv,
-                            ints(batch, table_width)), jit_kw),
+                            ints(batch, table_width))
+                   + ((ints(batch),) if by_slot else ()), jit_kw),
         "prefill": (prefill, (params, ints(1, window), ints(1), ints(1),
-                              kv, ints(1, table_width)), jit_kw),
+                              kv, ints(1, table_width))
+                    + ((ints(1, 4),) if by_slot else ()), jit_kw),
     }
     weights = []
     for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
@@ -385,8 +396,12 @@ def chip_plan(cfg):
     Mosaic lowering."""
     from xllm_service_tpu.ops.plan import KernelPlan
     return KernelPlan(decode_attn=True, kv_writers=True,
-                      write_then_attend=True, page_aligned=False,
-                      expert_gmm=cfg.dropless_experts, interpret=False)
+                      write_then_attend=True,
+                      # but a model whose state lives by slot, whose
+                      # engine starts every window on a page boundary
+                      page_aligned=cfg.num_ssm_layers > 0,
+                      expert_gmm=cfg.dropless_experts,
+                      ssm_decode=cfg.num_ssm_layers > 0, interpret=False)
 
 
 def census_plan(write_then_attend: bool):

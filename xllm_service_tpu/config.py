@@ -241,6 +241,35 @@ class ModelConfig:
     # divides by it (norm_topk_prob): 1e-20 in DeepSeek-V3's gate, 1e-6
     # in LFM2's.
     moe_gate_eps: float = 1e-20
+    # A Mamba-2 mixer BESIDE attention in a layer (Falcon-H1; the layer's
+    # operator is "mix" in ``layer_kinds``): both read the same normed
+    # input and their outputs are summed. ``ssm_heads`` heads of
+    # ``ssm_head_dim``, each with a matrix state [ssm_head_dim,
+    # ssm_state] kept in float32 in a pool addressed by SLOT
+    # (transformer.init_kv_cache's fourth pool); B and C in
+    # ``ssm_groups`` groups; a causal depthwise filter of ``conv_kernel``
+    # taps (with a bias) over the ``ssm_conv_dim`` channels x | B | C;
+    # the prefill scan in chunks of ``ssm_chunk``. 0 heads = no mixer.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
+    # The family's scalar multipliers (muP), applied where the published
+    # code applies them, never folded into a weight: on the embedding,
+    # the logits, the attention branch's input, its keys and its output,
+    # the mixer's input, the five segments z | x | B | C | dt of its input
+    # projection and its output, the feed-forward's gate and its output.
+    # 1.0 everywhere = every other model (no operation is emitted).
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
     dtype: str = "bfloat16"
 
     def __post_init__(self) -> None:
@@ -251,6 +280,15 @@ class ModelConfig:
             raise ValueError(
                 f"total_ut_steps={self.total_ut_steps}: a model runs its "
                 f"layers at least once")
+        ops = {k.split("+")[0] for k in self.layer_kinds or ()}
+        if "mix" in ops and (ops != {"mix"} or self.ssm_heads <= 0
+                             or self.ssm_heads % self.ssm_groups):
+            raise ValueError(
+                "a layer_kinds model with a 'mix' operator has it in every "
+                "layer (its convolution tails are rings of conv_kernel "
+                "rows, a 'conv' layer's are conv_kernel - 1 gated inputs: "
+                "one pool holds one or the other) and gives ssm_heads, a "
+                "multiple of ssm_groups")
         if self.total_ut_steps > 1:
             if self.mla or self.layer_kinds is not None or self.is_moe:
                 raise ValueError(
@@ -304,12 +342,42 @@ class ModelConfig:
         """Layers that keep keys and values: the pools' leading axis."""
         if self.layer_kinds is None:
             return self.num_layers
-        return sum(k.startswith("attn+") for k in self.layer_kinds)
+        return sum(k.startswith(("attn+", "mix+")) for k in self.layer_kinds)
 
     @property
     def num_conv_layers(self) -> int:
-        """Layers whose state is a convolution tail (the third pool)."""
-        return sum(k.startswith("conv+") for k in self.layer_kinds or ())
+        """Layers whose state is a convolution tail (the third pool): a
+        "conv" operator's, and the mixer's of a "mix" operator."""
+        return sum(k.startswith(("conv+", "mix+"))
+                   for k in self.layer_kinds or ())
+
+    @property
+    def num_ssm_layers(self) -> int:
+        """Layers that keep a matrix state a head (the fourth pool)."""
+        return sum(k.startswith("mix+") for k in self.layer_kinds or ())
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of the mixer's x and z: heads x head width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the mixer's short convolution runs over: x | B | C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def conv_tail_width(self) -> int:
+        """Values in one row of the pool of convolution tails. A "conv"
+        layer keeps its last conv_kernel - 1 gated inputs over
+        hidden_size channels; a mixer keeps a RING of conv_kernel inputs
+        over its ssm_conv_dim channels, the input at position t in row
+        t mod conv_kernel, so that a decode step writes one ring row
+        and never one it reads (transformer, "A mixer beside
+        attention")."""
+        if self.num_ssm_layers:
+            return self.conv_kernel * self.ssm_conv_dim
+        return max(self.conv_kernel - 1, 1) * self.hidden_size
 
     @property
     def dropless_experts(self) -> bool:
@@ -541,7 +609,8 @@ class ModelConfig:
                      "mixtral", "gemma2", "gemma3", "gemma3_text",
                      "qwen2_vl", "qwen2_5_vl",
                      "qwen3_moe", "deepseek_v2", "deepseek_v3",
-                     "joyai_llm_flash", "gpt_oss", "lfm2_moe", "ouro")
+                     "joyai_llm_flash", "gpt_oss", "lfm2_moe", "ouro",
+                     "falcon_h1")
         # The latent family: DeepSeek-V2, and the V3 layer (sigmoid
         # scores, selection bias) that JD's JoyAI-LLM-Flash shares.
         _v3 = mt in ("deepseek_v3", "joyai_llm_flash")
@@ -634,6 +703,32 @@ class ModelConfig:
                  "rms_norm_eps": d.get("norm_eps", 1e-5),
                  # Lfm2MoeConfig ties the head to the embedding.
                  "tie_word_embeddings": True, **d}
+        _fh1 = mt == "falcon_h1"
+        if _fh1:
+            # TII Falcon-H1: EVERY layer runs grouped-query attention and
+            # a Mamba-2 mixer on the same normed input and sums them,
+            # then a SwiGLU. What this loop has no body for is refused,
+            # by the key that asks for it.
+            for key, want in (("mamba_rms_norm", True),
+                              ("mamba_norm_before_gate", False),
+                              ("mamba_use_mlp", True),
+                              ("rope_scaling", None),
+                              ("attn_layer_indices", None),
+                              ("attention_bias", False),
+                              ("mamba_proj_bias", False),
+                              ("mlp_bias", False),
+                              ("projectors_bias", False),
+                              ("hidden_act", "silu")):
+                if d.get(key, want) != want:
+                    raise ValueError(
+                        f"falcon_h1 with {key}={d[key]!r} is not "
+                        f"implemented (only {want!r})")
+            if d["mamba_d_ssm"] != d["mamba_n_heads"] * d["mamba_d_head"]:
+                raise ValueError(
+                    f"falcon_h1 with mamba_d_ssm={d['mamba_d_ssm']} is not "
+                    f"implemented (only mamba_n_heads x mamba_d_head = "
+                    f"{d['mamba_n_heads'] * d['mamba_d_head']})")
+            layer_kinds = ("mix+dense",) * d["num_hidden_layers"]
         if mt == "ouro" and set(d.get("layer_types") or ()) \
                 - {"full_attention"}:
             raise ValueError(
@@ -716,7 +811,8 @@ class ModelConfig:
             # a head_dim its config.json also carries (JoyAI-LLM-Flash:
             # 64, the rope part) is no width of any of its tensors.
             head_dim=None if _dsk else d.get("head_dim"),
-            rope_theta=d.get("rope_theta", 10000.0),
+            # float: a published 100000000000 (Falcon-H1) overflows int32
+            rope_theta=float(d.get("rope_theta", 10000.0)),
             rms_norm_eps=d.get("rms_norm_eps", 1e-5),
             max_position_embeddings=d.get("max_position_embeddings", 4096),
             tie_word_embeddings=d.get("tie_word_embeddings",
@@ -784,7 +880,27 @@ class ModelConfig:
             moe_scoring="sigmoid" if _v3 or _lfm else "softmax",
             moe_gate_eps=1e-6 if _lfm else 1e-20,
             layer_kinds=layer_kinds,
-            conv_kernel=int(d.get("conv_L_cache", 0)) if _lfm else 0,
+            conv_kernel=(int(d.get("conv_L_cache", 0)) if _lfm
+                         else int(d["mamba_d_conv"]) if _fh1 else 0),
+            **({"ssm_heads": int(d["mamba_n_heads"]),
+                "ssm_head_dim": int(d["mamba_d_head"]),
+                "ssm_state": int(d["mamba_d_state"]),
+                "ssm_groups": int(d["mamba_n_groups"]),
+                "ssm_chunk": int(d["mamba_chunk_size"]),
+                "embedding_multiplier": float(d["embedding_multiplier"]),
+                "lm_head_multiplier": float(d["lm_head_multiplier"]),
+                "attention_in_multiplier":
+                    float(d["attention_in_multiplier"]),
+                "key_multiplier": float(d["key_multiplier"]),
+                "attention_out_multiplier":
+                    float(d["attention_out_multiplier"]),
+                "ssm_in_multiplier": float(d["ssm_in_multiplier"]),
+                "ssm_multipliers":
+                    tuple(float(x) for x in d["ssm_multipliers"]),
+                "ssm_out_multiplier": float(d["ssm_out_multiplier"]),
+                "mlp_multipliers":
+                    tuple(float(x) for x in d["mlp_multipliers"])}
+               if _fh1 else {}),
             gptoss=mt == "gpt_oss",
             rope_interleave=bool(d.get("rope_interleave", True)),
             # The mscale² softmax-scale fold follows the CHECKPOINT, not

@@ -144,7 +144,11 @@ def num_params(params: Params) -> int:
 
 
 def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
-                  dtype: Optional[jnp.dtype] = None) -> KVCache:
+                  dtype: Optional[jnp.dtype] = None,
+                  state_slots: int = 0) -> KVCache:
+    """The pools. ``state_slots``: slots of the fourth pool, which a model
+    with a mixer beside attention keeps (``cfg.num_ssm_layers``) and no
+    other does."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     if cfg.layer_kinds is not None:
         # Keys and values of the ATTENTION layers alone, and a third
@@ -156,9 +160,24 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
         shape = (max(cfg.num_attn_layers, 1), num_pages, page_size,
                  cfg.num_kv_heads // pack, pack * cfg.head_dim)
         tails = (max(cfg.num_conv_layers, 1), num_pages,
-                 max(cfg.conv_kernel - 1, 1) * cfg.hidden_size)
-        return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
-                jnp.zeros(tails, dtype))
+                 cfg.conv_tail_width)
+        pools = (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
+                 jnp.zeros(tails, dtype))
+        if cfg.num_ssm_layers:
+            # A fourth pool, addressed by SLOT and not by page: a layer's
+            # state of one sequence is heads x head width x state values
+            # in float32 (4.2 MB at 32 x 128 x 256), sixteen times the
+            # layer's keys and values of a page. Slot 0 is the null slot
+            # (padding rows), as page 0 is the null page; who owns which
+            # slot is the host's business (runtime/kv_cache.py). A
+            # head's matrix is stored [state, head width]: the head
+            # width rides the lanes, as the row's x and y do, so the
+            # decode kernel broadcasts its operands along an axis they
+            # already lack (ops/pallas/ssm_update.py).
+            pools += (jnp.zeros(
+                (cfg.num_ssm_layers, max(state_slots, 2), cfg.ssm_heads,
+                 cfg.ssm_state, cfg.ssm_head_dim), jnp.float32),)
+        return pools
     # One slot a layer a PASS (``ModelConfig.kv_cache_layers``): pass p
     # of a looped model keeps layer l's keys and values at p * L + l.
     shape = (cfg.kv_cache_layers, num_pages, page_size, cfg.kv_cache_heads,
@@ -483,6 +502,7 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                     return_stats: bool = False,
                     rope_pos: Optional[jnp.ndarray] = None,
                     plan: KernelPlan = KernelPlan(),
+                    state_cols: Optional[jnp.ndarray] = None,
                     ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], KVCache]:
     """Prefill ``tokens`` [B, T] (padded; true new-token counts in
     ``lengths``; nonzero ``start_pos`` = prefix-cache hit, those tokens are
@@ -554,7 +574,7 @@ def forward_prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             params, cfg, tokens, start_pos, lengths, kv, page_table,
             return_all_logits=return_all_logits,
             prompt_lp_targets=prompt_lp_targets,
-            return_stats=return_stats, plan=plan)
+            return_stats=return_stats, plan=plan, state_cols=state_cols)
     k_pages, v_pages = kv
     write_then_attend = plan.write_then_attend
     x = _scale_embed(cfg, params["embed"][tokens]
@@ -950,6 +970,7 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                    return_stats: bool = False,
                    rope_delta: Optional[jnp.ndarray] = None,
                    plan: KernelPlan = KernelPlan(),
+                   state_rows: Optional[jnp.ndarray] = None,
                    ) -> Tuple[jnp.ndarray, KVCache]:
     """One decode step for ``tokens`` [B] at ``positions`` [B]
     (``active`` [B] bool masks empty batch slots). Returns
@@ -974,7 +995,8 @@ def forward_decode(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if cfg.layer_kinds is not None:
         return _kinds_forward_decode(params, cfg, tokens, positions,
                                      active, kv, page_table,
-                                     return_stats=return_stats, plan=plan)
+                                     return_stats=return_stats, plan=plan,
+                                     state_rows=state_rows)
     k_pages, v_pages = kv
     write_then_attend = plan.write_then_attend
     x = _scale_embed(cfg, params["embed"][tokens[:, None]]
@@ -1630,7 +1652,21 @@ def _init_kinds_params(cfg: ModelConfig, key: jax.Array,
         if op == "conv":
             st.update(conv_in=w((n, D, 3 * D), D), conv_w=w((n, K, D), K),
                       conv_out=w((n, D, D), D))
-        else:
+        if op == "mix":
+            Hs, I, C = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
+            # The published input projection's three column blocks, kept
+            # apart (z | xBC | dt), so that each product reads its own
+            # weight where it lies.
+            st.update(ssm_in_z=w((n, D, I), D), ssm_in_xbc=w((n, D, C), D),
+                      ssm_in_dt=w((n, D, Hs), D),
+                      ssm_conv_w=w((n, K, C), K),
+                      ssm_conv_b=jnp.zeros((n, C), dtype),
+                      ssm_dt_bias=jnp.zeros((n, Hs), jnp.float32),
+                      ssm_a_log=jnp.zeros((n, Hs), jnp.float32),
+                      ssm_d=jnp.ones((n, Hs), jnp.float32),
+                      ssm_norm=jnp.ones((n, I), dtype),
+                      ssm_out=w((n, I, D), I))
+        if op != "conv":
             st.update(q_proj=w((n, D, Hq * Dh), D),
                       k_proj=w((n, D, Hkv * Dh), D),
                       v_proj=w((n, D, Hkv * Dh), D),
@@ -1688,22 +1724,24 @@ def kinds_pattern(kinds: Tuple[str, ...]) -> Tuple[int, int, int]:
 
 def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                   pools, conv_op, attn_op, valid: jnp.ndarray,
-                  plan: KernelPlan):
+                  plan: KernelPlan, mix_op=None):
     """The layer loop over ``cfg.layer_kinds``. ``pools`` = (k, v,
-    tails), carried and updated in place; ``conv_op(lp, h, tails, c) ->
-    (y, tails)`` and ``attn_op(lp, h, k, v, a) -> (y, k, v)`` are the
-    caller's (prefill's or decode's), ``c`` / ``a`` the layer's index
-    among the convolution / attention layers. Returns ``(x, pools,
-    moe_stats)``."""
+    tails) and, for a model with mixers, the pool of states after them,
+    carried and updated in place; ``conv_op(lp, h, tails, c) -> (y,
+    tails)``, ``attn_op(lp, h, k, v, a) -> (y, k, v)`` and ``mix_op(lp,
+    h, pools, a, c) -> (y, pools)`` (attention and a mixer on the same
+    input) are the caller's (prefill's or decode's), ``a`` / ``c`` the
+    layer's index among the layers that keep keys and values / a
+    convolution tail. Returns ``(x, pools, moe_stats)``."""
     kinds = cfg.layer_kinds
     lead, period, repeats = kinds_pattern(kinds)
 
     def tally(span) -> Dict[str, int]:
-        # how many layers of each kind, attention layers ("attn") and
-        # convolution layers ("conv") ``span`` holds
+        # how many layers of each kind, layers that attend ("attn") and
+        # layers that keep a convolution tail ("conv") ``span`` holds
         r = {k: span.count(k) for k in set(kinds)}
-        r["attn"] = sum(k.startswith("attn+") for k in span)
-        r["conv"] = sum(k.startswith("conv+") for k in span)
+        r["attn"] = sum(k.startswith(("attn+", "mix+")) for k in span)
+        r["conv"] = sum(k.startswith(("conv+", "mix+")) for k in span)
         return r
 
     def body(kind: str):
@@ -1713,17 +1751,21 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             else (stack, None)
 
         def layer(carry, s, a, c):
-            """Layer ``s`` of this kind's stack, the ``a``-th attention
-            or ``c``-th convolution layer of the model."""
-            x, kp, vp, tails, stats = carry
+            """Layer ``s`` of this kind's stack, the ``a``-th that
+            attends and the ``c``-th that keeps a tail."""
+            x, pools, stats = carry[0], carry[1:-1], carry[-1]
             lp = jax.tree_util.tree_map(
                 lambda w: jax.lax.dynamic_index_in_dim(
                     w, s, axis=0, keepdims=False), small)
             h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
             if op == "conv":
-                y, tails = conv_op(lp, h, tails, c)
+                y, tails = conv_op(lp, h, pools[2], c)
+                pools = pools[:2] + (tails,) + pools[3:]
+            elif op == "attn":
+                y, kp, vp = attn_op(lp, h, pools[0], pools[1], a)
+                pools = (kp, vp) + pools[2:]
             else:
-                y, kp, vp = attn_op(lp, h, kp, vp, a)
+                y, pools = mix_op(lp, h, pools, a, c)
             x = x + y
             h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
             if ffn == "moe":
@@ -1731,9 +1773,15 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                                           valid=valid, plan=plan)
                 stats = stats + st
             else:
-                m = (jax.nn.silu(h @ lp["gate_proj"])
-                     * (h @ lp["up_proj"])) @ lp["down_proj"]
-            return (x + m, kp, vp, tails, stats)
+                gate_m, down_m = cfg.mlp_multipliers
+                gate = h @ lp["gate_proj"]
+                if gate_m != 1.0:
+                    gate = gate * jnp.asarray(gate_m, gate.dtype)
+                m = (jax.nn.silu(gate) * (h @ lp["up_proj"])) \
+                    @ lp["down_proj"]
+                if down_m != 1.0:
+                    m = m * jnp.asarray(down_m, m.dtype)
+            return (x + m,) + pools + (stats,)
         return layer
 
     def walk(carry, span, at: Dict[str, int], step, r):
@@ -1754,7 +1802,8 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                     (layer(cr, s0 + j, a0 + j, c0 + j), None),
                     carry, jnp.arange(count, dtype=jnp.int32))
             at[kind] += count
-            at[op] += count
+            for rank in (("attn", "conv") if op == "mix" else (op,)):
+                at[rank] += count
         return carry
 
     carry = (x,) + tuple(pools) + (
@@ -1769,7 +1818,7 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             carry, jnp.arange(repeats, dtype=jnp.int32))
     done = lead + period * repeats
     carry = walk(carry, kinds[done:], tally(kinds[:done]), {}, zero)
-    return carry[0], carry[1:4], carry[4]
+    return carry[0], carry[1:-1], carry[-1]
 
 
 def _conv_mix(cfg: ModelConfig, lp, h: jnp.ndarray, tail: jnp.ndarray):
@@ -1830,6 +1879,244 @@ def _tails_write(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
         rows.reshape(pid.size, -1), mode="drop")
 
 
+# ---------------------------------------------------------------------------
+# A mixer beside attention (operator "mix"; Falcon-H1)
+#
+# A layer's operator is grouped-query attention AND a Mamba-2 mixer on
+# the same normed input, their outputs summed. The mixer's state of one
+# sequence is a matrix a head, ``S [heads, head width, state]`` in
+# float32: ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (outer) B_t``, ``y_t =
+# S_t C_t + D x_t``. It lives in the fourth pool (each head's matrix
+# transposed, [state, head width]), ADDRESSED BY SLOT (a
+# state is sixteen pages' worth of a layer's keys and values: a row
+# beside every page, as the tails have, would not fit). Which slot is
+# whose is the host's business (runtime/kv_cache.py, runtime/engine.py);
+# a step program is told, per row, what to read and what to write:
+#
+# - decode: the row's ``state_rows`` entry r >= 1 owns slots 2r - 1 and
+#   2r. The state as of position t lives in slot 2r - 1 + t mod 2:
+#   the step at t reads the other one and writes this one, so a step
+#   that was launched ahead, discarded and run again reads what the
+#   first read (a page's keys and values are re-written at the same
+#   place; a state that a discarded step had advanced in place would be
+#   advanced twice);
+# - prefill: ``state_cols`` [B, 4] = the slot the window starts from (0:
+#   from zero), the slot its final state goes to, a snapshot slot (0:
+#   none) and how many of the window's tokens the snapshot is taken
+#   after: the state at a page boundary that the prefix index can hand
+#   to a later request whose prompt shares the pages up to there.
+#
+# The short convolution's tail rides the third pool, one row a page, as
+# a "conv" layer's does, but as a RING: the input at position t in ring
+# row t mod conv_kernel. A decode step reads the three rows behind its
+# position and writes its own, never one it reads, for the same reason
+# as above (a "conv" layer's tail, shifted in place, is not safe under a
+# discarded launch: PERF.md section 7).
+#
+# Prefill runs the chunked form of the recurrence (chunks of
+# ``cfg.ssm_chunk``; plain XLA einsums), decode the one-token form (the
+# Pallas kernel of ops/pallas/ssm_update.py where ``plan.ssm_decode``).
+# ---------------------------------------------------------------------------
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _ssm_in(cfg: ModelConfig, lp, h: jnp.ndarray):
+    """The mixer's input projection of h [B, T, D]: z [B, T, I] (the
+    gate), xBC [B, T, C] (before the filter), dt [B, T, H] (before its
+    bias), each segment scaled as published (``ssm_multipliers`` over z,
+    x, B, C, dt)."""
+    m = cfg.ssm_multipliers
+    if cfg.ssm_in_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.ssm_in_multiplier, h.dtype)
+    z, xbc, dt = _pin((h @ lp["ssm_in_z"], h @ lp["ssm_in_xbc"],
+                       h @ lp["ssm_in_dt"]))
+    gn = cfg.ssm_groups * cfg.ssm_state
+    scale = jnp.concatenate([jnp.full((cfg.ssm_inner,), m[1], jnp.float32),
+                             jnp.full((gn,), m[2], jnp.float32),
+                             jnp.full((gn,), m[3], jnp.float32)])
+    return (z * jnp.asarray(m[0], z.dtype), xbc * scale.astype(xbc.dtype),
+            dt * jnp.asarray(m[4], dt.dtype))
+
+
+def _ssm_conv(cfg: ModelConfig, lp, xbc: jnp.ndarray, prev: jnp.ndarray):
+    """silu(causal depthwise filter + bias) over xBC [B, T, C] with the
+    K - 1 inputs before it, ``prev`` [B, K-1, C]. Returns ``(u, zz)``,
+    ``zz`` [B, K-1+T, C] the inputs one after the other."""
+    K, T = cfg.conv_kernel, xbc.shape[1]
+    zz = jnp.concatenate([prev.astype(xbc.dtype), xbc], axis=1)
+    w = lp["ssm_conv_w"].astype(jnp.float32)                   # [K, C]
+    acc = sum(w[j] * zz[:, j:j + T].astype(jnp.float32) for j in range(K))
+    acc = acc + lp["ssm_conv_b"].astype(jnp.float32)
+    return jax.nn.silu(acc).astype(xbc.dtype), zz
+
+
+def _ssm_split(cfg: ModelConfig, u: jnp.ndarray):
+    """u [B, T, C] -> x [B, T, H, P], B and C [B, T, G, N], float32."""
+    B, T, _ = u.shape
+    I, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
+    u = u.astype(jnp.float32)
+    return (u[..., :I].reshape(B, T, cfg.ssm_heads, cfg.ssm_head_dim),
+            u[..., I:I + gn].reshape(B, T, cfg.ssm_groups, cfg.ssm_state),
+            u[..., I + gn:].reshape(B, T, cfg.ssm_groups, cfg.ssm_state))
+
+
+def _ssm_out(cfg: ModelConfig, lp, y: jnp.ndarray, z: jnp.ndarray):
+    """y [B, T, H, P] float32 gated by silu(z), normed in
+    ``ssm_groups`` groups (``mamba_rms_norm``, the gate BEFORE the norm),
+    projected back to [B, T, D]."""
+    B, T = y.shape[:2]
+    I, G = cfg.ssm_inner, cfg.ssm_groups
+    y = y.reshape(B, T, G, I // G) * jax.nn.silu(
+        z.astype(jnp.float32)).reshape(B, T, G, I // G)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                          + cfg.rms_norm_eps)
+    y = (y.reshape(B, T, I) * lp["ssm_norm"].astype(jnp.float32)
+         ).astype(z.dtype)
+    out = y @ lp["ssm_out"]
+    if cfg.ssm_out_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.ssm_out_multiplier, out.dtype)
+    return out
+
+
+def _ssd(cfg: ModelConfig, x, dt, A, Bm, Cm, S0):
+    """The chunked form of the recurrence over a window: x [B, T, H, P],
+    dt [B, T, H] (0 where a position must not move the state: padding),
+    A [H] (negative), Bm and Cm [B, T, G, N], S0 [B, H, N, P] (a head's
+    matrix as the pool keeps it, [state, head width]: a transpose here
+    would settle its layout on the pool, which every prefill program
+    then copied whole, in and out, 12 ms a window on the chip, PR 45);
+    float32. Returns ``(y, S)``: y [B, T, H, P] without the D term, S
+    the state after the window's last position. ``Cm`` None: the state
+    alone.
+
+    Within a chunk of Q positions the output is a masked [Q, Q] product
+    (decay from s to t times C_t . B_s); across chunks a scan carries
+    the state, which each position of the next chunk reads decayed."""
+    B, T, H, P = x.shape
+    G, N = Bm.shape[2:]
+    J = H // G                                # heads a group
+    Q = min(cfg.ssm_chunk, T)
+    assert T % Q == 0, (T, Q)
+    nc = T // Q
+    dt = dt.reshape(B, nc, Q, G, J)
+    dtx = dt[..., None] * x.reshape(B, nc, Q, G, J, P)
+    Bs = Bm.reshape(B, nc, Q, G, N)
+    cum = jnp.cumsum(dt * A.reshape(G, J), axis=2)   # log decay, inclusive
+    total = cum[:, :, -1]                                  # [B, nc, G, J]
+    # a chunk's own contribution to the state at its end
+    local = jnp.einsum(
+        "bcqgjp,bcqgn->bcgjnp",
+        jnp.exp(total[:, :, None] - cum)[..., None] * dtx, Bs,
+        precision=_HIGHEST)
+
+    def step(S, inp):
+        tot, loc = inp
+        return jnp.exp(tot)[..., None, None] * S + loc, S
+
+    S, starts = jax.lax.scan(
+        step, S0.reshape(B, G, J, N, P),
+        (jnp.moveaxis(total, 1, 0), jnp.moveaxis(local, 1, 0)))
+    S = S.reshape(B, H, N, P)
+    if Cm is None:
+        return None, S
+    Cs = Cm.reshape(B, nc, Q, G, N)
+    # what the chunk's positions read of the state it started from
+    y = jnp.einsum("bcqgn,cbgjnp->bcqgjp", Cs, starts,
+                   precision=_HIGHEST) * jnp.exp(cum)[..., None]
+    # and of the chunk's own earlier positions
+    scores = jnp.einsum("bcqgn,bcsgn->bcgqs", Cs, Bs, precision=_HIGHEST)
+    ct = jnp.moveaxis(cum, 2, -1)                       # [B, nc, G, J, Q]
+    seg = ct[..., :, None] - ct[..., None, :]           # from s to t
+    causal = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    y = y + jnp.einsum("bcgjqs,bcsgjp->bcqgjp",
+                       scores[:, :, :, None] * decay, dtx,
+                       precision=_HIGHEST)
+    return y.reshape(B, T, H, P), S
+
+
+def _ssm_step(cfg: ModelConfig, state, c, read, write, x, dt, A, Bm, Cm,
+              plan: KernelPlan):
+    """One token a row, in place in the pool: reads layer ``c``'s slots
+    ``read`` [B], writes ``write`` [B]. x [B, H, P], dt [B, H] (0 on an
+    inactive row, whose slots are the null slot), Bm and Cm [B, G, N].
+    Returns ``(y [B, H, P] without the D term, state)``."""
+    if plan.ssm_decode:
+        from xllm_service_tpu.ops.pallas.ssm_update import ssm_decode_update
+        return ssm_decode_update(state, c, read, write, x, dt, A, Bm, Cm,
+                                 interpret=plan.interpret)
+    J = cfg.ssm_heads // cfg.ssm_groups
+    Bh, Ch = jnp.repeat(Bm, J, axis=1), jnp.repeat(Cm, J, axis=1)
+    # the pool's [state, head width] matrices
+    S = jax.lax.dynamic_index_in_dim(state, c, axis=0, keepdims=False)[read]
+    S = jnp.exp(dt * A)[..., None, None] * S \
+        + Bh[:, :, :, None] * (dt[..., None] * x)[:, :, None, :]
+    y = jnp.einsum("bhnp,bhn->bhp", S, Ch, precision=_HIGHEST)
+    return y, state.at[c, write].set(S)
+
+
+def _ring_read(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
+               positions: jnp.ndarray, ps: int) -> jnp.ndarray:
+    """[B, K-1, C]: the mixer's convolution inputs at the K - 1 positions
+    before ``positions`` [B], oldest first, from the ring of the page
+    that holds the position before; zeros before a sequence's start."""
+    K, C = cfg.conv_kernel, cfg.ssm_conv_dim
+    before = jnp.maximum(positions - 1, 0)
+    pid = jnp.take_along_axis(page_table, (before // ps)[:, None],
+                              axis=1)[:, 0]
+    ring = tails[c, pid].reshape(-1, K, C)
+    at = positions[:, None] - (K - 1) \
+        + jnp.arange(K - 1, dtype=jnp.int32)[None, :]            # [B, K-1]
+    rows = jnp.take_along_axis(ring, jnp.mod(at, K)[:, :, None], axis=1)
+    return jnp.where((at >= 0)[:, :, None], rows,
+                     jnp.zeros((), rows.dtype))
+
+
+def _ring_write(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
+                start: jnp.ndarray, lengths: jnp.ndarray,
+                zz: jnp.ndarray, ps: int) -> jnp.ndarray:
+    """Write the mixer's ring of every page that the windows [start,
+    start + lengths) touch: page p's ring holds the last K inputs as of
+    the LAST of the window's positions that lies in p, the input at
+    position t in ring row t mod K. ``zz`` [B, K-1+T, C] is
+    ``_ssm_conv``'s (the input at window position i is ``zz[:, i+K-1]``);
+    a row of length 0 writes nothing."""
+    K = cfg.conv_kernel
+    B, T = zz.shape[0], zz.shape[1] - (K - 1)
+    P, MP = tails.shape[1], page_table.shape[1]
+    j = jnp.arange(-(-T // ps) + 1 if T > 1 else 1, dtype=jnp.int32)
+    last = start + lengths - 1                                   # [B]
+    page = start[:, None] // ps + j[None, :]                     # [B, J]
+    touched = (lengths[:, None] > 0) & (page * ps <= last[:, None])
+    end = jnp.minimum(page * ps + ps - 1, last[:, None])         # absolute
+    # ring row r holds the latest position <= end that is r mod K
+    r = jnp.arange(K, dtype=jnp.int32)[None, None, :]
+    pos = end[:, :, None] - jnp.mod(end[:, :, None] - r, K)      # [B, J, K]
+    at = jnp.clip(pos - start[:, None, None] + (K - 1), 0, K + T - 2)
+    rows = jax.vmap(lambda z, i: z[i])(zz, at)                   # [B,J,K,C]
+    pid = jnp.take_along_axis(page_table, jnp.minimum(page, MP - 1),
+                              axis=1)
+    pid = jnp.where(touched, pid, P)            # past the pool: dropped
+    return tails.at[c, pid.reshape(-1)].set(
+        rows.reshape(pid.size, -1), mode="drop")
+
+
+def _mixer(cfg: ModelConfig, lp, h, prev, valid, scan):
+    """The mixer over h [B, T, D]: ``prev`` [B, K-1, C] the convolution
+    inputs before the window, ``valid`` [B, T] the positions that move
+    the state, ``scan(x, dt, A, Bm, Cm) -> (y, extra)`` the recurrence
+    (prefill's or decode's). Returns ``(out [B, T, D], zz, extra)``."""
+    z, xbc, dt = _ssm_in(cfg, lp, h)
+    u, zz = _ssm_conv(cfg, lp, xbc, prev)
+    x, Bm, Cm = _ssm_split(cfg, u)
+    dt = jnp.where(valid[..., None], jax.nn.softplus(
+        dt.astype(jnp.float32) + lp["ssm_dt_bias"]), 0.0)
+    y, extra = scan(x, dt, -jnp.exp(lp["ssm_a_log"]), Bm, Cm)
+    y = y + lp["ssm_d"][:, None] * x
+    return _ssm_out(cfg, lp, y, z), zz, extra
+
+
 def _kv_pack(cfg: ModelConfig) -> int:
     """Key-value heads that share one row of the pools. A TPU tiles an
     array's last axis in 128 lanes: a pool of 8 heads of 64 is stored
@@ -1877,6 +2164,38 @@ def _kinds_head(params: Params, cfg: ModelConfig, x: jnp.ndarray):
     return x, (params["embed"].T if head is None else head)
 
 
+def _kinds_embed(params: Params, cfg: ModelConfig, tokens: jnp.ndarray):
+    x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def _kinds_logits(cfg: ModelConfig, x: jnp.ndarray, head: jnp.ndarray):
+    logits = _head_logits(cfg, x, head)
+    if cfg.lm_head_multiplier != 1.0:
+        logits = logits * cfg.lm_head_multiplier
+    return logits
+
+
+def _attn_in(cfg: ModelConfig, lp, h: jnp.ndarray):
+    """q, k, v of the normed input, with the multipliers a family puts
+    on the attention branch's input and on its keys (1: none)."""
+    if cfg.attention_in_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.attention_in_multiplier, h.dtype)
+    q, k, v = _qkv(lp, cfg, h)
+    if cfg.key_multiplier != 1.0:
+        k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
+    return q, k, v
+
+
+def _attn_out(cfg: ModelConfig, lp, attn: jnp.ndarray):
+    out = attn @ lp["o_proj"]
+    if cfg.attention_out_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.attention_out_multiplier, out.dtype)
+    return out
+
+
 def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
                            tokens: jnp.ndarray, start_pos: jnp.ndarray,
                            lengths: jnp.ndarray, kv: KVCache,
@@ -1884,10 +2203,11 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
                            return_all_logits: bool = False,
                            prompt_lp_targets: Optional[jnp.ndarray] = None,
                            return_stats: bool = False,
-                           plan: KernelPlan = KernelPlan()):
+                           plan: KernelPlan = KernelPlan(),
+                           state_cols: Optional[jnp.ndarray] = None):
     B, T = tokens.shape
     ps = kv[0].shape[2]
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
+    x = _kinds_embed(params, cfg, tokens)
     positions = start_pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     kv_lengths = start_pos + lengths
     tok_valid = (jnp.arange(T, dtype=jnp.int32)[None, :]
@@ -1901,7 +2221,7 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
                                lengths, zz, ps)
 
     def attn_op(lp, h, kp, vp, a):
-        q, k, v = _qkv(lp, cfg, h)
+        q, k, v = _attn_in(cfg, lp, h)
         q = rope_for(cfg.rope_scaling, q, positions, cfg.rope_theta)
         k = rope_for(cfg.rope_scaling, k, positions, cfg.rope_theta)
         q, k, v, unpack = _packed_qkv(cfg, q, k, v)
@@ -1923,16 +2243,50 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
                 gather_pages(jax.lax.dynamic_index_in_dim(
                     vp, a, axis=0, keepdims=False), page_table),
                 kv_lengths, start_pos, scale=scale)
-        return unpack(attn).reshape(B, T, -1) @ lp["o_proj"], kp, vp
+        return _attn_out(cfg, lp, unpack(attn).reshape(B, T, -1)), kp, vp
+
+    def mix_op(lp, h, pools, a, c):
+        kp, vp, tails, state = pools
+        ya, kp, vp = attn_op(lp, h, kp, vp, a)
+        src, dst, snap, snap_len = (state_cols[:, i] for i in range(4))
+        n_slots = state.shape[1]
+        layer_state = jax.lax.dynamic_index_in_dim(state, c, axis=0,
+                                                   keepdims=False)
+        S0 = jnp.where((src > 0)[:, None, None, None], layer_state[src],
+                       0.0)
+
+        def scan(x_s, dt, A, Bm, Cm):
+            # The state a later request resumes from: the window's,
+            # stopped after ``snap_len`` of its tokens (the positions
+            # behind them do not move it).
+            keep = jnp.arange(T, dtype=jnp.int32)[None, :] \
+                < snap_len[:, None]
+            S_snap = _ssd(cfg, x_s, jnp.where(keep[..., None], dt, 0.0),
+                          A, Bm, None, S0)[1]
+            y, S = _ssd(cfg, x_s, dt, A, Bm, Cm, S0)
+            return y, (S, S_snap)
+
+        ym, zz, (S, S_snap) = _mixer(cfg, lp, h, _ring_read(
+            cfg, tails, c, page_table, start_pos, ps), tok_valid, scan)
+        tails = _ring_write(cfg, tails, c, page_table, start_pos, lengths,
+                            zz, ps)
+        # a slot past the pool: nothing is written (mode="drop")
+        state = state.at[c, jnp.where(lengths > 0, dst, n_slots)].set(
+            S, mode="drop")
+        state = state.at[c, jnp.where(snap > 0, snap, n_slots)].set(
+            S_snap, mode="drop")
+        return ya + ym, (kp, vp, tails, state)
 
     x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
-                                     tok_valid, plan)
+                                     tok_valid, plan, mix_op)
     x, head = _kinds_head(params, cfg, x)
     last_idx = jnp.maximum(lengths - 1, 0)
     last_x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-    outs = [_head_logits(cfg, last_x, head),
-            _head_logits(cfg, x, head) if return_all_logits else None, kv]
+    outs = [_kinds_logits(cfg, last_x, head),
+            _kinds_logits(cfg, x, head) if return_all_logits else None, kv]
     if prompt_lp_targets is not None:
+        if cfg.lm_head_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.lm_head_multiplier, x.dtype)
         outs.append(_prompt_logprobs(x, head, prompt_lp_targets))
     if return_stats:
         outs.append(_moe_stats_dict(moe_stats))
@@ -1944,10 +2298,11 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
                           active: jnp.ndarray, kv: KVCache,
                           page_table: jnp.ndarray,
                           return_stats: bool = False,
-                          plan: KernelPlan = KernelPlan()):
+                          plan: KernelPlan = KernelPlan(),
+                          state_rows: Optional[jnp.ndarray] = None):
     B = tokens.shape[0]
     ps = kv[0].shape[2]
-    x = params["embed"][tokens[:, None]].astype(jnp.dtype(cfg.dtype))
+    x = _kinds_embed(params, cfg, tokens[:, None])
     pos2 = positions[:, None]
     one = active.astype(jnp.int32)          # an inactive lane: length 0
     scale = cfg.head_dim ** -0.5        # of the head, not the packed row
@@ -1959,7 +2314,7 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
                                zz, ps)
 
     def attn_op(lp, h, kp, vp, a):
-        q, k, v = _qkv(lp, cfg, h)
+        q, k, v = _attn_in(cfg, lp, h)
         q = rope_for(cfg.rope_scaling, q, pos2, cfg.rope_theta)
         k = rope_for(cfg.rope_scaling, k, pos2, cfg.rope_theta)
         q, k, v, unpack = _packed_qkv(cfg, q, k, v)
@@ -1970,12 +2325,33 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
             q[:, 0], kp, vp, page_table,
             jnp.where(active, positions + 1, 0), plan, scale=scale,
             layer=a)
-        return unpack(attn).reshape(B, 1, -1) @ lp["o_proj"], kp, vp
+        return _attn_out(cfg, lp, unpack(attn).reshape(B, 1, -1)), kp, vp
+
+    def mix_op(lp, h, pools, a, c):
+        kp, vp, tails, state = pools
+        ya, kp, vp = attn_op(lp, h, kp, vp, a)
+        # The state as of position t is in the row's slot t mod 2: read
+        # the other one, write this one (an inactive lane: the null slot).
+        mine = 2 * state_rows - 1
+        read = jnp.where(active, mine + (positions + 1) % 2, 0)
+        write = jnp.where(active, mine + positions % 2, 0)
+
+        def scan(x_s, dt, A, Bm, Cm):
+            y, moved = _ssm_step(cfg, state, c, read, write, x_s[:, 0],
+                                 dt[:, 0], A, Bm[:, 0], Cm[:, 0], plan)
+            return y[:, None], moved
+
+        ym, zz, state = _mixer(cfg, lp, h, _ring_read(
+            cfg, tails, c, page_table, positions, ps), active[:, None],
+            scan)
+        tails = _ring_write(cfg, tails, c, page_table, positions, one, zz,
+                            ps)
+        return ya + ym, (kp, vp, tails, state)
 
     x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
-                                     active[:, None], plan)
+                                     active[:, None], plan, mix_op)
     x, head = _kinds_head(params, cfg, x)
-    logits = _head_logits(cfg, x[:, 0], head)
+    logits = _kinds_logits(cfg, x[:, 0], head)
     if return_stats:
         return logits, kv, _moe_stats_dict(moe_stats)
     return logits, kv

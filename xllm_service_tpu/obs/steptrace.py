@@ -85,6 +85,14 @@ STEP_FIELDS: Tuple[str, ...] = (
                         # computed position read a cached page's
                         # convolution tails (a prefix hit), else 0; None
                         # for a model whose cached state is pages alone
+    "state",            # a model whose state lives by slot (a matrix a
+                        # head a layer): {live, snapshots} slots held
+                        # after the step (rows with a live state,
+                        # snapshots under the prefix index) and this
+                        # step's {restored, snapshotted, evicted}
+                        # (admissions begun from a snapshot's copy,
+                        # snapshots attached to a page, snapshots
+                        # dropped); None for every other model
 )
 
 # ---------------------------------------------------------------------------
@@ -118,6 +126,10 @@ SPAN_NAMES: Tuple[str, ...] = (
     "xllm.loop.idle_wait",   # _work_event.wait when no runtime had work
     "xllm.kv.match_prefix",  # PrefixCacheIndex.match_prefix; arg tokens
     "xllm.kv.register_pages",  # PrefixCacheIndex.register_pages; arg tokens
+    "xllm.kv.state_slots",   # Engine: a snapshot slot reserved (the
+                             # least recently hit evicted for it) in
+                             # prefill.pack, or attached to its page in
+                             # prefill.post; arg snapshots
     "xllm.admit",            # handler thread: parsed request -> enqueued
     "xllm.admit.lock_wait",  # ... waiting for _engine_lock
     "xllm.admit.locked",     # ... holding it (Engine.add_request)

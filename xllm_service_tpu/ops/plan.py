@@ -45,6 +45,11 @@ class KernelPlan:
     # the dropless expert layer's grouped matmuls: the Pallas one
     # (ops/pallas/grouped_matmul.py), else XLA's jax.lax.ragged_dot
     expert_gmm: bool = False
+    # a mixer's one-token state update (ops/pallas/ssm_update.py), in
+    # place in the pool of states by slot; else XLA's gather and scatter
+    # of the rows' states. (Its prefill is the chunked scan in XLA
+    # einsums under every plan: ``ssm_prefill`` below.)
+    ssm_decode: bool = False
     kv_writers: bool = False       # in-place KV writers, else XLA scatter
     # The engine serves a mixed iteration as ONE ragged program ...
     mixed_step: bool = False
@@ -64,7 +69,15 @@ class KernelPlan:
     def uses_kernels(self) -> bool:
         return (self.decode_attn or self.prefill_attn
                 or self.latent_decode or self.expert_gmm
-                or self.kv_writers)
+                or self.ssm_decode or self.kv_writers)
+
+    @property
+    def ssm_prefill(self) -> str:
+        """How a mixer's prefill window runs its recurrence: the chunked
+        form in XLA einsums, under every plan (a Pallas scan only once a
+        trace shows the XLA form as the prefill program's largest
+        operation: PERF.md, PR 45)."""
+        return "xla_chunked"
 
     def mixed_program(self) -> "KernelPlan":
         """The plan of the ragged mixed program: decode rows start
@@ -133,14 +146,27 @@ class KernelPlan:
             # plan of every model whose sparse layers are the dropless
             # one says so, and no other's (ModelConfig.dropless_experts).
             expert_gmm=base and model_cfg.dropless_experts,
+            # XLA's form gathers the rows' states out of the pool and
+            # scatters them back: the kernel maps each row's block by its
+            # slot and aliases the pool (PERF.md, PR 45).
+            ssm_decode=base and model_cfg.num_ssm_layers > 0,
             kv_writers=writers and mesh is None,
             # No ragged kernel for absorbed-MLA pools, no ragged rows in
             # the loop over layer kinds: they keep the split path.
             mixed_step=bool(mixed) and not model_cfg.mla and not kinds,
             write_then_attend=bool(wta) or kinds,
-            # A window start is a sum of earlier bucket sizes.
+            # A window start is a sum of earlier bucket sizes. An engine
+            # of a model whose state lives by slot ends a window inside
+            # a page only where it ends the prompt
+            # (Engine._window_cap), so every window STARTS on a page
+            # boundary whatever the ladder holds: its windows of whole
+            # pages take the in-place writer. (Under the scatter the
+            # compiler relays pools of a few hundred pages around every
+            # layer's write: 8 pool-sized copies in a prefill program of
+            # this family, compiled for a described v5e; PERF.md, PR 45.)
             page_aligned=all(b % engine_cfg.page_size == 0
-                             for b in engine_cfg.prefill_buckets),
+                             for b in engine_cfg.prefill_buckets)
+            or model_cfg.num_ssm_layers > 0,
             interpret=default_interpret())
 
 

@@ -169,6 +169,8 @@ def load_checkpoint(model_dir: str, cfg: ModelConfig,
         r = _PrefixRemap(r, "model.", "model.language_model.")
     if cfg.mla:
         return _load_mla_checkpoint(r, cfg, dtype, mesh)
+    if cfg.num_ssm_layers:
+        return _load_mix_checkpoint(r, cfg, dtype)
     if cfg.layer_kinds is not None:
         return _load_kinds_checkpoint(r, cfg, dtype)
 
@@ -536,6 +538,61 @@ def _load_kinds_checkpoint(r, cfg: ModelConfig, dtype):
         "embed": r.get("model.embed_tokens.weight").astype(dtype),
         "stacks": stacks,
         "final_norm": r.get("model.embedding_norm.weight").astype(dtype)}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = (t("lm_head.weight") if "lm_head.weight" in r
+                             else params["embed"].T).astype(dtype)
+    r.close()
+    return jax.tree_util.tree_map(jax.device_put, params)
+
+
+def _load_mix_checkpoint(r, cfg: ModelConfig, dtype):
+    """Falcon-H1 tree: ONE stack of the one kind ``mix+dense``
+    (models/transformer.py ``_init_kinds_params``), from the published
+    names: ``input_layernorm`` / ``pre_ff_layernorm``;
+    ``self_attn.{q, k, v, o}_proj``; ``mamba.in_proj`` (the ONE matrix
+    [z | xBC | dt, hidden], kept as its three blocks, each [hidden,
+    width]), ``mamba.conv1d.{weight, bias}`` (the depthwise filter
+    [C, 1, K] becomes [K, C]: tap j weighs the input K - 1 - j positions
+    back; zeros for a checkpoint without ``mamba_conv_bias``),
+    ``mamba.{dt_bias, A_log, D}`` (float32), ``mamba.norm``,
+    ``mamba.out_proj``; ``feed_forward.{gate, up, down}_proj``;
+    ``final_layernorm`` after the last layer. No multiplier is folded
+    into a weight: the step programs apply them."""
+    idxs = range(cfg.num_layers)
+    I, C = cfg.ssm_inner, cfg.ssm_conv_dim
+
+    def t(name):
+        return np.ascontiguousarray(r.get(name).T)
+
+    def stack(fmt, f=r.get, to=dtype):
+        return np.stack([f(fmt.format(i=i)) for i in idxs]).astype(to)
+
+    L, M = "model.layers.{i}.", "model.layers.{i}.mamba."
+    st = {"input_norm": stack(L + "input_layernorm.weight"),
+          "post_norm": stack(L + "pre_ff_layernorm.weight")}
+    for w in ("q_proj", "k_proj", "v_proj", "o_proj"):
+        st[w] = stack(L + "self_attn." + w + ".weight", t)
+    for w in ("gate_proj", "up_proj", "down_proj"):
+        st[w] = stack(L + "feed_forward." + w + ".weight", t)
+    in_proj = stack(M + "in_proj.weight", t)        # [n, hidden, z|xBC|dt]
+    st.update(
+        ssm_in_z=in_proj[:, :, :I], ssm_in_xbc=in_proj[:, :, I:I + C],
+        ssm_in_dt=in_proj[:, :, I + C:],
+        ssm_conv_w=stack(M + "conv1d.weight",
+                         lambda n: r.get(n)[:, 0, :].T),
+        ssm_conv_b=stack(M + "conv1d.bias",
+                         lambda n: r.get(n) if n in r
+                         else np.zeros((C,), np.float32)),
+        ssm_dt_bias=stack(M + "dt_bias", to=np.float32),
+        ssm_a_log=stack(M + "A_log", to=np.float32),
+        ssm_d=stack(M + "D", to=np.float32),
+        ssm_norm=stack(M + "norm.weight"),
+        ssm_out=stack(M + "out_proj.weight", t))
+    params: Dict[str, Any] = {
+        "embed": r.get("model.embed_tokens.weight").astype(dtype),
+        "stacks": {cfg.layer_kinds[0]: {k: np.ascontiguousarray(v)
+                                        for k, v in st.items()}},
+        "final_norm": r.get("model.final_layernorm.weight").astype(dtype)}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = (t("lm_head.weight") if "lm_head.weight" in r
                              else params["embed"].T).astype(dtype)

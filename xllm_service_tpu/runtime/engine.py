@@ -53,7 +53,8 @@ from xllm_service_tpu.ops.sampling import (
     SamplingTensors, compute_logprobs, compute_top_logprobs, sample_tokens,
     update_counts)
 from xllm_service_tpu.runtime.kv_cache import (
-    HostKvTier, KvCacheEvent, PageAllocator, PrefixCacheIndex)
+    HostKvTier, KvCacheEvent, PageAllocator, PrefixCacheIndex,
+    SlotAllocator)
 from xllm_service_tpu.utils.jaxcache import disable_compile_cache
 from xllm_service_tpu.utils.types import FinishReason, SamplingParams
 
@@ -65,6 +66,11 @@ logger = logging.getLogger(__name__)
 # [length, tokens..., page_table...].
 _PACK_COLS = 4          # decode header columns (tok, pos, active, rope_delta)
 _PREFILL_HDR = 2        # prefill header columns
+# Trailing prefill columns of a model whose state lives by slot (read
+# slot, write slot, snapshot slot, snapshot after how many of the
+# window's tokens: transformer, "A mixer beside attention"); its decode
+# block carries the row's state row in the rope-delta column.
+_STATE_COLS = 4
 _RING_HDR = 1           # ring-prefill header columns
 _BIAS_K = 8             # default sparse logit-bias columns (pow2-bucketed)
 
@@ -144,6 +150,12 @@ class Sequence:
     # ``tokens`` only grows, so preemption and fault reset keep them:
     # a page is hashed once in the life of the sequence.
     page_digests: List[bytes] = dataclasses.field(default_factory=list)
+    # A model whose state lives by slot: the state row the sequence owns
+    # from admission to finish or preemption (0: none; it holds slots
+    # 2r - 1 and 2r), and the snapshot slot its first window starts from
+    # (0: from a zero state at position 0).
+    state_row: int = 0
+    state_src: int = 0
 
     @property
     def num_prompt_tokens(self) -> int:
@@ -204,6 +216,9 @@ class Engine:
         # moves pages OUT of or INTO the pools is refused for such a
         # model (ROADMAP.md Reach A1), here, once, and at each door.
         self.pages_only = model_cfg.num_conv_layers == 0
+        # Does a sequence also carry a state that lives by SLOT (a mixer
+        # beside attention: a matrix a head a layer, the fourth pool)?
+        self.state_model = model_cfg.num_ssm_layers > 0
         if not self.pages_only:
             if mesh is not None:
                 raise ValueError(
@@ -211,10 +226,42 @@ class Engine:
                     "its per-kind weight stacks and its pool of tails have "
                     "no sharding rules (parallel/sharding.py)")
             logger.info(
-                "%s keeps a convolution tail beside each page: PD "
+                "%s keeps a convolution tail beside each page%s: PD "
                 "migration, host spill and cross-worker block fetch are "
                 "refused for it (pages move with (k, v) alone)",
-                model_cfg.name)
+                model_cfg.name,
+                " and a matrix state by slot" if self.state_model else "")
+        if self.state_model and engine_cfg.decode_steps > 1:
+            # A step launched ahead and discarded must leave every row's
+            # state as it was: the single step keeps TWO states a row,
+            # by the parity of the position; a burst of N steps would
+            # need N + 1.
+            raise ValueError(
+                f"decode_steps={engine_cfg.decode_steps}: a model with a "
+                f"matrix state by slot decodes one step a program (a "
+                f"discarded burst of N steps would need N + 1 states a "
+                f"row; the single step keeps two)")
+        # The buckets a window that does NOT end its prompt may take (the
+        # interleaver's quantum, ``_window_cap``): all of them, but for a
+        # state model whole pages only, so that every window starts on a
+        # page boundary (the plan's ``page_aligned``: the in-place
+        # prefill writer; and a window's chunks of the scan are pages).
+        self._quantum_buckets = tuple(
+            b for b in engine_cfg.prefill_buckets
+            if not self.state_model or b % engine_cfg.page_size == 0)
+        if self.state_model and (
+                not self._quantum_buckets or
+                engine_cfg.prefill_buckets[-1] % engine_cfg.page_size):
+            raise ValueError(
+                f"prefill_buckets {engine_cfg.prefill_buckets}: a model "
+                f"with a matrix state by slot needs its largest bucket to "
+                f"be whole pages of {engine_cfg.page_size}")
+        # Its slots follow from the batch: a state row a decode row (two
+        # slots each) and as many snapshots under the prefix index.
+        # ... and its prefill block carries the slot columns at its end.
+        self._prefill_tail = _STATE_COLS if self.state_model else 0
+        n_rows = engine_cfg.max_batch_size if self.state_model else 0
+        n_snaps = n_rows if engine_cfg.enable_prefix_cache else 0
 
         # Weights and pools are BORN where they live: made under a jit
         # whose out_shardings is their final placement, each device makes
@@ -226,7 +273,8 @@ class Engine:
 
         def make_kv():
             return transformer.init_kv_cache(
-                model_cfg, engine_cfg.num_pages, engine_cfg.page_size, dtype)
+                model_cfg, engine_cfg.num_pages, engine_cfg.page_size, dtype,
+                state_slots=1 + 2 * n_rows + n_snaps)
 
         # A single-device engine pins the pools' layout at every jitted
         # step boundary (_build_step_programs) and creates them in that
@@ -289,6 +337,14 @@ class Engine:
         self.prefix_cache = PrefixCacheIndex(
             self.allocator, engine_cfg.page_size, seed=murmur_seed,
             enable=engine_cfg.enable_prefix_cache)
+        # The fourth pool's slots (slot 0 is null): rows 1 .. n_rows own
+        # slots 2r - 1 and 2r, the snapshots follow them. A row without
+        # a state row is not admitted; a prefix match ends at a page
+        # that has a snapshot (kv_cache.PrefixCacheIndex).
+        self.state_rows = SlotAllocator(1, n_rows)
+        if self.state_model:
+            self.prefix_cache.enable_snapshots(
+                SlotAllocator(2 * n_rows + 1, n_snaps))
         # Tiered spill (docs/KV_CACHE.md): prefix pages evicted from HBM
         # under allocation pressure park in a bounded host-DRAM tier
         # (optional disk tier behind it) instead of vanishing; a later
@@ -356,6 +412,11 @@ class Engine:
                 self.kv[0].dtype.itemsize, MP)
             fold = (f"; latent fold {pages} pages a grid step, "
                     f"{-(-MP // pages)} steps of {MP} columns")
+        if self.state_model:
+            fold += (f"; mixer ssm_prefill {self.plan.ssm_prefill}, "
+                     f"ssm_decode "
+                     f"{'pallas' if self.plan.ssm_decode else 'xla'}; "
+                     f"{n_rows} state rows x 2 + {n_snaps} snapshots")
         logger.info("engine plan: %s; decode walk %d of %d columns%s%s",
                     self.plan, self._decode_walk(MP), MP,
                     "; layer kinds " + ", ".join(
@@ -498,6 +559,10 @@ class Engine:
         self.state_rows_restored = 0
         self.state_rows_written = 0
         self.last_step_state_restored: List[int] = []
+        # Snapshot slots handed to the prefill in flight, each with the
+        # sequence and the page it is the state of: attached to that
+        # page once the window has run and the page is registered.
+        self._snapshots_in_flight: List[Tuple[Sequence, int, int]] = []
 
         # Device-plane fault containment (docs/ROBUSTNESS.md): the
         # worker's step fault boundary reads ``step_members`` (the
@@ -888,8 +953,8 @@ class Engine:
         decode grow the table page-by-page (``_grow_pages``) — true paged
         allocation, no max-length reservation."""
         slot = self._free_slot()
-        if slot < 0:
-            return False
+        if slot < 0 or (self.state_model and not self.state_rows.num_free):
+            return False        # no state row: queued, as for pages
         if seq.req.mm_embeds is None and not seq.req.prompt_logprobs:
             cached_pages, cached_tokens = \
                 self.prefix_cache.match_prefix(seq.req.token_ids,
@@ -946,6 +1011,13 @@ class Engine:
             # last cached page left in that page's row.
             self.state_rows_restored += cached_tokens > 0
             self.last_step_state_restored.append(int(cached_tokens > 0))
+        if self.state_model:
+            # ... and starts from a copy of that page's snapshot (the
+            # match ended at a page that has one), made inside the
+            # prefill program of this same iteration.
+            seq.state_row = self.state_rows.alloc()
+            seq.state_src = (self.prefix_cache.snapshot_of(cached_pages[-1])
+                             if cached_pages else 0)
         if not seq.admitted_once:
             seq.admitted_once = True
             seq.slotted_time = time.monotonic()
@@ -986,11 +1058,11 @@ class Engine:
             # for scoped warmup (bench.scoped_warmup_shapes: only the
             # prefill BATCH size varies under interleaving, never T/MP).
             # 0 = residual below the smallest bucket, no window fits.
-            i = bisect.bisect_right(self.ecfg.prefill_buckets,
-                                    self._window_budget)
+            ladder = self._quantum_buckets
+            i = bisect.bisect_right(ladder, self._window_budget)
             if i == 0:
                 return 0
-            cap = min(cap, self.ecfg.prefill_buckets[i - 1])
+            cap = min(cap, ladder[i - 1])
         return cap
 
     def _ring_eligible(self, seq: Sequence, start: int) -> bool:
@@ -1105,6 +1177,12 @@ class Engine:
         return True
 
     def _release_seq_slot(self, seq: Sequence) -> None:
+        if seq.state_row:
+            # Finish and preemption alike drop the live state: a
+            # preempted row resumes from the deepest snapshot, like a
+            # fresh admission.
+            self.state_rows.free(seq.state_row)
+            seq.state_row = seq.state_src = 0
         if seq.slot >= 0:
             self._slots[seq.slot] = None
             # Reset the slot's sampling params: a finished top-p request
@@ -1608,14 +1686,19 @@ class Engine:
                      max(self._pages_needed(s.num_computed + T)
                          for s in batch))
             MP = self._prefill_table_width(mp)
-            # One packed transfer: [start, len, tokens…, page table…].
-            packed = np.zeros((B, _PREFILL_HDR + T + MP), np.int32)
+            # One packed transfer: [start, len, tokens…, page table…]
+            # (and a state model's four slot columns behind them).
+            packed = np.zeros(
+                (B, _PREFILL_HDR + T + MP + self._prefill_tail), np.int32)
             for i, seq in enumerate(batch):
                 new = seq.tokens[seq.num_computed:
                                  seq.num_computed + windows[i]]
                 packed[i, 0] = seq.num_computed
                 packed[i, 1] = len(new)
                 packed[i, _PREFILL_HDR:_PREFILL_HDR + len(new)] = new
+                if self.state_model and new:
+                    packed[i, -_STATE_COLS:] = self._state_cols(seq,
+                                                                len(new))
                 if not self.pages_only and new:
                     # pages whose row of tails this window writes
                     ps = self.ecfg.page_size
@@ -1741,7 +1824,55 @@ class Engine:
                     seq.prompt_lps = None
                 outs.append(out)
                 self._sync_slot(seq)
+            self._attach_snapshots()
         return outs
+
+    @staticmethod
+    def _live_slot(row: int, pos: int) -> int:
+        """The slot of state row ``row`` that holds the state as of
+        position ``pos`` (its parity: the decode program computes the
+        same)."""
+        return 2 * row - 1 + pos % 2
+
+    def _state_cols(self, seq: Sequence, n: int) -> Tuple[int, ...]:
+        """The four slot columns of ``seq``'s prefill window of ``n``
+        tokens from ``seq.num_computed``: where its state starts from
+        (the snapshot its admission matched, its own live state from
+        the window before, or 0: zero), where its final state goes, and
+        a snapshot slot with the count of window tokens it is taken
+        after, where the window crosses the LAST FULL PAGE BOUNDARY of
+        the tokens being prefilled (the boundary up to which
+        ``register_pages`` will register them)."""
+        start, ps = seq.num_computed, self.ecfg.page_size
+        if start == seq.num_cached_tokens:
+            src = seq.state_src if start else 0
+        else:
+            src = self._live_slot(seq.state_row, start - 1)
+        snap = snap_len = 0
+        boundary = len(seq.tokens) // ps * ps
+        if start < boundary <= start + n and seq.req.mm_embeds is None:
+            with steptrace.span("xllm.kv.state_slots"):
+                snap = self.prefix_cache.reserve_snapshot()
+            if snap:
+                snap_len = boundary - start
+                self._snapshots_in_flight.append(
+                    (seq, snap, boundary // ps - 1))
+        return (src, self._live_slot(seq.state_row, start + n - 1), snap,
+                snap_len)
+
+    def _attach_snapshots(self) -> None:
+        """The windows that were handed a snapshot slot have run: give
+        each slot to the page whose last token its state is as of
+        (registered first: a window that does not end its prompt
+        registers nothing on its own)."""
+        pending, self._snapshots_in_flight = self._snapshots_in_flight, []
+        if not pending:
+            return
+        with steptrace.span("xllm.kv.state_slots", snapshots=len(pending)):
+            for seq, slot, page in pending:
+                self._register_pages(seq)
+                self.prefix_cache.attach_snapshot(seq.page_digests[page],
+                                                  slot)
 
     def _run_prefill_ring(self, seq: Sequence, window: int
                           ) -> List[StepOutput]:
@@ -2360,6 +2491,9 @@ class Engine:
             self._pending = None  # corrupt state; drop it unconsumed
         evict = set(evict_rids)
         evicted: List[str] = []
+        pending, self._snapshots_in_flight = self._snapshots_in_flight, []
+        for _, slot, _ in pending:      # handed out, never attached
+            self.prefix_cache.snapshot_slots.free(slot)
         for seq in list(self._by_id.values()):
             self._release_seq_slot(seq)
             self.prefix_cache.release_pages([p for p in seq.pages if p])
@@ -2597,7 +2731,9 @@ class Engine:
         if seq.slot < 0:
             return
         i = seq.slot
-        self._slot_rope_delta[i] = seq.req.rope_delta
+        # the column is a state model's state row (it has no mrope)
+        self._slot_rope_delta[i] = (seq.state_row if self.state_model
+                                    else seq.req.rope_delta)
         self._slot_pt[i] = 0
         self._slot_pt[i, :len(seq.pages)] = seq.pages
 
@@ -2945,7 +3081,7 @@ class Engine:
         """Bytes of one content-addressed KV block (k+v, all layers) —
         advertised in worker registration for the service's
         fetch-vs-recompute cost model."""
-        return sum(int(x.nbytes) for x in self.kv) \
+        return sum(int(x.nbytes) for x in self.kv[:3]) \
             // int(self.kv[0].shape[1])
 
     def prefix_cache_stats(self) -> Dict[str, int]:
@@ -2966,9 +3102,23 @@ class Engine:
         cached state is its pages alone."""
         if self.pages_only:
             return None
-        return {"restored": self.state_rows_restored,
-                "written": self.state_rows_written,
-                "pool_bytes": int(self.kv[2].nbytes)}
+        out = {"restored": self.state_rows_restored,
+               "written": self.state_rows_written,
+               "pool_bytes": sum(int(x.nbytes) for x in self.kv[2:])}
+        if self.state_model:
+            # The pool of states by slot, from slot moves on the host:
+            # rows that hold a live state now, snapshots the prefix index
+            # holds, free snapshot slots, and the lifetime counts of
+            # snapshots attached to a page and evicted (their page
+            # reclaimed, or the least recently hit making room);
+            # ``restored`` counts admissions begun from a snapshot's copy.
+            pc = self.prefix_cache
+            out.update(
+                live=self.state_rows.count - self.state_rows.num_free,
+                snapshots=pc.num_snapshots,
+                free=pc.snapshot_slots.num_free,
+                snapshotted=pc.snapshots_taken, evicted=pc.snapshots_evicted)
+        return out
 
     # ------------------------------------------------------------------
     # Warmup / metrics
@@ -3102,7 +3252,8 @@ class Engine:
                        if self._mrope else None)
             out = launch(
                 self._jit_prefill, self.params,
-                jnp.zeros((B, _PREFILL_HDR + T + mp), jnp.int32),
+                jnp.zeros((B, _PREFILL_HDR + T + mp + self._prefill_tail),
+                          jnp.int32),
                 self.kv, st_f32, st_i32, key, None, None, None,
                 b_ids, b_vals, warm_rp, T)
             if out is not None:
@@ -3330,12 +3481,17 @@ def _prefill_step(params, packed, kv, st_f32, st_i32, key, mm_embeds=None,
     lengths = packed[:, 1]
     tokens = packed[:, _PREFILL_HDR:_PREFILL_HDR + t_len]
     page_table = packed[:, _PREFILL_HDR + t_len:]
+    state_cols = None
+    if cfg.num_ssm_layers:
+        page_table, state_cols = (page_table[:, :-_STATE_COLS],
+                                  page_table[:, -_STATE_COLS:])
     st = SamplingTensors.unpack(st_f32, st_i32)
     res = transformer.forward_prefill(
         params, cfg, tokens, start_pos, lengths, kv, page_table,
         mm_embeds=mm_embeds, mm_positions=mm_positions,
         prompt_lp_targets=plp_targets if with_prompt_lps else None,
-        return_stats=True, rope_pos=rope_pos, plan=plan)
+        return_stats=True, rope_pos=rope_pos, plan=plan,
+        state_cols=state_cols)
     if with_prompt_lps:
         last_logits, _, kv, plp, stats = res
     else:
@@ -3392,7 +3548,8 @@ def _decode_step(params, packed, kv, st_f32, st_i32, key, counts=None,
     next_key, key = jax.random.split(key)
     logits, kv, stats = transformer.forward_decode(
         params, cfg, tokens, positions, active, kv, page_table,
-        return_stats=True, rope_delta=rope_delta, plan=plan)
+        return_stats=True, rope_delta=rope_delta, plan=plan,
+        state_rows=packed[:, 3] if cfg.num_ssm_layers else None)
     tok = sample_tokens(logits, st, key, positions=positions, counts=counts,
                         bias_ids=bias_ids, bias_vals=bias_vals)
     lp = compute_logprobs(logits, tok)

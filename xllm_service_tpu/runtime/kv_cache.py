@@ -271,6 +271,33 @@ class PageAllocator:
             self._free.append(p)
 
 
+class SlotAllocator:
+    """Free-list allocator of the slots of a pool addressed by slot (the
+    matrix states of a model with a mixer beside attention,
+    ``models.init_kv_cache``'s fourth pool): ids ``first .. first +
+    count - 1``. Slot 0 is the null slot, as page 0 is the null page,
+    and belongs to no allocator."""
+
+    def __init__(self, first: int, count: int) -> None:
+        if first < 1 or count < 0:
+            raise ValueError("slots start at 1 (slot 0 is NULL)")
+        self.first, self.count = first, count
+        self._free: List[int] = list(range(first + count - 1, first - 1, -1))
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    def alloc(self) -> Optional[int]:
+        return self._free.pop() if self._free else None
+
+    def free(self, slot: int) -> None:
+        if not self.first <= slot < self.first + self.count \
+                or slot in self._free:
+            raise ValueError(f"bad or free slot id {slot}")
+        self._free.append(slot)
+
+
 class PrefixCacheIndex:
     """Content-addressed index of *full* pages + LRU reclamation.
 
@@ -309,6 +336,24 @@ class PrefixCacheIndex:
         # restore and registration alike): the engine's
         # ``hashed_tokens_total``.
         self.hashed_tokens = 0
+        # A model whose sequences carry a state that lives by SLOT and
+        # not by page (``enable_snapshots``): page id -> the slot that
+        # holds the state as of that page's last token. A prefix match
+        # is then cut at the deepest page that has one: pages behind it
+        # hold keys and values nobody can resume from. Eviction takes the
+        # least recently HIT: first the snapshots no match has ever
+        # ended at (``_snapshots_unhit``, oldest first), then the others
+        # by their last hit (``_snapshots_hit``). A turn's own snapshot,
+        # which nobody asks for again, so never pushes out a system
+        # prompt's.
+        self.snapshot_slots: Optional[SlotAllocator] = None
+        self._snapshot_of: Dict[int, int] = {}  # guarded-by: worker.engine
+        self._snapshots_unhit: "collections.OrderedDict[int, None]" = \
+            collections.OrderedDict()           # guarded-by: worker.engine
+        self._snapshots_hit: "collections.OrderedDict[int, None]" = \
+            collections.OrderedDict()           # guarded-by: worker.engine
+        self.snapshots_taken = 0
+        self.snapshots_evicted = 0
 
     # -- hashing ----------------------------------------------------------
     def block_hashes(self, tokens: Sequence[int]) -> List[bytes]:
@@ -364,9 +409,68 @@ class PrefixCacheIndex:
         # logits from.
         while pages and len(pages) * self.page_size >= len(tokens):
             pages = pages[:-1]
+        if self.snapshot_slots is not None:
+            while pages and pages[-1] not in self._snapshot_of:
+                pages = pages[:-1]
+            if pages:                                       # a hit
+                self._snapshots_unhit.pop(pages[-1], None)
+                self._snapshots_hit[pages[-1]] = None
+                self._snapshots_hit.move_to_end(pages[-1])
         for pid in pages:
             self._acquire(pid)
         return pages, len(pages) * self.page_size
+
+    # -- snapshots of a state that lives by slot ---------------------------
+    def enable_snapshots(self, slots: SlotAllocator) -> None:
+        """From now on a match ends at a page that has a snapshot."""
+        self.snapshot_slots = slots
+
+    def snapshot_of(self, pid: int) -> int:
+        """The slot holding the state as of page ``pid``'s last token
+        (0: none)."""
+        return self._snapshot_of.get(pid, 0)
+
+    @property
+    def num_snapshots(self) -> int:
+        return len(self._snapshot_of)
+
+    def reserve_snapshot(self) -> int:
+        """A slot for a prefill to write a snapshot into, the least
+        recently hit snapshot making room where none is free (its page
+        stays registered and can no longer be resumed from). It belongs
+        to no page until ``attach_snapshot``. 0: the model keeps none."""
+        if self.snapshot_slots is None or not self.snapshot_slots.count:
+            return 0
+        slot = self.snapshot_slots.alloc()
+        if slot is None:
+            pid, _ = (self._snapshots_unhit
+                      or self._snapshots_hit).popitem(last=False)
+            slot = self._snapshot_of.pop(pid)
+            self.snapshots_evicted += 1
+        return slot
+
+    def attach_snapshot(self, digest: bytes, slot: int) -> bool:
+        """The prefill that was handed ``slot`` has run and the page of
+        ``digest`` is registered (``register_pages``): the page that owns
+        that content now has the snapshot, the newest of the never-hit.
+        False (and the slot free again) where no page owns it or the
+        owner has one already."""
+        pid = self._by_hash.get(digest)
+        if pid is None or pid in self._snapshot_of:
+            self.snapshot_slots.free(slot)
+            return False
+        self._snapshot_of[pid] = slot
+        self._snapshots_unhit[pid] = None
+        self.snapshots_taken += 1
+        return True
+
+    def _drop_snapshot(self, pid: int) -> None:
+        slot = self._snapshot_of.pop(pid, None)
+        if slot is not None:
+            self._snapshots_unhit.pop(pid, None)
+            self._snapshots_hit.pop(pid, None)
+            self.snapshot_slots.free(slot)
+            self.snapshots_evicted += 1
 
     # -- registration -----------------------------------------------------
     def register_full_pages(self, tokens: Sequence[int],
@@ -461,6 +565,7 @@ class PrefixCacheIndex:
         h = self._hash_of.pop(pid, None)
         if h is None:
             return
+        self._drop_snapshot(pid)    # the content goes: so does its state
         self._by_hash.pop(h, None)
         if spillable and self.spill_hook is not None:
             try:

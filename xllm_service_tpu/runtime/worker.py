@@ -619,6 +619,7 @@ class Worker:
         self._st_phase_snap: Dict[str, Dict[str, float]] = {}
         self._st_spec_snap: Dict[str, Dict[str, int]] = {}
         self._st_prefix_snap: Dict[str, int] = {}
+        self._st_state_snap: Dict[str, Dict[str, int]] = {}
         self._st_free_pages: Dict[str, int] = {}
         # The directory of the device trace that is running
         # (start_device_trace), None when none is. jax's profiler allows
@@ -1527,6 +1528,8 @@ class Worker:
             eng.phase_counts.get("decode.ahead_dropped_rows", 0), model=m)
         if eng.cfg.is_moe:
             self._flush_moe(rt)
+        if not eng.pages_only:
+            self._flush_state(rt)
         if eng.cfg.looped:
             self._flush_loop(rt)
         tok = self.obs.counter(
@@ -1633,6 +1636,14 @@ class Worker:
         self._st_free_pages[m] = free
         passes, exit_cdf = (_loop_record(eng.last_step_loop)
                             if eng.cfg.looped else (None, None))
+        state = None
+        if eng.state_model:
+            cur = eng.state_stats()
+            was = self._st_state_snap.get(m, {})
+            state = {"live": cur["live"], "snapshots": cur["snapshots"],
+                     **{k: cur[k] - was.get(k, 0)
+                        for k in ("restored", "snapshotted", "evicted")}}
+            self._st_state_snap[m] = cur
         self.steptrace.record(
             model=m, kind=kind, step_ms=round(step_ms, 3),
             prefill_tokens=eng.last_step_prefill_tokens,
@@ -1650,14 +1661,14 @@ class Worker:
             moe=_moe_record(eng.last_step_moe),
             passes=passes, exit_cdf=exit_cdf,
             state_restored=(None if eng.pages_only
-                            else tuple(eng.last_step_state_restored)))
+                            else tuple(eng.last_step_state_restored)),
+            state=state)
 
     def _flush_moe(self, rt: ModelRuntime) -> None:
         """What the sparse layers counted on the device (``Engine.
         moe_stats``: the dropless layer's, whichever family runs it; all
         zeros where a family's layer still buckets and counts its drops
-        alone). And the convolution tails' ledger, where the model has
-        them (``Engine.state_stats``)."""
+        alone)."""
         st, m = rt.engine.moe_stats, rt.model
         for name, key, text in (
                 ("xllm_worker_moe_assignments_total", "assignments",
@@ -1672,7 +1683,12 @@ class Worker:
                  "(requested - computed; 0 under the dropless layer)")):
             self.obs.counter(name, text, labelnames=("model",)).set_total(
                 st[key], model=m)
-        state = rt.engine.state_stats()
+
+    def _flush_state(self, rt: ModelRuntime) -> None:
+        """The ledger of a model whose cached state is more than its
+        pages (``Engine.state_stats``): the convolution tails', and the
+        slots' of a state that lives by slot."""
+        state, m = rt.engine.state_stats(), rt.model
         if state is not None:
             c = self.obs.counter(
                 "xllm_worker_state_rows_total",
@@ -1686,8 +1702,26 @@ class Worker:
             self.obs.gauge(
                 "xllm_worker_state_pool_bytes",
                 "bytes of the pool of convolution tails (one row a page "
-                "a convolution layer)",
+                "a convolution layer) and, where the model keeps one, of "
+                "the pool of matrix states by slot",
                 labelnames=("model",)).set(state["pool_bytes"], model=m)
+            if "live" in state:
+                # a state that lives by slot (a mixer beside attention):
+                # restored counts the admissions that began from a copy
+                # of a snapshot
+                c.set_total(state["snapshotted"], model=m,
+                            event="snapshotted")
+                c.set_total(state["evicted"], model=m, event="evicted")
+                g = self.obs.gauge(
+                    "xllm_worker_state_slots",
+                    "slots of the pool of matrix states by kind: live = "
+                    "rows that hold a state now (two slots each), "
+                    "snapshot = states at page boundaries the prefix "
+                    "index can resume from, free = snapshot slots unused",
+                    labelnames=("model", "kind"))
+                g.set(state["live"], model=m, kind="live")
+                g.set(state["snapshots"], model=m, kind="snapshot")
+                g.set(state["free"], model=m, kind="free")
 
     def _flush_loop(self, rt: ModelRuntime) -> None:
         """What a looped model's step programs counted on the device
