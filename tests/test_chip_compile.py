@@ -28,20 +28,40 @@ B_PF, T = 2, 256           # a prefill window of two pages
 MLA_HKV, MLA_D = 1, 576    # absorbed-MLA latent pool (kv_lora 512 + rope 64)
 
 
-def _pools(sds, hkv=HKV, d=D):
-    pool = sds((L, P, PS, hkv, d), jnp.bfloat16)
+def _pools(sds, hkv=HKV, d=D, layers=L, pages=P):
+    pool = sds((layers, pages, PS, hkv, d), jnp.bfloat16)
     return pool, pool
 
 
-def _decode_attention(sds, hq=HQ, hkv=HKV, d=D, mp=MP, window=0):
+def _decode_attention(sds, b=B_DEC, hq=HQ, hkv=HKV, d=D, mp=MP, window=0,
+                      pool=(L, P)):
     from xllm_service_tpu.ops.pallas import paged_decode_attention_pallas
     fn = functools.partial(paged_decode_attention_pallas, interpret=False,
                            sliding_window=window)
     return (lambda q, kp, vp, pt, ctx, lyr: fn(q, kp, vp, pt, ctx,
                                                layer=lyr),
-            (sds((B_DEC, hq, d), jnp.bfloat16), *_pools(sds, hkv, d),
-             sds((B_DEC, mp), jnp.int32), sds((B_DEC,), jnp.int32),
+            (sds((b, hq, d), jnp.bfloat16), *_pools(sds, hkv, d, *pool),
+             sds((b, mp), jnp.int32), sds((b,), jnp.int32),
              sds((), jnp.int32)), {})
+
+
+# The benchmark's four cells that decode through the paged kernel: rows,
+# query / key-value heads of 128 as the kernel sees them, table width,
+# static window, the pool's layers and pages, and the pages a grid step
+# folds there (ops/plan.py ``paged_fold_pages`` at 128-token pages of
+# bfloat16: PERF.md, PR 46).
+CELLS = {
+    # Mistral-7B-v0.1: the grid walks the window's 33 columns from each
+    # row's first live page, which the folded table holds
+    "window": (dict(b=8, hq=32, hkv=8, d=128, mp=64, window=4096,
+                    pool=(16, 768)), 4),
+    # Ouro-2.6B: 16 key-value heads of group size 1, a table of 8
+    "looped": (dict(b=8, hq=16, hkv=16, d=128, mp=8, pool=(192, 40)), 2),
+    # LFM2-24B-A2B: 8 heads of 64 packed two to a 128-wide row
+    "packed": (dict(b=64, hq=32, hkv=4, d=128, mp=96, pool=(2, 3776)), 8),
+    # Falcon-H1-34B: a group of 5
+    "group5": (dict(b=32, hq=20, hkv=4, d=128, mp=16, pool=(6, 256)), 8),
+}
 
 
 def _decode_writer(sds, hkv=HKV, d=D):
@@ -116,12 +136,11 @@ def _ragged_attention(sds):
 KERNELS = {
     # The default path: what a served worker runs on the chip.
     "decode-attention": _decode_attention,
-    # The benchmark's cell (Mistral-7B-v0.1: heads of 128, a 64-column
-    # table, a STATIC window of 4096): the grid walks the window's 33
-    # columns from each row's first live page, by arithmetic on
-    # prefetched scalars inside the block index maps.
-    "decode-attention[window]": functools.partial(
-        _decode_attention, d=128, mp=64, window=4096),
+    # ... and at the benchmark's cells, each with the block of pages the
+    # plan picks for it: a block that does not fit VMEM fails here.
+    **{f"decode-attention[{name}]": functools.partial(_decode_attention,
+                                                      **shape)
+       for name, (shape, _) in CELLS.items()},
     "decode-kv-writer": _decode_writer,
     "prefill-kv-writer": _prefill_writer,
     # Opt-in kernels: compile-checked here, their A/B is later work.
@@ -252,6 +271,25 @@ def _tp4_forward_decode(monkeypatch):
                        match="cannot be automatically partitioned"):
         jax.jit(with_kernels, donate_argnums=(4,)).lower(*args).compile()
     return jax.jit(reference, donate_argnums=(4,)).lower(*args).compile()
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_paged_fold_pages_at_the_cells_shapes(cell):
+    """K from shapes: one VMEM budget for every caller, no more pages
+    than the walk has columns, and a page a grid step (today's schedule
+    from the same body) where two page pairs do not fit the budget."""
+    from xllm_service_tpu.ops.plan import (
+        decode_walk_columns, paged_fold_pages)
+    shape, want = CELLS[cell]
+    walk = decode_walk_columns(shape["mp"], PS, shape.get("window", 0))
+    assert paged_fold_pages(PS, shape["hkv"], shape["d"], 2, walk) == want
+    # a narrower table (the engine's tables are powers of two) caps K
+    assert paged_fold_pages(PS, shape["hkv"], shape["d"], 2, 2) == 2
+    assert paged_fold_pages(PS, shape["hkv"], shape["d"], 2, 1) == 1
+    # float32 pools: half the pages; a page too large for two: one
+    assert paged_fold_pages(PS, shape["hkv"], shape["d"], 4,
+                            walk) == max(want // 2, 1)
+    assert paged_fold_pages(PS, 8 * shape["hkv"], shape["d"], 4, walk) == 1
 
 
 @pytest.mark.parametrize("case", [*KERNELS, "engine-decode-step",

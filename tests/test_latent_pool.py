@@ -240,7 +240,9 @@ def test_the_fold_is_what_the_shapes_say_and_the_plan_line_names_it(
     engine's plan line. At the benchmark cell's shapes (pages of 128 rows
     of 576 bfloat16 values in 640 lanes, a table of 96) the
     double-buffered block of 8 pages is 2.6 MB; no block is wider than
-    its table; a model without latent attention says nothing of it."""
+    its table; a model without latent attention names the paged
+    kernel's fold in its place (PR 46: the same rule over a page's keys
+    and values, ``paged_fold_pages``)."""
     import logging
     from xllm_service_tpu.ops.plan import latent_fold_pages
     assert latent_fold_pages(128, 576, 2, 96) == 8
@@ -262,8 +264,9 @@ def test_the_fold_is_what_the_shapes_say_and_the_plan_line_names_it(
     lines = [m for m in caplog.messages if m.startswith("engine plan:")]
     assert [m.rsplit("; ", 1)[1] for m in lines] == [
         "latent fold 16 pages a grid step, 1 steps of 16 columns",
-        "decode walk 16 of 16 columns"]
+        "paged fold 16 pages a grid step, 1 steps of 16 columns"]
     assert "decode walk 16 of 16 columns; latent fold" in lines[0]
+    assert "decode walk 16 of 16 columns; paged fold" in lines[1]
 
 
 def test_the_latent_writer_writes_a_steps_rows_and_nothing_else():

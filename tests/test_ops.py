@@ -481,7 +481,7 @@ class TestPallasPagedAttention:
         from xllm_service_tpu.ops.attention import (
             paged_decode_attention, paged_decode_attention_current)
         from xllm_service_tpu.ops.pallas.paged_attention import (
-            paged_decode_attention_pallas)
+            _paged_decode_attention_impl, paged_decode_attention_pallas)
         from xllm_service_tpu.ops.plan import decode_walk_columns
 
         ps, MP, G = self.WALK_PS, self.WALK_MP, 4
@@ -513,10 +513,101 @@ class TestPallasPagedAttention:
         assert (err[live] < 1e-5).all(), [
             (c, float(e)) for c, e in zip(ctxs, err) if e >= 1e-5]
         # The full walk (the window as a traced scalar) folds the same
-        # pages in the same order: the same bits, inactive rows too.
+        # pages: blocks of the plan's K pages that start at column 0 and
+        # not at the row's first live one, so the last bits may differ
+        # ...
         full = paged_decode_attention_pallas(
             q, *pool, pt, ctx, *cur, sliding_window=jnp.int32(W), **kw)
-        assert jnp.array_equal(full, out)
+        assert np.abs(np.asarray(full) - np.asarray(out)
+                      )[live].max() < 1e-5
+        # ... and at a page a grid step in the same order: the same bits,
+        # inactive rows too.
+        win = jnp.full((1,), W, jnp.int32)
+        one = [_paged_decode_attention_impl(
+            q, *pool, pt, ctx, *cur, win, None, interpret=True,
+            layer=kw["layer"], walk=walk, fold=1)
+            for walk in (decode_walk_columns(MP, ps, W), MP)]
+        assert jnp.array_equal(*one)
+
+    # -- a block of K pages a grid step (PR 46) ------------------------
+    # ONE online-softmax update a block, the table folded once outside
+    # the kernel. K is a jit static of the implementation, as ``walk``
+    # is; the public wrapper leaves it to ``ops/plan.py``
+    # ``paged_fold_pages``.
+    FOLD_PS, FOLD_MP, FOLD_W = 8, 13, 16        # 13: no multiple of a K
+
+    @staticmethod
+    def _fold_cases():
+        import itertools
+        cases = [pytest.param(*c, None, id="-".join(
+            (f"K{c[0]}", f"g{c[1]}", "layered" if c[2] else "pool4d",
+             c[3], "in_register" if c[4] else "written")))
+            for c in itertools.product(
+                (1, 2, 4, 8), (1, 4, 5), (False, True),
+                ("static", "traced", "none"), (True, False))]
+        cases.append(pytest.param(4, 4, True, "none", False, "soft_cap",
+                                  id="K4-soft_cap"))
+        cases.append(pytest.param(4, 4, True, "static", True, "sinks",
+                                  id="K4-sinks"))
+        return cases
+
+    @pytest.mark.parametrize("K,group,layered,window,current,extra",
+                             _fold_cases())
+    def test_block_fold_matches_reference(self, K, group, layered, window,
+                                          current, extra):
+        """Rows whose live pages are 0, 1, K - 1, K, K + 1 and the whole
+        table, whole and part pages, a walk that is no multiple of K,
+        NULL leading columns under a window, and every dead column a
+        page of garbage x50 (a column wrongly folded cannot pass)."""
+        import numpy as np
+
+        from xllm_service_tpu.ops.attention import (
+            paged_decode_attention, paged_decode_attention_current)
+        from xllm_service_tpu.ops.pallas.paged_attention import (
+            _paged_decode_attention_impl)
+        from xllm_service_tpu.ops.plan import decode_walk_columns
+
+        ps, MP, G = self.FOLD_PS, self.FOLD_MP, 4
+        W = self.FOLD_W if window != "none" else 0
+        walk = decode_walk_columns(MP, ps, W if window == "static" else 0)
+        assert walk % K or K == 1
+        pages = sorted({0, 1, K - 1, K, K + 1, MP})
+        ctxs = sorted({c for n in pages for c in (n * ps, n * ps - 3)
+                       if 0 <= c <= MP * ps})
+        if W:
+            ctxs += self._walk_rows(W, ps, MP, current)
+        ctxs = [c for c in ctxs if current or c <= MP * ps]
+        pt, P = self._walk_tables(ctxs, W or MP * ps + 1, ps, MP, current,
+                                  G)
+        rng = np.random.default_rng(46 + K)
+        Hkv, D, L = 2, 16, 2
+        B, Hq = len(ctxs), Hkv * group
+        pools = rng.normal(size=(2, L, P, ps, Hkv, D))
+        pools[:, :, :G] *= 50       # NULL page 0 and the garbage pages
+        k5, v5 = (jnp.asarray(x, jnp.float32) for x in pools)
+        q = jnp.asarray(rng.normal(size=(B, Hq, D)), jnp.float32)
+        pt, ctx = jnp.asarray(pt), jnp.asarray(ctxs, jnp.int32)
+        cur = ([jnp.asarray(rng.normal(size=(B, Hkv, D)), jnp.float32)
+                for _ in range(2)] if current else [None, None])
+        cap = 20.0 if extra == "soft_cap" else 0.0
+        sinks = (jnp.asarray(rng.normal(size=(Hq,)), jnp.float32)
+                 if extra == "sinks" else None)
+        if current:
+            ref = paged_decode_attention_current(
+                q, k5[1], v5[1], pt, ctx, *cur, cap, W, None, sinks)
+        else:
+            ref = paged_decode_attention(q, k5[1], v5[1], pt, ctx, cap, W,
+                                         None, sinks)
+        out = _paged_decode_attention_impl(
+            q, *((k5, v5) if layered else (k5[1], v5[1])), pt, ctx, *cur,
+            jnp.full((1,), W, jnp.int32), sinks, interpret=True,
+            logits_soft_cap=cap, layer=jnp.int32(1) if layered else None,
+            walk=walk, fold=K)
+        # (a written-token row of context 0 attends to nothing)
+        live = np.asarray(ctxs) >= (0 if current else 1)
+        err = np.abs(np.asarray(ref) - np.asarray(out)).max(axis=(1, 2))
+        assert (err[live] < 1e-5).all(), [
+            (c, float(e)) for c, e in zip(ctxs, err) if e >= 1e-5]
 
     @pytest.mark.parametrize("window,MP,ps,want", [
         (0, 12, 8, 12),                 # full attention
@@ -530,12 +621,14 @@ class TestPallasPagedAttention:
         (4096, 32, 128, 32),
     ], ids=str)
     def test_grid_columns(self, window, MP, ps, want):
-        """The grid the kernel is lowered with, read off the jaxpr."""
+        """The grid the kernel is lowered with, read off the jaxpr:
+        ``want`` columns a row, in blocks of the plan's K."""
         import jax
 
         from xllm_service_tpu.ops.pallas.paged_attention import (
             paged_decode_attention_pallas)
-        from xllm_service_tpu.ops.plan import decode_walk_columns
+        from xllm_service_tpu.ops.plan import (
+            decode_walk_columns, paged_fold_pages)
 
         B, Hq, Hkv, D, P = 2, 4, 2, 16, 4
         args = (jnp.zeros((B, Hq, D)), jnp.zeros((P, ps, Hkv, D)),
@@ -558,7 +651,10 @@ class TestPallasPagedAttention:
                     yield tuple(eqn.params["grid_mapping"].grid)
                 for sub in jax.core.jaxprs_in_params(eqn.params):
                     yield from grids(sub)
-        assert list(grids(jaxpr.jaxpr)) == [(B, want)]
+        # a grid step folds a block of K pages (PR 46), K from shapes
+        K = paged_fold_pages(ps, Hkv, D, 4, want)
+        assert 1 < K <= want
+        assert list(grids(jaxpr.jaxpr)) == [(B, -(-want // K))]
 
 
 class TestPagedKvUpdateKernel:

@@ -5,7 +5,9 @@ step_programs_pr34.json`` was taken on commit 17f4a2a with this file's
 ``digests``, and taken again by PR 44, which changed the dense path's
 text on purpose: two ``optimization_barrier``s a layer body, before and
 after the q/k/v products' reshape to heads, nothing else, by the count
-of every operation in the old and the new text). A change to the latent
+of every operation in the old and the new text; PR 46 took the two
+interpreted DECODE digests again: the paged decode kernel folds a block
+of pages a grid step, every other digest held). A change to the latent
 family's path, the expert layer or the step statistics must not reach a
 dense model's program: the Mistral cell is then measured on what it was
 measured on."""

@@ -280,6 +280,7 @@ class TestEngineWriteThenAttend:
 
         from xllm_service_tpu.config import EngineConfig, ModelConfig
         from xllm_service_tpu.obs import steptrace
+        from xllm_service_tpu.ops.plan import paged_fold_pages
         from xllm_service_tpu.runtime.engine import Engine, EngineRequest
         from xllm_service_tpu.utils.types import SamplingParams
 
@@ -326,16 +327,28 @@ class TestEngineWriteThenAttend:
             got, got_args = generate("1")
         assert ref == got
         span_cols = W // ps + 1
-        # the plan's log line states the walk once per engine
-        assert [m.rsplit("; ", 1)[1] for m in caplog.messages
+
+        def fold(walk):
+            """Pages a grid step of the kernel folds at the tiny model's
+            pools (float32, ``num_kv_heads`` heads of ``head_dim``)."""
+            return paged_fold_pages(ps, cfg.num_kv_heads, cfg.head_dim, 4,
+                                    walk)
+        # the plan's log line states the walk once per engine, and the
+        # block of pages a grid step where the kernel serves (PR 46)
+        assert [m.split("; decode walk ", 1)[1] for m in caplog.messages
                 if m.startswith("engine plan:")] == [
-            "decode walk 16 of 16 columns",
-            f"decode walk {span_cols} of 16 columns"]
+            "16 of 16 columns",
+            f"{span_cols} of 16 columns; paged fold {fold(span_cols)} "
+            f"pages a grid step, {-(-span_cols // fold(span_cols))} steps "
+            f"of {span_cols} columns"]
+        assert 1 < fold(span_cols) <= span_cols
         wide = [a for a in got_args if a["MP"] > span_cols]
         assert wide and all(a["walk"] == span_cols for a in wide)
         assert all(a["walk"] == min(a["MP"], span_cols) for a in got_args)
-        # the XLA reference gathers the whole table
-        assert all(a["walk"] == a["MP"] for a in ref_args)
+        assert all(a["fold"] == fold(a["walk"]) for a in got_args)
+        # the XLA reference gathers the whole table: no kernel folds
+        assert all(a["walk"] == a["MP"] and a["fold"] == 1
+                   for a in ref_args)
 
     @pytest.mark.parametrize("pallas", ["0", "1"], ids=["xla", "kernels"])
     def test_a_prefill_table_is_clamped_to_the_sequences_pages(

@@ -4,29 +4,53 @@ Replaces the reference XLA path (ops/attention.py ``paged_decode_attention``)
 which gathers every referenced page into a dense [B, S, Hkv, D] tensor
 before attending — 2× the HBM traffic and a full materialization per layer
 per decode step. Here each batch program streams its sequence's pages
-HBM→VMEM via a **scalar-prefetched page table** (the BlockSpec index map
-reads ``page_table[b, p]`` before the kernel body runs, so the pipeline
-DMAs exactly the right page), folding each page into a flash-style
-online-softmax accumulator in VMEM scratch.
+HBM→VMEM via a **scalar-prefetched page table** (the BlockSpec index maps
+read the table before the kernel body runs, so the pipeline DMAs exactly
+the right pages), folding them into a flash-style online-softmax
+accumulator in VMEM scratch.
 
-Grid: (B, walk), pages fastest → the scratch accumulator carries
-across the page walk of one batch row (standard TPU flash pattern). Each
-block is a whole page with all KV heads ([ps, Hkv, D] — Pallas TPU wants
-the trailing two block dims full or (8,128)-aligned, so heads stay in the
-block and the GQA grouping happens in-kernel). NULL pages (id 0) and
-positions ≥ context_len are masked; a page wholly out of range skips its
-compute via ``pl.when`` but still pays its grid step (0.12 us on a v5e
-against 0.96 us for a page folded: PERF.md section 6, PR 34).
+Grid: (B, ceil(walk / K)), blocks fastest → the scratch accumulator
+carries across the page walk of one batch row (standard TPU flash
+pattern). A grid step folds a BLOCK of K pages of its row (PR 46): each
+pool is passed K times with K one-page blocks, which Pallas double-buffers
+page by page; operand j of step p reads column ``p * K + j`` of the folded
+table. A block is a whole page with all KV heads ([ps, Hkv, D] — Pallas
+TPU wants the trailing two block dims full or (8,128)-aligned, so heads
+stay in the block and the GQA grouping happens in-kernel). K comes from
+shapes (``ops/plan.py`` ``paged_fold_pages``: the largest power of two
+whose double-buffered block of key and value pages stays under 4 MiB, no
+more than the walk has columns; 4 / 2 / 8 / 8 in the benchmark's cells; 1,
+today's page a step from the same body, where two page pairs do not fit).
 
-The walk. ``walk`` is the table's width MP, except under a STATIC
-sliding window W (``ops/plan.py`` ``decode_walk_columns``): a window
-spans at most ceil(W / ps) + 1 pages, so the grid has that many columns
-and column p of row b is table column ``first[b] + p``, where ``first``
-is the page of the oldest position the window keeps, computed in the
-index maps and the body from the prefetched scalars. The pages folded,
-and their order, are those of the full walk: the result is the same
-arithmetic. A traced window (per-layer window vectors) cannot shape a
-grid and walks all MP columns, as does full attention.
+The fold. ONE online-softmax update a block: the K pages' logits meet
+lane by lane, one max, one exp pass, one rescale of the accumulator, then
+the K ``prob x V`` products summed, so the pages' transposes and matmuls
+overlap instead of waiting on K serial max/exp/rescale chains. Operands
+go to the MXU in the pool's own type and are summed in float32 (the MXU
+rounds a float32 operand to bfloat16 anyway: at K = 1 the output is the
+float32-copy body's bit for bit, on the chip); statistics, accumulator
+and output are float32, kept grouped ``[Hkv, G, .]`` as the products give
+them (regrouping a group of 5 to ``[Hq, .]`` was a relayout a page).
+Positions ≥ context_len, below the window's floor, or of a NULL page
+(id 0) under it are masked by position; a block wholly out of range
+skips its compute via ``pl.when``. On a v5e at the Mistral cell's shapes
+the copies alone take 0.74 us a page however the grid is cut, the parent's
+page-a-step body 0.98 and this one 0.79 (PERF.md section 6, PR 46: the
+body, not the grid, set the pace); a block's padding columns are computed,
+so a wider block is slower where it overshoots the walk.
+
+The folded table (``_fold_table``), built ONCE outside the kernel: the
+index maps do no arithmetic. ``walk`` is the table's width MP, except
+under a STATIC sliding window W (``ops/plan.py``
+``decode_walk_columns``): a window spans at most ceil(W / ps) + 1 pages,
+so the grid covers that many columns and folded column c of row b is
+table column ``first[b] + c``, where ``first`` is the page of the oldest
+position the window keeps (added in the table, and handed to the body as
+a prefetched scalar for its positions). A column past the row's context
+or past the table names the page its operand read LAST, in this row or
+the rows before, so Pallas issues no copy for it. The pages folded are
+those of the full walk. A traced window (per-layer window vectors) cannot
+shape a grid and walks all MP columns, as does full attention.
 
 The V2–V5 experiment variants (transpose-free fold, whole-row manual-DMA
 walk, multi-row cells, wide block-diagonal) were deleted when the ragged
@@ -47,7 +71,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from xllm_service_tpu.ops.pallas._compat import (
     CompilerParams as _CompilerParams)
-from xllm_service_tpu.ops.plan import decode_walk_columns
+from xllm_service_tpu.ops.plan import (
+    decode_walk_columns, paged_fold_pages)
 
 _NEG_INF = -1e30
 
@@ -65,21 +90,22 @@ def _query_pos(ctx, has_current: bool):
     return ctx if has_current else ctx - 1
 
 
-def _first_column(q_pos, w, page_size: int):
-    """Table column of the oldest position a window of ``w`` keeps for a
-    query at ``q_pos``: max(0, (q_pos − w + 1) // ps). Scalar int32
-    arithmetic on prefetched values: the block index maps and the body
-    share it."""
-    return jax.lax.div(jnp.maximum(q_pos - w + 1, 0), page_size)
-
-
-def _kernel(ctx_ref, pt_ref, win_ref, q_ref, k_ref, v_ref, kc_ref, vc_ref,
-            sk_ref, o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
-            pages_per_seq: int, walk: int, num_kv_heads: int,
-            has_current: bool, logits_soft_cap: float, scale: float,
-            has_sinks: bool, layered: bool = False):
+def _kernel(ctx_ref, first_ref, pt_ref, win_ref, *refs, fold: int,
+            page_size: int, num_kv_heads: int, has_current: bool,
+            logits_soft_cap: float, scale: float, has_sinks: bool,
+            layered: bool):
+    """One grid step folds a BLOCK of ``fold`` pages of row ``b``: one
+    online-softmax update over the block's [Hkv, G, fold * ps] logits."""
+    if layered:
+        # the layer is consumed by the block index maps alone
+        refs = refs[1:]
+    q_ref = refs[0]
+    k_refs, v_refs = refs[1:1 + fold], refs[1 + fold:1 + 2 * fold]
+    kc_ref, vc_ref, sk_ref, o_ref, m_ref, l_ref, acc_ref = refs[1 + 2 * fold:]
     b = pl.program_id(0)
     p = pl.program_id(1)
+    hq, d = q_ref.shape[1], q_ref.shape[2]
+    g = hq // num_kv_heads
 
     @pl.when(p == 0)
     def _init():
@@ -88,69 +114,76 @@ def _kernel(ctx_ref, pt_ref, win_ref, q_ref, k_ref, v_ref, kc_ref, vc_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     ctx = ctx_ref[b]
-    page_start = p * page_size
     w = win_ref[0]
     w_eff = jnp.where(w > 0, w, _FULL)
     # The window keeps cache slot j > q_pos − W (slot j holds position j).
-    q_pos = _query_pos(ctx, has_current)
-    win_floor = q_pos - w_eff
-    if walk < pages_per_seq:
-        # Grid column p is table column first + p, UNclamped here: one
-        # past the table lies past the context (ctx <= MP * ps), so the
-        # fold below skips it like any other.
-        page_start += _first_column(q_pos, w, page_size) * page_size
+    win_floor = _query_pos(ctx, has_current) - w_eff
+    # Folded column c of row b is table column first[b] + c (0 + c on a
+    # full walk), UNclamped here: one past the table lies past the
+    # context (ctx <= MP * ps), so the mask below drops it like any other.
+    block_start = (first_ref[b] + p * fold) * page_size
 
-    @pl.when((page_start < ctx) & (page_start + page_size - 1 > win_floor))
+    @pl.when((block_start < ctx)
+             & (block_start + fold * page_size - 1 > win_floor))
     def _fold():
-        hq, d = q_ref.shape[1], q_ref.shape[2]
-        g = hq // num_kv_heads
-        q = q_ref[0].astype(jnp.float32)                     # [Hq, D]
-        qg = q.reshape(num_kv_heads, g, d)                   # [Hkv, G, D]
-        # ``layered``: the pool rides FULL as [L, P, ps, Hkv, D] and the
-        # block is [1, 1, ps, Hkv, D] (the round-5 fix for the per-layer
-        # 134 MB slice materialization feeding this custom call).
-        k = (k_ref[0, 0] if layered else k_ref[0]).astype(jnp.float32)
-        v = (v_ref[0, 0] if layered else v_ref[0]).astype(jnp.float32)
-        kt = jnp.transpose(k, (1, 0, 2))                     # [Hkv, ps, D]
-        # Batched over Hkv: [Hkv, G, D] x [Hkv, ps, D] -> [Hkv, G, ps]
-        logits = jax.lax.dot_general(
-            qg, kt, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale
-        logits = logits.reshape(hq, page_size)               # [Hq, ps]
-        if logits_soft_cap > 0.0:
-            logits = logits_soft_cap * jnp.tanh(logits / logits_soft_cap)
-        pos = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        mask = (pos < ctx) & (pos > win_floor)               # [1, ps]
-        logits = jnp.where(mask, logits, _NEG_INF)
-        m_prev = m_ref[:]                                    # [Hq, 1]
-        blk_max = jnp.max(logits, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, blk_max)
-        prob = jnp.exp(logits - m_new)
-        prob = jnp.where(mask, prob, 0.0)                    # [Hq, ps]
+        def heads_first(ref):
+            """A page [ps, Hkv, D] as [Hkv, ps, D], in the pool's own
+            type. ``layered``: the pool rides FULL as [L, P, ps, Hkv, D]
+            and the block is [1, 1, ps, Hkv, D] (no per-layer slice for
+            XLA to materialize in front of this custom call)."""
+            return jnp.transpose(ref[0, 0] if layered else ref[0],
+                                 (1, 0, 2))
+
+        # Operands in the pool's own type, products summed in f32: the
+        # MXU rounds an f32 operand to bf16 anyway (the same bits as
+        # from f32 copies, on the chip: PERF.md, PR 46). Statistics,
+        # accumulator and output stay f32, grouped [Hkv, G, .] as the
+        # products give them: no regrouping of heads in the fold.
+        qg = q_ref[0].astype(k_refs[0].dtype).reshape(num_kv_heads, g, d)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size), 2)
+        logits, masks = [], []
+        for j, k_ref in enumerate(k_refs):
+            # Batched over Hkv: [Hkv, G, D] x [Hkv, ps, D] -> [Hkv, G, ps]
+            lg = jax.lax.dot_general(
+                qg, heads_first(k_ref), (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * scale
+            if logits_soft_cap > 0.0:
+                lg = logits_soft_cap * jnp.tanh(lg / logits_soft_cap)
+            # A dead column holds a page read before (``_fold_table``):
+            # masked by its position, like the NULL page under a window.
+            pos = block_start + j * page_size + lane
+            masks.append((pos < ctx) & (pos > win_floor))    # [1, 1, ps]
+            logits.append(jnp.where(masks[-1], lg, _NEG_INF))
+        # ONE update a block: the pages' logits meet lane by lane, then
+        # one reduce across lanes for the max and one for the sum.
+        m_prev = m_ref[:]                                    # [Hkv, G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(
+            functools.reduce(jnp.maximum, logits), axis=-1, keepdims=True))
+        # (a block may hold no live position at all: a window of 1 under
+        # an in-register token; exp(-1e30 - -1e30) is 1, so mask again)
+        probs = [jnp.where(mk, jnp.exp(lg - m_new), 0.0)
+                 for lg, mk in zip(logits, masks)]
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(prob, axis=-1,
-                                             keepdims=True)
-        vt = jnp.transpose(v, (1, 0, 2))
-        # [Hkv, G, ps] x [Hkv, ps, D] -> [Hkv, G, D]
-        pv = jax.lax.dot_general(
-            prob.reshape(num_kv_heads, g, page_size), vt,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr + pv.reshape(hq, d)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(
+            functools.reduce(jnp.add, probs), axis=-1, keepdims=True)
+        # [Hkv, G, ps] x [Hkv, ps, D] -> [Hkv, G, D], summed over pages
+        acc_ref[:] = acc_ref[:] * corr + functools.reduce(jnp.add, [
+            jax.lax.dot_general(
+                pr.astype(v_ref.dtype), heads_first(v_ref),
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            for pr, v_ref in zip(probs, v_refs)])
         m_ref[:] = m_new
 
-    @pl.when(p == walk - 1)
+    @pl.when(p == pl.num_programs(1) - 1)
     def _finalize():
-        m_fin = m_ref[:]
-        l_fin = l_ref[:]
-        acc_fin = acc_ref[:]
+        m_fin = m_ref[:].reshape(hq, 1)
+        l_fin = l_ref[:].reshape(hq, 1)
+        acc_fin = acc_ref[:].reshape(hq, d)
         if has_current:
             # Fold the current token's K/V (held in-registers, not yet in
             # the pool) as a final always-valid single-position block
             # (soft-capped like any cache logit; inside its own window).
-            hq, d = q_ref.shape[1], q_ref.shape[2]
-            g = hq // num_kv_heads
             q = q_ref[0].astype(jnp.float32)
             qg = q.reshape(num_kv_heads, g, d)
             kc = kc_ref[0].astype(jnp.float32)               # [Hkv, D]
@@ -222,15 +255,35 @@ def paged_decode_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                                  sliding_window))
 
 
-def _kernel_layered(ctx_ref, pt_ref, win_ref, lyr_ref, *rest, **kw):
-    """Layered-pool entry: the 4th scalar-prefetch ref (layer) is
-    consumed by the BLOCK INDEX MAPS only — the body never reads it."""
-    return _kernel(ctx_ref, pt_ref, win_ref, *rest, **kw)
+def _fold_table(page_table: jnp.ndarray, live: jnp.ndarray,
+                first: jnp.ndarray, walk: int, fold: int) -> jnp.ndarray:
+    """The table as the grid reads it, ``[B, ceil(walk / fold) * fold]``:
+    operand j of grid step p takes column ``p * fold + j``, which is
+    table column ``first[b] + p * fold + j``. A dead column (at or past
+    ``live[b]``, the row's pages that hold context, or past the table)
+    names the page its operand read LAST, in this row or the rows before
+    it: the same block index as the grid step before, for which Pallas
+    issues no copy. The row's first live column is added HERE and the
+    index maps do no arithmetic (in the latent kernel arithmetic inside
+    them cost more than the copies it saved: PERF.md, PR 41)."""
+    B, MP = page_table.shape
+    steps = pl.cdiv(walk, fold)
+    col = first[:, None] + jnp.arange(steps * fold, dtype=jnp.int32)[None]
+    pages = jnp.take_along_axis(page_table, jnp.minimum(col, MP - 1),
+                                axis=1).reshape(B * steps, fold)
+    alive = ((col < live[:, None]) & (col < MP)).reshape(B * steps, fold)
+    # operand j's grid steps in the order the grid runs them: a dead one
+    # takes the last live one before it (step 0 where there is none: the
+    # first grid step copies whatever it names)
+    step = jnp.arange(B * steps, dtype=jnp.int32)[:, None]
+    last = jax.lax.cummax(jnp.where(alive, step, 0), axis=0)
+    return jnp.take_along_axis(pages, last, axis=0).reshape(
+        B, steps * fold)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "logits_soft_cap",
-                                    "scale", "walk"))
+                                    "scale", "walk", "fold"))
 def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
                                  v_pages: jnp.ndarray,
                                  page_table: jnp.ndarray,
@@ -243,16 +296,16 @@ def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
                                  logits_soft_cap: float = 0.0,
                                  scale: float = None,
                                  layer: jnp.ndarray = None,
-                                 walk: int = None) -> jnp.ndarray:
+                                 walk: int = None,
+                                 fold: int = None) -> jnp.ndarray:
     """``walk``: grid columns a row (``decode_walk_columns`` of the
     caller's STATIC window; None or MP walks the whole table). Under a
-    shorter walk ``win`` must hold that window."""
+    shorter walk ``win`` must hold that window. ``fold``: pages a grid
+    step folds (None: ``ops/plan.py`` ``paged_fold_pages``, from
+    shapes)."""
     B, Hq, D = q.shape
     layered = layer is not None
-    if layered:
-        _, _, page_size, Hkv, _ = k_pages.shape
-    else:
-        _, page_size, Hkv, _ = k_pages.shape
+    page_size, Hkv = k_pages.shape[-3:-1]
     MP = page_table.shape[1]
     has_current = k_cur is not None
     if not has_current:
@@ -267,62 +320,59 @@ def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
            else jnp.zeros((Hq, 1), jnp.float32))
     if walk is None:
         walk = MP
-
-    def column(b, p, ctx, w):
-        """Table column of grid column p in row b. Clamped to the table:
-        the body skips what the clamp repeats."""
-        if walk < MP:
-            first = _first_column(_query_pos(ctx[b], has_current), w[0],
-                                  page_size)
-            return jnp.minimum(first + p, MP - 1)
-        return p
-
-    if layered:
-        # Pool blocks index (layer, page) straight out of the FULL
-        # [L, P, ps, Hkv, D] pool — no per-layer slice exists for XLA
-        # to materialize (134 MB x layers x 2 pools per decode step).
-        lyr = layer.reshape(1).astype(jnp.int32)
-        pool_spec = pl.BlockSpec(
-            (1, 1, page_size, Hkv, D),
-            lambda b, p, ctx, pt, w, l: (
-                l[0], pt[b, column(b, p, ctx, w)], 0, 0, 0))
-        n_prefetch = 4
-        def small(ix):
-            return lambda b, p, ctx, pt, w, l: ix(b)
+    if fold is None:
+        fold = paged_fold_pages(page_size, Hkv, D, k_pages.dtype.itemsize,
+                                walk)
+    ctx = context_lens.astype(jnp.int32)
+    if walk < MP:
+        # Table column of the oldest position the window keeps for the
+        # row's query: max(0, (q_pos − W + 1) // ps).
+        first = jnp.maximum(
+            _query_pos(ctx, has_current) - win[0] + 1, 0) // page_size
     else:
-        pool_spec = pl.BlockSpec(
-            (1, page_size, Hkv, D),
-            lambda b, p, ctx, pt, w: (
-                pt[b, column(b, p, ctx, w)], 0, 0, 0))
-        n_prefetch = 3
-        def small(ix):
-            return lambda b, p, ctx, pt, w: ix(b)
+        first = jnp.zeros_like(ctx)
+    table = _fold_table(page_table, pl.cdiv(ctx, page_size), first, walk,
+                        fold)
 
+    def row(ix):
+        return lambda b, p, *prefetched: ix(b)
+
+    def page(j):
+        """Operand j: ONE page a block, so that Pallas double-buffers
+        page by page; straight out of the FULL [L, P, ps, Hkv, D] pool
+        where it is layered (no per-layer slice exists for XLA to
+        materialize: 134 MB x layers x 2 pools per decode step)."""
+        return pl.BlockSpec(
+            (1,) * (k_pages.ndim - 3) + (page_size, Hkv, D),
+            lambda b, p, ctx, fst, pt, w, *lyr: (
+                *(l[0] for l in lyr), pt[b, p * fold + j], 0, 0, 0))
+
+    pages = [page(j) for j in range(fold)]
+    # ctx, first, folded table, win[, layer]
+    prefetch = (ctx, first, table, win) + (
+        (layer.reshape(1).astype(jnp.int32),) if layered else ())
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,  # ctx, page_table, win[, layer]
-        grid=(B, walk),
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, pl.cdiv(walk, fold)),
         in_specs=[
-            pl.BlockSpec((1, Hq, D), small(lambda b: (b, 0, 0))),
-            pool_spec,
-            pool_spec,
-            pl.BlockSpec((1, Hkv, D), small(lambda b: (b, 0, 0))),
-            pl.BlockSpec((1, Hkv, D), small(lambda b: (b, 0, 0))),
-            pl.BlockSpec((Hq, 1), small(lambda b: (0, 0))),
+            pl.BlockSpec((1, Hq, D), row(lambda b: (b, 0, 0))),
+            # each pool ``fold`` times: the block's pages of keys, then
+            # of values
+            *pages, *pages,
+            pl.BlockSpec((1, Hkv, D), row(lambda b: (b, 0, 0))),
+            pl.BlockSpec((1, Hkv, D), row(lambda b: (b, 0, 0))),
+            pl.BlockSpec((Hq, 1), row(lambda b: (0, 0))),
         ],
-        out_specs=pl.BlockSpec((1, Hq, D), small(lambda b: (b, 0, 0))),
+        out_specs=pl.BlockSpec((1, Hq, D), row(lambda b: (b, 0, 0))),
         scratch_shapes=[
-            pltpu.VMEM((Hq, 1), jnp.float32),    # running max
-            pltpu.VMEM((Hq, 1), jnp.float32),    # running denom
-            pltpu.VMEM((Hq, D), jnp.float32),    # output accumulator
+            pltpu.VMEM((Hkv, Hq // Hkv, 1), jnp.float32),    # running max
+            pltpu.VMEM((Hkv, Hq // Hkv, 1), jnp.float32),    # running denom
+            pltpu.VMEM((Hkv, Hq // Hkv, D), jnp.float32),    # accumulator
         ],
     )
-    prefetch = (context_lens, page_table, win) + (
-        (lyr,) if layered else ())
-    out = pl.pallas_call(
-        functools.partial(_kernel_layered if layered else _kernel,
-                          page_size=page_size, pages_per_seq=MP,
-                          walk=walk, num_kv_heads=Hkv,
-                          has_current=has_current,
+    return pl.pallas_call(
+        functools.partial(_kernel, fold=fold, page_size=page_size,
+                          num_kv_heads=Hkv, has_current=has_current,
                           logits_soft_cap=logits_soft_cap, scale=scale,
                           has_sinks=has_sinks, layered=layered),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
@@ -330,5 +380,5 @@ def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
         compiler_params=_CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(*prefetch, q, k_pages, v_pages, k_cur, v_cur, sk2)
-    return out
+    )(*prefetch, q, *([k_pages] * fold), *([v_pages] * fold), k_cur, v_cur,
+      sk2)

@@ -185,26 +185,45 @@ def decode_walk_columns(table_width: int, page_size: int,
     return table_width
 
 
-# What the double-buffered block of pages that one grid step of the
-# latent decode kernel folds may take of VMEM (of 16 MiB a kernel may
-# use by default; decided on the chip, PERF.md, PR 41).
-_LATENT_FOLD_VMEM_BYTES = 4 << 20
+# What the double-buffered block of pages that one grid step of a decode
+# attention kernel folds may take of VMEM (of 16 MiB a kernel may use by
+# default; decided on the chip: PERF.md, PR 41 for the latent kernel,
+# PR 46 for the paged one).
+_FOLD_VMEM_BYTES = 4 << 20
+
+
+def _fold_pages(page_bytes: int, columns: int) -> int:
+    """The largest power of two of pages whose double-buffered block
+    stays under ``_FOLD_VMEM_BYTES``, and no more than ``columns``."""
+    pages = 1
+    while (2 * pages <= columns
+           and 2 * (2 * pages) * page_bytes <= _FOLD_VMEM_BYTES):
+        pages *= 2
+    return pages
 
 
 def latent_fold_pages(page_size: int, width: int, itemsize: int,
                       table_width: int) -> int:
     """Pages of a row that ONE grid step of the latent decode kernel
-    folds (ops/pallas/latent.py): the largest power of two whose
-    double-buffered block, a page's ``width`` values riding whole
-    128-lane tiles, stays under ``_LATENT_FOLD_VMEM_BYTES``, and no more
-    than the table has columns. From shapes alone: the kernel and the
-    engine's plan line both read it here."""
-    page = page_size * -(-width // 128) * 128 * itemsize
-    pages = 1
-    while (2 * pages <= table_width
-           and 2 * (2 * pages) * page <= _LATENT_FOLD_VMEM_BYTES):
-        pages *= 2
-    return pages
+    folds (ops/pallas/latent.py): ``_fold_pages`` of a page's ``width``
+    values riding whole 128-lane tiles, over the table's columns. From
+    shapes alone: the kernel and the engine's plan line both read it
+    here."""
+    return _fold_pages(page_size * -(-width // 128) * 128 * itemsize,
+                       table_width)
+
+
+def paged_fold_pages(page_size: int, num_kv_heads: int, head_dim: int,
+                     itemsize: int, walk: int) -> int:
+    """Pages of a row that ONE grid step of the paged decode kernel folds
+    (ops/pallas/paged_attention.py): ``_fold_pages`` of a page's keys
+    and values together, over the ``walk`` columns the grid walks a row
+    (``decode_walk_columns``). A page pair too large for two in the
+    budget gives 1: a page a grid step. From shapes alone, as the latent
+    kernel's: the kernel and the engine's plan line both read it here."""
+    return _fold_pages(
+        2 * page_size * num_kv_heads * -(-head_dim // 128) * 128 * itemsize,
+        walk)
 
 
 def _on_tpu() -> bool:

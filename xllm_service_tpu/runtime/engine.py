@@ -47,7 +47,7 @@ from xllm_service_tpu.config import EngineConfig, ModelConfig
 from xllm_service_tpu.models import transformer
 from xllm_service_tpu.obs import steptrace
 from xllm_service_tpu.ops.plan import (
-    KernelPlan, decode_walk_columns, latent_fold_pages)
+    KernelPlan, decode_walk_columns, latent_fold_pages, paged_fold_pages)
 from xllm_service_tpu.parallel.expert import MOE_STATS
 from xllm_service_tpu.ops.sampling import (
     SamplingTensors, compute_logprobs, compute_top_logprobs, sample_tokens,
@@ -403,15 +403,16 @@ class Engine:
             if self.plan.decode_attn and model_cfg.layer_sliding is None
             and not model_cfg.mla else 0)
         kinds = model_cfg.layer_kinds or ()
+        # Which decode attention kernel folds a row's pages in blocks:
+        # the latent one, the paged one, or none (the XLA reference).
+        self._fold_kernel = (
+            "latent" if self.plan.latent_decode else
+            "paged" if self.plan.decode_attn and not model_cfg.mla else "")
         fold = ""
-        if self.plan.latent_decode:
-            # pages a grid step of the latent decode kernel, from the
-            # function the kernel reads it from (ops/pallas/latent.py)
-            pages = latent_fold_pages(
-                engine_cfg.page_size, self.kv[0].shape[-1],
-                self.kv[0].dtype.itemsize, MP)
-            fold = (f"; latent fold {pages} pages a grid step, "
-                    f"{-(-MP // pages)} steps of {MP} columns")
+        if self._fold_kernel:
+            pages, walk = self._decode_fold(MP), self._decode_walk(MP)
+            fold = (f"; {self._fold_kernel} fold {pages} pages a grid "
+                    f"step, {-(-walk // pages)} steps of {walk} columns")
         if self.state_model:
             fold += (f"; mixer ssm_prefill {self.plan.ssm_prefill}, "
                      f"ssm_decode "
@@ -1959,6 +1960,21 @@ class Engine:
         return decode_walk_columns(mp, self.ecfg.page_size,
                                    self._static_window)
 
+    def _decode_fold(self, mp: int) -> int:
+        """Pages of a row that one grid step of the decode program's
+        attention kernel folds at an ``mp``-wide table, from the
+        functions the kernels read it from (ops/plan.py); 1 where no
+        kernel folds (the XLA reference)."""
+        pool = self.kv[0]
+        if self._fold_kernel == "latent":
+            return latent_fold_pages(self.ecfg.page_size, pool.shape[-1],
+                                     pool.dtype.itemsize, mp)
+        if self._fold_kernel == "paged":
+            return paged_fold_pages(self.ecfg.page_size, *pool.shape[-2:],
+                                    pool.dtype.itemsize,
+                                    self._decode_walk(mp))
+        return 1
+
     def _run_decode(self) -> List[StepOutput]:
         """One decode step for the running rows. Step N+1 is launched
         BEFORE step N is read (``_launch_ahead``): N's program hands back
@@ -2076,7 +2092,8 @@ class Engine:
     def _decode_shape(self, mp: int) -> Dict[str, Any]:
         """The shape key a decode launch's span carries."""
         return dict(program="decode", B=self.ecfg.max_batch_size, T=1,
-                    MP=mp, walk=self._decode_walk(mp))
+                    MP=mp, walk=self._decode_walk(mp),
+                    fold=self._decode_fold(mp))
 
     def _launch_decode(self, bracket, packed: jnp.ndarray,
                        mirror: np.ndarray, kind: str = "decode"
