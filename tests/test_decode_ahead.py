@@ -12,6 +12,7 @@ import dataclasses
 import functools
 import json
 
+import numpy as np
 import pytest
 
 from xllm_service_tpu.config import EngineConfig, ModelConfig
@@ -64,15 +65,18 @@ _COUNTS = {"launch": "decode.ahead_dispatch", "hit": "decode.ahead_hit",
            "upload": "decode.upload", "resident": "decode.resident_hit"}
 
 
-def _drive(eng, feed, cancel=()):
-    """Feed ``{step: [requests]}`` and cancel ``{step: rid}``; returns
-    ``({rid: (tokens, logprobs, reason)}, [a record per step])``. A
-    record holds the step's counter deltas, its kind, who finished, whose
-    table grew, who was trimmed, how many were preempted, whether a
-    request waited when it began and when it ended, and the members of
-    the step it left pending (None: nothing)."""
+def _drive(eng, feed, cancel=(), drain=()):
+    """Feed ``{step: [requests]}``, cancel ``{step: rid}`` and, before
+    the steps of ``drain``, throw away whatever step is on the device
+    ahead (``drain_pipeline``, as an import or a sleep would); returns
+    ``({rid: (tokens, logprobs, reason, top logprobs)}, [a record per
+    step])``. A record holds the step's counter deltas (a drain's
+    discard among them), its kind, who finished, whose table grew, who
+    was trimmed, how many were preempted, whether a request waited when
+    it began and when it ended, and the members of the step it left
+    pending (None: nothing)."""
     cancel = dict(cancel)
-    toks, lps, reasons, recs = {}, {}, {}, []
+    toks, lps, tops, reasons, recs = {}, {}, {}, {}, []
     pc, step = eng.phase_counts, 0
     while eng.has_work() or step < max(feed):
         step += 1
@@ -86,9 +90,13 @@ def _drive(eng, feed, cancel=()):
         trim = {s.req.request_id: s.num_trimmed for s in eng.running}
         rec = {"step": step, "waiting": bool(eng.waiting),
                "pending": eng._pending is not None, "fin": {}}
+        if step in drain:
+            eng.drain_pipeline()
         for out in eng.step():
             toks.setdefault(out.request_id, []).extend(out.new_token_ids)
             lps.setdefault(out.request_id, []).extend(out.logprobs)
+            tops.setdefault(out.request_id, []).extend(
+                out.top_logprobs or ())
             if out.finished:
                 reasons[out.request_id] = rec["fin"][out.request_id] = \
                     out.finish_reason
@@ -103,18 +111,19 @@ def _drive(eng, feed, cancel=()):
             waits=bool(eng.waiting))
         recs.append(rec)
         assert step < 400, "engine did not drain"
-    return {r: (toks[r], lps[r], reasons.get(r)) for r in toks}, recs
+    return {r: (toks[r], lps[r], reasons.get(r), tops[r])
+            for r in toks}, recs
 
 
 # ---------------------------------------------------------------------------
 # The same streams, whatever falls on a step launched ahead
 # ---------------------------------------------------------------------------
 def _event_schedule(event, sampling, eos=None):
-    """(engine options, feed, cancels) of the schedule that makes
+    """(engine options, feed, cancels, drains) of the schedule that makes
     ``event`` fall on a step that was launched ahead."""
     a = _req("a", range(1, 7), 40, sampling)
     b = _req("b", range(2, 9), 40, sampling)
-    opts, cancel = {}, {}
+    opts, cancel, drain = {}, {}, ()
     feed = {1: [a, b]}
     if event == "admit":
         feed[6] = [_req("late", range(5, 12), 12, sampling)]
@@ -134,13 +143,37 @@ def _event_schedule(event, sampling, eos=None):
         opts = dict(page_size=8)
     elif event == "swa_trim":
         opts = dict(page_size=4, num_pages=24, model=_tiny(window=8))
-    return opts, feed, cancel
+    elif event == "top_logprobs":
+        # a asks for the alternatives, b does not: the step on the
+        # device ahead carries them for both, and after a discard (5, 9)
+        # its replacement does
+        opts = dict(num_top_logprobs=2)
+        feed[1] = [_req("a", range(1, 7), 40, sampling, logprobs=True,
+                        top_logprobs=2), b]
+        drain = (5, 9)
+    elif event == "penalties":
+        # the histogram rides from step to step on the device; a prefill
+        # (6) and a discard (4, 12) rebuild it from the host's tokens
+        feed = {1: [_req("a", range(1, 7), 40, sampling,
+                         presence_penalty=0.8, frequency_penalty=0.4), b],
+                6: [_req("late", range(5, 12), 12, sampling,
+                         presence_penalty=0.5)]}
+        drain = (4, 12)
+    elif event == "model_len":
+        # both rows decode up to the 32 positions there are
+        opts = dict(max_model_len=32)
+    return opts, feed, cancel, drain
+
+
+_EVENTS = ["admit", "max_tokens", "eos", "cancel", "preempt",
+           "page_growth", "swa_trim", "top_logprobs", "penalties",
+           "model_len"]
 
 
 @functools.lru_cache(maxsize=None)
 def _eos_of(sampling):
     """The token the control's stream of b reaches fifth or later, first."""
-    opts, feed, cancel = _event_schedule("steady", sampling)
+    opts, feed, cancel, _ = _event_schedule("steady", sampling)
     st = _drive(_engine(sequential=True, **opts), feed, cancel)[0]["b"][0]
     return next(t for i, t in enumerate(st) if i >= 4 and t not in st[:i])
 
@@ -155,7 +188,7 @@ def _event_run(event, sampling, mode):
     inside a tail dispatch (the engine itself is not kept: dozens of live
     ones, each with its executables, crash the CPU backend's loader)."""
     eos = _eos_of(sampling) if event == "eos" else None
-    opts, feed, cancel = _event_schedule(event, sampling, eos)
+    opts, feed, cancel, drain = _event_schedule(event, sampling, eos)
     eng = _engine(sequential=mode == "sequential", ahead=mode == "both",
                   **opts)
     real = eng._dispatch_decode
@@ -167,14 +200,33 @@ def _event_run(event, sampling, mode):
             at_tail * (eng.num_preemptions - pre)
         return step
     eng._dispatch_decode = dispatch
-    return _drive(eng, feed, cancel) + (collections.Counter(
+    return _drive(eng, feed, cancel, drain) + (collections.Counter(
         eng.phase_counts),)
 
 
+def _a_alone_got_its_alternatives(got):
+    """``top_logprobs``: every token of a's came with its two
+    alternatives, off a step that was on the device ahead as off the one
+    that replaced a discarded one; b, who asked for none, got none."""
+    toks, _, _, tops = got["a"]
+    assert len(tops) == len(toks) == 40 and got["b"][3] == []
+    assert all(len(t) == 2 for t in tops)
+
+
+def _ends_at_the_last_position(got, recs):
+    """``model_len``: each row fills the 32 positions and ends by
+    length, and nothing is put on the device behind the step that makes
+    a row's last token (known a step ahead, as ``max_tokens`` is)."""
+    for rid, prompt in (("a", 6), ("b", 7)):
+        toks, _, reason, _ = got[rid]
+        assert prompt + len(toks) == 32 and reason == FinishReason.LENGTH
+        last = next(r for r in recs if rid in r["fin"])
+        assert not last["launch"] and not last["tail"]
+        assert last["left"] is None
+
+
 @pytest.mark.parametrize("sampling", ["greedy", "seeded"])
-@pytest.mark.parametrize("event", [
-    "admit", "max_tokens", "eos", "cancel", "preempt", "page_growth",
-    "swa_trim"])
+@pytest.mark.parametrize("event", _EVENTS)
 def test_streams_are_the_sequential_engines(event, sampling):
     """Token ids, logprobs and finish reasons are those of the same
     engine with the predicate forced false, with ``event`` falling on a
@@ -223,17 +275,40 @@ def test_streams_are_the_sequential_engines(event, sampling):
                                  for r in grown)
         assert all(r["tail"] and r["upload"] and not r["launch"]
                    and by_step[r["step"] + 1]["tail_hit"] for r in grown)
-    else:
+    elif event == "swa_trim":
         assert any(r["trimmed"] for r in hits)
+    elif event == "top_logprobs":
+        _a_alone_got_its_alternatives(got)
+        assert [by_step[s]["discard"] for s in (5, 9)] == [1, 1]
+    elif event == "penalties":
+        # a discarded step had counted a token that its replacement
+        # makes again: the histogram is the host's tokens' once more
+        # when the replacement goes out, and the next launch follows it
+        # (4: a step launched ahead; 12: one dispatched at a tail, behind
+        # a page grown)
+        assert [(by_step[s]["discard"], by_step[s]["tail_discard"],
+                 by_step[s]["launch"]) for s in (4, 12)] == \
+            [(1, 0, 1), (0, 1, 1)]
+        eng = _engine()
+        eng.add_request(_req("a", range(1, 7), 40, sampling,
+                             presence_penalty=0.8))
+        eng.step(), eng.step()
+        assert eng._pending is not None and eng._counts is not None
+        eng.drain_pipeline()
+        # ... and nothing is launched onto a histogram that is not there
+        assert eng._counts is None and not eng._ahead_eligible()
+        eng.step()
+        assert eng._pending is not None and eng._counts is not None
+    else:
+        _ends_at_the_last_position(got, recs)
+        assert not any(r["discard"] or r["dropped"] for r in recs)
 
 
 # ---------------------------------------------------------------------------
 # The tail dispatch alone: the same streams with it forced off
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("sampling", ["greedy", "seeded"])
-@pytest.mark.parametrize("event", [
-    "admit", "max_tokens", "eos", "cancel", "preempt", "page_growth",
-    "swa_trim"])
+@pytest.mark.parametrize("event", _EVENTS)
 def test_streams_are_those_of_the_tail_dispatch_forced_off(event, sampling):
     """Token ids, logprobs and finish reasons are those of the same
     engine with ``_tail_eligible`` forced false: the step dispatched at
@@ -256,9 +331,12 @@ def test_streams_are_those_of_the_tail_dispatch_forced_off(event, sampling):
             assert nxt["tail_hit"] + nxt["tail_discard"] == 1
     # never after a finish, never while anything waits
     assert not any(r["tail"] for r in recs if r["fin"] or r["waits"])
-    assert not any(r["tail_discard"] for r in recs)
+    # discarded by a drain alone, and then it had counted its block
+    drains = _event_schedule(event, sampling)[3]
+    assert [r["step"] for r in recs if r["tail_discard"]] == list(drains)
     assert pc["decode.upload"] + pc["decode.resident_hit"] == \
-        pc["decode.tail_hit"] + pc["decode.dispatch"]
+        pc["decode.tail_hit"] + pc["decode.dispatch"] \
+        + pc["decode.tail_discard"]
     if event == "admit":
         r = by_step[6]      # the step in flight is taken, then the prefill
         assert r["tail_hit"] and r["kind"] == "mixed"
@@ -289,14 +367,18 @@ def test_streams_are_those_of_the_tail_dispatch_forced_off(event, sampling):
         steady = [r for r in recs if r["tail"] and not r["grew"]
                   and r["kind"] == "decode"]
         assert steady and not any(r["upload"] for r in steady)
-    else:
+    elif event == "swa_trim":
         assert any(r["trimmed"] for r in hits)
+    elif event == "top_logprobs":
+        _a_alone_got_its_alternatives(got)
+    elif event == "model_len":
+        _ends_at_the_last_position(got, recs)
 
 
 def test_what_step_leaves_pending():
     """``step()`` leaves the next decode on the device after a page
-    grown and after a prefill section; nothing after a finish, while
-    anything waits, in a burst engine and under ``interleave=False``."""
+    grown and after a prefill section; nothing after a finish or while
+    anything waits."""
     def left(eng, feed, cancel=()):
         return [r["left"] for r in _drive(eng, feed, cancel)[1]]
     a, b = _req("a", range(1, 7), 12), _req("b", range(2, 9), 3)
@@ -313,11 +395,6 @@ def test_what_step_leaves_pending():
     assert len(windows) >= 3
     assert [r["left"] for r in windows[:-1]] == [None] * (len(windows) - 1)
     assert windows[-1]["left"] == ["a", "long"]
-    for opts in (dict(decode_steps=4), dict(interleave=False)):
-        eng = _engine(ahead=False, **opts)
-        _drive(eng, {1: [_req("a", range(1, 7), 12)],
-                     5: [_req("b", range(2, 9), 3)]})
-        assert not eng.phase_counts["decode.tail_dispatch"]
 
 
 def test_a_discarded_tail_dispatch_is_run_again_from_the_same_key():
@@ -348,6 +425,29 @@ def test_a_discarded_tail_dispatch_is_run_again_from_the_same_key():
     assert pc["decode.upload"] + pc["decode.resident_hit"] == \
         pc["decode.tail_hit"] + pc["decode.dispatch"] \
         + pc["decode.tail_discard"]
+
+
+def test_an_uploaded_block_is_the_steps_own_at_the_full_table_width(
+        monkeypatch):
+    """At the full table width the block a step uploads is the whole
+    slot array, and the CPU backend's upload aliases host memory where
+    its alignment lets it: a step dispatched at a tail would read what
+    the next iteration's ``_fill_slots`` writes (zero, then the rows
+    again) while it runs. The step is given a copy of its own."""
+    import jax
+    eng = _engine(ahead=False, max_model_len=32)     # two pages a row
+    uploaded = []
+    real = jax.device_put
+
+    def put(x, *a, **kw):
+        if isinstance(x, np.ndarray) and x.shape == eng._slot_packed.shape:
+            uploaded.append(np.shares_memory(x, eng._slot_packed))
+        return real(x, *a, **kw)
+    monkeypatch.setattr(jax, "device_put", put)
+    eng.add_request(_req("a", range(1, 18), 6))      # 17 tokens: 2 pages
+    eng.step()
+    assert eng._pending is not None                  # dispatched at the tail
+    assert uploaded == [False]
 
 
 # ---------------------------------------------------------------------------
@@ -415,7 +515,7 @@ def test_a_launch_whose_rows_have_all_gone_is_dropped():
     assert eng.phase_counts["decode.ahead_discard"] == 1
 
 
-def test_drain_pipeline_takes_the_single_steps_launch_too():
+def test_drain_pipeline_takes_the_step_launched_ahead():
     """Whatever changes membership outside the loop drains first (import,
     export, sleep, fault_reset, isolate, warm-up): the discarded step is
     run again from the same key, so the streams do not move."""
@@ -531,8 +631,8 @@ def test_a_latent_pool_under_the_dropless_experts_launches_ahead():
 def test_rows_that_share_an_experts_capacity_are_taken_whole():
     """``_mlp``'s bucketed sparse layer: a row that has left still
     competes for an expert's capacity in a step launched ahead. There a
-    known finish forbids the launch, and an EOS discards the step whole
-    (the burst path's rule): the streams are the sequential engine's."""
+    known finish forbids the launch, and an EOS discards the step
+    whole: the streams are the sequential engine's."""
     model = _tiny(num_experts=4)
     st = _family_streams(model, True)[0]["b"][0]
     eos = next(t for i, t in enumerate(st) if i >= 4 and t not in st[:i])
@@ -569,19 +669,6 @@ def test_every_family_dispatches_at_the_tail():
                 cancel["tail_discard"]) == (1 - discards, 1 - discards,
                                             discards)
         assert sum(r["tail_discard"] for r in recs) == discards
-
-
-def test_a_burst_engines_single_step_launches_nothing():
-    """Where bursts are fused the single step is the fallback for a row
-    near ``max_model_len``; its next decode may be a burst again, and
-    ``XLLM_DECODE_PIPELINE`` keeps its meaning for bursts alone."""
-    eng = _engine(decode_steps=4, max_model_len=32)
-    _, recs = _drive(eng, {1: [_req("a", range(1, 10), 30)]})
-    pc = eng.phase_counts
-    assert pc["decode.dispatch"] >= 2 and pc["decode_multi.dispatch"] >= 1
-    assert not pc["decode.ahead_dispatch"]
-    assert not pc["decode.tail_dispatch"]
-    assert pc["decode_multi.spec_dispatch"] >= 1
 
 
 # ---------------------------------------------------------------------------

@@ -225,12 +225,12 @@ class TestEndToEnd:
             master.stop()
 
     def test_streamed_chat_decode_pipeline_overlap(self, store):
-        """Pipelined decode end to end: a streamed chat over a
-        fused-burst engine (decode_steps=4, XLLM_DECODE_PIPELINE auto-on)
-        completes with the usual SSE grammar, and the worker /metrics
-        plane proves the overlap engaged — speculative dispatch/hit
-        counters nonzero, hit-ratio gauge exported, burst readbacks
-        overlapping live next-burst dispatches."""
+        """The decode pipeline end to end: a streamed chat completes
+        with the usual SSE grammar, and the worker /metrics plane proves
+        the overlap engaged — decode steps were on the device before
+        their iteration began (launched ahead and taken), the overlap
+        counters and the hit-ratio gauge say so, and the split readback
+        attribution reaches the phase ledger."""
         import http.client
         opts = ServiceOptions(
             http_port=0, rpc_port=0, num_output_pools=4,
@@ -238,13 +238,13 @@ class TestEndToEnd:
             block_size=16, heartbeat_interval_s=0.2,
             master_upload_interval_s=0.2)
         master = Master(opts, store=store).start()
-        # Large pages so the speculative burst's KV writes stay covered
-        # by the already-grown tables on most bursts (speculation never
-        # allocates — a page-boundary burst skips, the rest hit).
+        # Large pages so the write of a step launched ahead stays
+        # covered by the already-grown table on most steps (a launch
+        # ahead never allocates: at a page boundary it is the tail
+        # dispatch's, the rest hit).
         ecfg = EngineConfig(page_size=64, num_pages=32, max_model_len=256,
                             max_batch_size=4, max_prefill_tokens=256,
-                            prefill_buckets=(32, 64, 128),
-                            decode_steps=4)
+                            prefill_buckets=(32, 64, 128))
         wopts = WorkerOptions(
             port=0, instance_type=InstanceType.DEFAULT,
             service_addr=master.rpc_address, model="tiny",
@@ -267,25 +267,30 @@ class TestEndToEnd:
                        == "length" for o in objs)
 
             eng = worker.primary_runtime().engine
-            assert eng.phase_counts["decode_multi.spec_hit"] > 0
-            assert eng.phase_counts["decode_multi.spec_dispatch"] > 0
+            assert eng.phase_counts["decode.ahead_hit"] > 0
+            assert eng.phase_counts["decode.ahead_dispatch"] > 0
             conn = http.client.HTTPConnection(worker.name, timeout=10)
             conn.request("GET", "/metrics")
             wtext = conn.getresponse().read().decode()
             conn.close()
+            ahead = next(
+                float(line.split()[-1]) for line in wtext.splitlines()
+                if line.startswith('xllm_worker_decode_ahead_total'
+                                   '{model="tiny",result="hit"}'))
+            assert ahead > 0
             hits = next(
                 float(line.split()[-1]) for line in wtext.splitlines()
                 if line.startswith('xllm_worker_decode_overlap_spec_'
                                    'total{model="tiny",result="hit"}'))
-            assert hits > 0
+            assert hits >= ahead
             ratio = next(
                 float(line.split()[-1]) for line in wtext.splitlines()
                 if line.startswith('xllm_worker_decode_overlap_hit_'
                                    'ratio{model="tiny"}'))
             assert ratio > 0
             # The split readback attribution reaches the phase ledger.
-            assert 'phase="decode_multi.device_wait"' in wtext
-            assert 'phase="decode_multi.host_copy"' in wtext
+            assert 'phase="decode.device_wait"' in wtext
+            assert 'phase="decode.host_copy"' in wtext
             from xllm_service_tpu.obs import validate_exposition
             assert validate_exposition(wtext) == []
         finally:

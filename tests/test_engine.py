@@ -408,14 +408,12 @@ def _drive_one(eng, rid, prompt, max_tokens):
     return eng._by_id[rid]
 
 
-@pytest.mark.parametrize("decode_steps", [1, 4])
-def test_prefix_index_hashes_a_page_once(decode_steps):
+def test_prefix_index_hashes_a_page_once():
     """A P-page prompt decoded across two page boundaries feeds the block
     hash P pages at admission and one page at each boundary: the cost
     does not grow with the number of sampled tokens times the length."""
     ps, P = 8, 2
-    eng = _tiny_engine(page_size=ps, prefill_buckets=(16, 32),
-                       decode_steps=decode_steps)
+    eng = _tiny_engine(page_size=ps, prefill_buckets=(16, 32))
     before = eng.prefix_cache_stats()["hashed_tokens_total"]
     seq = _drive_one(eng, "r", range(1, P * ps + 1), 2 * ps + 3)
     _collect(eng)
@@ -426,16 +424,13 @@ def test_prefix_index_hashes_a_page_once(decode_steps):
     assert seq.page_digests == eng.prefix_cache.block_hashes(seq.tokens)
 
 
-@pytest.mark.parametrize("decode_steps", [1, 4])
-def test_no_call_site_rehashes_or_slices_the_token_list(decode_steps,
-                                                        monkeypatch):
+def test_no_call_site_rehashes_or_slices_the_token_list(monkeypatch):
     """Per token, at a preemption, at readmission and at the finish the
     engine hands the index the sequence's own list and digests: nothing
     calls the from-block-0 ``block_hashes``, nothing builds
     ``tokens[:n]``, and a readmitted sequence hashes nothing twice."""
     ps = 8
-    eng = _tiny_engine(page_size=ps, prefill_buckets=(16, 32),
-                       decode_steps=decode_steps)
+    eng = _tiny_engine(page_size=ps, prefill_buckets=(16, 32))
     idx = eng.prefix_cache
     seq = _drive_one(eng, "r", range(1, 2 * ps + 4), 2 * ps + 3)
     calls = []
@@ -461,7 +456,7 @@ def test_no_call_site_rehashes_or_slices_the_token_list(decode_steps,
         eng.step()
     assert idx.hashed_tokens == hashed
     _collect(eng)
-    assert len(calls) >= seq.num_generated // decode_steps
+    assert len(calls) >= seq.num_generated
     assert set(calls) == {(True, True)}
     n_full = (len(seq.tokens) - 1) // ps
     assert idx.hashed_tokens == n_full * ps == len(seq.page_digests) * ps
@@ -650,456 +645,6 @@ class TestKvMigration:
                           sampling=SamplingParams(max_tokens=4)),
             list(range(1, 17)), k, k)
         assert not ok   # no free slot → clean refusal
-
-
-class TestMultiStepDecode:
-    """Fused N-step decode must produce the same greedy tokens as
-    single-step decode, including finish handling."""
-
-    def _run(self, decode_steps, max_tokens, prompt, vocab=128):
-        from xllm_service_tpu.config import EngineConfig, ModelConfig
-        from xllm_service_tpu.runtime.engine import Engine, EngineRequest
-        from xllm_service_tpu.utils.types import SamplingParams
-
-        mcfg = ModelConfig.tiny(vocab_size=vocab)
-        ecfg = EngineConfig(page_size=8, num_pages=32, max_model_len=64,
-                            max_batch_size=2, max_prefill_tokens=64,
-                            prefill_buckets=(16, 32),
-                            decode_steps=decode_steps)
-        eng = Engine(mcfg, ecfg, seed=0)
-        eng.add_request(EngineRequest(
-            request_id="r", token_ids=list(prompt),
-            sampling=SamplingParams(max_tokens=max_tokens,
-                                    temperature=0.0, ignore_eos=True)))
-        toks = []
-        steps = 0
-        while eng.has_work():
-            for out in eng.step():
-                toks.extend(out.new_token_ids)
-            steps += 1
-        return toks, steps
-
-    def test_greedy_equivalence(self):
-        prompt = list(range(1, 13))
-        single, s_steps = self._run(1, 12, prompt)
-        multi, m_steps = self._run(4, 12, prompt)
-        assert multi == single
-        assert len(multi) == 12
-        # 1 prefill + ceil(11/4) multi rounds vs 1 + 11 single rounds.
-        assert m_steps < s_steps
-
-    def test_max_tokens_not_multiple_of_steps(self):
-        prompt = list(range(1, 9))
-        single, _ = self._run(1, 5, prompt)
-        multi, _ = self._run(4, 5, prompt)
-        assert multi == single
-        assert len(multi) == 5
-
-    def test_eos_mid_scan_stops(self):
-        from xllm_service_tpu.config import EngineConfig, ModelConfig
-        from xllm_service_tpu.runtime.engine import Engine, EngineRequest
-        from xllm_service_tpu.utils.types import SamplingParams
-
-        mcfg = ModelConfig.tiny(vocab_size=64)
-        ecfg = EngineConfig(page_size=8, num_pages=32, max_model_len=64,
-                            max_batch_size=2, max_prefill_tokens=64,
-                            prefill_buckets=(16,), decode_steps=4)
-        eng = Engine(mcfg, ecfg, seed=0)
-        # First, find what greedy emits so we can make token #2 the "eos".
-        eng.add_request(EngineRequest(
-            request_id="probe", token_ids=list(range(1, 9)),
-            sampling=SamplingParams(max_tokens=6, temperature=0.0,
-                                    ignore_eos=True)))
-        probe = []
-        while eng.has_work():
-            for out in eng.step():
-                probe.extend(out.new_token_ids)
-        eos = probe[1]
-        eng2 = Engine(mcfg, ecfg, seed=0)
-        eng2.add_request(EngineRequest(
-            request_id="r", token_ids=list(range(1, 9)),
-            sampling=SamplingParams(max_tokens=6, temperature=0.0),
-            eos_token_ids=(eos,)))
-        got = []
-        reasons = []
-        while eng2.has_work():
-            for out in eng2.step():
-                got.extend(out.new_token_ids)
-                if out.finished:
-                    reasons.append(out.finish_reason)
-        assert got == probe[:2]          # truncated at the eos token
-        from xllm_service_tpu.utils.types import FinishReason
-        assert reasons == [FinishReason.STOP]
-        # Pages were released on finish (no leak from discarded lookahead).
-        assert eng2.allocator.num_free + eng2.prefix_cache.num_reclaimable \
-            == ecfg.num_pages - 1
-
-    def test_device_resident_state_reused_across_bursts(self):
-        """Consecutive decode bursts with unchanged batch membership must
-        feed the previous burst's returned (tokens, positions) device
-        arrays straight back in — zero re-uploads — and produce the same
-        tokens as
-        the always-upload path (covered by the equivalence tests above,
-        which run with the same mechanism)."""
-        from xllm_service_tpu.config import EngineConfig, ModelConfig
-        from xllm_service_tpu.runtime.engine import Engine, EngineRequest
-        from xllm_service_tpu.utils.types import SamplingParams
-
-        mcfg = ModelConfig.tiny(vocab_size=64)
-        # decode_pipeline off: an accepted SPECULATIVE burst bypasses
-        # the resident snapshot entirely (it never re-packs) — this test
-        # exercises the fallback resident-reuse mechanism itself.
-        ecfg = EngineConfig(page_size=8, num_pages=32, max_model_len=64,
-                            max_batch_size=2, max_prefill_tokens=64,
-                            prefill_buckets=(16,), decode_steps=4,
-                            decode_pipeline=False)
-        eng = Engine(mcfg, ecfg, seed=0)
-        eng.add_request(EngineRequest(
-            request_id="r", token_ids=list(range(1, 9)),
-            sampling=SamplingParams(max_tokens=24, temperature=0.0,
-                                    ignore_eos=True)))
-        while eng.has_work():
-            eng.step()
-        bursts = eng.phase_counts.get("decode_multi.dispatch", 0)
-        hits = eng.phase_counts.get("decode_multi.resident_hit", 0)
-        assert bursts >= 5
-        # Every burst after the first runs on resident state: one
-        # uninterrupted sequence never invalidates the snapshot.
-        assert hits == bursts - 1
-
-    def test_resident_state_invalidated_by_new_admission(self):
-        """A prefill admission between bursts changes batch membership;
-        the snapshot must miss and the burst must fall back to a fresh
-        upload (wrong tokens for the new slot otherwise)."""
-        from xllm_service_tpu.config import EngineConfig, ModelConfig
-        from xllm_service_tpu.runtime.engine import Engine, EngineRequest
-        from xllm_service_tpu.utils.types import SamplingParams
-
-        mcfg = ModelConfig.tiny(vocab_size=64)
-        ecfg = EngineConfig(page_size=8, num_pages=64, max_model_len=64,
-                            max_batch_size=4, max_prefill_tokens=64,
-                            prefill_buckets=(16,), decode_steps=4)
-
-        def run(staggered: bool):
-            eng = Engine(mcfg, ecfg, seed=0)
-            eng.add_request(EngineRequest(
-                request_id="a", token_ids=list(range(1, 9)),
-                sampling=SamplingParams(max_tokens=16, temperature=0.0,
-                                        ignore_eos=True)))
-            toks = {"a": [], "b": []}
-            fed_b = not staggered
-            if not staggered:
-                eng.add_request(EngineRequest(
-                    request_id="b", token_ids=list(range(3, 11)),
-                    sampling=SamplingParams(max_tokens=16,
-                                            temperature=0.0,
-                                            ignore_eos=True)))
-            steps = 0
-            while eng.has_work() or not fed_b:
-                steps += 1
-                if staggered and steps == 3 and not fed_b:
-                    # Mid-generation admission: membership changes.
-                    eng.add_request(EngineRequest(
-                        request_id="b", token_ids=list(range(3, 11)),
-                        sampling=SamplingParams(max_tokens=16,
-                                                temperature=0.0,
-                                                ignore_eos=True)))
-                    fed_b = True
-                for out in eng.step():
-                    toks[out.request_id].extend(out.new_token_ids)
-            return toks
-
-        together = run(staggered=False)
-        staggered = run(staggered=True)
-        # Greedy decode is deterministic per sequence: the staggered
-        # admission must not corrupt either sequence's continuation.
-        assert staggered["a"] == together["a"]
-        assert len(staggered["b"]) == 16
-
-    def test_multi_to_single_fallback_no_kv_hole(self):
-        """Regression: a multi-step burst leaves pages covering only its
-        own lookahead; the single-step fallback near max_model_len must
-        grow pages before dispatch or its KV write is silently dropped
-        (NULL-page mode="drop"), leaving a hole in the cache."""
-        mcfg = ModelConfig.tiny(vocab_size=64)
-        # decode_steps=6 with max_model_len=16: multi runs while
-        # len+5 <= 16; prompt 6 -> prefill len 7 -> one multi burst to
-        # len 13 (pages pre-grown for 12 tokens = 3 pages) -> single-step
-        # fallback writes position 12, which needs an unmapped 4th page.
-        ecfg = EngineConfig(page_size=4, num_pages=32, max_model_len=16,
-                            max_batch_size=2, max_prefill_tokens=16,
-                            prefill_buckets=(8,), decode_steps=6)
-        eng = Engine(mcfg, ecfg, seed=0)
-        eng.add_request(EngineRequest(
-            request_id="r", token_ids=list(range(1, 7)),
-            sampling=SamplingParams(max_tokens=12, temperature=0.0,
-                                    ignore_eos=True),
-            hold_after_finish=True))
-        while eng.has_work():
-            eng.step()
-        tokens, k, v = eng.export_held("r")
-        assert len(tokens) == 16
-        # KV is resident for tokens[:-1]; every such position must hold a
-        # real (nonzero) key vector — a zero row is the dropped write.
-        ps = ecfg.page_size
-        for pos in range(len(tokens) - 1):
-            row = np.asarray(k[:, pos // ps, pos % ps])   # [L, Hkv, Dh]
-            assert np.abs(row).max() > 0, f"KV hole at position {pos}"
-
-
-def test_multi_step_lookahead_clamped_to_max_tokens():
-    """A sequence about to hit max_tokens must not reserve decode_steps-1
-    pages of lookahead it can never use: in a pool with exactly enough
-    pages for its true need, unclamped growth would self-preempt."""
-    cfg = ModelConfig.tiny(vocab_size=64)
-    ecfg = EngineConfig(page_size=4, num_pages=4, max_model_len=32,
-                        max_batch_size=1, max_prefill_tokens=16,
-                        prefill_buckets=(8,), decode_steps=8,
-                        enable_prefix_cache=False)
-    eng = Engine(cfg, ecfg, seed=0)
-    eng.add_request(EngineRequest(
-        request_id="clamp", token_ids=list(range(1, 9)),
-        sampling=SamplingParams(max_tokens=2, temperature=0.0,
-                                ignore_eos=True)))
-    toks = []
-    while eng.has_work():
-        for out in eng.step():
-            toks.extend(out.new_token_ids)
-    assert len(toks) == 2
-    assert eng.num_preemptions == 0
-
-
-# ---------------------------------------------------------------------------
-# Pipelined decode: speculative next-burst dispatch + async readback
-# ---------------------------------------------------------------------------
-
-class TestDecodePipeline:
-    """XLLM_DECODE_PIPELINE: burst k+1 dispatched speculatively from
-    burst k's device carries before burst k's readback. Contract pinned
-    here: token ids, logprobs and finish reasons are BYTE-IDENTICAL with
-    the pipeline on vs off across the whole rollback matrix (mid-burst
-    EOS, preempt-during-speculation, admit-invalidates-carries,
-    max_tokens expiry on the burst boundary), and the overlap counters
-    prove the speculation actually engaged."""
-
-    MCFG = ModelConfig.tiny(vocab_size=64)
-
-    @staticmethod
-    def _ecfg(pipeline, **kw):
-        # interleave=False pins the legacy prefill-first routing this
-        # matrix was written against (admission drains the speculative
-        # burst). The interleaver plans ahead instead — an admission
-        # becomes a spec HIT followed by the prefill — and its own
-        # matrix lives in tests/test_interleave.py.
-        d = dict(page_size=32, num_pages=16, max_model_len=64,
-                 max_batch_size=2, max_prefill_tokens=64,
-                 prefill_buckets=(8, 16, 32), decode_steps=4,
-                 decode_pipeline=pipeline, interleave=False)
-        d.update(kw)
-        return EngineConfig(**d)
-
-    @staticmethod
-    def _drive(eng, feed=None):
-        """Drive to idle; returns {rid: (tokens, logprobs, reason)}.
-        ``feed`` = optional {step_number: EngineRequest} mid-run admits
-        (applied before that step runs — the step count is identical on
-        vs off, one burst per step, so both paths see the same admit
-        point)."""
-        toks, lps, reasons = {}, {}, {}
-        fed = set()
-        step = 0
-        while eng.has_work() or (feed and len(fed) < len(feed)):
-            step += 1
-            if feed and step in feed and step not in fed:
-                eng.add_request(feed[step])
-                fed.add(step)
-            for out in eng.step():
-                toks.setdefault(out.request_id, []).extend(
-                    out.new_token_ids)
-                lps.setdefault(out.request_id, []).extend(out.logprobs)
-                if out.finished:
-                    reasons[out.request_id] = out.finish_reason
-            assert step < 200, "engine did not drain"
-        return {r: (toks[r], lps[r], reasons.get(r)) for r in toks}
-
-    @pytest.fixture(scope="class")
-    def greedy_probe(self):
-        """The tiny model's greedy continuation of prompt 1..8 — shared
-        across the matrix (every Engine construction re-compiles its
-        programs on CPU; the probe only needs to run once)."""
-        eng = Engine(self.MCFG, self._ecfg(False), seed=0)
-        eng.add_request(EngineRequest(
-            request_id="p", token_ids=list(range(1, 9)),
-            sampling=SamplingParams(max_tokens=12, temperature=0.0,
-                                    ignore_eos=True)))
-        return self._drive(eng)["p"][0]
-
-    def test_default_resolution_and_env_override(self, monkeypatch):
-        assert Engine(self.MCFG, self._ecfg(None),
-                      seed=0).decode_pipeline is True
-        assert Engine(self.MCFG, self._ecfg(None, decode_steps=1),
-                      seed=0).decode_pipeline is False
-        # Forcing the pipeline on cannot override single-step decode
-        # (there are no burst carries to speculate from).
-        assert Engine(self.MCFG, self._ecfg(True, decode_steps=1),
-                      seed=0).decode_pipeline is False
-        monkeypatch.setenv("XLLM_DECODE_PIPELINE", "0")
-        assert Engine(self.MCFG, self._ecfg(None),
-                      seed=0).decode_pipeline is False
-        monkeypatch.setenv("XLLM_DECODE_PIPELINE", "1")
-        assert Engine(self.MCFG, self._ecfg(None),
-                      seed=0).decode_pipeline is True
-
-    def test_rollback_mid_burst_eos(self, greedy_probe):
-        """A sequence hitting EOS mid-burst while a speculative burst is
-        in flight: the speculation rolls back, the continuing sequence's
-        stream (and the finisher's truncation) are byte-identical to the
-        pipeline-off run."""
-        eos = greedy_probe[1]  # second generated token → stops mid-burst
-
-        def run(pipeline):
-            e = Engine(self.MCFG, self._ecfg(pipeline), seed=0)
-            e.add_request(EngineRequest(
-                request_id="a", token_ids=list(range(1, 9)),
-                sampling=SamplingParams(max_tokens=12, temperature=0.0),
-                eos_token_ids=(eos,)))
-            e.add_request(EngineRequest(
-                request_id="b", token_ids=list(range(3, 11)),
-                sampling=SamplingParams(max_tokens=12, temperature=0.0,
-                                        ignore_eos=True)))
-            return self._drive(e), e.overlap_metrics()
-
-        on, om_on = run(True)
-        off, om_off = run(False)
-        assert on == off
-        assert on["a"][2] == FinishReason.STOP
-        assert len(on["a"][0]) == 2          # prefill token + the eos
-        assert on["b"][2] == FinishReason.LENGTH
-        assert om_on["spec_rollbacks"] >= 1, om_on
-        assert om_off["spec_dispatches"] == 0
-
-    def test_rollback_admit_invalidates_carries(self):
-        """A mid-generation admission drains the in-flight speculation
-        (the admit path must not wait behind it) and the next step
-        prefills the new prompt; both sequences' streams match the
-        pipeline-off run exactly."""
-        req_b = EngineRequest(
-            request_id="b", token_ids=list(range(3, 11)),
-            sampling=SamplingParams(max_tokens=16, temperature=0.0,
-                                    ignore_eos=True))
-
-        def run(pipeline):
-            e = Engine(self.MCFG, self._ecfg(pipeline), seed=0)
-            e.add_request(EngineRequest(
-                request_id="a", token_ids=list(range(1, 9)),
-                sampling=SamplingParams(max_tokens=16, temperature=0.0,
-                                        ignore_eos=True)))
-            out = self._drive(e, feed={3: dataclasses.replace(req_b)})
-            return out, e.overlap_metrics()
-
-        on, om_on = run(True)
-        off, om_off = run(False)
-        assert on == off
-        assert len(on["b"][0]) == 16
-        assert om_on["spec_rollbacks"] >= 1, om_on
-        assert om_on["spec_hits"] >= 1, om_on
-
-    def test_rollback_preempt_during_speculative_burst(self):
-        """An online admission that must preempt the decoding offline
-        sequence (page pressure) while its speculative burst is in
-        flight: rollback + recompute-on-readmit, streams identical to
-        the pipeline-off run."""
-        req_on = EngineRequest(
-            request_id="on", token_ids=list(range(3, 11)),
-            sampling=SamplingParams(max_tokens=4, temperature=0.0,
-                                    ignore_eos=True))
-
-        def run(pipeline):
-            # 1 usable page: admitting "on" forces the offline preempt.
-            e = Engine(self.MCFG,
-                       self._ecfg(pipeline, num_pages=2,
-                                  max_prefill_tokens=32), seed=0)
-            e.add_request(EngineRequest(
-                request_id="off", token_ids=list(range(1, 9)),
-                sampling=SamplingParams(max_tokens=12, temperature=0.0,
-                                        ignore_eos=True),
-                offline=True))
-            out = self._drive(e, feed={3: dataclasses.replace(req_on)})
-            return out, e.num_preemptions, e.overlap_metrics()
-
-        on, pre_on, om_on = run(True)
-        off, pre_off, om_off = run(False)
-        assert on == off
-        assert pre_on == pre_off == 1
-        assert len(on["off"][0]) == 12       # finished after readmission
-        assert om_on["spec_rollbacks"] >= 1, om_on
-
-    def test_no_speculation_across_max_tokens_boundary(self):
-        """max_tokens expiry exactly on a burst boundary is PREDICTABLE:
-        the engine skips speculating that burst instead of dispatching a
-        guaranteed rollback, and streams still match pipeline-off."""
-
-        def run(pipeline):
-            e = Engine(self.MCFG, self._ecfg(pipeline), seed=0)
-            # gen 1 (prefill) + 4 + 4 = 9: expires at burst 2's end.
-            e.add_request(EngineRequest(
-                request_id="a", token_ids=list(range(1, 9)),
-                sampling=SamplingParams(max_tokens=9, temperature=0.0,
-                                        ignore_eos=True)))
-            e.add_request(EngineRequest(
-                request_id="b", token_ids=list(range(3, 11)),
-                sampling=SamplingParams(max_tokens=21, temperature=0.0,
-                                        ignore_eos=True)))
-            return self._drive(e), e.overlap_metrics(), e
-
-        on, om_on, e_on = run(True)
-        off, _, _ = run(False)
-        assert on == off
-        assert on["a"][2] == FinishReason.LENGTH
-        assert len(on["a"][0]) == 9
-        assert len(on["b"][0]) == 21
-        # The boundary expiry was skipped, not rolled back — and later
-        # b-only bursts still speculate.
-        assert om_on["spec_rollbacks"] == 0, om_on
-        assert om_on["spec_hits"] >= 1, om_on
-        # "Overlap demonstrably engaged" (acceptance gate): the burst
-        # readback split into device_wait/host_copy, and host_copy ran
-        # while a speculative next-burst dispatch was live (every
-        # spec_dispatch is issued before its burst's readback blocks).
-        pc = e_on.phase_counts
-        assert pc["decode_multi.spec_dispatch"] >= 1
-        assert pc["decode_multi.device_wait"] >= 1
-        assert pc["decode_multi.host_copy"] >= 1
-        assert "decode_multi.readback" not in pc  # renamed, not doubled
-        # Covered boundaries book 0 idle; the ledger counts them all.
-        assert pc["decode_multi.device_idle"] >= pc["decode_multi.spec_hit"]
-        assert om_on["spec_dispatches"] == om_on["spec_hits"]
-        assert om_on["hit_ratio"] > 0
-
-    def test_top_logprobs_identical_with_pipeline(self):
-        """Top-k alternatives ride the speculative burst's gated
-        transfer: identical top_logprobs on vs off (and the transfer is
-        skipped entirely when nobody asked — same outputs either way)."""
-
-        def run(pipeline, want):
-            e = Engine(self.MCFG,
-                       self._ecfg(pipeline, num_top_logprobs=2), seed=0)
-            e.add_request(EngineRequest(
-                request_id="r", token_ids=list(range(1, 9)),
-                sampling=SamplingParams(max_tokens=8, temperature=0.0,
-                                        ignore_eos=True, logprobs=want,
-                                        top_logprobs=2)))
-            tops = []
-            while e.has_work():
-                for out in e.step():
-                    if out.top_logprobs:
-                        tops.extend(out.top_logprobs)
-            return tops
-
-        on = run(True, True)
-        assert on == run(False, True)
-        assert len(on) == 8
-        assert run(True, False) == []     # transfer gated off: no tops
 
 
 # ---------------------------------------------------------------------------
@@ -1330,14 +875,12 @@ def test_a_fault_reset_drops_the_carry():
     _uploads_per_step(eng, 3)
     assert eng._decode_carry is not None
     eng.fault_reset(())
-    assert eng._decode_carry is None and eng._resident is None
+    assert eng._decode_carry is None
     toks, done = _collect(eng)
     assert done["a"] == FinishReason.LENGTH
 
 
-@pytest.mark.parametrize("decode_steps", [1, 4])
-def test_warmup_compiles_side_by_side_and_its_calls_compile_nothing(
-        decode_steps):
+def test_warmup_compiles_side_by_side_and_its_calls_compile_nothing():
     """Warm-up lowers every program, compiles them in threads, and only
     then calls them: each call finds the executable its own lowering
     holds, so the step programs are compiled ONCE each, all of them
@@ -1345,7 +888,7 @@ def test_warmup_compiles_side_by_side_and_its_calls_compile_nothing(
     after another on a v5e, where its pools' pin bans the persistent
     cache; PERF.md, PR 36)."""
     from jax import monitoring
-    eng = _steady_engine(page_size=4, decode_steps=decode_steps)
+    eng = _steady_engine(page_size=4)
     compiled = []
     monitoring.register_event_duration_secs_listener(
         lambda event, _dur, **kw: compiled.append(event) if event ==
@@ -1374,10 +917,7 @@ def test_warmup_compiles_side_by_side_and_its_calls_compile_nothing(
     # programs around them (a key split, a slice of its result).
     report = eng.compile_report()
     assert report["prefill"] == 2
-    if decode_steps == 1:
-        assert report["decode"] == 2
-    else:       # a burst's two call signatures a width share one executable
-        assert report["decode_multi"] == 4
+    assert report["decode"] == 2
     assert calling <= 2, (walks, compiled)
 
 
@@ -1462,7 +1002,7 @@ def test_scoped_warmup_covers_bench_schedule():
     cfg = ModelConfig.tiny(vocab_size=256)
     ecfg = EngineConfig(page_size=16, num_pages=256, max_model_len=256,
                         max_batch_size=16, max_prefill_tokens=128,
-                        prefill_buckets=(32,), decode_steps=8)
+                        prefill_buckets=(32,))
     engine = Engine(cfg, ecfg, seed=0)
     batch, prompt_len, gen_len = 16, 32, 64
     # 128 prefill tokens admit 4 prompts at once; under the token budget
@@ -1501,8 +1041,7 @@ def test_scoped_warmup_covers_ragged_bucket_ladder():
     cfg = ModelConfig.tiny(vocab_size=256)
     ecfg = EngineConfig(page_size=16, num_pages=128, max_model_len=128,
                         max_batch_size=8, max_prefill_tokens=64,
-                        prefill_buckets=(32,), decode_steps=8,
-                        ragged_attn=True)
+                        prefill_buckets=(32,), ragged_attn=True)
     engine = Engine(cfg, ecfg, seed=0)
     batch, prompt_len, gen_len = 8, 32, 24
     # 64 prefill tokens admit 2 prompts at once (then 1 under decode

@@ -3,9 +3,9 @@
 The deterministic tentpole e2e: time is measured in ENGINE STEPS, not
 wall clock, so the pins hold on any CPU. Unloaded, a decode stream
 receives tokens every iteration (gap 1); the interleaver keeps that
-true under a burst of long prompts (TPOT bounded by construction),
-while the prefill-first control shows the decode stall. Token streams
-are byte-identical interleave on vs off at temperature=0.
+true under a burst of long prompts (TPOT bounded by construction), and
+each prompt of the burst reaches its first token within the staggered
+bound.
 """
 
 import dataclasses
@@ -22,7 +22,7 @@ MCFG = ModelConfig.tiny(vocab_size=64)
 def _ecfg(**kw):
     d = dict(page_size=4, num_pages=128, max_model_len=128,
              max_batch_size=4, max_prefill_tokens=32,
-             prefill_buckets=(8, 16, 32), decode_steps=1)
+             prefill_buckets=(8, 16, 32))
     d.update(kw)
     return EngineConfig(**d)
 
@@ -37,8 +37,7 @@ def _req(rid, toks, max_tokens, **kw):
 def _drive(eng, feed=None, max_steps=300):
     """Drive to idle; returns (tokens-per-rid, steps-delivering-per-rid).
     ``feed`` = {step_number: [EngineRequest, ...]} applied before that
-    step runs, so both interleave settings see the same arrival points
-    in step time."""
+    step runs: arrival points in step time."""
     toks, deliver = {}, {}
     fed = set()
     step = 0
@@ -66,50 +65,37 @@ class TestInterleaver:
     BURST = [_req("b0", range(2, 102), 4), _req("b1", range(5, 105), 4)]
     BURST_STEP = 4
 
-    def _run(self, interleave):
-        eng = Engine(MCFG, _ecfg(interleave=interleave), seed=0)
+    @pytest.fixture(scope="class")
+    def run(self):
+        """(tokens, delivering steps) per request: two streams, and a
+        burst of two 100-token prompts before step 4."""
+        eng = Engine(MCFG, _ecfg(), seed=0)
         for r in self.STREAMS:
             eng.add_request(dataclasses.replace(r))
-        toks, deliver = _drive(eng, feed={self.BURST_STEP: self.BURST})
-        return eng, toks, deliver
+        return _drive(eng, feed={self.BURST_STEP: self.BURST})
 
-    @pytest.fixture(scope="class")
-    def runs(self):
-        return {il: self._run(il) for il in (True, False)}
-
-    def test_streams_byte_identical_on_vs_off(self, runs):
-        _, on, _ = runs[True]
-        _, off, _ = runs[False]
-        assert on == off
-        assert set(on) == {"s0", "s1", "b0", "b1"}
-        assert len(on["s0"]) == 30 and len(on["b0"]) == 4
-
-    def test_decode_gap_bounded_under_burst(self, runs):
-        """With interleave on, running streams receive a token EVERY
-        iteration even while 200 prompt tokens prefill — gap p99 == 1,
-        within 2x the unloaded gap of 1. The prefill-first control
-        stalls decode for the whole burst prefill."""
-        _, _, d_on = runs[True]
-        _, _, d_off = runs[False]
+    def test_decode_gap_bounded_under_burst(self, run):
+        """Running streams receive a token EVERY iteration even while
+        200 prompt tokens prefill — every gap is 1, the unloaded gap —
+        and every request gets all of its tokens."""
+        toks, deliver = run
+        assert {r: len(t) for r, t in toks.items()} == \
+            {"s0": 30, "s1": 30, "b0": 4, "b1": 4}
         for rid in ("s0", "s1"):
-            gaps_on = _gaps(d_on[rid])
-            assert gaps_on and max(gaps_on) == 1, (rid, d_on[rid])
-        # Control: the same burst defers decode for several consecutive
-        # prefill-first iterations (the stall the interleaver removes).
-        stall = max(max(_gaps(d_off[r])) for r in ("s0", "s1"))
-        assert stall >= 3, d_off
+            gaps = _gaps(deliver[rid])
+            assert gaps and max(gaps) == 1, (rid, deliver[rid])
 
-    def test_burst_ttft_meets_staggered_bound(self, runs):
+    def test_burst_ttft_meets_staggered_bound(self, run):
         """Each burst prompt's first token lands within the analytic
         bound: the front waiting prompt is guaranteed a quantum of the
         largest bucket <= residual budget (32 - 2 decode = 30 -> 16)
         every iteration, so 200 burst tokens drain within ceil(200/16)
         steps, plus one step of arrival slack and one of admission
         order."""
-        _, _, d_on = runs[True]
+        _, deliver = run
         bound = self.BURST_STEP + -(-200 // 16) + 2
         for rid in ("b0", "b1"):
-            assert d_on[rid][0] <= bound, (rid, d_on[rid], bound)
+            assert deliver[rid][0] <= bound, (rid, deliver[rid], bound)
 
     def test_mixed_step_ledger_and_backlog(self):
         """The interleaved iteration reports the split the worker's obs
@@ -136,28 +122,22 @@ class TestInterleaver:
         assert max(eng.last_step_prefill_windows) <= 16
         assert eng.last_step_tokens == (eng.last_step_prefill_tokens
                                         + eng.last_step_decode_tokens)
-        assert not eng.last_step_decode_deferred
         assert eng.waiting_prefill_tokens() == 200 - \
             eng.last_step_prefill_tokens
         assert outs
 
 
-def test_env_and_default_resolution(monkeypatch):
+def test_the_budget_and_the_deadline_resolve_from_env_and_defaults(
+        monkeypatch):
     # Env overrides land on EngineConfig in __post_init__ (cheap to
     # pin); one Engine covers the engine-side default resolution.
-    monkeypatch.setenv("XLLM_INTERLEAVE", "0")
-    assert _ecfg().interleave is False
-    monkeypatch.setenv("XLLM_INTERLEAVE", "1")
-    assert _ecfg(interleave=False).interleave is True
     monkeypatch.setenv("XLLM_STEP_TOKEN_BUDGET", "16")
     monkeypatch.setenv("XLLM_PREFILL_DEADLINE_MS", "125")
     assert _ecfg().step_token_budget == 16
     assert _ecfg().prefill_deadline_ms == 125.0
-    monkeypatch.delenv("XLLM_INTERLEAVE")
     monkeypatch.delenv("XLLM_STEP_TOKEN_BUDGET")
     monkeypatch.delenv("XLLM_PREFILL_DEADLINE_MS")
     eng = Engine(MCFG, _ecfg(), seed=0)
-    assert eng.interleave is True            # None = auto ON
     assert eng.step_token_budget == 32       # 0 = max_prefill_tokens
     assert eng.prefill_deadline_ms == 500.0
 
@@ -215,109 +195,63 @@ def test_starvation_deadline_grants_quantum():
     assert any(o.request_id == "p" and o.new_token_ids for o in outs)
 
 
-class TestInterleavePipelineMatrix:
-    """Satellite to the PR-5 rollback matrix: pipeline on/off and
-    interleave on/off produce byte-identical streams when a prefill
-    lands mid-speculation. With interleave ON the arrival is planned
-    ahead — the in-flight speculative burst is consumed as a HIT and
-    the pipeline drains only when the prefill actually lands — where
-    the legacy prefill-first path rolls the burst back on admission."""
-
-    @staticmethod
-    def _ecfg(pipeline, interleave):
-        return EngineConfig(
-            page_size=32, num_pages=16, max_model_len=64,
-            max_batch_size=2, max_prefill_tokens=64,
-            prefill_buckets=(8, 16, 32), decode_steps=4,
-            decode_pipeline=pipeline, interleave=interleave)
-
-    def _run(self, pipeline, interleave):
-        eng = Engine(MCFG, self._ecfg(pipeline, interleave), seed=0)
-        eng.add_request(_req("a", range(1, 9), 16))
-        toks, _ = _drive(eng, feed={3: [_req("b", range(3, 11), 16)]})
-        return toks, eng.overlap_metrics()
-
-    def test_matrix_byte_identical_and_plan_ahead(self):
-        results = {(p, il): self._run(p, il)
-                   for p in (True, False) for il in (True, False)}
-        streams = [r[0] for r in results.values()]
-        assert all(s == streams[0] for s in streams[1:]), results
-        assert len(streams[0]["a"]) == 16 and len(streams[0]["b"]) == 16
-        om_on = results[(True, True)][1]
-        om_legacy = results[(True, False)][1]
-        # Legacy: the admission drains the in-flight speculation.
-        assert om_legacy["spec_rollbacks"] >= 1, om_legacy
-        # Interleaver: the same arrival is planned ahead — consumed as
-        # a hit, zero wasted bursts, speculation still engaged.
-        assert om_on["spec_dispatches"] >= 1, om_on
-        assert om_on["spec_hits"] >= 1, om_on
-        assert om_on["spec_rollbacks"] == 0, om_on
-        # Pipeline-off runs never speculate, any interleave setting.
-        assert results[(False, True)][1]["spec_dispatches"] == 0
-        assert results[(False, False)][1]["spec_dispatches"] == 0
-
-
 class TestRaggedMixedStep:
     """One-dispatch ragged mixed iterations (XLLM_RAGGED_ATTN /
     EngineConfig.ragged_attn): a mixed iteration packs decode rows and
     prefill windows into ONE ragged batch served by ONE attention
-    program. Streams must be byte-identical to the legacy split path
-    across the interleave × decode-pipeline rollback matrix, and the
-    dispatch ledger must prove the single launch."""
+    program. Streams must be byte-identical to the split sections', and
+    the dispatch ledger must prove the single launch."""
+
+    # 40 tokens, two windows (32 + 8). The first goes out behind the
+    # decode step that was on the device when the prompt arrived (the
+    # split sections: tests/test_decode_ahead.py,
+    # test_a_mixed_program_does_not_throw_the_step_in_flight_away);
+    # while a prompt waits nothing is launched ahead, so the second
+    # finds no step there and is the ragged program's.
+    LATE = range(3, 43)
 
     @staticmethod
-    def _ecfg(pipeline=False, interleave=True, ragged=None):
+    def _ecfg(ragged=None):
         return EngineConfig(
             page_size=32, num_pages=16, max_model_len=64,
             max_batch_size=2, max_prefill_tokens=64,
-            prefill_buckets=(8, 16, 32), decode_steps=4,
-            decode_pipeline=pipeline, interleave=interleave,
-            ragged_attn=ragged)
+            prefill_buckets=(8, 16, 32), ragged_attn=ragged)
 
-    def _run(self, pipeline, interleave, ragged):
-        eng = Engine(MCFG, self._ecfg(pipeline, interleave, ragged),
-                     seed=0)
+    def _run(self, ragged):
+        eng = Engine(MCFG, self._ecfg(ragged), seed=0)
         eng.add_request(_req("a", range(1, 9), 16))
-        toks, _ = _drive(eng, feed={3: [_req("b", range(3, 11), 16)]})
+        toks, _ = _drive(eng, feed={3: [_req("b", self.LATE, 16)]})
         return toks, eng
 
-    def test_matrix_byte_identical_ragged_on_vs_off(self):
-        """Ragged on/off across pipeline on/off: the step STRUCTURE
-        differs (one ragged launch vs a fused burst plus a prefill
-        call; a mixed ragged iteration decodes one token, not a burst),
-        but at temperature=0 the streams are prefix-determined, so
-        every cell must emit identical bytes. Interleave stays on —
-        with it off, prefill and decode never share an iteration, so
-        the ragged path can't fire and the cells degenerate to the
-        plain matrix test above."""
-        results = {(p, rg): self._run(p, True, rg)[0]
-                   for p in (True, False) for rg in (True, False)}
-        streams = list(results.values())
-        assert all(s == streams[0] for s in streams[1:]), results
-        assert len(streams[0]["a"]) == 16 and len(streams[0]["b"]) == 16
+    def test_streams_byte_identical_ragged_on_vs_off(self):
+        """Ragged on against off: the step STRUCTURE differs (one
+        ragged launch against a decode step plus a prefill call), but
+        at temperature=0 the streams are prefix-determined, so both
+        must emit identical bytes."""
+        (on, eng), (off, _) = self._run(True), self._run(False)
+        assert eng.phase_counts["ragged.dispatch"] == 1
+        assert on == off
+        assert len(on["a"]) == 16 and len(on["b"]) == 16
 
     def test_mixed_step_is_one_dispatch(self):
         """The acceptance pin: a ragged mixed iteration executes exactly
-        ONE attention dispatch, where the legacy split path needs the
-        decode burst plus one per prefill call (pipeline off isolates
-        the count to the iteration that used it)."""
+        ONE attention dispatch, where the split sections need the
+        decode step plus one per prefill call."""
         seen = {}
         for ragged in (True, False):
             eng = Engine(MCFG, self._ecfg(ragged=ragged), seed=0)
             eng.add_request(_req("a", range(1, 9), 16))
             for step in range(40):
                 if step == 2:
-                    eng.add_request(_req("b", range(3, 11), 16))
+                    eng.add_request(_req("b", self.LATE, 16))
                 eng.step()
                 if eng.last_step_kind == "mixed":
-                    seen[ragged] = (eng.last_step_ragged,
-                                    eng.last_step_attn_dispatches)
-                    break
-            else:
-                raise AssertionError("no mixed iteration observed")
-        assert seen[True] == (True, 1), seen
-        is_ragged, dispatches = seen[False]
-        assert not is_ragged and dispatches >= 2, seen
+                    seen.setdefault(ragged, []).append(
+                        (eng.last_step_ragged,
+                         eng.last_step_attn_dispatches))
+        # b's first window behind the step in flight, its second ragged
+        assert seen[True] == [(False, 2), (True, 1)], seen
+        assert seen[False] == [(False, 2), (False, 2)], seen
 
     def test_ragged_step_ledger_and_reports(self):
         """The ragged iteration keeps the worker-visible ledger: kind
@@ -331,7 +265,7 @@ class TestRaggedMixedStep:
         hit = False
         for step in range(40):
             if step == 2:
-                eng.add_request(_req("b", range(3, 11), 16))
+                eng.add_request(_req("b", self.LATE, 16))
             eng.step()
             if eng.last_step_ragged:
                 hit = True
@@ -352,7 +286,7 @@ class TestRaggedMixedStep:
     def test_penalized_decode_falls_back_to_split_path(self):
         """Presence/frequency penalties need the output-token histogram
         the ragged program doesn't carry — those iterations must take
-        the legacy sections (and still produce correct streams)."""
+        the split sections (and still produce correct streams)."""
         def drive(ragged):
             eng = Engine(MCFG, self._ecfg(ragged=ragged), seed=0)
             eng.add_request(EngineRequest(
@@ -363,7 +297,7 @@ class TestRaggedMixedStep:
             toks, ragged_steps = {}, 0
             for step in range(60):
                 if step == 2:
-                    eng.add_request(_req("b", range(3, 11), 8))
+                    eng.add_request(_req("b", self.LATE, 8))
                 for o in eng.step():
                     toks.setdefault(o.request_id, []).extend(
                         o.new_token_ids)

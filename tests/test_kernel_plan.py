@@ -213,13 +213,13 @@ def _decode_args(eng, mp):
 
 def test_engine_once_built_does_not_read_the_gates(monkeypatch, no_gates):
     """Every program an engine traces after construction — a new prefill
-    bucket, a new table width, the mixed program, the fused burst — is
+    bucket, a new table width, the mixed program — is
     traced under the plan it resolved, with no look at the environment:
     reading any kernel gate raises here."""
     monkeypatch.setenv("XLLM_PALLAS", "1")       # kernels on, interpreted
     monkeypatch.setenv("XLLM_PALLAS_PREFILL", "1")
     eng = E.Engine(ModelConfig.tiny(),
-                   _ecfg(ragged_attn=True, decode_steps=2))
+                   _ecfg(ragged_attn=True))
     assert eng.plan.decode_attn and eng.plan.prefill_attn \
         and eng.plan.mixed_step and eng.plan.interpret
     real = os.environ.get
@@ -235,16 +235,10 @@ def test_engine_once_built_does_not_read_the_gates(monkeypatch, no_gates):
     with pytest.raises(AssertionError):          # the guard itself works
         pallas.default_interpret()
     asked.clear()
-    B = eng.ecfg.max_batch_size
-    z = jnp.zeros((B,), jnp.int32)
     traced = [
         eng._jit_prefill.trace(*_prefill_args(eng, 1, 32, 8)),
         eng._jit_ragged.trace(*_prefill_args(eng, 2, 16, 4)),
         eng._jit_decode.trace(*_decode_args(eng, 4)),
-        eng._jit_decode_multi.trace(
-            eng.params, z, z, jnp.zeros((B, 2 + 4), jnp.int32), eng.kv,
-            *eng._sampling_tensors([], B), jax.random.PRNGKey(0), None,
-            *eng._batch_bias([], B, eng.cfg.vocab_size)),
     ]
     assert asked == []
     assert all("pallas_call" in str(t.jaxpr) for t in traced)

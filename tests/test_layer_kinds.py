@@ -146,12 +146,19 @@ OTHER = [int(t) for t in np.random.default_rng(8).integers(1, 256, 19)]
 N_OUT = 10
 
 
-def engine(**kw) -> Engine:
+def engine(launch="ahead", **kw) -> Engine:
+    """``launch``: ``ahead`` is the engine as served; ``sequential``
+    puts no decode step on the device ahead of its iteration, so a
+    fault that shows under both is the seam's own and one that shows
+    under ``ahead`` alone is the launch's."""
     defaults = dict(page_size=PS, num_pages=48, max_model_len=64,
                     max_batch_size=4, max_prefill_tokens=64,
                     prefill_buckets=(8, 16, 32, 64))
     defaults.update(kw)
-    return Engine(tiny_cfg(), EngineConfig(**defaults), seed=0)
+    eng = Engine(tiny_cfg(), EngineConfig(**defaults), seed=0)
+    if launch == "sequential":
+        eng._ahead_eligible = eng._tail_eligible = lambda *a: False
+    return eng
 
 
 def add(eng, rid, prompt, n=N_OUT):
@@ -197,8 +204,8 @@ def same_as_cold(cold, toks, lps):
     np.testing.assert_allclose(lps, cold[1], atol=2e-5)
 
 
-def hit_at_a_page_boundary(decode_steps):
-    eng = engine(decode_steps=decode_steps)
+def hit_at_a_page_boundary(launch):
+    eng = engine(launch)
     add(eng, "first", PROMPT)
     drain(eng)
     add(eng, "again", PROMPT)
@@ -208,9 +215,8 @@ def hit_at_a_page_boundary(decode_steps):
     return toks["again"], lps["again"]
 
 
-def two_prefill_windows(decode_steps):
-    eng = engine(prefill_buckets=(8, 16), max_prefill_tokens=16,
-                 decode_steps=decode_steps)
+def two_prefill_windows(launch):
+    eng = engine(launch, prefill_buckets=(8, 16), max_prefill_tokens=16)
     seq = add(eng, "chunked", PROMPT)
     got = ({}, {})
     step(eng, got)
@@ -219,8 +225,8 @@ def two_prefill_windows(decode_steps):
     return toks["chunked"], lps["chunked"]
 
 
-def preempted_and_resumed(decode_steps):
-    eng = engine(decode_steps=decode_steps)
+def preempted_and_resumed(launch):
+    eng = engine(launch)
     seq = add(eng, "victim", PROMPT)
     got = ({}, {})
     while seq.num_generated < 5:
@@ -235,12 +241,12 @@ def preempted_and_resumed(decode_steps):
     return toks["victim"], lps["victim"]
 
 
-def a_slot_and_pages_just_vacated(decode_steps):
+def a_slot_and_pages_just_vacated(launch):
     # No prefix cache: a finished sequence's pages go straight back to
     # the allocator, and the next sequence is given them (and the slot)
     # with the first one's rows of tails still in them.
-    eng = engine(enable_prefix_cache=False, num_pages=12,
-                 max_batch_size=1, decode_steps=decode_steps)
+    eng = engine(launch, enable_prefix_cache=False, num_pages=12,
+                 max_batch_size=1)
     first = add(eng, "first", OTHER)
     eng.step()
     used = set(first.pages)
@@ -253,13 +259,13 @@ def a_slot_and_pages_just_vacated(decode_steps):
     return toks["next"], lps["next"]
 
 
-@pytest.mark.parametrize("decode_steps", [1, 4])
+@pytest.mark.parametrize("launch", ["ahead", "sequential"])
 @pytest.mark.parametrize("seam", [
     hit_at_a_page_boundary, two_prefill_windows, preempted_and_resumed,
     a_slot_and_pages_just_vacated])
 def test_every_seam_of_the_state_gives_the_cold_runs_tokens(
-        cold, seam, decode_steps):
-    same_as_cold(cold, *seam(decode_steps))
+        cold, seam, launch):
+    same_as_cold(cold, *seam(launch))
 
 
 def test_rows_of_one_batch_keep_their_own_tails(cold):

@@ -7,7 +7,6 @@ constant 0) and the step record's ``moe``."""
 import json
 import os
 
-import pytest
 
 from xllm_service_tpu.config import EngineConfig, ModelConfig
 from xllm_service_tpu.runtime import engine as E
@@ -26,12 +25,11 @@ def tiny_model():
     return ModelConfig.from_hf_config(cfg, "joyai-tiny"), cfg
 
 
-@pytest.mark.parametrize("decode_steps", [1, 4])
-def test_the_engine_books_what_the_sparse_layers_counted(decode_steps):
+def test_the_engine_books_what_the_sparse_layers_counted():
     mc, cfg = tiny_model()
     eng = E.Engine(mc, EngineConfig(
         page_size=16, num_pages=32, max_model_len=128, max_batch_size=2,
-        prefill_buckets=(32,), decode_steps=decode_steps))
+        prefill_buckets=(32,)))
     P, N = 21, 9
     eng.add_request(E.EngineRequest(
         request_id="r0", token_ids=list(range(3, 3 + P)),
@@ -46,11 +44,10 @@ def test_the_engine_books_what_the_sparse_layers_counted(decode_steps):
     k = cfg["num_experts_per_tok"]
     sparse = cfg["num_hidden_layers"] - cfg["first_k_dense_replace"]
     st = eng.moe_stats
-    # the prompt's rows once, then one row a decode iteration; a fused
-    # burst may run iterations past the request's last token
+    # the prompt's rows once, then one row a decode iteration
     decoded = st["assignments"] // (k * sparse) - P
     assert st["assignments"] == (P + decoded) * k * sparse
-    assert N - 1 <= decoded <= N - 1 + decode_steps
+    assert N - 1 <= decoded <= N
     assert st["dropped"] == 0 and eng.moe_dropped_tokens == 0
     assert eng.load_metrics()["moe_dropped_tokens"] == 0
     assert st["layers"] == (1 + decoded) * sparse

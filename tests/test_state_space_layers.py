@@ -381,11 +381,6 @@ def test_the_pipeline_on_against_off_and_a_forced_discard_give_the_same_streams(
     assert discards > 3
 
 
-def test_a_burst_of_steps_is_refused_at_start_up(params):
-    with pytest.raises(ValueError, match="decode_steps=4.*N \\+ 1"):
-        engine(params, decode_steps=4)
-
-
 # (e) ----------------------------------------------------------------------
 
 def test_no_state_row_means_no_admission_and_a_finish_frees_one(params, cold):

@@ -375,7 +375,7 @@ class TestStepObservatoryE2E:
             for r in st["steps"]:
                 for c in r["compiled"]:
                     prog, _, shape = c.partition(":")
-                    assert prog in ("prefill", "decode", "decode_multi")
+                    assert prog in ("prefill", "decode")
                     assert shape.startswith(("B", "mp")), c
             carried = [r for r in st["steps"]
                        if NAMED_RID in (r.get("members") or ())]

@@ -203,7 +203,7 @@ def _run_engine(monkeypatch, env: dict, cfg=None, prompts=None,
     cfg = cfg or ModelConfig.tiny(vocab_size=256)
     kw = dict(page_size=16, num_pages=64, max_model_len=256,
               max_batch_size=4, max_prefill_tokens=128,
-              prefill_buckets=(16, 32, 64), decode_steps=4)
+              prefill_buckets=(16, 32, 64))
     kw.update(ecfg_kw or {})
     ecfg = EngineConfig(**kw)
     prompts = prompts or [list(range(1, 33)), [7, 9, 11] * 8]
@@ -225,7 +225,7 @@ def _run_engine(monkeypatch, env: dict, cfg=None, prompts=None,
 
 class TestEngineWriteThenAttend:
     """Greedy generations must be token-identical with the flag on vs
-    off — through fused decode bursts, chunked prefill windows, and a
+    off — through decode steps, chunked prefill windows, and a
     prefix-cache readmission — on both the Pallas (interpreter) and
     pure-XLA serving paths. The acceptance gate of the re-plumb."""
 

@@ -987,13 +987,6 @@ class EngineConfig:
     max_prefill_tokens: int = 2048      # prefill token budget per step
     prefill_buckets: Tuple[int, ...] = (64, 128, 256, 512, 1024, 2048)
     enable_prefix_cache: bool = True
-    # Decode steps fused into ONE compiled program per host round-trip
-    # (lax.scan over the step body). >1 amortizes host↔device dispatch
-    # latency across N tokens — the dominant cost under Python dispatch
-    # overhead. Finish
-    # detection runs on host afterwards; tokens sampled past a stop are
-    # discarded (bounded waste of N-1 steps worst case).
-    decode_steps: int = 1
     # Top-k alternative logprobs computed inside every compiled step
     # (static k — 0 disables the top_k entirely; OpenAI callers may ask
     # for at most this many ``top_logprobs``).
@@ -1015,41 +1008,23 @@ class EngineConfig:
     # built (ops/plan.py KernelPlan.from_env), where
     # XLLM_WRITE_THEN_ATTEND=0/1 overrides this field.
     write_then_attend: Optional[bool] = None
-    # Pipelined decode: after dispatching fused burst k, start its
-    # device→host copy asynchronously and — while the batch snapshot
-    # still matches — speculatively dispatch burst k+1 from the
-    # device-resident carries BEFORE blocking on burst k's readback, so
-    # the host post (stop detection, page bookkeeping, prefix-cache
-    # registration) overlaps the next burst's device compute. A wrong
-    # speculation (finish/preempt/admit) is discarded and re-dispatched
-    # from host truth; token streams are byte-identical either way
-    # (pinned in tests/test_engine.py). None = auto: on when
-    # decode_steps > 1, off for single-step decode. Env
-    # XLLM_DECODE_PIPELINE=0/1 overrides.
-    decode_pipeline: Optional[bool] = None
-    # One-dispatch ragged mixed steps: when on, an interleaved iteration
-    # with both running decoders and schedulable prefill windows packs
-    # BOTH into one ragged batch (decode rows are length-1 continuation
-    # windows) and launches ONE attention program
-    # (ops/pallas/ragged_attention.py) instead of a decode burst plus a
+    # One-dispatch ragged mixed steps: when on, an iteration with both
+    # running decoders and schedulable prefill windows packs BOTH into
+    # one ragged batch (decode rows are length-1 continuation windows)
+    # and launches ONE attention program
+    # (ops/pallas/ragged_attention.py) instead of a decode step plus a
     # prefill call. Pure-decode and pure-prefill iterations keep their
-    # dedicated programs (the fused burst + speculation pipeline stays).
+    # dedicated programs.
     # None = auto: off (opt-in while the kernel soaks). Resolved once,
     # when an engine is built (ops/plan.py KernelPlan.from_env), where
     # XLLM_RAGGED_ATTN=0/1 overrides this field.
     ragged_attn: Optional[bool] = None
-    # Token-budget prefill/decode interleaving (staggered admission,
-    # arxiv 2512.16134): every engine iteration decodes the running set
-    # FIRST (bounding TPOT by construction), then spends the residual of
-    # the per-iteration token budget on chunked-prefill windows — the
-    # prefill quantum shrinks under decode load instead of the engine
-    # running prompt-priority steps that stall every live stream.
-    # None = auto (on). Off restores the pre-interleaver prefill-first
-    # routing (the control that shows the decode stall). Env
-    # XLLM_INTERLEAVE=0/1 overrides.
-    interleave: Optional[bool] = None
-    # Per-iteration token budget the interleaver splits between the
-    # decode burst and prefill windows. 0 = default from
+    # The iteration's token budget (staggered admission, arxiv
+    # 2512.16134): every engine iteration decodes the running set FIRST
+    # (bounding TPOT by construction), then spends the residual of this
+    # budget on chunked-prefill windows — the prefill quantum shrinks
+    # under decode load instead of the engine running prompt-priority
+    # steps that stall every live stream. 0 = default from
     # max_prefill_tokens. Env XLLM_STEP_TOKEN_BUDGET overrides.
     step_token_budget: int = 0
     # Anti-starvation deadline (ms): once the oldest waiting prompt has
@@ -1082,16 +1057,6 @@ class EngineConfig:
                 f"max_model_len={self.max_model_len} must be a multiple of "
                 f"page_size={self.page_size}")
         self.max_pages_per_seq = self.max_model_len // self.page_size
-        env = os.environ.get("XLLM_DECODE_PIPELINE", "").strip()
-        if env in ("0", "false", "no"):
-            self.decode_pipeline = False
-        elif env in ("1", "true", "yes"):
-            self.decode_pipeline = True
-        env = os.environ.get("XLLM_INTERLEAVE", "").strip()
-        if env in ("0", "false", "no"):
-            self.interleave = False
-        elif env in ("1", "true", "yes"):
-            self.interleave = True
         env = os.environ.get("XLLM_STEP_TOKEN_BUDGET", "").strip()
         if env:
             try:

@@ -1273,13 +1273,6 @@ def moe_stats_shape(cfg: ModelConfig) -> Tuple[int, ...]:
     return (len(MOE_STATS),) if cfg.dropless_experts else ()
 
 
-def step_stats_zeros(cfg: ModelConfig) -> jnp.ndarray:
-    """What a burst of steps starts its sum of ``step_moe_stats`` from:
-    int32 counts, but a looped model's float32 vector."""
-    return jnp.zeros(moe_stats_shape(cfg),
-                     jnp.float32 if cfg.looped else jnp.int32)
-
-
 def _moe_stats_dict(moe_stats: jnp.ndarray) -> Dict[str, jnp.ndarray]:
     """The ``return_stats`` dict of the latent forwards: ``moe_dropped``
     as every family gives it, and the whole vector under ``moe``."""

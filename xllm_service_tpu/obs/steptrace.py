@@ -58,12 +58,12 @@ STEP_FIELDS: Tuple[str, ...] = (
     "prefill_tokens",   # prompt tokens computed this step
     "decode_tokens",    # tokens sampled this step
     "prefill_windows",  # scheduled prefill window sizes (tuple of ints)
-    "decode_deferred",  # prefill-first step deferred live decodes (bool)
     "ragged",           # served by the one-dispatch ragged program (bool)
     "attn_dispatches",  # attention-bearing device dispatches this step
     "members",          # request ids in the step's batch (tuple)
     "phases",           # {phase: ms} DELTA of the engine ledger this step
-    "spec",             # {dispatches,hits,rollbacks} speculation delta
+    "spec",             # {dispatches,hits,rollbacks} delta of the decode
+                        # steps dispatched ahead of their iteration
     "kv_usage",         # KV page pool utilization [0,1] after the step
     "pages_delta",      # free-page delta across the step (+freed/-taken)
     "cache_hit_tokens", # prefix-cache hit-token delta this step
@@ -111,11 +111,9 @@ STEP_PHASES: Tuple[str, ...] = (
     "ragged.pack", "ragged.dispatch", "ragged.post",
     "decode.pack", "decode.upload", "decode.dispatch",
     "decode.ahead_dispatch", "decode.tail_dispatch", "decode.post",
-    "decode_multi.pack", "decode_multi.dispatch",
-    "decode_multi.spec_dispatch", "decode_multi.post",
 )
 READ_HOST_PHASES: Tuple[str, ...] = (
-    "prefill", "prefill_ring", "ragged", "decode", "decode_multi",
+    "prefill", "prefill_ring", "ragged", "decode",
     "kv_spill", "kv_export_blocks")
 
 SPAN_NAMES: Tuple[str, ...] = (

@@ -231,16 +231,6 @@ class Engine:
                 "refused for it (pages move with (k, v) alone)",
                 model_cfg.name,
                 " and a matrix state by slot" if self.state_model else "")
-        if self.state_model and engine_cfg.decode_steps > 1:
-            # A step launched ahead and discarded must leave every row's
-            # state as it was: the single step keeps TWO states a row,
-            # by the parity of the position; a burst of N steps would
-            # need N + 1.
-            raise ValueError(
-                f"decode_steps={engine_cfg.decode_steps}: a model with a "
-                f"matrix state by slot decodes one step a program (a "
-                f"discarded burst of N steps would need N + 1 states a "
-                f"row; the single step keeps two)")
         # The buckets a window that does NOT end its prompt may take (the
         # interleaver's quantum, ``_window_cap``): all of them, but for a
         # state model whole pages only, so that every window starts on a
@@ -431,28 +421,13 @@ class Engine:
             import xllm_service_tpu.ops.pallas  # noqa: F401
         self._sp = int(mesh.shape.get("sp", 1)) if mesh is not None else 1
         self._build_step_programs(self.kv)
-        # Device-resident decode state between bursts: the previous
-        # burst's final (tokens, positions) handles plus a host snapshot
-        # proving they still describe the running batch, and the device
-        # copy of the active+page-table block with its host mirror for
-        # change detection. (docs/PERF_NOTES.md "ranked next steps" #1.)
-        self._resident: Optional[Dict[str, Any]] = None
-        # Pipelined decode bursts (docs/PERF_NOTES.md round 7): after
-        # burst k is dispatched, burst k+1 can be dispatched from the
-        # device-resident carries before burst k's outputs are read back.
-        # None = auto: on whenever bursts are fused. The SINGLE step has
-        # no such option: it launches ahead wherever ``_ahead_eligible``
-        # holds (``_launch_ahead``), and an iteration that could not
-        # dispatches its successor at its tail (``_tail_eligible``).
-        dp = getattr(engine_cfg, "decode_pipeline", None)
-        if dp is None:
-            dp = engine_cfg.decode_steps > 1
-        self.decode_pipeline = bool(dp) and engine_cfg.decode_steps > 1
-        # The ONE step or burst on the device ahead of the iteration that
-        # will read it (launched before the host read the one before it,
-        # or dispatched from host truth at an iteration's tail): its
-        # device handles and what it assumed of the batch (taken or
-        # discarded by the next decode: ``_take_ahead``).
+        # The ONE decode step on the device ahead of the iteration that
+        # will read it: launched before the host read the step before it
+        # wherever ``_ahead_eligible`` holds (``_launch_ahead``), else
+        # dispatched from host truth at an iteration's tail
+        # (``_tail_eligible``). Its device handles and what it assumed of
+        # the batch; taken or discarded by the next decode
+        # (``_take_ahead``).
         self._pending: Optional[Dict[str, Any]] = None
         # Do the rows of one step see each other? Only through a sparse
         # layer that buckets by capacity (``transformer._mlp``'s
@@ -463,14 +438,7 @@ class Engine:
         self._rows_interfere = (model_cfg.is_moe
                                 and not model_cfg.dropless_experts
                                 and model_cfg.moe_capacity_factor > 0)
-        # Device-idle attribution: when the previous decode burst's
-        # outputs became ready, and whether a speculative burst was
-        # already covering the gap to the next dispatch.
-        self._last_burst_ready_t: Optional[float] = None
-        self._last_burst_step = -1
-        self._dev_active_pt: Optional[jnp.ndarray] = None
-        self._active_pt_mirror: Optional[np.ndarray] = None
-        # Single-step decode carry: the block the last step program
+        # The decode carry: the block the last step program
         # handed back (its ``next_packed``) and the host's copy of what
         # that block holds. The next step passes the handle instead of
         # uploading when host truth compares equal; None = upload.
@@ -484,9 +452,7 @@ class Engine:
 
         # Token-budget interleaver (staggered admission): every iteration
         # decodes the running set, then spends the residual token budget
-        # on chunked-prefill windows. Off = legacy prefill-first routing.
-        il = getattr(engine_cfg, "interleave", None)
-        self.interleave = True if il is None else bool(il)
+        # on chunked-prefill windows.
         self.step_token_budget = (
             getattr(engine_cfg, "step_token_budget", 0)
             or engine_cfg.max_prefill_tokens)
@@ -513,13 +479,10 @@ class Engine:
         self.last_step_prefill_s = 0.0
         # Scheduled prefill window sizes (the quantum histogram feed).
         self.last_step_prefill_windows: Tuple[int, ...] = ()
-        # True when a prefill-first iteration deferred live decodes (the
-        # stall the interleaver removes; worker's decode-stall counter).
-        self.last_step_decode_deferred = False
         # Ragged-step ledger: whether the LAST iteration ran the
         # one-dispatch ragged mixed program, and how many attention-
         # bearing device dispatches the iteration issued (ragged mixed
-        # step = 1; legacy mixed step = 1 decode burst + 1 per prefill
+        # step = 1; sectioned mixed step = 1 decode step + 1 per prefill
         # call). The acceptance pin for the ragged path lives on these.
         self.last_step_ragged = False
         self.last_step_attn_dispatches = 0
@@ -681,28 +644,6 @@ class Engine:
                               plan=plan),
             donate_argnums=(2, 6),
             **_pin(9, 2, 8, here_in=(1, 5), here_out=(6, 7)))
-        # tokens/positions (1, 2) are donated too: each burst feeds back
-        # the previous burst's returned final-state handles, and a donated
-        # input lets XLA alias the new final state into the same buffers.
-        multi_pin = _pin(11, 4, 8, here_in=(7,))
-        if multi_pin:
-            # The burst's device-resident token/position handles flow
-            # OUT (fin_tok/fin_pos) and back IN next burst; under
-            # partially-specified shardings their layout must be pinned
-            # on both sides too or the upload-path and resident-path
-            # calls compile separate cache entries.
-            vec = row_major_format(1, kvl[0].sharding)
-            ins = list(multi_pin["in_shardings"])
-            ins[1] = ins[2] = vec
-            outs = list(multi_pin["out_shardings"])
-            outs[6] = outs[7] = vec
-            multi_pin = {"in_shardings": tuple(ins),
-                         "out_shardings": tuple(outs)}
-        self._jit_decode_multi = jax.jit(
-            functools.partial(_decode_multi_step, cfg=model_cfg,
-                              n_steps=engine_cfg.decode_steps, num_top=K,
-                              plan=plan),
-            donate_argnums=(1, 2, 4, 8), **multi_pin)
         # PD import, spill-tier restore and cross-worker block adoption
         # write pages into the pools through this one program.
         scatter_pin = {} if kvl is None else {
@@ -779,7 +720,6 @@ class Engine:
                              ("prefill_ring", self._jit_prefill_ring),
                              ("ragged", self._jit_ragged),
                              ("decode", self._jit_decode),
-                             ("decode_multi", self._jit_decode_multi),
                              ("kv_scatter", self._jit_kv_scatter)):
             if jitted is not None:
                 report[name] = self._jit_cache_size(jitted)
@@ -788,13 +728,11 @@ class Engine:
     def _read_host(self, phase: str, *arrays):
         """Blocking device→host readback with split attribution.
 
-        The conflated ``*.readback`` phase absorbed device compute AND
-        the host copy in one number, which made TPOT attribution
-        misleading in every TPU bench before the split (round 6:
-        5,946 ms of ``decode_multi.readback`` that was mostly the device
-        running the scan). Here an async copy is started for every live
-        array first (idempotent — the pipelined decode path already
-        started them at dispatch), ``<phase>.device_wait`` absorbs the
+        One ``*.readback`` number would absorb device compute AND the
+        host copy, and a wait for the device would read as a slow copy.
+        Here an async copy is started for every live array first
+        (idempotent: a decode launch already started its outputs' at
+        dispatch), ``<phase>.device_wait`` absorbs the
         wait for the producing computation, and ``<phase>.host_copy``
         the residual materialization. Returns one host array (or None)
         per input. The xlint ``hot-loop-blocking-readback`` rule pins
@@ -827,28 +765,22 @@ class Engine:
             s.req.sampling.logprobs for s in seqs)
 
     def overlap_metrics(self) -> Dict[str, Any]:
-        """Decode-pipeline health for the obs registry / bench JSON:
-        speculation dispatch/hit/rollback counts, the hit ratio, and
-        host-side device-idle ms per burst boundary (0 for boundaries a
-        speculative burst covered)."""
+        """How the decode steps put on the device ahead of their
+        iteration fared (launched ahead or dispatched at a tail), for
+        the obs registry and the step record: how many were dispatched,
+        taken and discarded, and the share taken. (The keys say "spec"
+        from before the single step launched ahead: the worker's series
+        ``xllm_worker_decode_overlap_*`` carry them.)"""
         pc = self.phase_counts
-        disp = pc.get("decode_multi.spec_dispatch", 0) \
-            + pc.get("decode.ahead_dispatch", 0) \
+        disp = pc.get("decode.ahead_dispatch", 0) \
             + pc.get("decode.tail_dispatch", 0)
-        hits = pc.get("decode_multi.spec_hit", 0) \
-            + pc.get("decode.ahead_hit", 0) \
-            + pc.get("decode.tail_hit", 0)
-        idle_n = pc.get("decode_multi.device_idle", 0)
-        idle_s = self.phase_times.get("decode_multi.device_idle", 0.0)
+        hits = pc.get("decode.ahead_hit", 0) + pc.get("decode.tail_hit", 0)
         return {
             "spec_dispatches": disp,
             "spec_hits": hits,
-            "spec_rollbacks": pc.get("decode_multi.spec_rollback", 0)
-            + pc.get("decode.ahead_discard", 0)
+            "spec_rollbacks": pc.get("decode.ahead_discard", 0)
             + pc.get("decode.tail_discard", 0),
             "hit_ratio": hits / disp if disp else 0.0,
-            "device_idle_ms_per_burst":
-                1e3 * idle_s / idle_n if idle_n else 0.0,
         }
 
     # ------------------------------------------------------------------
@@ -885,15 +817,10 @@ class Engine:
                         1, self.ecfg.max_model_len - len(req.token_ids))))
         if req.arrival_time == 0.0:
             req.arrival_time = time.monotonic()
-        # Prefill-first routing drains the pipeline on admission: the
-        # NEXT step schedules this prompt's prefill immediately, and a
-        # speculative burst assumed an unchanged batch. The interleaver
-        # plans the next iteration's kind ahead instead — it decodes
-        # FIRST, so the pending burst is still consumable as a hit and
-        # is drained only when a prefill actually lands
-        # (_step_interleaved), not on every arrival.
-        if not self.interleave:
-            self.drain_pipeline()
+        # A decode step on the device ahead stays there: the next
+        # iteration decodes FIRST and takes it; it is discarded only
+        # where a prefill lands before it is taken
+        # (``_run_prefill_section``), not on every arrival.
         seq = Sequence(req=req, tokens=list(req.token_ids))
         self._by_id[req.request_id] = seq
         if self._fault_isolated:
@@ -1147,11 +1074,11 @@ class Engine:
             self.waiting.append(seq)
         self._sort_waiting()
 
-    def _grow_pages(self, seq: Sequence, lookahead: int = 0) -> bool:
-        """Ensure ``seq`` has pages for its next ``1 + lookahead`` token
-        writes. On exhaustion preempt offline victims, else preempt ``seq``
-        itself. Returns False if the sequence was preempted."""
-        return self._ensure_pages(seq, len(seq.tokens) + lookahead)
+    def _grow_pages(self, seq: Sequence) -> bool:
+        """Ensure ``seq`` has a page for its next token's write. On
+        exhaustion preempt offline victims, else preempt ``seq`` itself.
+        Returns False if the sequence was preempted."""
+        return self._ensure_pages(seq, len(seq.tokens))
 
     def _ensure_pages(self, seq: Sequence, covered: int) -> bool:
         """Ensure ``seq.pages`` covers ``covered`` token positions,
@@ -1220,14 +1147,11 @@ class Engine:
     def step(self) -> List[StepOutput]:
         """Run one engine iteration.
 
-        Interleaved (the default): decode the running set first — TPOT
-        is bounded by construction, a decode is never skipped while
-        streams are live — then spend the residual of the per-iteration
-        token budget on chunked-prefill windows whose quantum shrinks
-        under decode load (staggered admission, arxiv 2512.16134).
-        Prefill-first (``interleave=False``): the pre-interleaver
-        either/or routing, kept as the control that shows the decode
-        stall under prompt bursts."""
+        Decode the running set first — TPOT is bounded by construction,
+        a decode is never skipped while streams are live — then spend
+        the residual of the per-iteration token budget on
+        chunked-prefill windows whose quantum shrinks under decode load
+        (staggered admission, arxiv 2512.16134)."""
         self.step_count += 1
         outs = self._drain_cancelled()
         if not self.running:
@@ -1243,7 +1167,6 @@ class Engine:
         self.last_step_decode_tokens = 0
         self.last_step_prefill_s = 0.0
         self.last_step_prefill_windows = ()
-        self.last_step_decode_deferred = False
         self.last_step_ragged = False
         self.last_step_attn_dispatches = 0
         self.last_step_compiled = []
@@ -1252,10 +1175,7 @@ class Engine:
             self.last_step_moe = dict.fromkeys(MOE_STATS, 0)
         if self.cfg.looped:
             self.last_step_loop = self._loop_book()
-        if self.interleave:
-            outs = self._step_interleaved(outs)
-        else:
-            outs = self._step_prefill_first(outs)
+        outs = self._step_interleaved(outs)
         pf = self.last_step_prefill_tokens
         dc = self.last_step_decode_tokens
         self.last_step_tokens = pf + dc
@@ -1272,7 +1192,7 @@ class Engine:
 
     def _tail_eligible(self, outs: List[StepOutput]) -> bool:
         """May this iteration, whose sections are read and posted, pack
-        and dispatch the NEXT single decode step from host truth before
+        and dispatch the NEXT decode step from host truth before
         it hands ``outs`` out? Where the next iteration would begin with
         exactly that pack and nothing else: rows are running, nothing
         waits or is cancelled (the next iteration would first drain a
@@ -1286,8 +1206,7 @@ class Engine:
         it can foresee (``_ahead_eligible``). And never where growing a
         row's table could need a victim: a preemption is left to the
         head of the next iteration."""
-        if (self.ecfg.decode_steps != 1 or not self.interleave
-                or not self.running or self.waiting or self._cancelled
+        if (not self.running or self.waiting or self._cancelled
                 or self._pending is not None
                 or any(o.finished for o in outs)):
             return False
@@ -1298,19 +1217,19 @@ class Engine:
 
     def _step_interleaved(self, outs: List[StepOutput]) -> List[StepOutput]:
         if self._jit_ragged is not None and self.running and self.waiting \
-                and (self._pending is None or self._pending["steps"] > 1):
+                and self._pending is None:
             # One-dispatch ragged mixed step: decode rows and prefill
             # windows in one batch, one compiled program. Falls back to
-            # the legacy decode-then-prefill sections when the iteration
+            # the decode-then-prefill sections when the iteration
             # isn't ragged-eligible (returns False without scheduling).
-            # A single step launched ahead IS this iteration's decode,
-            # already on the device: the sections below take it and
-            # prefill behind it.
+            # A step launched ahead IS this iteration's decode, already
+            # on the device: the sections below take it and prefill
+            # behind it.
             if self._step_ragged_mixed(outs):
                 return outs
         pre = len(outs)
         if self.running:
-            outs.extend(self._decode_once())
+            outs.extend(self._run_decode())
             self.last_step_decode_tokens = sum(
                 len(o.new_token_ids) for o in outs[pre:])
         # Residual budget: decode tokens already spent count against the
@@ -1326,27 +1245,14 @@ class Engine:
                 self._run_prefill_section(batch, outs)
         return outs
 
-    def _step_prefill_first(self, outs: List[StepOutput]) -> List[StepOutput]:
-        with self._phase("sched"):
-            batch = self._schedule_prefill()
-        pre = len(outs)
-        if batch:
-            # Any live decode streams wait this iteration out — the
-            # stall the interleaver removes.
-            self.last_step_decode_deferred = bool(self.running)
-            self._run_prefill_section(batch, outs)
-        elif self.running:
-            outs.extend(self._decode_once())
-            self.last_step_decode_tokens = sum(
-                len(o.new_token_ids) for o in outs[pre:])
-        return outs
-
     def _run_prefill_section(self, batch: List[Sequence],
                              outs: List[StepOutput]) -> None:
-        """Run a scheduled prefill batch, draining the speculative
-        pipeline first (the landing prefill is what invalidates the
-        burst's batch snapshot) and keeping the step's prefill token /
-        window / wall-time ledger."""
+        """Run a scheduled prefill batch and keep the step's prefill
+        token / window / wall-time ledger. A decode step still on the
+        device ahead (dispatched at the last iteration's tail and not
+        taken: this iteration decoded nothing) is discarded first: the
+        rows this prefill admits are not in it, and its successor is
+        packed from host truth once they are."""
         self.drain_pipeline()
         self._note_members(batch)
         # Occupancy is the PROMPT tokens this batch computes (the
@@ -1357,21 +1263,6 @@ class Engine:
         t0 = time.monotonic()
         outs.extend(self._run_prefill(batch))
         self.last_step_prefill_s = time.monotonic() - t0
-
-    def _decode_once(self) -> List[StepOutput]:
-        self._note_members(self.running)
-        N = self.ecfg.decode_steps
-        # The fused scan writes KV at positions up to len+N-2; any
-        # sequence that would cross max_model_len must take single
-        # steps (a clamped out-of-bounds page write could corrupt a
-        # content-addressed page). Only the last few tokens of a
-        # near-limit sequence hit this path.
-        if N > 1 and all(
-                len(s.tokens) + N - 1 <= self.ecfg.max_model_len
-                for s in self.running):
-            return self._run_decode_multi()
-        # Single-step fallback (it discards a burst launched ahead).
-        return self._run_decode()
 
     def _starvation_quantum(self) -> int:
         """Anti-starvation floor on the iteration's prefill budget: once
@@ -1419,8 +1310,7 @@ class Engine:
                for s in decode_seqs):
             return False
         # Ragged decode rows are single-token continuations: each
-        # decoder spends 1 token of the budget (the fused burst's N
-        # tokens don't apply — the ragged program takes one step).
+        # decoder spends 1 token of the budget.
         budget = self.step_token_budget - len(decode_seqs)
         if self.waiting:
             budget = max(budget, self._starvation_quantum())
@@ -1443,7 +1333,7 @@ class Engine:
             # sections with the batch the scheduler already pinned.
             pre = len(outs)
             if self.running:
-                outs.extend(self._decode_once())
+                outs.extend(self._run_decode())
                 self.last_step_decode_tokens = sum(
                     len(o.new_token_ids) for o in outs[pre:])
             self._run_prefill_section(batch, outs)
@@ -1598,8 +1488,7 @@ class Engine:
     # never starved.
     _ADMIT_SKIP_AHEAD = 4
 
-    def _schedule_prefill(self, budget: Optional[int] = None
-                          ) -> List[Sequence]:
+    def _schedule_prefill(self, budget: int) -> List[Sequence]:
         """Admit waiting sequences up to the prefill token budget.
 
         Prompts longer than the largest bucket prefill in bucket-sized
@@ -1607,21 +1496,17 @@ class Engine:
         prefilled sequence keeps its slot + pages, sorts to the queue
         front, and re-enters here for its next window.
 
-        ``budget`` is the interleaved iteration's residual token budget:
-        windows shrink to it (the staggered-admission quantum) via
-        ``_window_cap``. None = the prefill-first path's full per-step
-        budget with whole-bucket windows. Each scheduled window is
+        ``budget`` is the iteration's residual token budget: windows
+        shrink to it (the staggered-admission quantum) via
+        ``_window_cap``. Each scheduled window is
         pinned on ``seq.sched_window`` — the executor must run exactly
         the window the admit decision allocated pages for."""
         batch: List[Sequence] = []
-        interleaved = budget is not None
-        if budget is None:
-            budget = self.ecfg.max_prefill_tokens
         cap1 = self.ecfg.prefill_buckets[-1]
         skipped = 0
         try:
             for seq in list(self.waiting):
-                self._window_budget = budget if interleaved else None
+                self._window_budget = budget
                 window = self._next_window(seq, seq.num_computed)
                 if window <= 0:
                     break   # residual budget below the smallest bucket
@@ -1985,16 +1870,14 @@ class Engine:
         step in flight in place of a pack and a dispatch. Where N+1 could
         not be launched ahead, ``step()`` dispatches it after N's post,
         from host truth, and it is taken here all the same."""
-        step = self._take_ahead(1)
+        self._note_members(self.running)
+        step = self._take_ahead()
         if step is None:
             step = self._dispatch_decode()
             if step is None:
                 return []
         self.last_step_attn_dispatches += 1
-        # In a burst engine the single step is the fallback for a row
-        # near ``max_model_len``: its next decode may be a burst again.
-        ahead = self._launch_ahead(step) \
-            if self.ecfg.decode_steps == 1 else None
+        ahead = self._launch_ahead(step)
         fused, top_ids, top_lps, mdrop = self._read_host(
             "decode", step["fused"],
             step["top_ids"] if step["want_top"] else None,
@@ -2045,13 +1928,10 @@ class Engine:
         (``_tail_eligible``), under a phase and a pair of counts of its
         own; a page it allocates stays with its row whatever becomes of
         the launch, so a discard has nothing to undo."""
-        # Restore the pages-cover-len invariant at dispatch regardless of
-        # which decode path ran last: the fused multi-step accepts up to N
-        # tokens but pre-grows only its own lookahead window, so a sequence
-        # arriving here right after a multi-step burst can have its next
-        # write position on an unmapped page — the KV scatter would drop
-        # the write silently (NULL-page mode="drop"), leaving a permanent
-        # KV hole that later attention reads and the prefix cache could
+        # Every row's pages must cover its next write before the step
+        # is packed: a write to an unmapped position is dropped in
+        # silence (the NULL page, mode="drop") and would leave a hole
+        # that later attention reads and the prefix cache could
         # content-address. May preempt, so iterate over a snapshot.
         with self._phase("decode.pack"):
             for seq in list(self.running):
@@ -2076,10 +1956,15 @@ class Engine:
                 packed, mirror = carry
                 self.phase_counts["decode.resident_hit"] += 1
             else:
-                with self._phase("decode.upload"):
-                    packed = jax.device_put(np.ascontiguousarray(block),
-                                            self._carry_place)
+                # The step is given the mirror, a copy nobody writes
+                # until the step has been read, and not the block: at
+                # the full width the block IS the slot array, an upload
+                # may alias its source (the CPU backend's does), and
+                # ``_fill_slots`` rewrites the slot array, through zero,
+                # while a step dispatched at a tail still reads it.
                 mirror = block.copy()
+                with self._phase("decode.upload"):
+                    packed = jax.device_put(mirror, self._carry_place)
         if at_tail:
             return self._launch_decode(
                 self._phase("decode.tail_dispatch",
@@ -2120,7 +2005,7 @@ class Engine:
         want_top = self._want_top(top_ids, self.running)
         _start_host_copy(fused, top_ids if want_top else None,
                          top_lps if want_top else None)
-        return {"steps": 1, "whole": self._rows_interfere,
+        return {"whole": self._rows_interfere,
                 "counts": (kind + "_hit", kind + "_discard"),
                 "fused": fused, "top_ids": top_ids, "top_lps": top_lps,
                 "mdrop": mdrop, "want_top": want_top,
@@ -2140,7 +2025,7 @@ class Engine:
         on the host moves, so a discard has nothing to undo."""
         mirror = step["mirror"]
         mp = mirror.shape[1] - _PACK_COLS
-        if not self._ahead_eligible(1) or mp != self._table_width():
+        if not self._ahead_eligible() or mp != self._table_width():
             return None
         self._fill_slots()
         if not _same_block(mirror[:, 2:],
@@ -2154,201 +2039,30 @@ class Engine:
             self._phase("decode.ahead_dispatch", **self._decode_shape(mp)),
             step["next_packed"], mirror, kind="decode.ahead")
 
-    def _run_decode_multi(self) -> List[StepOutput]:
-        """N fused decode steps per host round-trip (one lax.scan program).
-
-        Pages are pre-grown for the whole lookahead; finish detection runs
-        on host afterwards, discarding tokens sampled past a stop. Each
-        surviving sequence gets ONE StepOutput carrying its accepted token
-        run, so streaming consumers see a burst of up to N tokens.
-
-        Pipelined (``decode_pipeline``): burst k+1's inputs are burst k's
-        device-resident carries (``fin_tok``/``fin_pos``) — they do not
-        depend on burst k's host readback at all, only stop/finish/admit
-        handling does. So after dispatching burst k, its device→host copy
-        starts asynchronously and, when no host event can be pending,
-        burst k+1 is dispatched SPECULATIVELY before blocking on burst
-        k's copy; the host post of burst k then runs concurrently with
-        burst k+1's device compute. A speculation invalidated by the post
-        (EOS/length finish, preempt, admit, trim) is discarded: the
-        engine's key goes back to the one it split (the replacement burst
-        re-splits the same key — token streams stay byte-identical to
-        pipeline-off, pinned in tests/test_engine.py), the penalty
-        histogram rebuilds from host truth, and its in-place KV writes
-        are harmless — they land only at positions >= every sequence's
-        computed length
-        (re-written by the replacement burst before they are attended or
-        content-addressed), and pages released meanwhile are only reused
-        by computations the runtime enqueues after it (program order on
-        the one device stream)."""
-        # A hit: burst k+1 was dispatched before burst k's readback and
-        # the batch still matches its carries: it is consumed with zero
-        # pack/upload work; the device never idled across the boundary.
-        burst = self._take_ahead(self.ecfg.decode_steps)
-        if burst is not None:
-            self._note_burst_gap(overlapped=True)
-        else:
-            burst = self._dispatch_burst()
-            if burst is None:
-                return []
-        # Two-deep pipeline: enqueue burst k+1 BEFORE blocking on burst
-        # k's host copy (no-op when ineligible or the pipeline is off).
-        # Whenever spec is non-None, the host copy below overlaps a live
-        # next-burst device dispatch (spec_dispatch counts those).
-        spec = self._dispatch_spec(burst) if self.decode_pipeline else None
-        fused, top_ids, top_lps, mdrop = self._read_host(
-            "decode_multi", burst["fused"],
-            burst["top_ids"] if burst["want_top"] else None,
-            burst["top_lps"] if burst["want_top"] else None,
-            burst["mdrop"])
-        toks, logps = _split_tok_lp(fused)               # [N, B] each
-        self._note_step_stats(mdrop, "decode")
-        self._last_burst_ready_t = time.monotonic()
-        self._last_burst_step = self.step_count
-
-        outs = self._post_decode_multi(burst, toks, logps, top_ids,
-                                       top_lps, carry_free=spec is None)
-        self._settle_ahead(spec)
-        return outs
-
-    def _dispatch_burst(self) -> Optional[Dict[str, Any]]:
-        """Pack + dispatch one fused burst from host truth (the
-        non-speculative path), start its outputs' async host copy, and
-        return the burst's device handles (None when pre-grow preempted
-        the whole batch away)."""
-        N = self.ecfg.decode_steps
-        B = self.ecfg.max_batch_size
-        with self._phase("decode_multi.pack"):
-            # Pre-grow pages to cover the burst's KV writes (may preempt
-            # — iterate over a snapshot). Clamped to the tokens this
-            # sequence can still accept: a sequence 2 tokens from its
-            # max_tokens must not reserve N-1 pages of lookahead it will
-            # never use (page pressure preempts other work). Writes the
-            # scan performs past the clamp land on unmapped positions
-            # and are dropped — those sampled tokens are discarded on
-            # host anyway.
-            for seq in list(self.running):
-                if seq.status == SeqStatus.RUNNING:
-                    remaining = min(
-                        N, seq.req.sampling.max_tokens - seq.num_generated)
-                    self._grow_pages(seq,
-                                     lookahead=max(remaining - 1, 0))
-            if not self.running:
-                return None
-            self._fill_slots()
-            if self._slot_st is None:
-                self._slot_st = self._sampling_tensors(
-                    self._slot_sampling, B)
-            st_f32, st_i32 = self._slot_st
-            self._rng_key, key = jax.random.split(self._rng_key)
-            # Width must cover the lookahead pages pre-grown above.
-            mp = self._table_width()
-            # active+page-table block: re-upload ONLY when it changed
-            # (page growth, admit/finish). Steady-state long bursts reuse
-            # the device copy — page tables change every page_size tokens,
-            # not every burst.
-            apt_now = self._slot_packed[:, 2:_PACK_COLS + mp]
-            if not _same_block(self._active_pt_mirror, apt_now):
-                self._active_pt_mirror = apt_now.copy()
-                self._dev_active_pt = jnp.asarray(
-                    np.ascontiguousarray(apt_now))
-            # tokens/positions: reuse the previous burst's returned device
-            # arrays when the snapshot still matches the running batch —
-            # the common case inside a long all-decode stretch.
-            snap = tuple((s.req.request_id, s.slot, s.tokens[-1],
-                          len(s.tokens) - 1) for s in self.running)
-            resident = self._resident
-            if resident is not None and resident["snap"] == snap:
-                dev_tok, dev_pos = resident["tok"], resident["pos"]
-                resident_hit = True
-            else:
-                dev_tok = jnp.asarray(
-                    np.ascontiguousarray(self._slot_last_token))
-                dev_pos = jnp.asarray(np.ascontiguousarray(self._slot_pos))
-                resident_hit = False
-            self._resident = None     # handles are consumed (donated)
-        self._note_burst_gap(overlapped=False)
-        cache_before = self._jit_cache_size(self._jit_decode_multi)
-        with self._phase("decode_multi.dispatch", program="decode_multi",
-                         B=B, T=N, MP=mp):
-            (fused, top_ids, top_lps, self.kv, self._counts,
-             mdrop, fin_tok, fin_pos) = self._jit_decode_multi(
-                    self.params, dev_tok, dev_pos, self._dev_active_pt,
-                    self.kv, st_f32, st_i32, key, self._ensure_counts(),
-                    *self._ensure_bias())
-        self.last_step_attn_dispatches += 1
-        self._note_recompile("decode_multi", self._jit_decode_multi,
-                             cache_before, mp)
-        self.phase_counts["decode_multi.resident_hit"] += int(resident_hit)
-        want_top = self._want_top(top_ids, self.running)
-        _start_host_copy(fused, top_ids if want_top else None,
-                         top_lps if want_top else None)
-        return {"fused": fused, "top_ids": top_ids, "top_lps": top_lps,
-                "mdrop": mdrop, "fin_tok": fin_tok, "fin_pos": fin_pos,
-                "want_top": want_top}
-
-    def _dispatch_spec(self, burst: Dict[str, Any]
-                       ) -> Optional[Dict[str, Any]]:
-        """Speculatively dispatch the NEXT burst from ``burst``'s
-        device-resident carries, before ``burst``'s readback. The key it
-        split rides along (``_discard_ahead`` puts it back, so a rollback
-        replays the exact pipeline-off key stream). Starts the async host
-        copy of the speculative outputs immediately: by the time the next
-        step accepts them the copy has been overlapping host post +
-        device compute for a whole burst."""
-        N = self.ecfg.decode_steps
-        if self._dev_active_pt is None or not self._ahead_eligible(N):
-            return None
-        key_before = self._rng_key
-        self._rng_key, key = jax.random.split(key_before)
-        cache_before = self._jit_cache_size(self._jit_decode_multi)
-        mp = self._dev_active_pt.shape[1] - 2
-        with self._phase("decode_multi.spec_dispatch",
-                         program="decode_multi",
-                         B=self.ecfg.max_batch_size, T=N, MP=mp):
-            (fused, top_ids, top_lps, self.kv, self._counts,
-             mdrop, fin_tok, fin_pos) = self._jit_decode_multi(
-                    self.params, burst["fin_tok"], burst["fin_pos"],
-                    self._dev_active_pt, self.kv, *self._slot_st, key,
-                    self._ensure_counts(), *self._ensure_bias())
-        self.last_step_attn_dispatches += 1
-        self._note_recompile("decode_multi", self._jit_decode_multi,
-                             cache_before, mp)
-        _start_host_copy(fused, top_ids if burst["want_top"] else None,
-                         top_lps if burst["want_top"] else None)
-        return {"steps": N, "whole": True,
-                "counts": ("decode_multi.spec_hit",
-                           "decode_multi.spec_rollback"),
-                "fused": fused, "top_ids": top_ids, "top_lps": top_lps,
-                "mdrop": mdrop, "fin_tok": fin_tok, "fin_pos": fin_pos,
-                "want_top": burst["want_top"], "key_before": key_before,
-                "members": tuple((s.req.request_id, s.slot)
-                                 for s in self.running)}
-
-    def _ahead_eligible(self, N: int) -> bool:
-        """May the next ``N`` decode steps be launched from the device
-        carries of the step or burst in flight, before its outputs are
-        read back? Conservative: only when the host post cannot need
-        anything the launch lacks: no queued or cancelled work (the next
-        iteration would schedule a prefill behind it, or drain), the
-        sampling tensors resident, nobody expiring by length in the step
-        or burst in flight (known a step ahead; an EOS is not: its row's
-        result is dropped, or the launch discarded where it is taken
-        whole), the writes inside ``max_model_len``, the existing page
-        tables already covering them (a launch ahead never allocates, so
-        a discard has nothing to undo), and any penalty histogram already
+    def _ahead_eligible(self) -> bool:
+        """May the next decode step be launched from what the step in
+        flight leaves on the device, before its outputs are read back?
+        Conservative: only when the host post cannot need anything the
+        launch lacks: no queued or cancelled work (the next iteration
+        would schedule a prefill behind it, or drain), the sampling
+        tensors resident, nobody expiring by length in the step in
+        flight (known a step ahead; an EOS is not: its row's result is
+        dropped, or the launch discarded where it is taken whole), the
+        write inside ``max_model_len``, the existing page tables already
+        covering it (a launch ahead never allocates, so a discard has
+        nothing to undo), and any penalty histogram already
         device-resident (a host rebuild would read a stale ledger)."""
         if self.waiting or self._cancelled or self._slot_st is None:
             return False
         ps = self.ecfg.page_size
         for s in self.running:
-            rem = s.req.sampling.max_tokens - s.num_generated
-            if rem <= N:
+            # The step in flight samples the token at position len; the
+            # launch feeds it and writes its keys and values there.
+            if s.req.sampling.max_tokens - s.num_generated <= 1:
                 return False
-            if len(s.tokens) + 2 * N - 1 > self.ecfg.max_model_len:
+            if len(s.tokens) + 1 > self.ecfg.max_model_len:
                 return False
-            cover = len(s.tokens) + N + min(N, rem - N) - 1
-            if len(s.pages) * ps < cover:
+            if len(s.pages) * ps < len(s.tokens) + 1:
                 return False
         if self._counts is None and any(
                 s.req.sampling.presence_penalty
@@ -2364,54 +2078,40 @@ class Engine:
         construction; a row that finished, was cancelled or preempted
         since has its result dropped (its write landed at or past its
         computed length in a page it held when the program was enqueued,
-        see ``_run_decode_multi``). So a step whose rows do not see each
+        see ``_discard_ahead``). So a step whose rows do not see each
         other stands while every running row was active in it, whatever
         the post did to a table meanwhile (it read and wrote only pages
         the row held at launch; a trimmed page lies outside the window
-        it attended). Taken ``whole`` (a burst, or rows that share an
-        expert's capacity) it stands only while the batch is exactly
-        what its carries assumed: same membership in the same slots (an
-        EOS/length finish, preempt, cancel or import changes it, and
-        membership equality implies every sequence accepted the full
-        burst, so the host token tail EQUALS the device carries) and,
-        for a burst, an unchanged active+page-table block (its next
-        launch passes the device copy on)."""
+        it attended). Taken ``whole`` (rows that share an expert's
+        capacity) it stands only while the batch is exactly what it
+        assumed: same membership in the same slots (an EOS/length
+        finish, preempt, cancel or import changes it)."""
         live = tuple((s.req.request_id, s.slot) for s in self.running)
         if not live:
             return False
         if not p["whole"]:
             return set(live) <= set(p["members"])
-        if live != p["members"] or self._slot_st is None:
-            return False
-        if p["steps"] == 1:
-            return True
-        if self._active_pt_mirror is None:
-            return False
-        mp = self._active_pt_mirror.shape[1] - 2
-        apt_now = self._slot_packed[:, 2:_PACK_COLS + mp]
-        return _same_block(self._active_pt_mirror, apt_now)
+        return live == p["members"] and self._slot_st is not None
 
-    def _take_ahead(self, n_steps: int) -> Optional[Dict[str, Any]]:
-        """The launch ahead, handed to the decode of ``n_steps`` that
-        would otherwise pack and dispatch; discarded (None) if it is of
-        the other kind or no longer stands."""
+    def _take_ahead(self) -> Optional[Dict[str, Any]]:
+        """The launch ahead, handed to the decode that would otherwise
+        pack and dispatch; discarded (None) if it no longer stands."""
         p, self._pending = self._pending, None
         if p is None:
             return None
-        if p["steps"] != n_steps or not self._ahead_stands(p):
+        if not self._ahead_stands(p):
             self._discard_ahead(p)
             return None
         self.phase_counts[p["counts"][0]] += 1
-        if n_steps == 1:
-            self.phase_counts["decode.ahead_dropped_rows"] += \
-                len(p["members"]) - len(self.running)
+        self.phase_counts["decode.ahead_dropped_rows"] += \
+            len(p["members"]) - len(self.running)
         return p
 
     def _settle_ahead(self, p: Optional[Dict[str, Any]]) -> None:
         """After the post of the step before it: keep the launch ahead
         for the next decode, or discard it now if the post voided it (a
-        finish mid-burst, a trim, every row gone: nothing would ever
-        take it), before anything else observes the stale carries."""
+        finish where it is taken whole, every row gone: nothing would
+        ever take it), before anything else observes the key it moved."""
         if p is None:
             return
         if self._ahead_stands(p):
@@ -2423,24 +2123,31 @@ class Engine:
         """Roll a launch ahead back (host bookkeeping only: the device
         computation finishes on its own and its outputs are dropped).
         The engine's key goes back to the one the launch was given, so
-        the replacement draws what a sequential engine draws; the penalty
-        histogram rebuilds from host truth at the next dispatch; the
-        burst's resident carries are dropped so the replacement uploads
-        fresh token/position state (the single step's carry is compared
-        by value anyway)."""
+        the replacement draws what a sequential engine draws (streams
+        stay byte-identical to an engine that launches nothing ahead,
+        tests/test_decode_ahead.py); the penalty histogram rebuilds from
+        host truth at the next dispatch; the block the launch handed
+        back is no carry (``_decode_carry`` holds the block of a step
+        that was READ, and is compared by value anyway).
+
+        What the discarded program wrote in place is harmless. Its
+        keys and values land only at positions at or past every row's
+        computed length, in pages the row held when the program was
+        enqueued: the replacement step writes those positions again
+        before anything attends to them or the prefix index
+        content-addresses them (a state by slot is written beside the
+        one it was read from, never over it: ``_live_slot``). And a page
+        released meanwhile is reused only by a computation the runtime
+        enqueues AFTER the discarded one: program order on the one
+        device stream."""
         self.phase_counts[p["counts"][1]] += 1
         self._rng_key = p["key_before"]
         self._counts = None
-        self._resident = None
-        # A rolled-back boundary is neither idle nor covered: the device
-        # spent it computing the discarded burst (wasted work, counted
-        # above) — exclude it from the idle ledger rather than book a
-        # saturated device as a bubble.
-        self._last_burst_ready_t = None
 
     def drain_pipeline(self) -> None:
-        """Discard any in-flight speculative burst. Called wherever
-        engine state changes outside the decode loop — admits, KV
+        """Discard the decode step on the device ahead, if there is one
+        (launched ahead or dispatched at a tail). Called wherever engine
+        state changes outside the decode loop — a landing prefill, KV
         import/export, warmup — and by the worker's sleep path."""
         pending, self._pending = self._pending, None
         if pending is not None:
@@ -2499,8 +2206,9 @@ class Engine:
         requeued for re-prefill (recompute keeps generated tokens —
         the same resume shape as preemption). Device KV touched by the
         faulted step is suspect, so pages are released WITHOUT being
-        content-addressed into the prefix cache, and any speculative
-        carry is dropped cold. Returns the ids actually evicted."""
+        content-addressed into the prefix cache, and a decode step on
+        the device ahead is dropped cold. Returns the ids actually
+        evicted."""
         self.release_isolation()
         try:
             self.drain_pipeline()
@@ -2540,86 +2248,8 @@ class Engine:
         self._counts = None
         self._slot_st = None
         self._bias = None
-        self._resident = None
         self._decode_carry = None
         return evicted
-
-    def _note_burst_gap(self, overlapped: bool) -> None:
-        """Device-idle attribution per burst boundary: host time between
-        the previous burst's outputs being ready and this dispatch,
-        during which the device had nothing queued — 0 when a
-        speculative burst covered the gap. Only consecutive decode
-        bursts count: a prefill or idle stretch in between is
-        scheduling, and a rolled-back boundary is excluded entirely
-        (_discard_ahead clears the timestamp — the device was busy on
-        the discarded burst, not idle)."""
-        t = self._last_burst_ready_t
-        if t is None or self.step_count != self._last_burst_step + 1:
-            return
-        gap = 0.0 if overlapped else max(time.monotonic() - t, 0.0)
-        self.phase_times["decode_multi.device_idle"] += gap
-        self.phase_counts["decode_multi.device_idle"] += 1
-
-    def _post_decode_multi(self, burst: Dict[str, Any], toks, logps,
-                           top_ids, top_lps,
-                           carry_free: bool) -> List[StepOutput]:
-        """Host post of one fused burst: append accepted tokens, detect
-        finishes, register prefix pages, trim sliding windows. Runs
-        concurrently with the next burst's device compute when one was
-        dispatched speculatively (``carry_free=False`` — the carries
-        were donated into it, so resident state must not be kept)."""
-        N = self.ecfg.decode_steps
-        outs: List[StepOutput] = []
-        with self._phase("decode_multi.post"):
-            for seq, slot in [(s, s.slot) for s in self.running]:
-                accepted: List[int] = []
-                lps: List[float] = []
-                tops: Optional[List[List[Dict[str, Any]]]] = \
-                    [] if (top_ids is not None
-                           and seq.req.sampling.logprobs) else None
-                reason = FinishReason.NONE
-                for k_step in range(N):
-                    tok = int(toks[k_step, slot])
-                    seq.tokens.append(tok)
-                    accepted.append(tok)
-                    lps.append(float(logps[k_step, slot]))
-                    if tops is not None:
-                        tops.append(_top_row(top_ids[k_step],
-                                             top_lps[k_step], slot))
-                    reason = self._finish_reason(seq, tok)
-                    if reason != FinishReason.NONE:
-                        break
-                if seq.status == SeqStatus.RUNNING:
-                    # KV resident for every token but the last sampled one.
-                    seq.num_computed = len(seq.tokens) - 1
-                out = StepOutput(
-                    request_id=seq.req.request_id, new_token_ids=accepted,
-                    logprobs=lps, finish_reason=reason,
-                    num_prompt_tokens=seq.num_prompt_tokens,
-                    num_generated=seq.num_generated, top_logprobs=tops)
-                outs.append(out)
-                if reason != FinishReason.NONE:
-                    self._finish_seq(seq, reason)
-                elif seq.status == SeqStatus.RUNNING:
-                    self._register_pages(seq)
-                    self._swa_trim(seq)
-            # Keep the scan's final (tokens, positions) as device-resident
-            # state for the next burst. Every still-RUNNING sequence
-            # accepted the full N tokens (early finish leaves running), so
-            # its host tail now EQUALS the device carry — the snapshot
-            # below re-proves that at next dispatch; any host-side change
-            # in between (admit, preempt, import) makes it miss and fall
-            # back to a fresh upload. When a speculative burst was
-            # dispatched the carries were donated into it (the pending
-            # dict carries the next-resident state instead).
-            if carry_free:
-                self._resident = {
-                    "tok": burst["fin_tok"], "pos": burst["fin_pos"],
-                    "snap": tuple((s.req.request_id, s.slot, s.tokens[-1],
-                                   len(s.tokens) - 1)
-                                  for s in self.running),
-                }
-        return outs
 
     def _top_entry(self, seq: Sequence, top_ids, top_lps,
                    row: int) -> Optional[List[List[Dict[str, Any]]]]:
@@ -3147,8 +2777,8 @@ class Engine:
                decode_widths: Optional[Sequence[int]] = None) -> float:
         """Pre-compile every steady-state program of this engine, so a
         client request almost never pays a compile (round-1 weakness:
-        B=1-only warmup left pow2 batch buckets, table-width variants and
-        the fused multi-step program compiling mid-serving). Not covered:
+        B=1-only warmup left pow2 batch buckets and table-width variants
+        compiling mid-serving). Not covered:
         rare shapes whose page-table width comes from a readmitted
         sequence's long history (MP above the bucket's own need) — those
         still compile lazily on first hit.
@@ -3233,11 +2863,6 @@ class Engine:
                 widths = widths[:1]
         else:
             widths = list(decode_widths)
-        # Scoped callers ask for exactly what their schedule hits: with
-        # fused bursts on, steady state is _run_decode_multi (single
-        # steps only near max_model_len, which a scoped bench never
-        # approaches) — don't pay a compile for the other one.
-        single = decode_widths is None or self.ecfg.decode_steps == 1
         with concurrent.futures.ThreadPoolExecutor(
                 os.cpu_count() or 1) as pool:
             # Each program goes to the compiler as soon as it is lowered,
@@ -3246,17 +2871,17 @@ class Engine:
             self._warm_programs(
                 lambda jitted, *args: compiling.append(
                     pool.submit(jitted.lower(*args).compile)),
-                key, prefill_shapes, widths, batch_pows, single, extended)
+                key, prefill_shapes, widths, batch_pows, extended)
             for job in compiling:
                 job.result()
         self._warm_programs(
             lambda jitted, *args: jitted(*args),
-            key, prefill_shapes, widths, batch_pows, single, extended)
+            key, prefill_shapes, widths, batch_pows, extended)
         jax.block_until_ready(jax.tree_util.tree_leaves(self.kv)[0])
         return time.monotonic() - t0
 
     def _warm_programs(self, launch, key, prefill_shapes, widths,
-                       batch_pows, single: bool, ragged: bool) -> None:
+                       batch_pows, ragged: bool) -> None:
         """One walk over warm-up's programs, each with its inert
         arguments: ``launch(jitted, *args)`` lowers it (and returns
         nothing) or calls it (and returns its outputs, the pools among
@@ -3276,40 +2901,19 @@ class Engine:
             if out is not None:
                 self.kv = out[3]
 
-        # Decode (single + fused multi): every width asked for. Inactive
-        # slots + NULL pages make the KV writes no-ops.
+        # Decode: every width asked for. Inactive slots + NULL pages
+        # make the KV writes no-ops.
         st_f32, st_i32 = self._sampling_tensors([], Bmax)
         b_ids, b_vals = self._batch_bias([], Bmax, self.cfg.vocab_size)
         for mp in widths:
-            if single:
-                packed = jax.device_put(
-                    np.zeros((Bmax, _PACK_COLS + mp), np.int32),
-                    self._carry_place)
-                out = launch(self._jit_decode, self.params, packed,
-                             self.kv, st_f32, st_i32, key, None, b_ids,
-                             b_vals)
-                if out is not None:
-                    self.kv = out[3]
-            if self.ecfg.decode_steps > 1:
-                tok0 = jnp.zeros((Bmax,), jnp.int32)
-                pos0 = jnp.zeros((Bmax,), jnp.int32)
-                apt0 = jnp.zeros((Bmax, 2 + mp), jnp.int32)
-                out = launch(self._jit_decode_multi, self.params, tok0,
-                             pos0, apt0, self.kv, st_f32, st_i32, key,
-                             None, b_ids, b_vals)
-                if out is not None:
-                    # Second call feeding back the returned device-resident
-                    # carries and a split (device-committed) key: the
-                    # serving path's resident-reuse signature. Under the
-                    # pinned-layout jits, committed-vs-uncommitted inputs
-                    # are distinct pjit cache signatures (same executable,
-                    # no compile) — prime both here or the first serving
-                    # burst shows up in the recompile counters.
-                    _, _, _, self.kv, _, _, f_tok, f_pos = out
-                    key2 = jax.random.split(key)[0]
-                    self.kv = self._jit_decode_multi(
-                        self.params, f_tok, f_pos, apt0, self.kv, st_f32,
-                        st_i32, key2, None, b_ids, b_vals)[3]
+            packed = jax.device_put(
+                np.zeros((Bmax, _PACK_COLS + mp), np.int32),
+                self._carry_place)
+            out = launch(self._jit_decode, self.params, packed,
+                         self.kv, st_f32, st_i32, key, None, b_ids,
+                         b_vals)
+            if out is not None:
+                self.kv = out[3]
         # Ragged mixed programs (opt-in): batch bucket = pow2(decoders +
         # admits) — any rung of the pow2 ladder — at each prefill bucket,
         # with the table as wide as the wider of the decode widths and
@@ -3444,8 +3048,8 @@ def _start_host_copy(*arrays) -> None:
     """Kick off device→host copies without blocking (``jax.Array
     .copy_to_host_async``; re-requesting an in-flight copy is a no-op,
     and array types without the method are simply read synchronously
-    later). The pipelined decode path calls this at dispatch so the copy
-    overlaps the next burst's device compute and the host post."""
+    later). A decode launch calls this at dispatch, so the copy overlaps
+    the device's next step and the host's post of the one before."""
     for a in arrays:
         if a is None:
             continue
@@ -3579,55 +3183,3 @@ def _decode_step(params, packed, kv, st_f32, st_i32, key, counts=None,
         .at[:, 1].set(positions + active.astype(jnp.int32))
     return (_fuse_tok_lp(tok, lp), top_ids, top_lps, kv, counts,
             transformer.step_moe_stats(stats), next_packed, next_key)
-
-
-def _decode_multi_step(params, tokens, positions, active_pt, kv, st_f32,
-                       st_i32, key, counts=None, bias_ids=None,
-                       bias_vals=None, *, cfg: ModelConfig, n_steps: int,
-                       num_top: int = 0,
-                       plan: KernelPlan = KernelPlan()):
-    """``n_steps`` fused greedy/sampled decode iterations: the scan body is
-    traced once, tokens feed forward on-device, and only the [N, B] token/
-    logprob blocks cross back to the host — one dispatch per N tokens.
-
-    ``tokens``/``positions`` are separate [B] arrays (not packed columns)
-    so consecutive bursts can feed the previous burst's RETURNED final
-    token/position arrays straight back in — device-resident decode state,
-    zero host uploads when batch membership is unchanged. ``active_pt`` is
-    [B, 2+MP]: column 0 the active mask, column 1 the per-slot mrope
-    rope delta (0 for standard-rope models), the rest the page table —
-    kept as one buffer because all change on the same events (admit/
-    finish/page growth), detected host-side by an array compare."""
-    active = active_pt[:, 0].astype(bool)
-    rope_delta = active_pt[:, 1] if cfg.is_mrope else None
-    page_table = active_pt[:, 2:]
-    st = SamplingTensors.unpack(st_f32, st_i32)
-
-    def body(carry, key_i):
-        tok, pos, kv, cnt, drop = carry
-        logits, kv, stats = transformer.forward_decode(
-            params, cfg, tok, pos, active, kv, page_table,
-            return_stats=True, rope_delta=rope_delta, plan=plan)
-        new_tok = sample_tokens(logits, st, key_i, positions=pos,
-                                counts=cnt, bias_ids=bias_ids,
-                                bias_vals=bias_vals)
-        lp = compute_logprobs(logits, new_tok)
-        if num_top > 0:
-            top_ids, top_lps = compute_top_logprobs(logits, num_top)
-        else:
-            top_ids = top_lps = None
-        if cnt is not None:
-            cnt = update_counts(cnt, new_tok, active)
-        return (new_tok, pos + 1, kv, cnt,
-                drop + transformer.step_moe_stats(stats)), \
-            (new_tok, lp, top_ids, top_lps)
-
-    keys = jax.random.split(key, n_steps)
-    (fin_tok, fin_pos, kv, counts, moe_dropped), \
-        (toks, lps, top_ids, top_lps) = \
-        jax.lax.scan(body, (tokens, positions, kv, counts,
-                            transformer.step_stats_zeros(cfg)), keys)
-    # Final carry token/position go back to the host AS HANDLES ONLY —
-    # next burst feeds them in again without a host→device upload.
-    return (_fuse_tok_lp(toks, lps), top_ids, top_lps, kv, counts,
-            moe_dropped, fin_tok, fin_pos)

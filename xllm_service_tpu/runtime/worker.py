@@ -284,8 +284,8 @@ class ModelRuntime:
         if self.state == MODEL_ASLEEP:
             return
         if self.engine is not None:
-            # Settle the decode pipeline first: an in-flight speculative
-            # burst must not be left referencing a pool we are dropping.
+            # Settle the decode pipeline first: a step on the device
+            # ahead must not be left referencing a pool we are dropping.
             self.engine.drain_pipeline()
             self._host_params = jax.tree_util.tree_map(
                 np.asarray, jax.device_get(self.engine.params))
@@ -1559,18 +1559,6 @@ class Worker:
                 "xllm_worker_interleave_mix",
                 "prefill-token share of the last engine iteration",
                 labelnames=("model",)).set(pf / (pf + dc), model=m)
-        # Materialized at 0 so a scrape can tell "no stalls" from "not
-        # exported" — it stays 0 while interleaving is on.
-        stall = self.obs.counter(
-            "xllm_worker_decode_stall_ms_total",
-            "wall ms of prefill-first iterations that deferred live "
-            "decode streams (zero while interleaving is on)",
-            labelnames=("model",))
-        stall.inc(0, model=m)
-        if eng.last_step_decode_deferred:
-            # Prefill-first control path ran a prompt step while decode
-            # streams were live — the stall the interleaver removes.
-            stall.inc(step_ms, model=m)
         if eng.queue_waits_ms:
             h = self.obs.histogram(
                 "xllm_worker_queue_wait_ms",
@@ -1649,7 +1637,6 @@ class Worker:
             prefill_tokens=eng.last_step_prefill_tokens,
             decode_tokens=eng.last_step_decode_tokens,
             prefill_windows=eng.last_step_prefill_windows,
-            decode_deferred=eng.last_step_decode_deferred,
             ragged=eng.last_step_ragged,
             attn_dispatches=eng.last_step_attn_dispatches,
             members=eng.step_members,
@@ -1751,10 +1738,10 @@ class Worker:
             labelnames=("model",)).set_total(st["rows"], model=m)
 
     def _flush_overlap(self, rt: ModelRuntime) -> None:
-        """Decode-pipeline overlap health: speculative-burst
-        dispatch/hit/rollback counters plus the two derived gauges a
-        dashboard charts — speculation hit ratio and device-idle ms per
-        burst boundary (docs/OBSERVABILITY.md)."""
+        """How the decode steps put on the device ahead of their
+        iteration fared (``Engine.overlap_metrics``): dispatched, taken
+        ("hit") and discarded ("rollback"), and the share taken
+        (docs/OBSERVABILITY.md)."""
         eng = rt.engine
         if eng is None:
             return
@@ -1762,22 +1749,17 @@ class Worker:
         m = rt.model
         c = self.obs.counter(
             "xllm_worker_decode_overlap_spec_total",
-            "speculative next-burst dispatches by outcome "
-            "(pipelined decode, XLLM_DECODE_PIPELINE)",
+            "decode steps dispatched ahead of their iteration (launched "
+            "ahead or at a tail), by outcome",
             labelnames=("model", "result"))
         c.set_total(om["spec_dispatches"], model=m, result="dispatch")
         c.set_total(om["spec_hits"], model=m, result="hit")
         c.set_total(om["spec_rollbacks"], model=m, result="rollback")
         self.obs.gauge(
             "xllm_worker_decode_overlap_hit_ratio",
-            "fraction of speculative burst dispatches consumed as-is",
+            "fraction of the decode steps dispatched ahead that were "
+            "taken as they were",
             labelnames=("model",)).set(om["hit_ratio"], model=m)
-        self.obs.gauge(
-            "xllm_worker_decode_overlap_device_idle_ms_per_burst",
-            "host-side gap per decode burst boundary not covered by a "
-            "speculative burst",
-            labelnames=("model",)).set(
-            om["device_idle_ms_per_burst"], model=m)
 
     def _flush_prefix_cache(self, rt: ModelRuntime) -> None:
         """Prefix-reuse health (docs/KV_CACHE.md): lookup/hit-token
