@@ -61,6 +61,8 @@ CELLS = {
     "packed": (dict(b=64, hq=32, hkv=4, d=128, mp=96, pool=(2, 3776)), 8),
     # Falcon-H1-34B: a group of 5
     "group5": (dict(b=32, hq=20, hkv=4, d=128, mp=16, pool=(6, 256)), 8),
+    # Solar-Open2-250B: ONE layer of 4 attends, at a group of 8
+    "group8": (dict(b=64, hq=64, hkv=8, d=128, mp=96, pool=(1, 1888)), 4),
 }
 
 
@@ -133,6 +135,16 @@ def _ragged_attention(sds):
              sds((B_DEC,), jnp.int32), sds((), jnp.int32)), {})
 
 
+def _kda_update(sds):
+    from xllm_service_tpu.ops.pallas.kda_update import kda_decode_update
+    b, h, d = 64, 64, 128
+    row = sds((b, h, d), jnp.float32)
+    return (functools.partial(kda_decode_update, interpret=False),
+            (sds((3, 193, h, d, d), jnp.float32), sds((), jnp.int32),
+             sds((b,), jnp.int32), sds((b,), jnp.int32), row, row, row, row,
+             sds((b, h), jnp.float32)), {"donate_argnums": (0,)})
+
+
 KERNELS = {
     # The default path: what a served worker runs on the chip.
     "decode-attention": _decode_attention,
@@ -165,6 +177,12 @@ KERNELS = {
         _latent_attention, b=32, hq=32, mp=96,
         pool=(5, 1888, PS, MLA_D)),
     "latent-decode-kv-writer": _latent_writer,
+    # A delta-rule layer's one-token state update (ops/pallas/
+    # kda_update.py) at the benchmark cell's shapes: 64 rows, 64 heads of
+    # 128 x 128 float32 in a pool of 3 layers x 193 slots, blocks of 8
+    # heads mapped by slot, four [128, 1] columns a head sliced out of a
+    # 32-lane block.
+    "kda-decode-update[cell]": _kda_update,
 }
 
 

@@ -221,7 +221,8 @@ def test_census_plan_is_what_an_engine_resolves(monkeypatch):
     monkeypatch.delenv("XLLM_PALLAS_KV")
     for cfg in (ModelConfig.tiny(), _cell_config("lfm2-24b-a2b"),
                 _cell_config("joyai-llm-flash", 0),
-                _cell_config("falcon-h1-34b", 0)):
+                _cell_config("falcon-h1-34b", 0),
+                _cell_config("solar-open2-250b", 0)):
         assert cc.chip_plan(cfg) == KernelPlan.from_env(
             cfg, EngineConfig(page_size=128, num_pages=64,
                               max_model_len=8192, max_batch_size=8))
@@ -506,6 +507,11 @@ PREFILL_CELLS = {
     # out too (406 MB): transformer._state_rows reads a slice a row
     "falcon-h1-34b": (0, 256, 32, 97,
                       [(1, 256, 16), (2, 256, 16), (1, 1024, 8)]),
+    # from two rows on the gather of a row's filter ring (196 KB over
+    # q | k | v) was split over thirds of the whole pool of tails, copied
+    # first (1.1 GB a layer): transformer._ring_read takes a slice a row
+    # (two rows: ``test_a_wide_ring_is_read_a_slice_a_row``)
+    "solar-open2-250b": (0, 1888, 64, 193, [(1, 2048, 96)]),
 }
 CELL_SHAPES = [(cell, shape) for cell, spec in PREFILL_CELLS.items()
                for shape in spec[4]]
@@ -518,7 +524,10 @@ CELL_SHAPES = [(cell, shape) for cell, spec in PREFILL_CELLS.items()
 # and forward_prefill gathers vp_c as it gathers kp_c (clean offline at
 # every warm-up shape of both dense cells: my compiles, PR 47).
 LEFT = {"mistral-7b-v01": ["fusion 768,128,8,128"],
-        "ouro-2.6b": ["fusion 40,128,16,128"]}
+        "ouro-2.6b": ["fusion 40,128,16,128"],
+        # ONE layer attends, so the in-place prefill writer's aliased
+        # result, the pool itself, has the shape of "one layer of it"
+        "solar-open2-250b": ["custom-call 1,1888,128,8,128"]}
 
 
 def _slice_then_gather(pool, layer, page_table):
@@ -568,7 +577,28 @@ class TestPrefillReadsPagesOffThePool:
         assert layer_sized == LEFT.get(cell, []) and copies == [], (
             layer_sized, copies)
 
-    @pytest.mark.parametrize("cell", list(PREFILL_CELLS))
+    def test_a_wide_ring_is_read_a_slice_a_row(self, aot):
+        """Two follow-ups in one window of the delta-rule cell: the
+        gather of their filter rings (a row of 196 KB) was split over
+        thirds of the WHOLE pool of tails, each copied first: 1.1 GB of
+        temporaries that no pool-shaped result shows (PR 49)."""
+        import tools.aot_copy_census as cc
+        aot_compile, _ = aot
+        name = "solar-open2-250b"
+        _, pages, batch, slots, _ = PREFILL_CELLS[name]
+        programs, _, pools = cc.build_cell_programs(
+            _cell_config(name, 0), pages, 96, batch, window=256,
+            state_slots=slots, prefill_rows=2)
+        fn, args, jit_kw = programs["prefill"]
+        compiled = aot_compile(fn, args, **jit_kw)
+        assert cc.census_pools(compiled.as_text(), pools) == (LEFT[name], [])
+        tails = 3 * 1888 * 98304 * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < tails // 4
+
+    # (not the delta-rule cell: ONE of its layers attends, and a layer
+    # sliced out of a pool of one layer is the pool)
+    @pytest.mark.parametrize("cell", [c for c in PREFILL_CELLS
+                                      if c != "solar-open2-250b"])
     def test_positive_control_slice_then_gather(self, aot, cell,
                                                 monkeypatch):
         """The parent's form patched in: the census must find the slice

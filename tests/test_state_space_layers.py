@@ -582,7 +582,9 @@ def test_from_hf_config_refuses_what_it_does_not_run_by_name(key, value):
 
 
 def test_a_mixer_is_in_every_layer_or_in_none():
-    with pytest.raises(ValueError, match="'mix' operator"):
+    # (since PR 49 a state layer may stand beside attention layers; what
+    # one pool of tails cannot hold beside a ring is a "conv" layer's row)
+    with pytest.raises(ValueError, match="'conv' operator has no 'mix'"):
         dataclasses.replace(model(), layer_kinds=("mix+dense", "conv+dense"))
     with pytest.raises(ValueError, match="'mix' operator"):
         dataclasses.replace(model(), ssm_heads=0)

@@ -441,7 +441,7 @@ def build_cell_programs(cfg, pages: int, table_width: int, batch: int = 8,
     # with its size-1 head axis outermost (Engine._pool_format).
     pin = tuple(latent_pool_format(x.sharding) if cfg.mla
                 else row_major_format(x.ndim, x.sharding) for x in kv)
-    by_slot = cfg.num_ssm_layers > 0
+    by_slot = cfg.num_state_layers > 0
     jit_kw = {"donate_argnums": (4,),
               "in_shardings": (None, None, None, None, pin, None)
               + ((None,) if by_slot else ()),
@@ -493,9 +493,9 @@ def chip_plan(cfg):
                       write_then_attend=True, latent_decode=cfg.mla,
                       # but a model whose state lives by slot, whose
                       # engine starts every window on a page boundary
-                      page_aligned=cfg.num_ssm_layers > 0,
+                      page_aligned=cfg.num_state_layers > 0,
                       expert_gmm=cfg.dropless_experts,
-                      ssm_decode=cfg.num_ssm_layers > 0, interpret=False)
+                      ssm_decode=cfg.num_state_layers > 0, interpret=False)
 
 
 def census_plan(write_then_attend: bool):
@@ -586,7 +586,7 @@ def run_cells_census(only=()) -> dict:
         eng = mix["engine"]
         rows = eng["max_batch_size"]
         # the slots an engine gives a pool of states (runtime/engine.py)
-        slots = 1 + 3 * rows if cfg.num_ssm_layers else 0
+        slots = 1 + 3 * rows if cfg.num_state_layers else 0
         for B, T, MP in traffic.warmup_shapes(
                 mix, eng["page_size"])["prefill"]:
             programs, _, pools = build_cell_programs(

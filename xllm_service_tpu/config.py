@@ -270,6 +270,37 @@ class ModelConfig:
     ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     ssm_out_multiplier: float = 1.0
     mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+    # A delta-rule linear-attention layer (Kimi Delta Attention; operator
+    # "kda" in ``layer_kinds``; Solar-Open2) INSTEAD of attention: it
+    # keeps no keys and values. ``kda_heads`` heads, each with a matrix
+    # state [kda_head_dim, kda_head_dim] (key channel x value channel)
+    # in float32 in the pool by slot; q, k and v each through a causal
+    # depthwise filter of ``conv_kernel`` taps (no bias) and SiLU, their
+    # inputs kept as a ring over the 3 x ``kda_inner`` channels q | k | v;
+    # a decay a KEY CHANNEL and an output gate, both through a low-rank
+    # pair of rank ``kda_gate_rank``; ``kda_beta_scale`` 2 lets the
+    # rank-one correction's eigenvalue pass below zero
+    # (kda_allow_neg_eigval). 0 heads = no such layer.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_gate_rank: int = 0
+    kda_beta_scale: float = 1.0
+    # False: attention layers rotate nothing (NoPE; Solar-Open2's
+    # ``use_rope``).
+    use_rope: bool = True
+    # An elementwise sigmoid gate on attention's output before ``o_proj``,
+    # from the layer's normed input (hidden -> heads x head_dim;
+    # ``use_gqa_gate``).
+    attn_gate: bool = False
+    # A held SHARE of a wider router: ``expert_share_chips`` chips divide
+    # each sparse layer's experts among them, ``num_experts`` is what THIS
+    # chip holds and ``expert_share_rank`` which (experts rank *
+    # num_experts onward). The gate scores and chooses over all
+    # ``router_experts``; an assignment to an expert held elsewhere is
+    # computed by nobody here and counted (``expert.MOE_STATS``
+    # "elsewhere"). 1 chip = every expert held: every other model.
+    expert_share_chips: int = 1
+    expert_share_rank: int = 0
     dtype: str = "bfloat16"
 
     def __post_init__(self) -> None:
@@ -281,14 +312,33 @@ class ModelConfig:
                 f"total_ut_steps={self.total_ut_steps}: a model runs its "
                 f"layers at least once")
         ops = {k.split("+")[0] for k in self.layer_kinds or ()}
-        if "mix" in ops and (ops != {"mix"} or self.ssm_heads <= 0
+        if "mix" in ops and (self.ssm_heads <= 0
                              or self.ssm_heads % self.ssm_groups):
             raise ValueError(
-                "a layer_kinds model with a 'mix' operator has it in every "
-                "layer (its convolution tails are rings of conv_kernel "
-                "rows, a 'conv' layer's are conv_kernel - 1 gated inputs: "
-                "one pool holds one or the other) and gives ssm_heads, a "
-                "multiple of ssm_groups")
+                "a layer_kinds model with a 'mix' operator gives "
+                "ssm_heads, a multiple of ssm_groups")
+        if "kda" in ops and (self.kda_heads <= 0 or self.kda_head_dim <= 0
+                             or self.kda_gate_rank <= 0
+                             or self.conv_kernel <= 0):
+            raise ValueError(
+                "a layer_kinds model with a 'kda' operator gives "
+                "kda_heads, kda_head_dim, kda_gate_rank and conv_kernel")
+        if "conv" in ops and ops & {"mix", "kda"}:
+            raise ValueError(
+                "a layer_kinds model with a 'conv' operator has no 'mix' "
+                "or 'kda' layer: their convolution tails are rings of "
+                "conv_kernel rows, a 'conv' layer's are conv_kernel - 1 "
+                "gated inputs, and the one pool of tails holds one kind "
+                "of row")
+        if {"mix", "kda"} <= ops:
+            raise ValueError(
+                "a layer_kinds model has 'mix' or 'kda' layers, not both: "
+                "the pool of matrix states by slot has one shape a head "
+                "and the ring one width")
+        if not (0 <= self.expert_share_rank < self.expert_share_chips):
+            raise ValueError(
+                f"expert_share_rank={self.expert_share_rank} is not a "
+                f"rank among expert_share_chips={self.expert_share_chips}")
         if self.total_ut_steps > 1:
             if self.mla or self.layer_kinds is not None or self.is_moe:
                 raise ValueError(
@@ -346,15 +396,59 @@ class ModelConfig:
 
     @property
     def num_conv_layers(self) -> int:
-        """Layers whose state is a convolution tail (the third pool): a
-        "conv" operator's, and the mixer's of a "mix" operator."""
-        return sum(k.startswith(("conv+", "mix+"))
+        """Layers that keep a convolution tail (the third pool): a "conv"
+        operator's, and the filter ring of a "mix" or a "kda" operator."""
+        return sum(k.startswith(("conv+", "mix+", "kda+"))
+                   for k in self.layer_kinds or ())
+
+    @property
+    def num_state_layers(self) -> int:
+        """Layers that keep a matrix state a head (the fourth pool, by
+        slot): a "mix" operator's mixer, a "kda" operator."""
+        return sum(k.startswith(("mix+", "kda+"))
                    for k in self.layer_kinds or ())
 
     @property
     def num_ssm_layers(self) -> int:
-        """Layers that keep a matrix state a head (the fourth pool)."""
+        """Layers with a Mamba-2 mixer beside attention."""
         return sum(k.startswith("mix+") for k in self.layer_kinds or ())
+
+    @property
+    def num_kda_layers(self) -> int:
+        """Delta-rule linear-attention layers."""
+        return sum(k.startswith("kda+") for k in self.layer_kinds or ())
+
+    @property
+    def state_shape(self) -> Tuple[int, int, int]:
+        """A layer's state of one sequence in the pool by slot: (heads,
+        sublane axis, lane axis). A mixer's matrix is kept [state, head
+        width], a delta-rule head's [key channel, value channel]."""
+        if self.num_kda_layers:
+            return (self.kda_heads, self.kda_head_dim, self.kda_head_dim)
+        return (self.ssm_heads, self.ssm_state, self.ssm_head_dim)
+
+    @property
+    def kda_inner(self) -> int:
+        """Width of a delta-rule layer's q, k and v: heads x head width."""
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def ring_channels(self) -> int:
+        """Channels of the filter ring a state layer keeps a page: a
+        mixer's x | B | C, a delta-rule layer's q | k | v."""
+        if self.num_kda_layers:
+            return 3 * self.kda_inner
+        return self.ssm_conv_dim
+
+    @property
+    def router_experts(self) -> int:
+        """Outputs of a sparse layer's router: the experts of the whole
+        deployment, of which this chip holds ``num_experts``."""
+        return self.num_experts * self.expert_share_chips
+
+    @property
+    def first_held_expert(self) -> int:
+        return self.num_experts * self.expert_share_rank
 
     @property
     def ssm_inner(self) -> int:
@@ -370,13 +464,13 @@ class ModelConfig:
     def conv_tail_width(self) -> int:
         """Values in one row of the pool of convolution tails. A "conv"
         layer keeps its last conv_kernel - 1 gated inputs over
-        hidden_size channels; a mixer keeps a RING of conv_kernel inputs
-        over its ssm_conv_dim channels, the input at position t in row
-        t mod conv_kernel, so that a decode step writes one ring row
-        and never one it reads (transformer, "A mixer beside
-        attention")."""
-        if self.num_ssm_layers:
-            return self.conv_kernel * self.ssm_conv_dim
+        hidden_size channels; a state layer (a mixer, a delta-rule
+        layer) keeps a RING of conv_kernel inputs over its
+        ``ring_channels``, the input at position t in row t mod
+        conv_kernel, so that a decode step writes one ring row and never
+        one it reads (transformer, "A mixer beside attention")."""
+        if self.num_state_layers:
+            return self.conv_kernel * self.ring_channels
         return max(self.conv_kernel - 1, 1) * self.hidden_size
 
     @property
@@ -610,7 +704,7 @@ class ModelConfig:
                      "qwen2_vl", "qwen2_5_vl",
                      "qwen3_moe", "deepseek_v2", "deepseek_v3",
                      "joyai_llm_flash", "gpt_oss", "lfm2_moe", "ouro",
-                     "falcon_h1")
+                     "falcon_h1", "solar_open2")
         # The latent family: DeepSeek-V2, and the V3 layer (sigmoid
         # scores, selection bias) that JD's JoyAI-LLM-Flash shares.
         _v3 = mt in ("deepseek_v3", "joyai_llm_flash")
@@ -729,6 +823,38 @@ class ModelConfig:
                     f"implemented (only mamba_n_heads x mamba_d_head = "
                     f"{d['mamba_n_heads'] * d['mamba_d_head']})")
             layer_kinds = ("mix+dense",) * d["num_hidden_layers"]
+        _so2 = mt == "solar_open2"
+        if _so2:
+            # Upstage Solar-Open2: delta-rule linear-attention layers
+            # (Kimi Delta Attention) between gated grouped-query
+            # attention layers that rotate nothing, routed experts in
+            # every layer. What this loop has no body for is refused, by
+            # the key that asks for it.
+            for key, want in (("first_k_dense_replace", 0),
+                              ("kda_use_full_proj", False),
+                              ("kda_allow_neg_eigval", True),
+                              ("use_rope", False),
+                              ("use_gqa_gate", True),
+                              ("n_shared_experts", 1),
+                              ("attention_bias", False)):
+                if d.get(key, want) != want:
+                    raise ValueError(
+                        f"solar_open2 with {key}={d[key]!r} is not "
+                        f"implemented (only {want!r})")
+            la = d["linear_attn_config"]
+            if la.get("num_kv_heads") not in (None, la["num_heads"]):
+                raise ValueError(
+                    f"solar_open2 with linear_attn_config.num_kv_heads="
+                    f"{la['num_kv_heads']!r} is not implemented (only as "
+                    f"many as num_heads)")
+            gqa = set(d["gqa_layers"])
+            layer_kinds = tuple(
+                ("attn" if i in gqa else "kda") + "+moe"
+                for i in range(d["num_hidden_layers"]))
+            # n_routed_experts counts the experts HELD here, of
+            # ``expert_share_chips`` times as many routed (the
+            # deployment's share: the program's own two keys beside the
+            # published ones, 1 and 0 where a config has neither).
         if mt == "ouro" and set(d.get("layer_types") or ()) \
                 - {"full_attention"}:
             raise ValueError(
@@ -853,7 +979,7 @@ class ModelConfig:
                                   if mt == "gemma3_text" else None),
             num_experts=(d.get("num_experts", 0)
                          if mt in ("qwen3_moe", "lfm2_moe")
-                         else d.get("n_routed_experts", 0) if _dsk
+                         else d.get("n_routed_experts", 0) if _dsk or _so2
                          else d.get("num_local_experts", 0)),
             num_experts_per_tok=d.get("num_experts_per_tok", 2),
             moe_intermediate_size=d.get("moe_intermediate_size"),
@@ -862,8 +988,8 @@ class ModelConfig:
             qk_nope_head_dim=d.get("qk_nope_head_dim", 0) if _dsk else 0,
             qk_rope_head_dim=d.get("qk_rope_head_dim", 0) if _dsk else 0,
             v_head_dim=d.get("v_head_dim", 0) if _dsk else 0,
-            n_shared_experts=(d.get("n_shared_experts") or 0) if _dsk
-            else 0,
+            n_shared_experts=(d.get("n_shared_experts") or 0)
+            if _dsk or _so2 else 0,
             routed_scaling_factor=d.get("routed_scaling_factor", 1.0),
             # V3's "noaux_tc" IS grouped selection under sigmoid scoring;
             # with one group (JoyAI-LLM-Flash) nothing is limited.
@@ -877,11 +1003,25 @@ class ModelConfig:
             # LFM2's gate is V3's with one group: sigmoid scores, a bias
             # that shapes the choice only (use_expert_bias; zeros without
             # it), the chosen scores over their sum + 1e-6.
-            moe_scoring="sigmoid" if _v3 or _lfm else "softmax",
+            moe_scoring="sigmoid" if _v3 or _lfm or _so2 else "softmax",
             moe_gate_eps=1e-6 if _lfm else 1e-20,
             layer_kinds=layer_kinds,
             conv_kernel=(int(d.get("conv_L_cache", 0)) if _lfm
-                         else int(d["mamba_d_conv"]) if _fh1 else 0),
+                         else int(d["mamba_d_conv"]) if _fh1
+                         else int(d["linear_attn_config"]
+                                  ["short_conv_kernel_size"]) if _so2
+                         else 0),
+            **({"kda_heads": int(d["linear_attn_config"]["num_heads"]),
+                "kda_head_dim": int(d["linear_attn_config"]["head_dim"]),
+                # kda_use_full_proj false: the decay's and the output
+                # gate's projections are low-rank pairs, rank = head_dim
+                # (Kimi Linear's form).
+                "kda_gate_rank": int(d["linear_attn_config"]["head_dim"]),
+                "kda_beta_scale": 2.0,
+                "use_rope": False, "attn_gate": True,
+                "expert_share_chips": int(d.get("expert_share_chips", 1)),
+                "expert_share_rank": int(d.get("expert_share_rank", 0))}
+               if _so2 else {}),
             **({"ssm_heads": int(d["mamba_n_heads"]),
                 "ssm_head_dim": int(d["mamba_d_head"]),
                 "ssm_state": int(d["mamba_d_state"]),

@@ -149,8 +149,8 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                   dtype: Optional[jnp.dtype] = None,
                   state_slots: int = 0) -> KVCache:
     """The pools. ``state_slots``: slots of the fourth pool, which a model
-    with a mixer beside attention keeps (``cfg.num_ssm_layers``) and no
-    other does."""
+    with layers that keep a matrix state keeps (``cfg.num_state_layers``:
+    a mixer beside attention, a delta-rule layer) and no other does."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     if cfg.layer_kinds is not None:
         # Keys and values of the ATTENTION layers alone, and a third
@@ -165,7 +165,7 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                  cfg.conv_tail_width)
         pools = (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
                  jnp.zeros(tails, dtype))
-        if cfg.num_ssm_layers:
+        if cfg.num_state_layers:
             # A fourth pool, addressed by SLOT and not by page: a layer's
             # state of one sequence is heads x head width x state values
             # in float32 (4.2 MB at 32 x 128 x 256), sixteen times the
@@ -175,10 +175,12 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
             # head's matrix is stored [state, head width]: the head
             # width rides the lanes, as the row's x and y do, so the
             # decode kernel broadcasts its operands along an axis they
-            # already lack (ops/pallas/ssm_update.py).
+            # already lack (ops/pallas/ssm_update.py). A delta-rule
+            # head's is [key channel, value channel]
+            # (``cfg.state_shape``; ops/pallas/kda_update.py).
             pools += (jnp.zeros(
-                (cfg.num_ssm_layers, max(state_slots, 2), cfg.ssm_heads,
-                 cfg.ssm_state, cfg.ssm_head_dim), jnp.float32),)
+                (cfg.num_state_layers, max(state_slots, 2))
+                + cfg.state_shape, jnp.float32),)
         return pools
     # One slot a layer a PASS (``ModelConfig.kv_cache_layers``): pass p
     # of a looped model keeps layer l's keys and values at p * L + l.
@@ -1270,7 +1272,11 @@ def moe_stats_shape(cfg: ModelConfig) -> Tuple[int, ...]:
     from xllm_service_tpu.parallel.expert import MOE_STATS
     if cfg.looped:
         return (cfg.total_ut_steps + 2,)        # ``_dense_stats``' vector
-    return (len(MOE_STATS),) if cfg.dropless_experts else ()
+    if not cfg.dropless_experts:
+        return ()
+    # ``elsewhere``, the last, rides the vector under a held share alone
+    share = cfg.router_experts > cfg.num_experts
+    return (len(MOE_STATS) if share else len(MOE_STATS) - 1,)
 
 
 def _moe_stats_dict(moe_stats: jnp.ndarray) -> Dict[str, jnp.ndarray]:
@@ -1322,7 +1328,8 @@ def _dropless_moe_mlp(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
         x.reshape(B * T, D), topi.reshape(B * T, k),
         topw.reshape(B * T, k), vf, experts["gate_proj"],
         experts["up_proj"], experts["down_proj"], layer=layer,
-        kernel=plan.expert_gmm, interpret=plan.interpret)
+        kernel=plan.expert_gmm, interpret=plan.interpret,
+        first_held=cfg.first_held_expert, routed=cfg.router_experts)
     shared = (jax.nn.silu(x @ lp["shared_gate"]) * (x @ lp["shared_up"])) \
         @ lp["shared_down"] if "shared_gate" in lp else 0.0
     return routed.reshape(B, T, D) + shared, stats
@@ -1668,7 +1675,21 @@ def _init_kinds_params(cfg: ModelConfig, key: jax.Array,
                       ssm_d=jnp.ones((n, Hs), jnp.float32),
                       ssm_norm=jnp.ones((n, I), dtype),
                       ssm_out=w((n, I, D), I))
-        if op != "conv":
+        if op == "kda":
+            Hk, Dk, I = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_inner
+            R = cfg.kda_gate_rank
+            # q | k | v in ONE matrix (one product, one filter, one ring
+            # row); the decay's and the output gate's low-rank pairs.
+            st.update(kda_qkv=w((n, D, 3 * I), D),
+                      kda_conv_w=w((n, K, 3 * I), K),
+                      kda_f_down=w((n, D, R), D), kda_f_up=w((n, R, I), R),
+                      kda_dt_bias=jnp.zeros((n, I), jnp.float32),
+                      kda_a_log=jnp.zeros((n, Hk), jnp.float32),
+                      kda_beta=w((n, D, Hk), D),
+                      kda_g_down=w((n, D, R), D), kda_g_up=w((n, R, I), R),
+                      kda_norm=jnp.ones((n, Dk), dtype),
+                      kda_out=w((n, I, D), I))
+        if op in ("attn", "mix"):
             st.update(q_proj=w((n, D, Hq * Dh), D),
                       k_proj=w((n, D, Hkv * Dh), D),
                       v_proj=w((n, D, Hkv * Dh), D),
@@ -1676,12 +1697,22 @@ def _init_kinds_params(cfg: ModelConfig, key: jax.Array,
             if cfg.qk_norm:
                 st.update(q_norm=jnp.ones((n, Dh), dtype),
                           k_norm=jnp.ones((n, Dh), dtype))
+            if cfg.attn_gate:
+                st.update(attn_gate=w((n, D, Hq * Dh), D))
         if ffn == "moe":
-            st.update(router=w((n, D, E), D),
-                      router_bias=jnp.zeros((n, E), jnp.float32),
+            # the router is as wide as the deployment's experts, the
+            # stacks hold the share held here (all of them: E_r == E)
+            E_r = cfg.router_experts
+            st.update(router=w((n, D, E_r), D),
+                      router_bias=jnp.zeros((n, E_r), jnp.float32),
                       gate_proj=w((n, E, D, Fe), D),
                       up_proj=w((n, E, D, Fe), D),
                       down_proj=w((n, E, Fe, D), Fe))
+            if cfg.n_shared_experts:
+                Fs = cfg.n_shared_experts * Fe
+                st.update(shared_gate=w((n, D, Fs), D),
+                          shared_up=w((n, D, Fs), D),
+                          shared_down=w((n, Fs, D), Fs))
         else:
             st.update(gate_proj=w((n, D, F), D), up_proj=w((n, D, F), D),
                       down_proj=w((n, F, D), F))
@@ -1691,6 +1722,15 @@ def _init_kinds_params(cfg: ModelConfig, key: jax.Array,
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w((D, cfg.vocab_size), D)
     return params
+
+
+# What a layer's operator KEEPS between steps, and so which ranks it
+# advances: keys and values ("attn": the (k, v) pools' leading axis), a
+# convolution tail or filter ring ("conv": the tails pool's), a matrix
+# state by slot ("state": the fourth pool's).
+_RANKS = ("attn", "conv", "state")
+_KEEPS = {"attn": ("attn",), "conv": ("conv",),
+          "mix": ("attn", "conv", "state"), "kda": ("conv", "state")}
 
 
 def _runs(kinds) -> Tuple[Tuple[str, int], ...]:
@@ -1726,24 +1766,28 @@ def kinds_pattern(kinds: Tuple[str, ...]) -> Tuple[int, int, int]:
 
 def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                   pools, conv_op, attn_op, valid: jnp.ndarray,
-                  plan: KernelPlan, mix_op=None):
+                  plan: KernelPlan, mix_op=None, kda_op=None):
     """The layer loop over ``cfg.layer_kinds``. ``pools`` = (k, v,
-    tails) and, for a model with mixers, the pool of states after them,
-    carried and updated in place; ``conv_op(lp, h, tails, c) -> (y,
-    tails)``, ``attn_op(lp, h, k, v, a) -> (y, k, v)`` and ``mix_op(lp,
-    h, pools, a, c) -> (y, pools)`` (attention and a mixer on the same
-    input) are the caller's (prefill's or decode's), ``a`` / ``c`` the
-    layer's index among the layers that keep keys and values / a
-    convolution tail. Returns ``(x, pools, moe_stats)``."""
+    tails) and, for a model with state layers, the pool of states after
+    them, carried and updated in place; ``conv_op(lp, h, tails, c) ->
+    (y, tails)``, ``attn_op(lp, h, k, v, a) -> (y, k, v)``, ``mix_op(lp,
+    h, pools, a, c, r) -> (y, pools)`` (attention and a mixer on the
+    same input) and ``kda_op(lp, h, pools, c, r) -> (y, pools)`` (a
+    delta-rule layer: a ring and a state, no keys and values) are the
+    caller's (prefill's or decode's). A layer has a rank among the
+    layers that KEEP what it keeps: ``a`` keys and values, ``c`` a
+    convolution tail or ring, ``r`` a matrix state. Returns ``(x,
+    pools, moe_stats)``."""
     kinds = cfg.layer_kinds
     lead, period, repeats = kinds_pattern(kinds)
 
     def tally(span) -> Dict[str, int]:
-        # how many layers of each kind, layers that attend ("attn") and
-        # layers that keep a convolution tail ("conv") ``span`` holds
+        # how many layers of each kind ``span`` holds, and how many that
+        # keep keys and values ("attn"), a convolution tail or ring
+        # ("conv"), a matrix state ("state")
         r = {k: span.count(k) for k in set(kinds)}
-        r["attn"] = sum(k.startswith(("attn+", "mix+")) for k in span)
-        r["conv"] = sum(k.startswith(("conv+", "mix+")) for k in span)
+        for rank in _RANKS:
+            r[rank] = sum(rank in _KEEPS[k.split("+")[0]] for k in span)
         return r
 
     def body(kind: str):
@@ -1752,9 +1796,10 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         small, experts = _split_experts(stack) if ffn == "moe" \
             else (stack, None)
 
-        def layer(carry, s, a, c):
+        def layer(carry, s, a, c, r):
             """Layer ``s`` of this kind's stack, the ``a``-th that
-            attends and the ``c``-th that keeps a tail."""
+            attends, the ``c``-th that keeps a tail and the ``r``-th
+            that keeps a state."""
             x, pools, stats = carry[0], carry[1:-1], carry[-1]
             lp = jax.tree_util.tree_map(
                 lambda w: jax.lax.dynamic_index_in_dim(
@@ -1766,8 +1811,10 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             elif op == "attn":
                 y, kp, vp = attn_op(lp, h, pools[0], pools[1], a)
                 pools = (kp, vp) + pools[2:]
+            elif op == "mix":
+                y, pools = mix_op(lp, h, pools, a, c, r)
             else:
-                y, pools = mix_op(lp, h, pools, a, c)
+                y, pools = kda_op(lp, h, pools, c, r)
             x = x + y
             h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
             if ffn == "moe":
@@ -1793,18 +1840,18 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         at = dict(at)
         for kind, count in _runs(span):
             op = kind.split("+")[0]
-            s0, a0, c0 = (at[k] + step.get(k, 0) * r
-                          for k in (kind, "attn", "conv"))
+            first = tuple(at[k] + step.get(k, 0) * r
+                          for k in (kind,) + _RANKS)
             layer = body(kind)
             if count == 1:
-                carry = layer(carry, s0, a0, c0)
+                carry = layer(carry, *first)
             else:
                 carry, _ = jax.lax.scan(
-                    lambda cr, j, layer=layer, s0=s0, a0=a0, c0=c0:
-                    (layer(cr, s0 + j, a0 + j, c0 + j), None),
+                    lambda cr, j, layer=layer, first=first:
+                    (layer(cr, *(f + j for f in first)), None),
                     carry, jnp.arange(count, dtype=jnp.int32))
             at[kind] += count
-            for rank in (("attn", "conv") if op == "mix" else (op,)):
+            for rank in _KEEPS[op]:
                 at[rank] += count
         return carry
 
@@ -2072,16 +2119,32 @@ def _ssm_step(cfg: ModelConfig, state, c, read, write, x, dt, A, Bm, Cm,
     return y, state.at[c, write].set(S)
 
 
+# A row of the pool of tails from which on ``_ring_read`` takes one slice
+# a row instead of a gather.
+_WIDE_ROW_BYTES = 64 << 10
+
+
 def _ring_read(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
                positions: jnp.ndarray, ps: int) -> jnp.ndarray:
     """[B, K-1, C]: the mixer's convolution inputs at the K - 1 positions
     before ``positions`` [B], oldest first, from the ring of the page
     that holds the position before; zeros before a sequence's start."""
-    K, C = cfg.conv_kernel, cfg.ssm_conv_dim
+    K, C = cfg.conv_kernel, cfg.ring_channels
     before = jnp.maximum(positions - 1, 0)
     pid = jnp.take_along_axis(page_table, (before // ps)[:, None],
                               axis=1)[:, 0]
-    ring = tails[c, pid].reshape(-1, K, C)
+    if tails.shape[-1] * tails.dtype.itemsize < _WIDE_ROW_BYTES:
+        ring = tails[c, pid]
+    else:
+        # One slice a row out of the whole pool, as ``_state_rows``: a
+        # gather of rows this wide (196 KB over q | k | v of 3 x 8192)
+        # the compiler splits over thirds of the WHOLE pool, copied
+        # first (1.1 GB a layer in every program of two rows or more;
+        # compiled for a described v5e, PERF.md, PR 49).
+        ring = jnp.concatenate([jax.lax.dynamic_slice(
+            tails, (c, pid[b], 0), (1, 1, tails.shape[-1]))[0]
+            for b in range(pid.shape[0])])
+    ring = ring.reshape(-1, K, C)
     at = positions[:, None] - (K - 1) \
         + jnp.arange(K - 1, dtype=jnp.int32)[None, :]            # [B, K-1]
     rows = jnp.take_along_axis(ring, jnp.mod(at, K)[:, :, None], axis=1)
@@ -2131,6 +2194,165 @@ def _mixer(cfg: ModelConfig, lp, h, prev, valid, scan):
     y, extra = scan(x, dt, -jnp.exp(lp["ssm_a_log"]), Bm, Cm)
     y = y + lp["ssm_d"][:, None] * x
     return _ssm_out(cfg, lp, y, z), zz, extra
+
+
+# ---------------------------------------------------------------------------
+# A delta-rule linear-attention layer (operator "kda": Kimi Delta
+# Attention; Solar-Open2)
+#
+# The layer's operator INSTEAD of attention: it keeps no keys and values.
+# A head's state is a matrix ``S [key channel, value channel]`` in
+# float32 in the pool by slot, moved a token by
+#
+#     S' = Diag(alpha_t) S_{t-1}          (a decay a KEY CHANNEL)
+#     r  = v_t - S'^T k_t                 (what the state says of k_t)
+#     S_t = S' + beta_t k_t r^T           (the rank-one correction)
+#     o_t = S_t^T q_t
+#
+# with q, k, v through a causal depthwise filter and SiLU, q and k
+# l2-normed a head, alpha_t = exp(g_t) in (0, 1) from a low-rank pair
+# and beta_t in (0, kda_beta_scale). It is NOT the mixer's recurrence:
+# the correction READS the state. Slots, snapshots, the two slots a row
+# by parity and the ring of filter inputs (here over q | k | v) are the
+# mixer's, above.
+#
+# Prefill runs the chunked form in XLA, chunks of up to ``_KDA_CHUNK``
+# positions that divide window and page (so a page boundary is a chunk's
+# end; 32 at a page of 128: the [Q, Q, channels] decay product a head a
+# chunk is what sizes a window's temporaries, 1.3 GB at 16 rows of 256,
+# compiled for a described v5e, PR 49). Within a
+# chunk that starts from S_0, with G_t the log-decay summed from the
+# chunk's first position through t (a channel):
+#
+#     w_t = beta_t (v_t - S_0^T (e^{G_t} k_t)) - beta_t sum_{i<t} A_ti w_i
+#     A_ti = sum_c k_t[c] k_i[c] e^{G_t[c] - G_i[c]}
+#
+# a unit lower-triangular system in the rows w_t (one triangular solve a
+# chunk), then o_t = S_0^T (e^{G_t} q_t) + sum_{i<=t} (q_t . k_i under
+# the same decay) w_i and S_Q = Diag(e^{G_Q}) S_0 + sum_i (e^{G_Q - G_i}
+# k_i) w_i^T. Every exponent is a DIFFERENCE G_t - G_i <= 0 with i <= t,
+# or G_t itself: never exp(+G), which a channel that decays by 0.3 a
+# step takes past float32's range inside one chunk.
+# ---------------------------------------------------------------------------
+
+def _kda_in(cfg: ModelConfig, lp, h: jnp.ndarray, prev: jnp.ndarray,
+            valid: jnp.ndarray):
+    """A delta-rule layer's operands of h [B, T, D] with the K - 1
+    filter inputs before it, ``prev`` [B, K-1, 3I]: ``(q, k, v [B, T, H,
+    Dk], g [B, T, H, Dk] (log-decay, 0 where ``valid`` [B, T] is not),
+    beta [B, T, H] (0 there too), zz [B, K-1+T, 3I])``, float32."""
+    B, T, _ = h.shape
+    K, H, Dk = cfg.conv_kernel, cfg.kda_heads, cfg.kda_head_dim
+    f32 = jnp.float32
+    qkv = h @ lp["kda_qkv"]
+    zz = jnp.concatenate([prev.astype(qkv.dtype), qkv], axis=1)
+    w = lp["kda_conv_w"].astype(f32)                          # [K, 3I]
+    u = jax.nn.silu(sum(w[j] * zz[:, j:j + T].astype(f32)
+                        for j in range(K)))
+    q, k, v = (a.reshape(B, T, H, Dk) for a in jnp.split(u, 3, axis=-1))
+
+    def l2(a):
+        return a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True)
+                                 + 1e-6)
+    f = ((h @ lp["kda_f_down"]) @ lp["kda_f_up"]).astype(f32)
+    g = -jnp.exp(lp["kda_a_log"])[:, None] * jax.nn.softplus(
+        f + lp["kda_dt_bias"]).reshape(B, T, H, Dk)
+    beta = cfg.kda_beta_scale * jax.nn.sigmoid(
+        (h @ lp["kda_beta"]).astype(f32))
+    return (l2(q) * Dk ** -0.5, l2(k), v,
+            jnp.where(valid[..., None, None], g, 0.0),
+            jnp.where(valid[..., None], beta, 0.0), zz)
+
+
+def _kda_out(cfg: ModelConfig, lp, o: jnp.ndarray, h: jnp.ndarray):
+    """o [B, T, H, Dv] float32 normed a head, times the norm's weight and
+    the sigmoid of the low-rank output gate of h, projected to [B, T, D]."""
+    B, T = o.shape[:2]
+    gate = ((h @ lp["kda_g_down"]) @ lp["kda_g_up"]).astype(jnp.float32)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + cfg.rms_norm_eps)
+    o = o * lp["kda_norm"].astype(jnp.float32) \
+        * jax.nn.sigmoid(gate).reshape(o.shape)
+    return o.reshape(B, T, -1).astype(h.dtype) @ lp["kda_out"]
+
+
+_KDA_CHUNK = 32
+
+
+def _kda_scan(q, k, v, g, beta, S0, snap_len, ps: int):
+    """The chunked delta rule over a window: q, k, v, g [B, T, H, Dk],
+    beta [B, T, H] (g and beta 0 where a position must not move the
+    state), S0 [B, H, Dk, Dv]; float32; ``ps`` the page size. Returns
+    ``(o [B, T, H, Dv], S, S_snap)``: the state after the window's last
+    position, and the state after ``snap_len`` [B] of its tokens (a
+    page boundary of a window that starts on one, so a chunk's end; S0
+    where none is)."""
+    B, T, H, Dk = q.shape
+    Q = math.gcd(T, ps, _KDA_CHUNK)
+    nc = T // Q
+
+    def chunks(a):          # [B, T, H, ...] -> [nc, B, H, Q, ...]
+        a = a.reshape((B, nc, Q) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    t_i = jnp.arange(Q)
+    upto = t_i[:, None] >= t_i[None, :]                     # i <= t
+    before = t_i[:, None] > t_i[None, :]                    # i < t
+
+    def mm(eq, a, b):
+        return jnp.einsum(eq, a, b, precision=_HIGHEST)
+
+    def step(carry, inp):
+        S, S_snap = carry
+        j, qc, kc, vc, gc, bc = inp
+        G = jnp.cumsum(gc, axis=2)                          # [B, H, Q, Dk]
+        # decay from i to t, a channel: only ever for i <= t
+        dec = jnp.exp(jnp.where(upto[:, :, None],
+                                G[:, :, :, None] - G[:, :, None, :],
+                                -jnp.inf))                  # [B,H,Q,Q,Dk]
+        kd = kc[:, :, None, :] * dec
+        A = jnp.sum(kc[:, :, :, None] * kd, axis=-1)        # [B, H, Qt, Qi]
+        P = jnp.sum(qc[:, :, :, None] * kd, axis=-1)
+        eG = jnp.exp(G)
+        M = jnp.where(before, bc[..., None] * A, 0.0) \
+            + jnp.eye(Q, dtype=A.dtype)
+        rhs = bc[..., None] * (vc - mm("bhqk,bhkv->bhqv", eG * kc, S))
+        W = jax.scipy.linalg.solve_triangular(M, rhs, lower=True,
+                                              unit_diagonal=True)
+        o = mm("bhqk,bhkv->bhqv", eG * qc, S) \
+            + mm("bhqi,bhiv->bhqv", P, W)       # P is 0 where i > t
+        last = G[:, :, -1:]                                 # [B, H, 1, Dk]
+        S = jnp.swapaxes(jnp.exp(last), 2, 3) * S \
+            + mm("bhqk,bhqv->bhkv", jnp.exp(last - G) * kc, W)
+        S_snap = jnp.where(((j + 1) * Q == snap_len)[:, None, None, None],
+                           S, S_snap)
+        return (S, S_snap), o
+
+    (S, S_snap), o = jax.lax.scan(
+        step, (S0, S0),
+        (jnp.arange(nc, dtype=jnp.int32), chunks(q), chunks(k), chunks(v),
+         chunks(g), chunks(beta)))
+    # [nc, B, H, Q, Dv] -> [B, T, H, Dv]
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)
+    return o.reshape(B, T, H, -1), S, S_snap
+
+
+def _kda_step(cfg: ModelConfig, state, r, read, write, q, k, v, g, beta,
+              plan: KernelPlan):
+    """One token a row, in place in the pool: reads layer ``r``'s slots
+    ``read`` [B], writes ``write`` [B]. q, k, v, g [B, H, Dk], beta
+    [B, H] (g and beta 0 on an inactive row, whose slots are the null
+    slot). Returns ``(o [B, H, Dv], state)``."""
+    if plan.ssm_decode:
+        from xllm_service_tpu.ops.pallas.kda_update import kda_decode_update
+        return kda_decode_update(state, r, read, write, q, k, v,
+                                 jnp.exp(g), beta, interpret=plan.interpret)
+    S = jax.lax.dynamic_index_in_dim(state, r, axis=0, keepdims=False)[read]
+    S = jnp.exp(g)[..., None] * S
+    res = v - jnp.einsum("bhkv,bhk->bhv", S, k, precision=_HIGHEST)
+    S = S + (beta[..., None] * k)[..., None] * res[:, :, None, :]
+    o = jnp.einsum("bhkv,bhk->bhv", S, q, precision=_HIGHEST)
+    return o, state.at[r, write].set(S)
 
 
 def _kv_pack(cfg: ModelConfig) -> int:
@@ -2205,7 +2427,12 @@ def _attn_in(cfg: ModelConfig, lp, h: jnp.ndarray):
     return q, k, v
 
 
-def _attn_out(cfg: ModelConfig, lp, attn: jnp.ndarray):
+def _attn_out(cfg: ModelConfig, lp, attn: jnp.ndarray, h: jnp.ndarray):
+    """attention's output [B, T, Hq * Dh] projected back, under the
+    sigmoid gate of the layer's normed input ``h`` where the family has
+    one (``cfg.attn_gate``)."""
+    if cfg.attn_gate:
+        attn = attn * jax.nn.sigmoid(h @ lp["attn_gate"])
     out = attn @ lp["o_proj"]
     if cfg.attention_out_multiplier != 1.0:
         out = out * jnp.asarray(cfg.attention_out_multiplier, out.dtype)
@@ -2238,8 +2465,9 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
 
     def attn_op(lp, h, kp, vp, a):
         q, k, v = _attn_in(cfg, lp, h)
-        q = rope_for(cfg.rope_scaling, q, positions, cfg.rope_theta)
-        k = rope_for(cfg.rope_scaling, k, positions, cfg.rope_theta)
+        if cfg.use_rope:
+            q = rope_for(cfg.rope_scaling, q, positions, cfg.rope_theta)
+            k = rope_for(cfg.rope_scaling, k, positions, cfg.rope_theta)
         q, k, v, unpack = _packed_qkv(cfg, q, k, v)
         # The window's keys and values into the pool first, then
         # attention reads cached prefix and window alike from the pool.
@@ -2257,15 +2485,40 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
                 q, gather_layer_pages(kp, a, page_table),
                 gather_layer_pages(vp, a, page_table),
                 kv_lengths, start_pos, scale=scale)
-        return _attn_out(cfg, lp, unpack(attn).reshape(B, T, -1)), kp, vp
+        return _attn_out(cfg, lp, unpack(attn).reshape(B, T, -1), h), \
+            kp, vp
 
-    def mix_op(lp, h, pools, a, c):
-        kp, vp, tails, state = pools
-        ya, kp, vp = attn_op(lp, h, kp, vp, a)
+    def state_io(state, r):
+        """The window's state slots of layer ``r`` of the pool by slot:
+        ``(S0, snap_len, put)``, ``put(state, S, S_snap) -> state``."""
         src, dst, snap, snap_len = (state_cols[:, i] for i in range(4))
         n_slots = state.shape[1]
         S0 = jnp.where((src > 0)[:, None, None, None],
-                       _state_rows(state, c, src), 0.0)
+                       _state_rows(state, r, src), 0.0)
+
+        def put(state, S, S_snap):
+            # a slot past the pool: nothing is written (mode="drop")
+            state = state.at[r, jnp.where(lengths > 0, dst, n_slots)].set(
+                S, mode="drop")
+            return state.at[r, jnp.where(snap > 0, snap, n_slots)].set(
+                S_snap, mode="drop")
+        return S0, snap_len, put
+
+    def kda_op(lp, h, pools, c, r):
+        kp, vp, tails, state = pools
+        S0, snap_len, put = state_io(state, r)
+        q, k, v, g, beta, zz = _kda_in(cfg, lp, h, _ring_read(
+            cfg, tails, c, page_table, start_pos, ps), tok_valid)
+        o, S, S_snap = _kda_scan(q, k, v, g, beta, S0, snap_len, ps)
+        tails = _ring_write(cfg, tails, c, page_table, start_pos, lengths,
+                            zz, ps)
+        return _kda_out(cfg, lp, o, h), (kp, vp, tails,
+                                         put(state, S, S_snap))
+
+    def mix_op(lp, h, pools, a, c, r):
+        kp, vp, tails, state = pools
+        ya, kp, vp = attn_op(lp, h, kp, vp, a)
+        S0, snap_len, put = state_io(state, r)
 
         def scan(x_s, dt, A, Bm, Cm):
             # The state a later request resumes from: the window's,
@@ -2282,15 +2535,10 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
             cfg, tails, c, page_table, start_pos, ps), tok_valid, scan)
         tails = _ring_write(cfg, tails, c, page_table, start_pos, lengths,
                             zz, ps)
-        # a slot past the pool: nothing is written (mode="drop")
-        state = state.at[c, jnp.where(lengths > 0, dst, n_slots)].set(
-            S, mode="drop")
-        state = state.at[c, jnp.where(snap > 0, snap, n_slots)].set(
-            S_snap, mode="drop")
-        return ya + ym, (kp, vp, tails, state)
+        return ya + ym, (kp, vp, tails, put(state, S, S_snap))
 
     x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
-                                     tok_valid, plan, mix_op)
+                                     tok_valid, plan, mix_op, kda_op)
     x, head = _kinds_head(params, cfg, x)
     last_idx = jnp.maximum(lengths - 1, 0)
     last_x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
@@ -2327,8 +2575,9 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
 
     def attn_op(lp, h, kp, vp, a):
         q, k, v = _attn_in(cfg, lp, h)
-        q = rope_for(cfg.rope_scaling, q, pos2, cfg.rope_theta)
-        k = rope_for(cfg.rope_scaling, k, pos2, cfg.rope_theta)
+        if cfg.use_rope:
+            q = rope_for(cfg.rope_scaling, q, pos2, cfg.rope_theta)
+            k = rope_for(cfg.rope_scaling, k, pos2, cfg.rope_theta)
         q, k, v, unpack = _packed_qkv(cfg, q, k, v)
         kp, vp = write_decode_kv_layer(kp, vp, k[:, 0], v[:, 0],
                                        page_table, positions, active, a,
@@ -2337,19 +2586,34 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
             q[:, 0], kp, vp, page_table,
             jnp.where(active, positions + 1, 0), plan, scale=scale,
             layer=a)
-        return _attn_out(cfg, lp, unpack(attn).reshape(B, 1, -1)), kp, vp
+        return _attn_out(cfg, lp, unpack(attn).reshape(B, 1, -1), h), \
+            kp, vp
 
-    def mix_op(lp, h, pools, a, c):
-        kp, vp, tails, state = pools
-        ya, kp, vp = attn_op(lp, h, kp, vp, a)
+    def slots():
         # The state as of position t is in the row's slot t mod 2: read
         # the other one, write this one (an inactive lane: the null slot).
         mine = 2 * state_rows - 1
-        read = jnp.where(active, mine + (positions + 1) % 2, 0)
-        write = jnp.where(active, mine + positions % 2, 0)
+        return (jnp.where(active, mine + (positions + 1) % 2, 0),
+                jnp.where(active, mine + positions % 2, 0))
+
+    def kda_op(lp, h, pools, c, r):
+        kp, vp, tails, state = pools
+        read, write = slots()
+        q, k, v, g, beta, zz = _kda_in(cfg, lp, h, _ring_read(
+            cfg, tails, c, page_table, positions, ps), active[:, None])
+        o, state = _kda_step(cfg, state, r, read, write, q[:, 0], k[:, 0],
+                             v[:, 0], g[:, 0], beta[:, 0], plan)
+        tails = _ring_write(cfg, tails, c, page_table, positions, one, zz,
+                            ps)
+        return _kda_out(cfg, lp, o[:, None], h), (kp, vp, tails, state)
+
+    def mix_op(lp, h, pools, a, c, r):
+        kp, vp, tails, state = pools
+        ya, kp, vp = attn_op(lp, h, kp, vp, a)
+        read, write = slots()
 
         def scan(x_s, dt, A, Bm, Cm):
-            y, moved = _ssm_step(cfg, state, c, read, write, x_s[:, 0],
+            y, moved = _ssm_step(cfg, state, r, read, write, x_s[:, 0],
                                  dt[:, 0], A, Bm[:, 0], Cm[:, 0], plan)
             return y[:, None], moved
 
@@ -2361,7 +2625,7 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
         return ya + ym, (kp, vp, tails, state)
 
     x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
-                                     active[:, None], plan, mix_op)
+                                     active[:, None], plan, mix_op, kda_op)
     x, head = _kinds_head(params, cfg, x)
     logits = _kinds_logits(cfg, x[:, 0], head)
     if return_stats:

@@ -45,10 +45,11 @@ class KernelPlan:
     # the dropless expert layer's grouped matmuls: the Pallas one
     # (ops/pallas/grouped_matmul.py), else XLA's jax.lax.ragged_dot
     expert_gmm: bool = False
-    # a mixer's one-token state update (ops/pallas/ssm_update.py), in
-    # place in the pool of states by slot; else XLA's gather and scatter
-    # of the rows' states. (Its prefill is the chunked scan in XLA
-    # einsums under every plan: ``ssm_prefill`` below.)
+    # a state layer's one-token update, in place in the pool of states by
+    # slot: a mixer's (ops/pallas/ssm_update.py) or a delta-rule layer's
+    # (ops/pallas/kda_update.py), whichever the model has; else XLA's
+    # gather and scatter of the rows' states. (Its prefill is the chunked
+    # scan in XLA einsums under every plan: ``ssm_prefill`` below.)
     ssm_decode: bool = False
     kv_writers: bool = False       # in-place KV writers, else XLA scatter
     # The engine serves a mixed iteration as ONE ragged program ...
@@ -73,7 +74,8 @@ class KernelPlan:
 
     @property
     def ssm_prefill(self) -> str:
-        """How a mixer's prefill window runs its recurrence: the chunked
+        """How a state layer's prefill window runs its recurrence (a
+        mixer's, a delta-rule layer's): the chunked
         form in XLA einsums, under every plan (a Pallas scan only once a
         trace shows the XLA form as the prefill program's largest
         operation: PERF.md, PR 45)."""
@@ -148,8 +150,9 @@ class KernelPlan:
             expert_gmm=base and model_cfg.dropless_experts,
             # XLA's form gathers the rows' states out of the pool and
             # scatters them back: the kernel maps each row's block by its
-            # slot and aliases the pool (PERF.md, PR 45).
-            ssm_decode=base and model_cfg.num_ssm_layers > 0,
+            # slot and aliases the pool (PERF.md, PR 45; the delta
+            # rule's twin, PR 49).
+            ssm_decode=base and model_cfg.num_state_layers > 0,
             kv_writers=writers and mesh is None,
             # No ragged kernel for absorbed-MLA pools, no ragged rows in
             # the loop over layer kinds: they keep the split path.
@@ -166,7 +169,7 @@ class KernelPlan:
             # this family, compiled for a described v5e; PERF.md, PR 45.)
             page_aligned=all(b % engine_cfg.page_size == 0
                              for b in engine_cfg.prefill_buckets)
-            or model_cfg.num_ssm_layers > 0,
+            or model_cfg.num_state_layers > 0,
             interpret=default_interpret())
 
 

@@ -193,13 +193,16 @@ MOE_STATS: Tuple[str, ...] = (
     "experts_touched",  # experts that received at least one row
     "load_max",         # rows of the most loaded expert
     "layers",           # 1 where the layer had a valid row (sums to calls)
+    "elsewhere",        # assignments to experts another chip holds (the
+                        # vector has it under a held share alone)
 )
 
 
 def dropless_moe(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
                  valid: jnp.ndarray, gate_w: jnp.ndarray, up_w: jnp.ndarray,
                  down_w: jnp.ndarray, layer: jnp.ndarray,
-                 kernel: bool = False, interpret: bool = False
+                 kernel: bool = False, interpret: bool = False,
+                 first_held: int = 0, routed: int = 0
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Routed SwiGLU experts without capacity.
 
@@ -208,7 +211,8 @@ def dropless_moe(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
     re-selects); ``valid`` [N] bool: padding and inactive lanes, which are
     given to NO group; gate/up [L, E, D, F], down [L, E, F, D]: the
     layers' WHOLE stacks, and ``layer`` the (traced) index of this one.
-    Returns ``(out [N, D], stats int32[len(MOE_STATS)])``. ``kernel``
+    Returns ``(out [N, D], stats int32[len(MOE_STATS)])`` (without the
+    last, ``elsewhere``, where every expert is held). ``kernel``
     takes the Pallas grouped matmul (ops/pallas/grouped_matmul.py;
     ``interpret`` anywhere but a TPU), else XLA's ``jax.lax.ragged_dot``:
     the plan's choice (ops/plan.py ``expert_gmm``).
@@ -223,13 +227,27 @@ def dropless_moe(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
     sizes handed to the matmul, is the expert the gate chose for it. An
     expert id outside [0, E), a row past the last group, or sizes that
     disagree with the sort would show there.
+
+    A held SHARE (``routed`` > E: the gate chose among ``routed``
+    experts, of which this chip holds the E from ``first_held`` on): an
+    assignment to an expert held elsewhere gets no group either, is
+    computed by nobody here and adds nothing, so ``out`` is this chip's
+    PART of the routed sum; it is counted as ``elsewhere`` and not as
+    ``dropped``, which stays "the gate chose it, this chip holds it, no
+    group computed it".
     """
     N, D = x.shape
     k = topi.shape[-1]
     L, E = gate_w.shape[:2]
     # An invalid row's assignments carry expert id E: they sort
     # behind every real group and belong to none.
-    flat = jnp.where(valid[:, None], topi, E).reshape(N * k)
+    flat = jnp.where(valid[:, None], topi, E)
+    if routed > E:
+        here = (topi >= first_held) & (topi < first_held + E)
+        elsewhere = jnp.sum((valid[:, None] & ~here).astype(jnp.int32))
+        held = valid[:, None] & here
+        flat = jnp.where(held, topi - first_held, E)
+    flat = flat.reshape(N * k)
     order = jnp.argsort(flat, stable=True)
     group_sizes = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
     groups = jax.lax.dynamic_update_slice(
@@ -253,8 +271,12 @@ def dropless_moe(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
     inv = jnp.zeros((N * k,), order.dtype).at[order].set(
         jnp.arange(N * k, dtype=order.dtype))
     y = ys[inv].reshape(N, k, D)
-    w = jnp.where(valid[:, None], topw, 0.0).astype(x.dtype)
-    y = jnp.where(valid[:, None, None], y, jnp.zeros((), y.dtype))
+    if routed > E:
+        w = jnp.where(held, topw, 0.0).astype(x.dtype)       # [N, k]
+        y = jnp.where(held[:, :, None], y, jnp.zeros((), y.dtype))
+    else:
+        w = jnp.where(valid[:, None], topw, 0.0).astype(x.dtype)
+        y = jnp.where(valid[:, None, None], y, jnp.zeros((), y.dtype))
     out = jnp.einsum("nkd,nk->nd", y, w,
                      preferred_element_type=jnp.float32).astype(x.dtype)
     # The group each sorted position falls into (L * E past the last).
@@ -266,8 +288,10 @@ def dropless_moe(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
                         & (given == layer * E + chosen))
                        .astype(jnp.int32))
     requested = k * jnp.sum(valid.astype(jnp.int32))
-    stats = jnp.stack([requested - computed, computed,
-                       jnp.sum((group_sizes > 0).astype(jnp.int32)),
-                       jnp.max(group_sizes),
-                       (computed > 0).astype(jnp.int32)])
-    return out, stats
+    stats = [requested - computed, computed,
+             jnp.sum((group_sizes > 0).astype(jnp.int32)),
+             jnp.max(group_sizes), (computed > 0).astype(jnp.int32)]
+    if routed > E:
+        stats[0] = stats[0] - elsewhere     # asked of another chip
+        stats.append(elsewhere)
+    return out, jnp.stack(stats)

@@ -171,6 +171,8 @@ def load_checkpoint(model_dir: str, cfg: ModelConfig,
         return _load_mla_checkpoint(r, cfg, dtype, mesh)
     if cfg.num_ssm_layers:
         return _load_mix_checkpoint(r, cfg, dtype)
+    if cfg.num_kda_layers:
+        return _load_kda_checkpoint(r, cfg, dtype)
     if cfg.layer_kinds is not None:
         return _load_kinds_checkpoint(r, cfg, dtype)
 
@@ -480,6 +482,22 @@ def _load_mla_checkpoint(r, cfg: ModelConfig, dtype, mesh):
     return jax.tree_util.tree_map(jax.device_put, params)
 
 
+def _kinds_tree(r, cfg: ModelConfig, dtype, stacks, final_norm: str):
+    """The tree of a ``layer_kinds`` model from its stacks: the embedding,
+    the norm after the last layer under the family's name for it, and
+    the head (the embedding's transpose where a checkpoint has none)."""
+    params: Dict[str, Any] = {
+        "embed": r.get("model.embed_tokens.weight").astype(dtype),
+        "stacks": stacks,
+        "final_norm": r.get(final_norm).astype(dtype)}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = (
+            np.ascontiguousarray(r.get("lm_head.weight").T)
+            if "lm_head.weight" in r else params["embed"].T).astype(dtype)
+    r.close()
+    return jax.tree_util.tree_map(jax.device_put, params)
+
+
 def _load_kinds_checkpoint(r, cfg: ModelConfig, dtype):
     """LFM2-MoE tree: one stack per KIND of layer, in layer order
     (models/transformer.py ``_init_kinds_params``), from the published
@@ -534,15 +552,75 @@ def _load_kinds_checkpoint(r, cfg: ModelConfig, dtype):
             for ours, hf in names:
                 st[ours] = stack(F + hf + ".weight", t)
         stacks[kind] = st
-    params: Dict[str, Any] = {
-        "embed": r.get("model.embed_tokens.weight").astype(dtype),
-        "stacks": stacks,
-        "final_norm": r.get("model.embedding_norm.weight").astype(dtype)}
-    if not cfg.tie_word_embeddings:
-        params["lm_head"] = (t("lm_head.weight") if "lm_head.weight" in r
-                             else params["embed"].T).astype(dtype)
-    r.close()
-    return jax.tree_util.tree_map(jax.device_put, params)
+    return _kinds_tree(r, cfg, dtype, stacks, "model.embedding_norm.weight")
+
+
+def _load_kda_checkpoint(r, cfg: ModelConfig, dtype):
+    """Solar-Open2 tree: a stack of the attention layers and one of the
+    delta-rule layers (models/transformer.py ``_init_kinds_params``).
+    No published checkpoint is in the repository: the names are the
+    family's convention (the ``fla`` layer ``KimiDeltaAttention`` under
+    ``self_attn.``, DeepSeek-V3's under ``mlp.``) and are tested on a
+    seeded tree only. ``input_layernorm`` / ``post_attention_layernorm``;
+    a delta-rule layer's ``self_attn.{q, k, v}_proj`` side by side in
+    ``kda_qkv`` and its three depthwise filters ``{q, k, v}_conv1d.weight``
+    ([C, 1, K] becomes [K, C]) in ``kda_conv_w``, ``f_a_proj`` /
+    ``f_b_proj`` and ``g_a_proj`` / ``g_b_proj`` (the low-rank pairs),
+    ``b_proj``, ``dt_bias`` and ``A_log`` (float32), ``o_norm``,
+    ``o_proj``; an attention layer's ``self_attn.{q, k, v, o}_proj`` and
+    ``g_proj`` (the output gate); ``mlp.gate`` (as wide as all the routed
+    experts) with ``e_score_correction_bias``, ``mlp.experts.E.{gate, up,
+    down}_proj`` for the experts HELD here (expert ``first_held_expert +
+    e`` of the published numbering) and ``mlp.shared_experts``;
+    ``model.norm`` after the last layer."""
+    def t(name):
+        return np.ascontiguousarray(r.get(name).T)
+
+    first = cfg.first_held_expert
+    stacks: Dict[str, Any] = {}
+    for kind in sorted(set(cfg.layer_kinds)):
+        idxs = [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
+
+        def stack(fmt, f=r.get, to=dtype):
+            return np.stack([f(fmt.format(i=i)) for i in idxs]).astype(to)
+
+        L, A = "model.layers.{i}.", "model.layers.{i}.self_attn."
+        M = "model.layers.{i}.mlp."
+        st = {"input_norm": stack(L + "input_layernorm.weight"),
+              "post_norm": stack(L + "post_attention_layernorm.weight")}
+        if kind.startswith("kda+"):
+            st["kda_qkv"] = np.concatenate(
+                [stack(A + f"{n}_proj.weight", t) for n in "qkv"], axis=2)
+            st["kda_conv_w"] = np.concatenate(
+                [stack(A + f"{n}_conv1d.weight",
+                       lambda n: r.get(n)[:, 0, :].T) for n in "qkv"],
+                axis=2)
+            for ours, hf in (("kda_f_down", "f_a_proj"),
+                             ("kda_f_up", "f_b_proj"),
+                             ("kda_beta", "b_proj"),
+                             ("kda_g_down", "g_a_proj"),
+                             ("kda_g_up", "g_b_proj"),
+                             ("kda_out", "o_proj")):
+                st[ours] = stack(A + hf + ".weight", t)
+            st["kda_dt_bias"] = stack(A + "dt_bias", to=np.float32)
+            st["kda_a_log"] = stack(A + "A_log", to=np.float32)
+            st["kda_norm"] = stack(A + "o_norm.weight")
+        else:
+            for w in ("q_proj", "k_proj", "v_proj", "o_proj"):
+                st[w] = stack(A + w + ".weight", t)
+            st["attn_gate"] = stack(A + "g_proj.weight", t)
+        st["router"] = stack(M + "gate.weight", t)
+        st["router_bias"] = stack(M + "gate.e_score_correction_bias",
+                                  to=np.float32)
+        for w in ("gate_proj", "up_proj", "down_proj"):
+            st[w] = np.stack([np.stack([
+                t(M.format(i=i) + f"experts.{first + e}.{w}.weight")
+                for e in range(cfg.num_experts)])
+                for i in idxs]).astype(dtype)
+            st["shared_" + w.split("_")[0]] = stack(
+                M + f"shared_experts.{w}.weight", t)
+        stacks[kind] = {k: np.ascontiguousarray(v) for k, v in st.items()}
+    return _kinds_tree(r, cfg, dtype, stacks, "model.norm.weight")
 
 
 def _load_mix_checkpoint(r, cfg: ModelConfig, dtype):
@@ -588,16 +666,10 @@ def _load_mix_checkpoint(r, cfg: ModelConfig, dtype):
         ssm_d=stack(M + "D", to=np.float32),
         ssm_norm=stack(M + "norm.weight"),
         ssm_out=stack(M + "out_proj.weight", t))
-    params: Dict[str, Any] = {
-        "embed": r.get("model.embed_tokens.weight").astype(dtype),
-        "stacks": {cfg.layer_kinds[0]: {k: np.ascontiguousarray(v)
-                                        for k, v in st.items()}},
-        "final_norm": r.get("model.final_layernorm.weight").astype(dtype)}
-    if not cfg.tie_word_embeddings:
-        params["lm_head"] = (t("lm_head.weight") if "lm_head.weight" in r
-                             else params["embed"].T).astype(dtype)
-    r.close()
-    return jax.tree_util.tree_map(jax.device_put, params)
+    return _kinds_tree(
+        r, cfg, dtype, {cfg.layer_kinds[0]: {k: np.ascontiguousarray(v)
+                                             for k, v in st.items()}},
+        "model.final_layernorm.weight")
 
 
 def _visual_reader(model_dir: str, depth: int, dtype):

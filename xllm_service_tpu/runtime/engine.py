@@ -217,8 +217,9 @@ class Engine:
         # model (ROADMAP.md Reach A1), here, once, and at each door.
         self.pages_only = model_cfg.num_conv_layers == 0
         # Does a sequence also carry a state that lives by SLOT (a mixer
-        # beside attention: a matrix a head a layer, the fourth pool)?
-        self.state_model = model_cfg.num_ssm_layers > 0
+        # beside attention, a delta-rule layer: a matrix a head a layer,
+        # the fourth pool)?
+        self.state_model = model_cfg.num_state_layers > 0
         if not self.pages_only:
             if mesh is not None:
                 raise ValueError(
@@ -404,8 +405,10 @@ class Engine:
             fold = (f"; {self._fold_kernel} fold {pages} pages a grid "
                     f"step, {-(-walk // pages)} steps of {walk} columns")
         if self.state_model:
-            fold += (f"; mixer ssm_prefill {self.plan.ssm_prefill}, "
-                     f"ssm_decode "
+            op = "kda" if model_cfg.num_kda_layers else "ssm"
+            fold += (f"; {'delta rule' if op == 'kda' else 'mixer'} "
+                     f"{op}_prefill {self.plan.ssm_prefill}, "
+                     f"{op}_decode "
                      f"{'pallas' if self.plan.ssm_decode else 'xla'}; "
                      f"{n_rows} state rows x 2 + {n_snaps} snapshots")
         logger.info("engine plan: %s; decode walk %d of %d columns%s%s",
@@ -3103,7 +3106,7 @@ def _prefill_step(params, packed, kv, st_f32, st_i32, key, mm_embeds=None,
     tokens = packed[:, _PREFILL_HDR:_PREFILL_HDR + t_len]
     page_table = packed[:, _PREFILL_HDR + t_len:]
     state_cols = None
-    if cfg.num_ssm_layers:
+    if cfg.num_state_layers:
         page_table, state_cols = (page_table[:, :-_STATE_COLS],
                                   page_table[:, -_STATE_COLS:])
     st = SamplingTensors.unpack(st_f32, st_i32)
@@ -3170,7 +3173,7 @@ def _decode_step(params, packed, kv, st_f32, st_i32, key, counts=None,
     logits, kv, stats = transformer.forward_decode(
         params, cfg, tokens, positions, active, kv, page_table,
         return_stats=True, rope_delta=rope_delta, plan=plan,
-        state_rows=packed[:, 3] if cfg.num_ssm_layers else None)
+        state_rows=packed[:, 3] if cfg.num_state_layers else None)
     tok = sample_tokens(logits, st, key, positions=positions, counts=counts,
                         bias_ids=bias_ids, bias_vals=bias_vals)
     lp = compute_logprobs(logits, tok)

@@ -386,6 +386,7 @@ def _moe_record(st: Dict[str, int]) -> Optional[Dict[str, Any]]:
     return {"assignments": st["assignments"],
             "experts_touched": st["experts_touched"],
             "dropped": st["dropped"],
+            "elsewhere": st["elsewhere"],
             "load_max_over_mean": round(
                 (st["load_max"] / st["layers"])
                 / (st["assignments"] / st["experts_touched"]), 3)}
@@ -1667,7 +1668,12 @@ class Worker:
                  "the share of expert weights a step reads"),
                 ("xllm_worker_moe_dropped_assignments_total", "dropped",
                  "assignments the gate made and no expert computed "
-                 "(requested - computed; 0 under the dropless layer)")):
+                 "(requested - computed; 0 under the dropless layer)"),
+                ("xllm_worker_moe_elsewhere_assignments_total", "elsewhere",
+                 "assignments the gate made to experts that another chip "
+                 "of the deployment holds (a held share of a wider "
+                 "router: computed by nobody here; 0 where every expert "
+                 "is held)")):
             self.obs.counter(name, text, labelnames=("model",)).set_total(
                 st[key], model=m)
 
