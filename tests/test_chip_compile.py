@@ -145,6 +145,24 @@ def _kda_update(sds):
              sds((b, h), jnp.float32)), {"donate_argnums": (0,)})
 
 
+def _ring_writer(sds, rows, pool):
+    """The pool pinned row-major on both sides, as an engine pins it,
+    and a second result beside it, as every step program has: the kernel
+    types its result as HBM's, and a program whose ONLY result is that
+    fails the compiler's check of the donated argument's alias (seen
+    here, PR 50; a step program's tuple does not)."""
+    from xllm_service_tpu.ops.pallas.ring_update import ring_write
+    from xllm_service_tpu.runtime.engine import row_major_format
+    tails = sds(pool, jnp.bfloat16)
+    pin = row_major_format(len(pool), tails.sharding)
+    return (lambda tails, layer, pid, rings: (
+                ring_write(tails, layer, pid, rings, interpret=False), pid),
+            (tails, sds((), jnp.int32), sds((rows,), jnp.int32),
+             sds((rows,) + pool[2:], jnp.bfloat16)),
+            {"donate_argnums": (0,), "out_shardings": (pin, None),
+             "in_shardings": (pin, None, None, None)})
+
+
 KERNELS = {
     # The default path: what a served worker runs on the chip.
     "decode-attention": _decode_attention,
@@ -183,6 +201,14 @@ KERNELS = {
     # heads mapped by slot, four [128, 1] columns a head sliced out of a
     # 32-lane block.
     "kda-decode-update[cell]": _kda_update,
+    # A state layer's filter ring written in place (ops/pallas/
+    # ring_update.py) at both cells' shapes: a copy a row from [B, K, C]
+    # into a page of [n, P, K, C] (a page's ring has to be
+    # one aligned piece: a pool of flat rows is refused here).
+    "ring-write[solar-open2-250b]": functools.partial(
+        _ring_writer, rows=64, pool=(3, 1888, 4, 24576)),
+    "ring-write[falcon-h1-34b]": functools.partial(
+        _ring_writer, rows=32, pool=(6, 256, 4, 5120)),
 }
 
 

@@ -483,6 +483,69 @@ class TestStatePoolAot:
         assert ma.alias_size_in_bytes >= 2.9e9      # every pool in place
 
 
+# The two cells whose state layers keep a filter ring a page: pool pages,
+# table width, decode rows, slots of the pool of states.
+RING_CELLS = {"solar-open2-250b": (1888, 96, 64, 193),
+              "falcon-h1-34b": (256, 16, 32, 97)}
+
+
+def _ring_sliced_by_layer(real):
+    """The form NOT taken: the layer's rings sliced out of the pool in
+    front of the writer and put back behind it."""
+    def write(cfg, tails, c, *rest):
+        import jax
+        layer = jax.lax.dynamic_index_in_dim(tails, c, axis=0, keepdims=True)
+        return jax.lax.dynamic_update_index_in_dim(
+            tails, real(cfg, layer, 0, *rest)[0], c, 0)
+    return write
+
+
+@pytest.mark.parametrize("cell", RING_CELLS)
+class TestRingWrittenInPlace:
+    """A state layer's filter ring in the DECODE program of its cell
+    (PR 50): written by the in-place writer of ops/pallas/ring_update.py
+    into the whole pool of tails ([3, 1888, 4, 24576], 1.11 GB; the
+    mixer's [6, 256, 4, 5120]), the layer an index. No copy of the pool,
+    no result that is one layer of it, no ``dynamic-update-slice`` into
+    it (the parent's scatter went one row after another: 2.6 ms of the
+    delta-rule cell's 17.7 ms step on the chip), and the pool not staged
+    through VMEM around the call (the mixer's 31 MB were, until the
+    kernel typed its result as HBM's)."""
+
+    def _text(self, aot, cell):
+        import tools.aot_copy_census as cc
+        aot_compile, _ = aot
+        pages, width, batch, slots = RING_CELLS[cell]
+        programs, _, pools = cc.build_cell_programs(
+            _cell_config(cell, 0), pages, width, batch, state_slots=slots)
+        fn, args, jit_kw = programs["decode"]
+        return aot_compile(fn, args, **jit_kw).as_text(), pools
+
+    def test_the_decode_program_leaves_the_pool_of_tails_where_it_lies(
+            self, aot, cell):
+        import re
+        text, pools = self._text(aot, cell)
+        tails = pools[2]
+        assert len(tails) == 4 and tails[2] == 4
+        assert "ring_write" in text
+        assert census_pool_copies(text, tails) == []
+        assert census_layer_results(text, [tails]) == []
+        dims = ",".join(str(d) for d in tails)
+        assert not re.search(
+            rf"= bf16\[{dims}\][^ ]* dynamic-update-slice\(", text)
+        # a copy of it INTO the alternate memory space too, which the
+        # census by design lets a toy-sized pool have
+        assert not re.search(rf"= \(bf16\[{dims}\][^ ]* copy-start\(", text)
+
+    def test_positive_control_sliced_by_layer(self, aot, cell, monkeypatch):
+        from xllm_service_tpu.models import transformer
+        monkeypatch.setattr(transformer, "_ring_write",
+                            _ring_sliced_by_layer(transformer._ring_write))
+        text, pools = self._text(aot, cell)
+        assert (census_layer_results(text, [pools[2]])
+                or census_pool_copies(text, pools[2]))
+
+
 # Every cell of the benchmark, its config at its published widths and its
 # pools at the CELL'S OWN page count (what the compiler copies depends
 # on the pool's size: the hybrid family's whole-pool copies under the
@@ -509,8 +572,9 @@ PREFILL_CELLS = {
                       [(1, 256, 16), (2, 256, 16), (1, 1024, 8)]),
     # from two rows on the gather of a row's filter ring (196 KB over
     # q | k | v) was split over thirds of the whole pool of tails, copied
-    # first (1.1 GB a layer): transformer._ring_read takes a slice a row
-    # (two rows: ``test_a_wide_ring_is_read_a_slice_a_row``)
+    # first (1.1 GB a layer) while the pool kept flat rows; a ring is
+    # one contiguous piece of [n, P, K, C] and one gather reads it (two
+    # rows: ``test_a_wide_ring_is_gathered_where_it_lies``)
     "solar-open2-250b": (0, 1888, 64, 193, [(1, 2048, 96)]),
 }
 CELL_SHAPES = [(cell, shape) for cell, spec in PREFILL_CELLS.items()
@@ -561,7 +625,8 @@ def _prefill_census(aot, name, shape):
         _cell_config(name, layers), pages, table_width, batch,
         window=window, state_slots=slots, prefill_rows=rows)
     fn, args, jit_kw = programs["prefill"]
-    return cc.census_pools(aot_compile(fn, args, **jit_kw).as_text(), pools)
+    return cc.census_pools(aot_compile(fn, args, **jit_kw).as_text(), pools,
+                           window=(rows, window))
 
 
 class TestPrefillReadsPagesOffThePool:
@@ -577,11 +642,13 @@ class TestPrefillReadsPagesOffThePool:
         assert layer_sized == LEFT.get(cell, []) and copies == [], (
             layer_sized, copies)
 
-    def test_a_wide_ring_is_read_a_slice_a_row(self, aot):
+    def test_a_wide_ring_is_gathered_where_it_lies(self, aot):
         """Two follow-ups in one window of the delta-rule cell: the
-        gather of their filter rings (a row of 196 KB) was split over
-        thirds of the WHOLE pool of tails, each copied first: 1.1 GB of
-        temporaries that no pool-shaped result shows (PR 49)."""
+        gather of their filter rings (196 KB each) out of a pool of
+        FLAT rows was split over thirds of the whole pool, each copied
+        first: 1.1 GB of temporaries that no pool-shaped result shows
+        (PR 49, which read a slice a row instead). Out of [n, P, K, C]
+        one gather takes them where they lie (PR 50)."""
         import tools.aot_copy_census as cc
         aot_compile, _ = aot
         name = "solar-open2-250b"
@@ -592,7 +659,7 @@ class TestPrefillReadsPagesOffThePool:
         fn, args, jit_kw = programs["prefill"]
         compiled = aot_compile(fn, args, **jit_kw)
         assert cc.census_pools(compiled.as_text(), pools) == (LEFT[name], [])
-        tails = 3 * 1888 * 98304 * 2
+        tails = 3 * 1888 * 4 * 24576 * 2
         assert compiled.memory_analysis().temp_size_in_bytes < tails // 4
 
     # (not the delta-rule cell: ONE of its layers attends, and a layer

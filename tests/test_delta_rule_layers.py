@@ -187,7 +187,7 @@ def test_padding_moves_neither_state_nor_ring_and_the_layers_keep_what_they_keep
     # q | k | v, three layers' states: the ranks come apart
     kv = pools(mc)
     assert [p.shape for p in kv] == [
-        (1, 16, PS, 1, 16), (1, 16, PS, 1, 16), (3, 16, 4 * 3 * 64),
+        (1, 16, PS, 1, 16), (1, 16, PS, 1, 16), (3, 16, 4, 3 * 64),
         (3, 12, 4, 16, 16)]
     pt = table(1, 2, 3, 4)
     n = 29
@@ -221,6 +221,10 @@ def test_the_decode_kernel_in_the_interpreter_is_the_xla_form(made):
     np.testing.assert_allclose(kernel, xla, atol=2e-6)
     np.testing.assert_allclose(np.asarray(kv_k[3]), np.asarray(kv_x[3]),
                                atol=1e-6)
+    # the ring under the same bit, written in place: the scatter's, bit
+    # for bit (ops/pallas/ring_update.py)
+    np.testing.assert_array_equal(np.asarray(kv_k[2]), np.asarray(kv_x[2]))
+    assert np.abs(np.asarray(kv_k[2]) - np.asarray(kv[2])).max() > 0
     # the step at position 17 wrote row 1's slot of the ODD positions
     # (2) and left the state as of 16 (slot 1) as it was
     np.testing.assert_array_equal(np.asarray(kv_k[3][:, 1]),
@@ -560,6 +564,38 @@ def test_a_discarded_launch_leaves_state_and_ring_as_they_were(made):
     assert np.abs(ring(kv1)[:, 1] - ring(kv)[:, 1]).max() > 0
 
 
+def test_a_discarded_launch_over_the_ring_written_in_place(params,
+                                                           monkeypatch):
+    """An engine whose plan writes the ring in place
+    (``plan.ssm_decode``; the kernels interpreted): every second
+    iteration the step on the device ahead is thrown away after it has
+    written its rows' rings, and run again. The stream, the rings and
+    the states at the end are the sequential engine's, bit for bit: the
+    writer puts a row's ring into the row's own page and shifts
+    nothing."""
+    monkeypatch.setattr(
+        KernelPlan, "from_env", classmethod(lambda cls, *a, **kw: cls(
+            ssm_decode=True, write_then_attend=True, interpret=True)))
+
+    def run(eng, each=None):
+        add(eng, "a", PROMPT, 14)
+        return drain(eng, each=each)["a"]
+    sequential = engine(params)
+    assert sequential.plan.ssm_decode
+    sequential._ahead_eligible = sequential._tail_eligible = \
+        lambda *a: False
+    want = run(sequential)
+    torn = engine(params)
+    assert run(torn, each=lambda i: i % 2 == 0
+               and torn.drain_pipeline()) == want
+    assert (torn.phase_counts["decode.ahead_discard"]
+            + torn.phase_counts["decode.tail_discard"]) > 3
+    for pool in (2, 3):
+        np.testing.assert_array_equal(np.asarray(torn.kv[pool]),
+                                      np.asarray(sequential.kv[pool]))
+    assert np.abs(np.asarray(torn.kv[2], np.float32)).max() > 0
+
+
 # (g) ----------------------------------------------------------------------
 
 def test_such_a_models_pages_do_not_move_and_it_takes_no_mesh(params):
@@ -600,11 +636,11 @@ def test_from_hf_config_reads_the_published_config_verbatim():
         == (40, 320, 0)
     # a ring of 4 inputs over q | k | v of 3 x 8192 channels a page, the
     # fourth pool a layer's 64 states of 128 x 128: 4.19 MB a sequence
-    assert cut.conv_tail_width == 4 * 24576
+    assert cut.conv_tail_shape == (4, 24576)
     kv = jax.eval_shape(lambda: T.init_kv_cache(cut, 1888, 128,
                                                 state_slots=193))
     assert [p.shape for p in kv] == [
-        (1, 1888, 128, 8, 128), (1, 1888, 128, 8, 128), (3, 1888, 98304),
+        (1, 1888, 128, 8, 128), (1, 1888, 128, 8, 128), (3, 1888, 4, 24576),
         (3, 193, 64, 128, 128)]
     assert kv[3].dtype == jnp.float32 and 64 * 128 * 128 * 4 == 4_194_304
 

@@ -299,6 +299,36 @@ def test_two_plans_are_two_cache_entries_of_one_jitted_forward(no_gates):
         transformer.init_kv_cache(cfg, pages, ps), pt))
 
 
+_SWITCHES = [f.name for f in dataclasses.fields(KernelPlan)
+             if f.name != "interpret"]
+
+
+@pytest.mark.parametrize("field", _SWITCHES)
+def test_the_ring_is_written_in_place_under_ssm_decode_and_no_other_field(
+        field):
+    """A state layer's filter ring in a decode step (a window of ONE
+    token): the in-place writer of ops/pallas/ring_update.py where
+    ``plan.ssm_decode``, the bit that puts the layer's state update in
+    place too, and XLA's scatter under every other field flipped alone;
+    a prefill window keeps the scatter under every plan."""
+    import types
+    cfg = types.SimpleNamespace(conv_kernel=4)
+    flipped = not getattr(KernelPlan(), field)
+    plan = KernelPlan(**{field: flipped}, interpret=True)
+    tails = jnp.zeros((3, 6, 4, 128), jnp.bfloat16)
+    pt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    start = jnp.asarray([9, 3], jnp.int32)
+
+    def text(tokens):
+        zz = jnp.zeros((2, 3 + tokens, 128), jnp.bfloat16)
+        return str(jax.make_jaxpr(lambda t, z: transformer._ring_write(
+            cfg, t, 1, pt, start, jnp.full((2,), tokens, jnp.int32), z, 8,
+            plan))(tails, zz))
+    assert ("pallas_call" in text(1)) == (field == "ssm_decode")
+    assert ("scatter" in text(1)) == (field != "ssm_decode")
+    assert "pallas_call" not in text(8) and "scatter" in text(8)
+
+
 def test_no_thread_local_and_no_trace_time_gate_left():
     """``ops/pallas`` and ``ops/plan.py`` keep no per-thread state and no
     gate that a trace could call; ``models``, ``ops/attention`` and the

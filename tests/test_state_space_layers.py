@@ -161,12 +161,75 @@ def test_the_decode_kernel_in_the_interpreter_is_the_xla_form(made):
     np.testing.assert_allclose(kernel, xla, atol=2e-6)
     np.testing.assert_allclose(np.asarray(kv_k[3]), np.asarray(kv_x[3]),
                                atol=1e-6)
+    # the ring under the same bit, written in place: the scatter's, bit
+    # for bit (ops/pallas/ring_update.py)
+    np.testing.assert_array_equal(np.asarray(kv_k[2]), np.asarray(kv_x[2]))
+    assert np.abs(np.asarray(kv_k[2]) - np.asarray(kv[2])).max() > 0
     # the step at position 17 wrote row 1's slot of the ODD positions
     # (2) and left the state as of 16 (slot 1) as it was
     np.testing.assert_array_equal(np.asarray(kv_k[3][:, 1]),
                                   np.asarray(kv[3][:, 1]))
     assert np.abs(np.asarray(kv_k[3][:, 2])
                   - np.asarray(kv[3][:, 2])).max() > 0
+
+
+# A decode step's ring write in place (ops/pallas/ring_update.py) against
+# the XLA scatter it replaces, at both families' rings: a delta-rule
+# layer's 4 x 24,576 over q | k | v and the mixer's 4 x 5,120. Rows, by
+# position at a page of 8: in the middle of a page; an INACTIVE lane
+# (its page's ring stays); one that OPENS a page (the whole ring comes
+# from the inputs behind it, which lie in the page before); a sequence's
+# START (zeros before it); its third token (one zero before it).
+RING_POSITIONS = [13, 12, 8, 0, 2]
+RING_ACTIVE = [True, False, True, True, True]
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("K, C", [(4, 24576), (4, 5120)])
+def test_the_ring_written_in_place_is_the_scatters_ring(K, C, layer):
+    import types
+    cfg = types.SimpleNamespace(conv_kernel=K)
+    rng = np.random.default_rng(C + layer)
+    rows = len(RING_POSITIONS)
+    tails = jnp.asarray(rng.standard_normal((3, 12, K, C)), jnp.bfloat16)
+    pos = jnp.asarray(RING_POSITIONS, jnp.int32)
+    one = jnp.asarray(RING_ACTIVE).astype(jnp.int32)
+    # row b's pages are 2b + 1 and 2b + 2; page 0 is the null page
+    pt = jnp.asarray([[2 * b + 1, 2 * b + 2, 0] for b in range(rows)],
+                     jnp.int32)
+    x = jnp.asarray(rng.standard_normal((rows, 1, C)), jnp.bfloat16)
+
+    def step(plan):
+        prev = T._ring_read(cfg, tails, layer, pt, pos, PS)
+        zz = jnp.concatenate([prev, x], axis=1)
+        after = T._ring_write(cfg, tails, layer, pt, pos, one, zz, PS, plan)
+        # what the NEXT step reads behind its position
+        return prev, after, T._ring_read(cfg, after, layer, pt, pos + 1, PS)
+    prev, want, want_next = jax.jit(lambda: step(KernelPlan()))()
+    _, got, got_next = jax.jit(lambda: step(
+        KernelPlan(ssm_decode=True, interpret=True)))()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got_next),
+                                  np.asarray(want_next))
+    got, before = np.asarray(got), np.asarray(tails)
+    # the other layers, the null page and the inactive lane's pages: bit
+    # for bit what they were
+    others = [c for c in range(3) if c != layer]
+    np.testing.assert_array_equal(got[others], before[others])
+    np.testing.assert_array_equal(got[layer, [0, 3, 4]],
+                                  before[layer, [0, 3, 4]])
+    # every live row wrote its input to ring row t mod K of its page
+    for b, (t, live) in enumerate(zip(RING_POSITIONS, RING_ACTIVE)):
+        if live:
+            np.testing.assert_array_equal(
+                got[layer, 2 * b + 1 + t // PS, t % K], np.asarray(x)[b, 0])
+    # the row that opened a page took the three inputs behind it along
+    np.testing.assert_array_equal(got[layer, 6, [1, 2, 3]],
+                                  before[layer, 5, [1, 2, 3]])
+    # zeros before a sequence's start, and none after it
+    assert not np.asarray(prev)[3].any() and not np.asarray(prev)[4][:1].any()
+    assert not np.asarray(got_next)[3][:2].any()
+    assert np.asarray(got_next)[3][2].any()
 
 
 # ---------------------------------------------------------------------------
@@ -544,7 +607,7 @@ def test_from_hf_config_reads_the_published_config_verbatim():
     assert (mc.ssm_inner, mc.ssm_conv_dim) == (4096, 5120)
     # a ring of 4 inputs over the 5120 convolved channels, not
     # hidden_size by name
-    assert mc.conv_tail_width == 4 * 5120
+    assert mc.conv_tail_shape == (4, 5120)
     assert mc.rope_theta == 1e11 and mc.rope_scaling is None
     assert not mc.tie_word_embeddings and not mc.is_moe
     assert (mc.embedding_multiplier, mc.lm_head_multiplier,
@@ -562,7 +625,7 @@ def test_from_hf_config_reads_the_published_config_verbatim():
         dataclasses.replace(mc, layer_kinds=mc.layer_kinds[:6]), 256, 128,
         state_slots=97))
     assert [p.shape for p in kv] == [
-        (6, 256, 128, 4, 128), (6, 256, 128, 4, 128), (6, 256, 20480),
+        (6, 256, 128, 4, 128), (6, 256, 128, 4, 128), (6, 256, 4, 5120),
         (6, 97, 32, 256, 128)]
     assert kv[3].dtype == jnp.float32
     assert 32 * 128 * 256 * 4 == 4_194_304

@@ -262,12 +262,19 @@ def census_layer_results(hlo_text: str, pool_shapes) -> list:
     return hits
 
 
-def census_pools(hlo_text: str, pool_shapes) -> tuple:
+def census_pools(hlo_text: str, pool_shapes, window=None) -> tuple:
     """(``census_layer_results``, ``census_pool_copies`` over every
-    distinct pool shape) of one compiled program."""
-    return (census_layer_results(hlo_text, pool_shapes),
-            [hit for pool in set(map(tuple, pool_shapes))
-             for hit in census_pool_copies(hlo_text, pool)])
+    distinct pool shape) of one compiled program. ``window``: the (rows,
+    tokens) of a prefill. A pool whose layer, its last axis aside, counts
+    what the window's tokens count is left out of the layer census,
+    which tells by shape and cannot tell the window's own activations
+    from it (256 pages of rings [4, 5120] under a window of 1,024
+    tokens over the mixer's 5,120 channels); its copies still count."""
+    tokens = math.prod(window) if window else None
+    return (census_layer_results(
+        hlo_text, [s for s in pool_shapes if math.prod(s[1:-1]) != tokens]),
+        [hit for pool in set(map(tuple, pool_shapes))
+         for hit in census_pool_copies(hlo_text, pool)])
 
 
 def _llama3_1b_sds():
@@ -595,7 +602,8 @@ def run_cells_census(only=()) -> dict:
                 prefill_rows=B)
             fn, args, jit_kw = programs["prefill"]
             compiled = aot_compile(fn, args, **jit_kw)
-            layer_sized, copies = census_pools(compiled.as_text(), pools)
+            layer_sized, copies = census_pools(compiled.as_text(), pools,
+                                               window=(B, T))
             verdict = {
                 "layer_sized": layer_sized, "pool_copies": copies,
                 "temp_gb": round(compiled.memory_analysis()

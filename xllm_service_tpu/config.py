@@ -461,17 +461,17 @@ class ModelConfig:
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
-    def conv_tail_width(self) -> int:
-        """Values in one row of the pool of convolution tails. A "conv"
-        layer keeps its last conv_kernel - 1 gated inputs over
-        hidden_size channels; a state layer (a mixer, a delta-rule
-        layer) keeps a RING of conv_kernel inputs over its
-        ``ring_channels``, the input at position t in row t mod
-        conv_kernel, so that a decode step writes one ring row and never
-        one it reads (transformer, "A mixer beside attention")."""
+    def conv_tail_shape(self) -> tuple:
+        """One row of the pool of convolution tails. A "conv" layer keeps
+        its last conv_kernel - 1 gated inputs over hidden_size channels,
+        flat; a state layer (a mixer, a delta-rule layer) keeps a RING
+        of conv_kernel inputs over its ``ring_channels``, [K, C], the
+        input at position t in row t mod conv_kernel, so that a decode
+        step writes one ring row and never one it reads (transformer,
+        "A mixer beside attention")."""
         if self.num_state_layers:
-            return self.conv_kernel * self.ring_channels
-        return max(self.conv_kernel - 1, 1) * self.hidden_size
+            return (self.conv_kernel, self.ring_channels)
+        return (max(self.conv_kernel - 1, 1) * self.hidden_size,)
 
     @property
     def dropless_experts(self) -> bool:

@@ -156,13 +156,15 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
         # Keys and values of the ATTENTION layers alone, and a third
         # pool of convolution tails: one row a page a convolution layer,
         # the layer's state as of the last token written into that page
-        # ("Layers that differ in kind", below). Flat rows, so that no
-        # short axis is padded to a tile.
+        # ("Layers that differ in kind", below). A "conv" layer's row is
+        # flat, so that no short axis is padded to a tile; a state
+        # layer's is its ring [K, C], a page's ring one contiguous piece
+        # that a decode step writes in place (ops/pallas/ring_update.py).
         pack = _kv_pack(cfg)
         shape = (max(cfg.num_attn_layers, 1), num_pages, page_size,
                  cfg.num_kv_heads // pack, pack * cfg.head_dim)
-        tails = (max(cfg.num_conv_layers, 1), num_pages,
-                 cfg.conv_tail_width)
+        tails = (max(cfg.num_conv_layers, 1), num_pages) \
+            + cfg.conv_tail_shape
         pools = (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
                  jnp.zeros(tails, dtype))
         if cfg.num_state_layers:
@@ -1955,16 +1957,18 @@ def _tails_write(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
 #   after: the state at a page boundary that the prefix index can hand
 #   to a later request whose prompt shares the pages up to there.
 #
-# The short convolution's tail rides the third pool, one row a page, as
-# a "conv" layer's does, but as a RING: the input at position t in ring
-# row t mod conv_kernel. A decode step reads the three rows behind its
-# position and writes its own, never one it reads, for the same reason
-# as above (a "conv" layer's tail, shifted in place, is not safe under a
-# discarded launch: PERF.md section 7).
+# The short convolution's tail rides the third pool, a page's as a
+# "conv" layer's does, but as a RING [conv_kernel, channels]: the input
+# at position t in ring row t mod conv_kernel. A decode step reads the
+# three rows behind its position and writes its own, never one it reads,
+# for the same reason as above (a "conv" layer's tail, shifted in place,
+# is not safe under a discarded launch: PERF.md section 7).
 #
 # Prefill runs the chunked form of the recurrence (chunks of
 # ``cfg.ssm_chunk``; plain XLA einsums), decode the one-token form (the
-# Pallas kernel of ops/pallas/ssm_update.py where ``plan.ssm_decode``).
+# Pallas kernel of ops/pallas/ssm_update.py where ``plan.ssm_decode``,
+# and under the same bit the step's ring is written in place:
+# ops/pallas/ring_update.py).
 # ---------------------------------------------------------------------------
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -2119,32 +2123,21 @@ def _ssm_step(cfg: ModelConfig, state, c, read, write, x, dt, A, Bm, Cm,
     return y, state.at[c, write].set(S)
 
 
-# A row of the pool of tails from which on ``_ring_read`` takes one slice
-# a row instead of a gather.
-_WIDE_ROW_BYTES = 64 << 10
-
-
 def _ring_read(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
                positions: jnp.ndarray, ps: int) -> jnp.ndarray:
     """[B, K-1, C]: the mixer's convolution inputs at the K - 1 positions
     before ``positions`` [B], oldest first, from the ring of the page
-    that holds the position before; zeros before a sequence's start."""
-    K, C = cfg.conv_kernel, cfg.ring_channels
+    that holds the position before (``tails`` [n, P, K, C]); zeros
+    before a sequence's start. One gather out of the whole pool: a
+    page's ring is one contiguous piece of it (in a pool of flat rows
+    [n, P, K * C], where the tiles interleave neighbouring pages, the
+    compiler split a gather of 196 KB rows over thirds of the pool,
+    each copied first: 1.1 GB a layer, PERF.md, PRs 49 and 50)."""
+    K = cfg.conv_kernel
     before = jnp.maximum(positions - 1, 0)
     pid = jnp.take_along_axis(page_table, (before // ps)[:, None],
                               axis=1)[:, 0]
-    if tails.shape[-1] * tails.dtype.itemsize < _WIDE_ROW_BYTES:
-        ring = tails[c, pid]
-    else:
-        # One slice a row out of the whole pool, as ``_state_rows``: a
-        # gather of rows this wide (196 KB over q | k | v of 3 x 8192)
-        # the compiler splits over thirds of the WHOLE pool, copied
-        # first (1.1 GB a layer in every program of two rows or more;
-        # compiled for a described v5e, PERF.md, PR 49).
-        ring = jnp.concatenate([jax.lax.dynamic_slice(
-            tails, (c, pid[b], 0), (1, 1, tails.shape[-1]))[0]
-            for b in range(pid.shape[0])])
-    ring = ring.reshape(-1, K, C)
+    ring = tails[c, pid]
     at = positions[:, None] - (K - 1) \
         + jnp.arange(K - 1, dtype=jnp.int32)[None, :]            # [B, K-1]
     rows = jnp.take_along_axis(ring, jnp.mod(at, K)[:, :, None], axis=1)
@@ -2154,13 +2147,14 @@ def _ring_read(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
 
 def _ring_write(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
                 start: jnp.ndarray, lengths: jnp.ndarray,
-                zz: jnp.ndarray, ps: int) -> jnp.ndarray:
+                zz: jnp.ndarray, ps: int, plan: KernelPlan) -> jnp.ndarray:
     """Write the mixer's ring of every page that the windows [start,
     start + lengths) touch: page p's ring holds the last K inputs as of
     the LAST of the window's positions that lies in p, the input at
     position t in ring row t mod K. ``zz`` [B, K-1+T, C] is
     ``_ssm_conv``'s (the input at window position i is ``zz[:, i+K-1]``);
-    a row of length 0 writes nothing."""
+    a row of length 0 writes nothing. A window of one token (a decode
+    step) writes its one page in place where ``plan.ssm_decode``."""
     K = cfg.conv_kernel
     B, T = zz.shape[0], zz.shape[1] - (K - 1)
     P, MP = tails.shape[1], page_table.shape[1]
@@ -2176,9 +2170,14 @@ def _ring_write(cfg: ModelConfig, tails: jnp.ndarray, c, page_table,
     rows = jax.vmap(lambda z, i: z[i])(zz, at)                   # [B,J,K,C]
     pid = jnp.take_along_axis(page_table, jnp.minimum(page, MP - 1),
                               axis=1)
+    if plan.ssm_decode and T == 1:
+        from xllm_service_tpu.ops.pallas.ring_update import ring_write
+        return ring_write(tails, c, jnp.where(touched, pid, -1)[:, 0],
+                          rows[:, 0].astype(tails.dtype),
+                          interpret=plan.interpret)
     pid = jnp.where(touched, pid, P)            # past the pool: dropped
     return tails.at[c, pid.reshape(-1)].set(
-        rows.reshape(pid.size, -1), mode="drop")
+        rows.reshape((pid.size,) + rows.shape[2:]), mode="drop")
 
 
 def _mixer(cfg: ModelConfig, lp, h, prev, valid, scan):
@@ -2511,7 +2510,7 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
             cfg, tails, c, page_table, start_pos, ps), tok_valid)
         o, S, S_snap = _kda_scan(q, k, v, g, beta, S0, snap_len, ps)
         tails = _ring_write(cfg, tails, c, page_table, start_pos, lengths,
-                            zz, ps)
+                            zz, ps, plan)
         return _kda_out(cfg, lp, o, h), (kp, vp, tails,
                                          put(state, S, S_snap))
 
@@ -2534,7 +2533,7 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
         ym, zz, (S, S_snap) = _mixer(cfg, lp, h, _ring_read(
             cfg, tails, c, page_table, start_pos, ps), tok_valid, scan)
         tails = _ring_write(cfg, tails, c, page_table, start_pos, lengths,
-                            zz, ps)
+                            zz, ps, plan)
         return ya + ym, (kp, vp, tails, put(state, S, S_snap))
 
     x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
@@ -2604,7 +2603,7 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
         o, state = _kda_step(cfg, state, r, read, write, q[:, 0], k[:, 0],
                              v[:, 0], g[:, 0], beta[:, 0], plan)
         tails = _ring_write(cfg, tails, c, page_table, positions, one, zz,
-                            ps)
+                            ps, plan)
         return _kda_out(cfg, lp, o[:, None], h), (kp, vp, tails, state)
 
     def mix_op(lp, h, pools, a, c, r):
@@ -2621,7 +2620,7 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
             cfg, tails, c, page_table, positions, ps), active[:, None],
             scan)
         tails = _ring_write(cfg, tails, c, page_table, positions, one, zz,
-                            ps)
+                            ps, plan)
         return ya + ym, (kp, vp, tails, state)
 
     x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,

@@ -48,8 +48,11 @@ class KernelPlan:
     # a state layer's one-token update, in place in the pool of states by
     # slot: a mixer's (ops/pallas/ssm_update.py) or a delta-rule layer's
     # (ops/pallas/kda_update.py), whichever the model has; else XLA's
-    # gather and scatter of the rows' states. (Its prefill is the chunked
-    # scan in XLA einsums under every plan: ``ssm_prefill`` below.)
+    # gather and scatter of the rows' states. The layer's filter ring
+    # with it: the step's ring written in place into its page of the
+    # pool of tails (ops/pallas/ring_update.py), else XLA's scatter, a
+    # row after another. (Its prefill is the chunked scan in XLA einsums
+    # and the scatter under every plan: ``ssm_prefill`` below.)
     ssm_decode: bool = False
     kv_writers: bool = False       # in-place KV writers, else XLA scatter
     # The engine serves a mixed iteration as ONE ragged program ...
@@ -151,7 +154,7 @@ class KernelPlan:
             # XLA's form gathers the rows' states out of the pool and
             # scatters them back: the kernel maps each row's block by its
             # slot and aliases the pool (PERF.md, PR 45; the delta
-            # rule's twin, PR 49).
+            # rule's twin, PR 49; the ring's writer, PR 50).
             ssm_decode=base and model_cfg.num_state_layers > 0,
             kv_writers=writers and mesh is None,
             # No ragged kernel for absorbed-MLA pools, no ragged rows in

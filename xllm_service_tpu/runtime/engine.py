@@ -2139,7 +2139,11 @@ class Engine:
         enqueued: the replacement step writes those positions again
         before anything attends to them or the prefix index
         content-addresses them (a state by slot is written beside the
-        one it was read from, never over it: ``_live_slot``). And a page
+        one it was read from, never over it: ``_live_slot``; a filter
+        ring is written whole into the row's own page, in place under
+        ``plan.ssm_decode``, the input at position t in ring row t mod
+        K and nothing shifted, so the replacement writes the same ring
+        to the same page: ops/pallas/ring_update.py). And a page
         released meanwhile is reused only by a computation the runtime
         enqueues AFTER the discarded one: program order on the one
         device stream."""
