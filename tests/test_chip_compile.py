@@ -45,25 +45,38 @@ def _decode_attention(sds, b=B_DEC, hq=HQ, hkv=HKV, d=D, mp=MP, window=0,
              sds((), jnp.int32)), {})
 
 
-# The benchmark's four cells that decode through the paged kernel: rows,
+# The benchmark's five cells that decode through the paged kernel: rows,
 # query / key-value heads of 128 as the kernel sees them, table width,
-# static window, the pool's layers and pages, and the pages a grid step
+# static window, the pool's layers and pages, the pages a grid step
 # folds there (ops/plan.py ``paged_fold_pages`` at 128-token pages of
-# bfloat16: PERF.md, PR 46).
+# bfloat16: PERF.md, PR 46) and the positions a tile of the page read
+# flat (``paged_flat_positions``, PR 51; 1: the page is read by heads).
 CELLS = {
     # Mistral-7B-v0.1: the grid walks the window's 33 columns from each
     # row's first live page, which the folded table holds
     "window": (dict(b=8, hq=32, hkv=8, d=128, mp=64, window=4096,
-                    pool=(16, 768)), 4),
+                    pool=(16, 768)), 4, 1),
     # Ouro-2.6B: 16 key-value heads of group size 1, a table of 8
-    "looped": (dict(b=8, hq=16, hkv=16, d=128, mp=8, pool=(192, 40)), 2),
+    "looped": (dict(b=8, hq=16, hkv=16, d=128, mp=8, pool=(192, 40)), 2, 1),
     # LFM2-24B-A2B: 8 heads of 64 packed two to a 128-wide row
-    "packed": (dict(b=64, hq=32, hkv=4, d=128, mp=96, pool=(2, 3776)), 8),
+    "packed": (dict(b=64, hq=32, hkv=4, d=128, mp=96, pool=(2, 3776)), 8,
+               4),
     # Falcon-H1-34B: a group of 5
-    "group5": (dict(b=32, hq=20, hkv=4, d=128, mp=16, pool=(6, 256)), 8),
+    "group5": (dict(b=32, hq=20, hkv=4, d=128, mp=16, pool=(6, 256)), 8, 4),
     # Solar-Open2-250B: ONE layer of 4 attends, at a group of 8
-    "group8": (dict(b=64, hq=64, hkv=8, d=128, mp=96, pool=(1, 1888)), 4),
+    "group8": (dict(b=64, hq=64, hkv=8, d=128, mp=96, pool=(1, 1888)), 4,
+               1),
 }
+# No cell runs one: a chip's slice of an 8-head model under tensor
+# parallelism, 2 heads or 1 (the Mistral cell's widths otherwise); the
+# page is read flat like the hybrid cell's.
+TP_SLICES = {
+    "tp-slice-2": (dict(b=8, hq=8, hkv=2, d=128, mp=64, window=4096,
+                        pool=(16, 768)), 8, 8),
+    "tp-slice-1": (dict(b=8, hq=4, hkv=1, d=128, mp=64, window=4096,
+                        pool=(16, 768)), 8, 16),
+}
+DECODE_SHAPES = {**CELLS, **TP_SLICES}
 
 
 def _decode_writer(sds, hkv=HKV, d=D):
@@ -170,7 +183,7 @@ KERNELS = {
     # plan picks for it: a block that does not fit VMEM fails here.
     **{f"decode-attention[{name}]": functools.partial(_decode_attention,
                                                       **shape)
-       for name, (shape, _) in CELLS.items()},
+       for name, (shape, _, _) in DECODE_SHAPES.items()},
     "decode-kv-writer": _decode_writer,
     "prefill-kv-writer": _prefill_writer,
     # Opt-in kernels: compile-checked here, their A/B is later work.
@@ -324,7 +337,7 @@ def test_paged_fold_pages_at_the_cells_shapes(cell):
     from the same body) where two page pairs do not fit the budget."""
     from xllm_service_tpu.ops.plan import (
         decode_walk_columns, paged_fold_pages)
-    shape, want = CELLS[cell]
+    shape, want, _ = CELLS[cell]
     walk = decode_walk_columns(shape["mp"], PS, shape.get("window", 0))
     assert paged_fold_pages(PS, shape["hkv"], shape["d"], 2, walk) == want
     # a narrower table (the engine's tables are powers of two) caps K
@@ -334,6 +347,26 @@ def test_paged_fold_pages_at_the_cells_shapes(cell):
     assert paged_fold_pages(PS, shape["hkv"], shape["d"], 4,
                             walk) == max(want // 2, 1)
     assert paged_fold_pages(PS, 8 * shape["hkv"], shape["d"], 4, walk) == 1
+
+
+@pytest.mark.parametrize("cell", DECODE_SHAPES)
+def test_paged_flat_positions_at_the_cells_shapes(cell):
+    """Which pages the kernel reads flat, from shapes: those whose head
+    axis is under 8 rows, over whole 128-lane rows; as many positions a
+    tile as fill it (16 rows of bfloat16, 8 of float32)."""
+    from xllm_service_tpu.ops.plan import paged_flat_positions
+    shape, _, want = DECODE_SHAPES[cell]
+    hkv, d = shape["hkv"], shape["d"]
+    assert paged_flat_positions(hkv, d, 2) == want
+    # float32: half as many rows a tile, so 8 heads and more fill it too
+    assert paged_flat_positions(hkv, d, 4) == max(want // 2, 1)
+    # unpacked heads of 64 (or the rehearsal widths' 16) are no whole
+    # row of lanes: the flat view would not name the pool's bytes in
+    # their order, and the page is read by heads
+    assert paged_flat_positions(hkv, 64, 2) == 1
+    assert paged_flat_positions(hkv, 16, 4) == 1
+    # a head count that does not divide a tile is read by heads
+    assert paged_flat_positions(3, d, 2) == paged_flat_positions(6, d, 2) == 1
 
 
 @pytest.mark.parametrize("case", [*KERNELS, "engine-decode-step",
@@ -346,8 +379,19 @@ def test_compiles_for_v5e(aot, monkeypatch, case):
         return
     if case != "engine-decode-step":
         fn, args, jit_kw = KERNELS[case](sds)
-        compiled = aot_compile(fn, args, **jit_kw)
-        assert "tpu_custom_call" in compiled.as_text()
+        text = aot_compile(fn, args, **jit_kw).as_text()
+        assert "tpu_custom_call" in text
+        cell = case.removeprefix("decode-attention[").rstrip("]")
+        if cell in DECODE_SHAPES:
+            # the page as the compiled kernel's operands see it: flat,
+            # a bitcast of the pool (no copy of it), or by heads
+            shape, _, flat = DECODE_SHAPES[cell]
+            page = (f"{PS * shape['hkv']},{shape['d']}" if flat > 1
+                    else f"{PS},{shape['hkv']},{shape['d']}")
+            pool = "bf16[" + ",".join(map(str, shape["pool"]))
+            assert f"{pool},{page}]" in text
+            assert not [ln for ln in text.splitlines()
+                        if " copy(" in ln and pool in ln]
         return
     compiled, pool_nominal_bytes = _engine_decode_step(sds, monkeypatch)
     assert "tpu_custom_call" in compiled.as_text()

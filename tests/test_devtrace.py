@@ -226,9 +226,10 @@ def test_engine_phase_builds_no_annotation_while_off(counted):
     # the step the prefill iteration dispatched at its tail (PR 39)
     args = dict(counted)["xllm.step.decode.tail_dispatch"]
     # (no window in this model: the attention walks the whole table; the
-    # CPU's plan is the XLA reference: no kernel folds a block of pages)
+    # CPU's plan is the XLA reference: no kernel folds a block of pages
+    # and none reads a page flat)
     assert args == {"program": "decode", "B": 2, "T": 1, "MP": args["MP"],
-                    "walk": args["MP"], "fold": 1}
+                    "walk": args["MP"], "fold": 1, "flat": 1}
     assert dict(counted)["xllm.kv.match_prefix"] == {"tokens": 17}
 
 

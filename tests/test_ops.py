@@ -536,8 +536,19 @@ class TestPallasPagedAttention:
     # ``paged_fold_pages``.
     FOLD_PS, FOLD_MP, FOLD_W = 8, 13, 16        # 13: no multiple of a K
 
+    # (key-value heads, head width, pool type) whose page the kernel reads
+    # FLAT (PR 51; ops/plan.py ``paged_flat_positions`` > 1), with the
+    # group sizes folded over each: the hybrid cell's 4 heads at a group
+    # of 8 under a packed query (the odd half of every row zeros), the
+    # fifth cell's group of 5, a TP slice's 2 and 1 heads; 4 positions a
+    # tile of bfloat16 at 4 heads, 2 of float32.
+    FLAT_PAGES = [(4, 128, "bfloat16", 8), (4, 128, "bfloat16", 5),
+                  (4, 128, "float32", 8), (4, 128, "float32", 5),
+                  (2, 128, "bfloat16", 4), (2, 128, "float32", 4),
+                  (1, 128, "bfloat16", 8), (1, 128, "float32", 8)]
+
     @staticmethod
-    def _fold_cases():
+    def _fold_cases(flat_pages):
         import itertools
         cases = [pytest.param(*c, None, id="-".join(
             (f"K{c[0]}", f"g{c[1]}", "layered" if c[2] else "pool4d",
@@ -549,10 +560,31 @@ class TestPallasPagedAttention:
                                   id="K4-soft_cap"))
         cases.append(pytest.param(4, 4, True, "static", True, "sinks",
                                   id="K4-sinks"))
+        # the flat page: every shape under every window and both places
+        # of the current token at the cells' K; every K, the soft cap and
+        # the sinks at the hybrid cell's page
+        flat = [(8, page, n % 2 == 0, window, current, None)
+                for n, (page, window, current) in enumerate(
+                    itertools.product(
+                        flat_pages, ("static", "traced", "none"),
+                        (True, False)))]
+        packed = flat_pages[0]
+        flat += [(K, packed, layered, "static", current, None)
+                 for K, (layered, current) in itertools.product(
+                     (1, 2, 4), ((True, False), (False, True)))]
+        flat += [(4, packed, True, "none", False, "soft_cap"),
+                 (4, packed, True, "static", True, "sinks")]
+        cases += [pytest.param(K, page, layered, window, current, extra,
+                               id="-".join(
+            (f"K{K}", "flat%dx%d%s-g%d" % page,
+             "layered" if layered else "pool4d", window,
+             "in_register" if current else "written")
+            + ((extra,) if extra else ())))
+            for K, page, layered, window, current, extra in flat]
         return cases
 
     @pytest.mark.parametrize("K,group,layered,window,current,extra",
-                             _fold_cases())
+                             _fold_cases(FLAT_PAGES))
     def test_block_fold_matches_reference(self, K, group, layered, window,
                                           current, extra):
         """Rows whose live pages are 0, 1, K - 1, K, K + 1 and the whole
@@ -567,6 +599,8 @@ class TestPallasPagedAttention:
             _paged_decode_attention_impl)
         from xllm_service_tpu.ops.plan import decode_walk_columns
 
+        from xllm_service_tpu.ops.plan import paged_flat_positions
+
         ps, MP, G = self.FOLD_PS, self.FOLD_MP, 4
         W = self.FOLD_W if window != "none" else 0
         walk = decode_walk_columns(MP, ps, W if window == "static" else 0)
@@ -580,14 +614,30 @@ class TestPallasPagedAttention:
         pt, P = self._walk_tables(ctxs, W or MP * ps + 1, ps, MP, current,
                                   G)
         rng = np.random.default_rng(46 + K)
-        Hkv, D, L = 2, 16, 2
+        # ``group``: a group size over the small page the kernel reads by
+        # heads, or a page read flat with its group
+        Hkv, D, dtype, group = (group if isinstance(group, tuple)
+                                else (2, 16, "float32", group))
+        dtype, L = jnp.dtype(dtype), 2
+        flat = paged_flat_positions(Hkv, D, dtype.itemsize)
+        assert (flat > 1) == (D == 128)
+        if flat > 1:
+            # a tile of the flat page holds several positions (a context
+            # ends inside one: ctx % flat != 0 among the rows)
+            assert flat == (16 if dtype.itemsize == 2 else 8) // Hkv
+            assert any(c % flat for c in ctxs)
         B, Hq = len(ctxs), Hkv * group
         pools = rng.normal(size=(2, L, P, ps, Hkv, D))
         pools[:, :, :G] *= 50       # NULL page 0 and the garbage pages
-        k5, v5 = (jnp.asarray(x, jnp.float32) for x in pools)
-        q = jnp.asarray(rng.normal(size=(B, Hq, D)), jnp.float32)
+        k5, v5 = (jnp.asarray(x, dtype) for x in pools)
+        q = rng.normal(size=(B, Hq, D))
+        if flat > 1 and group == 8:
+            # the packed query (models/transformer.py ``_kv_pack``): two
+            # heads of 64 to a row, so half of every query row is zeros
+            q *= (np.arange(Hq)[:, None] % 2 == 0) == (np.arange(D) < 64)
+        q = jnp.asarray(q, dtype)
         pt, ctx = jnp.asarray(pt), jnp.asarray(ctxs, jnp.int32)
-        cur = ([jnp.asarray(rng.normal(size=(B, Hkv, D)), jnp.float32)
+        cur = ([jnp.asarray(rng.normal(size=(B, Hkv, D)), dtype)
                 for _ in range(2)] if current else [None, None])
         cap = 20.0 if extra == "soft_cap" else 0.0
         sinks = (jnp.asarray(rng.normal(size=(Hq,)), jnp.float32)
@@ -605,9 +655,13 @@ class TestPallasPagedAttention:
             walk=walk, fold=K)
         # (a written-token row of context 0 attends to nothing)
         live = np.asarray(ctxs) >= (0 if current else 1)
-        err = np.abs(np.asarray(ref) - np.asarray(out)).max(axis=(1, 2))
-        assert (err[live] < 1e-5).all(), [
-            (c, float(e)) for c, e in zip(ctxs, err) if e >= 1e-5]
+        err = np.abs(np.asarray(ref, np.float32)
+                     - np.asarray(out, np.float32)).max(axis=(1, 2))
+        # bfloat16: the probabilities go to the MXU in the pool's type
+        # and the output is rounded once more (values of a few units)
+        tol = 1e-5 if dtype == jnp.float32 else 4e-2
+        assert (err[live] < tol).all(), [
+            (c, float(e)) for c, e in zip(ctxs, err) if e >= tol]
 
     @pytest.mark.parametrize("window,MP,ps,want", [
         (0, 12, 8, 12),                 # full attention

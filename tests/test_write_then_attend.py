@@ -9,6 +9,8 @@ form against the dual-source reference, and engine-level greedy-token
 identity with the flag on vs off — the acceptance gate of the
 re-plumb."""
 
+import contextlib
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -229,15 +231,53 @@ class TestEngineWriteThenAttend:
     prefix-cache readmission — on both the Pallas (interpreter) and
     pure-XLA serving paths. The acceptance gate of the re-plumb."""
 
-    def test_identical_generations_pallas_path(self, monkeypatch):
+    @pytest.mark.parametrize("heads", ["by_heads", "flat"])
+    def test_identical_generations_pallas_path(self, monkeypatch, caplog,
+                                               heads):
+        """``flat`` (PR 51): two key-value heads of 128, whose page the
+        paged decode kernel reads as one matrix (ops/plan.py
+        ``paged_flat_positions``): written in place as [ps, 2, 128] by
+        the writer, read as [ps * 2, 128] by the kernel in the same step
+        program, with the token in registers (off) and in the pool (on).
+        The engine's plan line and the decode launch's span say so."""
+        import dataclasses
+        import logging
+
+        from xllm_service_tpu.config import ModelConfig
+        from xllm_service_tpu.obs import steptrace
+        cfg, flat = None, 1
+        if heads == "flat":
+            # float32: in bfloat16 the two orders' roundings part on a
+            # near-tie
+            cfg = dataclasses.replace(
+                ModelConfig.tiny(vocab_size=256), name="tiny-flat-page",
+                hidden_size=512, head_dim=128, dtype="float32")
+            flat = 4
+        spans = []
+
+        def span(*parts, **args):
+            spans.append(("".join(parts), args))
+            return contextlib.nullcontext()
+        monkeypatch.setattr(steptrace, "span", span)
         base = {"XLLM_PALLAS": "1", "XLLM_PALLAS_PREFILL": "1"}
-        off = _run_engine(monkeypatch,
-                          dict(base, XLLM_WRITE_THEN_ATTEND="0"))
-        on = _run_engine(monkeypatch,
-                         dict(base, XLLM_WRITE_THEN_ATTEND="1"))
+        with caplog.at_level(logging.INFO,
+                             logger="xllm_service_tpu.runtime.engine"):
+            off = _run_engine(monkeypatch,
+                              dict(base, XLLM_WRITE_THEN_ATTEND="0"),
+                              cfg=cfg)
+            on = _run_engine(monkeypatch,
+                             dict(base, XLLM_WRITE_THEN_ATTEND="1"),
+                             cfg=cfg)
         assert set(off) == set(on)
         for rid in off:
             assert off[rid] == on[rid], rid
+        lines = [m for m in caplog.messages if m.startswith("engine plan:")]
+        assert len(lines) == 2 and all(
+            m.endswith(", a page flat, 4 positions a tile") == (flat > 1)
+            and "; paged fold " in m for m in lines)
+        launches = [a for n, a in spans if n.endswith("dispatch")
+                    and a.get("program") == "decode"]
+        assert launches and all(a["flat"] == flat for a in launches)
 
     def test_identical_generations_xla_path(self, monkeypatch):
         base = {"XLLM_PALLAS": "0", "XLLM_PALLAS_PREFILL": "0"}

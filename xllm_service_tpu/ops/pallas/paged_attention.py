@@ -16,7 +16,8 @@ pool is passed K times with K one-page blocks, which Pallas double-buffers
 page by page; operand j of step p reads column ``p * K + j`` of the folded
 table. A block is a whole page with all KV heads ([ps, Hkv, D] — Pallas
 TPU wants the trailing two block dims full or (8,128)-aligned, so heads
-stay in the block and the GQA grouping happens in-kernel). K comes from
+stay in the block and the GQA grouping happens in-kernel; or the same
+page flat, "The flat page" below). K comes from
 shapes (``ops/plan.py`` ``paged_fold_pages``: the largest power of two
 whose double-buffered block of key and value pages stays under 4 MiB, no
 more than the walk has columns; 4 / 2 / 8 / 8 in the benchmark's cells; 1,
@@ -39,6 +40,34 @@ page-a-step body 0.98 and this one 0.79 (PERF.md section 6, PR 46: the
 body, not the grid, set the pace); a block's padding columns are computed,
 so a wider block is slower where it overshoots the walk.
 
+The flat page (PR 51). A page whose head axis fills a fraction of a
+packed tile (``ops/plan.py`` ``paged_flat_positions`` > 1: under 8
+key-value heads over whole 128-lane rows; 4 positions a tile at 4 heads
+of bfloat16, which is both the hybrid and the fifth cell; 1, the page by
+heads as above, in the Mistral, looped and sixth cells) is read as ONE
+matrix ``[ps * Hkv, D]``, row ``pos * Hkv + h``: a reshape of the pool
+outside the ``pallas_call`` that XLA lowers to a bitcast (the pool's
+``(4, 128)`` tiles of two rows a word hold a position's heads in 1 KB,
+and the view's ``(8, 128)`` tiles pair the same rows), so a block is
+whole tiles. ALL query heads meet it in one plain product,
+``[Hq, D] x [ps * Hkv, D]^T``; a logit whose row's head ``r mod Hkv`` is
+not the query's own is masked with the dead positions before the max, its
+probability is an exact zero, and ``[Hq, ps * Hkv] x [ps * Hkv, D]`` sums
+every live position once. No transpose; the same weight tiles a page as
+the body by heads, with all the query rows streamed through each; Hkv
+times the ``exp`` lanes; statistics ``[Hq, 1]``. By heads a ``[ps, 4, D]``
+page arrived as 128 quarter-dense vregs and the transpose to heads first
+paid a vreg a position: on a v5e 0.59 us a page folded against copies of
+0.36, the flat body 0.39 (2,796 -> 1,864 us a call at the hybrid cell's
+shapes, 252 -> 149 at the fifth's; the output is the body by heads' but
+for one value in ten thousand, a bfloat16 unit off). NOT kept, from the
+same sizing run (PERF.md section 6, PR 51): s positions seen as one
+sublane group ``[ps / s, Hkv * s, D]``, transposed once, the products
+batched over ``Hkv * s`` classes whose softmax states merge in
+``_finalize`` (1,933 us at s = 2, 1,942 at s = 4: one dense transpose and
+s times as many smaller products); the flat page with an update a page
+(2,183) or every 2 or 4 pages (1,914 / 1,879).
+
 The folded table (``_fold_table``), built ONCE outside the kernel: the
 index maps do no arithmetic. ``walk`` is the table's width MP, except
 under a STATIC sliding window W (``ops/plan.py``
@@ -57,7 +86,11 @@ walk, multi-row cells, wide block-diagonal) were deleted when the ragged
 kernel (ops/pallas/ragged_attention.py) subsumed the mixed-step decode
 path — none of them beat this base kernel on hardware, and their flag
 matrix fragmented the bench slots and xlint pins (docs/PERF_NOTES.md
-keeps the post-mortems).
+keeps the post-mortems). The flat page is neither: the wide
+block-diagonal form merged heads into LANES (``[ps, Hkv * D]``, a
+relayout) under a grid of rows alone behind hand-issued copies; here heads
+merge into ROWS, which is free under the pool's tiling, and the grid, the
+pipeline and the block fold stay PR 46's.
 """
 
 from __future__ import annotations
@@ -72,7 +105,7 @@ from jax.experimental.pallas import tpu as pltpu
 from xllm_service_tpu.ops.pallas._compat import (
     CompilerParams as _CompilerParams)
 from xllm_service_tpu.ops.plan import (
-    decode_walk_columns, paged_fold_pages)
+    decode_walk_columns, paged_flat_positions, paged_fold_pages)
 
 _NEG_INF = -1e30
 
@@ -93,9 +126,11 @@ def _query_pos(ctx, has_current: bool):
 def _kernel(ctx_ref, first_ref, pt_ref, win_ref, *refs, fold: int,
             page_size: int, num_kv_heads: int, has_current: bool,
             logits_soft_cap: float, scale: float, has_sinks: bool,
-            layered: bool):
+            layered: bool, flat: bool):
     """One grid step folds a BLOCK of ``fold`` pages of row ``b``: one
-    online-softmax update over the block's [Hkv, G, fold * ps] logits."""
+    online-softmax update over the block's logits, [Hkv, G, fold * ps]
+    page by heads or, ``flat``, [Hq, fold * ps * Hkv] (module docstring,
+    "The flat page")."""
     if layered:
         # the layer is consumed by the block index maps alone
         refs = refs[1:]
@@ -123,40 +158,15 @@ def _kernel(ctx_ref, first_ref, pt_ref, win_ref, *refs, fold: int,
     # context (ctx <= MP * ps), so the mask below drops it like any other.
     block_start = (first_ref[b] + p * fold) * page_size
 
-    @pl.when((block_start < ctx)
-             & (block_start + fold * page_size - 1 > win_floor))
-    def _fold():
-        def heads_first(ref):
-            """A page [ps, Hkv, D] as [Hkv, ps, D], in the pool's own
-            type. ``layered``: the pool rides FULL as [L, P, ps, Hkv, D]
-            and the block is [1, 1, ps, Hkv, D] (no per-layer slice for
-            XLA to materialize in front of this custom call)."""
-            return jnp.transpose(ref[0, 0] if layered else ref[0],
-                                 (1, 0, 2))
+    def soft_cap(lg):
+        if logits_soft_cap > 0.0:
+            return logits_soft_cap * jnp.tanh(lg / logits_soft_cap)
+        return lg
 
-        # Operands in the pool's own type, products summed in f32: the
-        # MXU rounds an f32 operand to bf16 anyway (the same bits as
-        # from f32 copies, on the chip: PERF.md, PR 46). Statistics,
-        # accumulator and output stay f32, grouped [Hkv, G, .] as the
-        # products give them: no regrouping of heads in the fold.
-        qg = q_ref[0].astype(k_refs[0].dtype).reshape(num_kv_heads, g, d)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size), 2)
-        logits, masks = [], []
-        for j, k_ref in enumerate(k_refs):
-            # Batched over Hkv: [Hkv, G, D] x [Hkv, ps, D] -> [Hkv, G, ps]
-            lg = jax.lax.dot_general(
-                qg, heads_first(k_ref), (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32) * scale
-            if logits_soft_cap > 0.0:
-                lg = logits_soft_cap * jnp.tanh(lg / logits_soft_cap)
-            # A dead column holds a page read before (``_fold_table``):
-            # masked by its position, like the NULL page under a window.
-            pos = block_start + j * page_size + lane
-            masks.append((pos < ctx) & (pos > win_floor))    # [1, 1, ps]
-            logits.append(jnp.where(masks[-1], lg, _NEG_INF))
-        # ONE update a block: the pages' logits meet lane by lane, then
-        # one reduce across lanes for the max and one for the sum.
-        m_prev = m_ref[:]                                    # [Hkv, G, 1]
+    def update(logits, masks, prob_x_v):
+        """ONE update a block: the pages' logits meet lane by lane, then
+        one reduce across lanes for the max and one for the sum."""
+        m_prev = m_ref[:]                       # [Hkv, G, 1] | [Hq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(
             functools.reduce(jnp.maximum, logits), axis=-1, keepdims=True))
         # (a block may hold no live position at all: a window of 1 under
@@ -166,14 +176,78 @@ def _kernel(ctx_ref, first_ref, pt_ref, win_ref, *refs, fold: int,
         corr = jnp.exp(m_prev - m_new)
         l_ref[:] = l_ref[:] * corr + jnp.sum(
             functools.reduce(jnp.add, probs), axis=-1, keepdims=True)
-        # [Hkv, G, ps] x [Hkv, ps, D] -> [Hkv, G, D], summed over pages
         acc_ref[:] = acc_ref[:] * corr + functools.reduce(jnp.add, [
-            jax.lax.dot_general(
-                pr.astype(v_ref.dtype), heads_first(v_ref),
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            for pr, v_ref in zip(probs, v_refs)])
+            prob_x_v(pr, v_ref) for pr, v_ref in zip(probs, v_refs)])
         m_ref[:] = m_new
+
+    def page(ref):
+        """A block's page in the pool's own type. ``layered``: the pool
+        rides FULL as [L, P, ...] and the block is [1, 1, ...] (no
+        per-layer slice for XLA to materialize in front of this custom
+        call)."""
+        return ref[0, 0] if layered else ref[0]
+
+    # Operands in the pool's own type, products summed in f32: the MXU
+    # rounds an f32 operand to bf16 anyway (the same bits as from f32
+    # copies, on the chip: PERF.md, PR 46). Statistics, accumulator and
+    # output stay f32, in the shape the products give them.
+    def _fold_by_heads():
+        """A page [ps, Hkv, D] transposed to [Hkv, ps, D], the products
+        batched over Hkv, everything grouped [Hkv, G, .]: no regrouping
+        of heads in the fold."""
+        def heads_first(ref):
+            return jnp.transpose(page(ref), (1, 0, 2))
+
+        qg = q_ref[0].astype(k_refs[0].dtype).reshape(num_kv_heads, g, d)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size), 2)
+        logits, masks = [], []
+        for j, k_ref in enumerate(k_refs):
+            # Batched over Hkv: [Hkv, G, D] x [Hkv, ps, D] -> [Hkv, G, ps]
+            lg = soft_cap(jax.lax.dot_general(
+                qg, heads_first(k_ref), (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * scale)
+            # A dead column holds a page read before (``_fold_table``):
+            # masked by its position, like the NULL page under a window.
+            pos = block_start + j * page_size + lane
+            masks.append((pos < ctx) & (pos > win_floor))    # [1, 1, ps]
+            logits.append(jnp.where(masks[-1], lg, _NEG_INF))
+        # [Hkv, G, ps] x [Hkv, ps, D] -> [Hkv, G, D], summed over pages
+        update(logits, masks, lambda pr, v_ref: jax.lax.dot_general(
+            pr.astype(v_ref.dtype), heads_first(v_ref),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32))
+
+    def _fold_flat():
+        """A page as ONE matrix [ps * Hkv, D], row ``pos * Hkv + h``
+        (whole packed tiles: no transpose, no quarter-dense vreg): ALL
+        query heads against it in one plain product, and a logit whose
+        row's head is not the query's own masked like a dead position, so
+        its probability is an exact zero in ``prob x V``."""
+        rows = page_size * num_kv_heads
+        q = q_ref[0].astype(k_refs[0].dtype)                 # [Hq, D]
+        col = jax.lax.broadcasted_iota(jnp.int32, (hq, rows), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (hq, rows), 0)
+        first_q = (col % num_kv_heads) * g      # the row's head's queries
+        own = (head >= first_q) & (head < first_q + g)       # [Hq, rows]
+        in_page = jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows), 1) // num_kv_heads
+        logits, masks = [], []
+        for j, k_ref in enumerate(k_refs):
+            # [Hq, D] x [ps * Hkv, D] -> [Hq, ps * Hkv]
+            lg = soft_cap(jax.lax.dot_general(
+                q, page(k_ref), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale)
+            pos = block_start + j * page_size + in_page
+            masks.append(own & (pos < ctx) & (pos > win_floor))
+            logits.append(jnp.where(masks[-1], lg, _NEG_INF))
+        # [Hq, ps * Hkv] x [ps * Hkv, D] -> [Hq, D], summed over pages
+        update(logits, masks, lambda pr, v_ref: jax.lax.dot_general(
+            pr.astype(v_ref.dtype), page(v_ref), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
+
+    pl.when((block_start < ctx)
+            & (block_start + fold * page_size - 1 > win_floor))(
+                _fold_flat if flat else _fold_by_heads)
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _finalize():
@@ -323,6 +397,17 @@ def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
     if fold is None:
         fold = paged_fold_pages(page_size, Hkv, D, k_pages.dtype.itemsize,
                                 walk)
+    # A page as the kernel's blocks see it: by heads, or flat where its
+    # head axis fills a fraction of a tile (from shapes: ops/plan.py).
+    # Flat names the same bytes in the same order under the pool's
+    # tiling, so the reshape lowers to a bitcast
+    # (tests/test_copy_census.py holds the cells' pools at no copy).
+    flat = paged_flat_positions(Hkv, D, k_pages.dtype.itemsize) > 1
+    page_shape = (page_size * Hkv, D) if flat else (page_size, Hkv, D)
+    stat_shape = (Hq,) if flat else (Hkv, Hq // Hkv)
+    if flat:
+        k_pages = k_pages.reshape(*k_pages.shape[:-3], *page_shape)
+        v_pages = v_pages.reshape(*v_pages.shape[:-3], *page_shape)
     ctx = context_lens.astype(jnp.int32)
     if walk < MP:
         # Table column of the oldest position the window keeps for the
@@ -343,9 +428,10 @@ def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
         where it is layered (no per-layer slice exists for XLA to
         materialize: 134 MB x layers x 2 pools per decode step)."""
         return pl.BlockSpec(
-            (1,) * (k_pages.ndim - 3) + (page_size, Hkv, D),
+            (1,) * (k_pages.ndim - len(page_shape)) + page_shape,
             lambda b, p, ctx, fst, pt, w, *lyr: (
-                *(l[0] for l in lyr), pt[b, p * fold + j], 0, 0, 0))
+                *(l[0] for l in lyr), pt[b, p * fold + j],
+                *(0,) * len(page_shape)))
 
     pages = [page(j) for j in range(fold)]
     # ctx, first, folded table, win[, layer]
@@ -365,16 +451,16 @@ def _paged_decode_attention_impl(q: jnp.ndarray, k_pages: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, Hq, D), row(lambda b: (b, 0, 0))),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, Hq // Hkv, 1), jnp.float32),    # running max
-            pltpu.VMEM((Hkv, Hq // Hkv, 1), jnp.float32),    # running denom
-            pltpu.VMEM((Hkv, Hq // Hkv, D), jnp.float32),    # accumulator
+            pltpu.VMEM(stat_shape + (1,), jnp.float32),      # running max
+            pltpu.VMEM(stat_shape + (1,), jnp.float32),      # running denom
+            pltpu.VMEM(stat_shape + (D,), jnp.float32),      # accumulator
         ],
     )
     return pl.pallas_call(
         functools.partial(_kernel, fold=fold, page_size=page_size,
                           num_kv_heads=Hkv, has_current=has_current,
                           logits_soft_cap=logits_soft_cap, scale=scale,
-                          has_sinks=has_sinks, layered=layered),
+                          has_sinks=has_sinks, layered=layered, flat=flat),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         grid_spec=grid_spec,
         compiler_params=_CompilerParams(

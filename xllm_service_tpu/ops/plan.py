@@ -232,6 +232,27 @@ def paged_fold_pages(page_size: int, num_kv_heads: int, head_dim: int,
         walk)
 
 
+def paged_flat_positions(num_kv_heads: int, head_dim: int,
+                         itemsize: int) -> int:
+    """Positions of a page that ONE packed tile holds when the paged
+    decode kernel reads the page flat, as the matrix
+    ``[page_size * num_kv_heads, head_dim]`` (ops/pallas/
+    paged_attention.py, "The flat page"); 1: the page is read by heads.
+    A tile is 8 sublanes of 32-bit words, 16 rows of bfloat16. A head
+    axis of 8 rows and more fills its vregs at least as far as the body
+    by heads needs (84-86% of the roofline at 8 heads, PERF.md, PR 46);
+    under that a position's heads are a fraction of a vreg each, and as
+    many positions as fill a tile are read as one (4 at 4 heads of
+    bfloat16, decided on the chip: PERF.md, PR 51). Only over whole
+    128-lane rows, where the view names the pool's bytes in their order.
+    From shapes alone: the kernel, the engine's plan line and the decode
+    launch's span key all read it here."""
+    tile_rows = 8 * max(4 // itemsize, 1)
+    if head_dim % 128 or num_kv_heads >= 8 or tile_rows % num_kv_heads:
+        return 1
+    return tile_rows // num_kv_heads
+
+
 def _on_tpu() -> bool:
     # A backend that fails to initialise raises here: "no TPU" must not
     # be how a broken TPU run looks (it would switch the kernels off and

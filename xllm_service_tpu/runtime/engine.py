@@ -47,7 +47,8 @@ from xllm_service_tpu.config import EngineConfig, ModelConfig
 from xllm_service_tpu.models import transformer
 from xllm_service_tpu.obs import steptrace
 from xllm_service_tpu.ops.plan import (
-    KernelPlan, decode_walk_columns, latent_fold_pages, paged_fold_pages)
+    KernelPlan, decode_walk_columns, latent_fold_pages,
+    paged_flat_positions, paged_fold_pages)
 from xllm_service_tpu.parallel.expert import MOE_STATS
 from xllm_service_tpu.ops.sampling import (
     SamplingTensors, compute_logprobs, compute_top_logprobs, sample_tokens,
@@ -399,11 +400,22 @@ class Engine:
         self._fold_kernel = (
             "latent" if self.plan.latent_decode else
             "paged" if self.plan.decode_attn and not model_cfg.mla else "")
+        # Positions a tile of the page that the paged decode kernel reads
+        # flat, from the function the kernel reads it from (ops/plan.py);
+        # 1 where it reads a page by heads, and where another kernel or
+        # none serves.
+        self._decode_flat = (
+            paged_flat_positions(*self.kv[0].shape[-2:],
+                                 self.kv[0].dtype.itemsize)
+            if self._fold_kernel == "paged" else 1)
         fold = ""
         if self._fold_kernel:
             pages, walk = self._decode_fold(MP), self._decode_walk(MP)
             fold = (f"; {self._fold_kernel} fold {pages} pages a grid "
                     f"step, {-(-walk // pages)} steps of {walk} columns")
+            if self._decode_flat > 1:
+                fold += (f", a page flat, {self._decode_flat} positions "
+                         f"a tile")
         if self.state_model:
             op = "kda" if model_cfg.num_kda_layers else "ssm"
             fold += (f"; {'delta rule' if op == 'kda' else 'mixer'} "
@@ -1981,7 +1993,7 @@ class Engine:
         """The shape key a decode launch's span carries."""
         return dict(program="decode", B=self.ecfg.max_batch_size, T=1,
                     MP=mp, walk=self._decode_walk(mp),
-                    fold=self._decode_fold(mp))
+                    fold=self._decode_fold(mp), flat=self._decode_flat)
 
     def _launch_decode(self, bracket, packed: jnp.ndarray,
                        mirror: np.ndarray, kind: str = "decode"
