@@ -1,8 +1,8 @@
 """A temporary root in which the fixture family is a cell.
 
 The fixture (``tests/chipbench/fixtures/``: a ``deepseek_v3`` configuration
-with its ``weights.py`` and ``reference.py``, a traffic mix and three
-per-layer metric files) is entered in no ``BENCHMARK.json`` of the repo.
+with its ``weights.py`` and ``reference.py``, and a traffic mix) is
+entered in no ``BENCHMARK.json`` of the repo.
 Tests, and the builder's run on the chip, make a root of their own: a
 copy of the benchmark as it is, then the fixture's files and entries
 ADDED to it, exactly as a later PR would add a configuration of another
@@ -57,21 +57,22 @@ def add_fixture(root: str) -> str:
     shutil.copytree(os.path.join(FIXTURES, name),
                     os.path.join(bench_dir, "configs", name),
                     ignore=shutil.ignore_patterns("__pycache__"))
-    for sub in ("traffic", "layer_metrics"):
-        for file in os.listdir(os.path.join(FIXTURES, sub)):
-            dst = os.path.join(bench_dir, sub, file)
-            if os.path.exists(dst):
-                raise FileExistsError(f"{dst}: the fixture edits no file")
-            shutil.copy(os.path.join(FIXTURES, sub, file), dst)
+    for file in os.listdir(os.path.join(FIXTURES, "traffic")):
+        dst = os.path.join(bench_dir, "traffic", file)
+        if os.path.exists(dst):
+            raise FileExistsError(f"{dst}: the fixture edits no file")
+        shutil.copy(os.path.join(FIXTURES, "traffic", file), dst)
     path = os.path.join(root, "BENCHMARK.json")
     with open(path) as f:
         bench = json.load(f)
     cell = entries["workload"]["name"]
     bench["configs"].append(entries["config"])
     bench["workloads"].append(entries["workload"])
-    bench["per_layer"].extend(entries["per_layer"])
-    for m in bench["end_to_end"]:
-        if m["name"] in entries["end_to_end_workloads"]:
+    # no per-layer entry of its own: the three metrics it reports are
+    # read by files that are there, so their lists are lengthened
+    joins = entries["end_to_end_workloads"] + entries["per_layer_workloads"]
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in joins:
             m["workloads"].append(cell)
     with open(path, "w") as f:
         json.dump(bench, f, indent=1)

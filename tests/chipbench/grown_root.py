@@ -7,11 +7,20 @@ What is added to a copy of ``BENCHMARK.json`` and ``chipbench/``:
 - a configuration directory (``mistral-7b-v03``'s files under a new name)
   with its entry under ``configs``;
 - a traffic mix file and the cell's entry under ``workloads``;
-- the cell's name appended to every ``workloads`` list that names every
-  cell there was (``out_tok_s``'s, and those of the per-layer metrics
-  that every cell reports);
+- the cell's name appended to the ``workloads`` list of every metric
+  whose file fits the cell as it stands, WHICHEVER cells that list
+  names: ``out_tok_s``'s and every per-layer metric's that names every
+  cell there was (``decode_step_ms.docqa``, ``kv_index_ms.docqa``,
+  ``hbm_peak_gb``, ...: a name's suffix is the mix the metric first
+  arrived with and says nothing about who reports it), and the two of
+  ``JOINS``, whose lists name only some cells and whose files read a
+  dense grouped-query decoder's step as they stand;
 - four trailing ``per_layer`` entries that list the new cell alone, one of
-  each ``source``, each with its metric file; one new reader serves them;
+  each ``source``, each with its metric file; one new reader serves them:
+  the example of what is truly new (a reading no file that is there
+  gives). No twin: a copy of a file under a name of the new mix's would
+  read what the entry that is there reads
+  (``test_no_two_entered_metric_files_read_the_same``);
 - a kernel cost function.
 
 The shape tests of ``tests/chipbench`` run against the tree as committed
@@ -32,6 +41,11 @@ import fixture_root            # beside this file (pytest prepends its directory
 
 CONFIG, MIX, CELL = "grown-model", "trickle", "grown-model-trickle"
 READER, KERNEL_COST = "grown_constant", "grown_matmul"
+# metrics whose lists name only some cells and whose files fit this one:
+# the batch's occupancy (the sparse cells lack it), and the paged
+# kernel's roofline under ``kernel_costs/decode_attention.py`` (every
+# layer of a grouped-query decoder attends)
+JOINS = ("decode_batch_occupancy.docqa", "decode_attn_roofline.docqa")
 # one metric of each source, all the new cell's alone
 METRICS = {"grown_device_ms.trickle": "device_trace",
            "grown_span_ms.trickle": "program_span",
@@ -99,7 +113,8 @@ def grow(root: str) -> Grown:
         "why": "a test's: what a model_config PR adds, entered in no "
                "BENCHMARK.json of the repo"})
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if set(m.get("workloads", ())) == set(cells):     # out_tok_s too
+        if set(m.get("workloads", ())) == set(cells) \
+                or m["name"] in JOINS:                    # out_tok_s too
             m["workloads"].append(CELL)
     bench["per_layer"].extend(entries)
     with open(bench_path, "w") as f:

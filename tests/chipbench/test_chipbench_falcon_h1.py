@@ -19,7 +19,8 @@ import pytest
 
 import fixture_root            # beside this file (pytest prepends its directory)
 from chipbench import check, spec, weights
-from test_chipbench_rehearsal import rehearsal_counters
+from test_chipbench_rehearsal import (EVERY_CELL_REPORTS,
+                                      rehearsal_counters)
 
 CONFIG = os.path.join(spec.ROOT, "chipbench", "configs", "falcon-h1-34b")
 CELL = "falcon-h1-34b-syschat32"
@@ -354,9 +355,11 @@ def test_the_new_reader_on_hand_made_steps():
     assert read("state_slots_live_peak.syschat32", old) is None
     assert read("state_restored_share.syschat32", old) is None
     # and no device metric without a trace
-    for name in ("ssm_update_roofline", "ssm_share_of_decode_step",
-                 "decode_step_roofline", "decode_attn_roofline"):
-        assert read(name + ".syschat32") is None
+    for name in ("ssm_update_roofline.syschat32",
+                 "ssm_share_of_decode_step.syschat32",
+                 "decode_step_roofline.syschat32",
+                 "decode_attn_roofline.docqa"):
+        assert read(name) is None
     with pytest.raises(ValueError):
         spec.load_reader("state_step_stat").read(ctx, {"stat": "nope"})
 
@@ -417,20 +420,15 @@ def test_every_metric_of_the_cell_has_its_file_and_its_reader(root):
     cell = spec.load_cell(CELL, root)
     names = {m["name"] for m in cell.per_layer}
     own = {f"{n}.syschat32" for n in (
-        "decode_step_ms", "device_idle_share", "launch_gap_ms",
-        "sched_pack_ms", "itl_tail_ms", "emit_ms", "obs_flush_ms",
-        "kv_index_ms", "decode_upload_ms", "decode_ahead_ms",
-        "decode_tail_ms", "decode_batch_occupancy",
-        "attn_share_of_decode_step", "decode_attn_roofline",
-        "state_restored_share", "ssm_update_roofline",
-        "ssm_share_of_decode_step", "decode_step_roofline",
-        "state_snapshot_evictions", "state_slots_live_peak")}
-    shared = {"prefix_hit_token_share.docqa", "kv_pages_peak_share.docqa",
-              "compiles_in_window.docqa", "prefill_tok_s", "hbm_peak_gb",
-              "engine_thread_own_share.docqa"} | {
-        f"ttft_{s}_ms.docqa" for s in (
-            "master_in", "parse", "lock_wait", "queue", "prefill_host",
-            "prefill_device", "post_emit", "stream_out", "unattributed")}
+        "ssm_update_roofline", "ssm_share_of_decode_step",
+        "decode_step_roofline")}
+    # entries that other cells list too (PR 52 folded the twins into lists)
+    shared = EVERY_CELL_REPORTS | {
+        "decode_batch_occupancy.docqa",
+        "attn_share_of_decode_step.docqa64", "decode_attn_roofline.docqa",
+        "state_restored_share.syschat32",
+        "state_snapshot_evictions.syschat32",
+        "state_slots_live_peak.syschat32"}
     assert own | shared == names
     assert {m["name"] for m in cell.end_to_end} == {
         "ttft_p50_ms", "out_tok_s", "setup_s"}
@@ -447,7 +445,7 @@ def test_every_metric_of_the_cell_has_its_file_and_its_reader(root):
                if m["name"] in own)
     assert rehearsal_counters(CELL, root) == {
         "prefix_hit_token_share.docqa", "kv_pages_peak_share.docqa",
-        "compiles_in_window.docqa", "decode_batch_occupancy.syschat32",
+        "compiles_in_window.docqa", "decode_batch_occupancy.docqa",
         "state_restored_share.syschat32",
         "state_snapshot_evictions.syschat32",
         "state_slots_live_peak.syschat32"}
@@ -456,7 +454,7 @@ def test_every_metric_of_the_cell_has_its_file_and_its_reader(root):
 def test_the_span_the_index_metric_reads_is_in_the_catalog():
     from xllm_service_tpu.obs import steptrace
     import re
-    info = spec.layer_metric_file("kv_index_ms.syschat32")
+    info = spec.layer_metric_file("kv_index_ms.docqa")
     hit = [n for n in steptrace.SPAN_NAMES
            if re.search(info["span_pattern"], n)]
     assert sorted(hit) == ["xllm.kv.match_prefix", "xllm.kv.register_pages",

@@ -270,7 +270,7 @@ def test_every_metric_of_the_cell_has_its_file_and_its_reader(root):
     cell = spec.load_cell(CELL, root)
     names = {m["name"] for m in cell.per_layer}
     assert {"decode_attn_roofline.docqa64", "state_restored_share.docqa64",
-            "moe_gmm_roofline.docqa64", "attn_share_of_decode_step.docqa64",
+            "moe_gmm_roofline.docqa32", "attn_share_of_decode_step.docqa64",
             "prefix_hit_token_share.docqa", "hbm_peak_gb"} <= names
     assert {m["name"] for m in cell.end_to_end} == {
         "ttft_p50_ms", "out_tok_s", "setup_s"}

@@ -53,6 +53,21 @@ def window_schedule(cell):
 SCHEDULES = {}      # trace mode -> the window's schedule, same seed
 
 
+# The 26 per-layer metrics that list every cell: a name's suffix is the
+# mix a metric first arrived with, its entry's ``workloads`` says who
+# reports it (PR 52 folded the cells' twins into these lists).
+EVERY_CELL_REPORTS = {"prefill_tok_s", "hbm_peak_gb"} | {
+    f"{n}.docqa" for n in (
+        "prefix_hit_token_share", "kv_pages_peak_share",
+        "compiles_in_window", "engine_thread_own_share", "decode_step_ms",
+        "device_idle_share", "launch_gap_ms", "sched_pack_ms",
+        "itl_tail_ms", "emit_ms", "obs_flush_ms", "kv_index_ms",
+        "decode_upload_ms", "decode_ahead_ms", "decode_tail_ms")} | {
+    f"ttft_{s}_ms.docqa" for s in (
+        "master_in", "parse", "lock_wait", "queue", "prefill_host",
+        "prefill_device", "post_emit", "stream_out", "unattributed")}
+
+
 def rehearsal_counters(cell, root=ROOT):
     """The per-layer metrics a ``--rehearse-cpu`` run of ``cell`` prints
     under a trace: those that LIST THE CELL and whose file says

@@ -21,7 +21,8 @@ import pytest
 
 import fixture_root            # beside this file (pytest prepends its directory)
 from chipbench import check, spec, weights
-from test_chipbench_rehearsal import rehearsal_counters
+from test_chipbench_rehearsal import (EVERY_CELL_REPORTS,
+                                      rehearsal_counters)
 
 CONFIG = os.path.join(spec.ROOT, "chipbench", "configs", "solar-open2-250b")
 CELL = "solar-open2-250b-statedoc64"
@@ -405,7 +406,7 @@ def test_the_new_readers_on_hand_made_steps():
     # of the 40 HELD x 4 sparse layers, over the two decode-only steps
     assert read("moe_experts_touched_share.statedoc64") == pytest.approx(
         100.0 * (120 + 136) / (40 * 4 * 2))
-    assert read("moe_dropped_assignments.statedoc64") == 0
+    assert read("moe_dropped_assignments.docqa32") == 0
     # the parent's records (no elsewhere), and a program without any
     old = dict(ctx, steps=[{"t_wall": 100.5, "kind": "decode", "moe": {
         "assignments": 5, "dropped": 0, "experts_touched": 3,
@@ -414,10 +415,13 @@ def test_the_new_readers_on_hand_made_steps():
     assert read("moe_held_assignment_share.statedoc64",
                 dict(ctx, steps=[])) is None
     # and no device metric without a trace
-    for name in ("kda_update_roofline", "kda_share_of_decode_step",
-                 "decode_step_roofline", "decode_attn_roofline",
-                 "moe_gmm_roofline", "attn_share_of_decode_step"):
-        assert read(name + ".statedoc64") is None
+    for name in ("kda_update_roofline.statedoc64",
+                 "kda_share_of_decode_step.statedoc64",
+                 "decode_step_roofline.statedoc64",
+                 "decode_attn_roofline.statedoc64",
+                 "moe_gmm_roofline.docqa32",
+                 "attn_share_of_decode_step.docqa64"):
+        assert read(name) is None
 
 
 def test_the_kernel_rooflines_on_a_hand_made_trace():
@@ -493,23 +497,19 @@ def test_every_metric_of_the_cell_has_its_file_and_its_reader(root):
     cell = spec.load_cell(CELL, root)
     names = {m["name"] for m in cell.per_layer}
     own = {f"{n}.statedoc64" for n in (
-        "decode_step_ms", "device_idle_share", "launch_gap_ms",
-        "sched_pack_ms", "itl_tail_ms", "emit_ms", "obs_flush_ms",
-        "kv_index_ms", "decode_upload_ms", "decode_ahead_ms",
-        "decode_tail_ms", "decode_batch_occupancy",
         "kda_update_roofline", "kda_share_of_decode_step",
-        "moe_gmm_roofline", "moe_gmm_share_of_decode_step",
-        "moe_experts_touched_share", "moe_load_max_over_mean",
-        "moe_dropped_assignments", "moe_held_assignment_share",
-        "decode_attn_roofline", "attn_share_of_decode_step",
-        "state_restored_share", "state_snapshot_evictions",
-        "state_slots_live_peak", "decode_step_roofline")}
-    shared = {"prefix_hit_token_share.docqa", "kv_pages_peak_share.docqa",
-              "compiles_in_window.docqa", "prefill_tok_s", "hbm_peak_gb",
-              "engine_thread_own_share.docqa"} | {
-        f"ttft_{s}_ms.docqa" for s in (
-            "master_in", "parse", "lock_wait", "queue", "prefill_host",
-            "prefill_device", "post_emit", "stream_out", "unattributed")}
+        "moe_experts_touched_share", "moe_held_assignment_share",
+        "decode_attn_roofline", "decode_step_roofline")}
+    # entries that other cells list too (PR 52 folded the twins into lists)
+    shared = EVERY_CELL_REPORTS | {
+        "decode_batch_occupancy.docqa",
+        "attn_share_of_decode_step.docqa64",
+        "state_restored_share.syschat32",
+        "state_snapshot_evictions.syschat32",
+        "state_slots_live_peak.syschat32"} | {
+        f"{n}.docqa32" for n in (
+            "moe_gmm_roofline", "moe_gmm_share_of_decode_step",
+            "moe_load_max_over_mean", "moe_dropped_assignments")}
     assert own | shared == names
     assert {m["name"] for m in cell.end_to_end} == {
         "ttft_p50_ms", "out_tok_s", "setup_s"}
@@ -526,11 +526,11 @@ def test_every_metric_of_the_cell_has_its_file_and_its_reader(root):
                if m["name"] in own)
     assert rehearsal_counters(CELL, root) == {
         "prefix_hit_token_share.docqa", "kv_pages_peak_share.docqa",
-        "compiles_in_window.docqa", "decode_batch_occupancy.statedoc64",
+        "compiles_in_window.docqa", "decode_batch_occupancy.docqa",
         "moe_held_assignment_share.statedoc64",
-        "state_restored_share.statedoc64",
-        "state_snapshot_evictions.statedoc64",
-        "state_slots_live_peak.statedoc64"}
+        "state_restored_share.syschat32",
+        "state_snapshot_evictions.syschat32",
+        "state_slots_live_peak.syschat32"}
 
 
 def test_the_cell_walks_the_whole_command_in_a_copied_root(tmp_path):
@@ -563,8 +563,8 @@ def test_the_cell_walks_the_whole_command_in_a_copied_root(tmp_path):
     assert cmp_["served_token_gap_p90"]["limit"] == 0.07
     m = out["metrics"]
     assert set(m) == {"setup_s"} | rehearsal_counters(CELL, root)
-    assert m["state_restored_share.statedoc64"]["value"] == 100.0
+    assert m["state_restored_share.syschat32"]["value"] == 100.0
     assert 70 < m["prefix_hit_token_share.docqa"]["value"] < 90
-    assert 1 <= m["state_slots_live_peak.statedoc64"]["value"] <= 2
+    assert 1 <= m["state_slots_live_peak.syschat32"]["value"] <= 2
     # 4 of 32 experts held at the rehearsal's widths: about an eighth
     assert 0.05 < m["moe_held_assignment_share.statedoc64"]["value"] < 0.25

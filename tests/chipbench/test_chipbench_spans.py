@@ -123,6 +123,40 @@ def test_span_reader_reduces_as_its_file_says(metric, want_ns):
     assert got == pytest.approx(want_ns / 1e6)
 
 
+NARROW = r"^xllm\.kv\.(match_prefix|register_pages)$"     # before PR 52
+
+
+@pytest.mark.parametrize("events,want", [("hand-made", 16e-6),
+                                         ("recorded", None)])
+def test_the_index_metrics_wider_pattern_reads_the_narrower_ones_number(
+        events, want):
+    """``kv_index_ms.docqa`` took ``state_slots`` into its alternation
+    when the six cells' files folded into one (PR 52). Where the program
+    writes no ``xllm.kv.state_slots`` span (every model whose state is
+    pages alone) the wider pattern reads what the narrower read: on the
+    hand-made iterations, and on the recorded v5e slice (a trace of PR
+    26, before the program wrote spans: both read nothing)."""
+    i = info("kv_index_ms.docqa")
+    assert i["span_pattern"] \
+        == r"^xllm\.kv\.(match_prefix|register_pages|state_slots)$"
+    evs = HAND if events == "hand-made" else trace.read_events(os.path.join(
+        spec.ROOT, "chipbench", "testdata", "trace_small.json.gz"))
+    assert spans.per_step_ms(evs, NARROW, i["reduce"]) \
+        == host_span_ms.read({"trace": {"events": evs}}, i) \
+        == (want if want is None else pytest.approx(want))
+
+
+def test_the_index_metric_reads_a_state_slots_span_where_one_is_written():
+    """As ``kv_index_ms.syschat32`` and ``.statedoc64`` did: a slot
+    reserved inside the prefill's pack, 6 more over two steps."""
+    i = info("kv_index_ms.docqa")
+    slots = HAND + [sp("xllm.kv.state_slots", 360, 6)]
+    assert host_span_ms.read({"trace": {"events": slots}}, i) \
+        == pytest.approx(19e-6)
+    assert spans.per_step_ms(slots, NARROW, i["reduce"]) \
+        == pytest.approx(16e-6)
+
+
 def test_per_step_reductions_and_their_empty_cases():
     assert spans.per_step_ms(HAND, r"^xllm\.loop\.emit$",
                              "median_per_span") == pytest.approx(20e-6)
@@ -199,7 +233,7 @@ def test_every_new_metric_has_its_file_entry_and_reader(root):
         i, e = spec.layer_metric_file(name, root), entries[name]
         assert (i["layer"], i["unit"], i["source"], i["moves"]) == \
             (e["layer"], e["unit"], e["source"], e["moves"])
-        # entered for the Mistral cell alone: a later cell brings twins
-        # under names of its own and is appended to no such list
-        assert e["workloads"] == ["mistral7b-v01-docqa"]
+        # entered with the Mistral cell; the cells that came later are
+        # appended to the list (PR 52: no twin under a name of theirs)
+        assert e["workloads"][0] == "mistral7b-v01-docqa"
         assert callable(spec.load_reader(i["reader"], root).read)
