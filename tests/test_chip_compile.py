@@ -158,6 +158,18 @@ def _kda_update(sds):
              sds((b, h), jnp.float32)), {"donate_argnums": (0,)})
 
 
+def _retention_update(sds):
+    from xllm_service_tpu.ops.pallas.retention_update import (
+        retention_decode_update)
+    b, h, g, d = 16, 8, 5, 128
+    row = sds((b, h, d), jnp.float32)
+    return (functools.partial(retention_decode_update, interpret=False),
+            (sds((4, 49, h, 8392, d), jnp.float32), sds((), jnp.int32),
+             sds((b,), jnp.int32), sds((b,), jnp.int32),
+             sds((b, h * g, d), jnp.float32), row, row,
+             sds((b, h), jnp.float32)), {"donate_argnums": (0,)})
+
+
 def _ring_writer(sds, rows, pool):
     """The pool pinned row-major on both sides, as an engine pins it,
     and a second result beside it, as every step program has: the kernel
@@ -214,6 +226,13 @@ KERNELS = {
     # heads mapped by slot, four [128, 1] columns a head sliced out of a
     # 32-lane block.
     "kda-decode-update[cell]": _kda_update,
+    # A retention layer's one-token state update (ops/pallas/
+    # retention_update.py) at the benchmark cell's shapes: 16 rows, 8
+    # key-value heads of 8,392 x 128 float32 (4.3 MB a block, in and out
+    # double-buffered 17.2 MB: the kernel asks for its VMEM) in a pool
+    # of 4 layers x 49 slots, five query heads a block; the strided lane
+    # rotation that expands a key and the two [128, 128] transposes.
+    "retention-decode-update[cell]": _retention_update,
     # A state layer's filter ring written in place (ops/pallas/
     # ring_update.py) at both cells' shapes: a copy a row from [B, K, C]
     # into a page of [n, P, K, C] (a page's ring has to be

@@ -11,6 +11,7 @@ Two tiers inside one file:
   AOT path exists.
 """
 
+import math
 import os
 
 import jax.numpy as jnp
@@ -576,6 +577,10 @@ PREFILL_CELLS = {
     # one contiguous piece of [n, P, K, C] and one gather reads it (two
     # rows: ``test_a_wide_ring_is_gathered_where_it_lies``)
     "solar-open2-250b": (0, 1888, 64, 193, [(1, 2048, 96)]),
+    # NO pool of keys, values or tails (shapes of no layers, which the
+    # census leaves out); the pool of states, 6.7 GB, is read and
+    # written a row at a time where it lies (transformer._ret_window)
+    "brumby-14b": (0, 1024, 16, 49, [(1, 2048, 168), (2, 256, 168)]),
 }
 CELL_SHAPES = [(cell, shape) for cell, spec in PREFILL_CELLS.items()
                for shape in spec[4]]
@@ -662,10 +667,34 @@ class TestPrefillReadsPagesOffThePool:
         tails = 3 * 1888 * 4 * 24576 * 2
         assert compiled.memory_analysis().temp_size_in_bytes < tails // 4
 
+    def test_sixteen_retention_windows_hold_one_rows_temporaries(self, aot):
+        """A retention layer's entry of one row is 34 MB a layer: sixteen
+        follow-ups gathered, scanned and scattered side by side would
+        hold over 2 GB of entries (a source, a final state and a
+        snapshot each) beside a pool of 6.7 GB and weights of 5.75 on a
+        chip of 16. Walked one row after another the program's
+        temporaries stay under a gigabyte (0.75 GB, compiled for a
+        described v5e, PR 53; 0.51 at one row), and the pool is never
+        copied."""
+        import tools.aot_copy_census as cc
+        aot_compile, _ = aot
+        name = "brumby-14b"
+        _, pages, batch, slots, _ = PREFILL_CELLS[name]
+        programs, _, pools = cc.build_cell_programs(
+            _cell_config(name, 0), pages, 168, batch, window=256,
+            state_slots=slots, prefill_rows=16)
+        assert [math.prod(p) for p in pools[:3]] == [0, 0, 0]
+        fn, args, jit_kw = programs["prefill"]
+        compiled = aot_compile(fn, args, **jit_kw)
+        assert cc.census_pools(compiled.as_text(), pools) == ([], [])
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
     # (not the delta-rule cell: ONE of its layers attends, and a layer
-    # sliced out of a pool of one layer is the pool)
+    # sliced out of a pool of one layer is the pool; not the retention
+    # cell: no layer attends)
     @pytest.mark.parametrize("cell", [c for c in PREFILL_CELLS
-                                      if c != "solar-open2-250b"])
+                                      if c not in ("solar-open2-250b",
+                                                   "brumby-14b")])
     def test_positive_control_slice_then_gather(self, aot, cell,
                                                 monkeypatch):
         """The parent's form patched in: the census must find the slice

@@ -659,7 +659,8 @@ def test_what_still_cannot_run_is_refused_by_name():
     mc = model()
     with pytest.raises(ValueError, match="'conv' operator has no 'mix'"):
         dataclasses.replace(mc, layer_kinds=("kda+moe", "conv+moe"))
-    with pytest.raises(ValueError, match="'mix' or 'kda' layers, not both"):
+    with pytest.raises(ValueError,
+                       match="'mix', 'kda' or 'ret' layers, one of the three"):
         dataclasses.replace(mc, layer_kinds=("kda+moe", "mix+moe"),
                             ssm_heads=4)
     with pytest.raises(ValueError, match="'kda' operator gives"):

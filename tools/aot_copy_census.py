@@ -269,8 +269,12 @@ def census_pools(hlo_text: str, pool_shapes, window=None) -> tuple:
     what the window's tokens count is left out of the layer census,
     which tells by shape and cannot tell the window's own activations
     from it (256 pages of rings [4, 5120] under a window of 1,024
-    tokens over the mixer's 5,120 channels); its copies still count."""
+    tokens over the mixer's 5,120 channels); its copies still count. A
+    pool of no elements is no pool."""
     tokens = math.prod(window) if window else None
+    # a pool of no layers (a kind of layer the model has none of) has no
+    # bytes: nothing of it can be sliced or copied
+    pool_shapes = [s for s in pool_shapes if math.prod(s)]
     return (census_layer_results(
         hlo_text, [s for s in pool_shapes if math.prod(s[1:-1]) != tokens]),
         [hit for pool in set(map(tuple, pool_shapes))

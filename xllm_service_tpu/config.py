@@ -301,6 +301,19 @@ class ModelConfig:
     # "elsewhere"). 1 chip = every expert held: every other model.
     expert_share_chips: int = 1
     expert_share_rank: int = 0
+    # A power-retention layer (operator "ret" in ``layer_kinds``; Brumby)
+    # INSTEAD of attention: causal attention whose weight is (q . k)^p in
+    # place of exp(q . k), times a learned decay a KEY-VALUE head, the
+    # output divided by the sum of its weights. For even p the weight is
+    # a dot product of symmetric-power features, so the layer keeps a
+    # fixed state by slot and NOTHING else: no keys and values, no tail,
+    # no ring. Heads are the attention's (``num_heads`` queries over
+    # ``num_kv_heads`` states), q and k normed and rotated as the
+    # attention's are; a head's state is the symmetric half of the
+    # degree-2 products of its ``head_dim`` key channels against its
+    # value channels, and a normaliser beside it (``ret_state_rows``).
+    # Only degree 2 is implemented. 0 = no such layer.
+    ret_degree: int = 0
     dtype: str = "bfloat16"
 
     def __post_init__(self) -> None:
@@ -330,11 +343,23 @@ class ModelConfig:
                 "conv_kernel rows, a 'conv' layer's are conv_kernel - 1 "
                 "gated inputs, and the one pool of tails holds one kind "
                 "of row")
-        if {"mix", "kda"} <= ops:
+        if len(ops & {"mix", "kda", "ret"}) > 1:
             raise ValueError(
-                "a layer_kinds model has 'mix' or 'kda' layers, not both: "
-                "the pool of matrix states by slot has one shape a head "
-                "and the ring one width")
+                "a layer_kinds model has 'mix', 'kda' or 'ret' layers, one "
+                "of the three: the pool of matrix states by slot has one "
+                "shape a head and the ring one width (or none)")
+        if "ret" in ops and (self.ret_degree != 2 or self.head_dim % 2
+                             or self.num_heads % self.num_kv_heads):
+            raise ValueError(
+                "a layer_kinds model with a 'ret' operator gives "
+                "ret_degree 2 (the one degree implemented), an even "
+                "head_dim and num_heads a multiple of num_kv_heads")
+        if "ret" in ops and "conv" in ops:
+            raise ValueError(
+                "a layer_kinds model with a 'ret' operator has no 'conv' "
+                "layer: no model has both, and a 'conv' tail shifted in "
+                "place beside a state that a discarded launch ahead must "
+                "leave as it was is run by no test")
         if not (0 <= self.expert_share_rank < self.expert_share_chips):
             raise ValueError(
                 f"expert_share_rank={self.expert_share_rank} is not a "
@@ -404,8 +429,9 @@ class ModelConfig:
     @property
     def num_state_layers(self) -> int:
         """Layers that keep a matrix state a head (the fourth pool, by
-        slot): a "mix" operator's mixer, a "kda" operator."""
-        return sum(k.startswith(("mix+", "kda+"))
+        slot): a "mix" operator's mixer, a "kda" operator, a "ret"
+        operator (which keeps nothing else)."""
+        return sum(k.startswith(("mix+", "kda+", "ret+"))
                    for k in self.layer_kinds or ())
 
     @property
@@ -419,10 +445,38 @@ class ModelConfig:
         return sum(k.startswith("kda+") for k in self.layer_kinds or ())
 
     @property
+    def num_ret_layers(self) -> int:
+        """Power-retention layers."""
+        return sum(k.startswith("ret+") for k in self.layer_kinds or ())
+
+    @property
+    def ret_blocks(self) -> int:
+        """Blocks of a retention head's state: block d holds the products
+        of key channels i and (i - d) mod head_dim for every i, and d = 0
+        .. head_dim / 2 reaches every unordered pair once (the last block
+        twice: its upper half stays zero)."""
+        return self.head_dim // 2 + 1
+
+    @property
+    def ret_state_rows(self) -> int:
+        """Rows of a retention head's state in the pool: ``ret_blocks``
+        blocks of ``head_dim`` rows (a block is [value channel, key
+        channel i]: the matrix, 8,320 rows of which 8,256 are the
+        symmetric half at a head of 128) and the normaliser's
+        ``ret_blocks`` rows [key channel i] behind them, to a tile's 8
+        (8,392 in all). NOT the head_dim x head_dim = 16,384-row full
+        product."""
+        return self.ret_blocks * self.head_dim + -(-self.ret_blocks // 8) * 8
+
+    @property
     def state_shape(self) -> Tuple[int, int, int]:
         """A layer's state of one sequence in the pool by slot: (heads,
         sublane axis, lane axis). A mixer's matrix is kept [state, head
-        width], a delta-rule head's [key channel, value channel]."""
+        width], a delta-rule head's [key channel, value channel], a
+        retention head's matrix AND normaliser as ``ret_state_rows`` rows
+        over the key channel."""
+        if self.num_ret_layers:
+            return (self.num_kv_heads, self.ret_state_rows, self.head_dim)
         if self.num_kda_layers:
             return (self.kda_heads, self.kda_head_dim, self.kda_head_dim)
         return (self.ssm_heads, self.ssm_state, self.ssm_head_dim)
@@ -462,7 +516,9 @@ class ModelConfig:
 
     @property
     def conv_tail_shape(self) -> tuple:
-        """One row of the pool of convolution tails. A "conv" layer keeps
+        """One row of the pool of convolution tails (of which a model
+        without convolution layers keeps none:
+        ``transformer.init_kv_cache``). A "conv" layer keeps
         its last conv_kernel - 1 gated inputs over hidden_size channels,
         flat; a state layer (a mixer, a delta-rule layer) keeps a RING
         of conv_kernel inputs over its ``ring_channels``, [K, C], the
@@ -704,7 +760,7 @@ class ModelConfig:
                      "qwen2_vl", "qwen2_5_vl",
                      "qwen3_moe", "deepseek_v2", "deepseek_v3",
                      "joyai_llm_flash", "gpt_oss", "lfm2_moe", "ouro",
-                     "falcon_h1", "solar_open2")
+                     "falcon_h1", "solar_open2", "brumby")
         # The latent family: DeepSeek-V2, and the V3 layer (sigmoid
         # scores, selection bias) that JD's JoyAI-LLM-Flash shares.
         _v3 = mt in ("deepseek_v3", "joyai_llm_flash")
@@ -855,6 +911,22 @@ class ModelConfig:
             # ``expert_share_chips`` times as many routed (the
             # deployment's share: the program's own two keys beside the
             # published ones, 1 and 0 where a config has neither).
+        _brm = mt == "brumby"
+        if _brm:
+            # Manifest AI Brumby: Qwen3's block (q and k normed a head and
+            # rotated, SwiGLU, untied head) with power retention in place
+            # of softmax attention in EVERY layer. The config carries no
+            # key of the retention itself: degree 2 and one sigmoid decay
+            # a key-value head are the family's published description.
+            for key, want in (("attention_bias", False),
+                              ("hidden_act", "silu"),
+                              ("rope_scaling", None),
+                              ("use_sliding_window", False)):
+                if d.get(key, want) != want:
+                    raise ValueError(
+                        f"brumby with {key}={d[key]!r} is not implemented "
+                        f"(only {want!r})")
+            layer_kinds = ("ret+dense",) * d["num_hidden_layers"]
         if mt == "ouro" and set(d.get("layer_types") or ()) \
                 - {"full_attention"}:
             raise ValueError(
@@ -883,7 +955,7 @@ class ModelConfig:
         sw = d.get("sliding_window") or None
         if sw is not None \
                 and mt in ("qwen2", "qwen3", "qwen2_vl", "qwen2_5_vl",
-                           "qwen3_moe") \
+                           "qwen3_moe", "brumby") \
                 and not d.get("use_sliding_window", False):
             # Qwen2-family raw config.json declares-but-disables the
             # window (e.g. Qwen2.5-7B-Instruct-1M: sliding_window 32768,
@@ -948,7 +1020,8 @@ class ModelConfig:
                                  in ("qwen2", "qwen2_vl", "qwen2_5_vl",
                                      "gpt_oss")),
             qk_norm=d.get("model_type") in ("qwen3", "qwen3_moe",
-                                            "gemma3_text", "lfm2_moe"),
+                                            "gemma3_text", "lfm2_moe",
+                                            "brumby"),
             fused_proj=d.get("model_type") == "phi3",
             sliding_window=sw,
             layer_sliding=layer_sliding,
@@ -1041,6 +1114,7 @@ class ModelConfig:
                 "mlp_multipliers":
                     tuple(float(x) for x in d["mlp_multipliers"])}
                if _fh1 else {}),
+            ret_degree=2 if _brm else 0,
             gptoss=mt == "gpt_oss",
             rope_interleave=bool(d.get("rope_interleave", True)),
             # The mscale² softmax-scale fold follows the CHECKPOINT, not

@@ -160,11 +160,15 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
         # flat, so that no short axis is padded to a tile; a state
         # layer's is its ring [K, C], a page's ring one contiguous piece
         # that a decode step writes in place (ops/pallas/ring_update.py).
+        # A kind of layer the model has NONE of gets a pool of no layers
+        # and no bytes (a retention model keeps neither keys and values
+        # nor tails: its pages are bookkeeping); the pool still says the
+        # page size and the pool's pages by its shape, which is all a
+        # step program reads of it.
         pack = _kv_pack(cfg)
-        shape = (max(cfg.num_attn_layers, 1), num_pages, page_size,
+        shape = (cfg.num_attn_layers, num_pages, page_size,
                  cfg.num_kv_heads // pack, pack * cfg.head_dim)
-        tails = (max(cfg.num_conv_layers, 1), num_pages) \
-            + cfg.conv_tail_shape
+        tails = (cfg.num_conv_layers, num_pages) + cfg.conv_tail_shape
         pools = (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
                  jnp.zeros(tails, dtype))
         if cfg.num_state_layers:
@@ -179,7 +183,9 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
             # decode kernel broadcasts its operands along an axis they
             # already lack (ops/pallas/ssm_update.py). A delta-rule
             # head's is [key channel, value channel]
-            # (``cfg.state_shape``; ops/pallas/kda_update.py).
+            # (``cfg.state_shape``; ops/pallas/kda_update.py); a
+            # retention head's holds matrix and normaliser, 34 MB a
+            # layer a sequence (ops/pallas/retention_update.py).
             pools += (jnp.zeros(
                 (cfg.num_state_layers, max(state_slots, 2))
                 + cfg.state_shape, jnp.float32),)
@@ -1691,7 +1697,10 @@ def _init_kinds_params(cfg: ModelConfig, key: jax.Array,
                       kda_g_down=w((n, D, R), D), kda_g_up=w((n, R, I), R),
                       kda_norm=jnp.ones((n, Dk), dtype),
                       kda_out=w((n, I, D), I))
-        if op in ("attn", "mix"):
+        if op == "ret":
+            # one decay a key-value head, from the layer's normed input
+            st.update(ret_gate=w((n, D, Hkv), D))
+        if op in ("attn", "mix", "ret"):
             st.update(q_proj=w((n, D, Hq * Dh), D),
                       k_proj=w((n, D, Hkv * Dh), D),
                       v_proj=w((n, D, Hkv * Dh), D),
@@ -1732,7 +1741,8 @@ def _init_kinds_params(cfg: ModelConfig, key: jax.Array,
 # state by slot ("state": the fourth pool's).
 _RANKS = ("attn", "conv", "state")
 _KEEPS = {"attn": ("attn",), "conv": ("conv",),
-          "mix": ("attn", "conv", "state"), "kda": ("conv", "state")}
+          "mix": ("attn", "conv", "state"), "kda": ("conv", "state"),
+          "ret": ("state",)}
 
 
 def _runs(kinds) -> Tuple[Tuple[str, int], ...]:
@@ -1768,15 +1778,17 @@ def kinds_pattern(kinds: Tuple[str, ...]) -> Tuple[int, int, int]:
 
 def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                   pools, conv_op, attn_op, valid: jnp.ndarray,
-                  plan: KernelPlan, mix_op=None, kda_op=None):
+                  plan: KernelPlan, mix_op=None, kda_op=None, ret_op=None):
     """The layer loop over ``cfg.layer_kinds``. ``pools`` = (k, v,
     tails) and, for a model with state layers, the pool of states after
     them, carried and updated in place; ``conv_op(lp, h, tails, c) ->
     (y, tails)``, ``attn_op(lp, h, k, v, a) -> (y, k, v)``, ``mix_op(lp,
     h, pools, a, c, r) -> (y, pools)`` (attention and a mixer on the
-    same input) and ``kda_op(lp, h, pools, c, r) -> (y, pools)`` (a
-    delta-rule layer: a ring and a state, no keys and values) are the
-    caller's (prefill's or decode's). A layer has a rank among the
+    same input), ``kda_op(lp, h, pools, c, r) -> (y, pools)`` (a
+    delta-rule layer: a ring and a state, no keys and values) and
+    ``ret_op(lp, h, pools, r) -> (y, pools)`` (a power-retention layer:
+    a state and nothing else) are the caller's (prefill's or decode's).
+    A layer has a rank among the
     layers that KEEP what it keeps: ``a`` keys and values, ``c`` a
     convolution tail or ring, ``r`` a matrix state. Returns ``(x,
     pools, moe_stats)``."""
@@ -1815,8 +1827,10 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                 pools = (kp, vp) + pools[2:]
             elif op == "mix":
                 y, pools = mix_op(lp, h, pools, a, c, r)
-            else:
+            elif op == "kda":
                 y, pools = kda_op(lp, h, pools, c, r)
+            else:
+                y, pools = ret_op(lp, h, pools, r)
             x = x + y
             h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
             if ffn == "moe":
@@ -2354,6 +2368,226 @@ def _kda_step(cfg: ModelConfig, state, r, read, write, q, k, v, g, beta,
     return o, state.at[r, write].set(S)
 
 
+# ---------------------------------------------------------------------------
+# A power-retention layer (operator "ret"; Brumby)
+#
+# The layer's operator INSTEAD of attention, and it keeps no keys and
+# values, no tail and no ring: causal attention whose weight is
+# ``(q_t . k_j)^2`` in place of ``exp(q_t . k_j)``, times a decay a
+# KEY-VALUE head from ``k_j``'s position to ``q_t``'s, the output divided
+# by the sum of its weights. q and k are the attention's (normed a head,
+# rotated; ``_qkv``), ``gamma_t = sigmoid(h W_g)`` one decay a key-value
+# head. With ``phi(u)`` the symmetric half of u's degree-2 products
+# (``u_i^2`` and ``sqrt(2) u_i u_j``, i < j), ``phi(a) . phi(b) =
+# (a . b)^2``, so the layer has an exact recurrent form over a FIXED
+# state a key-value head, which the head's query heads all read:
+#
+#     S_t = gamma_t S_{t-1} + phi(k_t) v_t^T        (the matrix)
+#     z_t = gamma_t z_{t-1} + phi(k_t)              (the normaliser)
+#     o_t = S_t^T phi(q_t) / (z_t . phi(q_t))
+#
+# Both live in ONE entry of the pool by slot, float32, in blocks by how
+# far apart a product's two key channels lie (``ModelConfig.
+# ret_state_rows``; ``_ret_phi``; ops/pallas/retention_update.py says
+# why). Slots, snapshots and the two slots a row by parity are the
+# mixer's, above.
+#
+# Prefill runs the chunked form in XLA, chunks of a page (so a page
+# boundary's state is what the scan carries): inside a chunk the
+# attention form with the decay as a mask; between chunks the state,
+# read through the chunk's expanded queries and advanced by its expanded
+# keys, ONE ROW after another in place in the pool (``_ret_window``: a
+# row's entry is 34 MB a layer and a chunk's expanded queries 170 MB, so
+# a batch of rows side by side would not fit). Every exponent is
+# a DIFFERENCE of summed log-decays from a later position back to an
+# earlier one, never positive. Decode is the one-token form: the Pallas
+# kernel where ``plan.ssm_decode``, else a gather and a scatter.
+# ---------------------------------------------------------------------------
+
+def _ret_phi(u: jnp.ndarray) -> jnp.ndarray:
+    """u [..., Dh] -> [..., nb, Dh], nb = Dh / 2 + 1: entry [d, i] is
+    ``c u_i u_{(i - d) mod Dh}``, c = 1 for d = 0 (the squares), sqrt 2
+    for 0 < d < Dh / 2 (each pair that far apart once) and, for d =
+    Dh / 2, sqrt 2 in lanes i < Dh / 2 and 0 in the others (which hold
+    the same pairs again): the 8,256 symmetric products of 128 channels
+    in 65 x 128 places, ``sum(_ret_phi(a) * _ret_phi(b)) == (a . b)^2``."""
+    Dh = u.shape[-1]
+    d = jnp.arange(Dh // 2 + 1, dtype=jnp.int32)[:, None]
+    i = jnp.arange(Dh, dtype=jnp.int32)[None, :]
+    coef = jnp.where(d == 0, 1.0, jnp.where(
+        (d < Dh // 2) | (i < Dh // 2), math.sqrt(2.0), 0.0)
+        ).astype(jnp.float32)
+    return coef * u[..., None, :] * u[..., jnp.mod(i - d, Dh)]
+
+
+def _ret_unpack(cfg: ModelConfig, rows: jnp.ndarray):
+    """A pool entry's rows [..., R, Dh] as ``(S [..., nb, Dh (value
+    channel), Dh (key channel)], z [..., nb, Dh])``."""
+    nb, Dh = cfg.ret_blocks, cfg.head_dim
+    lead = rows.shape[:-2]
+    return (rows[..., :nb * Dh, :].reshape(lead + (nb, Dh, Dh)),
+            rows[..., nb * Dh:nb * Dh + nb, :])
+
+
+def _ret_pack(cfg: ModelConfig, S: jnp.ndarray, z: jnp.ndarray):
+    nb, Dh = cfg.ret_blocks, cfg.head_dim
+    lead = z.shape[:-2]
+    pad = jnp.zeros(lead + (cfg.ret_state_rows - nb * Dh - nb, Dh), z.dtype)
+    return jnp.concatenate([S.reshape(lead + (nb * Dh, Dh)), z, pad],
+                           axis=-2)
+
+
+def _ret_in(cfg: ModelConfig, lp, h: jnp.ndarray, positions: jnp.ndarray,
+            valid: jnp.ndarray):
+    """A retention layer's operands of h [B, T, D] at ``positions``
+    [B, T]: ``(q [B, T, Hq, Dh], k, v [B, T, Hkv, Dh], g [B, T, Hkv])``,
+    float32; g the log of the decay. Where ``valid`` [B, T] is not, a
+    position must not move the state: g = 0 and k = v = 0 there (and
+    q = 0: it reads nothing)."""
+    q, k, v = _qkv(lp, cfg, h)
+    if cfg.use_rope:
+        q = rope_for(cfg.rope_scaling, q, positions, cfg.rope_theta)
+        k = rope_for(cfg.rope_scaling, k, positions, cfg.rope_theta)
+    f32 = jnp.float32
+    g = jax.nn.log_sigmoid((h @ lp["ret_gate"]).astype(f32))
+    on = valid[..., None, None]
+    return (jnp.where(on, q.astype(f32) * cfg.head_dim ** -0.5, 0.0),
+            jnp.where(on, k.astype(f32), 0.0),
+            jnp.where(on, v.astype(f32), 0.0),
+            jnp.where(valid[..., None], g, 0.0))
+
+
+def _ret_quotient(num: jnp.ndarray, den: jnp.ndarray) -> jnp.ndarray:
+    """The weighted sum over the sum of the weights; a position that
+    reads nothing (padding: every weight exactly 0) gives 0."""
+    return num / jnp.where(den == 0.0, 1.0, den)[..., None]
+
+
+def _ret_scan(cfg: ModelConfig, q, k, v, g, rows0, snap_len, ps: int):
+    """The chunked form over ONE row's window: q [T, Hq, Dh], k, v [T,
+    Hkv, Dh], g [T, Hkv] (as ``_ret_in`` gives them), rows0 [Hkv, R, Dh]
+    the pool entry the window starts from; float32; ``ps`` the page
+    size. Returns ``(o [T, Hq, Dh], rows, rows_snap)``: the entry after
+    the window's last position and after ``snap_len`` of its tokens (a
+    page boundary of a window that starts on one, so a chunk's end;
+    rows0 where none is)."""
+    T, Hq, Dh = q.shape
+    H = k.shape[1]
+    G = Hq // H
+    Q = math.gcd(T, ps)
+    nc = T // Q
+    t_i = jnp.arange(Q)
+    upto = t_i[:, None] >= t_i[None, :]                     # j <= t
+
+    def mm(eq, a, b):
+        return jnp.einsum(eq, a, b, precision=_HIGHEST)
+
+    def step(carry, inp):
+        S, z, S_snap, z_snap = carry    # S [H, nb, Dh, Dh], z [H, nb, Dh]
+        j, qc, kc, vc, gc = inp
+        # qc [H, G, Q, Dh], kc and vc [H, Q, Dh], gc [H, Q]
+        Gc = jnp.cumsum(gc, axis=-1)                # through t, inclusive
+        dec = jnp.exp(jnp.where(upto, Gc[:, :, None] - Gc[:, None, :],
+                                -jnp.inf))                  # [H, Qt, Qj]
+        s = mm("hgqi,hji->hgqj", qc, kc)
+        w = s * s * dec[:, None]
+        # what the chunk's positions read of its own earlier positions
+        num = mm("hgqj,hjc->hgqc", w, vc)
+        den = jnp.sum(w, axis=-1)
+        # ... and of the state the chunk started from
+        pq = _ret_phi(qc) * jnp.exp(Gc)[:, None, :, None, None]
+        num = num + mm("hgqdi,hdci->hgqc", pq, S)
+        den = den + mm("hgqdi,hdi->hgq", pq, z)
+        last = Gc[:, -1]
+        pk = _ret_phi(kc) * jnp.exp(last[:, None] - Gc)[..., None, None]
+        S = jnp.exp(last)[:, None, None, None] * S \
+            + mm("hjdi,hjc->hdci", pk, vc)
+        z = jnp.exp(last)[:, None, None] * z + jnp.sum(pk, axis=1)
+        at = (j + 1) * Q == snap_len
+        return (S, z, jnp.where(at, S, S_snap), jnp.where(at, z, z_snap)), \
+            _ret_quotient(num, den)
+
+    # a chunk and a key-value head in front: [nc, H, ...]
+    qs = jnp.transpose(q.reshape(nc, Q, H, G, Dh), (0, 2, 3, 1, 4))
+    ks, vs = (jnp.transpose(a.reshape(nc, Q, H, Dh), (0, 2, 1, 3))
+              for a in (k, v))
+    gs = jnp.transpose(g.reshape(nc, Q, H), (0, 2, 1))
+    S0, z0 = _ret_unpack(cfg, rows0)
+    (S, z, S_snap, z_snap), o = jax.lax.scan(
+        step, (S0, z0, S0, z0),
+        (jnp.arange(nc, dtype=jnp.int32), qs, ks, vs, gs))
+    # [nc, H, G, Q, Dh] -> [T, Hq, Dh]
+    o = jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(T, Hq, Dh)
+    return o, _ret_pack(cfg, S, z), _ret_pack(cfg, S_snap, z_snap)
+
+
+def _ret_window(cfg: ModelConfig, state, r, lengths, state_cols, q, k, v, g,
+                ps: int):
+    """A prefill step's windows through layer ``r`` of the pool by slot,
+    ONE ROW AFTER ANOTHER, each read out of the pool and written back
+    into it where it lies: a row's entry is 34 MB, and a batch of
+    sixteen gathered, scanned and scattered side by side would hold two
+    gigabytes of them at once. ``state_cols`` [B, 4] is the engine's
+    (source slot, 0: from zero; final slot; snapshot slot, 0: none;
+    tokens the snapshot is taken after); q, k, v, g are ``_ret_in``'s,
+    [B, T, ...]. Returns ``(o [B, T, Hq, Dh], state)``. Rows behind the
+    last one with a token are not walked (their output is 0)."""
+    B = q.shape[0]
+    src, dst, snap, snap_len = (state_cols[:, i] for i in range(4))
+    entry = (1, 1) + state.shape[2:]
+
+    def row(b, carry):
+        state, o_all = carry
+        rows0 = jnp.where(src[b] > 0, jax.lax.dynamic_slice(
+            state, (r, src[b], 0, 0, 0), entry)[0, 0], 0.0)
+        o, rows, rows_snap = _ret_scan(cfg, q[b], k[b], v[b], g[b], rows0,
+                                       snap_len[b], ps)
+        # a row without a token started from zero and moved nothing: what
+        # it writes to the null slot is zeros
+        at = jnp.where(lengths[b] > 0, dst[b], 0)
+        state = jax.lax.dynamic_update_slice(
+            state, rows[None, None], (r, at, 0, 0, 0))
+        # ... and a row without a snapshot writes its final state twice
+        taken = snap[b] > 0
+        state = jax.lax.dynamic_update_slice(
+            state, jnp.where(taken, rows_snap, rows)[None, None],
+            (r, jnp.where(taken, snap[b], at), 0, 0, 0))
+        return state, jax.lax.dynamic_update_slice(
+            o_all, o[None], (b, 0, 0, 0))
+
+    walked = jnp.max(jnp.where(lengths > 0, jnp.arange(B) + 1, 0))
+    state, o = jax.lax.fori_loop(
+        0, walked, row, (state, jnp.zeros(q.shape, jnp.float32)))
+    return o, state
+
+
+def _ret_step(cfg: ModelConfig, state, r, read, write, q, k, v, g,
+              plan: KernelPlan):
+    """One token a row, in place in the pool: reads layer ``r``'s slots
+    ``read`` [B], writes ``write`` [B]. q [B, Hq, Dh], k and v [B, Hkv,
+    Dh], g [B, Hkv] (g = 0 and q = k = v = 0 on an inactive row, whose
+    slots are the null slot). Returns ``(o [B, Hq, Dh], state)``."""
+    if plan.ssm_decode and cfg.head_dim % 8 == 0:
+        from xllm_service_tpu.ops.pallas.retention_update import (
+            retention_decode_update)
+        return retention_decode_update(state, r, read, write, q, k, v,
+                                       jnp.exp(g), interpret=plan.interpret)
+    B, Hq, Dh = q.shape
+    H = k.shape[1]
+    S, z = _ret_unpack(cfg, jax.lax.dynamic_index_in_dim(
+        state, r, axis=0, keepdims=False)[read])
+    pk = _ret_phi(k)                                        # [B, H, nb, Dh]
+    gamma = jnp.exp(g)
+    S = gamma[..., None, None, None] * S \
+        + pk[..., None, :] * v[:, :, None, :, None]
+    z = gamma[..., None, None] * z + pk
+    pq = _ret_phi(q.reshape(B, H, Hq // H, Dh))
+    num = jnp.einsum("bhgdi,bhdci->bhgc", pq, S, precision=_HIGHEST)
+    den = jnp.einsum("bhgdi,bhdi->bhg", pq, z, precision=_HIGHEST)
+    return _ret_quotient(num, den).reshape(B, Hq, Dh), \
+        state.at[r, write].set(_ret_pack(cfg, S, z))
+
+
 def _kv_pack(cfg: ModelConfig) -> int:
     """Key-value heads that share one row of the pools. A TPU tiles an
     array's last axis in 128 lanes: a pool of 8 heads of 64 is stored
@@ -2514,6 +2748,14 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
         return _kda_out(cfg, lp, o, h), (kp, vp, tails,
                                          put(state, S, S_snap))
 
+    def ret_op(lp, h, pools, r):
+        kp, vp, tails, state = pools
+        q, k, v, g = _ret_in(cfg, lp, h, positions, tok_valid)
+        o, state = _ret_window(cfg, state, r, lengths, state_cols, q, k, v,
+                               g, ps)
+        return _attn_out(cfg, lp, o.reshape(B, T, -1).astype(h.dtype), h), \
+            (kp, vp, tails, state)
+
     def mix_op(lp, h, pools, a, c, r):
         kp, vp, tails, state = pools
         ya, kp, vp = attn_op(lp, h, kp, vp, a)
@@ -2537,7 +2779,7 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
         return ya + ym, (kp, vp, tails, put(state, S, S_snap))
 
     x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
-                                     tok_valid, plan, mix_op, kda_op)
+                                     tok_valid, plan, mix_op, kda_op, ret_op)
     x, head = _kinds_head(params, cfg, x)
     last_idx = jnp.maximum(lengths - 1, 0)
     last_x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
@@ -2606,6 +2848,15 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
                             ps, plan)
         return _kda_out(cfg, lp, o[:, None], h), (kp, vp, tails, state)
 
+    def ret_op(lp, h, pools, r):
+        kp, vp, tails, state = pools
+        read, write = slots()
+        q, k, v, g = _ret_in(cfg, lp, h, pos2, active[:, None])
+        o, state = _ret_step(cfg, state, r, read, write, q[:, 0], k[:, 0],
+                             v[:, 0], g[:, 0], plan)
+        return _attn_out(cfg, lp, o.reshape(B, 1, -1).astype(h.dtype), h), \
+            (kp, vp, tails, state)
+
     def mix_op(lp, h, pools, a, c, r):
         kp, vp, tails, state = pools
         ya, kp, vp = attn_op(lp, h, kp, vp, a)
@@ -2624,7 +2875,8 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
         return ya + ym, (kp, vp, tails, state)
 
     x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
-                                     active[:, None], plan, mix_op, kda_op)
+                                     active[:, None], plan, mix_op, kda_op,
+                                     ret_op)
     x, head = _kinds_head(params, cfg, x)
     logits = _kinds_logits(cfg, x[:, 0], head)
     if return_stats:

@@ -131,14 +131,20 @@ class KernelPlan:
         # the layer loop and writes before it attends, always
         # (models/transformer.py, "Layers that differ in kind").
         kinds = model_cfg.layer_kinds is not None
+        # A model with NO layer that keeps keys and values (every layer a
+        # retention layer) has nothing for the attention kernels or the
+        # writers to serve, and its plan says so (``write_then_attend``
+        # stays what a loop over kinds makes it: it orders nothing there
+        # and still clamps a prefill table to the sequence's pages).
+        attends = model_cfg.num_attn_layers > 0
         return cls(
-            decode_attn=base,
+            decode_attn=base and attends,
             # Opt-in; needs the base gate (no interpreter fallback on
             # the serving path). Checked on the chip for a model whose
             # layers differ in kind (PERF.md, PR 38: correct, a
             # follow-up window 17.6 ms against 28.9) and left opt-in
             # there too: the cell it speeds up spreads twice as widely.
-            prefill_attn=base
+            prefill_attn=base and attends
             and environ.get("XLLM_PALLAS_PREFILL", "0") == "1",
             # Decided on the chip (PERF.md, PR 36: batch 32, table width
             # 96, contexts of 8k-10.5k over a 576-wide row): the kernel
@@ -154,9 +160,11 @@ class KernelPlan:
             # XLA's form gathers the rows' states out of the pool and
             # scatters them back: the kernel maps each row's block by its
             # slot and aliases the pool (PERF.md, PR 45; the delta
-            # rule's twin, PR 49; the ring's writer, PR 50).
+            # rule's twin, PR 49; the ring's writer, PR 50; a retention
+            # layer's, whose block is 64 times a delta-rule head's,
+            # PR 53).
             ssm_decode=base and model_cfg.num_state_layers > 0,
-            kv_writers=writers and mesh is None,
+            kv_writers=writers and mesh is None and attends,
             # No ragged kernel for absorbed-MLA pools, no ragged rows in
             # the loop over layer kinds: they keep the split path.
             mixed_step=bool(mixed) and not model_cfg.mla and not kinds,

@@ -173,6 +173,8 @@ def load_checkpoint(model_dir: str, cfg: ModelConfig,
         return _load_mix_checkpoint(r, cfg, dtype)
     if cfg.num_kda_layers:
         return _load_kda_checkpoint(r, cfg, dtype)
+    if cfg.num_ret_layers:
+        return _load_ret_checkpoint(r, cfg, dtype)
     if cfg.layer_kinds is not None:
         return _load_kinds_checkpoint(r, cfg, dtype)
 
@@ -670,6 +672,40 @@ def _load_mix_checkpoint(r, cfg: ModelConfig, dtype):
         r, cfg, dtype, {cfg.layer_kinds[0]: {k: np.ascontiguousarray(v)
                                              for k, v in st.items()}},
         "model.final_layernorm.weight")
+
+
+def _load_ret_checkpoint(r, cfg: ModelConfig, dtype):
+    """Brumby tree: ONE stack of the one kind ``ret+dense``
+    (models/transformer.py ``_init_kinds_params``). The names are
+    Qwen3's, which the family's block is, with the decay's projection
+    beside the attention's: ``input_layernorm`` /
+    ``post_attention_layernorm``; ``self_attn.{q, k, v, o}_proj``,
+    ``self_attn.{q, k}_norm``, ``self_attn.g_proj`` ([key-value heads,
+    hidden], bias-free: one decay a key-value head); ``mlp.{gate, up,
+    down}_proj``; ``model.norm`` after the last layer. ASSUMED from the
+    family's convention: no published checkpoint is in this repository,
+    and the loader is tested on a seeded tree alone."""
+    idxs = range(cfg.num_layers)
+
+    def t(name):
+        return np.ascontiguousarray(r.get(name).T)
+
+    def stack(fmt, f=r.get):
+        return np.ascontiguousarray(
+            np.stack([f(fmt.format(i=i)) for i in idxs]).astype(dtype))
+
+    L, A = "model.layers.{i}.", "model.layers.{i}.self_attn."
+    st = {"input_norm": stack(L + "input_layernorm.weight"),
+          "post_norm": stack(L + "post_attention_layernorm.weight"),
+          "q_norm": stack(A + "q_norm.weight"),
+          "k_norm": stack(A + "k_norm.weight"),
+          "ret_gate": stack(A + "g_proj.weight", t)}
+    for w in ("q_proj", "k_proj", "v_proj", "o_proj"):
+        st[w] = stack(A + w + ".weight", t)
+    for w in ("gate_proj", "up_proj", "down_proj"):
+        st[w] = stack(L + "mlp." + w + ".weight", t)
+    return _kinds_tree(r, cfg, dtype, {cfg.layer_kinds[0]: st},
+                       "model.norm.weight")
 
 
 def _visual_reader(model_dir: str, depth: int, dtype):

@@ -209,30 +209,47 @@ class Engine:
         self.ecfg = engine_cfg
         self.mesh = mesh
         dtype = jnp.dtype(model_cfg.dtype)
-        # Is a sequence's cached state its (k, v) pages and nothing else?
-        # Not where convolution layers keep a tail beside each page
-        # (transformer.init_kv_cache's third pool): the wire, the host
-        # tier and a peer's import carry (k, v) blocks, and a page that
-        # arrived without its tail must never be resumed from. So what
-        # moves pages OUT of or INTO the pools is refused for such a
-        # model (ROADMAP.md Reach A1), here, once, and at each door.
-        self.pages_only = model_cfg.num_conv_layers == 0
-        # Does a sequence also carry a state that lives by SLOT (a mixer
-        # beside attention, a delta-rule layer: a matrix a head a layer,
-        # the fourth pool)?
+        # What a sequence keeps between steps, by kind, decided once,
+        # here (transformer.init_kv_cache's pools):
+        # - pages of BYTES: keys and values (a latent row) a page, of the
+        #   layers that attend. Every model but one whose every layer is
+        #   a retention layer: there a page holds no byte and the page
+        #   table is bookkeeping (the prefix index, the snapshot's
+        #   boundary, the windows' shapes), ``kv_usage`` says nothing of
+        #   memory, and what admits is a free state row;
+        # - ROWS beside a page: a convolution tail or filter ring a page
+        #   a convolution layer (the third pool);
+        # - a state by SLOT: a matrix a head a layer (a mixer beside
+        #   attention, a delta-rule layer, a retention layer: the fourth
+        #   pool), restored from a snapshot under the prefix index.
+        self.page_bytes = model_cfg.num_attn_layers > 0
+        self.page_rows = model_cfg.num_conv_layers > 0
         self.state_model = model_cfg.num_state_layers > 0
+        # Is a sequence's cached state its (k, v) pages and nothing else?
+        # The wire, the host tier and a peer's import carry (k, v)
+        # blocks, and a page that arrived without its tail, or a sequence
+        # without its state, must never be resumed from. So what moves
+        # pages OUT of or INTO the pools is refused for a model that
+        # keeps anything else (ROADMAP.md Reach A1), here, once, and at
+        # each door.
+        self.pages_only = not (self.page_rows or self.state_model)
         if not self.pages_only:
             if mesh is not None:
                 raise ValueError(
-                    "a model with convolution layers runs on one device: "
-                    "its per-kind weight stacks and its pool of tails have "
-                    "no sharding rules (parallel/sharding.py)")
+                    "a model that keeps more than (k, v) pages (convolution "
+                    "tails, a state by slot) runs on one device: its "
+                    "per-kind weight stacks and its pools have no sharding "
+                    "rules (parallel/sharding.py)")
+            keeps = [what for what, on in (
+                ("a convolution tail beside each page", self.page_rows),
+                ("a matrix state by slot", self.state_model)) if on]
             logger.info(
-                "%s keeps a convolution tail beside each page%s: PD "
-                "migration, host spill and cross-worker block fetch are "
-                "refused for it (pages move with (k, v) alone)",
-                model_cfg.name,
-                " and a matrix state by slot" if self.state_model else "")
+                "%s keeps %s%s: PD migration, host spill and cross-worker "
+                "block fetch are refused for it (pages move with (k, v) "
+                "alone)", model_cfg.name, " and ".join(keeps),
+                "" if self.page_bytes else
+                " and NO keys and values (its pages are bookkeeping: a "
+                "free state row is what admits)")
         # The buckets a window that does NOT end its prompt may take (the
         # interleaver's quantum, ``_window_cap``): all of them, but for a
         # state model whole pages only, so that every window starts on a
@@ -416,9 +433,14 @@ class Engine:
             if self._decode_flat > 1:
                 fold += (f", a page flat, {self._decode_flat} positions "
                          f"a tile")
+        if not self.page_bytes:
+            fold += ("; no layer keeps keys and values: decode_attn, "
+                     "prefill_attn and kv_writers serve nothing")
         if self.state_model:
-            op = "kda" if model_cfg.num_kda_layers else "ssm"
-            fold += (f"; {'delta rule' if op == 'kda' else 'mixer'} "
+            op, what = (("ret", "retention") if model_cfg.num_ret_layers
+                        else ("kda", "delta rule")
+                        if model_cfg.num_kda_layers else ("ssm", "mixer"))
+            fold += (f"; {what} "
                      f"{op}_prefill {self.plan.ssm_prefill}, "
                      f"{op}_decode "
                      f"{'pallas' if self.plan.ssm_decode else 'xla'}; "
@@ -429,6 +451,13 @@ class Engine:
                         f"{k} {kinds.count(k)}"
                         for k in dict.fromkeys(kinds)) if kinds else "",
                     fold)
+        # What the cache IS, in bytes: a model no layer of which attends
+        # holds all of it in its states by slot.
+        logger.info("engine pools: %s", ", ".join(
+            f"{name} {sum(int(x.nbytes) for x in pools) / 1e9:.2f} GB"
+            for name, pools in (("(k, v)", self.kv[:2]),
+                                ("tails", self.kv[2:3]),
+                                ("states", self.kv[3:])) if pools))
         if self.plan.uses_kernels:
             # The kernels are loaded here (1.2-1.6 s of
             # jax.experimental.pallas), where an engine is built, and
@@ -1600,7 +1629,7 @@ class Engine:
                 if self.state_model and new:
                     packed[i, -_STATE_COLS:] = self._state_cols(seq,
                                                                 len(new))
-                if not self.pages_only and new:
+                if self.page_rows and new:
                     # pages whose row of tails this window writes
                     ps = self.ecfg.page_size
                     self.state_rows_written += (
@@ -1839,6 +1868,8 @@ class Engine:
         max_pages_per_seq pages), so its table is clamped like
         ``_table_width``: at 96 pages a sequence, a 10k-token document
         is gathered over 96 columns and not 128."""
+        if not self.page_bytes:
+            return self.ecfg.max_pages_per_seq
         mp = 1 << max(pages - 1, 0).bit_length()
         if self.plan.write_then_attend:
             mp = min(mp, self.ecfg.max_pages_per_seq)
@@ -1848,7 +1879,12 @@ class Engine:
         """Page-table columns actually needed by the running batch, bucketed
         to a power of two. Attention cost (page DMAs / gather width) scales
         with table width, so shipping the full max_pages_per_seq table
-        makes every short-context batch pay long-context prices."""
+        makes every short-context batch pay long-context prices. Where a
+        page holds no byte (``page_bytes``) no program reads the table
+        and a width costs nothing but a compiled program each: ONE width,
+        the whole table's, for its prefill programs too."""
+        if not self.page_bytes:
+            return self.ecfg.max_pages_per_seq
         mp = max((len(s.pages) for s in self.running), default=1)
         mp = 1 << max(mp - 1, 0).bit_length()
         return min(mp, self.ecfg.max_pages_per_seq)

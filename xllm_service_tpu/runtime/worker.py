@@ -27,6 +27,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import logging
+import math
 import os
 import queue
 import threading
@@ -326,7 +327,13 @@ class ModelRuntime:
                     + cfg.num_layers * (
                         4 * cfg.hidden_size * cfg.num_heads * cfg.head_dim
                         + mlp))
-        return 2.0 * n_params / 1e9
+        # A state by slot is the cache of a model that keeps one (all of
+        # it where no layer attends): 1 + 3 x max_batch_size slots
+        # (Engine.__init__), float32.
+        slots = 1 + 3 * self.engine_cfg.max_batch_size
+        state = 4 * cfg.num_state_layers * slots * math.prod(
+            cfg.state_shape) if cfg.num_state_layers else 0
+        return (2.0 * n_params + state) / 1e9
 
 
 class _StopWatcher:
@@ -560,15 +567,16 @@ class Worker:
             self.opts.instance_type = InstanceType.ENCODE
         self.runtimes: Dict[str, ModelRuntime] = {}
         primary_cfg = resolve_model_config(opts.model, opts.model_dir)
-        if primary_cfg.num_conv_layers and self.instance_type in (
-                InstanceType.PREFILL, InstanceType.DECODE):
+        if (primary_cfg.num_conv_layers or primary_cfg.num_state_layers) \
+                and self.instance_type in (InstanceType.PREFILL,
+                                           InstanceType.DECODE):
             # Its pages do not move between workers (Engine.pages_only):
             # a disaggregated role could only ever fall back.
             raise ValueError(
-                f"{opts.model} has convolution layers, whose tails ride "
-                f"the page table: instance type "
-                f"{self.instance_type.value} (PD migration) is refused; "
-                f"serve it as DEFAULT or MIX")
+                f"{opts.model} keeps more than (k, v) pages (convolution "
+                f"tails that ride the page table, a state by slot): "
+                f"instance type {self.instance_type.value} (PD migration) "
+                f"is refused; serve it as DEFAULT or MIX")
         # Encode-only mode: the LM runtime starts asleep — engine=None,
         # no params, no KV pool. Every heartbeat/metrics/registration
         # path already handles an asleep runtime; the vision tower
@@ -1529,6 +1537,14 @@ class Worker:
             eng.phase_counts.get("decode.ahead_dropped_rows", 0), model=m)
         if eng.cfg.is_moe:
             self._flush_moe(rt)
+        self.obs.gauge(
+            "xllm_worker_kv_pool_bytes",
+            "bytes of the pools of keys and values (a latent model's one "
+            "pool of rows): 0 for a model no layer of which attends, "
+            "whose pages are bookkeeping and whose memory is "
+            "xllm_worker_state_pool_bytes",
+            labelnames=("model",)).set(
+            sum(int(x.nbytes) for x in eng.kv[:2]), model=m)
         if not eng.pages_only:
             self._flush_state(rt)
         if eng.cfg.looped:
