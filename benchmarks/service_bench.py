@@ -10,7 +10,13 @@ service-side work.
 
 Run (CPU-only):
     python -m benchmarks.service_bench [--requests 400] [--concurrency 16]
-        [--workers 2] [--gen-tokens 16] [--stream]
+        [--workers 2] [--gen-tokens 16] [--stream] [--prompt-tokens N]
+
+``--prompt-tokens N`` sends N token ids a request where the default
+sends a short text, under the default (cache-aware) routing policy, and
+reports the master's own stage of a first token as the workers read it
+off the forward (``x-xllm-front-ms``: ``master_in_ms_p50`` / ``_p99``):
+the master's milliseconds at a chip cell's prompt length, on the CPU.
 
 ``--service-procs N`` runs the horizontal-scaling leg: N service
 replicas as separate OS processes against one shared store, with fake
@@ -49,6 +55,7 @@ def _child_env(**extra):
 
 from xllm_service_tpu.config import (
     InstanceType, LoadBalancePolicyType, ServiceOptions)
+from xllm_service_tpu.obs import FRONT_MS_HEADER
 from xllm_service_tpu.service.coordination import (
     InMemoryStore, instance_prefix)
 from xllm_service_tpu.service.httpd import (
@@ -80,6 +87,8 @@ class FakeWorker:
         # TPOT cadence, so N concurrent streams stay GENUINELY
         # concurrent instead of draining each stream in one burst.
         self.frame_interval_ms = frame_interval_ms
+        # The master's stage of each forward (x-xllm-front-ms), as read.
+        self.front_ms: List[float] = []
         router = Router()
         router.route("GET", "/hello",
                      lambda r: Response.json({"ok": True}))
@@ -127,6 +136,9 @@ class FakeWorker:
         if self.delay_ms:
             time.sleep(self.delay_ms / 1e3)
         body = req.json()
+        front = req.headers.get(FRONT_MS_HEADER)
+        if front:
+            self.front_ms.append(float(front))
         srid = body.get("service_request_id", "fake-req")
         model = body.get("model", "fake")
         toks = list(range(1, self.gen_tokens + 1))
@@ -167,7 +179,8 @@ class FakeWorker:
 
 
 def run(num_requests: int, concurrency: int, n_workers: int,
-        gen_tokens: int, stream: bool, store_kind: str = "mem") -> Dict:
+        gen_tokens: int, stream: bool, store_kind: str = "mem",
+        prompt_tokens: int = 0) -> Dict:
     """``store_kind='native-etcd'`` routes every coordination operation
     (leases, keepalives, watches, master upload) through the native
     etcd-v3-gateway server (csrc/xllm_etcd.cpp) over real sockets — the
@@ -192,15 +205,26 @@ def run(num_requests: int, concurrency: int, n_workers: int,
                 return s
         else:
             store = InMemoryStore()
+        # Long prompts run under the policy a deployment that sends them
+        # runs (the cells' too): it hashes every full block of a prompt.
+        policy = (LoadBalancePolicyType.CACHE_AWARE if prompt_tokens
+                  else LoadBalancePolicyType.ROUND_ROBIN)
         opts = ServiceOptions(
-            http_port=0, rpc_port=0,
-            load_balance_policy=LoadBalancePolicyType.ROUND_ROBIN,
+            http_port=0, rpc_port=0, load_balance_policy=policy,
             heartbeat_interval_s=0.5, master_upload_interval_s=0.5)
         master = Master(opts, store=store).start()
         out = _measure(master, workers, store, num_requests, concurrency,
                        n_workers, gen_tokens, stream,
-                       store_factory=store_factory)
+                       store_factory=store_factory,
+                       prompt_tokens=prompt_tokens)
         out["detail"]["store"] = store_kind
+        if prompt_tokens:
+            from benchmarks.loadgen import _percentile
+            front = sorted(x for w in workers for x in w.front_ms)
+            out["detail"].update(
+                prompt_tokens=prompt_tokens, policy=policy.value,
+                master_in_ms_p50=round(_percentile(front, 50), 3),
+                master_in_ms_p99=round(_percentile(front, 99), 3))
         return out
     finally:
         for w in workers:
@@ -216,7 +240,8 @@ def run(num_requests: int, concurrency: int, n_workers: int,
 
 
 def _measure(master, workers, store, num_requests, concurrency,
-             n_workers, gen_tokens, stream, store_factory=None) -> Dict:
+             n_workers, gen_tokens, stream, store_factory=None,
+             prompt_tokens: int = 0) -> Dict:
     # Each fake worker gets its own store connection when a factory is
     # given (native-etcd leg: one socket per worker, like a real fleet).
     mk = store_factory or (lambda: store)
@@ -232,16 +257,20 @@ def _measure(master, workers, store, num_requests, concurrency,
         raise RuntimeError("fake workers never registered")
 
     return _client_sweep([master.http_address], num_requests, concurrency,
-                         n_workers, gen_tokens, stream)
+                         n_workers, gen_tokens, stream,
+                         prompt_tokens=prompt_tokens)
 
 
 def _client_sweep(addrs: List[str], num_requests: int, concurrency: int,
                   n_workers: int, gen_tokens: int, stream: bool,
-                  raw: bool = False) -> Dict:
+                  raw: bool = False, prompt_tokens: int = 0) -> Dict:
     """Shared closed-loop client: ``concurrency`` threads drain
     ``num_requests``, round-robining requests across ``addrs`` (one
     address for the in-process bench; N service replicas for
-    --service-procs)."""
+    --service-procs). ``prompt_tokens`` N: a request sends N token ids,
+    one document's but for the last (a cell's cached document and its
+    question), where it sends a short text otherwise."""
+    document = [(j * 40503 + 17) % 151936 for j in range(prompt_tokens)]
     latencies: List[float] = []
     lat_lock = threading.Lock()
     errors = [0]
@@ -256,8 +285,12 @@ def _client_sweep(addrs: List[str], num_requests: int, concurrency: int,
                 i = idx[0]
                 idx[0] += 1
             addr = addrs[i % len(addrs)]
-            body = {"model": "fake", "prompt": f"benchmark prompt {i}",
-                    "max_tokens": gen_tokens, "stream": stream}
+            body = {"model": "fake", "max_tokens": gen_tokens,
+                    "stream": stream}
+            if prompt_tokens:
+                body["token_ids"] = document[:-1] + [i]
+            else:
+                body["prompt"] = f"benchmark prompt {i}"
             t0 = time.monotonic()
             try:
                 if stream:
@@ -1155,6 +1188,10 @@ def main() -> None:
     ap.add_argument("--workers", type=int, default=2)
     ap.add_argument("--gen-tokens", type=int, default=16)
     ap.add_argument("--stream", action="store_true")
+    ap.add_argument("--prompt-tokens", type=int, default=0,
+                    help="send N token ids a request instead of a short "
+                         "text, and report the master's stage of a "
+                         "first token (default leg only)")
     ap.add_argument("--overload", action="store_true",
                     help="saturation sweep past --max-concurrency")
     ap.add_argument("--max-concurrency", type=int, default=32)
@@ -1183,6 +1220,9 @@ def main() -> None:
     if args.store != "mem" and args.overload:
         ap.error("--store native-etcd is not wired into the --overload "
                  "leg")
+    if args.prompt_tokens and (args.saturate or args.service_procs
+                               or args.overload):
+        ap.error("--prompt-tokens is wired into the default leg alone")
     if args.saturate:
         steps = [int(x) for x in args.sat_steps.split(",") if x.strip()]
         out = saturate_run(steps, args.sat_seconds, args.workers,
@@ -1207,7 +1247,8 @@ def main() -> None:
             args.worker_delay_ms)))
         return
     print(json.dumps(run(args.requests, args.concurrency, args.workers,
-                         args.gen_tokens, args.stream, args.store)))
+                         args.gen_tokens, args.stream, args.store,
+                         prompt_tokens=args.prompt_tokens)))
 
 
 if __name__ == "__main__":

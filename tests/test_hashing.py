@@ -117,3 +117,64 @@ def test_chained_block_hash_block_by_block(ids, seed, monkeypatch):
     monkeypatch.setattr(hashing, "_load_native", lambda: None)
     assert hashing.chained_block_hash(tokens[bs:2 * bs], whole[0], seed) \
         == whole[1]
+
+
+# The master hashes a prompt twice a request (the quarantine gate's
+# whole-prompt digest, the router's block hashes): both pack the list
+# ONCE in compiled code and wrap id by id only where an id lies outside
+# int32, and both read a buffer ``pack_tokens`` already made. Every
+# digest stays what the token-by-token reference gives.
+_I32_MAX, _I32_MIN = 2**31 - 1, -2**31
+_PROMPTS = {
+    "empty": [],
+    "one_partial_block": list(range(1, 100)),
+    "exact_blocks": [(i * 2654435761) % 120000 for i in range(3 * 128)],
+    "long_16150": [(i * 40503 + 17) % 151936 for i in range(16150)],
+    "int32_edges": [_I32_MAX, _I32_MIN, 0, -1, _I32_MAX - 1,
+                    _I32_MIN + 1, 5] * 40,
+    "outside_int32": [2**31, -5, 2**40 + 3, 1, -2**31 - 1, 7,
+                      2**70] * 40,
+}
+_WRAPS = {"outside_int32"}
+
+
+def _reference(tokens, bs, seed):
+    blocks, prev = [], None
+    for b in range(len(tokens) // bs):
+        prev = hashing.chained_block_hash_py(
+            tokens[b * bs:(b + 1) * bs], prev, seed)
+        blocks.append(prev)
+    data = struct.pack(f"<{len(tokens)}i",
+                       *[hashing._as_i32(t) for t in tokens])
+    return blocks, hashing.murmur3_x64_128_py(data, seed).hex()
+
+
+@pytest.mark.parametrize("native", ["native", "no_native"])
+@pytest.mark.parametrize("given", ["list", "packed"])
+@pytest.mark.parametrize("ids", sorted(_PROMPTS))
+def test_prompt_hashes_equal_reference(ids, given, native, monkeypatch):
+    tokens, bs, seed = _PROMPTS[ids], 128, 1234567
+    want_blocks, want_digest = _reference(tokens, bs, seed)
+    if native == "native":
+        if not hashing.native_available():
+            pytest.skip("native lib unavailable")
+    else:
+        monkeypatch.setattr(hashing, "_load_native", lambda: None)
+    calls = []
+    real = hashing._as_i32
+    monkeypatch.setattr(hashing, "_as_i32",
+                        lambda t: calls.append(t) or real(t))
+    arg = tokens
+    if given == "packed":
+        arg, wrapped = hashing.pack_tokens(tokens)
+        assert wrapped == (ids in _WRAPS)
+        assert len(arg) == len(tokens)
+        assert hashing.pack_tokens(arg) == (arg, False)  # no second pack
+        del calls[:]    # from here on nothing converts: the buffer is read
+    assert hashing.prefix_block_hashes(arg, bs, seed) == want_blocks
+    assert hashing.prompt_digest(arg, seed) == want_digest
+    if ids in _WRAPS and given == "list":
+        assert calls            # the wrap ran, id by id
+    else:
+        # the regression this guards: one interpreted call a token
+        assert calls == []

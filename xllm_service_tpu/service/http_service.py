@@ -634,8 +634,8 @@ class HttpService:
                 # goes to the client as-is.
                 name = self._routed_name(fwd)
                 poisoned = self.scheduler.note_engine_fault(
-                    req.service_request_id, req.token_ids, name,
-                    verdict)
+                    req.service_request_id,
+                    self.scheduler.prompt_buffer(req), name, verdict)
                 if not poisoned and attempt + 1 < attempts:
                     failed.add(name)
                     new = self._redispatch(req, fwd, exclude=failed)
@@ -861,7 +861,8 @@ class HttpService:
                             if verdict is not None:
                                 poisoned = \
                                     self.scheduler.note_engine_fault(
-                                        srid, req.token_ids,
+                                        srid,
+                                        self.scheduler.prompt_buffer(req),
                                         self._routed_name(fwd), verdict)
                                 if poisoned:
                                     self._m_errors.inc()

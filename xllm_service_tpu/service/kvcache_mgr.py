@@ -18,10 +18,8 @@ page hashes, so service-side match and worker-side reuse agree exactly).
 
 from __future__ import annotations
 
-import threading
-
-
-from typing import Dict, Iterable, List, Optional, Set, Tuple
+import time
+from typing import Callable, Dict, Iterable, List, Optional, Set, Tuple
 
 from xllm_service_tpu.service.coordination import (
     KEY_CACHE, CoordinationStore)
@@ -68,6 +66,9 @@ class GlobalKVCacheMgr:
         # value None → block gone everywhere (delete the store key).
         self._dirty: Dict[bytes, Optional[Dict[str, List[str]]]] = {}  # guarded-by: kvcache_mgr
         self._watch_id: Optional[int] = None
+        # Told the seconds each prompt's block hashing took (the
+        # scheduler counts them: xllm_service_prompt_hash_seconds_total).
+        self.on_hashed: Optional[Callable[[float], None]] = None
         if not is_master:
             self._watch_id = store.add_watch(KEY_CACHE, self._on_watch)
         self._bootstrap()
@@ -133,7 +134,10 @@ class GlobalKVCacheMgr:
         contiguous leading run (``holders[inst][i]`` = tier of block i).
         ``len(holders[inst])`` is the instance's usable prefix in blocks
         — unweighted, unlike the routing score."""
+        t0 = time.perf_counter()
         hashes = prefix_block_hashes(token_ids, self.block_size, self.seed)
+        if self.on_hashed is not None:
+            self.on_hashed(time.perf_counter() - t0)
         scores: Dict[str, float] = {}
         holders: Dict[str, List[str]] = {}
         alive: Dict[str, bool] = {}

@@ -26,7 +26,7 @@ import threading
 
 
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from xllm_service_tpu.config import ServiceOptions
 from xllm_service_tpu.nlp.chat_template import ChatTemplate
@@ -42,7 +42,7 @@ from xllm_service_tpu.service.instance_types import (
 from xllm_service_tpu.service.kvcache_mgr import GlobalKVCacheMgr
 from xllm_service_tpu.service.lb_policy import create_policy
 from xllm_service_tpu.service.recovery import PoisonLedger
-from xllm_service_tpu.utils.hashing import prompt_digest
+from xllm_service_tpu.utils.hashing import pack_tokens, prompt_digest
 from xllm_service_tpu.utils.misc import OrderedFanInPools, short_uuid
 from xllm_service_tpu.utils import threads
 from xllm_service_tpu.utils.threads import spawn
@@ -173,6 +173,7 @@ class Scheduler:
             store, block_size=opts.block_size, seed=opts.murmur_hash3_seed,
             is_master=self.is_master)
         self.instance_mgr.on_removed = self._on_instance_removed
+        self.kvcache_mgr.on_hashed = self._count_prompt_hash
         self.lb_policy = create_policy(opts, self.instance_mgr,
                                        self.kvcache_mgr)
 
@@ -541,7 +542,8 @@ class Scheduler:
         # preprocess (the digest is over the post-template token ids,
         # the same ids note_engine_fault strikes on), instead of
         # letting a retry restart the rampage worker by worker.
-        if self.quarantined_digest(request.token_ids):
+        tokens = self.prompt_buffer(request)
+        if self.quarantined_digest(tokens):
             return Status(StatusCode.INTERNAL,
                           "request quarantined: an identical prompt "
                           "repeatedly faulted the engine "
@@ -576,7 +578,7 @@ class Scheduler:
             routing = Routing(prefill_name=name, decode_name=name)
         else:
             prefill, decode = self.lb_policy.select_instances_pair(
-                request.token_ids, audit=audit)
+                tokens, audit=audit)
             if prefill is None:
                 audit.setdefault("reason", "no_instance")
                 self._record_decision(request, audit)
@@ -592,7 +594,7 @@ class Scheduler:
         # attributed, not asserted.
         if not request.mm_inputs:
             routing.kv_fetch = self._plan_kv_fetch(
-                request.token_ids, routing.prefill_name, audit,
+                tokens, routing.prefill_name, audit,
                 model=request.model)
         else:
             # EPD: cost-aware encode pick (queue depth + measured encode
@@ -841,7 +843,7 @@ class Scheduler:
             instance = source or tracked.decode_name \
                 or tracked.prefill_name
             poisoned = self.note_engine_fault(
-                srid, tracked.request.token_ids, instance,
+                srid, self.prompt_buffer(tracked.request), instance,
                 out.status.message)
             ctx = tracked.recovery
             if not poisoned and ctx is not None \
@@ -999,7 +1001,7 @@ class Scheduler:
         crossed ``XLLM_POISON_STRIKES`` and is now poisoned — callers
         must then fail it to the client instead of re-scheduling.
         Events/metrics are emitted outside the ledger lock."""
-        digest = prompt_digest(token_ids, self.opts.murmur_hash3_seed)
+        digest = self._prompt_digest(token_ids)
         strikes, poisoned = self.poison.strike(
             service_request_id, digest)
         if self.events is not None:
@@ -1025,8 +1027,53 @@ class Scheduler:
         """True when the prompt's content digest is under quarantine —
         the admission gate refuses such requests outright for
         ``XLLM_POISON_TTL_S`` after a poisoning."""
-        return self.poison.quarantined(
-            prompt_digest(token_ids, self.opts.murmur_hash3_seed))
+        return self.poison.quarantined(self._prompt_digest(token_ids))
+
+    # ------------------------------------------------------------------
+    # A prompt's tokens, packed once a request (utils/hashing.py)
+    # ------------------------------------------------------------------
+    def prompt_buffer(self, request: Request) -> Sequence[int]:
+        """``request.token_ids`` as the one packed int32 buffer every
+        digest of the prompt reads (the quarantine gate's and a
+        strike's ``prompt_digest``, the router's block hashes), built
+        on first use and kept on the request: a redispatch or a
+        recovery, which schedule the same request again, convert
+        nothing."""
+        buf = request.packed_ids
+        if buf is None:
+            t0 = time.perf_counter()
+            buf, wrapped = pack_tokens(request.token_ids)
+            request.packed_ids = buf
+            self._count_prompt_hash(time.perf_counter() - t0)
+            if self.obs is not None:
+                self.obs.counter(
+                    "xllm_service_prompt_hashed_tokens_total",
+                    "prompt tokens packed for hashing, once a request"
+                ).inc(len(buf))
+                self.obs.counter(
+                    "xllm_service_prompt_hash_fallback_total",
+                    "prompts with a token id outside int32, packed by "
+                    "the interpreted wrap instead of compiled code"
+                ).inc(1.0 if wrapped else 0.0)
+        return buf
+
+    def _prompt_digest(self, token_ids: Sequence[int]) -> str:
+        t0 = time.perf_counter()
+        digest = prompt_digest(token_ids, self.opts.murmur_hash3_seed)
+        self._count_prompt_hash(time.perf_counter() - t0)
+        return digest
+
+    def _count_prompt_hash(self, seconds: float) -> None:
+        """Seconds inside the pack, the digest and the block hashes of
+        prompts: over ``xllm_service_prompt_hashed_tokens_total`` (the
+        tokens packed for them, once a request) it is what a prompt
+        token costs the master's first-token stage on this deployment."""
+        if self.obs is not None:
+            self.obs.counter(
+                "xllm_service_prompt_hash_seconds_total",
+                "seconds the scheduler spent packing prompts into int32 "
+                "buffers and hashing them (quarantine digest, prefix "
+                "block hashes)").inc(seconds)
 
     # ------------------------------------------------------------------
     # Mid-stream recovery support (service/recovery.py drives these)

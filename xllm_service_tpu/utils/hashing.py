@@ -19,10 +19,10 @@ from __future__ import annotations
 import ctypes
 import os
 import struct
-import threading
+import sys
+from array import array
+from typing import List, Optional, Sequence, Tuple
 
-
-from typing import List, Optional, Sequence
 from xllm_service_tpu.utils.locks import make_lock
 
 _MASK64 = (1 << 64) - 1
@@ -134,7 +134,7 @@ def _load_native() -> Optional[ctypes.CDLL]:
             lib.xllm_murmur3_x64_128.argtypes = [
                 ctypes.c_void_p, ctypes.c_int32, ctypes.c_uint32, ctypes.c_void_p]
             lib.xllm_prefix_block_hashes.argtypes = [
-                ctypes.POINTER(ctypes.c_int32), ctypes.c_int32, ctypes.c_int32,
+                ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
                 ctypes.c_uint32, ctypes.c_void_p]
             lib.xllm_prefix_block_hashes.restype = ctypes.c_int32
             lib.xllm_chained_block_hash.argtypes = [
@@ -166,6 +166,32 @@ def _as_i32(t: int) -> int:
     return ((t & 0xFFFFFFFF) ^ 0x80000000) - 0x80000000
 
 
+def pack_tokens(tokens: Sequence[int]) -> Tuple["array[int]", bool]:
+    """``tokens`` as ONE packed int32 buffer (``array('i')``: a sequence
+    of the same ids, so ``len`` and slices read as the list's), built
+    by compiled code, and whether an id lay outside int32 and the
+    interpreted wrap of ``_as_i32`` had to run. A buffer handed in comes
+    back as it is: a caller that keeps a prompt's buffer (the master, a
+    request) packs it once, and ``prefix_block_hashes`` and
+    ``prompt_digest`` read it."""
+    if isinstance(tokens, array) and tokens.typecode == "i":
+        return tokens, False
+    try:
+        return array("i", tokens), False
+    except OverflowError:       # an id outside int32: wrap as _as_i32 does
+        return array("i", [_as_i32(t) for t in tokens]), True
+
+
+def _le32(tokens: Sequence[int], n: int) -> bytes:
+    """The first ``n`` of ``tokens`` as little-endian int32 bytes: what
+    every digest of this module hashes, on either path."""
+    buf = pack_tokens(tokens)[0]
+    if sys.byteorder == "big":
+        buf = array("i", buf)
+        buf.byteswap()
+    return memoryview(buf)[:n].tobytes()
+
+
 def chained_block_hash_py(tokens: Sequence[int], prev: Optional[bytes],
                           seed: int = 0) -> bytes:
     buf = (prev or b"") + struct.pack(
@@ -185,20 +211,18 @@ def chained_block_hash(tokens: Sequence[int], prev: Optional[bytes],
     if prev is not None and len(prev) != 16:
         raise ValueError("prev is a 16-byte digest or None")
     n = len(tokens)
-    try:
-        data = struct.pack(f"<{n}i", *tokens)
-    except struct.error:        # an id outside int32: wrap as _as_i32 does
-        data = struct.pack(f"<{n}i", *[_as_i32(t) for t in tokens])
     out = ctypes.create_string_buffer(16)
     # The library copies the buffer byte for byte, so le32 in is le32
     # hashed, whatever the host's byte order.
-    lib.xllm_chained_block_hash(data, n, prev, seed & 0xFFFFFFFF, out)
+    lib.xllm_chained_block_hash(_le32(tokens, n), n, prev,
+                                seed & 0xFFFFFFFF, out)
     return out.raw
 
 
 def prefix_block_hashes(tokens: Sequence[int], block_size: int,
                         seed: int = 0) -> List[bytes]:
-    """Chained digests of every *complete* ``block_size`` window of ``tokens``.
+    """Chained digests of every *complete* ``block_size`` window of ``tokens``
+    (a list of ids, or the buffer ``pack_tokens`` made of one).
 
     The trailing partial block is excluded: the prefix-cache index only tracks
     full blocks, matching the KV-page granularity of the worker.
@@ -206,20 +230,21 @@ def prefix_block_hashes(tokens: Sequence[int], block_size: int,
     n_blocks = len(tokens) // block_size
     if n_blocks == 0:
         return []
+    data = _le32(tokens, n_blocks * block_size)
     lib = _load_native()
     if lib is None:
         out: List[bytes] = []
-        prev: Optional[bytes] = None
+        prev = b""
+        step = 4 * block_size
         for b in range(n_blocks):
-            d = chained_block_hash_py(
-                tokens[b * block_size:(b + 1) * block_size], prev, seed)
-            out.append(d)
-            prev = d
+            prev = murmur3_x64_128_py(
+                prev + data[b * step:(b + 1) * step], seed)
+            out.append(prev)
         return out
-    arr = (ctypes.c_int32 * (n_blocks * block_size))(
-        *[_as_i32(t) for t in tokens[: n_blocks * block_size]])
     buf = ctypes.create_string_buffer(16 * n_blocks)
-    lib.xllm_prefix_block_hashes(arr, n_blocks * block_size, block_size,
+    # The library copies the buffer byte for byte (as for
+    # chained_block_hash): le32 in is le32 hashed.
+    lib.xllm_prefix_block_hashes(data, n_blocks * block_size, block_size,
                                  seed & 0xFFFFFFFF, buf)
     raw = buf.raw
     return [raw[i * 16:(i + 1) * 16] for i in range(n_blocks)]
@@ -229,7 +254,7 @@ def prompt_digest(tokens: Sequence[int], seed: int = 0) -> str:
     """Whole-prompt content digest (hex) for the poison ledger
     (docs/ROBUSTNESS.md): unlike ``prefix_block_hashes`` it covers the
     trailing partial block too — two prompts quarantine together iff
-    they are token-identical. Same int32 packing as the block hashes,
-    so the digest is stable across the native and Python paths."""
-    data = struct.pack(f"<{len(tokens)}i", *[_as_i32(t) for t in tokens])
-    return murmur3_x64_128(data, seed).hex()
+    they are token-identical. Same int32 packing as the block hashes
+    (and the same two inputs: a list, or its packed buffer), so the
+    digest is stable across the native and Python paths."""
+    return murmur3_x64_128(_le32(tokens, len(tokens)), seed).hex()

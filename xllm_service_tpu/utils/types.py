@@ -377,6 +377,13 @@ class Request:
     prompt: str = ""
     messages: List[Dict[str, Any]] = dataclasses.field(default_factory=list)
     token_ids: List[int] = dataclasses.field(default_factory=list)
+    # ``token_ids`` as one packed int32 buffer (utils/hashing.py
+    # ``pack_tokens``), built by the scheduler the first time it hashes
+    # the prompt (``Scheduler.prompt_buffer``) and read by every digest
+    # after that: the quarantine gate, the router's block hashes, a
+    # redispatch's, a strike's.
+    packed_ids: Optional[Any] = dataclasses.field(
+        default=None, repr=False, compare=False)
     routing: Routing = dataclasses.field(default_factory=Routing)
     sampling: SamplingParams = dataclasses.field(default_factory=SamplingParams)
     # Multimodal inputs for the EPD encode stage.
