@@ -492,8 +492,8 @@ def test_the_counters_equal_a_hand_count():
     # was handed its block: uploads + resident hits = decode steps
     assert [r["upload"] for r in recs] == [1, 0, 0, 0, 1, 1, 0, 0, 1]
     assert pc["decode.upload"] == 4 and pc["decode.resident_hit"] == 4
-    assert eng.overlap_metrics()["spec_hits"] == 6
-    assert eng.overlap_metrics()["spec_dispatches"] == 6
+    assert eng.overlap_metrics() == {
+        "ahead_dispatches": 6, "ahead_hits": 6, "ahead_discards": 0}
     assert eng._pending is None and not eng.has_work()
 
 

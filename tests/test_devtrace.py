@@ -290,6 +290,53 @@ def test_endpoint_start_stop_and_its_refusals(worker, tmp_path):
         <= s["start"] + s["dur"] for s in steps) for e in inner)
 
 
+def test_a_streamed_token_is_a_span_on_its_handlers_line(worker, tmp_path):
+    """``xllm.stream.token`` (an output off the request's queue -> its
+    frames written) is a HANDLER's span: in a real CPU trace it lies on
+    a line that holds no span of the engine loop, its name is none the
+    benchmark nests under the engine's thread, and no stream writes one
+    before start or after stop."""
+    from jax.profiler import ProfileData
+    from chipbench import spans, trace
+
+    def stream(prompt, n):
+        host, port = worker.name.rsplit(":", 1)
+        conn = HTTPConnection(host, int(port), timeout=120)
+        try:
+            conn.request("POST", "/v1/completions", body=json.dumps({
+                "model": "tiny", "prompt": prompt, "max_tokens": n,
+                "temperature": 0.0, "ignore_eos": True, "stream": True}))
+            r = conn.getresponse()
+            assert r.status == 200 and b"[DONE]" in r.read()
+        finally:
+            conn.close()
+
+    name = "xllm.stream.token"
+    assert name in steptrace.SPAN_NAMES
+    assert not spans.ENGINE_THREAD.match(name)
+    stream("before the trace", 3)
+    d = str(tmp_path / "t")
+    worker.start_device_trace(d)
+    stream("inside the trace", 6)
+    worker.stop_device_trace()
+    stream("after the trace", 3)
+    lines = [{e.name for e in ln.events if e.name.startswith("xllm.")}
+             for plane in ProfileData.from_file(
+                 trace.find_xplane(d)).planes for ln in plane.lines]
+    handlers = [names for names in lines if name in names]
+    assert handlers
+    for names in handlers:              # a handler admits and streams
+        assert not any(spans.ENGINE_THREAD.match(n) for n in names), names
+    assert any("xllm.loop.emit" in names and name not in names
+               for names in lines)
+    events = trace.load_events(trace.find_xplane(d))
+    # one a token's output of the ONE traced stream (6), none of the
+    # other two's
+    assert len(spans.program_spans(events, r"^xllm\.stream\.token$")) == 6
+    assert name not in {seg[2] for seg in spans.innermost_segments(
+        spans.program_spans(events, spans.ENGINE_THREAD.pattern))}
+
+
 # ---------------------------------------------------------------------------
 # The two counters
 # ---------------------------------------------------------------------------

@@ -278,16 +278,17 @@ class TestEndToEnd:
                 if line.startswith('xllm_worker_decode_ahead_total'
                                    '{model="tiny",result="hit"}'))
             assert ahead > 0
-            hits = next(
+            launched = next(
                 float(line.split()[-1]) for line in wtext.splitlines()
-                if line.startswith('xllm_worker_decode_overlap_spec_'
-                                   'total{model="tiny",result="hit"}'))
-            assert hits >= ahead
-            ratio = next(
-                float(line.split()[-1]) for line in wtext.splitlines()
-                if line.startswith('xllm_worker_decode_overlap_hit_'
-                                   'ratio{model="tiny"}'))
-            assert ratio > 0
+                if line.startswith('xllm_worker_decode_ahead_total'
+                                   '{model="tiny",result="launched"}'))
+            assert launched >= ahead
+            # the kept series alone says it: the pair that repeated it
+            # under the deleted burst's names is gone (PR 55)
+            assert "xllm_worker_decode_overlap" not in wtext
+            om = eng.overlap_metrics()
+            assert om["ahead_hits"] >= ahead > 0
+            assert om["ahead_dispatches"] >= launched
             # The split readback attribution reaches the phase ledger.
             assert 'phase="decode.device_wait"' in wtext
             assert 'phase="decode.host_copy"' in wtext

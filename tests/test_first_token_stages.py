@@ -391,6 +391,65 @@ def test_a_non_stream_request_ends_its_chain_at_first_token(worker):
     assert "first_frame" not in stages and "ready" in stages
 
 
+def _token_out(worker):
+    """(tokens, wake seconds, write seconds) of the worker's emit-to-wire
+    counters now."""
+    _, text = _get(worker.name, "/metrics")
+
+    def one(series):
+        m = re.search(re.escape(series) + r" (\S+)", text)
+        return float(m.group(1)) if m else 0.0
+    fam = 'xllm_worker_token_out_seconds_total{model="tiny",stage="%s"}'
+    return (one('xllm_worker_token_out_tokens_total{model="tiny"}'),
+            one(fam % "wake"), one(fam % "write"))
+
+
+@pytest.mark.parametrize("stream", [True, False],
+                         ids=["_stream_sse", "_collect_full"])
+def test_every_token_is_timed_from_emit_to_the_wire(worker, stream):
+    """Three requests of N tokens leave 3N in the count and both stages
+    ahead, on either path a handler's thread takes a token; the engine's
+    thread gave each output the ONE clock read its emit makes."""
+    n = 70                              # past the fold at 64 tokens
+    seen, real = [], worker._dispatch_outputs
+    worker._dispatch_outputs = lambda rt, outs, ms: (
+        real(rt, outs, ms), seen.extend(outs))[0]
+    before = _token_out(worker)
+    try:
+        for k in range(3):
+            if stream:
+                _stream(worker.name, "", max_tokens=n,
+                        service_request_id=f"tok-s{k}")
+            else:
+                status, resp = http_json(
+                    "POST", worker.name, "/v1/completions",
+                    {"model": "tiny", "prompt": "all at once",
+                     "max_tokens": n, "temperature": 0.0,
+                     "ignore_eos": True,
+                     "service_request_id": f"tok-f{k}"}, timeout=120.0)
+                assert status == 200 \
+                    and resp["usage"]["completion_tokens"] == n
+    finally:
+        del worker._dispatch_outputs
+    assert wait_until(lambda: _token_out(worker)[0] - before[0] == 3 * n)
+    after = _token_out(worker)
+    assert after[1] > before[1] and after[2] > before[2]
+    # a token's mean way out is far under a second on any machine
+    assert (after[1] - before[1]) / (3 * n) < 1.0
+    # one stamp an emit: the outputs of one call share it, to the bit
+    mine = [o for o in seen if o.request_id.startswith("tok-")]
+    assert len(mine) >= 3 * n - 3 and all(o.emit_t > 0 for o in mine)
+    assert len({o.emit_t for o in mine}) <= len(mine)
+    src = open(os.path.join(PKG, "runtime", "worker.py")).read()
+    emit = next(f for f in ast.walk(ast.parse(src))
+                if isinstance(f, ast.FunctionDef)
+                and f.name == "_dispatch_outputs")
+    clock_reads = [c for c in ast.walk(emit) if isinstance(c, ast.Call)
+                   and ast.unparse(c.func) == "time.monotonic"]
+    # the call's own read, and the first token's (PR 40): none a token
+    assert len(clock_reads) == 2
+
+
 def test_the_fan_in_folds_the_admission_at_its_ack(store):
     master, workers = make_cluster(store, decode_to_service=True)
     w = workers[0]

@@ -126,6 +126,178 @@ class TestLockContention:
             in text
 
 
+def _series(text, family, label="root"):
+    import re
+    return {m.group(1): float(m.group(2)) for m in re.finditer(
+        r"^%s\{%s=\"([^\"]+)\"\} (\S+)$" % (family, label), text, re.M)}
+
+
+@pytest.fixture
+def task_dir(tmp_path, monkeypatch):
+    """The per-root books emptied and pointed at a directory of fixture
+    files (``<tid>/schedstat``, ``<tid>/stat``) in the place of
+    ``/proc/self/task``; put back afterwards. ``write(tid, cpu_s,
+    runq_s)`` lays a thread's files down as the kernel would, ``exit``
+    takes them away, ``claim`` registers a tid as its thread would."""
+    import os
+    for name, fresh in (("_tid_root", {}), ("_fresh_tids", set()),
+                        ("_tid_last", {}), ("_root_total", {}),
+                        ("_clock_source", None),
+                        ("_TASK_DIR", str(tmp_path))):
+        monkeypatch.setattr(profiler, name, fresh)
+
+    class Dir:
+        schedstat = True
+
+        def write(self, tid, cpu_s, runq_s=0.0):
+            d = tmp_path / str(tid)
+            d.mkdir(exist_ok=True)
+            ticks = int(round(cpu_s * profiler._CLK_TCK))
+            (d / "stat").write_text(
+                f"{tid} (httpd-native (1)) S 1 1 1 0 -1 4194304 0 0 0 0 "
+                f"{ticks - ticks // 4} {ticks // 4} 0 0 20 0 9 0 1 2 3\n")
+            if self.schedstat:
+                (d / "schedstat").write_text(
+                    f"{int(cpu_s * 1e9)} {int(runq_s * 1e9)} 7\n")
+
+        def exit(self, tid):
+            import shutil
+            shutil.rmtree(tmp_path / str(tid))
+
+        def claim(self, tid, root):
+            monkeypatch.setattr(threading, "get_native_id", lambda: tid)
+            profiler.register_thread_root(root)
+
+    d = Dir()
+    d.pid = os.getpid()
+    return d
+
+
+@pytest.mark.parametrize("source", ["schedstat", "stat"])
+def test_every_thread_has_a_root_and_no_series_ever_falls(task_dir, source):
+    """Handlers come and go a request and the kernel hands their tids out
+    again: an exited handler's seconds stay, a thread no root claims is
+    ``unregistered``, the main thread is ``main``, and where the host
+    keeps no schedstats the ticks of ``stat`` are read and no wait is
+    known. One walk for both clocks."""
+    task_dir.schedstat = source == "schedstat"
+    wait = 1.0 if task_dir.schedstat else 0.0     # stat knows no wait
+    task_dir.write(task_dir.pid, 3.0, 0.25)
+    task_dir.write(501, 2.0, 0.5)
+    task_dir.write(777, 40.0, 8.0)                # a native thread
+    task_dir.claim(501, profiler.HANDLER_ROOT)
+    snap = profiler.thread_clock_snapshot()
+    assert profiler._source() == source
+    assert snap == {"main": (3.0, 0.25 * wait),
+                    "httpd.handler": (2.0, 0.5 * wait),
+                    "unregistered": (40.0, 8.0 * wait)}
+    seen = [snap]
+    # it runs on; then it exits between two scrapes, having booked its
+    # own last seconds as service/httpd.py's finish() does
+    task_dir.write(501, 2.5, 0.75)
+    seen.append(profiler.thread_clock_snapshot())
+    assert seen[-1]["httpd.handler"] == (2.5, 0.75 * wait)
+    task_dir.write(501, 2.75, 0.75)
+    profiler.retire_thread_root()                 # (the tid is 501's)
+    task_dir.exit(501)
+    seen.append(profiler.thread_clock_snapshot())
+    assert seen[-1]["httpd.handler"] == (2.75, 0.75 * wait)
+    # the kernel hands 501 to the next connection's thread, which
+    # starts from nothing: the root gains what THAT thread runs
+    task_dir.write(501, 0.5, 0.125)
+    task_dir.claim(501, profiler.HANDLER_ROOT)
+    seen.append(profiler.thread_clock_snapshot())
+    assert seen[-1]["httpd.handler"] == (3.25, 0.875 * wait)
+    # that one dies unbooked (a crash) and, before the next scrape, its
+    # tid goes to a native thread nobody claims: the dead claim is
+    # dropped, not fed
+    task_dir.write(501, 0.25, 0.0)
+    task_dir.write(777, 41.0, 8.0)
+    seen.append(profiler.thread_clock_snapshot())
+    assert seen[-1]["httpd.handler"] == (3.25, 0.875 * wait)
+    assert seen[-1]["unregistered"] == (41.25, 8.0 * wait)
+    assert seen[-1]["main"] == (3.0, 0.25 * wait)
+    for before, after in zip(seen, seen[1:]):
+        for root, (cpu, runq) in before.items():
+            assert after[root][0] >= cpu and after[root][1] >= runq
+    reg = Registry()
+    profiler.flush_metrics(reg)
+    text = reg.render()
+    assert _series(text, "xllm_thread_clock", label="source") == {
+        "schedstat": float(source == "schedstat"),
+        "stat": float(source == "stat")}
+    assert _series(text, "xllm_thread_runq_wait_seconds_total")[
+        "unregistered"] == 8.0 * wait
+    assert profiler.thread_cpu_snapshot()["httpd.handler"] == 3.25
+
+
+def test_threads_come_and_go_under_a_scraper_and_no_total_falls():
+    """Handlers that register, burn a little and book themselves out,
+    more of them than cores and under a shortened switch interval, while
+    one thread scrapes: no read raises, no root's series ever falls, and
+    what the handlers burned is all there at the end."""
+    import os
+    import sys
+    errors, seen = [], []
+    stop = threading.Event()
+    burn_s, workers, rounds = 0.002, 4 * (os.cpu_count() or 2), 6
+
+    def handler():
+        try:
+            for _ in range(rounds):
+                t = threading.Thread(target=one_connection)
+                t.start()
+                t.join(10.0)
+                assert not t.is_alive()
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    def one_connection():
+        try:
+            profiler.register_thread_root("test.stress")
+            t0 = time.thread_time()
+            while time.thread_time() - t0 < burn_s:
+                pass
+            profiler.retire_thread_root()
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    def scraper():
+        try:
+            while not stop.is_set():
+                seen.append(profiler.thread_clock_snapshot())
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    before = profiler.thread_clock_snapshot().get("test.stress", (0.0, 0.0))
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        sc = threading.Thread(target=scraper)
+        sc.start()
+        ts = [threading.Thread(target=handler) for _ in range(workers)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(60.0)
+        stop.set()
+        sc.join(10.0)
+        assert not sc.is_alive() and not any(t.is_alive() for t in ts)
+    finally:
+        sys.setswitchinterval(old)
+    assert errors == []
+    seen.append(profiler.thread_clock_snapshot())
+    for a, b in zip(seen, seen[1:]):
+        for root, (cpu, runq) in a.items():
+            assert b[root][0] >= cpu and b[root][1] >= runq, root
+    burned = seen[-1]["test.stress"][0] - before[0]
+    if profiler._source() == "schedstat":
+        # every thread booked itself out: nothing between two scrapes
+        # was lost (thread_time and schedstat agree to a fraction)
+        assert burned >= 0.8 * workers * rounds * burn_s
+    assert "test.stress" not in profiler._tid_root.values()
+
+
 class TestSelfStats:
     def test_thread_cpu_attributed_per_root(self):
         done = threading.Event()
@@ -145,6 +317,37 @@ class TestSelfStats:
         assert snap["test.burner"] >= 0.0
         # After exit the root's total is retired, never dropped.
         assert "test.burner" in profiler.thread_cpu_snapshot()
+
+    def test_a_handlers_thread_shows_under_both_families(self):
+        """What service/httpd.py does once a connection: the thread
+        takes the handlers' root, and the scrape carries its seconds on
+        a core and its seconds waiting for one, beside ``main`` and the
+        threads no root claims."""
+        done, go = threading.Event(), threading.Event()
+
+        def handle():
+            profiler.register_thread_root(profiler.HANDLER_ROOT)
+            t0 = time.process_time()
+            while time.process_time() - t0 < 0.03:
+                pass
+            done.set()
+            go.wait(5.0)
+        t = threading.Thread(target=handle)
+        t.start()
+        assert done.wait(5.0)
+        reg = Registry()
+        profiler.flush_metrics(reg)
+        go.set()
+        t.join()
+        text = reg.render()
+        cpu = _series(text, "xllm_thread_cpu_seconds_total")
+        runq = _series(text, "xllm_thread_runq_wait_seconds_total")
+        assert {"httpd.handler", "main", "unregistered"} <= set(cpu)
+        assert set(cpu) == set(runq)
+        assert cpu["httpd.handler"] >= 0.02 and cpu["main"] > 0
+        clock = _series(text, "xllm_thread_clock", label="source")
+        assert sorted(clock.values()) == [0.0, 1.0]
+        assert clock[profiler._source()] == 1.0
 
     def test_gc_pauses_are_booked(self):
         import gc

@@ -36,6 +36,7 @@ def _fake_worker(timeout_s: float):
     w.finalized = []
     w._finalize_live = w.finalized.append
     w._process_step_output = lambda live, out: []
+    w._fold_token_out = lambda live: None    # nothing was handed out
     return w
 
 

@@ -19,13 +19,20 @@ instead of a guess:
   (``XLLM_LOCK_PROFILE_SAMPLE``) into its own book; ``flush_metrics``
   mirrors it here as ``xllm_lock_wait_ms{lock,rank}`` /
   ``xllm_lock_contended_total{lock}`` (locks.py never imports obs).
-- **Per-thread-root CPU**: supervised threads register their native tid
+- **Per-thread-root CPU and run-queue wait**: every thread of the
+  process has a root. Supervised threads register their native tid
   under their root name (utils/threads.py calls
-  ``register_thread_root``); scrape-time reads of
-  ``/proc/self/task/<tid>/stat`` utime+stime become
-  ``xllm_thread_cpu_seconds_total{root}``. ``time.thread_time_ns`` only
-  measures the *calling* thread, so /proc is the only way to account
-  someone else's CPU.
+  ``register_thread_root``), an HTTP handler's thread registers as
+  ``httpd.handler`` once a connection (service/httpd.py), the main
+  thread is ``main``, and whatever no root claims (the runtime's native
+  threads) is ``unregistered``. Scrape-time reads of
+  ``/proc/self/task/<tid>/schedstat`` (nanoseconds on a core, and
+  nanoseconds runnable and waiting for one; ``stat``'s ticks where the
+  kernel keeps no schedstats, ``xllm_thread_clock{source}`` says which)
+  become ``xllm_thread_cpu_seconds_total{root}`` and
+  ``xllm_thread_runq_wait_seconds_total{root}``.
+  ``time.thread_time_ns`` only measures the *calling* thread, so /proc
+  is the only way to account someone else's CPU.
 - **Self-gauges**: RSS, process CPU% (delta between scrapes), live
   thread count, and GC pauses via ``gc.callbacks`` →
   ``xllm_gc_pause_ms`` + ``xllm_gc_collections_total{generation}``.
@@ -274,65 +281,158 @@ def recent_events(window_s: float = 0.0,
 
 
 # ---------------------------------------------------------------------------
-# Per-thread-root CPU accounting (/proc/self/task/<tid>/stat)
+# Per-thread-root accounting: CPU and run-queue wait of EVERY thread of
+# the process (/proc/self/task/<tid>/schedstat, or stat's ticks)
 # ---------------------------------------------------------------------------
 
+# Where the threads' files are read. A test points it at a directory of
+# fixture files (``<tid>/schedstat``, ``<tid>/stat``).
+_TASK_DIR = "/proc/self/task"
+# Every tid no root claims: the runtime's native threads (PJRT, XLA's
+# pools, the device profiler's) and any bare Python thread.
+UNREGISTERED = "unregistered"
+MAIN_ROOT = "main"
+# Every thread that runs a request's handler, under either front door
+# (service/httpd.py, service/native_httpd.py).
+HANDLER_ROOT = "httpd.handler"
+
 _roots_lock = threading.Lock()
-_root_tids: Dict[str, set] = {}       # root -> live native tids
-_tid_cpu_last: Dict[int, float] = {}  # tid -> last observed cpu seconds
-_root_retired: Dict[str, float] = {}  # cpu seconds of exited threads
+_tid_root: Dict[int, str] = {}        # native tid -> the root it claims
+_fresh_tids: set = set()              # claimed since the last snapshot
+# tid -> (cpu s, run-queue-wait s) as last read; a root's totals only
+# ever gain the difference to it, so an exited thread's seconds stay and
+# every series is monotonic whatever comes and goes.
+_tid_last: Dict[int, Tuple[float, float]] = {}
+_root_total: Dict[str, List[float]] = {}   # root -> [cpu s, runq s]
+_clock_source: Optional[str] = None   # "schedstat" | "stat", probed once
 
 
 def register_thread_root(root: str) -> None:
-    """Called from the supervised-thread wrapper (utils/threads.py) at
-    thread start: binds this thread's native tid to its root name so
-    scrape-time /proc reads can attribute CPU per root."""
+    """Bind the calling thread's native tid to ``root``, so that
+    scrape-time /proc reads attribute its time there: the
+    supervised-thread wrapper (utils/threads.py) at thread start, an
+    HTTP handler's thread once a connection (service/httpd.py)."""
     try:
         tid = threading.get_native_id()
     except Exception:  # noqa: BLE001 — attribution is best-effort: on a
         return         # platform with no native tids the root simply
-                       # reports no CPU series, never fails to start
+                       # reports no series, never fails to start
     with _roots_lock:
-        _root_tids.setdefault(root, set()).add(tid)
+        _tid_root[tid] = root
+        _fresh_tids.add(tid)
+        _root_total.setdefault(root, [0.0, 0.0])
 
 
-def _read_tid_cpu_s(tid: int) -> Optional[float]:
+def retire_thread_root() -> None:
+    """The calling thread is about to exit: book what it has run since
+    the last scrape and let its tid go. A thread a connection lives
+    between two scrapes more often than not, and no later read finds
+    it."""
     try:
-        with open(f"/proc/self/task/{tid}/stat", "rb") as f:
-            data = f.read()
+        tid = threading.get_native_id()
+    except Exception:  # noqa: BLE001 — as in register_thread_root
+        return
+    with _roots_lock:
+        root = _tid_root.pop(tid, None)
+        if root is not None:
+            _book(tid, root, _read_tid_clock(tid, _source()))
+            _fresh_tids.discard(tid)
+            _tid_last.pop(tid, None)
+
+
+def _read_fields(tid: int, name: str) -> Optional[List[bytes]]:
+    # os.open/os.read and not open(): three system calls a file where
+    # the buffered reader makes a dozen, 49 us a thread against 223 on
+    # a sandboxed kernel (PERF.md, PR 55), times a few hundred threads.
+    try:
+        fd = os.open(f"{_TASK_DIR}/{tid}/{name}", os.O_RDONLY)
     except OSError:
         return None
-    # comm may contain spaces/parens — fields resume after the LAST ')'.
-    rest = data.rsplit(b")", 1)[-1].split()
     try:
-        return (int(rest[11]) + int(rest[12])) / _CLK_TCK
+        data = os.read(fd, 1024)
+    except OSError:
+        return None
+    finally:
+        os.close(fd)
+    # stat: comm may contain spaces/parens — fields resume after the
+    # LAST ')'. schedstat has no ')' and is split whole.
+    return data.rsplit(b")", 1)[-1].split()
+
+
+def _read_tid_clock(tid: int, source: str
+                    ) -> Optional[Tuple[float, float]]:
+    """(seconds on a core, seconds runnable and waiting for one) of one
+    thread, or None once it has exited. ``schedstat`` counts both in
+    nanoseconds; ``stat`` counts the first in ticks of 10 ms and knows
+    nothing of the second (0)."""
+    try:
+        if source == "schedstat":
+            f = _read_fields(tid, "schedstat")
+            return None if f is None else (int(f[0]) / 1e9,
+                                           int(f[1]) / 1e9)
+        f = _read_fields(tid, "stat")
+        return None if f is None else (
+            (int(f[11]) + int(f[12])) / _CLK_TCK, 0.0)
     except (IndexError, ValueError):
         return None
 
 
-def thread_cpu_snapshot() -> Dict[str, float]:
-    """Cumulative CPU seconds per supervised root (live threads read
-    from /proc; exited threads keep their last-known contribution, so
-    the series stays monotonic)."""
+def _source() -> str:
+    """Which file the clocks come from, settled once a process on the
+    main thread's own (it has run by now: zeros mean the kernel keeps no
+    schedstats), so that no series ever mixes nanoseconds with ticks."""
+    global _clock_source
+    if _clock_source is None:
+        probe = _read_tid_clock(os.getpid(), "schedstat")
+        _clock_source = "schedstat" if probe and probe[0] > 0 else "stat"
+    return _clock_source
+
+
+def _book(tid: int, root: str, cur: Optional[Tuple[float, float]]) -> None:
+    """Add what ``tid`` ran since it was last read to ``root``'s totals
+    (roots lock held)."""
+    if cur is None:
+        return
+    last = _tid_last.get(tid, (0.0, 0.0))
+    if cur[0] < last[0]:            # the tid was handed out again
+        last = (0.0, 0.0)
+    tot = _root_total.setdefault(root, [0.0, 0.0])
+    tot[0] += cur[0] - last[0]
+    tot[1] += max(0.0, cur[1] - last[1])
+    _tid_last[tid] = cur
+
+
+def thread_clock_snapshot() -> Dict[str, Tuple[float, float]]:
+    """Cumulative (CPU seconds, run-queue-wait seconds) per root, over
+    every thread the process has: one small file a live thread, read
+    now. The main thread is ``main``; a tid no root claims goes to
+    ``unregistered``."""
     with _roots_lock:
-        out: Dict[str, float] = {}
-        for root, tids in _root_tids.items():
-            live = 0.0
-            for tid in list(tids):
-                cur = _read_tid_cpu_s(tid)
-                if cur is None:
-                    # Thread exited: retire its last-known total.
-                    _root_retired[root] = (
-                        _root_retired.get(root, 0.0)
-                        + _tid_cpu_last.pop(tid, 0.0))
-                    tids.discard(tid)
-                    continue
-                _tid_cpu_last[tid] = cur
-                live += cur
-            out[root] = _root_retired.get(root, 0.0) + live
-        for root, retired in _root_retired.items():
-            out.setdefault(root, retired)
-    return out
+        source = _source()
+        try:
+            listed = {int(n) for n in os.listdir(_TASK_DIR) if n.isdigit()}
+        except OSError:
+            listed = set()
+        for tid in listed:
+            cur = _read_tid_clock(tid, source)
+            if cur is not None and tid not in _fresh_tids \
+                    and cur[0] < _tid_last.get(tid, cur)[0]:
+                # The kernel handed the number to another thread, which
+                # did not claim it: the claim is the dead one's.
+                _tid_root.pop(tid, None)
+            _book(tid, _tid_root.get(tid) or (
+                MAIN_ROOT if tid == os.getpid() else UNREGISTERED), cur)
+        for book in (_tid_root, _tid_last):     # exited: already booked
+            for tid in set(book) - listed:
+                del book[tid]
+        _fresh_tids.clear()
+        return {root: (t[0], t[1]) for root, t in _root_total.items()}
+
+
+def thread_cpu_snapshot() -> Dict[str, float]:
+    """Cumulative CPU seconds per root (``thread_clock_snapshot``'s
+    first column)."""
+    return {root: t[0] for root, t in thread_clock_snapshot().items()}
 
 
 # ---------------------------------------------------------------------------
@@ -482,10 +582,27 @@ def flush_metrics(registry) -> None:
 
     cpu_c = registry.counter(
         "xllm_thread_cpu_seconds_total",
-        "cumulative CPU seconds per supervised thread root",
+        "cumulative CPU seconds per thread root: every thread of the "
+        "process has one (supervised roots, httpd.handler, main, and "
+        "unregistered for the runtime's native threads)",
         labelnames=("root",))
-    for root, secs in thread_cpu_snapshot().items():
-        cpu_c.set_total(secs, root=root)
+    runq_c = registry.counter(
+        "xllm_thread_runq_wait_seconds_total",
+        "cumulative seconds a root's threads were runnable and waiting "
+        "for a core (schedstat's second field; stays 0 under "
+        "xllm_thread_clock{source=\"stat\"})",
+        labelnames=("root",))
+    for root, (cpu_s, runq_s) in thread_clock_snapshot().items():
+        cpu_c.set_total(cpu_s, root=root)
+        runq_c.set_total(runq_s, root=root)
+    clock_g = registry.gauge(
+        "xllm_thread_clock",
+        "1 on the file the per-root thread clocks are read from: "
+        "schedstat (nanoseconds, with the run-queue wait) or stat "
+        "(ticks of 10 ms, no wait)",
+        labelnames=("source",))
+    for source in ("schedstat", "stat"):
+        clock_g.set(1.0 if source == _source() else 0.0, source=source)
 
     rss = process_rss_bytes()
     if rss is not None:
@@ -558,13 +675,15 @@ def snapshot() -> Dict[str, Any]:
                 _locks.LOCK_WAIT_BUCKETS_MS).items()})
         lock_rows[name] = row
     g = gc_snapshot()
+    clocks = sorted(thread_clock_snapshot().items())
     return {
         "enabled": ENABLED,
         "lock_profile_sample": _locks.PROFILE_SAMPLE,
         "sections": sections,
         "locks": lock_rows,
-        "thread_cpu_s": {r: round(v, 3) for r, v in
-                         sorted(thread_cpu_snapshot().items())},
+        "thread_clock": _source(),
+        "thread_cpu_s": {r: round(c, 3) for r, (c, _) in clocks},
+        "thread_runq_wait_s": {r: round(w, 3) for r, (_, w) in clocks},
         "self": {
             "rss_bytes": process_rss_bytes(),
             "threads": threading.active_count(),

@@ -62,8 +62,10 @@ STEP_FIELDS: Tuple[str, ...] = (
     "attn_dispatches",  # attention-bearing device dispatches this step
     "members",          # request ids in the step's batch (tuple)
     "phases",           # {phase: ms} DELTA of the engine ledger this step
-    "spec",             # {dispatches,hits,rollbacks} delta of the decode
-                        # steps dispatched ahead of their iteration
+    "spec",             # {ahead_dispatches, ahead_hits, ahead_discards}
+                        # delta of the decode steps put on the device
+                        # ahead of their iteration: launched ahead and
+                        # dispatched at a tail, summed
     "kv_usage",         # KV page pool utilization [0,1] after the step
     "pages_delta",      # free-page delta across the step (+freed/-taken)
     "cache_hit_tokens", # prefix-cache hit-token delta this step
@@ -131,6 +133,8 @@ SPAN_NAMES: Tuple[str, ...] = (
     "xllm.admit",            # handler thread: parsed request -> enqueued
     "xllm.admit.lock_wait",  # ... waiting for _engine_lock
     "xllm.admit.locked",     # ... holding it (Engine.add_request)
+    "xllm.stream.token",     # handler thread: an output taken off the
+                             # request's queue -> its frames written
 ) + tuple("xllm.step." + p for p in STEP_PHASES) + tuple(
     f"xllm.step.{p}.{half}" for p in READ_HOST_PHASES
     for half in ("device_wait", "host_copy"))

@@ -191,6 +191,11 @@ class StepOutput:
     # The engine's stamps of the request's first-token chain (obs/spans.py
     # FIRST_TOKEN_STAMPS): on a sequence's FIRST output only, None after.
     first_token_stamps: Optional[Dict[str, float]] = None
+    # When the worker's ``emit`` that handed this output out began, on
+    # its monotonic clock (``Worker._dispatch_outputs`` reads it once a
+    # call and stores it here): the start of every token's way to the
+    # wire. 0.0 for an output no ``emit`` handed out.
+    emit_t: float = 0.0
 
     @property
     def finished(self) -> bool:
@@ -808,23 +813,20 @@ class Engine:
         return top_ids is not None and any(
             s.req.sampling.logprobs for s in seqs)
 
-    def overlap_metrics(self) -> Dict[str, Any]:
+    def overlap_metrics(self) -> Dict[str, int]:
         """How the decode steps put on the device ahead of their
         iteration fared (launched ahead or dispatched at a tail), for
-        the obs registry and the step record: how many were dispatched,
-        taken and discarded, and the share taken. (The keys say "spec"
-        from before the single step launched ahead: the worker's series
-        ``xllm_worker_decode_overlap_*`` carry them.)"""
+        the step record and tests: how many were dispatched, taken and
+        discarded, both kinds of launch summed
+        (``xllm_worker_decode_ahead_total{result}`` tells them apart)."""
         pc = self.phase_counts
-        disp = pc.get("decode.ahead_dispatch", 0) \
-            + pc.get("decode.tail_dispatch", 0)
-        hits = pc.get("decode.ahead_hit", 0) + pc.get("decode.tail_hit", 0)
         return {
-            "spec_dispatches": disp,
-            "spec_hits": hits,
-            "spec_rollbacks": pc.get("decode.ahead_discard", 0)
+            "ahead_dispatches": pc.get("decode.ahead_dispatch", 0)
+            + pc.get("decode.tail_dispatch", 0),
+            "ahead_hits": pc.get("decode.ahead_hit", 0)
+            + pc.get("decode.tail_hit", 0),
+            "ahead_discards": pc.get("decode.ahead_discard", 0)
             + pc.get("decode.tail_discard", 0),
-            "hit_ratio": hits / disp if disp else 0.0,
         }
 
     # ------------------------------------------------------------------

@@ -463,7 +463,8 @@ class _LiveRequest:
                  "stream_to_service", "service_request_id", "model",
                  "is_chat", "stream", "include_usage", "first_out_time",
                  "sampling", "prompt_tokens", "target_n", "prompt_lps",
-                 "_echo_cache", "emit_token_ids", "stamps", "front_ms")
+                 "_echo_cache", "emit_token_ids", "stamps", "front_ms",
+                 "tok_wake_s", "tok_write_s", "tok_n")
 
     def __init__(self, req: EngineRequest, tokenizer: Tokenizer,
                  service_request_id: str, model: str, is_chat: bool,
@@ -488,6 +489,13 @@ class _LiveRequest:
         # share, as the forward's header gave it.
         self.stamps: Optional[Dict[str, float]] = {}
         self.front_ms: Optional[float] = None
+        # Every token from ``emit`` to the wire, summed by the handler's
+        # thread (``token_out``) until ``Worker._fold_token_out`` moves
+        # them into the counters: seconds from the emit to the handler's
+        # wake, seconds from the wake to the frame written, tokens.
+        self.tok_wake_s = 0.0
+        self.tok_write_s = 0.0
+        self.tok_n = 0
         n = max(1, n)
         self.engine_rids = ([service_request_id] if n == 1 else
                             [f"{service_request_id}#{k}" for k in range(n)])
@@ -527,6 +535,18 @@ class _LiveRequest:
         stamps = self.stamps
         if stamps is not None:
             stamps[name] = time.monotonic() if t is None else t
+
+    def token_out(self, out: StepOutput, wake: float,
+                  written: float) -> bool:
+        """Book one output's tokens (handler's thread): each waited
+        ``wake - out.emit_t`` for this thread to run and ``written -
+        wake`` for its frame. True when a fold is due."""
+        n = len(out.new_token_ids)
+        if n and out.emit_t:
+            self.tok_wake_s += n * (wake - out.emit_t)
+            self.tok_write_s += n * (written - wake)
+            self.tok_n += n
+        return self.tok_n >= 64
 
     def choice_index(self, engine_rid: str) -> int:
         if len(self.choices) == 1:
@@ -1605,7 +1625,6 @@ class Worker:
             labelnames=("model",)).set_total(
             eng.phase_counts.get("ragged.dispatch", 0), model=m)
         self._flush_phase_ledger(rt)
-        self._flush_overlap(rt)
         self._flush_prefix_cache(rt)
 
     def _record_step(self, rt: ModelRuntime, lm: LoadMetrics,
@@ -1630,8 +1649,8 @@ class Worker:
         om = eng.overlap_metrics()
         sspec = self._st_spec_snap.get(m, {})
         spec = {k: int(om[k] - sspec.get(k, 0))
-                for k in ("spec_dispatches", "spec_hits",
-                          "spec_rollbacks")}
+                for k in ("ahead_dispatches", "ahead_hits",
+                          "ahead_discards")}
         self._st_spec_snap[m] = {k: int(om[k]) for k in spec}
         hit_cum = int(eng.prefix_cache_stats()["hit_tokens_total"])
         hit_delta = hit_cum - self._st_prefix_snap.get(m, 0)
@@ -1758,30 +1777,6 @@ class Worker:
             "xllm_worker_exit_cdf_count",
             "decode rows the exit gate was read for",
             labelnames=("model",)).set_total(st["rows"], model=m)
-
-    def _flush_overlap(self, rt: ModelRuntime) -> None:
-        """How the decode steps put on the device ahead of their
-        iteration fared (``Engine.overlap_metrics``): dispatched, taken
-        ("hit") and discarded ("rollback"), and the share taken
-        (docs/OBSERVABILITY.md)."""
-        eng = rt.engine
-        if eng is None:
-            return
-        om = eng.overlap_metrics()
-        m = rt.model
-        c = self.obs.counter(
-            "xllm_worker_decode_overlap_spec_total",
-            "decode steps dispatched ahead of their iteration (launched "
-            "ahead or at a tail), by outcome",
-            labelnames=("model", "result"))
-        c.set_total(om["spec_dispatches"], model=m, result="dispatch")
-        c.set_total(om["spec_hits"], model=m, result="hit")
-        c.set_total(om["spec_rollbacks"], model=m, result="rollback")
-        self.obs.gauge(
-            "xllm_worker_decode_overlap_hit_ratio",
-            "fraction of the decode steps dispatched ahead that were "
-            "taken as they were",
-            labelnames=("model",)).set(om["hit_ratio"], model=m)
 
     def _flush_prefix_cache(self, rt: ModelRuntime) -> None:
         """Prefix-reuse health (docs/KV_CACHE.md): lookup/hit-token
@@ -1935,6 +1930,9 @@ class Worker:
                     with self._live_lock:
                         self._live_srid.pop(live.service_request_id, None)
             else:
+                # this call's one clock read: where the token's way to
+                # the wire starts (xllm_worker_token_out_seconds_total)
+                out.emit_t = now
                 live.q.put(out)
                 if out.finished:
                     self._drop_live(out.request_id)
@@ -2540,6 +2538,30 @@ class Worker:
             self.spans.record(srid, name, plane="worker", t_mono=t,
                               t_wall=t + off)
 
+    def _fold_token_out(self, live: _LiveRequest) -> None:
+        """Move a request's emit-to-wire sums into the counters: on the
+        HANDLER's thread, every 64 tokens and at the request's end (sums
+        and a count; no histogram on the token path)."""
+        n, live.tok_n = live.tok_n, 0
+        if not n:
+            return
+        secs = self.obs.counter(
+            "xllm_worker_token_out_seconds_total",
+            "every streamed or collected token's time after emit, summed:"
+            " wake is from the emit that handed it out "
+            "(Worker._dispatch_outputs' one clock read) until the "
+            "handler's thread has it off the request's queue, write from "
+            "there until its frame is written (collected, without a "
+            "stream); over token_out_tokens_total, the mean a token",
+            labelnames=("model", "stage"))
+        secs.inc(live.tok_wake_s, model=live.model, stage="wake")
+        secs.inc(live.tok_write_s, model=live.model, stage="write")
+        live.tok_wake_s = live.tok_write_s = 0.0
+        self.obs.counter(
+            "xllm_worker_token_out_tokens_total",
+            "tokens timed from emit to the wire",
+            labelnames=("model",)).inc(n, model=live.model)
+
     def _serve_generate_inner(self, req: Request,
                               is_chat: bool) -> Response:
         t_recv = time.monotonic()
@@ -2671,21 +2693,28 @@ class Worker:
                 if out is None:
                     yield SSE_DONE
                     return
+                wake = time.monotonic()
                 done = wrote = False
-                for ro in self._process_step_output(live, out):
-                    for frame in asm.on_output(ro):
-                        yield frame
-                        wrote = True
-                    done = done or ro.finished
+                with steptrace.span("xllm.stream.token"):
+                    for ro in self._process_step_output(live, out):
+                        for frame in asm.on_output(ro):
+                            yield frame
+                            wrote = True
+                        done = done or ro.finished
+                # Control is back from the yield of the output's last
+                # frame: it is written.
+                written = time.monotonic()
                 if wrote and live.stamps is not None and out.new_token_ids:
-                    # Control is back from the yield of the first frame
-                    # that carries a token: the frame is written.
-                    live.stamp("first_frame")
+                    # (the first frame that carries a token)
+                    live.stamp("first_frame", written)
                     self._fold_first_token(live)
+                if live.token_out(out, wake, written):
+                    self._fold_token_out(live)
                 if done:
                     return
         finally:
             self._finalize_live(live)
+            self._fold_token_out(live)
 
     def _collect_full(self, live: _LiveRequest,
                       initial: Optional[List[RequestOutput]] = None
@@ -2716,6 +2745,7 @@ class Worker:
                         "engine_fault")
                 if out is None:
                     break
+                wake = time.monotonic()
                 if live.stamps is not None:
                     # No frame is written before the last token: the
                     # chain ends at ``first_token``.
@@ -2724,10 +2754,14 @@ class Worker:
                 for ro in self._process_step_output(live, out):
                     coll.add(ro)
                     done = done or ro.finished
+                # (no frame a token here: ``write`` is the collecting)
+                if live.token_out(out, wake, time.monotonic()):
+                    self._fold_token_out(live)
                 if done:
                     break
         finally:
             self._finalize_live(live)
+            self._fold_token_out(live)
         return Response.json(coll.body())
 
     # ------------------------------------------------------------------
@@ -2757,7 +2791,6 @@ class Worker:
             self._engine_load(rt)
             self._flush_phase_ledger(rt)
             self._flush_phase_cpu(rt)
-            self._flush_overlap(rt)
             self._flush_prefix_cache(rt)
         # Supervised-thread crash / swallowed-callback books
         # (utils/threads.py — process-global, root-labeled).

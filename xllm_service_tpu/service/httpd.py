@@ -24,6 +24,7 @@ from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
                     Tuple)
 from urllib.parse import parse_qs, urlparse
 
+from xllm_service_tpu.obs import profiler
 from xllm_service_tpu.utils.locks import make_lock
 from xllm_service_tpu.utils.threads import spawn
 
@@ -208,6 +209,20 @@ class _RequestHandler(BaseHTTPRequestHandler):
 
     def log_message(self, fmt: str, *args: Any) -> None:  # quiet
         pass
+
+    # ThreadingHTTPServer runs setup/handle/finish once a CONNECTION, on
+    # the connection's own bare thread: the place to give it a root, so
+    # that a scrape can say what the handlers cost beside the engine's
+    # loop (obs/profiler.py), and to book its seconds before it exits.
+    def setup(self) -> None:
+        profiler.register_thread_root(profiler.HANDLER_ROOT)
+        super().setup()
+
+    def finish(self) -> None:
+        try:
+            super().finish()
+        finally:
+            profiler.retire_thread_root()
 
     def _handle(self) -> None:
         parsed = urlparse(self.path)

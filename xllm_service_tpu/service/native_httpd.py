@@ -25,6 +25,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
 from urllib.parse import parse_qs
 
+from xllm_service_tpu.obs import profiler
 from xllm_service_tpu.utils.locks import make_lock
 from xllm_service_tpu.utils import threads
 from xllm_service_tpu.utils.native_build import build_artifact
@@ -194,9 +195,15 @@ class NativeHttpServer:
         self._pool_cap = max((limit or 0) + 32, 64)
         self._pool_busy = 0
         self._pool_lock = threading.Lock()
+        # A pool thread is a bare thread: it takes the handlers' root
+        # once, when it starts (obs/profiler.py, as the Python server's
+        # thread a connection does).
         self._pool = ThreadPoolExecutor(
             max_workers=self._pool_cap,
-            thread_name_prefix=f"httpd-native-{self.port}")
+            thread_name_prefix=f"httpd-native-{self.port}",
+            initializer=profiler.register_thread_root,
+            initargs=(profiler.HANDLER_ROOT,))
+        self._dispatch_rooted = False
 
     @staticmethod
     def _render_shed_response() -> bytes:
@@ -266,6 +273,11 @@ class NativeHttpServer:
 
     def _on_request(self, _user, rid, method, path, query, headers_ptr,
                     headers_len, body_ptr, body_len) -> None:
+        if not self._dispatch_rooted:
+            # The library's ONE dispatch thread, which runs this much
+            # Python a request under the interpreter's lock.
+            self._dispatch_rooted = True
+            profiler.register_thread_root("httpd.dispatch")
         try:
             method_s = method.decode("latin-1")
             path_s = path.decode("latin-1")
@@ -293,7 +305,7 @@ class NativeHttpServer:
                     # polls, or a live limit raise): fall back to the
                     # old per-request Thread so nothing queues behind a
                     # 30 s watcher or an SSE stream.
-                    spawn("native_httpd.overflow", self._run,
+                    spawn("native_httpd.overflow", self._run_overflow,
                           args=(rid, req, counted),
                           thread_name=(f"httpd-native-{self.port}-ovf")
                           ).start()
@@ -326,6 +338,12 @@ class NativeHttpServer:
             traceback.print_exc()
             self._respond(rid, 500, {"Content-Type": "application/json"},
                           b'{"error":{"message":"dispatch error"}}')
+
+    def _run_overflow(self, rid: int, req, counted: bool) -> None:
+        # A thread a request: a handler's like the pool's (the
+        # supervised wrapper books its seconds when it exits).
+        profiler.register_thread_root(profiler.HANDLER_ROOT)
+        self._run(rid, req, counted)
 
     def _run_pooled(self, rid: int, req, counted: bool) -> None:
         try:

@@ -168,6 +168,7 @@ class SupervisedThread(threading.Thread):
                     and self._stop_event.is_set())
 
     def run(self) -> None:        # noqa: D102 — Thread contract
+        profiler = None
         try:
             # Lazy import: threads.py sits below obs in the import
             # graph (obs.metrics imports utils.locks). The profiler
@@ -177,6 +178,18 @@ class SupervisedThread(threading.Thread):
         except Exception:  # noqa: BLE001 — best-effort CPU attribution;
             pass           # a root must start even if the profiler can't
                            # bind its tid (partial deploy, exotic libc)
+        try:
+            self._supervise()
+        finally:
+            if profiler is not None:
+                try:
+                    # what it ran since the last scrape, before the tid
+                    # goes back to the kernel
+                    profiler.retire_thread_root()
+                except Exception:  # noqa: BLE001 — as above
+                    pass
+
+    def _supervise(self) -> None:
         attempt = 0
         while True:
             started = time.monotonic()
