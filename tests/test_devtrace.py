@@ -290,10 +290,14 @@ def test_endpoint_start_stop_and_its_refusals(worker, tmp_path):
         <= s["start"] + s["dur"] for s in steps) for e in inner)
 
 
-def test_a_streamed_token_is_a_span_on_its_handlers_line(worker, tmp_path):
-    """``xllm.stream.token`` (an output off the request's queue -> its
-    frames written) is a HANDLER's span: in a real CPU trace it lies on
-    a line that holds no span of the engine loop, its name is none the
+def test_a_streamed_token_is_a_span_on_the_line_of_the_thread_that_writes(
+        worker, tmp_path):
+    """``xllm.stream.token`` (an output in hand -> its frames written)
+    is a span of the thread that WRITES the stream: the worker's one
+    stream writer under the native front door (all of a trace's on ONE
+    line, which admits nothing), a handler's own under the Python
+    server (a line that also admits). In a real CPU trace it lies on a
+    line that holds no span of the engine loop, its name is none the
     benchmark nests under the engine's thread, and no stream writes one
     before start or after stop."""
     from jax.profiler import ProfileData
@@ -323,10 +327,14 @@ def test_a_streamed_token_is_a_span_on_its_handlers_line(worker, tmp_path):
     lines = [{e.name for e in ln.events if e.name.startswith("xllm.")}
              for plane in ProfileData.from_file(
                  trace.find_xplane(d)).planes for ln in plane.lines]
-    handlers = [names for names in lines if name in names]
-    assert handlers
-    for names in handlers:              # a handler admits and streams
+    writers = [names for names in lines if name in names]
+    assert writers
+    for names in writers:
         assert not any(spans.ENGINE_THREAD.match(n) for n in names), names
+    if worker._srv.chunks_block:        # a handler admits and streams
+        assert all("xllm.admit" in names for names in writers)
+    else:                               # the one writer only writes
+        assert len(writers) == 1 and "xllm.admit" not in writers[0]
     assert any("xllm.loop.emit" in names and name not in names
                for names in lines)
     events = trace.load_events(trace.find_xplane(d))

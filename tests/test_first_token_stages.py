@@ -1,8 +1,10 @@
 """The time to the first token, accounted for inside the program: the
 closed table of stamps and stages (``obs/spans.py``
 ``FIRST_TOKEN_STAMPS``), the histogram
-``xllm_worker_first_token_stage_ms`` the handler's thread folds a
-request's chain into once its first frame is written, the same chain at
+``xllm_worker_first_token_stage_ms`` the thread that writes a request's
+first frame folds its chain into once that frame is written (the
+worker's stream writer under the native front door, the handler's own
+thread on the pull path), the same chain at
 ``GET /admin/trace/<id>``, the master's share on the forward, and which
 serving path observes which stage (docs/OBSERVABILITY.md has the
 table)."""
@@ -238,7 +240,8 @@ def store():
 
 @pytest.fixture(scope="module")
 def worker():
-    """A worker with no master in front: direct callers."""
+    """A worker with no master in front: direct callers. Under the native
+    front door (wherever it builds) its streams are the stream writer's."""
     from xllm_service_tpu.runtime.worker import Worker, WorkerOptions
     from xllm_service_tpu.service.coordination import InMemoryStore
     w = Worker(WorkerOptions(model="tiny"), InMemoryStore()).start()
@@ -248,6 +251,58 @@ def worker():
         w.stop()
 
 
+@pytest.fixture(scope="module")
+def pulled_worker():
+    """The same behind the Python server: a blocking ``wfile``, so each
+    stream is pulled by its connection's own thread (``_stream_sse``)."""
+    from xllm_service_tpu.runtime import worker as worker_mod
+    from xllm_service_tpu.service.coordination import InMemoryStore
+    from xllm_service_tpu.service.httpd import PyHttpServer
+    real, worker_mod.HttpServer = worker_mod.HttpServer, PyHttpServer
+    try:
+        w = worker_mod.Worker(worker_mod.WorkerOptions(model="tiny"),
+                              InMemoryStore()).start()
+    finally:
+        worker_mod.HttpServer = real
+    try:
+        yield w
+    finally:
+        w.stop()
+
+
+def _stream_path(w):
+    """Who writes this worker's streams."""
+    return "handler" if w._srv.chunks_block else "writer"
+
+
+@pytest.mark.parametrize("which", ["worker", "pulled_worker"],
+                         ids=["the_writer", "_stream_sse"])
+def test_the_stages_sum_to_the_first_tokens_time_whoever_writes(
+        request, which):
+    """``first_frame`` is stamped, and the chain folded, on the thread
+    that writes the frame: on either path every stage is observed once
+    and the seven between ``received`` and ``first_frame`` sum to
+    ``total``."""
+    w = request.getfixturevalue(which)
+    _stream(w.name, "", service_request_id=f"sum-{which}-0")    # compiles
+    before = _stage_books(w)
+    _stream(w.name, "", service_request_id=f"sum-{which}-1")
+    assert wait_until(lambda: "total" in _delta(_stage_books(w), before))
+    got = _delta(_stage_books(w), before)
+    assert sorted(got) == sorted(WORKER_OWN + ["total"])
+    assert all(c == 1 and ms >= 0 for c, ms in got.values()), got
+    assert sum(got[st][1] for st in WORKER_OWN) \
+        == pytest.approx(got["total"][1], abs=1e-6)
+    stages = [e["stage"] for e in
+              w.spans.get(f"sum-{which}-1")["events"]]
+    assert stages.index("first_token") < stages.index("first_frame")
+    _, text = _get(w.name, "/metrics")
+    paths = set(re.findall(
+        r'xllm_worker_stream_outputs_total\{model="tiny",path="(\w+)"\}',
+        text))
+    assert paths == {_stream_path(w)}
+
+
 def test_a_streamed_request_through_the_master(store):
     master, workers = make_cluster(store)
     w = workers[0]
@@ -255,7 +310,7 @@ def test_a_streamed_request_through_the_master(store):
         _stream(master.http_address, "warm-0")      # compiles here
         before = _stage_books(w)
         _stream(master.http_address, "chain-1")
-        # the fold follows the first frame's write, on the handler's thread
+        # the fold follows the first frame's write, on the thread that writes
         assert wait_until(lambda: "total" in _delta(_stage_books(w), before))
         got = _delta(_stage_books(w), before)
         assert sorted(got) == sorted(FIRST_TOKEN_STAGES)
@@ -300,7 +355,7 @@ def test_a_streamed_request_through_the_master(store):
 
 
 def test_a_folded_request_allocates_and_observes_nothing(worker):
-    """Once the handler's thread has folded the chain the request holds
+    """Once the thread that writes has folded the chain the request holds
     no dict, and whatever reaches a stamp or the fold again (an output
     past the first) is one branch: nothing retained, nothing observed."""
     seen, real = [], worker._fold_first_token
@@ -404,12 +459,15 @@ def _token_out(worker):
             one(fam % "wake"), one(fam % "write"))
 
 
-@pytest.mark.parametrize("stream", [True, False],
-                         ids=["_stream_sse", "_collect_full"])
-def test_every_token_is_timed_from_emit_to_the_wire(worker, stream):
+@pytest.mark.parametrize("which,stream", [
+    ("worker", True), ("pulled_worker", True), ("worker", False)],
+    ids=["the_writer", "_stream_sse", "_collect_full"])
+def test_every_token_is_timed_from_emit_to_the_wire(request, which, stream):
     """Three requests of N tokens leave 3N in the count and both stages
-    ahead, on either path a handler's thread takes a token; the engine's
-    thread gave each output the ONE clock read its emit makes."""
+    ahead, on every path a token takes out (the stream writer's thread,
+    a handler's own pulling a stream, a handler's collecting); the
+    engine's thread gave each output the ONE clock read its emit makes."""
+    worker = request.getfixturevalue(which)
     n = 70                              # past the fold at 64 tokens
     seen, real = [], worker._dispatch_outputs
     worker._dispatch_outputs = lambda rt, outs, ms: (

@@ -5,7 +5,9 @@ cannot regress even if the rule (or its allowlist) drifts:
 
 1. the worker's fan-out queue waits are bounded by
    ``request_timeout_s`` and surface a TYPED 504 — never a silent
-   stall — on engine silence (stream AND collect paths);
+   stall — on engine silence (the pulled stream, the collect path, AND
+   a stream the worker's writer owns, where the bounded wait is the
+   parked handler's on the stream's event);
 2. the etcd watch stream socket carries the config-time
    ``XLLM_ETCD_WATCH_TIMEOUT_S`` bound, and both watch planes pace
    reconnects through ``utils/retry.RetryPolicy`` (capped, jittered,
@@ -62,6 +64,43 @@ class TestBoundedEngineWait:
         assert payload["error"]["code"] == 504
         # The finalizer ran: unfinished engine work gets cancelled.
         assert w.finalized == [live]
+
+    def test_writer_owned_stream_engine_silence_yields_typed_504(self):
+        """Where the stream writer owns the stream, the thread that
+        WRITES the typed frame is the writer's; the handler's thread
+        only parks, with a timed wait, and posts ``_TIMEOUT``."""
+        import functools
+        from xllm_service_tpu.runtime import worker as wm
+        w, live = _fake_worker(0.05), _fake_live()
+        w._live_lock = threading.Lock()
+        w._writer_closed = False
+        w._writer_q = queue.SimpleQueue()
+        w._writer_owned, w._writer_batch = set(), ()
+        for name in ("_writer_run", "_writer_take", "_writer_end"):
+            setattr(w, name,
+                    functools.partial(getattr(wm.Worker, name), w))
+        st = live.push = wm._Stream(w, live, "writer")
+        wrote = []
+
+        def writer():
+            for batch in iter(w._writer_q.get, None):
+                w._writer_run(batch)
+        t = threading.Thread(target=writer, daemon=True)
+        t.start()
+
+        def sink(chunk):
+            wrote.append((chunk, threading.current_thread()))
+            return 0
+        t0 = time.monotonic()
+        clean = wm.Worker._serve_pushed(w, st, sink)
+        assert 0.05 <= time.monotonic() - t0 < 5.0, "park not bounded"
+        w._writer_q.put(None)
+        t.join(timeout=5)
+        assert clean and st.done and st.over.is_set()
+        assert len(wrote) == 1 and wrote[0][1] is t
+        payload = json.loads(wrote[0][0].decode()[len("data: "):])
+        assert payload["error"]["type"] == "timeout"
+        assert payload["error"]["code"] == 504
 
     def test_collect_engine_silence_returns_typed_504(self):
         from xllm_service_tpu.runtime.worker import Worker

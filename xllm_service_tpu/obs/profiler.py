@@ -22,8 +22,12 @@ instead of a guess:
 - **Per-thread-root CPU and run-queue wait**: every thread of the
   process has a root. Supervised threads register their native tid
   under their root name (utils/threads.py calls
-  ``register_thread_root``), an HTTP handler's thread registers as
-  ``httpd.handler`` once a connection (service/httpd.py), the main
+  ``register_thread_root``: a worker's ``worker.engine_loop``, its
+  ``worker.stream_writer``, which writes every stream the native front
+  door serves, ``worker.hb_loop``, ``worker.encode_loop``), an HTTP
+  handler's thread registers as ``httpd.handler`` once a connection
+  (service/httpd.py; under the native front door it admits a streamed
+  request and then parks until the writer ends the stream), the main
   thread is ``main``, and whatever no root claims (the runtime's native
   threads) is ``unregistered``. Scrape-time reads of
   ``/proc/self/task/<tid>/schedstat`` (nanoseconds on a core, and
@@ -583,8 +587,9 @@ def flush_metrics(registry) -> None:
     cpu_c = registry.counter(
         "xllm_thread_cpu_seconds_total",
         "cumulative CPU seconds per thread root: every thread of the "
-        "process has one (supervised roots, httpd.handler, main, and "
-        "unregistered for the runtime's native threads)",
+        "process has one (supervised roots, worker.stream_writer "
+        "among them, httpd.handler, main, and unregistered for the "
+        "runtime's native threads)",
         labelnames=("root",))
     runq_c = registry.counter(
         "xllm_thread_runq_wait_seconds_total",

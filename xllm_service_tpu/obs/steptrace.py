@@ -133,8 +133,10 @@ SPAN_NAMES: Tuple[str, ...] = (
     "xllm.admit",            # handler thread: parsed request -> enqueued
     "xllm.admit.lock_wait",  # ... waiting for _engine_lock
     "xllm.admit.locked",     # ... holding it (Engine.add_request)
-    "xllm.stream.token",     # handler thread: an output taken off the
-                             # request's queue -> its frames written
+    "xllm.stream.token",     # the thread that writes a stream (the
+                             # worker's ONE stream writer; the handler's
+                             # own on the pull path): an output in hand
+                             # -> its frames written
 ) + tuple("xllm.step." + p for p in STEP_PHASES) + tuple(
     f"xllm.step.{p}.{half}" for p in READ_HOST_PHASES
     for half in ("device_wait", "host_copy"))

@@ -109,6 +109,73 @@ class _EngineFault:
         self.verdict = verdict
 
 
+# Posted to the stream writer in an output's place, by a stream's own
+# handler: the stream has begun and its sink is attached; no engine
+# output came for ``request_timeout_s``.
+_ATTACH = object()
+_TIMEOUT = object()
+
+
+def _timeout_frame(limit_s: float) -> bytes:
+    """The engine stopped producing (hang, wedged step): a TYPED frame,
+    never a silent stall."""
+    return sse_frame({"error": {
+        "message": f"no engine output within {limit_s:g}s",
+        "type": "timeout", "code": 504}})
+
+
+def _engine_fault_frame(verdict: str) -> bytes:
+    """Blamed by the step fault boundary: a TYPED error frame (not a
+    broken socket), so that the relay can strike the poison ledger and
+    reroute or fail clean (docs/ROBUSTNESS.md)."""
+    return sse_frame({"error": {
+        "message": f"engine_fault: {verdict}",
+        "type": "engine_fault", "code": 500}})
+
+
+class _Stream:
+    """One streamed response's way out, whoever writes it: the frames'
+    assembler, and ``done`` once nothing more is to be written
+    (``Worker._stream_output`` is what both paths run an output
+    through; ``last_t`` is its last clock read). ``path`` says who
+    writes: ``"handler"``, the connection's own thread pulling from
+    ``live.q`` (``Worker._stream_sse``), or ``"writer"``, the worker's
+    ONE stream writer (``Worker._stream_writer_loop``), which then owns
+    the stream as ``live.push`` and keeps the rest here: the sink its
+    handler attached (``write``), what the engine emitted before that
+    (``held``), the frames that lead (``initial``), and ``over``, the
+    event the handler's thread parks on until the writer ends the
+    stream, ``clean`` or with the socket broken. But for ``write`` and
+    ``initial``, set before the handler posts ``_ATTACH``, only the
+    writer's thread writes these."""
+
+    __slots__ = ("worker", "live", "asm", "path", "done", "last_t",
+                 "initial", "write", "held", "attached", "clean", "over")
+
+    def __init__(self, worker: "Worker", live: "_LiveRequest",
+                 path: str) -> None:
+        self.worker = worker
+        self.live = live
+        self.asm = (ChatStreamAssembler if live.is_chat
+                    else CompletionStreamAssembler)(
+            live.service_request_id, live.model, live.include_usage,
+            emit_token_ids=live.emit_token_ids)
+        self.path = live.out_path = path
+        self.done = False
+        self.last_t = 0.0
+        self.initial: Any = ()
+        self.write: Any = None
+        self.held: List[Any] = []
+        self.attached = False
+        self.clean = True
+        self.over = threading.Event()
+
+    def serve(self, write) -> bool:
+        """``Response.push``: on the handler's thread, once the headers
+        are queued."""
+        return self.worker._serve_pushed(self, write)
+
+
 def _classify_step_fault(exc: BaseException) -> str:
     """Transient device faults (a flaky transport, a device timeout)
     are retried in place with no one blamed; anything else is treated
@@ -464,7 +531,8 @@ class _LiveRequest:
                  "is_chat", "stream", "include_usage", "first_out_time",
                  "sampling", "prompt_tokens", "target_n", "prompt_lps",
                  "_echo_cache", "emit_token_ids", "stamps", "front_ms",
-                 "tok_wake_s", "tok_write_s", "tok_n")
+                 "tok_wake_s", "tok_write_s", "tok_n", "out_n", "out_path",
+                 "push")
 
     def __init__(self, req: EngineRequest, tokenizer: Tokenizer,
                  service_request_id: str, model: str, is_chat: bool,
@@ -489,13 +557,20 @@ class _LiveRequest:
         # share, as the forward's header gave it.
         self.stamps: Optional[Dict[str, float]] = {}
         self.front_ms: Optional[float] = None
-        # Every token from ``emit`` to the wire, summed by the handler's
-        # thread (``token_out``) until ``Worker._fold_token_out`` moves
-        # them into the counters: seconds from the emit to the handler's
-        # wake, seconds from the wake to the frame written, tokens.
+        # Every token from ``emit`` to the wire, summed by the thread
+        # that writes it (``token_out``) until ``Worker._fold_token_out``
+        # moves them into the counters: seconds from the emit until that
+        # thread has the output in hand, seconds from there to the frame
+        # written, tokens; and the outputs ``Worker._stream_output`` ran,
+        # under the path that ran them.
         self.tok_wake_s = 0.0
         self.tok_write_s = 0.0
         self.tok_n = 0
+        self.out_n = 0
+        self.out_path = ""
+        # The stream's state where the worker's writer owns it
+        # (``Worker._writer_adopt``); None: outputs go to ``q``.
+        self.push: Optional[_Stream] = None
         n = max(1, n)
         self.engine_rids = ([service_request_id] if n == 1 else
                             [f"{service_request_id}#{k}" for k in range(n)])
@@ -538,9 +613,9 @@ class _LiveRequest:
 
     def token_out(self, out: StepOutput, wake: float,
                   written: float) -> bool:
-        """Book one output's tokens (handler's thread): each waited
-        ``wake - out.emit_t`` for this thread to run and ``written -
-        wake`` for its frame. True when a fold is due."""
+        """Book one output's tokens (on the thread that writes them):
+        each waited ``wake - out.emit_t`` for this thread to turn to it
+        and ``written - wake`` for its frame. True when a fold is due."""
         n = len(out.new_token_ids)
         if n and out.emit_t:
             self.tok_wake_s += n * (wake - out.emit_t)
@@ -923,6 +998,24 @@ class Worker:
                 "/fork_master", "/kv/import", "/kv/chunk", "/kv/blocks",
                 "/kv/blocks_done", "/encode", "/encode_done"))
         self.name = self._srv.address
+        # The worker's ONE stream writer (``_stream_writer_loop``): an
+        # iteration's outputs for the streams it owns reach it as one
+        # item of this queue (a sequence of ``(live, output)`` pairs, in
+        # emit order), a stream's ends and its handler's posts as items
+        # of one pair, ``None`` at stop. It owns a streamed response
+        # served by a server whose chunk call cannot block
+        # (``_writer_adopt``); ``_writer_owned`` holds the streams it
+        # has seen and not yet ended, ``_writer_batch`` the item in its
+        # hands (both its thread's alone). ``_writer_closed``: it takes
+        # no more streams (set at stop, once).
+        self._writer_q: "queue.SimpleQueue[Any]" = queue.SimpleQueue()
+        self._writer_owned: set = set()
+        self._writer_batch: Any = ()
+        self._writer_closed = False     # guarded-by: worker.live
+        # Iterations handed to the writer and the outputs in them (the
+        # engine loop's thread counts; a scrape mirrors them).
+        self._writer_batches = 0
+        self._writer_batch_outs = 0
 
         # Supervised roots (utils/threads.py): an uncaught exception
         # logs + counts (xllm_thread_crashes_total) + emits
@@ -957,6 +1050,13 @@ class Worker:
         self._encode_thread = spawn(
             "worker.encode_loop", self._encode_loop,
             thread_name=f"worker-encode-{self.name}",
+            restart=threads.RESTART_POLICY,
+            events=self.events, stop=self._stop)
+        # The stream writer RESTARTS: a crash breaks the streams it
+        # owned (none is left hanging) and the next ones need a writer.
+        self._writer_thread = spawn(
+            "worker.stream_writer", self._stream_writer_loop,
+            thread_name=f"worker-writer-{self.name}",
             restart=threads.RESTART_POLICY,
             events=self.events, stop=self._stop)
         # Registration plane: one lock serializes every revoke→grant→put
@@ -1058,6 +1158,7 @@ class Worker:
             self._addr_watch = self.store.add_watch(
                 KEY_MASTER_ADDR, self._on_master_addr)
         self._loop_thread.start()
+        self._writer_thread.start()
         self._hb_thread.start()
         self._encode_thread.start()
         return self
@@ -1176,7 +1277,8 @@ class Worker:
             except Exception:  # noqa: BLE001 — shutdown cleanup is
                 pass            # best-effort; the store may be gone
             self._addr_watch = None
-        # Release consumer threads blocked on live.q.get(): the engine
+        # Release consumer threads blocked on live.q.get(), and the
+        # handlers parked on a stream the writer owns: the engine
         # loop is about to exit, so no further outputs (or cancel
         # effects) will ever arrive — without the sentinel a client of
         # an abandoned request hangs until process exit instead of
@@ -1191,10 +1293,17 @@ class Worker:
                 lives = list(self._live_srid.values())
                 inflight = self._inflight_parse
             for live in lives:
-                live.q.put(None)
+                self._post_end(live, None)
             if inflight == 0 or time.monotonic() > release_deadline:
                 break
             time.sleep(0.02)
+        # The writer ends what it still owns and exits; a handler that
+        # attaches after this ends its stream itself (_serve_pushed).
+        # Joined BEFORE the server goes down, so that the sentinels'
+        # frames are with the server's event loop by then.
+        self._writer_q.put(None)
+        if self._writer_thread.ident is not None:
+            self._writer_thread.join(timeout=5)
         self._srv.stop()
         if self._lease_id is not None:
             try:
@@ -1484,7 +1593,7 @@ class Worker:
                                   f"engine_fault: {verdict}"),
                     finished=True))
             else:
-                live.q.put(_EngineFault(verdict))
+                self._post_end(live, _EngineFault(verdict))
             # Cancels sibling choices still in the engine and clears
             # the live maps; the blamed rid itself is already evicted
             # (a cancel on it is benign).
@@ -1878,10 +1987,18 @@ class Worker:
         with self._engine_lock:
             to_service: List[RequestOutput] = self._service_push_buffer
             self._service_push_buffer = []
+        # What the stream writer gets of this iteration, as ONE item of
+        # its queue: the outputs that carry a request's first token, then
+        # the others, each list in emit order (a request's own stay in
+        # order: its first output is the first of its outputs here).
+        firsts: List[Tuple[_LiveRequest, StepOutput]] = []
+        rest: List[Tuple[_LiveRequest, StepOutput]] = []
         for out in outs:
             if not self._dead and self.failpoints.fire(
                     "worker.die_after_n_tokens",
                     n=len(out.new_token_ids)) is not None:
+                self._hand_to_writer(firsts, rest)   # ahead of the _ABORTs
+                firsts, rest = [], []
                 self._die()
             if self._dead:
                 # Simulated death: outputs past the trip point — and
@@ -1892,7 +2009,9 @@ class Worker:
                 live = self._live.get(out.request_id)
             if live is None:
                 continue
+            batch = rest
             if live.first_out_time == 0.0:
+                batch = firsts
                 # Its own clock read: ``emit`` up to THIS request is the
                 # end of its ``post_emit`` stage.
                 t_first = live.first_out_time = time.monotonic()
@@ -1933,11 +2052,33 @@ class Worker:
                 # this call's one clock read: where the token's way to
                 # the wire starts (xllm_worker_token_out_seconds_total)
                 out.emit_t = now
-                live.q.put(out)
+                if live.push is not None:
+                    batch.append((live, out))
+                else:
+                    live.q.put(out)
                 if out.finished:
                     self._drop_live(out.request_id)
+        self._hand_to_writer(firsts, rest)
         if to_service and self.service_addr:
             self._push_outputs_to_service(to_service)
+
+    def _hand_to_writer(self, firsts: List[Tuple[Any, Any]],
+                        rest: List[Tuple[Any, Any]]) -> None:
+        """One ``put`` an iteration: it wakes one thread, whatever the
+        number of streams (engine loop's thread)."""
+        if firsts or rest:
+            self._writer_batches += 1
+            self._writer_batch_outs += len(firsts) + len(rest)
+            self._writer_q.put(firsts + rest)
+
+    def _post_end(self, live: _LiveRequest, end: Any) -> None:
+        """Post one of a request's ends (None, ``_ABORT``, an
+        ``_EngineFault``) to whoever consumes its outputs, behind every
+        output posted before it."""
+        if live.push is not None:
+            self._writer_q.put(((live, end),))
+        else:
+            live.q.put(end)
 
     def _drop_live(self, request_id: str) -> None:
         with self._live_lock:
@@ -2130,7 +2271,7 @@ class Worker:
                 with self._engine_lock:
                     for erid in live.engine_rids:
                         rt.engine.cancel(erid)
-            live.q.put(_ABORT)
+            self._post_end(live, _ABORT)
         self._work_event.set()
 
     def _serve_failpoint(self, req: Request) -> Response:
@@ -2388,6 +2529,7 @@ class Worker:
         live.emit_token_ids = bool(body.get("ledger_tokens"))
         if not pd_prefill:
             live.target_n = max(1, sampling.n)
+            self._writer_adopt(live)
         with self._live_lock:
             self._live_srid[srid] = live
             for erid in live.engine_rids:
@@ -2509,8 +2651,9 @@ class Worker:
         """Close a request's first-token chain: one observation a stage
         into ``xllm_worker_first_token_stage_ms`` and one span event a new
         stamp, so that ``/admin/trace/<id>`` shows for one request what
-        the histogram shows for all. On the HANDLER's thread, once the
-        first frame is written (or where the path's last stamp is taken:
+        the histogram shows for all. On the thread that WRITES the first
+        frame, once it is written (the stream writer's, or the handler's
+        own on the pull path; or where the path's last stamp is taken:
         docs/OBSERVABILITY.md has the table); the engine thread only
         stamps. A path that lacks a stamp observes the stages it has."""
         stamps, live.stamps = live.stamps, None
@@ -2540,19 +2683,33 @@ class Worker:
 
     def _fold_token_out(self, live: _LiveRequest) -> None:
         """Move a request's emit-to-wire sums into the counters: on the
-        HANDLER's thread, every 64 tokens and at the request's end (sums
-        and a count; no histogram on the token path)."""
+        thread that writes its tokens, every 64 tokens and at the
+        request's end (sums and a count; no histogram and no counter
+        write on the token path)."""
         n, live.tok_n = live.tok_n, 0
+        outs, live.out_n = live.out_n, 0
+        if outs:
+            self.obs.counter(
+                "xllm_worker_stream_outputs_total",
+                "engine outputs run through a streamed response's way "
+                "out (Worker._stream_output) by who ran them: writer is "
+                "the worker's one stream writer, handler the "
+                "connection's own thread (a server whose chunk call can "
+                "block)",
+                labelnames=("model", "path")).inc(
+                    outs, model=live.model, path=live.out_path)
         if not n:
             return
         secs = self.obs.counter(
             "xllm_worker_token_out_seconds_total",
             "every streamed or collected token's time after emit, summed:"
             " wake is from the emit that handed it out "
-            "(Worker._dispatch_outputs' one clock read) until the "
-            "handler's thread has it off the request's queue, write from "
-            "there until its frame is written (collected, without a "
-            "stream); over token_out_tokens_total, the mean a token",
+            "(Worker._dispatch_outputs' one clock read) until the thread "
+            "that writes it has it in hand (the stream writer turns to "
+            "it, or the handler's thread has it off the request's "
+            "queue), write from there until its frame is written "
+            "(collected, without a stream); over token_out_tokens_total, "
+            "the mean a token",
             labelnames=("model", "stage"))
         secs.inc(live.tok_wake_s, model=live.model, stage="wake")
         secs.inc(live.tok_write_s, model=live.model, stage="write")
@@ -2646,75 +2803,241 @@ class Worker:
                                   "service_request_id":
                                       live.service_request_id})
         if live.stream:
-            return self._stream_response(
-                self._stream_sse(live),
-                lambda: self._finalize_live(live))
+            return self._sse_response(live)
         return self._collect_full(live)
+
+    # ------------------------------------------------------------------
+    # A streamed response's way out. Who runs it is decided by what the
+    # code can see: where the response streams to its caller (not to the
+    # master's fan-in) and the server's chunk call cannot block, the
+    # worker's ONE writer does, woken once an iteration; else the
+    # connection's own thread pulls from ``live.q``. The two paths share
+    # ``_stream_output`` and nothing else.
+    # ------------------------------------------------------------------
+    def _writer_adopt(self, live: _LiveRequest) -> None:
+        """Give ``live``'s stream to the writer where it may own it,
+        BEFORE the engine can emit for it: ``_dispatch_outputs`` routes
+        by ``live.push``."""
+        if live.stream and not live.stream_to_service \
+                and not self._srv.chunks_block:
+            live.push = _Stream(self, live, "writer")
+
+    def _sse_response(self, live: _LiveRequest,
+                      initial: Optional[List[RequestOutput]] = None
+                      ) -> Response:
+        def finalize() -> None:
+            self._finalize_live(live)
+        st = live.push
+        if st is None:
+            return self._stream_response(
+                self._stream_sse(live, initial), finalize)
+        st.initial = initial or ()
+
+        def release() -> None:
+            # The server is done with a response it never served (a
+            # failed header write): the writer forgets the stream.
+            if not st.over.is_set():
+                self._post_end(live, _ABORT)
+        resp = self._stream_response(None, release, finalize)
+        resp.push = st
+        return resp
+
+    def _stream_output(self, st: _Stream, out: StepOutput,
+                       wake: float) -> Iterator[bytes]:
+        """One engine output's way out of a streamed response, on the
+        thread that writes it, which had it in hand at ``wake``: its
+        frames, for the caller to write (the writer) or to yield on (the
+        pull path), under the span ``xllm.stream.token``; once control
+        is back from the last of them it is written, and the request's
+        first-token chain and its emit-to-wire sums are booked."""
+        live = st.live
+        wrote = False
+        with steptrace.span("xllm.stream.token"):
+            for ro in self._process_step_output(live, out):
+                for frame in st.asm.on_output(ro):
+                    yield frame
+                    wrote = True
+                st.done = st.done or ro.finished
+        st.last_t = written = time.monotonic()
+        if wrote and live.stamps is not None and out.new_token_ids:
+            # (the first frame that carries a token)
+            live.stamp("first_frame", written)
+            self._fold_first_token(live)
+        live.out_n += 1
+        if live.token_out(out, wake, written):
+            self._fold_token_out(live)
 
     def _stream_sse(self, live: _LiveRequest,
                     initial: Optional[List[RequestOutput]] = None
                     ) -> Iterator[bytes]:
-        asm = (ChatStreamAssembler if live.is_chat
-               else CompletionStreamAssembler)(
-            live.service_request_id, live.model, live.include_usage,
-            emit_token_ids=live.emit_token_ids)
+        """The pull path: the connection's own thread takes the
+        request's outputs off ``live.q``, one wake a token."""
+        st = _Stream(self, live, "handler")
         try:
             # The initial frames sit INSIDE the try: a client disconnect
             # while they stream must still run the finalizer.
             for ro in (initial or []):
-                for frame in asm.on_output(ro):
+                for frame in st.asm.on_output(ro):
                     yield frame
             while True:
                 try:
                     out = live.q.get(
                         timeout=self.opts.request_timeout_s)
                 except queue.Empty:
-                    # Engine stopped producing (hang, wedged step):
-                    # a TYPED timeout frame, never a silent stall —
-                    # the finally cancels the unfinished engine work.
-                    yield sse_frame({"error": {
-                        "message": f"no engine output within "
-                                   f"{self.opts.request_timeout_s:g}s",
-                        "type": "timeout", "code": 504}})
+                    # (the finally cancels the unfinished engine work)
+                    yield _timeout_frame(self.opts.request_timeout_s)
                     return
                 if out is _ABORT:
                     # Simulated death: break the socket mid-stream (no
                     # [DONE]) so the relay sees what a crash looks like.
                     raise RuntimeError("worker died (failpoint)")
                 if isinstance(out, _EngineFault):
-                    # Blamed by the step fault boundary: a TYPED error
-                    # frame (not a broken socket) so the relay can
-                    # strike the poison ledger and reroute or fail
-                    # clean (docs/ROBUSTNESS.md).
-                    yield sse_frame({"error": {
-                        "message": f"engine_fault: {out.verdict}",
-                        "type": "engine_fault", "code": 500}})
+                    yield _engine_fault_frame(out.verdict)
                     return
                 if out is None:
                     yield SSE_DONE
                     return
-                wake = time.monotonic()
-                done = wrote = False
-                with steptrace.span("xllm.stream.token"):
-                    for ro in self._process_step_output(live, out):
-                        for frame in asm.on_output(ro):
-                            yield frame
-                            wrote = True
-                        done = done or ro.finished
-                # Control is back from the yield of the output's last
-                # frame: it is written.
-                written = time.monotonic()
-                if wrote and live.stamps is not None and out.new_token_ids:
-                    # (the first frame that carries a token)
-                    live.stamp("first_frame", written)
-                    self._fold_first_token(live)
-                if live.token_out(out, wake, written):
-                    self._fold_token_out(live)
-                if done:
+                yield from self._stream_output(st, out, time.monotonic())
+                if st.done:
                     return
         finally:
             self._finalize_live(live)
             self._fold_token_out(live)
+
+    def _serve_pushed(self, st: _Stream, write) -> bool:
+        """The handler's side of a stream the writer owns
+        (``Response.push``): attach the sink, then park on ONE event
+        until the writer ends the stream; a parked thread holds no
+        interpreter. True: end it cleanly; False: break the socket. This
+        thread also keeps the stream's watch: no output written for
+        ``request_timeout_s`` and it posts ``_TIMEOUT``, which the
+        writer checks again under the stream's one write order."""
+        live = st.live
+        st.write = write
+        st.last_t = time.monotonic()
+        with self._live_lock:
+            closed = self._writer_closed
+            if not closed:
+                self._writer_q.put(((live, _ATTACH),))
+        if closed:
+            # The worker stopped before this stream began: what stop()'s
+            # sentinel gives a stream.
+            write(SSE_DONE)
+            return True
+        limit = wait = self.opts.request_timeout_s
+        while not st.over.wait(timeout=wait):
+            idle = time.monotonic() - st.last_t
+            if idle >= limit:
+                self._writer_q.put(((live, _TIMEOUT),))
+                wait = limit
+            else:
+                wait = limit - idle
+        return st.clean
+
+    def _stream_writer_loop(self) -> None:
+        """The worker's ONE stream writer (root ``worker.stream_writer``):
+        takes an iteration's outputs as one item and runs each, in emit
+        order, through ``_stream_output`` into its stream's sink, so that
+        an iteration wakes one thread and not one a stream. The sink
+        only queues a chunk for the server's event loop: one slow client
+        stalls no other."""
+        try:
+            while True:
+                try:
+                    batch = self._writer_q.get(
+                        timeout=self.opts.request_timeout_s)
+                except queue.Empty:
+                    continue
+                if batch is None:
+                    break
+                self._writer_run(batch)
+            with self._live_lock:
+                self._writer_closed = True
+            # Whatever was posted before the door closed, then the
+            # streams still open: each gets stop()'s sentinel.
+            while not self._writer_q.empty():
+                self._writer_run(self._writer_q.get_nowait() or ())
+            for st in list(self._writer_owned):
+                if st.attached:
+                    self._writer_take(st, None, 0.0)
+            self._writer_owned.clear()
+        except BaseException:
+            # The crash is the supervised thread's (counted, restarted).
+            # Every stream this thread owned is broken, none left
+            # hanging: the ones it knew and the item in its hands.
+            for st in list(self._writer_owned) + [
+                    live.push for live, _ in self._writer_batch]:
+                st.done, st.clean = True, False
+                st.over.set()
+            self._writer_owned.clear()
+            raise
+
+    def _writer_run(self, batch: Any) -> None:
+        self._writer_batch = batch
+        wake = time.monotonic()
+        for live, out in batch:
+            st = live.push
+            if not st.done:
+                wake = self._writer_take(st, out, wake)
+        self._writer_batch = ()
+
+    def _writer_take(self, st: _Stream, out: Any, wake: float) -> float:
+        """One posted item of one stream, in the order posted (writer's
+        thread). Returns the clock as last read: when the writer turns
+        to the next output."""
+        if out is _ABORT:
+            # Simulated death, or a response never served: the socket
+            # breaks with no [DONE], whatever is held.
+            self._writer_end(st, clean=False)
+            return wake
+        if not st.attached:
+            self._writer_owned.add(st)
+            if out is not _ATTACH:
+                # The engine was faster than the handler: held, and
+                # written first at the attachment.
+                st.held.append(out)
+                return wake
+            st.attached = True
+            if any(st.write(frame) for ro in st.initial
+                   for frame in st.asm.on_output(ro)):
+                self._writer_end(st)        # the client is gone already
+            held, st.held = st.held, []
+            for o in held:
+                if not st.done:
+                    wake = self._writer_take(st, o, wake)
+            return wake
+        if isinstance(out, StepOutput):
+            frames = self._stream_output(st, out, wake)
+            for frame in frames:
+                if st.write(frame):
+                    # The client went away: as the pull path's server
+                    # closes the generator at its yield.
+                    frames.close()
+                    st.done = True
+                    break
+            if st.done:
+                self._writer_end(st)
+            return st.last_t
+        if out is _TIMEOUT:
+            if wake - st.last_t < self.opts.request_timeout_s:
+                return wake         # an output came meanwhile
+            frame = _timeout_frame(self.opts.request_timeout_s)
+        elif out is None:
+            frame = SSE_DONE
+        else:
+            frame = _engine_fault_frame(out.verdict)
+        st.write(frame)
+        self._writer_end(st)
+        return wake
+
+    def _writer_end(self, st: _Stream, clean: bool = True) -> None:
+        """The stream is over: its handler's thread wakes and runs what
+        a handler runs at a response's end (the server's end or abort,
+        ``_finalize_live``, which cancels what is unfinished)."""
+        st.done, st.clean = True, clean
+        self._writer_owned.discard(st)
+        self._fold_token_out(st.live)
+        st.over.set()
 
     def _collect_full(self, live: _LiveRequest,
                       initial: Optional[List[RequestOutput]] = None
@@ -2813,6 +3136,16 @@ class Worker:
                   "coordination-store health as seen by this plane "
                   "(2 healthy / 1 flaky / 0 down)").set(
             int(getattr(self.store, "health", 2)))
+        obs.counter(
+            "xllm_worker_stream_writer_batch_outputs_sum",
+            "outputs handed to the stream writer; over "
+            "xllm_worker_stream_writer_batch_outputs_count, the "
+            "outputs one wake of its thread carries").set_total(
+            self._writer_batch_outs)
+        obs.counter(
+            "xllm_worker_stream_writer_batch_outputs_count",
+            "iterations that handed outputs to the stream writer: one "
+            "queue put each").set_total(self._writer_batches)
         obs.counter("xllm_worker_encode_seconds_total").set_total(
             self.encode_seconds)
         obs.counter("xllm_worker_encode_calls_total").set_total(
@@ -4080,6 +4413,7 @@ class Worker:
         # The migrated first token reaches the client via first_out below,
         # outside _to_request_output — count it here.
         new_live.choices[0].completion_tokens = 1
+        self._writer_adopt(new_live)
         first_out = RequestOutput(
             request_id=srid, service_request_id=srid,
             outputs=[SequenceOutput(
@@ -4100,9 +4434,7 @@ class Worker:
             return Response.json({"status": "accepted",
                                   "service_request_id": srid})
         if live.stream:
-            return self._stream_response(
-                self._stream_sse(new_live, initial=[first_out]),
-                lambda: self._finalize_live(new_live))
+            return self._sse_response(new_live, initial=[first_out])
         return self._collect_full(new_live, initial=[first_out])
 
     def adopt_migrated(self, meta: Dict[str, Any], k, v):

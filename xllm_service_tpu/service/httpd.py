@@ -52,18 +52,29 @@ class Request:
 
 class Response:
     """``body`` for buffered responses; ``stream`` (an iterator of byte
-    chunks) for progressive/SSE responses — chunks are flushed as produced."""
+    chunks) for progressive/SSE responses — chunks are flushed as produced
+    by the thread that runs the handler. ``push``: a progressive body its
+    PRODUCER writes, for a server whose chunk call cannot block
+    (``chunks_block`` False: the native one). Once the headers are queued
+    the server calls ``push.serve(write)`` on the handler's thread;
+    ``write(chunk) -> int`` may then be called from any thread, queues
+    the chunk and returns nonzero once the client is gone. ``serve``
+    returns when the body is over: True ends it cleanly, False breaks
+    the connection without the terminating chunk. A handler returns one
+    only to a server that says it can take it."""
 
     def __init__(self, status: int = 200, body: Optional[bytes] = None,
                  content_type: str = "application/json",
                  headers: Optional[Dict[str, str]] = None,
                  stream: Optional[Iterable[bytes]] = None,
-                 on_close: Optional[Callable[[], None]] = None) -> None:
+                 on_close: Optional[Callable[[], None]] = None,
+                 push: Any = None) -> None:
         self.status = status
         self.body = body if body is not None else b""
         self.content_type = content_type
         self.headers = headers or {}
         self.stream = stream
+        self.push = push
         # Invoked by the server EXACTLY when it is done with this
         # response — including when a stream body is never iterated
         # (failed header write): a never-STARTED generator's finally
@@ -85,9 +96,11 @@ class Response:
             status=status)
 
     @classmethod
-    def sse(cls, chunks: Iterable[bytes]) -> "Response":
+    def sse(cls, chunks: Optional[Iterable[bytes]] = None,
+            push: Any = None) -> "Response":
         return cls(content_type="text/event-stream",
-                   headers={"Cache-Control": "no-cache"}, stream=chunks)
+                   headers={"Cache-Control": "no-cache"}, stream=chunks,
+                   push=push)
 
 
 Handler = Callable[[Request], Response]
@@ -314,6 +327,11 @@ class PyHttpServer:
 
     ``max_concurrency``: int / None / zero-arg callable — see
     ``Admission``. Control-plane paths (``_ADMISSION_EXEMPT``) bypass it."""
+
+    # A chunk goes to a blocking ``wfile``: one thread writing every
+    # stream would put them all behind the slowest client, so a
+    # streamed body is pulled on its connection's own thread.
+    chunks_block = True
 
     def __init__(self, host: str, port: int, router: Router,
                  max_concurrency=None,

@@ -146,6 +146,12 @@ class NativeHttpServer:
     the ``HttpServer`` factory in ``httpd`` catches that and falls back
     to the pure-Python server, so callers never see the difference."""
 
+    # ``xllm_httpd_stream_chunk`` only queues an op for the event loop,
+    # from any thread and for any request's id: it never waits for a
+    # client's socket, so a producer may write every stream from one
+    # thread (``Response.push``).
+    chunks_block = False
+
     def __init__(self, host: str, port: int, router,
                  max_concurrency=None,
                  admission_exempt: Optional[Tuple[str, ...]] = None
@@ -390,28 +396,38 @@ class NativeHttpServer:
     def _write(self, rid: int, resp) -> None:
         headers = {"Content-Type": resp.content_type}
         headers.update(resp.headers)
-        if resp.stream is not None:
+        if resp.push is not None or resp.stream is not None:
             blob = _headers_blob(headers)
             self._lib.xllm_httpd_stream_begin(self._h, rid, resp.status,
                                               blob, len(blob))
+            chunk_call, h = self._lib.xllm_httpd_stream_chunk, self._h
+
+            def write(chunk: bytes) -> int:
+                return chunk_call(h, rid, chunk, len(chunk))
+            clean = True
             try:
-                for chunk in resp.stream:
-                    if not chunk:
-                        continue
-                    rc = self._lib.xllm_httpd_stream_chunk(
-                        self._h, rid, chunk, len(chunk))
-                    if rc != 0:
-                        break   # client went away — stop producing
+                if resp.push is not None:
+                    # The producer writes; this thread parks in there
+                    # for the body's lifetime and holds no interpreter.
+                    clean = resp.push.serve(write)
+                else:
+                    for chunk in resp.stream:
+                        if chunk and write(chunk) != 0:
+                            break   # client went away — stop producing
             except BaseException:
-                # Producer failure mid-stream: ABORT (close without the
-                # chunked terminator) so the client's decoder sees a
-                # truncated response — a clean 0-chunk would make a
-                # partial answer look complete. The connection must
-                # always be resolved one way or the other: a
-                # busy+streaming conn is skipped by the idle sweep.
-                self._lib.xllm_httpd_stream_abort(self._h, rid)
+                clean = False
                 raise
-            else:
-                self._lib.xllm_httpd_stream_end(self._h, rid)
+            finally:
+                # Producer failure mid-stream (or a producer that says
+                # so): ABORT (close without the chunked terminator) so
+                # the client's decoder sees a truncated response — a
+                # clean 0-chunk would make a partial answer look
+                # complete. The connection must always be resolved one
+                # way or the other: a busy+streaming conn is skipped by
+                # the idle sweep.
+                if clean:
+                    self._lib.xllm_httpd_stream_end(self._h, rid)
+                else:
+                    self._lib.xllm_httpd_stream_abort(self._h, rid)
         else:
             self._respond(rid, resp.status, headers, resp.body)
