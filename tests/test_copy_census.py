@@ -581,6 +581,12 @@ PREFILL_CELLS = {
     # census leaves out); the pool of states, 6.7 GB, is read and
     # written a row at a time where it lies (transformer._ret_window)
     "brumby-14b": (0, 1024, 16, 49, [(1, 2048, 168), (2, 256, 168)]),
+    # TWO pairs of pools (the 2 full layers' of the 8 taken, and the 6
+    # window layers' own 562 pages: ``_prefill_census`` sizes it as an
+    # engine does) and two tables side by side; a window layer gathers
+    # the 18 or 33 columns its window reaches, a full layer all 264
+    "trinity-mini": (8, 1088, 16, 0,
+                     [(1, 256, 264), (8, 256, 264), (1, 2048, 264)]),
 }
 CELL_SHAPES = [(cell, shape) for cell, spec in PREFILL_CELLS.items()
                for shape in spec[4]]
@@ -618,6 +624,15 @@ def _merged_leading_axes(pool, layer, page_table):
                         layer * pages + page_table)
 
 
+def _window_pages(name, layers, batch):
+    """Pages of the cell's pool of window layers, as its engine sizes it
+    (0: the model has none)."""
+    from xllm_service_tpu.runtime.engine import window_pool_pages
+    cfg = _cell_config(name, layers)
+    return window_pool_pages(cfg.sliding_window, 128, batch, batch,
+                             2048)[1] if cfg.num_swa_layers else 0
+
+
 def _prefill_census(aot, name, shape):
     """(results that are one layer of a pool, pool-sized copies) of the
     cell's compiled prefill program of ``shape`` = (rows, bucket, table
@@ -628,7 +643,8 @@ def _prefill_census(aot, name, shape):
     rows, window, table_width = shape
     programs, _, pools = cc.build_cell_programs(
         _cell_config(name, layers), pages, table_width, batch,
-        window=window, state_slots=slots, prefill_rows=rows)
+        window=window, state_slots=slots, prefill_rows=rows,
+        window_pages=_window_pages(name, layers, batch))
     fn, args, jit_kw = programs["prefill"]
     return cc.census_pools(aot_compile(fn, args, **jit_kw).as_text(), pools,
                            window=(rows, window))
@@ -688,6 +704,33 @@ class TestPrefillReadsPagesOffThePool:
         compiled = aot_compile(fn, args, **jit_kw)
         assert cc.census_pools(compiled.as_text(), pools) == ([], [])
         assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+    def test_two_pools_decode_under_two_names_and_copy_neither(self, aot):
+        """The decode program of the cell with window and full layers,
+        at its pools' real shapes (8 of its 32 layers): the paged kernel
+        is called under the two names a trace tells its pools by (6
+        window layers and 2 full ones: one call each in the scanned
+        period, one a layer outside it), neither pool is copied, and the
+        program's temporaries are a few MB."""
+        import re
+
+        import tools.aot_copy_census as cc
+        aot_compile, _ = aot
+        name = "trinity-mini"
+        layers, pages, batch, _, _ = PREFILL_CELLS[name]
+        programs, _, pools = cc.build_cell_programs(
+            _cell_config(name, layers), pages, 264, batch,
+            window_pages=_window_pages(name, layers, batch))
+        assert pools[0] == (2, 1088, 128, 4, 128) \
+            and pools[3] == (6, 562, 128, 4, 128)
+        fn, args, jit_kw = programs["decode"]
+        compiled = aot_compile(fn, args, **jit_kw)
+        text = compiled.as_text()
+        assert set(re.findall(r"%(paged_decode_attention_\w+?)[.\d]* = ",
+                              text)) == {"paged_decode_attention_swa",
+                                         "paged_decode_attention_full"}
+        assert cc.census_pools(text, pools) == ([], [])
+        assert compiled.memory_analysis().temp_size_in_bytes < 64e6
 
     # (not the delta-rule cell: ONE of its layers attends, and a layer
     # sliced out of a pool of one layer is the pool; not the retention

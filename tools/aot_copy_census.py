@@ -417,7 +417,7 @@ def build_programs(tiny: bool = False, plan=None):
 def build_cell_programs(cfg, pages: int, table_width: int, batch: int = 8,
                         window: int = 256, page_size: int = 128,
                         state_slots: int = 0, plan=None,
-                        prefill_rows: int = 1):
+                        prefill_rows: int = 1, window_pages: int = 0):
     """(name → (fn, args, jit keywords)), the shapes of the attention
     projections' weights, stacked and a layer's
     (``census_weight_relayouts``), and the pools' (``census_pool_copies``):
@@ -430,7 +430,9 @@ def build_cell_programs(cfg, pages: int, table_width: int, batch: int = 8,
     a few layers. A model with a mixer beside attention takes
     ``state_slots`` slots of its fourth pool, and its programs the slot
     columns an engine hands them (a state row a decode row, four slot
-    columns a prefill row)."""
+    columns a prefill row). A model with window layers beside full ones
+    takes ``window_pages`` pages of its second pair of pools, and its
+    programs the two tables side by side."""
     from xllm_service_tpu.models import transformer
     from xllm_service_tpu.runtime.engine import (latent_pool_format,
                                                  row_major_format)
@@ -447,7 +449,9 @@ def build_cell_programs(cfg, pages: int, table_width: int, batch: int = 8,
         lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
     kv = described(
         lambda: transformer.init_kv_cache(cfg, pages, page_size,
-                                          state_slots=state_slots))
+                                          state_slots=state_slots,
+                                          window_pages=window_pages))
+    table_width *= 2 if cfg.num_swa_layers else 1
     # ... but a latent model's one pool, which an engine on a TPU pins
     # with its size-1 head axis outermost (Engine._pool_format).
     pin = tuple(latent_pool_format(x.sharding) if cfg.mla
@@ -504,7 +508,9 @@ def chip_plan(cfg):
                       write_then_attend=True, latent_decode=cfg.mla,
                       # but a model whose state lives by slot, whose
                       # engine starts every window on a page boundary
-                      page_aligned=cfg.num_state_layers > 0,
+                      # ... or a second pool of window layers
+                      page_aligned=cfg.num_state_layers > 0
+                      or cfg.num_swa_layers > 0,
                       expert_gmm=cfg.dropless_experts,
                       ssm_decode=cfg.num_state_layers > 0, interpret=False)
 
@@ -598,12 +604,17 @@ def run_cells_census(only=()) -> dict:
         rows = eng["max_batch_size"]
         # the slots an engine gives a pool of states (runtime/engine.py)
         slots = 1 + 3 * rows if cfg.num_state_layers else 0
+        # ... and a pool of window layers
+        from xllm_service_tpu.runtime.engine import window_pool_pages
+        wpages = window_pool_pages(
+            cfg.sliding_window, eng["page_size"], rows, rows,
+            2048)[1] if cfg.num_swa_layers else 0
         for B, T, MP in traffic.warmup_shapes(
                 mix, eng["page_size"])["prefill"]:
             programs, _, pools = build_cell_programs(
                 cfg, eng["num_pages"], MP, rows, window=T,
                 page_size=eng["page_size"], state_slots=slots,
-                prefill_rows=B)
+                prefill_rows=B, window_pages=wpages)
             fn, args, jit_kw = programs["prefill"]
             compiled = aot_compile(fn, args, **jit_kw)
             layer_sized, copies = census_pools(compiled.as_text(), pools,

@@ -121,8 +121,12 @@ class ModelConfig:
     # 2047): each token attends only to the last W positions including
     # itself. None/0 = full causal attention. Threaded as a static mask
     # parameter through every attention path (ops/attention.py), so one
-    # transformer body serves both regimes; the Pallas fast paths are
-    # bypassed at trace time when a window is set.
+    # transformer body serves both regimes; under a STATIC window the
+    # paged decode kernel walks the window's pages alone
+    # (ops/plan.py ``decode_walk_columns``, PR 34). In a ``layer_kinds``
+    # model it is the window of the "swa" layers, which keep their keys
+    # and values in a pool of their own (``num_swa_layers``); the "attn"
+    # layers attend to everything.
     sliding_window: Optional[int] = None
     # Per-layer window activation (Gemma-2's alternating local/global
     # layers): tuple of bools, True = this layer uses sliding_window,
@@ -229,7 +233,7 @@ class ModelConfig:
     # Layers that differ in KIND (LFM2: gated short convolutions between
     # attention layers, a dense FFN in the leading layers and routed
     # experts after): one "<operator>+<ffn>" a layer, operator "conv" |
-    # "attn", ffn "dense" | "moe". Data, not a family: the layer loop
+    # "attn" | "swa" | "mix" | "kda" | "ret", ffn "dense" | "moe". Data, not a family: the layer loop
     # (models/transformer.py, "Layers that differ in kind") walks
     # whatever pattern stands here. None = every layer alike (the
     # scanned bodies of the other families).
@@ -292,6 +296,17 @@ class ModelConfig:
     # from the layer's normed input (hidden -> heads x head_dim;
     # ``use_gqa_gate``).
     attn_gate: bool = False
+    # Sliding-window attention layers beside full ones (operator "swa" in
+    # ``layer_kinds``; Arcee's afmoe): a "swa" layer attends to the last
+    # ``sliding_window`` positions and ALWAYS rotates; an "attn" layer
+    # attends to everything and rotates by ``use_rope``. Its keys and
+    # values live in a second pair of pools with a page allocator and a
+    # page table of their own, trimmed behind the window as a row
+    # advances (runtime/engine.py; docs/KV_CACHE.md "Two pools").
+    # ``sandwich_norm``: the loop over kinds norms each sublayer's OUTPUT
+    # before the residual add as well as its input (``post_attn_norm``,
+    # ``post_mlp_norm`` beside ``input_norm`` and ``post_norm``).
+    sandwich_norm: bool = False
     # A held SHARE of a wider router: ``expert_share_chips`` chips divide
     # each sparse layer's experts among them, ``num_experts`` is what THIS
     # chip holds and ``expert_share_rank`` which (experts rank *
@@ -360,6 +375,13 @@ class ModelConfig:
                 "layer: no model has both, and a 'conv' tail shifted in "
                 "place beside a state that a discarded launch ahead must "
                 "leave as it was is run by no test")
+        if "swa" in ops and (not self.sliding_window
+                             or ops - {"swa", "attn"}):
+            raise ValueError(
+                "a layer_kinds model with a 'swa' operator gives "
+                "sliding_window and has 'swa' and 'attn' layers only: the "
+                "window pool beside tails or a state by slot is run by no "
+                "test")
         if not (0 <= self.expert_share_rank < self.expert_share_chips):
             raise ValueError(
                 f"expert_share_rank={self.expert_share_rank} is not a "
@@ -418,6 +440,12 @@ class ModelConfig:
         if self.layer_kinds is None:
             return self.num_layers
         return sum(k.startswith(("attn+", "mix+")) for k in self.layer_kinds)
+
+    @property
+    def num_swa_layers(self) -> int:
+        """Sliding-window attention layers: the leading axis of the
+        WINDOW pools (a pair of their own, behind the others)."""
+        return sum(k.startswith("swa+") for k in self.layer_kinds or ())
 
     @property
     def num_conv_layers(self) -> int:
@@ -760,7 +788,7 @@ class ModelConfig:
                      "qwen2_vl", "qwen2_5_vl",
                      "qwen3_moe", "deepseek_v2", "deepseek_v3",
                      "joyai_llm_flash", "gpt_oss", "lfm2_moe", "ouro",
-                     "falcon_h1", "solar_open2", "brumby")
+                     "falcon_h1", "solar_open2", "brumby", "afmoe")
         # The latent family: DeepSeek-V2, and the V3 layer (sigmoid
         # scores, selection bias) that JD's JoyAI-LLM-Flash shares.
         _v3 = mt in ("deepseek_v3", "joyai_llm_flash")
@@ -927,6 +955,52 @@ class ModelConfig:
                         f"brumby with {key}={d[key]!r} is not implemented "
                         f"(only {want!r})")
             layer_kinds = ("ret+dense",) * d["num_hidden_layers"]
+        _afm = mt == "afmoe"
+        if _afm:
+            # Arcee afmoe (Trinity): sliding-window layers that rotate
+            # between full layers that rotate nothing, q and k normed a
+            # head, a sigmoid gate on attention's output, four norms a
+            # layer, dense feed-forwards in the leading layers and
+            # sigmoid-routed experts beside one shared expert after them,
+            # the embedding scaled by sqrt(hidden) (mup_enabled). The
+            # config has no key for the gate, the norms or the unrotated
+            # full layers: they are the family's published block. What
+            # this loop has no body for is refused, by the key that asks
+            # for it.
+            for key, want in (("rope_scaling", None),
+                              ("n_group", 1), ("topk_group", 1),
+                              ("num_expert_groups", 1),
+                              ("num_limited_groups", 1),
+                              ("score_func", "sigmoid"),
+                              ("hidden_act", "silu"),
+                              ("num_shared_experts", 1),
+                              ("route_norm", True),
+                              ("mup_enabled", True),
+                              ("attention_bias", False),
+                              ("tie_word_embeddings", False)):
+                if d.get(key, want) != want:
+                    raise ValueError(
+                        f"afmoe with {key}={d[key]!r} is not implemented "
+                        f"(only {want!r})")
+            ops = {"sliding_attention": "swa", "full_attention": "attn"}
+            lt = d["layer_types"]
+            if len(lt) != d["num_hidden_layers"] \
+                    or any(t not in ops for t in lt):
+                raise ValueError(
+                    f"afmoe layer_types {sorted(set(lt))} over {len(lt)} of "
+                    f"{d['num_hidden_layers']} layers is not implemented "
+                    f"(sliding_attention, full_attention; one a layer)")
+            if "sliding_attention" in lt and not d.get("sliding_window"):
+                raise ValueError(
+                    "afmoe with sliding_attention layers and no "
+                    "sliding_window is not implemented")
+            n_dense = int(d.get("num_dense_layers", 0))
+            layer_kinds = tuple(
+                ops[t] + ("+dense" if i < n_dense else "+moe")
+                for i, t in enumerate(lt))
+            # num_experts counts the experts HELD here, of
+            # ``expert_share_chips`` times as many routed (Solar-Open2's
+            # two keys for a deployment's share; 1 and 0 without them).
         if mt == "ouro" and set(d.get("layer_types") or ()) \
                 - {"full_attention"}:
             raise ValueError(
@@ -966,7 +1040,7 @@ class ModelConfig:
             sw = None
         if sw is not None \
                 and sw >= d.get("max_position_embeddings", 4096) \
-                and mt != "gemma3_text":
+                and mt not in ("gemma3_text", "afmoe"):
             # An at-least-context-wide window never binds, so dropping
             # it keeps full-attention fast paths eligible. Gemma-3 is
             # EXEMPT: its sliding/full layer pattern also selects the
@@ -1021,7 +1095,7 @@ class ModelConfig:
                                      "gpt_oss")),
             qk_norm=d.get("model_type") in ("qwen3", "qwen3_moe",
                                             "gemma3_text", "lfm2_moe",
-                                            "brumby"),
+                                            "brumby", "afmoe"),
             fused_proj=d.get("model_type") == "phi3",
             sliding_window=sw,
             layer_sliding=layer_sliding,
@@ -1051,7 +1125,7 @@ class ModelConfig:
             rope_local_base_freq=(d.get("rope_local_base_freq", 10000.0)
                                   if mt == "gemma3_text" else None),
             num_experts=(d.get("num_experts", 0)
-                         if mt in ("qwen3_moe", "lfm2_moe")
+                         if mt in ("qwen3_moe", "lfm2_moe", "afmoe")
                          else d.get("n_routed_experts", 0) if _dsk or _so2
                          else d.get("num_local_experts", 0)),
             num_experts_per_tok=d.get("num_experts_per_tok", 2),
@@ -1062,8 +1136,10 @@ class ModelConfig:
             qk_rope_head_dim=d.get("qk_rope_head_dim", 0) if _dsk else 0,
             v_head_dim=d.get("v_head_dim", 0) if _dsk else 0,
             n_shared_experts=(d.get("n_shared_experts") or 0)
-            if _dsk or _so2 else 0,
-            routed_scaling_factor=d.get("routed_scaling_factor", 1.0),
+            if _dsk or _so2 else int(d.get("num_shared_experts", 1))
+            if _afm else 0,
+            routed_scaling_factor=(float(d.get("route_scale", 1.0)) if _afm
+                                   else d.get("routed_scaling_factor", 1.0)),
             # V3's "noaux_tc" IS grouped selection under sigmoid scoring;
             # with one group (JoyAI-LLM-Flash) nothing is limited.
             topk_method=(("group_limited_greedy"
@@ -1076,7 +1152,8 @@ class ModelConfig:
             # LFM2's gate is V3's with one group: sigmoid scores, a bias
             # that shapes the choice only (use_expert_bias; zeros without
             # it), the chosen scores over their sum + 1e-6.
-            moe_scoring="sigmoid" if _v3 or _lfm or _so2 else "softmax",
+            moe_scoring="sigmoid" if _v3 or _lfm or _so2 or _afm
+            else "softmax",
             moe_gate_eps=1e-6 if _lfm else 1e-20,
             layer_kinds=layer_kinds,
             conv_kernel=(int(d.get("conv_L_cache", 0)) if _lfm
@@ -1114,6 +1191,11 @@ class ModelConfig:
                 "mlp_multipliers":
                     tuple(float(x) for x in d["mlp_multipliers"])}
                if _fh1 else {}),
+            **({"use_rope": False, "attn_gate": True, "sandwich_norm": True,
+                "embedding_multiplier": float(d["hidden_size"]) ** 0.5,
+                "expert_share_chips": int(d.get("expert_share_chips", 1)),
+                "expert_share_rank": int(d.get("expert_share_rank", 0))}
+               if _afm else {}),
             ret_degree=2 if _brm else 0,
             gptoss=mt == "gpt_oss",
             rope_interleave=bool(d.get("rope_interleave", True)),

@@ -147,10 +147,12 @@ def num_params(params: Params) -> int:
 
 def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                   dtype: Optional[jnp.dtype] = None,
-                  state_slots: int = 0) -> KVCache:
+                  state_slots: int = 0, window_pages: int = 0) -> KVCache:
     """The pools. ``state_slots``: slots of the fourth pool, which a model
     with layers that keep a matrix state keeps (``cfg.num_state_layers``:
-    a mixer beside attention, a delta-rule layer) and no other does."""
+    a mixer beside attention, a delta-rule layer) and no other does.
+    ``window_pages``: pages of the window layers' pair of pools
+    (``cfg.num_swa_layers``), which no other model keeps."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     if cfg.layer_kinds is not None:
         # Keys and values of the ATTENTION layers alone, and a third
@@ -189,6 +191,15 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
             pools += (jnp.zeros(
                 (cfg.num_state_layers, max(state_slots, 2))
                 + cfg.state_shape, jnp.float32),)
+        if cfg.num_swa_layers:
+            # Keys and values of the WINDOW layers, LAST: a pair of pools
+            # of ``window_pages`` pages addressed by page ids of their
+            # own through a table of their own (a row holds the pages its
+            # window can reach and the null page behind them). A model
+            # without window layers keeps no such pair at all, so its
+            # step programs take the arguments they took.
+            wshape = (cfg.num_swa_layers, max(window_pages, 2)) + shape[2:]
+            pools += (jnp.zeros(wshape, dtype), jnp.zeros(wshape, dtype))
         return pools
     # One slot a layer a PASS (``ModelConfig.kv_cache_layers``): pass p
     # of a looped model keeps layer l's keys and values at p * L + l.
@@ -1666,6 +1677,9 @@ def _init_kinds_params(cfg: ModelConfig, key: jax.Array,
         op, ffn = kind.split("+")
         st = {"input_norm": jnp.ones((n, D), dtype),
               "post_norm": jnp.ones((n, D), dtype)}
+        if cfg.sandwich_norm:
+            st.update(post_attn_norm=jnp.ones((n, D), dtype),
+                      post_mlp_norm=jnp.ones((n, D), dtype))
         if op == "conv":
             st.update(conv_in=w((n, D, 3 * D), D), conv_w=w((n, K, D), K),
                       conv_out=w((n, D, D), D))
@@ -1700,7 +1714,7 @@ def _init_kinds_params(cfg: ModelConfig, key: jax.Array,
         if op == "ret":
             # one decay a key-value head, from the layer's normed input
             st.update(ret_gate=w((n, D, Hkv), D))
-        if op in ("attn", "mix", "ret"):
+        if op in ("attn", "swa", "mix", "ret"):
             st.update(q_proj=w((n, D, Hq * Dh), D),
                       k_proj=w((n, D, Hkv * Dh), D),
                       v_proj=w((n, D, Hkv * Dh), D),
@@ -1742,7 +1756,15 @@ def _init_kinds_params(cfg: ModelConfig, key: jax.Array,
 _RANKS = ("attn", "conv", "state")
 _KEEPS = {"attn": ("attn",), "conv": ("conv",),
           "mix": ("attn", "conv", "state"), "kda": ("conv", "state"),
-          "ret": ("state",)}
+          "ret": ("state",), "swa": ("swa",)}
+
+
+def _ranks(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The ranks a model's layers advance: ``_RANKS``, and behind them
+    "swa" (keys and values of a window layer: the window pools' leading
+    axis) for a model that has such layers and for no other, whose loop
+    then traces what it traced."""
+    return _RANKS + (("swa",) if cfg.num_swa_layers else ())
 
 
 def _runs(kinds) -> Tuple[Tuple[str, int], ...]:
@@ -1778,7 +1800,8 @@ def kinds_pattern(kinds: Tuple[str, ...]) -> Tuple[int, int, int]:
 
 def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                   pools, conv_op, attn_op, valid: jnp.ndarray,
-                  plan: KernelPlan, mix_op=None, kda_op=None, ret_op=None):
+                  plan: KernelPlan, mix_op=None, kda_op=None, ret_op=None,
+                  swa_op=None):
     """The layer loop over ``cfg.layer_kinds``. ``pools`` = (k, v,
     tails) and, for a model with state layers, the pool of states after
     them, carried and updated in place; ``conv_op(lp, h, tails, c) ->
@@ -1787,20 +1810,22 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     same input), ``kda_op(lp, h, pools, c, r) -> (y, pools)`` (a
     delta-rule layer: a ring and a state, no keys and values) and
     ``ret_op(lp, h, pools, r) -> (y, pools)`` (a power-retention layer:
-    a state and nothing else) are the caller's (prefill's or decode's).
-    A layer has a rank among the
+    a state and nothing else) and ``swa_op(lp, h, wk, wv, w) -> (y, wk,
+    wv)`` (a window layer: the LAST two pools) are the caller's
+    (prefill's or decode's). A layer has a rank among the
     layers that KEEP what it keeps: ``a`` keys and values, ``c`` a
-    convolution tail or ring, ``r`` a matrix state. Returns ``(x,
-    pools, moe_stats)``."""
+    convolution tail or ring, ``r`` a matrix state, ``w`` keys and
+    values of a window. Returns ``(x, pools, moe_stats)``."""
     kinds = cfg.layer_kinds
     lead, period, repeats = kinds_pattern(kinds)
+    ranks = _ranks(cfg)
 
     def tally(span) -> Dict[str, int]:
         # how many layers of each kind ``span`` holds, and how many that
         # keep keys and values ("attn"), a convolution tail or ring
         # ("conv"), a matrix state ("state")
         r = {k: span.count(k) for k in set(kinds)}
-        for rank in _RANKS:
+        for rank in ranks:
             r[rank] = sum(rank in _KEEPS[k.split("+")[0]] for k in span)
         return r
 
@@ -1810,10 +1835,10 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         small, experts = _split_experts(stack) if ffn == "moe" \
             else (stack, None)
 
-        def layer(carry, s, a, c, r):
+        def layer(carry, s, a, c, r, w=None):
             """Layer ``s`` of this kind's stack, the ``a``-th that
-            attends, the ``c``-th that keeps a tail and the ``r``-th
-            that keeps a state."""
+            attends, the ``c``-th that keeps a tail, the ``r``-th
+            that keeps a state and the ``w``-th window layer."""
             x, pools, stats = carry[0], carry[1:-1], carry[-1]
             lp = jax.tree_util.tree_map(
                 lambda w: jax.lax.dynamic_index_in_dim(
@@ -1829,8 +1854,13 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                 y, pools = mix_op(lp, h, pools, a, c, r)
             elif op == "kda":
                 y, pools = kda_op(lp, h, pools, c, r)
+            elif op == "swa":
+                y, wk, wv = swa_op(lp, h, pools[-2], pools[-1], w)
+                pools = pools[:-2] + (wk, wv)
             else:
                 y, pools = ret_op(lp, h, pools, r)
+            if cfg.sandwich_norm:
+                y = rms_norm(y, lp["post_attn_norm"], cfg.rms_norm_eps)
             x = x + y
             h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
             if ffn == "moe":
@@ -1846,6 +1876,8 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                     @ lp["down_proj"]
                 if down_m != 1.0:
                     m = m * jnp.asarray(down_m, m.dtype)
+            if cfg.sandwich_norm:
+                m = rms_norm(m, lp["post_mlp_norm"], cfg.rms_norm_eps)
             return (x + m,) + pools + (stats,)
         return layer
 
@@ -1857,7 +1889,7 @@ def _kinds_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         for kind, count in _runs(span):
             op = kind.split("+")[0]
             first = tuple(at[k] + step.get(k, 0) * r
-                          for k in (kind,) + _RANKS)
+                          for k in (kind,) + ranks)
             layer = body(kind)
             if count == 1:
                 carry = layer(carry, *first)
@@ -2660,6 +2692,18 @@ def _attn_in(cfg: ModelConfig, lp, h: jnp.ndarray):
     return q, k, v
 
 
+def _attn_qkv(cfg: ModelConfig, lp, h: jnp.ndarray, positions: jnp.ndarray,
+              rotate: bool):
+    """``_attn_in``'s q, k, v, rotated at ``positions`` where the layer
+    rotates, as attention over the packed pools takes them
+    (``_packed_qkv``): ``(q, k, v, unpack)``."""
+    q, k, v = _attn_in(cfg, lp, h)
+    if rotate:
+        q = rope_for(cfg.rope_scaling, q, positions, cfg.rope_theta)
+        k = rope_for(cfg.rope_scaling, k, positions, cfg.rope_theta)
+    return _packed_qkv(cfg, q, k, v)
+
+
 def _attn_out(cfg: ModelConfig, lp, attn: jnp.ndarray, h: jnp.ndarray):
     """attention's output [B, T, Hq * Dh] projected back, under the
     sigmoid gate of the layer's normed input ``h`` where the family has
@@ -2670,6 +2714,16 @@ def _attn_out(cfg: ModelConfig, lp, attn: jnp.ndarray, h: jnp.ndarray):
     if cfg.attention_out_multiplier != 1.0:
         out = out * jnp.asarray(cfg.attention_out_multiplier, out.dtype)
     return out
+
+
+def _split_tables(cfg: ModelConfig, page_table: jnp.ndarray):
+    """``(full table, window table)`` of the table a step program is
+    handed: side by side, each half its width, for a model with window
+    layers; the one table and None for every other."""
+    if not cfg.num_swa_layers:
+        return page_table, None
+    half = page_table.shape[1] // 2
+    return page_table[:, :half], page_table[:, half:]
 
 
 def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
@@ -2689,6 +2743,7 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
     tok_valid = (jnp.arange(T, dtype=jnp.int32)[None, :]
                  < lengths[:, None])                             # [B, T]
     scale = cfg.head_dim ** -0.5        # of the head, not the packed row
+    page_table, wtable = _split_tables(cfg, page_table)
 
     def conv_op(lp, h, tails, c):
         y, zz = _conv_mix(cfg, lp, h, _tails_read(
@@ -2696,12 +2751,31 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
         return y, _tails_write(cfg, tails, c, page_table, start_pos,
                                lengths, zz, ps)
 
+    def swa_op(lp, h, wk, wv, w):
+        W = cfg.sliding_window
+        q, k, v, unpack = _attn_qkv(cfg, lp, h, positions, True)
+        wk, wv = write_prefill_kv_layer(wk, wv, k, v, wtable, start_pos,
+                                        lengths, w, plan)
+        # Only the table columns the window can reach are gathered: the
+        # positions [start - W + 1, start + T), which span at most
+        # (W + T - 2) // ps + 2 pages wherever they start, never the
+        # whole table (33 columns of 264 at W = T = 2,048, ps = 128).
+        MP = wtable.shape[1]
+        nc = min(MP, (W + T - 2) // ps + 2)
+        first = jnp.maximum(start_pos - W + 1, 0) // ps            # [B]
+        cols = first[:, None] + jnp.arange(nc, dtype=jnp.int32)[None, :]
+        sub = jnp.where(cols < MP, jnp.take_along_axis(
+            wtable, jnp.minimum(cols, MP - 1), axis=1), 0)
+        off = first * ps
+        attn = mha_prefill_auto(
+            q, gather_layer_pages(wk, w, sub),
+            gather_layer_pages(wv, w, sub), kv_lengths - off,
+            start_pos - off, sliding_window=W, scale=scale)
+        return _attn_out(cfg, lp, unpack(attn).reshape(B, T, -1), h), \
+            wk, wv
+
     def attn_op(lp, h, kp, vp, a):
-        q, k, v = _attn_in(cfg, lp, h)
-        if cfg.use_rope:
-            q = rope_for(cfg.rope_scaling, q, positions, cfg.rope_theta)
-            k = rope_for(cfg.rope_scaling, k, positions, cfg.rope_theta)
-        q, k, v, unpack = _packed_qkv(cfg, q, k, v)
+        q, k, v, unpack = _attn_qkv(cfg, lp, h, positions, cfg.use_rope)
         # The window's keys and values into the pool first, then
         # attention reads cached prefix and window alike from the pool.
         kp, vp = write_prefill_kv_layer(kp, vp, k, v, page_table,
@@ -2779,7 +2853,8 @@ def _kinds_forward_prefill(params: Params, cfg: ModelConfig,
         return ya + ym, (kp, vp, tails, put(state, S, S_snap))
 
     x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
-                                     tok_valid, plan, mix_op, kda_op, ret_op)
+                                     tok_valid, plan, mix_op, kda_op, ret_op,
+                                     swa_op)
     x, head = _kinds_head(params, cfg, x)
     last_idx = jnp.maximum(lengths - 1, 0)
     last_x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
@@ -2807,6 +2882,11 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
     pos2 = positions[:, None]
     one = active.astype(jnp.int32)          # an inactive lane: length 0
     scale = cfg.head_dim ** -0.5        # of the head, not the packed row
+    page_table, wtable = _split_tables(cfg, page_table)
+    # Which pool a call of the decode kernel reads is in its name in the
+    # device trace, where a model has both kinds of attention layer.
+    named = {"name": "paged_decode_attention_full"} \
+        if cfg.num_swa_layers else {}
 
     def conv_op(lp, h, tails, c):
         y, zz = _conv_mix(cfg, lp, h, _tails_read(
@@ -2814,21 +2894,28 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
         return y, _tails_write(cfg, tails, c, page_table, positions, one,
                                zz, ps)
 
-    def attn_op(lp, h, kp, vp, a):
-        q, k, v = _attn_in(cfg, lp, h)
-        if cfg.use_rope:
-            q = rope_for(cfg.rope_scaling, q, pos2, cfg.rope_theta)
-            k = rope_for(cfg.rope_scaling, k, pos2, cfg.rope_theta)
-        q, k, v, unpack = _packed_qkv(cfg, q, k, v)
-        kp, vp = write_decode_kv_layer(kp, vp, k[:, 0], v[:, 0],
-                                       page_table, positions, active, a,
-                                       plan)
+    def attend(lp, h, kp, vp, layer, table, rotate, **kernel):
+        """The row's keys and values into ``table``'s pool, then the
+        paged kernel over it (``kernel``: its window and its name)."""
+        q, k, v, unpack = _attn_qkv(cfg, lp, h, pos2, rotate)
+        kp, vp = write_decode_kv_layer(kp, vp, k[:, 0], v[:, 0], table,
+                                       positions, active, layer, plan)
         attn = paged_decode_attention_auto(
-            q[:, 0], kp, vp, page_table,
-            jnp.where(active, positions + 1, 0), plan, scale=scale,
-            layer=a)
+            q[:, 0], kp, vp, table, jnp.where(active, positions + 1, 0),
+            plan, scale=scale, layer=layer, **kernel)
         return _attn_out(cfg, lp, unpack(attn).reshape(B, 1, -1), h), \
             kp, vp
+
+    def attn_op(lp, h, kp, vp, a):
+        return attend(lp, h, kp, vp, a, page_table, cfg.use_rope, **named)
+
+    def swa_op(lp, h, wk, wv, w):
+        # the STATIC window: the kernel's grid walks the window's pages
+        # of the row's own table and no others (ops/plan.py
+        # ``decode_walk_columns``)
+        return attend(lp, h, wk, wv, w, wtable, True,
+                      sliding_window=cfg.sliding_window,
+                      name="paged_decode_attention_swa")
 
     def slots():
         # The state as of position t is in the row's slot t mod 2: read
@@ -2876,7 +2963,7 @@ def _kinds_forward_decode(params: Params, cfg: ModelConfig,
 
     x, kv, moe_stats = _kinds_layers(params, cfg, x, kv, conv_op, attn_op,
                                      active[:, None], plan, mix_op, kda_op,
-                                     ret_op)
+                                     ret_op, swa_op)
     x, head = _kinds_head(params, cfg, x)
     logits = _kinds_logits(cfg, x[:, 0], head)
     if return_stats:

@@ -130,6 +130,16 @@ SPAN_NAMES: Tuple[str, ...] = (
                              # least recently hit evicted for it) in
                              # prefill.pack, or attached to its page in
                              # prefill.post; arg snapshots
+    "xllm.kv.window_trim",   # Engine._swa_trim: a row lets go of the
+                             # window pool's pages behind its window (a
+                             # model with a second pool of window layers);
+                             # arg pages
+    "xllm.kv.window_tail",   # the tails the prefix index keeps in the
+                             # window pool: event attach (a finished
+                             # prefill's deepest boundary; arg pages),
+                             # restore (an admission takes its matched
+                             # boundary's tail; arg pages), evict (the
+                             # least recently hit makes room)
     "xllm.admit",            # handler thread: parsed request -> enqueued
     "xllm.admit.lock_wait",  # ... waiting for _engine_lock
     "xllm.admit.locked",     # ... holding it (Engine.add_request)

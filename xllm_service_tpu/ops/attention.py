@@ -760,12 +760,14 @@ def paged_decode_attention_auto(q, k_pages, v_pages, page_table,
                                 context_lens, plan,
                                 logits_soft_cap: float = 0.0,
                                 sliding_window=0, scale=None, sinks=None,
-                                layer=None):
+                                layer=None, name=None):
     """Write-then-attend decode dispatch: the current token's K/V is
     already IN the pool (written by the layer body's aliased writer), so
     ``context_lens`` INCLUDES it and there is no ``k_cur``/``v_cur``
     plumbing. The Pallas kernel path reads the full 5D pools at a traced
-    ``layer``; the XLA fallback slices locally (its gather fuses)."""
+    ``layer``; the XLA fallback slices locally (its gather fuses).
+    ``name``: the kernel call's name in the device trace (None: the
+    kernel's own)."""
     if plan.decode_attn:
         from xllm_service_tpu.ops import pallas
         return pallas.paged_decode_attention_pallas(
@@ -773,7 +775,7 @@ def paged_decode_attention_auto(q, k_pages, v_pages, page_table,
             k_cur=None, v_cur=None, interpret=plan.interpret,
             sliding_window=sliding_window,
             logits_soft_cap=logits_soft_cap, scale=scale, sinks=sinks,
-            layer=layer)
+            layer=layer, name=name)
     if layer is not None:
         k_pages = jax.lax.dynamic_index_in_dim(
             k_pages, layer, axis=0, keepdims=False)

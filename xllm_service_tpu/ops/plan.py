@@ -180,7 +180,9 @@ class KernelPlan:
             # this family, compiled for a described v5e; PERF.md, PR 45.)
             page_aligned=all(b % engine_cfg.page_size == 0
                              for b in engine_cfg.prefill_buckets)
-            or model_cfg.num_state_layers > 0,
+            or model_cfg.num_state_layers > 0
+            # ... and so does one with a second pool of window layers
+            or model_cfg.num_swa_layers > 0,
             interpret=default_interpret())
 
 

@@ -175,6 +175,8 @@ def load_checkpoint(model_dir: str, cfg: ModelConfig,
         return _load_kda_checkpoint(r, cfg, dtype)
     if cfg.num_ret_layers:
         return _load_ret_checkpoint(r, cfg, dtype)
+    if cfg.num_swa_layers:
+        return _load_swa_checkpoint(r, cfg, dtype)
     if cfg.layer_kinds is not None:
         return _load_kinds_checkpoint(r, cfg, dtype)
 
@@ -706,6 +708,60 @@ def _load_ret_checkpoint(r, cfg: ModelConfig, dtype):
         st[w] = stack(L + "mlp." + w + ".weight", t)
     return _kinds_tree(r, cfg, dtype, {cfg.layer_kinds[0]: st},
                        "model.norm.weight")
+
+
+def _load_swa_checkpoint(r, cfg: ModelConfig, dtype):
+    """Arcee afmoe tree: one stack per KIND of layer (``swa`` / ``attn``
+    x ``dense`` / ``moe``), in layer order, from the family's published
+    names: ``input_layernorm``, ``post_attention_layernorm``,
+    ``pre_mlp_layernorm`` (the loop's ``post_norm``: the norm in front of
+    the feed-forward) and ``post_mlp_layernorm``; ``self_attn.{q, k, v,
+    o}_proj``, ``self_attn.gate_proj`` (the output gate),
+    ``self_attn.{q, k}_norm``; ``mlp.{gate, up, down}_proj`` in the
+    dense layers; ``mlp.router.gate``, ``mlp.expert_bias`` (float32),
+    ``mlp.experts.E.{gate, up, down}_proj`` and ``mlp.shared_experts.*``
+    in the others; ``model.norm`` after the last layer. A held share
+    (``expert_share_chips`` > 1) reads the experts it holds and the
+    whole router. No published checkpoint is in this repository: the
+    loader is tested on a seeded tree alone."""
+    def t(name):
+        return np.ascontiguousarray(r.get(name).T)
+
+    first = cfg.first_held_expert
+    stacks: Dict[str, Any] = {}
+    for kind in sorted(set(cfg.layer_kinds)):
+        idxs = [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
+
+        def stack(fmt, f=r.get, to=dtype):
+            return np.stack([f(fmt.format(i=i)) for i in idxs]).astype(to)
+
+        L, A = "model.layers.{i}.", "model.layers.{i}.self_attn."
+        M = L + "mlp."
+        st = {"input_norm": stack(L + "input_layernorm.weight"),
+              "post_attn_norm": stack(L + "post_attention_layernorm.weight"),
+              "post_norm": stack(L + "pre_mlp_layernorm.weight"),
+              "post_mlp_norm": stack(L + "post_mlp_layernorm.weight"),
+              "q_norm": stack(A + "q_norm.weight"),
+              "k_norm": stack(A + "k_norm.weight"),
+              "attn_gate": stack(A + "gate_proj.weight", t)}
+        for w in ("q_proj", "k_proj", "v_proj", "o_proj"):
+            st[w] = stack(A + w + ".weight", t)
+        names = ("gate_proj", "up_proj", "down_proj")
+        if kind.endswith("+moe"):
+            st["router"] = stack(M + "router.gate.weight", t)
+            st["router_bias"] = stack(M + "expert_bias", to=np.float32)
+            for w in names:
+                st[w] = np.stack([np.stack([
+                    t(M.format(i=i) + f"experts.{first + e}.{w}.weight")
+                    for e in range(cfg.num_experts)])
+                    for i in idxs]).astype(dtype)
+                st["shared_" + w.split("_")[0]] = stack(
+                    M + "shared_experts." + w + ".weight", t)
+        else:
+            for w in names:
+                st[w] = stack(M + w + ".weight", t)
+        stacks[kind] = st
+    return _kinds_tree(r, cfg, dtype, stacks, "model.norm.weight")
 
 
 def _visual_reader(model_dir: str, depth: int, dtype):

@@ -55,7 +55,7 @@ from xllm_service_tpu.ops.sampling import (
     update_counts)
 from xllm_service_tpu.runtime.kv_cache import (
     HostKvTier, KvCacheEvent, PageAllocator, PrefixCacheIndex,
-    SlotAllocator)
+    SlotAllocator, WindowPool)
 from xllm_service_tpu.utils.jaxcache import disable_compile_cache
 from xllm_service_tpu.utils.types import FinishReason, SamplingParams
 
@@ -131,8 +131,14 @@ class Sequence:
     # (index 0 stays None), emitted with the prompt-completion output.
     prompt_lps: Optional[List[Optional[float]]] = None
     # Sliding-window models: count of leading pages already freed (their
-    # positions fell fully below every future attention window).
+    # positions fell fully below every future attention window): of
+    # ``pages`` under a uniform window, of ``wpages`` where the window
+    # layers have a pool of their own (``pages`` is then never trimmed).
     num_trimmed: int = 0
+    # A model whose window layers keep their keys and values in a second
+    # pool: that pool's page of each of the sequence's logical pages (as
+    # long as ``pages``; 0 behind the window).
+    wpages: List[int] = dataclasses.field(default_factory=list)
     # Prefill window pinned by the scheduler for THIS step: under the
     # token-budget interleaver a window can shrink below the bucket cap
     # to the iteration's residual budget, and the executor must run
@@ -230,6 +236,11 @@ class Engine:
         self.page_bytes = model_cfg.num_attn_layers > 0
         self.page_rows = model_cfg.num_conv_layers > 0
         self.state_model = model_cfg.num_state_layers > 0
+        # - pages of a SECOND pool: keys and values of the sliding-window
+        #   layers of a model that has full layers beside them, under an
+        #   allocator and a table of their own, trimmed behind the window
+        #   (``WindowPool``; a cached prefix keeps its TAIL there).
+        self.window_model = model_cfg.num_swa_layers > 0
         # Is a sequence's cached state its (k, v) pages and nothing else?
         # The wire, the host tier and a peer's import carry (k, v)
         # blocks, and a page that arrived without its tail, or a sequence
@@ -237,17 +248,21 @@ class Engine:
         # pages OUT of or INTO the pools is refused for a model that
         # keeps anything else (ROADMAP.md Reach A1), here, once, and at
         # each door.
-        self.pages_only = not (self.page_rows or self.state_model)
+        self.keeps_state = self.page_rows or self.state_model
+        self.pages_only = not (self.keeps_state or self.window_model)
         if not self.pages_only:
             if mesh is not None:
                 raise ValueError(
                     "a model that keeps more than (k, v) pages (convolution "
-                    "tails, a state by slot) runs on one device: its "
+                    "tails, a state by slot, a second pool of window "
+                    "layers) runs on one device: its "
                     "per-kind weight stacks and its pools have no sharding "
                     "rules (parallel/sharding.py)")
             keeps = [what for what, on in (
                 ("a convolution tail beside each page", self.page_rows),
-                ("a matrix state by slot", self.state_model)) if on]
+                ("a matrix state by slot", self.state_model),
+                ("its window layers' keys and values in a second pool "
+                 "with a table of its own", self.window_model)) if on]
             logger.info(
                 "%s keeps %s%s: PD migration, host spill and cross-worker "
                 "block fetch are refused for it (pages move with (k, v) "
@@ -259,16 +274,20 @@ class Engine:
         # interleaver's quantum, ``_window_cap``): all of them, but for a
         # state model whole pages only, so that every window starts on a
         # page boundary (the plan's ``page_aligned``: the in-place
-        # prefill writer; and a window's chunks of the scan are pages).
+        # prefill writer; and a window's chunks of the scan are pages);
+        # and for a model with a second pool of window layers, whose
+        # prefill programs would else scatter into two pools.
+        whole_pages = self.state_model or self.window_model
         self._quantum_buckets = tuple(
             b for b in engine_cfg.prefill_buckets
-            if not self.state_model or b % engine_cfg.page_size == 0)
-        if self.state_model and (
+            if not whole_pages or b % engine_cfg.page_size == 0)
+        if whole_pages and (
                 not self._quantum_buckets or
                 engine_cfg.prefill_buckets[-1] % engine_cfg.page_size):
             raise ValueError(
                 f"prefill_buckets {engine_cfg.prefill_buckets}: a model "
-                f"with a matrix state by slot needs its largest bucket to "
+                f"with a matrix state by slot, or with a second pool of "
+                f"window layers, needs its largest bucket to "
                 f"be whole pages of {engine_cfg.page_size}")
         # Its slots follow from the batch: a state row a decode row (two
         # slots each) and as many snapshots under the prefix index.
@@ -276,6 +295,20 @@ class Engine:
         self._prefill_tail = _STATE_COLS if self.state_model else 0
         n_rows = engine_cfg.max_batch_size if self.state_model else 0
         n_snaps = n_rows if engine_cfg.enable_prefix_cache else 0
+        # The window pool follows from the batch too: a row's window and
+        # the page it grows into and the one it has not trimmed yet
+        # (W / page_size + 2), as many tails as rows (W / page_size pages
+        # each, shared with the rows that resumed at them), one prefill
+        # window's growth, and the null page.
+        self._tables = 2 if self.window_model else 1
+        w_tail = w_pages = n_tails = 0
+        if self.window_model:
+            n_tails = (engine_cfg.max_batch_size
+                       if engine_cfg.enable_prefix_cache else 0)
+            w_tail, w_pages = window_pool_pages(
+                model_cfg.sliding_window, engine_cfg.page_size,
+                engine_cfg.max_batch_size, n_tails,
+                engine_cfg.prefill_buckets[-1])
 
         # Weights and pools are BORN where they live: made under a jit
         # whose out_shardings is their final placement, each device makes
@@ -288,7 +321,7 @@ class Engine:
         def make_kv():
             return transformer.init_kv_cache(
                 model_cfg, engine_cfg.num_pages, engine_cfg.page_size, dtype,
-                state_slots=1 + 2 * n_rows + n_snaps)
+                state_slots=1 + 2 * n_rows + n_snaps, window_pages=w_pages)
 
         # A single-device engine pins the pools' layout at every jitted
         # step boundary (_build_step_programs) and creates them in that
@@ -359,6 +392,12 @@ class Engine:
         if self.state_model:
             self.prefix_cache.enable_snapshots(
                 SlotAllocator(2 * n_rows + 1, n_snaps))
+        # The window layers' pool (None: the model has none). A prefix
+        # match ends at a boundary whose tail of window pages is live.
+        self.window: Optional[WindowPool] = None
+        if self.window_model:
+            self.window = WindowPool(w_pages, w_tail, n_tails)
+            self.prefix_cache.enable_tails(self.window)
         # Tiered spill (docs/KV_CACHE.md): prefix pages evicted from HBM
         # under allocation pressure park in a bounded host-DRAM tier
         # (optional disk tier behind it) instead of vanishing; a later
@@ -388,12 +427,15 @@ class Engine:
         # [1]=pos, [2]=active, [3:]=page table. The named views below keep
         # the update sites readable.
         B, MP = engine_cfg.max_batch_size, engine_cfg.max_pages_per_seq
-        self._slot_packed = np.zeros((B, _PACK_COLS + MP), np.int32)
+        self._slot_packed = np.zeros((B, _PACK_COLS + MP * self._tables),
+                                     np.int32)
         self._slot_last_token = self._slot_packed[:, 0]
         self._slot_pos = self._slot_packed[:, 1]
         self._slot_active = self._slot_packed[:, 2]
         self._slot_rope_delta = self._slot_packed[:, 3]
-        self._slot_pt = self._slot_packed[:, _PACK_COLS:]
+        self._slot_pt = self._slot_packed[:, _PACK_COLS:_PACK_COLS + MP]
+        # ... and the window pool's table behind it (no columns without)
+        self._slot_wpt = self._slot_packed[:, _PACK_COLS + MP:]
         # mrope models ship explicit 3-D rope positions at prefill and a
         # per-slot rope delta at decode (trace-time switch; cfg static).
         self._mrope = model_cfg.is_mrope
@@ -450,6 +492,12 @@ class Engine:
                      f"{op}_decode "
                      f"{'pallas' if self.plan.ssm_decode else 'xla'}; "
                      f"{n_rows} state rows x 2 + {n_snaps} snapshots")
+        if self.window_model:
+            fold += (f"; two pools: the {model_cfg.num_swa_layers} window "
+                     f"layers walk {self._decode_walk(MP)} columns of "
+                     f"their own table, the {model_cfg.num_attn_layers} "
+                     f"full layers all {MP} of theirs; window pool "
+                     f"{w_pages} pages, {n_tails} tails of {w_tail}")
         logger.info("engine plan: %s; decode walk %d of %d columns%s%s",
                     self.plan, self._decode_walk(MP), MP,
                     "; layer kinds " + ", ".join(
@@ -460,9 +508,12 @@ class Engine:
         # holds all of it in its states by slot.
         logger.info("engine pools: %s", ", ".join(
             f"{name} {sum(int(x.nbytes) for x in pools) / 1e9:.2f} GB"
-            for name, pools in (("(k, v)", self.kv[:2]),
-                                ("tails", self.kv[2:3]),
-                                ("states", self.kv[3:])) if pools))
+            for name, pools in (
+                ("(k, v)", self.kv[:2]), ("tails", self.kv[2:3]),
+                ("states", self.kv[3:len(self.kv)
+                                   - (2 if self.window_model else 0)]),
+                ("window (k, v)", self.kv[-2:] if self.window_model
+                 else ())) if pools))
         if self.plan.uses_kernels:
             # The kernels are loaded here (1.2-1.6 s of
             # jax.experimental.pallas), where an engine is built, and
@@ -959,18 +1010,34 @@ class Engine:
             # prompt_logprobs sequences skip hits too: cached positions
             # would never be scored.
             cached_pages, cached_tokens = [], 0
+        tail: List[int] = []
+        if self.window is not None and cached_pages:
+            # The row resumes at the matched boundary's tail: it reads
+            # those pages (a reference each, taken BEFORE its own pages
+            # are asked for: a short window pool makes room by evicting
+            # tails nobody holds) and writes pages of its own.
+            tail = self.window.tail_of(cached_pages[-1])
+            with steptrace.span("xllm.kv.window_tail", event="restore",
+                                pages=len(tail)):
+                self.window.acquire(tail)
         window = self._next_window(seq, cached_tokens)
         final = cached_tokens + window >= len(seq.tokens)
         covered = cached_tokens + window + (1 if final else 0)
         need = self._pages_needed(covered) - len(cached_pages)
-        new_pages = self.prefix_cache.alloc(max(need, 0))
+        new_pages = self._alloc_pages(max(need, 0))
         while new_pages is None and not seq.req.offline and \
                 self._preempt_one_offline():
-            new_pages = self.prefix_cache.alloc(max(need, 0))
+            new_pages = self._alloc_pages(max(need, 0))
         if new_pages is None:
             self.prefix_cache.release_pages(cached_pages)
+            if tail:
+                self.window.release(tail)
             return False
+        new_pages, new_w = new_pages
         seq.pages = list(cached_pages) + new_pages
+        if self.window is not None:
+            seq.num_trimmed = len(cached_pages) - len(tail)
+            seq.wpages = [0] * seq.num_trimmed + list(tail) + new_w
         seq.num_computed = cached_tokens
         seq.num_cached_tokens = cached_tokens
         # Count only ADMITTED lookups: a page-pressure refusal leaves
@@ -980,7 +1047,7 @@ class Engine:
         if seq.req.mm_embeds is None and not seq.req.prompt_logprobs:
             self.prefix_lookups += 1
             self.prefix_hit_tokens += cached_tokens
-        if not self.pages_only:
+        if self.keeps_state:
             # Its first window reads the tails that whoever wrote the
             # last cached page left in that page's row.
             self.state_rows_restored += cached_tokens > 0
@@ -1065,28 +1132,42 @@ class Engine:
                 and n / max(self._sp, 1) < n - cached_tokens)
 
     def _swa_trim(self, seq: Sequence) -> None:
-        """Uniform-sliding-window models: free leading pages whose every
-        position sits below all future attention windows (positions <
-        num_computed − W can never be attended again — the window mask
-        discards them, so HBM need not hold them). Bounds per-sequence KV
-        to O(W) regardless of generated length. Freed table entries
-        become NULL pages; stale device-side reads of a recycled page are
-        confined to window-masked lanes. Skipped for per-layer window
-        mixes (full-attention layers still need the whole history) and
-        for PD-held prefills (export ships the full prefix)."""
+        """Free the leading pages whose every position sits below all
+        future attention windows (positions < num_computed − W can never
+        be attended again — the window mask discards them, so HBM need
+        not hold them): ``seq.pages`` of a uniform-window model, and
+        ``seq.wpages``, the window pool's table ALONE, of a model whose
+        window layers have a pool of their own (its full table is never
+        trimmed; a page a tail still holds stays with the tail). Called
+        after every prefill window and every decode step, so a row holds
+        O(W) of the trimmed pool whatever its length. Freed table
+        entries become NULL pages; stale device-side reads of a recycled
+        page are confined to window-masked lanes. Skipped for the dense
+        scanned families' per-layer window mixes (``layer_sliding``: one
+        pool, whose full-attention layers still need the whole history)
+        and for PD-held prefills (export ships the full prefix)."""
         W = self.cfg.sliding_window
         if not W or self.cfg.layer_sliding is not None \
                 or seq.req.hold_after_finish:
             return
+        pages = seq.pages if self.window is None else seq.wpages
         bound = min((seq.num_computed - W) // self.ecfg.page_size,
-                    len(seq.pages))
+                    len(pages))
         if bound <= seq.num_trimmed:
             return
-        for i in range(seq.num_trimmed, bound):
-            pid = seq.pages[i]
-            if pid:
-                self.prefix_cache.release_pages([pid])
-                seq.pages[i] = 0
+        if self.window is not None:
+            with steptrace.span("xllm.kv.window_trim",
+                                pages=bound - seq.num_trimmed):
+                self.window.release(
+                    [p for p in pages[seq.num_trimmed:bound] if p])
+                self.window.pages_trimmed += bound - seq.num_trimmed
+            pages[seq.num_trimmed:bound] = [0] * (bound - seq.num_trimmed)
+        else:
+            for i in range(seq.num_trimmed, bound):
+                pid = pages[i]
+                if pid:
+                    self.prefix_cache.release_pages([pid])
+                    pages[i] = 0
         seq.num_trimmed = bound
         self._sync_slot(seq)
 
@@ -1107,6 +1188,7 @@ class Engine:
         self._register_pages(seq)
         self.prefix_cache.release_pages([p for p in seq.pages if p])
         seq.pages = []
+        self._release_window(seq)
         seq.num_trimmed = 0
         seq.num_computed = 0
         seq.sched_window = 0
@@ -1133,7 +1215,7 @@ class Engine:
         need = self._pages_needed(covered) - len(seq.pages)
         if need <= 0:
             return True
-        pages = self.prefix_cache.alloc(need)
+        pages = self._alloc_pages(need)
         while pages is None:
             victims = [s for s in self.running
                        if s.req.offline and s is not seq]
@@ -1145,10 +1227,52 @@ class Engine:
             else:
                 self._preempt_seq(seq)
                 return False
-            pages = self.prefix_cache.alloc(need)
-        seq.pages.extend(pages)
+            pages = self._alloc_pages(need)
+        seq.pages.extend(pages[0])
+        seq.wpages.extend(pages[1])
         self._sync_slot(seq)
         return True
+
+    def _alloc_pages(self, n: int
+                     ) -> Optional[Tuple[List[int], List[int]]]:
+        """``n`` pages for a row's next ``n`` logical pages: ``(pages,
+        window pages)``, the second empty for a model without a window
+        pool; None, and nothing held, where either pool is short. The
+        window pool is asked first: where it is the short one (the
+        smaller by far) the full pool's cached pages stay."""
+        wpages: List[int] = []
+        if self.window is not None:
+            wpages = self.window.alloc(n)
+            if wpages is None:
+                return None
+        pages = self.prefix_cache.alloc(n)
+        if pages is None:
+            if self.window is not None:
+                self.window.release(wpages)
+            return None
+        return pages, wpages
+
+    def _release_window(self, seq: Sequence) -> None:
+        """``seq`` lets go of its window pages (those a tail holds too
+        stay with the tail)."""
+        if self.window is not None:
+            self.window.release([p for p in seq.wpages if p])
+        seq.wpages = []
+
+    def _attach_tail(self, seq: Sequence) -> None:
+        """``seq``'s prompt has just been prefilled whole and its full
+        pages are registered: the deepest full-page boundary keeps the
+        window pages that end there as its tail (those its last window
+        has not trimmed: the boundary lies within W of the prompt's
+        end), so that a later request resumes there."""
+        ps = self.ecfg.page_size
+        b = seq.num_computed // ps
+        if self.window is None or not b or not self.window.max_tails \
+                or seq.req.mm_embeds is not None:
+            return
+        first = max(b - self.window.tail_pages, 0)
+        self.prefix_cache.attach_tail(seq.page_digests, b,
+                                      seq.wpages[first:b])
 
     def _release_seq_slot(self, seq: Sequence) -> None:
         if seq.state_row:
@@ -1184,6 +1308,7 @@ class Engine:
         else:
             self.prefix_cache.release_pages([p for p in seq.pages if p])
             seq.pages = []
+        self._release_window(seq)
         self._by_id.pop(seq.req.request_id, None)
         self._cancelled.discard(seq.req.request_id)
 
@@ -1258,6 +1383,9 @@ class Engine:
             return False
         grow = sum(max(self._pages_needed(len(s.tokens)) - len(s.pages), 0)
                    for s in self.running)
+        if self.window is not None \
+                and grow > self.window.allocator.num_free:
+            return False
         return grow <= (self.allocator.num_free
                         + self.prefix_cache.num_reclaimable)
 
@@ -1621,7 +1749,8 @@ class Engine:
             # One packed transfer: [start, len, tokens…, page table…]
             # (and a state model's four slot columns behind them).
             packed = np.zeros(
-                (B, _PREFILL_HDR + T + MP + self._prefill_tail), np.int32)
+                (B, _PREFILL_HDR + T + MP * self._tables
+                 + self._prefill_tail), np.int32)
             for i, seq in enumerate(batch):
                 new = seq.tokens[seq.num_computed:
                                  seq.num_computed + windows[i]]
@@ -1639,6 +1768,11 @@ class Engine:
                         - seq.num_computed // ps + 1)
                 packed[i, _PREFILL_HDR + T:
                        _PREFILL_HDR + T + len(seq.pages)] = seq.pages
+                if self.window is not None:
+                    # the window pool's table, behind the full one
+                    packed[i, _PREFILL_HDR + T + MP:
+                           _PREFILL_HDR + T + MP + len(seq.wpages)] = \
+                        seq.wpages
             st_f32, st_i32 = self._sampling_tensors(
                 [s.req.sampling for s in batch], B)
             bias_ids, bias_vals = self._batch_bias(
@@ -1746,6 +1880,11 @@ class Engine:
                 seq.num_computed = len(seq.tokens)
                 seq.first_token_time = now
                 self.running.append(seq)
+                if self.window is not None:
+                    # before the first token is appended: that may finish
+                    # the row and release its pages
+                    self._register_pages(seq)
+                    self._attach_tail(seq)
                 tok = int(next_tok[i])
                 out = self._append_token(
                     seq, tok, float(logprob[i]),
@@ -1870,7 +2009,7 @@ class Engine:
         max_pages_per_seq pages), so its table is clamped like
         ``_table_width``: at 96 pages a sequence, a 10k-token document
         is gathered over 96 columns and not 128."""
-        if not self.page_bytes:
+        if not self.page_bytes or self.window_model:
             return self.ecfg.max_pages_per_seq
         mp = 1 << max(pages - 1, 0).bit_length()
         if self.plan.write_then_attend:
@@ -1884,8 +2023,14 @@ class Engine:
         makes every short-context batch pay long-context prices. Where a
         page holds no byte (``page_bytes``) no program reads the table
         and a width costs nothing but a compiled program each: ONE width,
-        the whole table's, for its prefill programs too."""
-        if not self.page_bytes:
+        the whole table's, for its prefill programs too. ONE width too
+        for a model with a second pool of window layers: its block holds
+        two tables, a window layer's kernel walks the window's columns
+        whatever the width, a full layer's names a dead column's page as
+        the one before it (no copy), and every width less is a program
+        less to compile at every start (its full layers' prefill on the
+        XLA reference does gather the whole table: docs/KV_CACHE.md)."""
+        if not self.page_bytes or self.window_model:
             return self.ecfg.max_pages_per_seq
         mp = max((len(s.pages) for s in self.running), default=1)
         mp = 1 << max(mp - 1, 0).bit_length()
@@ -2003,7 +2148,7 @@ class Engine:
             # and position, by values the device computed itself). Any
             # other change (admit, finish, page growth, trim, import,
             # another width) compares unequal and uploads the block whole.
-            block = self._slot_packed[:, :_PACK_COLS + mp]
+            block = self._slot_block(mp)
             carry, self._decode_carry = self._decode_carry, None
             if carry is not None and _same_block(carry[1], block):
                 packed, mirror = carry
@@ -2027,6 +2172,16 @@ class Engine:
             self._phase("decode.dispatch", **self._decode_shape(mp)),
             packed, mirror)
 
+    def _slot_block(self, mp: int) -> np.ndarray:
+        """The decode block at table width ``mp`` from the slot array:
+        the header and the first ``mp`` columns of the page table (a
+        view), and behind them the first ``mp`` of the window pool's
+        table for a model that has one (a copy)."""
+        block = self._slot_packed[:, :_PACK_COLS + mp]
+        if self.window is None:
+            return block
+        return np.concatenate([block, self._slot_wpt[:, :mp]], axis=1)
+
     def _decode_shape(self, mp: int) -> Dict[str, Any]:
         """The shape key a decode launch's span carries."""
         return dict(program="decode", B=self.ecfg.max_batch_size, T=1,
@@ -2045,7 +2200,7 @@ class Engine:
         hands the first half back: the values of a host-side split, with
         no program of its own between two steps; the key it was given
         rides along for a discard."""
-        mp = mirror.shape[1] - _PACK_COLS
+        mp = (mirror.shape[1] - _PACK_COLS) // self._tables
         key_before = self._rng_key
         cache_before = self._jit_cache_size(self._jit_decode)
         with bracket:
@@ -2077,12 +2232,11 @@ class Engine:
         same rows active, same tables. Nothing is allocated and nothing
         on the host moves, so a discard has nothing to undo."""
         mirror = step["mirror"]
-        mp = mirror.shape[1] - _PACK_COLS
+        mp = (mirror.shape[1] - _PACK_COLS) // self._tables
         if not self._ahead_eligible() or mp != self._table_width():
             return None
         self._fill_slots()
-        if not _same_block(mirror[:, 2:],
-                           self._slot_packed[:, 2:_PACK_COLS + mp]):
+        if not _same_block(mirror[:, 2:], self._slot_block(mp)[:, 2:]):
             return None
         # It is handed the block the step before it leaves on the device
         # (counted where the block is chosen, as ``_dispatch_decode``
@@ -2280,6 +2434,7 @@ class Engine:
             self._release_seq_slot(seq)
             self.prefix_cache.release_pages([p for p in seq.pages if p])
             seq.pages = []
+            self._release_window(seq)
             seq.num_trimmed = 0
             seq.num_computed = 0
             seq.sched_window = 0
@@ -2440,6 +2595,9 @@ class Engine:
                                     else seq.req.rope_delta)
         self._slot_pt[i] = 0
         self._slot_pt[i, :len(seq.pages)] = seq.pages
+        if self.window is not None:
+            self._slot_wpt[i] = 0
+            self._slot_wpt[i, :len(seq.wpages)] = seq.wpages
 
     @staticmethod
     def _sampling_tensors(params: Sequence[SamplingParams],
@@ -2804,7 +2962,7 @@ class Engine:
     def state_stats(self) -> Optional[Dict[str, int]]:
         """The xllm_worker_state_* series source; None for a model whose
         cached state is its pages alone."""
-        if self.pages_only:
+        if not self.keeps_state:
             return None
         out = {"restored": self.state_rows_restored,
                "written": self.state_rows_written,
@@ -2823,6 +2981,19 @@ class Engine:
                 free=pc.snapshot_slots.num_free,
                 snapshotted=pc.snapshots_taken, evicted=pc.snapshots_evicted)
         return out
+
+    def window_stats(self) -> Optional[Dict[str, int]]:
+        """The xllm_worker_kv_window_* series' source; None for a model
+        without a window pool."""
+        w = self.window
+        if w is None:
+            return None
+        return {"pages": w.num_pages - 1, "live": w.pages_live,
+                "peak": w.pages_peak, "trimmed": w.pages_trimmed,
+                "tails": w.num_tails, "taken": w.tails_taken,
+                "hits": w.tail_hits, "misses": w.tail_misses,
+                "evicted": w.tail_evictions,
+                "pool_bytes": sum(int(x.nbytes) for x in self.kv[-2:])}
 
     # ------------------------------------------------------------------
     # Warmup / metrics
@@ -2951,8 +3122,8 @@ class Engine:
                        if self._mrope else None)
             out = launch(
                 self._jit_prefill, self.params,
-                jnp.zeros((B, _PREFILL_HDR + T + mp + self._prefill_tail),
-                          jnp.int32),
+                jnp.zeros((B, _PREFILL_HDR + T + mp * self._tables
+                           + self._prefill_tail), jnp.int32),
                 self.kv, st_f32, st_i32, key, None, None, None,
                 b_ids, b_vals, warm_rp, T)
             if out is not None:
@@ -2964,7 +3135,7 @@ class Engine:
         b_ids, b_vals = self._batch_bias([], Bmax, self.cfg.vocab_size)
         for mp in widths:
             packed = jax.device_put(
-                np.zeros((Bmax, _PACK_COLS + mp), np.int32),
+                np.zeros((Bmax, _PACK_COLS + mp * self._tables), np.int32),
                 self._carry_place)
             out = launch(self._jit_decode, self.params, packed,
                          self.kv, st_f32, st_i32, key, None, b_ids,
@@ -3070,6 +3241,18 @@ class Engine:
 # ---------------------------------------------------------------------------
 # Compiled step bodies (sampling fused in; only token ids leave the device)
 # ---------------------------------------------------------------------------
+
+def window_pool_pages(window: int, page_size: int, rows: int, tails: int,
+                      bucket: int) -> Tuple[int, int]:
+    """``(pages a tail, pages of the pool)`` of the window layers' pool
+    of an engine of ``rows`` rows: a row's window, the page it grows
+    into and the one it has not trimmed yet; ``tails`` tails of a whole
+    window each; the growth of one prefill window of ``bucket`` tokens
+    (and the page it starts inside); the null page."""
+    tail = -(-window // page_size)
+    return tail, (rows * (tail + 2) + tails * tail
+                  + -(-bucket // page_size) + 1 + 1)
+
 
 def row_major_format(ndim: int, sharding) -> Format:
     """Default major-to-minor layout on ``sharding`` — the layout the

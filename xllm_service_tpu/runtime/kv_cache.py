@@ -298,6 +298,154 @@ class SlotAllocator:
         self._free.append(slot)
 
 
+class WindowPool:
+    """Host bookkeeping of the WINDOW layers' pool (a model whose
+    sliding-window layers keep their keys and values apart from its full
+    layers': ``models.init_kv_cache``'s last pair): pages by reference
+    count, held by ROWS (the pages a row's window can reach; trimmed
+    behind it as the row advances) and by TAILS.
+
+    A tail is what a cached prefix keeps of the window layers: the
+    ``tail_pages`` whole window pages that END at a full-page boundary
+    of a finished prefill, keyed by the FULL pool's page at that
+    boundary. A request that matches the boundary takes references to
+    the tail's pages (it only ever reads them: its own positions land in
+    pages of its own) and resumes there; a boundary without a live tail
+    cannot be resumed at, because a window layer's rows could only be
+    recomputed from every layer's rows before them. Tails are evicted
+    when the pool runs short, the least recently HIT first (never-hit
+    ones oldest first, then by last hit), and never while a row holds
+    one of their pages; where ``max_tails`` are held a new tail takes a
+    never-hit one's place or is not kept (a request's own prompt never
+    pushes out a document's). Engine-internal state under
+    the worker's engine lock, like the index it hangs off."""
+
+    def __init__(self, num_pages: int, tail_pages: int,
+                 max_tails: int) -> None:
+        self.allocator = PageAllocator(num_pages)
+        self.tail_pages = tail_pages
+        self.max_tails = max_tails
+        self._ref: Dict[int, int] = collections.defaultdict(int)
+        self._tail_ref: Dict[int, int] = collections.defaultdict(int)
+        # full-pool page id at the boundary -> (window pages, the digests
+        # of the full pages up to the boundary)
+        self._tails: Dict[int, Tuple[List[int], List[bytes]]] = {}
+        self._unhit: "collections.OrderedDict[int, None]" = \
+            collections.OrderedDict()
+        self._hit: "collections.OrderedDict[int, None]" = \
+            collections.OrderedDict()
+        # told the digests of a tail that goes (the index retracts what
+        # it advertised for them)
+        self.on_drop: Optional[Callable[[List[bytes]], None]] = None
+        self.pages_peak = 0
+        self.pages_trimmed = 0
+        self.tails_taken = 0
+        self.tail_hits = 0
+        self.tail_misses = 0
+        self.tail_evictions = 0
+
+    # -- pages ------------------------------------------------------------
+    @property
+    def num_pages(self) -> int:
+        return self.allocator.num_pages
+
+    @property
+    def pages_live(self) -> int:
+        """Pages some row or tail holds (the null page is nobody's)."""
+        return self.allocator.num_pages - 1 - self.allocator.num_free
+
+    @property
+    def num_tails(self) -> int:
+        return len(self._tails)
+
+    def alloc(self, n: int) -> Optional[List[int]]:
+        """``n`` pages for a row, tails nobody holds making room where
+        the pool is short; None where it stays short."""
+        while n > self.allocator.num_free and self._evict_one():
+            pass
+        pages = self.allocator.alloc(n)
+        if pages is not None:
+            for pid in pages:
+                self._ref[pid] += 1
+            self.pages_peak = max(self.pages_peak, self.pages_live)
+        return pages
+
+    def acquire(self, pages: Sequence[int]) -> None:
+        for pid in pages:
+            self._ref[pid] += 1
+
+    def release(self, pages: Sequence[int]) -> None:
+        for pid in pages:
+            self._ref[pid] -= 1
+            if self._ref[pid] <= 0:
+                del self._ref[pid]
+                self.allocator.free([pid])
+
+    # -- tails ------------------------------------------------------------
+    def tail_of(self, pid: int) -> Optional[List[int]]:
+        t = self._tails.get(pid)
+        return t[0] if t is not None else None
+
+    def note_hit(self, pid: int) -> None:
+        self.tail_hits += 1
+        self._unhit.pop(pid, None)
+        self._hit[pid] = None
+        self._hit.move_to_end(pid)
+
+    def attach(self, pid: int, wpages: Sequence[int],
+               digests: Sequence[bytes]) -> bool:
+        """The full pool's page ``pid`` ends a finished prefill at a page
+        boundary and ``wpages`` are the window pages that end there: the
+        tail takes a reference to each. False where the page has one
+        already, or ``max_tails`` are held and none of the never-hit
+        ones can go (a row reads each): a new tail has never been hit
+        itself, so it does not push out one that has."""
+        if pid in self._tails or not wpages or not all(wpages):
+            return False
+        while len(self._tails) >= self.max_tails:
+            if not self._evict_one(hit_too=False):
+                return False
+        for w in wpages:
+            self._ref[w] += 1
+            self._tail_ref[w] += 1
+        self._tails[pid] = (list(wpages), list(digests))
+        self._unhit[pid] = None
+        self.tails_taken += 1
+        return True
+
+    def drop(self, pid: int) -> None:
+        """The tail of ``pid`` goes (evicted here, or its page's content
+        reclaimed in the full pool)."""
+        t = self._tails.pop(pid, None)
+        if t is None:
+            return
+        self._unhit.pop(pid, None)
+        self._hit.pop(pid, None)
+        for w in t[0]:
+            self._tail_ref[w] -= 1
+            if self._tail_ref[w] <= 0:
+                del self._tail_ref[w]
+        self.release(t[0])
+        self.tail_evictions += 1
+        if self.on_drop is not None:
+            self.on_drop(t[1])
+
+    def _evict_one(self, hit_too: bool = True) -> bool:
+        """Drop the least recently hit tail none of whose pages a row
+        holds (a page's holders are then all tails); of the never-hit
+        ones alone where ``hit_too`` is false."""
+        for order in (self._unhit, self._hit) if hit_too \
+                else (self._unhit,):
+            for pid in order:
+                if all(self._ref[w] == self._tail_ref[w]
+                       for w in self._tails[pid][0]):
+                    with steptrace.span("xllm.kv.window_tail",
+                                        event="evict"):
+                        self.drop(pid)
+                    return True
+        return False
+
+
 class PrefixCacheIndex:
     """Content-addressed index of *full* pages + LRU reclamation.
 
@@ -354,6 +502,13 @@ class PrefixCacheIndex:
             collections.OrderedDict()           # guarded-by: worker.engine
         self.snapshots_taken = 0
         self.snapshots_evicted = 0
+        # A model whose window layers keep their keys and values in a
+        # pool of their own (``enable_tails``): a prefix match ends at
+        # the deepest boundary whose TAIL of window pages is live, and
+        # the blocks told to the cluster's index are those under such a
+        # boundary (digest -> how many live tails lie at or past it).
+        self.tails: Optional[WindowPool] = None
+        self._advertised: Dict[bytes, int] = {}  # guarded-by: worker.engine
 
     # -- hashing ----------------------------------------------------------
     def block_hashes(self, tokens: Sequence[int]) -> List[bytes]:
@@ -416,6 +571,15 @@ class PrefixCacheIndex:
                 self._snapshots_unhit.pop(pages[-1], None)
                 self._snapshots_hit[pages[-1]] = None
                 self._snapshots_hit.move_to_end(pages[-1])
+        if self.tails is not None and pages:
+            # The deepest matched boundary whose tail is live and nothing
+            # deeper: no partial credit for pages past it.
+            if self.tails.tail_of(pages[-1]) is None:
+                self.tails.tail_misses += 1
+                while pages and self.tails.tail_of(pages[-1]) is None:
+                    pages = pages[:-1]
+            if pages:
+                self.tails.note_hit(pages[-1])
         for pid in pages:
             self._acquire(pid)
         return pages, len(pages) * self.page_size
@@ -464,6 +628,46 @@ class PrefixCacheIndex:
         self.snapshots_taken += 1
         return True
 
+    # -- tails of a pool of window layers ---------------------------------
+    def enable_tails(self, pool: WindowPool) -> None:
+        """From now on a match ends at a boundary that has a tail, and
+        the cluster is told of the blocks under one alone."""
+        self.tails = pool
+        pool.on_drop = self._retract
+
+    def attach_tail(self, digests: Sequence[bytes], boundary: int,
+                    wpages: Sequence[int]) -> bool:
+        """A prefill has finished, the first ``boundary`` full pages of
+        its tokens are registered (``register_pages``) and ``wpages`` are
+        the window pool's pages that end there: the page that owns the
+        boundary's content now has the tail, and the blocks up to it are
+        told to the cluster (a request can resume there)."""
+        pid = self._by_hash.get(digests[boundary - 1]) if boundary else None
+        if pid is None:
+            return False
+        chain = list(digests[:boundary])
+        with steptrace.span("xllm.kv.window_tail", event="attach",
+                            pages=len(wpages)):
+            if not self.tails.attach(pid, wpages, chain):
+                return False
+        for h in chain:
+            n = self._advertised.get(h, 0)
+            self._advertised[h] = n + 1
+            if not n:
+                self._pending_event.stored.append(h)
+        return True
+
+    def _retract(self, chain: Sequence[bytes]) -> None:
+        for h in chain:
+            n = self._advertised.get(h)
+            if n is None:
+                continue            # its page went first (_evict_mapping)
+            if n > 1:
+                self._advertised[h] = n - 1
+            else:
+                del self._advertised[h]
+                self._pending_event.removed.append(h)
+
     def _drop_snapshot(self, pid: int) -> None:
         slot = self._snapshot_of.pop(pid, None)
         if slot is not None:
@@ -485,13 +689,22 @@ class PrefixCacheIndex:
         chained hash. ``pages[i]`` holds tokens [i*ps, (i+1)*ps);
         ``digests`` is the sequence's chain (``extend_digests``), so a
         page is hashed once, when it fills. Safe to call repeatedly as a
-        sequence grows, and again on new pages after a preemption."""
+        sequence grows, and again on new pages after a preemption.
+        ``pages`` is the FULL pool's table: under a uniform window the
+        engine trims it, and a sequence whose first page is trimmed
+        registers nothing more (below); where the window layers have a
+        pool of their own it is never trimmed, every full page is
+        registered, and what makes a boundary resumable is its TAIL
+        (``attach_tail``), with which the cluster is told of it."""
         if not self.enable:
             return
         if pages and not pages[0]:
-            # Leading page already sliding-window-trimmed: nothing below
-            # is registrable (see the break below), so nothing is hashed
-            # or walked every decode step of a long SWA sequence.
+            # Leading page already trimmed behind a UNIFORM window:
+            # nothing below is registrable (see the break below), so
+            # nothing is hashed or walked every decode step of a long
+            # sequence. (Registration under a trimmed lead needs the
+            # tails' rule for a model with no full layer: ROADMAP.md
+            # Reach A2 (c).)
             return
         n_full = num_computed // self.page_size
         with steptrace.span(
@@ -517,7 +730,8 @@ class PrefixCacheIndex:
                 self._evict_mapping(pid)
                 self._by_hash[h] = pid
                 self._hash_of[pid] = h
-                self._pending_event.stored.append(h)
+                if self.tails is None:      # else: told with its tail
+                    self._pending_event.stored.append(h)
 
     # -- refcounting ------------------------------------------------------
     def _acquire(self, pid: int) -> None:
@@ -567,6 +781,10 @@ class PrefixCacheIndex:
             return
         self._drop_snapshot(pid)    # the content goes: so does its state
         self._by_hash.pop(h, None)
+        if self.tails is not None:
+            self.tails.drop(pid)        # ... and the tail that ends there
+            if self._advertised.pop(h, None) is None:
+                return                  # the cluster was never told of it
         if spillable and self.spill_hook is not None:
             try:
                 if self.spill_hook(h, pid):

@@ -662,14 +662,16 @@ class Worker:
             self.opts.instance_type = InstanceType.ENCODE
         self.runtimes: Dict[str, ModelRuntime] = {}
         primary_cfg = resolve_model_config(opts.model, opts.model_dir)
-        if (primary_cfg.num_conv_layers or primary_cfg.num_state_layers) \
+        if (primary_cfg.num_conv_layers or primary_cfg.num_state_layers
+                or primary_cfg.num_swa_layers) \
                 and self.instance_type in (InstanceType.PREFILL,
                                            InstanceType.DECODE):
             # Its pages do not move between workers (Engine.pages_only):
             # a disaggregated role could only ever fall back.
             raise ValueError(
                 f"{opts.model} keeps more than (k, v) pages (convolution "
-                f"tails that ride the page table, a state by slot): "
+                f"tails that ride the page table, a state by slot, window "
+                f"layers' keys and values in a second pool): "
                 f"instance type {self.instance_type.value} (PD migration) "
                 f"is refused; serve it as DEFAULT or MIX")
         # Encode-only mode: the LM runtime starts asleep — engine=None,
@@ -1674,8 +1676,10 @@ class Worker:
             "xllm_worker_state_pool_bytes",
             labelnames=("model",)).set(
             sum(int(x.nbytes) for x in eng.kv[:2]), model=m)
-        if not eng.pages_only:
+        if eng.keeps_state:
             self._flush_state(rt)
+        if eng.window_model:
+            self._flush_window(rt)
         if eng.cfg.looped:
             self._flush_loop(rt)
         tok = self.obs.counter(
@@ -1792,8 +1796,8 @@ class Worker:
             compiled=tuple(eng.last_step_compiled),
             moe=_moe_record(eng.last_step_moe),
             passes=passes, exit_cdf=exit_cdf,
-            state_restored=(None if eng.pages_only
-                            else tuple(eng.last_step_state_restored)),
+            state_restored=(tuple(eng.last_step_state_restored)
+                            if eng.keeps_state else None),
             state=state)
 
     def _flush_moe(self, rt: ModelRuntime) -> None:
@@ -1859,6 +1863,47 @@ class Worker:
                 g.set(state["live"], model=m, kind="live")
                 g.set(state["snapshots"], model=m, kind="snapshot")
                 g.set(state["free"], model=m, kind="free")
+
+    def _flush_window(self, rt: ModelRuntime) -> None:
+        """The ledger of the window layers' pool and of the tails the
+        prefix index keeps there (``Engine.window_stats``)."""
+        w, m = rt.engine.window_stats(), rt.model
+        g = self.obs.gauge(
+            "xllm_worker_kv_window_pages",
+            "pages of the window layers' pool (the second pair of pools "
+            "of a model with sliding-window layers beside full ones) by "
+            "kind: size = pages a row or a tail can hold, live = held "
+            "now, peak = the most held at once",
+            labelnames=("model", "kind"))
+        g.set(w["pages"], model=m, kind="size")
+        g.set(w["live"], model=m, kind="live")
+        g.set(w["peak"], model=m, kind="peak")
+        self.obs.gauge(
+            "xllm_worker_kv_window_pool_bytes",
+            "bytes of the window layers' pair of pools",
+            labelnames=("model",)).set(w["pool_bytes"], model=m)
+        self.obs.counter(
+            "xllm_worker_kv_window_trimmed_pages_total",
+            "window pages rows let go of behind their window as they "
+            "advanced (prefill windows and decode steps alike)",
+            labelnames=("model",)).set_total(w["trimmed"], model=m)
+        self.obs.gauge(
+            "xllm_worker_kv_window_tails",
+            "tails held: cached prefixes whose last window of pages the "
+            "prefix index keeps, so that a request can resume there",
+            labelnames=("model",)).set(w["tails"], model=m)
+        c = self.obs.counter(
+            "xllm_worker_kv_window_tail_events_total",
+            "tails by event: taken = attached to a finished prefill's "
+            "deepest page boundary, hit = a request resumed at one, miss "
+            "= the deepest matched boundary had no live tail (the row "
+            "recomputed from a shallower one or from nothing), evicted = "
+            "dropped for room or with its page",
+            labelnames=("model", "event"))
+        c.set_total(w["taken"], model=m, event="taken")
+        c.set_total(w["hits"], model=m, event="hit")
+        c.set_total(w["misses"], model=m, event="miss")
+        c.set_total(w["evicted"], model=m, event="evicted")
 
     def _flush_loop(self, rt: ModelRuntime) -> None:
         """What a looped model's step programs counted on the device
