@@ -211,7 +211,7 @@ def test_engine_phase_builds_no_annotation_while_off(counted):
     # dispatch carries the shape key the program was launched with
     steptrace.set_spans(True)
     eng.add_request(EngineRequest(
-        request_id="r1", token_ids=list(range(3, 20)),
+        request_id="r1", token_ids=list(range(43, 60)),
         sampling=SamplingParams(max_tokens=3, temperature=0.0,
                                 ignore_eos=True)))
     while eng.has_work():
@@ -231,6 +231,57 @@ def test_engine_phase_builds_no_annotation_while_off(counted):
     assert args == {"program": "decode", "B": 2, "T": 1, "MP": args["MP"],
                     "walk": args["MP"], "fold": 1, "flat": 1}
     assert dict(counted)["xllm.kv.match_prefix"] == {"tokens": 17}
+    # the prompt's one full page, registered with the first token (its
+    # digest is the admission's: nothing hashed); the two tokens after
+    # it fill no page and open no span
+    assert [a for n, a in counted if n == "xllm.kv.register_pages"] \
+        == [{"tokens": 0, "pages": 1}]
+
+
+def test_a_decode_step_in_which_no_page_fills_registers_nothing(counted):
+    """Eight rows of six full pages each: the per-token registration
+    starts at a row's watermark, so a step in which no row's page fills
+    opens no ``xllm.kv.register_pages`` span and walks no page, a step
+    in which one row's fills opens one with ``pages`` 1 (and the 16
+    tokens of that page hashed), and the engine's total of walked pages
+    reads the spans' sum."""
+    from xllm_service_tpu.config import EngineConfig, ModelConfig
+    from xllm_service_tpu.runtime.engine import Engine, EngineRequest
+    from xllm_service_tpu.utils.types import SamplingParams
+    ps = 16
+    eng = Engine(ModelConfig.tiny(vocab_size=256), EngineConfig(
+        page_size=ps, num_pages=96, max_model_len=256, max_batch_size=8,
+        prefill_buckets=(128,)))
+    for i in range(8):              # 6 pages and 1 to 15 tokens, by twos
+        eng.add_request(EngineRequest(
+            request_id=f"r{i}",
+            token_ids=[(i * 31 + j * 7) % 250 + 3
+                       for j in range(6 * ps + 1 + 2 * i)],
+            sampling=SamplingParams(max_tokens=48, temperature=0.0,
+                                    ignore_eos=True)))
+    while eng.waiting or len(eng.running) < 8:
+        eng.step()
+    assert all(s.pages_settled == s.num_computed // ps >= 6
+               for s in eng.running)
+
+    def walked():
+        return eng.prefix_cache_stats()["walked_pages_total"]
+
+    steptrace.set_spans(True)
+    total, seen = walked(), set()
+    for _ in range(20):
+        fills = [s for s in eng.running if (s.num_computed + 1) % ps == 0]
+        n, w = len(counted), walked()
+        eng.step()
+        spans = [a for name, a in counted[n:]
+                 if name == "xllm.kv.register_pages"]
+        assert spans == [{"tokens": ps, "pages": 1}] * len(fills)
+        assert walked() - w == len(fills)
+        seen.add(len(fills))
+    assert len(eng.running) == 8 and {0, 1} <= seen
+    assert walked() - total == sum(
+        a["pages"] for name, a in counted
+        if name == "xllm.kv.register_pages") > 0
 
 
 # ---------------------------------------------------------------------------

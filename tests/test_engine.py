@@ -436,9 +436,9 @@ def test_no_call_site_rehashes_or_slices_the_token_list(monkeypatch):
     calls = []
     real = idx.register_pages
 
-    def spy(digests, tokens, num_computed, pages):
+    def spy(digests, tokens, num_computed, pages, settled=0):
         calls.append((digests is seq.page_digests, tokens is seq.tokens))
-        return real(digests, tokens, num_computed, pages)
+        return real(digests, tokens, num_computed, pages, settled)
 
     def banned(*a, **kw):
         raise AssertionError("the engine rehashed a list from block 0")

@@ -9,6 +9,7 @@ CPU). The full two-worker e2e lives in tests/test_e2e.py
 (TestPrefixReuse).
 """
 
+import random
 import threading
 import time
 from typing import List, Tuple
@@ -20,7 +21,8 @@ from xllm_service_tpu.config import (
     EngineConfig, InstanceType, ModelConfig, ServiceOptions)
 from xllm_service_tpu.obs.events import EventLog
 from xllm_service_tpu.runtime.kv_cache import (
-    HostKvTier, KvCacheEvent, PageAllocator, PrefixCacheIndex)
+    HostKvTier, KvCacheEvent, PageAllocator, PrefixCacheIndex,
+    SlotAllocator, WindowPool)
 from xllm_service_tpu.service.coordination import (
     InMemoryStore, instance_prefix)
 from xllm_service_tpu.service.instance_types import (
@@ -147,27 +149,42 @@ class TestPrefixCacheIndexEdges:
 # A page is hashed once: the per-sequence digests against the full rehash
 # ---------------------------------------------------------------------------
 
-def _register_by_full_rehash(idx: PrefixCacheIndex, tokens: List[int],
-                             pages: List[int]) -> None:
-    """Registration as it was before sequences kept their digests: every
-    call hashes ``tokens`` from block 0. The reference for
-    ``register_pages``."""
+def _register_by_whole_walk(idx: PrefixCacheIndex, digests: List[bytes],
+                            tokens: List[int], num_computed: int,
+                            pages: List[int]) -> int:
+    """``register_pages`` as it was before a row carried its settled
+    count (its span left out): EVERY call walks every full page of the
+    row. The oracle for the watermark. Returns the pages it walked."""
     if pages and not pages[0]:
-        return
-    for i, h in enumerate(idx.block_hashes(tokens)):
-        if i >= len(pages):
-            break
+        return 0
+    n_full = num_computed // idx.page_size
+    idx.extend_digests(digests, tokens, num_computed)
+    for i in range(min(n_full, len(pages))):
         pid = pages[i]
         if not pid:
             break
+        h = digests[i]
         if idx._hash_of.get(pid) == h:
             continue
         if h in idx._by_hash:
-            continue
+            continue  # another sequence already owns this content
         idx._evict_mapping(pid)
         idx._by_hash[h] = pid
         idx._hash_of[pid] = h
-        idx._pending_event.stored.append(h)
+        if idx.tails is None:
+            idx._pending_event.stored.append(h)
+    return min(n_full, len(pages))
+
+
+def _register_by_full_rehash(idx: PrefixCacheIndex, tokens: List[int],
+                             pages: List[int]) -> None:
+    """Registration as it was before sequences kept their digests: every
+    call hashes ``tokens`` from block 0, then walks. The reference for
+    ``register_pages``'s digests."""
+    if pages and not pages[0]:
+        return
+    _register_by_whole_walk(idx, idx.block_hashes(tokens), tokens,
+                            len(tokens), pages)
 
 
 def _toks(n: int, salt: int) -> List[int]:
@@ -184,6 +201,11 @@ class _Seq:
         self.pages: List[int] = []
         self.num_computed = 0
         self.digests: List[bytes] = []
+        # what the watermark's parity test adds (_Watermark, below)
+        self.settled = 0
+        self.oracle_digests: List[bytes] = []
+        self.wpages: List[int] = []
+        self.trimmed = 0
 
 
 class _Lockstep:
@@ -363,6 +385,363 @@ def test_sequence_digests_leave_the_index_as_the_full_rehash_does(
     assert ls.old.hashed_tokens > 2 * ls.new.hashed_tokens
     for s in ls.seqs:
         assert s.digests == ls.old.block_hashes(s.tokens)[:len(s.digests)]
+
+
+# ---------------------------------------------------------------------------
+# Registration from a row's watermark: the whole walk as the oracle
+# ---------------------------------------------------------------------------
+
+class _Watermark(_Lockstep):
+    """What the engine does to its prefix index, done to two: ``new``
+    registers from each row's watermark (``Sequence.pages_settled``),
+    ``old`` by the whole walk. ``family``: ``plain``; ``window`` (a
+    uniform window of three pages: the lead is trimmed); ``tails`` (the
+    window layers' pool of their own, a tail at a finished prefill's
+    boundary); ``state`` (a snapshot at a prompt's last full page). After
+    every call both hold the same mappings, the same reclaimable pages in
+    the same order, the same snapshots and tails, and have queued the
+    same events."""
+
+    def __init__(self, ps: int, family: str = "plain",
+                 num_pages: int = 24) -> None:
+        super().__init__(ps, num_pages)
+        self.family = family
+        self.window = 3 * ps if family in ("window", "tails") else 0
+        for idx in (self.new, self.old):
+            if family == "state":
+                idx.enable_snapshots(SlotAllocator(1, 3))
+            if family == "tails":
+                idx.enable_tails(WindowPool(num_pages, tail_pages=2,
+                                            max_tails=3))
+        self.running: List[_Seq] = []
+        self.waiting: List[_Seq] = []
+        self.calls = self.oracle_walked = self.twins = self.trims = 0
+
+    def both(self, f, seq: _Seq = None):
+        """``f(index, the row's chain on that side)`` on both sides: the
+        same answer."""
+        a = f(self.new, seq.digests if seq else None)
+        b = f(self.old, seq.oracle_digests if seq else None)
+        assert a == b
+        return a
+
+    def check(self) -> None:
+        super().check()
+        n, o = self.new, self.old
+        assert n._advertised == o._advertised
+        assert n._snapshot_of == o._snapshot_of
+        assert list(n._snapshots_unhit) == list(o._snapshots_unhit)
+        assert list(n._snapshots_hit) == list(o._snapshots_hit)
+        if n.tails is not None:
+            assert n.tails._tails == o.tails._tails
+            assert list(n.tails._unhit) == list(o.tails._unhit)
+            assert n.tails.pages_live == o.tails.pages_live
+
+    def release(self, pages: List[int]) -> None:
+        super().release(pages)
+        self.check()
+
+    def pressure(self, n: int = 0) -> None:
+        """``n`` pages taken and given back (0: every free and every
+        reclaimable one): the oldest unowned mappings are evicted."""
+        n = n or self.new.allocator.num_free + self.new.num_reclaimable
+        n = min(n, self.new.allocator.num_free + self.new.num_reclaimable)
+        self.release(self.alloc(n))
+
+    def _alloc_row(self, n: int):
+        """Engine._alloc_pages: the window pool first, then the full
+        one; None and nothing held where either is short."""
+        w: List[int] = []
+        if self.family == "tails":
+            w = self.both(lambda idx, _: idx.tails.alloc(n))
+            if w is None:
+                return None
+        pages = self.both(lambda idx, _: idx.alloc(n))
+        if pages is None and self.family == "tails":
+            self.both(lambda idx, _: idx.tails.release(w))
+        self.check()
+        return None if pages is None else (pages, w)
+
+    def lookup(self, seq: _Seq) -> int:
+        """Engine._try_admit: the cached prefix by reference (settled: it
+        was found under the row's own digests), pages for the rest and
+        the token sampled next. -1: no room, the row waits."""
+        if seq not in self.seqs:
+            self.seqs.append(seq)
+        hit, cached = self.both(
+            lambda idx, d: idx.match_prefix(seq.prompt, d), seq)
+        tail: List[int] = []
+        if self.family == "tails" and hit:
+            tail = list(self.new.tails.tail_of(hit[-1]))
+            self.both(lambda idx, _: idx.tails.acquire(tail))
+        got = self._alloc_row(-(-(len(seq.tokens) + 1) // self.ps)
+                              - len(hit))
+        if got is None:
+            self.release(hit)
+            if tail:
+                self.both(lambda idx, _: idx.tails.release(tail))
+            if seq not in self.waiting:
+                self.waiting.append(seq)
+            return -1
+        seq.pages = hit + got[0]
+        seq.settled = len(hit)
+        if self.family == "tails":
+            seq.trimmed = len(hit) - len(tail)
+            seq.wpages = [0] * seq.trimmed + tail + got[1]
+        seq.num_computed = cached
+        self.running.append(seq)
+        return cached
+
+    def prefill(self, batch: List[_Seq], salt: int = 0) -> None:
+        """One prefill program over the rest of each row's tokens, and
+        Engine._run_prefill's post: a window family's tail BEFORE the
+        first token (``salt``; 0: none is appended), a state family's
+        snapshots after every row's."""
+        ps = self.ps
+        snaps = []
+        for seq in batch:                       # Engine._state_cols
+            boundary = len(seq.tokens) // ps
+            if self.family == "state" and seq.num_computed < boundary * ps:
+                snaps.append((seq, boundary - 1, self.both(
+                    lambda idx, _: idx.reserve_snapshot())))
+        for seq in batch:
+            seq.num_computed = len(seq.tokens)
+            boundary = seq.num_computed // ps
+            if self.family == "tails":          # Engine._attach_tail
+                self.register(seq)
+                if boundary:
+                    first = max(boundary - self.new.tails.tail_pages, 0)
+                    self.both(lambda idx, d: idx.attach_tail(
+                        d, boundary, seq.wpages[first:boundary]), seq)
+                    self.check()
+            if salt:
+                self.decode(seq, _toks(1, salt))
+        for seq, page, slot in snaps:           # Engine._attach_snapshots
+            self.register(seq)
+            self.both(lambda idx, d: idx.attach_snapshot(d[page], slot),
+                      seq)
+            self.check()
+
+    def admit(self, seq: _Seq, prefill: int = 0) -> int:
+        """A row admitted alone: ``prefill`` tokens past the hit are
+        computed (a first window that registers nothing; 0: all of
+        them)."""
+        cached = self.lookup(seq)
+        if cached >= 0 and prefill:
+            seq.num_computed = cached + prefill
+        elif cached >= 0:
+            self.prefill([seq])
+        return cached
+
+    def register(self, seq: _Seq) -> None:
+        n_full = seq.num_computed // self.ps
+        seq.settled = self.new.register_pages(
+            seq.digests, seq.tokens, seq.num_computed, seq.pages,
+            seq.settled)
+        self.oracle_walked += _register_by_whole_walk(
+            self.old, seq.oracle_digests, seq.tokens, seq.num_computed,
+            seq.pages)
+        self.calls += 1
+        assert seq.digests == seq.oracle_digests
+        self.check()
+        if seq.pages and seq.pages[0]:
+            # the watermark is what it says it is ...
+            assert seq.settled <= min(n_full, len(seq.pages))
+            assert all(self.new._hash_of.get(seq.pages[i]) == seq.digests[i]
+                       for i in range(seq.settled))
+            # ... and stops only in front of a page whose content
+            # another page owns (or of a NULL the engine never makes
+            # above a live lead)
+            if seq.settled < min(n_full, len(seq.pages)) \
+                    and seq.pages[seq.settled]:
+                self.twins += 1
+                assert self.new._by_hash[seq.digests[seq.settled]] \
+                    != seq.pages[seq.settled]
+
+    def decode(self, seq: _Seq, toks: List[int]) -> None:
+        """Engine._append_token, once a token: register, trim behind
+        the window, grow the table (a row that finds no page is
+        preempted)."""
+        for tok in toks:
+            seq.num_computed = len(seq.tokens)
+            seq.tokens.append(tok)
+            self.register(seq)
+            self._swa_trim(seq)
+            need = -(-(len(seq.tokens) + 1) // self.ps) - len(seq.pages)
+            if need > 0:
+                got = self._alloc_row(need)
+                if got is None:
+                    self.give_up(seq)
+                    self.waiting.append(seq)
+                    return
+                seq.pages += got[0]
+                seq.wpages += got[1]
+
+    def _swa_trim(self, seq: _Seq) -> None:
+        if not self.window:
+            return
+        pages = seq.pages if self.family == "window" else seq.wpages
+        bound = min((seq.num_computed - self.window) // self.ps,
+                    len(pages))
+        if bound <= seq.trimmed:
+            return
+        gone = [p for p in pages[seq.trimmed:bound] if p]
+        if self.family == "window":
+            self.release(gone)
+        else:
+            self.both(lambda idx, _: idx.tails.release(gone))
+        pages[seq.trimmed:bound] = [0] * (bound - seq.trimmed)
+        seq.trimmed = bound
+        self.trims += 1
+
+    def give_up(self, seq: _Seq) -> None:
+        """Engine._finish_seq and _preempt_seq: register, drop the pages
+        and the watermark with them; tokens and digests stay."""
+        super().give_up(seq)
+        if self.family == "tails":          # Engine._release_window
+            self.both(lambda idx, _: idx.tails.release(
+                [p for p in seq.wpages if p]))
+        seq.settled = seq.trimmed = 0
+        seq.wpages = []
+        if seq in self.running:
+            self.running.remove(seq)
+        self.check()
+
+
+def _twin_settles_late(ls: _Watermark, ps: int) -> None:
+    """Two rows prefill one content at once: the second's pages are
+    twins, its watermark stays in front of them through every token, and
+    it takes the content over at its first call after the owner's
+    mappings are evicted."""
+    a, b = _Seq(_toks(2 * ps + 1, 30)), _Seq(_toks(2 * ps + 1, 30))
+    ls.admit(a)
+    ls.admit(b)
+    ls.decode(a, _toks(1, 31))
+    ls.decode(b, _toks(ps, 32))                 # a page of b's own fills
+    assert (a.settled, b.settled) == (2, 0)
+    assert b.pages[2] in ls.new._hash_of        # registered past the twins
+    ls.give_up(a)
+    ls.pressure()
+    ls.decode(b, _toks(1, 33))
+    assert b.settled == 3
+    assert [ls.new._by_hash[h] for h in b.digests] == b.pages[:3]
+    ls.give_up(b)
+
+
+def _tails(ls: _Watermark, ps: int) -> None:
+    """A window family: a finished prefill's boundary keeps a tail, a
+    follow-up resumes there with the document's pages settled, the
+    cluster hears of blocks with their tail alone."""
+    doc = _toks(4 * ps, 34)
+    a = _Seq(doc + _toks(3, 35))
+    assert ls.admit(a) == 0
+    assert ls.new.tails.num_tails == 1
+    ls.decode(a, _toks(2 * ps, 36))             # the window moves on
+    ls.give_up(a)
+    b = _Seq(doc + _toks(ps + 2, 37))
+    assert ls.admit(b) == 4 * ps and b.trimmed == 2
+    ls.decode(b, _toks(ps, 38))
+    assert b.settled == 6
+    ls.pressure(2)
+    ls.give_up(b)
+    ls.pressure()                               # pages go, tails with them
+    assert ls.new.tails.num_tails == 0 and not ls.new._advertised
+
+
+def _snapshots(ls: _Watermark, ps: int) -> None:
+    """A state family: the page a snapshot is attached to is looked up
+    right after registration, and a match ends at a page that has one."""
+    doc = _toks(3 * ps, 39)
+    a = _Seq(doc + _toks(2, 40))
+    assert ls.admit(a) == 0
+    assert ls.new.snapshot_of(a.pages[2])
+    ls.decode(a, _toks(ps, 41))                 # a page with no snapshot
+    ls.give_up(a)
+    b = _Seq(a.tokens[:4 * ps] + _toks(1, 42))
+    assert ls.admit(b) == 3 * ps and b.settled == 3
+    ls.decode(b, _toks(2, 43))
+    # a's fourth page is cached behind no snapshot: b wrote a copy of its
+    # own, a twin until a's unowned mapping goes
+    assert b.settled == 3 and b.pages[3] not in ls.new._hash_of
+    ls.pressure()
+    ls.decode(b, _toks(1, 47))
+    assert b.settled == 4 and ls.new._hash_of[b.pages[3]] == b.digests[3]
+    ls.give_up(b)
+    page = ls.new._by_hash[b.digests[2]]
+    for salt in (44, 45, 46):                   # three slots: never-hit
+        c = _Seq(_toks(ps + 1, salt))           # ones make room
+        ls.admit(c)
+        ls.give_up(c)
+    assert ls.new.snapshot_of(page) and ls.new.snapshots_evicted >= 2
+
+
+def _seeded(seed: int):
+    def schedule(ls: _Watermark, ps: int) -> None:
+        """Iterations of an engine under a seeded draw: admissions (one
+        to three rows at once, on five shared documents or one of their
+        own: rows that prefill one document at once are twins), a token
+        for every running row, finishes, preemptions and readmissions,
+        pages taken and given back."""
+        rng = random.Random(seed)
+        docs = [_toks((2 + d) * ps + rng.randrange(ps), 50 + d)
+                for d in range(5)]
+        for it in range(120):
+            draw = rng.random()
+            if draw < 0.22 and len(ls.running) < 5:
+                batch = []
+                for _ in range(rng.choice((1, 1, 2, 3))):
+                    if ls.waiting and rng.random() < 0.6:
+                        batch.append(ls.waiting.pop(0))
+                    elif rng.random() < 0.8:
+                        batch.append(_Seq(docs[rng.randrange(5)] + _toks(
+                            rng.randrange(1, ps), 1000 + it)))
+                    else:
+                        batch.append(_Seq(_toks(
+                            rng.randrange(ps, 4 * ps), 2000 + it)))
+                # looked up one after another, prefilled in one program
+                ls.prefill([s for s in batch if ls.lookup(s) >= 0],
+                           salt=3000 + it)
+            elif draw < 0.80:
+                for s in list(ls.running):
+                    ls.decode(s, _toks(1, 4000 + it))
+            elif draw < 0.90 and ls.running:
+                ls.give_up(rng.choice(ls.running))
+            elif draw < 0.95 and ls.running:
+                s = rng.choice(ls.running)
+                ls.give_up(s)                   # preempted: admitted again
+                ls.waiting.append(s)
+            else:
+                ls.pressure(rng.randrange(1, 5))
+        for s in list(ls.running):
+            ls.give_up(s)
+        # the draw met what it is for
+        assert ls.twins
+        assert ls.trims or not ls.window
+        assert ls.family != "tails" or (ls.new.tails.tail_hits
+                                        and ls.new.tails.tail_evictions)
+        assert ls.family != "state" or ls.new.snapshots_evicted
+    schedule.__name__ = f"seeded{seed}"
+    return schedule
+
+
+@pytest.mark.parametrize("family, scenario", [
+    ("plain", _plain), ("plain", _swa_null_lead),
+    ("plain", _shared_content), ("plain", _preempt_reregister),
+    ("plain", _evict_other_owner), ("plain", _twin_settles_late),
+    ("tails", _tails), ("state", _snapshots)] + [
+    (family, _seeded(seed))
+    for family in ("plain", "window", "tails", "state")
+    for seed in (1, 2, 3)],
+    ids=lambda v: v if isinstance(v, str) else v.__name__.strip("_"))
+def test_registration_from_the_watermark_leaves_the_index_as_the_whole_walk_does(
+        family, scenario):
+    ps = 8
+    ls = _Watermark(ps, family, num_pages=40)
+    scenario(ls, ps)
+    assert ls.calls and ls.new.hashed_tokens == ls.old.hashed_tokens
+    # the watermark looked at the pages that filled, the walk at every
+    # page of every row at every call
+    assert ls.new.walked_pages <= ls.oracle_walked
 
 
 # ---------------------------------------------------------------------------

@@ -125,7 +125,10 @@ SPAN_NAMES: Tuple[str, ...] = (
     "xllm.loop.obs_flush",   # around Worker._flush_engine_obs
     "xllm.loop.idle_wait",   # _work_event.wait when no runtime had work
     "xllm.kv.match_prefix",  # PrefixCacheIndex.match_prefix; arg tokens
-    "xllm.kv.register_pages",  # PrefixCacheIndex.register_pages; arg tokens
+    "xllm.kv.register_pages",  # PrefixCacheIndex.register_pages, where a
+                             # full page lies past the row's watermark
+                             # (no span where none does); args tokens,
+                             # pages
     "xllm.kv.state_slots",   # Engine: a snapshot slot reserved (the
                              # least recently hit evicted for it) in
                              # prefill.pack, or attached to its page in

@@ -157,6 +157,11 @@ class Sequence:
     # ``tokens`` only grows, so preemption and fault reset keep them:
     # a page is hashed once in the life of the sequence.
     page_digests: List[bytes] = dataclasses.field(default_factory=list)
+    # How many leading entries of ``pages`` are registered under this
+    # row's own digest (what kv_cache.PrefixCacheIndex.register_pages
+    # returned last): the per-token registration starts there. Belongs
+    # to ``pages``: 0 again wherever they are emptied or rebuilt.
+    pages_settled: int = 0
     # A model whose state lives by slot: the state row the sequence owns
     # from admission to finish or preemption (0: none; it holds slots
     # 2r - 1 and 2r), and the snapshot slot its first window starts from
@@ -980,10 +985,14 @@ class Engine:
         slot = self._free_slot()
         if slot < 0 or (self.state_model and not self.state_rows.num_free):
             return False        # no state row: queued, as for pages
+        settled = 0
         if seq.req.mm_embeds is None and not seq.req.prompt_logprobs:
             cached_pages, cached_tokens = \
                 self.prefix_cache.match_prefix(seq.req.token_ids,
                                                seq.page_digests)
+            # found under the row's own digests, so registered under
+            # them (what a tier restore adds below is walked once more)
+            settled = len(cached_pages)
             if self.host_tier is not None \
                     and not self._ring_eligible(seq, 0):
                 # Ring-eligible prompts skip the tier restore outright:
@@ -1035,6 +1044,7 @@ class Engine:
             return False
         new_pages, new_w = new_pages
         seq.pages = list(cached_pages) + new_pages
+        seq.pages_settled = min(settled, len(cached_pages))
         if self.window is not None:
             seq.num_trimmed = len(cached_pages) - len(tail)
             seq.wpages = [0] * seq.num_trimmed + list(tail) + new_w
@@ -1174,12 +1184,14 @@ class Engine:
     def _register_pages(self, seq: Sequence) -> None:
         """Content-address ``seq``'s full pages of computed tokens, so
         other prompts can reuse the prefix. Called for every sampled
-        token: the index hashes a page when it fills and otherwise only
-        walks ``seq.page_digests`` against ``seq.pages``; no token list
-        is sliced or converted here."""
+        token: the index hashes and registers a page when it fills, from
+        the row's settled lead on (``seq.pages_settled``), and returns
+        at once while none has; no token list is sliced or converted
+        here."""
         if seq.req.mm_embeds is None:
-            self.prefix_cache.register_pages(
-                seq.page_digests, seq.tokens, seq.num_computed, seq.pages)
+            seq.pages_settled = self.prefix_cache.register_pages(
+                seq.page_digests, seq.tokens, seq.num_computed, seq.pages,
+                seq.pages_settled)
 
     def _preempt_seq(self, seq: Sequence) -> None:
         """Recompute-style preemption: free pages, requeue (generated
@@ -1188,6 +1200,7 @@ class Engine:
         self._register_pages(seq)
         self.prefix_cache.release_pages([p for p in seq.pages if p])
         seq.pages = []
+        seq.pages_settled = 0
         self._release_window(seq)
         seq.num_trimmed = 0
         seq.num_computed = 0
@@ -2434,6 +2447,7 @@ class Engine:
             self._release_seq_slot(seq)
             self.prefix_cache.release_pages([p for p in seq.pages if p])
             seq.pages = []
+            seq.pages_settled = 0
             self._release_window(seq)
             seq.num_trimmed = 0
             seq.num_computed = 0
@@ -2955,6 +2969,7 @@ class Engine:
             "hit_tokens_total": self.prefix_hit_tokens,
             "fetched_blocks_total": self.fetched_blocks,
             "hashed_tokens_total": self.prefix_cache.hashed_tokens,
+            "walked_pages_total": self.prefix_cache.walked_pages,
             "spilled_pages": tier.spilled_blocks if tier else 0,
             "restored_pages": tier.restored_blocks if tier else 0,
         }

@@ -484,6 +484,10 @@ class PrefixCacheIndex:
         # restore and registration alike): the engine's
         # ``hashed_tokens_total``.
         self.hashed_tokens = 0
+        # Pages ``register_pages`` set out to look at over this index's
+        # life (a row's full pages past its settled lead): the engine's
+        # ``walked_pages_total``.
+        self.walked_pages = 0
         # A model whose sequences carry a state that lives by SLOT and
         # not by page (``enable_snapshots``): page id -> the slot that
         # holds the state as of that page's last token. A prefix match
@@ -525,16 +529,22 @@ class PrefixCacheIndex:
         lacks. ``digests`` belongs to one token list that only grows
         (an engine ``Sequence``'s), so ``digest(block_i) =
         murmur3(digest(block_{i-1}) || le32(block_i))`` never changes once
-        computed: byte-equal to ``block_hashes(tokens[:num_tokens])``."""
+        computed: byte-equal to ``block_hashes(tokens[:num_tokens])``.
+        A chain that is still empty and wanted over the whole list (a
+        fresh prompt at its admission) is made by the ONE call that
+        hashes a list's blocks, not a slice, a pack and a call a block."""
         ps = self.page_size
         have, want = len(digests), num_tokens // ps
         if want <= have:
             return
-        prev = digests[-1] if digests else None
-        for b in range(have, want):
-            prev = chained_block_hash(tokens[b * ps:(b + 1) * ps], prev,
-                                      self.seed)
-            digests.append(prev)
+        if not have and want == len(tokens) // ps:
+            digests.extend(prefix_block_hashes(tokens, ps, self.seed))
+        else:
+            prev = digests[-1] if digests else None
+            for b in range(have, want):
+                prev = chained_block_hash(tokens[b * ps:(b + 1) * ps], prev,
+                                          self.seed)
+                digests.append(prev)
         self.hashed_tokens += (want - have) * ps
 
     # -- lookup -----------------------------------------------------------
@@ -684,12 +694,33 @@ class PrefixCacheIndex:
         self.register_pages([], tokens, len(tokens), pages)
 
     def register_pages(self, digests: List[bytes], tokens: Sequence[int],
-                       num_computed: int, pages: Sequence[int]) -> None:
+                       num_computed: int, pages: Sequence[int],
+                       settled: int = 0) -> int:
         """Register every full page of ``tokens[:num_computed]`` under its
         chained hash. ``pages[i]`` holds tokens [i*ps, (i+1)*ps);
         ``digests`` is the sequence's chain (``extend_digests``), so a
         page is hashed once, when it fills. Safe to call repeatedly as a
         sequence grows, and again on new pages after a preemption.
+
+        ``settled`` is what an earlier call on the SAME ``pages``
+        returned (0: nothing known, every full page is walked): the
+        count of leading pages that are registered under the row's own
+        digest (``_hash_of[pages[i]] == digests[i]``). The walk starts
+        there, and a call that finds no full page past it (every sampled
+        token but the one that fills a page) returns before a span is
+        opened or anything is looked up: the work follows the pages that
+        FILLED since the last call, not the row's table. The count is
+        sound because a settled page cannot lose its mapping while the
+        row holds its reference: ``_evict_mapping`` is reached only from
+        ``alloc``'s reclaim of pages with no owner, from this loop and
+        ``register_blocks`` (both on a page being registered anew, never
+        one that is settled). A page whose content ANOTHER page owns
+        (``h in self._by_hash``: two rows prefilled one content at once)
+        is not settled: the count stops in front of it and the next call
+        tests it again, as a walk from page 0 would, so it takes the
+        content over once the owner's mapping is evicted. Whoever empties
+        or rebuilds ``pages`` starts again from 0.
+
         ``pages`` is the FULL pool's table: under a uniform window the
         engine trims it, and a sequence whose first page is trimmed
         registers nothing more (below); where the window layers have a
@@ -697,7 +728,7 @@ class PrefixCacheIndex:
         registered, and what makes a boundary resumable is its TAIL
         (``attach_tail``), with which the cluster is told of it."""
         if not self.enable:
-            return
+            return settled
         if pages and not pages[0]:
             # Leading page already trimmed behind a UNIFORM window:
             # nothing below is registrable (see the break below), so
@@ -705,13 +736,19 @@ class PrefixCacheIndex:
             # sequence. (Registration under a trimmed lead needs the
             # tails' rule for a model with no full layer: ROADMAP.md
             # Reach A2 (c).)
-            return
+            return settled
         n_full = num_computed // self.page_size
+        if n_full <= settled:
+            return settled
+        end = min(n_full, len(pages))
+        walk = max(end - settled, 0)
+        self.walked_pages += walk
         with steptrace.span(
                 "xllm.kv.register_pages",
-                tokens=max(n_full - len(digests), 0) * self.page_size):
+                tokens=max(n_full - len(digests), 0) * self.page_size,
+                pages=walk):
             self.extend_digests(digests, tokens, num_computed)
-            for i in range(min(n_full, len(pages))):
+            for i in range(settled, end):
                 pid = pages[i]
                 if not pid:
                     # NULL placeholder: a sliding-window-trimmed page
@@ -723,15 +760,17 @@ class PrefixCacheIndex:
                     # hit.
                     break
                 h = digests[i]
-                if self._hash_of.get(pid) == h:
-                    continue
-                if h in self._by_hash:
-                    continue  # another sequence already owns this content
-                self._evict_mapping(pid)
-                self._by_hash[h] = pid
-                self._hash_of[pid] = h
-                if self.tails is None:      # else: told with its tail
-                    self._pending_event.stored.append(h)
+                if self._hash_of.get(pid) != h:
+                    if h in self._by_hash:
+                        continue  # another sequence already owns this content
+                    self._evict_mapping(pid)
+                    self._by_hash[h] = pid
+                    self._hash_of[pid] = h
+                    if self.tails is None:      # else: told with its tail
+                        self._pending_event.stored.append(h)
+                if settled == i:
+                    settled = i + 1
+        return settled
 
     # -- refcounting ------------------------------------------------------
     def _acquire(self, pid: int) -> None:

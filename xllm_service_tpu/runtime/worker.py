@@ -1976,6 +1976,14 @@ class Worker:
             "stays near 1 while a page is hashed once",
             labelnames=("model",)).set_total(
             stats["hashed_tokens_total"], model=m)
+        self.obs.counter(
+            "xllm_worker_prefix_cache_walked_pages_total",
+            "pages the prefix index's registration set out to look at (a "
+            "row's full pages past its settled lead); over the sampled "
+            "tokens it stays near the pages that fill (1 / page_size) "
+            "plus an admission's own, whatever the rows' contexts",
+            labelnames=("model",)).set_total(
+            stats["walked_pages_total"], model=m)
 
     def _flush_phase_cpu(self, rt: ModelRuntime) -> None:
         """The phase ledger's CPU column, mirrored at scrape time alone:
